@@ -17,13 +17,10 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 
 
 def main(argv=None) -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
     from ..core.config import load_config_from_env
     from ..loadgen import SCENARIOS, run_scenario
 

@@ -12,9 +12,7 @@ wire-compatible daemon (gubernator-tpu or the reference service):
                              serialized and responses unmarshalled by
                              the native codec (native/gubtpu.cpp) over a
                              raw-bytes gRPC method, so a check never
-                             constructs a python protobuf object —
-                             attacking the ~1.3ms of python client
-                             machinery the BENCH_E2E artifacts measure;
+                             constructs a python protobuf object;
   LeasedClient / AsyncLeasedClient
                              client-side admission (docs/leases.md;
                              arXiv:2510.04516): a bounded local

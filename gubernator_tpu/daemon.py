@@ -342,6 +342,7 @@ class Daemon:
                 )
             )
         self.service: Optional[Service] = None
+        self._warmup_s = 0.0
         self.fastpath = None
         # Gubstat census sampler (runtime/gubstat.py): armed in start()
         # per GUBER_STATS_ENABLED, closed before the fastpath (its ring
@@ -401,6 +402,7 @@ class Daemon:
         )
         if self.flightrec is not None:
             self.flightrec.start()
+        t_warm = time.monotonic()
         self.service = Service(
             cfg,
             clock=self.clock,
@@ -428,6 +430,8 @@ class Daemon:
             await asyncio.get_running_loop().run_in_executor(
                 None, self.fastpath._ring.warmup
             )
+        # Table build + every start-up compile (or compile-cache load).
+        self._warmup_s = time.monotonic() - t_warm
         if cfg.stats.enabled:
             # Gubstat census sampler: periodic table_stats census off
             # the request path (docs/observability.md).  Registered as
@@ -555,6 +559,10 @@ class Daemon:
         log.info(
             "gubernator-tpu daemon up: grpc=%s http=%s",
             self.grpc_address, self.http_address,
+        )
+        log.info(
+            "device: %s",
+            " ".join(f"{k}={v}" for k, v in self._device_vars().items()),
         )
 
     async def drain(self) -> int:
@@ -787,6 +795,20 @@ class Daemon:
         snap["enabled"] = True
         return web.json_response(snap)
 
+    def _device_vars(self) -> dict:
+        """Where this daemon runs, as JAX reports it: platform,
+        device_kind, device count, the ids of the devices the table
+        lives on, whether the compiled lane loaded, and what start-up
+        warm-up cost."""
+        from gubernator_tpu import native
+
+        out = self.service.backend.device_info()
+        out["compiled_lane"] = native.available()
+        if not out["compiled_lane"]:
+            out["compiled_lane_error"] = native.load_error()
+        out["warmup_s"] = round(self._warmup_s, 3)
+        return out
+
     async def _http_vars(self, request: web.Request):
         """expvar-style internal counters (the Go daemon exposes
         /debug/vars via expvar; these are the TPU engine's equivalents)."""
@@ -796,6 +818,7 @@ class Daemon:
         }
         s = self.service
         if s is not None:
+            out["device"] = self._device_vars()
             be = s.backend
             out["backend"] = {
                 "checks": be.checks,

@@ -1,15 +1,22 @@
 """ctypes bindings for the C++ host runtime (native/gubtpu.cpp).
 
 Loads `libgubtpu.so` from this directory, building it with `make -C native`
-on first use when a toolchain is present.  All entry points have pure-Python
-fallbacks (core/hashing.py, ops/batch.py), so the library is an
-accelerator, not a dependency; `available()` reports which path is active.
+when it is missing or was built from a different `native/gubtpu.cpp` than
+the one in the checkout (the build stamps the source's SHA-256 into the
+library; mtimes do not survive a copy).  All entry points have pure-Python
+fallbacks (core/hashing.py, ops/batch.py) for library users without a
+toolchain; `available()` reports which path is active, a failed build or
+load is logged as an ERROR with the compiler's output, and `require()`
+raises it — the daemon's `/debug/vars` `device.compiled_lane` and the chip
+smoke treat a lane that did not load as a failure, not a slower daemon.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
+import re
 import subprocess
 import threading
 from typing import Optional, Tuple
@@ -22,71 +29,79 @@ _SO_PATH = os.path.join(os.path.dirname(__file__), "libgubtpu.so")
 _NATIVE_DIR = os.path.join(
     os.path.dirname(__file__), os.pardir, os.pardir, "native"
 )
+_SRC_PATH = os.path.join(_NATIVE_DIR, "gubtpu.cpp")
+_STAMP_RE = re.compile(rb"GUBSRCHASH:([0-9a-f]{64})")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_load_error = ""
+_rebuilt = False
 _load_lock = threading.Lock()
 
 
-def _build() -> bool:
+def source_hash() -> Optional[str]:
+    """SHA-256 of native/gubtpu.cpp; None where the checkout carries no
+    source (an installed package ships only the library)."""
+    try:
+        with open(_SRC_PATH, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()
+    except FileNotFoundError:
+        return None
+
+
+def _stamped_hash() -> Optional[str]:
+    """The source hash stamped into the library on disk, read from its
+    bytes — a stale library is never dlopen'ed (a second dlopen of the
+    same path would hand back the stale mapping)."""
+    try:
+        with open(_SO_PATH, "rb") as f:
+            m = _STAMP_RE.search(f.read())
+    except FileNotFoundError:
+        return None
+    return m.group(1).decode() if m else None
+
+
+def _build() -> None:
     """Compile via make; the Makefile writes to a temp path and renames so
-    concurrent builders (other processes) never expose a half-written .so."""
-    if not os.path.isdir(_NATIVE_DIR):
-        return False
+    concurrent builders (other processes) never expose a half-written .so.
+    -B: make keys on mtime, which says nothing after a copy."""
     try:
         subprocess.run(
-            ["make", "-C", _NATIVE_DIR],
+            ["make", "-B", "-C", _NATIVE_DIR],
             check=True,
             capture_output=True,
+            text=True,
             timeout=120,
         )
-        return True
-    except (subprocess.SubprocessError, OSError) as e:
-        log.info("native build unavailable (%s); using python paths", e)
-        return False
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native build failed (rc={e.returncode}): "
+            f"{(e.stderr or e.stdout or '').strip()[-2000:]}"
+        ) from e
+    except (subprocess.TimeoutExpired, OSError) as e:
+        raise RuntimeError(f"native build failed: {e}") from e
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _load_error, _rebuilt
     if _lib is not None or _tried:
         return _lib
     with _load_lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        lib = _try_load()
-        if lib is not None:
-            try:
-                lib = _bind(lib)
-            except AttributeError as e:
-                # Loaded fine but misses symbols: a STALE .so from an
-                # older build.  Rebuild and retry like a failed dlopen.
-                log.info("stale native library (%s); rebuilding", e)
-                lib = None
-        if lib is None:
-            # Missing, stale, torn, or wrong-arch: rebuild once and retry.
-            if _build():
-                lib = _try_load()
-                if lib is not None:
-                    try:
-                        lib = _bind(lib)
-                    except AttributeError as e:
-                        log.warning(
-                            "rebuilt native library still missing "
-                            "symbols: %s", e,
-                        )
-                        lib = None
-        _lib = lib
+        try:
+            want = source_hash()
+            if want is not None and _stamped_hash() != want:
+                _build()
+                _rebuilt = True
+            _lib = _bind(ctypes.CDLL(_SO_PATH))
+        except (RuntimeError, OSError, AttributeError) as e:
+            _load_error = f"{type(e).__name__}: {e}"
+            log.error(
+                "native library unavailable, python lanes only: %s",
+                _load_error,
+            )
         return _lib
-
-
-def _try_load() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_SO_PATH):
-        return None
-    try:
-        return ctypes.CDLL(_SO_PATH)
-    except OSError as e:
-        log.warning("failed to load %s: %s", _SO_PATH, e)
-        return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -184,6 +199,26 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> str:
+    """Why the library is unavailable ("" when it loaded)."""
+    _load()
+    return _load_error
+
+
+def require() -> None:
+    """Raise unless the library loaded — for callers that must serve
+    from the compiled lane (chip_smoke.py)."""
+    if _load() is None:
+        raise RuntimeError(f"native library unavailable: {_load_error}")
+
+
+def rebuilt() -> bool:
+    """True when this process had to build the library (missing, or
+    stamped with another source hash) rather than verify it."""
+    _load()
+    return _rebuilt
 
 
 def hash_keys(keys) -> np.ndarray:
@@ -363,8 +398,7 @@ def encode_reqs(reqs) -> Optional[bytes]:
     protobuf objects — the compiled CLIENT codec (client.FastV1Client;
     gub_serialize_reqs).  Returns None when the native library is
     unavailable (callers fall back to python-protobuf)."""
-    lib = _load()
-    if lib is None:
+    if _load() is None:
         return None
     n = len(reqs)
     names = [r.name.encode() for r in reqs]
@@ -380,15 +414,38 @@ def encode_reqs(reqs) -> Optional[bytes]:
             dtype=np.int64, count=n,
         )
 
+    return encode_req_columns(
+        b"".join(names), name_off, b"".join(keys), key_off,
+        col("hits"), col("limit"), col("duration"), col("algorithm"),
+        col("behavior"), col("burst"),
+    )
+
+
+def encode_req_columns(
+    names: bytes, name_off: np.ndarray, keys: bytes, key_off: np.ndarray,
+    hits: np.ndarray, limit: np.ndarray, duration: np.ndarray,
+    algorithm: np.ndarray, behavior: np.ndarray, burst: np.ndarray,
+) -> bytes:
+    """encode_reqs from columns: concatenated name/key bytes with
+    int64[n+1] offsets plus int64[n] field columns — a bulk loader
+    (chip_smoke.py) never builds per-request objects.  Native only."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native library unavailable")
+    n = len(hits)
     # Worst case per item: 6 numeric fields at 11 B (negative int64
     # varints are 10 B + tag), two string frames at 6 B of framing, and
     # the item frame header — 96 B covers it with slack.
     cap = int(name_off[-1] + key_off[-1]) + n * 96 + 16
     out = np.empty(cap, dtype=np.uint8)
+
+    def c64(a):
+        return np.ascontiguousarray(a, dtype=np.int64)
+
     written = lib.gub_serialize_reqs(
-        n, b"".join(names), name_off, b"".join(keys), key_off,
-        col("hits"), col("limit"), col("duration"), col("algorithm"),
-        col("behavior"), col("burst"), out, cap,
+        n, names, c64(name_off), keys, c64(key_off),
+        c64(hits), c64(limit), c64(duration), c64(algorithm),
+        c64(behavior), c64(burst), out, cap,
     )
     if written < 0:
         raise RuntimeError("serialize_reqs buffer overflow")

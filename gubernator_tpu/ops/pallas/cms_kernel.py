@@ -27,12 +27,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gubernator_tpu.ops.sketch import SketchState, _rotate, row_columns
 
-# jax 0.5 renamed TPUCompilerParams -> CompilerParams; serve both so the
-# kernel traces (and interprets on CPU) across the supported range.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", None
-) or pltpu.TPUCompilerParams
-
 # 128 keeps the [BLK, W] one-hot at 4MB — safely under the 16MB VMEM
 # scoped limit with double buffering — and measured fastest on v5e
 # (49.6M decisions/s vs 34.2M at 256; 512 OOMs VMEM).
@@ -146,7 +140,7 @@ def cms_step_pallas_impl(
         ],
         # The sketch output is revisited by every grid step (accumulation),
         # so the grid must be sequential, not parallel.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,

@@ -2,9 +2,8 @@
 queue (docs/ring.md's "kill the last dispatch" direction).
 
 Every ring iteration — even a megaround block — is still one XLA entry:
-a host->device dispatch whose fixed cost dominates small-batch latency
-on every rig we have measured (the ~13ms CPU-rig small-batch p50 vs the
-µs the kernel math costs).  This kernel is the next structural step: a
+a host->device dispatch with a fixed cost per entry (not measured on a
+directly attached chip yet).  This kernel is the next structural step: a
 long-lived `pallas_call` that OWNS the table block for the duration of
 the launch and drains a device-resident request queue of `k` stacked
 rounds across its sequential grid steps — the table lives in the
@@ -38,14 +37,16 @@ without re-entering XLA dispatch.
 from __future__ import annotations
 
 import functools
+import os
+import traceback
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from gubernator_tpu.ops.pallas.cms_kernel import _CompilerParams
 from gubernator_tpu.ops.state import SlotTable
 from gubernator_tpu.ops.step import apply_batch_packed_q_impl
 
@@ -126,7 +127,7 @@ def persistent_serve_step_impl(
         ],
         # The table outputs are revisited by every grid step
         # (accumulation), so the grid must be sequential.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
@@ -174,19 +175,30 @@ def probe_compile(
             ways=ways,
         ).compile()
     except Exception as e:  # noqa: BLE001 — the reason IS the signal
-        return False, f"persistent serve kernel failed to compile: {e}"
+        # The innermost frame says whose code refused (the lowering's
+        # own errors carry no location in their message).
+        at = traceback.extract_tb(e.__traceback__)[-1]
+        return False, (
+            "persistent serve kernel failed to compile: "
+            f"{type(e).__name__}: {e} (raised in {at.name} at "
+            f"{os.path.basename(at.filename)}:{at.lineno})"
+        )
     return True, ""
 
 
-def persistent_supported(platform: str) -> Tuple[bool, str]:
-    """Capability report for a backend on `platform`: only a real TPU
-    may even attempt the Mosaic compile — CPU/GPU report the interpret
-    gap honestly instead of shipping an emulated 'persistent' mode that
-    is slower than the scan it replaces."""
+def persistent_supported(
+    platform: str, num_slots: int, ways: int, batch: int
+) -> Tuple[bool, str]:
+    """Capability report for a backend on `platform` at ITS table and
+    batch geometry (a kernel that compiles at a toy size says nothing
+    about 2^24 slots held in VMEM): only a real TPU may even attempt the
+    Mosaic compile — CPU/GPU report the interpret gap honestly instead
+    of shipping an emulated 'persistent' mode that is slower than the
+    scan it replaces."""
     if platform != "tpu":
         return False, (
             "persistent serve kernel needs a TPU backend (running on "
             f"{platform!r}; interpret mode serves the differential "
             "tests only)"
         )
-    return probe_compile()
+    return probe_compile(num_slots, ways, batch)

@@ -102,15 +102,34 @@ def _f64(x: jax.Array) -> jax.Array:
 def _trunc_i64(x: jax.Array) -> jax.Array:
     """Go's int64(float64): truncation toward zero.
 
-    Edge semantics are XLA convert's, differentially pinned against the
-    oracle (core/pymodel.py _trunc; tests/test_differential.py::
+    The edge semantics are spelled out here, not left to the backend's
+    convert, and differentially pinned against the oracle
+    (core/pymodel.py _trunc; tests/test_differential.py::
     test_go_trunc_differential): -1.5 -> -1 (toward zero, not floor),
     exact through +/-2^62, out-of-range/inf SATURATE at the int64
     bounds, NaN -> 0.  Go's own spec leaves these implementation-
     dependent (amd64 CVTTSD2SI gives INT64_MIN for all three), so the
     saturating behavior is this build's documented contract.
+
+    XLA:CPU's convert already behaves so.  XLA:TPU's does not (measured
+    on a v5e, PR 21): float64 is a pair of float32s there and the
+    convert truncates each half on its own, so a value just below an
+    integer lands ON it (18.999999999 -> 19, 6646153.846 -> 6646154),
+    and out-of-range values wrap, inf gives -1, NaN garbage.  Hence
+    the explicit range selects, and the step back toward zero wherever
+    the converted value overshot — a no-op where the convert is exact.
     """
-    return x.astype(jnp.int64)
+    two63 = jnp.float64(2.0**63)
+    over = x >= two63
+    under = x <= -two63
+    safe = jnp.where(over | under | jnp.isnan(x), jnp.float64(0.0), x)
+    y = safe.astype(jnp.int64)
+    yf = y.astype(jnp.float64)
+    y = jnp.where((safe >= 0) & (yf > safe), y - 1, y)
+    y = jnp.where((safe < 0) & (yf < safe), y + 1, y)
+    return jnp.where(
+        over, jnp.int64(2**63 - 1), jnp.where(under, jnp.int64(-(2**63)), y)
+    )
 
 
 def _sat_add_i64(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -684,8 +703,7 @@ def apply_batch_packed_impl(
     ways: int = 8,
 ) -> Tuple[SlotTable, jax.Array]:
     """apply_batch with the response packed into ONE int64[9, B] array —
-    a single device->host transfer per step instead of nine.  Matters when
-    the host link has per-transfer latency (e.g. remote-device tunnels).
+    a single device->host transfer per step instead of nine.
 
     Rows: status, limit, remaining, reset_time, persisted, found, stored,
     cached, stored_status.
@@ -729,9 +747,8 @@ def apply_batch_packed_q_impl(
     ways: int = 8,
 ) -> Tuple[SlotTable, jax.Array]:
     """Fully packed step: ONE int64[12, B] host->device transfer in, ONE
-    int64[9, B] transfer out.  Per-transfer link latency (remote-device
-    tunnels) makes the 12-arrays-in form 12x more expensive; this is the
-    single-device analog of the mesh path's pack_grid_batch."""
+    int64[9, B] transfer out — the single-device analog of the mesh
+    path's pack_grid_batch."""
     return apply_batch_packed_impl(table, unpack_batch_q(q), now, ways)
 
 

@@ -49,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from gubernator_tpu.core.types import RateLimitReq, RateLimitResp
 from gubernator_tpu.ops.batch import pack_requests_grid
-from gubernator_tpu.ops.state import SlotTable, init_table
+from gubernator_tpu.ops.state import SlotTable
 from gubernator_tpu.ops.step import (
     CachedRows,
     DeviceBatchJ,
@@ -60,6 +60,7 @@ from gubernator_tpu.parallel.mesh import SHARD_AXIS, shard_of_hash
 from gubernator_tpu.parallel.sharded import (
     MeshBackend,
     _shard_map,
+    init_sharded_table,
     pack_grid_batch,
     packed_grid_rounds_to_host,
 )
@@ -166,6 +167,30 @@ def make_global_sync_step(mesh, ways: int):
     return jax.jit(sharded, donate_argnums=(0, 1))
 
 
+def _psum_mod64(a: jax.Array) -> jax.Array:
+    """psum of an int64 lane over the shard axis, modulo 2^64, as ONE
+    uint32 collective.  XLA:TPU lowers no 64-bit integer all-reduce
+    ("UNIMPLEMENTED: Supported lowering only of Sum all reduce" on a
+    uint64 psum, v5e, PR 21), so each value travels as four 16-bit limbs
+    in uint32 lanes — a limb's sum over up to 2^16 shards cannot wrap —
+    and the carries are propagated afterwards.  Bit-identical to the
+    uint64 psum it replaces."""
+    u = a.astype(jnp.uint64)
+    mask = jnp.uint64(0xFFFF)
+    limbs = jnp.stack([
+        ((u >> jnp.uint64(16 * k)) & mask).astype(jnp.uint32)
+        for k in range(4)
+    ])
+    sums = jax.lax.psum(limbs, SHARD_AXIS).astype(jnp.uint64)
+    out = jnp.zeros_like(u)
+    carry = jnp.zeros_like(u)
+    for k in range(4):
+        c = sums[k] + carry
+        out = out | ((c & mask) << jnp.uint64(16 * k))
+        carry = c >> jnp.uint64(16)  # the last carry is the mod 2^64
+    return out.astype(jnp.int64)
+
+
 def make_global_sync_step_psum(mesh, ways: int):
     """The single-collective form of the sync step: hit aggregation is
     ONE `psum` over the shard axis instead of an all_to_all followed by
@@ -190,20 +215,18 @@ def make_global_sync_step_psum(mesh, ways: int):
 
         # sendHits, as ONE collective: per-source grids are disjoint by
         # host construction, so the sum IS the merge (bool fields ride
-        # as int32 — psum is an add reduction).  int64 lanes reduce in
-        # uint64: the fingerprint lane spans the full int64 range, and
-        # if the disjointness invariant is ever violated its sum must
-        # wrap modularly (a bogus key that matches nothing) rather than
-        # hit signed overflow — two's-complement addition is
+        # as int32 — psum is an add reduction).  int64 lanes reduce
+        # modulo 2^64: the fingerprint lane spans the full int64 range,
+        # and if the disjointness invariant is ever violated its sum
+        # must wrap modularly (a bogus key that matches nothing) rather
+        # than hit signed overflow — two's-complement addition is
         # bit-identical either way, so behavior under the invariant is
         # unchanged (still pinned against the a2a step).
         def _psum_lane(a):
             if a.dtype == jnp.bool_:
                 a = a.astype(jnp.int32)
             if a.dtype == jnp.int64:
-                return jax.lax.psum(
-                    a.astype(jnp.uint64), SHARD_AXIS
-                ).astype(jnp.int64)
+                return _psum_mod64(a)
             return jax.lax.psum(a, SHARD_AXIS)
 
         merged = DeltaGrid(*[_psum_lane(a) for a in d])
@@ -334,8 +357,8 @@ class GlobalEngine:
                 f"global cache buckets per shard ({nb_local}) must be a "
                 "power of two"
             )
-        self.cache_table: SlotTable = jax.device_put(
-            init_table(self.cache_slots), backend._tsharding
+        self.cache_table: SlotTable = init_sharded_table(
+            self.cache_slots, backend._tsharding
         )
         # Same packed sharded step as the backend hot path, run on the
         # cache table (single-transfer in and out).

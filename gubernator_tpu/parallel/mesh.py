@@ -21,19 +21,27 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from gubernator_tpu.ops.devices import platform_devices
+
 SHARD_AXIS = "shard"
 _SHARD_SHIFT = 32
 
 
 def make_mesh(
-    num_shards: int, devices: Optional[Sequence[jax.Device]] = None
+    num_shards: int,
+    devices: Optional[Sequence[jax.Device]] = None,
+    platform: Optional[str] = None,
 ) -> Mesh:
-    """1-D mesh over the first `num_shards` devices, axis name "shard".
+    """1-D mesh over the first `num_shards` devices (of `platform` unless
+    `devices` is given), axis name "shard".
 
     The rate-limit table is pure data-parallel over the key space, so one
     axis is the natural topology (the reference's peer ring is also 1-D).
     """
-    devs = list(devices) if devices is not None else jax.devices()
+    devs = (
+        list(devices) if devices is not None
+        else platform_devices(platform)
+    )
     if len(devs) < num_shards:
         raise ValueError(
             f"need {num_shards} devices, have {len(devs)}"

@@ -16,17 +16,14 @@ reference (peer_client.go) compiles away to local work on the right device.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 import gubernator_tpu.ops  # noqa: F401  (enables x64)
 from gubernator_tpu.core import clock as clock_mod
@@ -34,6 +31,7 @@ from gubernator_tpu.core.config import DeviceConfig
 from gubernator_tpu.core.hashing import key_hash64
 from gubernator_tpu.core.types import CacheItem, RateLimitReq, RateLimitResp
 from gubernator_tpu.ops.batch import PackedGrid, pack_requests_grid
+from gubernator_tpu.ops.devices import device_info
 from gubernator_tpu.ops.state import SlotTable, init_table, table_to_host
 from gubernator_tpu.ops.step import DeviceBatchJ, apply_batch_packed_impl
 from gubernator_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_of_hash
@@ -71,12 +69,10 @@ def pack_requests_sharded(
 
 
 # -- packed single-transfer hot path ------------------------------------
-# A per-field path would cost 12 sharded host->device puts and 6
-# device->host reads per round; with a per-transfer host link latency
-# (remote-device tunnels) transfers dominate E2E, which is why the
-# single-device backend got apply_batch_packed (ops/step.py:542-568).
-# Here the whole DeviceBatch travels as ONE int64[12, n, B] array and the
-# response returns as ONE int64[n, 6, B] array.
+# A per-field path would cost 12 sharded host->device puts and 9
+# device->host reads per round.  Here the whole DeviceBatch travels as
+# ONE int64[12, n, B] array and the response returns as ONE
+# int64[n, 9, B] array (the mesh analog of ops/step.apply_batch_packed).
 
 
 def pack_grid_batch(db) -> np.ndarray:
@@ -318,6 +314,18 @@ def make_sharded_table_stats(mesh, ways: int):
     return jax.jit(sharded)
 
 
+@functools.lru_cache(maxsize=8)
+def _sharded_init(sharding):
+    return jax.jit(init_table, static_argnums=0, out_shardings=sharding)
+
+
+def init_sharded_table(num_slots: int, sharding) -> SlotTable:
+    """All-empty table laid out by `sharding`: each shard's slice is
+    zero-filled on its own device, so the whole table never exists on
+    device 0 first."""
+    return _sharded_init(sharding)(num_slots)
+
+
 def drain_to_grids(per_shard: List[list], B: int, make_grid, fill_lane):
     """Drain per-shard row lists into consecutive [n, B] grids (overflow
     chunks into extra grids).  `fill_lane(grid, shard, lane, row)` writes
@@ -354,7 +362,7 @@ class MeshBackend(PersistenceHost):
         self.clock = clock or clock_mod.default_clock()
         self._lock = threading.Lock()
         self._init_write_through()
-        self.mesh = make_mesh(cfg.num_shards, devices)
+        self.mesh = make_mesh(cfg.num_shards, devices, cfg.platform)
         self.local_slots = cfg.num_slots // cfg.num_shards
         nb_local = self.local_slots // cfg.ways
         if nb_local & (nb_local - 1):
@@ -363,8 +371,8 @@ class MeshBackend(PersistenceHost):
             )
         self._tsharding = NamedSharding(self.mesh, P(SHARD_AXIS))
         self._bsharding = NamedSharding(self.mesh, P(SHARD_AXIS))
-        self.table: SlotTable = jax.device_put(
-            init_table(cfg.num_slots), self._tsharding
+        self.table: SlotTable = init_sharded_table(
+            cfg.num_slots, self._tsharding
         )
         from gubernator_tpu.ops.step import (
             BucketRows,
@@ -470,6 +478,11 @@ class MeshBackend(PersistenceHost):
                 time_mod.monotonic() - t_start
             )
         return resps, seq
+
+    def device_info(self) -> dict:
+        return device_info(
+            list(self.mesh.devices.flat), self.cfg.platform
+        )
 
     def persistent_serve_supported(self):
         """The persistent Pallas decision kernel owns ONE table block;

@@ -2339,10 +2339,9 @@ class GlobalManager:
         Deliberately NOT routed through the compiled lane: re-read lanes
         share keys with in-flight client GLOBAL merges, and a key whose
         occurrences mix use_cached (client reads) with uncached (the
-        re-read) loses host-cascade eligibility — an A/B on the r4 rig
-        measured global_4peer collapsing 20k -> 5k checks/s with re-reads
-        merged into the lane, versus ~1/3 of cluster cycles saved.  The
-        LocalBatcher still coalesces concurrent re-read batches."""
+        re-read) loses host-cascade eligibility and falls back to one
+        device round per occurrence.  The LocalBatcher still coalesces
+        concurrent re-read batches."""
         return await self.s._check_local(reads)
 
     async def _broadcast_peers(

@@ -23,6 +23,15 @@
 
 extern "C" {
 
+// The build stamps the SHA-256 of this file (native/Makefile); the loader
+// (gubernator_tpu/native/__init__.py) scans the .so for the marker before
+// dlopen and rebuilds on a mismatch, so a library never outlives the
+// source it was built from.
+#ifndef GUB_SRC_HASH
+#define GUB_SRC_HASH ""
+#endif
+const char* gub_src_hash() { return "GUBSRCHASH:" GUB_SRC_HASH; }
+
 // ---------------------------------------------------------------------------
 // XXH64 (from the xxHash spec; seed fixed to 0 like core/hashing.py)
 // ---------------------------------------------------------------------------

@@ -1,6 +1,9 @@
 #!/usr/bin/env python
-"""Perf-regression CI gate: diff two BENCH_E2E artifacts (ROADMAP item
-5's down payment — a slow PR fails loudly instead of drifting).
+"""Perf-regression CI gate: diff two BENCH_E2E-shaped artifacts (a slow
+PR fails loudly instead of drifting).  The repo commits no such
+artifacts at present — `--repo .` then reports "nothing to gate" — so
+today it gates gubload scenario artifacts (scripts/load_smoke.py) and
+whatever pair it is handed.
 
 Compares the NEW artifact's per-config p50 against the BASELINE's on
 MATCHING keys — (config, serve_mode, concurrency) for bench_e2e rows,
@@ -9,18 +12,17 @@ key with no baseline warns instead of failing) — and fails (exit 1)
 when any matched config's p50 regressed by more than --threshold
 (default 25%).  Throughput (checks_per_sec) regressions past the same
 threshold are reported as warnings: p50 is the gate (the tail is what
-operators feel), throughput is rig-noise-prone.
+operators feel), throughput is noise-prone.
 
 Platform honesty: artifacts record the ACTUAL jax platform.  When the
-two artifacts' platforms differ (e.g. a cpu CI runner diffing a tpu rig
+two artifacts' platforms differ (e.g. a cpu CI runner diffing a tpu
 recording), every finding downgrades to a warning and the gate exits 0
 — a cross-platform diff measures the platform, not the PR.  `--warn-
 only` forces the same downgrade for same-platform diffs (e.g. a fresh
 CI-runner artifact vs a committed one recorded on different hardware).
 
 Noise honesty: CPU artifacts carry multi-ms scheduler noise on the
-small-batch configs (the r09/r10 depth sweeps bounce ±30% between
-identical-code runs), so on cpu-vs-cpu diffs a p50 regression must
+small-batch configs, so on cpu-vs-cpu diffs a p50 regression must
 clear BOTH the relative threshold and an absolute floor
 (--min-delta-ms, default 5).  TPU diffs gate on the relative threshold
 alone — that is the 2ms-SLO regime where half a millisecond is a real
@@ -87,16 +89,14 @@ def _round_no(path: Path) -> int:
 
 def find_latest_pair(repo: Path):
     """The two most recent committed BENCH_E2E_r{N}.json (suffix-free)
-    artifacts — the PR-vs-previous-round diff the CI gate runs."""
+    artifacts — the PR-vs-previous-round diff the CI gate runs — or
+    None where the repo carries fewer than two (nothing to gate)."""
     arts = sorted(
         (p for p in repo.glob("BENCH_E2E_r*.json") if _round_no(p) >= 0),
         key=_round_no,
     )
     if len(arts) < 2:
-        raise SystemExit(
-            f"bench_gate: need >= 2 BENCH_E2E_r*.json under {repo}, "
-            f"found {[p.name for p in arts]}"
-        )
+        return None
     return arts[-2], arts[-1]
 
 
@@ -199,7 +199,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.repo is not None:
-        base_p, new_p = find_latest_pair(Path(args.repo))
+        pair = find_latest_pair(Path(args.repo))
+        if pair is None:
+            print(f"bench_gate: fewer than two BENCH_E2E_r*.json under "
+                  f"{args.repo} — nothing to gate")
+            return 0
+        base_p, new_p = pair
     elif args.baseline and args.new:
         base_p, new_p = Path(args.baseline), Path(args.new)
     else:

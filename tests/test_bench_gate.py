@@ -189,8 +189,17 @@ def test_find_latest_pair(tmp_path):
     base, new = bench_gate.find_latest_pair(tmp_path)
     assert base.name == "BENCH_E2E_r09.json"
     assert new.name == "BENCH_E2E_r10.json"
-    with pytest.raises(SystemExit, match="need >= 2"):
-        bench_gate.find_latest_pair(tmp_path / "nowhere")
+    assert bench_gate.find_latest_pair(tmp_path / "nowhere") is None
+
+
+def test_repo_without_artifacts_is_nothing_to_gate(tmp_path, capsys):
+    """The BENCH_E2E_r*.json records are gone (PR 21); until a benchmark
+    commits artifacts again `--repo` is a clean one-line no-op, not a
+    crash."""
+    (tmp_path / "BENCH_E2E_r01.json").write_text("{}")  # one is no pair
+    assert bench_gate.main(["--repo", str(tmp_path)]) == 0
+    out = capsys.readouterr().out.strip().splitlines()
+    assert len(out) == 1 and "nothing to gate" in out[0]
 
 
 def test_cli_end_to_end(tmp_path):

@@ -612,6 +612,59 @@ def test_fastpath_sticky_token_status(frozen_clock):
     asyncio.run(scenario())
 
 
+def test_fastpath_new_leaky_bucket_over_asked_in_a_duplicate_group(
+    frozen_clock,
+):
+    """A NEW leaky bucket asked for more than its burst is stored empty
+    (algorithms.go:470-476), unlike an existing bucket's over-ask, which
+    mutates nothing.  The host cascade's read lane creates the bucket
+    FULL, so its replay must take the new-item branch itself — found by
+    chip_smoke.py's wire stream against core/pymodel.py (PR 21)."""
+    import asyncio
+
+    from gubernator_tpu.core.config import Config
+    from gubernator_tpu.core.pymodel import PyRateLimiter
+    from gubernator_tpu.net.grpc_api import reqs_from_pb
+    from gubernator_tpu.proto import gubernator_pb2 as pb
+    from gubernator_tpu.runtime.fastpath import FastPath
+    from gubernator_tpu.runtime.service import Service
+
+    async def scenario():
+        dev = DeviceConfig(num_slots=1024, ways=8, batch_size=64)
+        svc = Service(Config(device=dev), clock=frozen_clock)
+        await svc.start()
+        fp = FastPath(svc)
+        oracle = PyRateLimiter(clock=frozen_clock)
+
+        def batch(key, hits_list):
+            return [
+                pb.RateLimitReq(name="lk", unique_key=key, hits=h,
+                                limit=10, burst=20, duration=3_600_000,
+                                algorithm=pb.LEAKY_BUCKET)
+                for h in hits_list
+            ]
+
+        for reqs in [
+            batch("fresh", [40, 40]),      # new + over-asked: stored 0
+            batch("fresh", [1, 1]),        # stays empty
+            batch("warm", [5, 5]),         # new, under: lattice as before
+            batch("warm", [40, 40, 1]),    # EXISTING over-ask: no mutation
+        ]:
+            payload = pb.GetRateLimitsReq(requests=reqs).SerializeToString()
+            out = await fp.check_raw(payload, peer_rpc=False)
+            got = pb.GetRateLimitsResp.FromString(out).responses
+            for j, (g, r) in enumerate(zip(got, reqs_from_pb(reqs))):
+                w = oracle.get_rate_limit(r)
+                assert (g.status, g.remaining, g.reset_time) == (
+                    int(w.status), w.remaining, w.reset_time
+                ), (reqs[0].unique_key, j)
+        assert fp.fallbacks == 0
+        await fp.close()
+        await svc.close()
+
+    asyncio.run(scenario())
+
+
 def test_multinode_columnar_routing():
     """Multi-node client path on the compiled lane: vectorized ring
     lookup, zero-copy forwards to owners, owner metadata on forwarded

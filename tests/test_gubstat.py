@@ -418,11 +418,23 @@ def test_debug_vars_schema_golden(stats_cluster):
         time.sleep(0.1)
 
     assert set(v) == {
-        "grpc_address", "http_address", "backend", "inflight_checks",
+        "grpc_address", "http_address", "device", "backend",
+        "inflight_checks",
         "global", "multi_region_sends", "peers", "circuits", "degraded",
         "hotkeys", "leases", "reshard", "tenants", "table", "fastpath",
         "tracing", "flightrec",
     }
+    # Where the daemon runs, as JAX reports it (tests are held to the
+    # CPU; chip_smoke.py requires "tpu" here).
+    assert set(v["device"]) == {
+        "platform", "device_kind", "device_count", "table_device_ids",
+        "compiled_lane", "warmup_s",
+    }
+    assert v["device"]["platform"] == "cpu"
+    assert v["device"]["device_count"] == 8  # conftest's virtual mesh
+    assert v["device"]["table_device_ids"] == [0]
+    assert v["device"]["compiled_lane"] is True
+    assert v["device"]["warmup_s"] > 0
     assert set(v["table"]) == {
         "samples", "errors", "interval_s", "occupancy", "live",
         "expired_resident", "per_shard_occupancy", "bucket_fill",

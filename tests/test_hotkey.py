@@ -323,7 +323,7 @@ def hot_cluster():
     )
     c = Cluster.start_with(["", "", ""], conf_template=conf)
     for d in c.daemons:
-        # No ORGANIC pressure on the CPU rig (its latencies would breach
+        # No ORGANIC pressure on a CPU run (its latencies would breach
         # the 2ms production target constantly); tests lower the target
         # on purpose and restore it.
         d.flightrec.slo_p99_ms = 1e9

@@ -1,7 +1,17 @@
-"""Native host runtime tests: XXH64 parity + packer differential."""
+"""Native host runtime tests: the loader's build rule, XXH64 parity,
+packer differential.
+
+Nothing here skips when the library is missing: the toolchain is part of
+the installation, the daemon's compiled lane is the path under test, and
+a library that did not build is a failure the suite must show."""
 from __future__ import annotations
 
+import json
 import random
+import shutil
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -14,9 +24,63 @@ from gubernator_tpu.ops.batch import (
     _pack_requests_grid_py,
 )
 
-pytestmark = pytest.mark.skipif(
-    not native.available(), reason="native library not built"
-)
+REPO = Path(__file__).resolve().parent.parent
+
+
+def test_library_is_built_from_the_source_beside_it():
+    native.require()
+    assert native.load_error() == ""
+    assert native._stamped_hash() == native.source_hash()
+
+
+_LOAD_COPY = """
+import importlib.util, json, sys
+spec = importlib.util.spec_from_file_location("natcopy", sys.argv[1])
+m = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(m)
+ok = m.available()
+print(json.dumps({
+    "available": ok, "rebuilt": m.rebuilt(), "error": m.load_error(),
+    "stamp_matches": m._stamped_hash() == m.source_hash(),
+    "hash": (m.hash_keys(["a"]).tolist() if ok else None),
+}))
+"""
+
+
+def test_loader_rebuilds_on_source_mismatch_and_reports_failure(tmp_path):
+    """The build stamps the source's SHA-256; the loader rebuilds when the
+    stamp and native/gubtpu.cpp disagree (mtimes say nothing after a
+    copy), and a build that fails is a named error, not a quiet
+    python-lane fallback."""
+    pkg = tmp_path / "gubernator_tpu" / "native"
+    pkg.mkdir(parents=True)
+    shutil.copy(REPO / "gubernator_tpu/native/__init__.py", pkg)
+    shutil.copytree(REPO / "native", tmp_path / "native")
+    src = tmp_path / "native" / "gubtpu.cpp"
+
+    def load() -> dict:
+        out = subprocess.run(
+            [sys.executable, "-c", _LOAD_COPY, str(pkg / "__init__.py")],
+            capture_output=True, text=True, timeout=180,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    built = load()  # no library yet: built from the source beside it
+    assert built["available"] and built["rebuilt"]
+    assert built["stamp_matches"]
+    again = load()  # stamp matches: verified, not rebuilt
+    assert again["available"] and not again["rebuilt"]
+
+    src.write_text(src.read_text() + "\n// edited\n")
+    edited = load()  # same mtime-order or not: the hash moved
+    assert edited["available"] and edited["rebuilt"]
+    assert edited["stamp_matches"] and edited["hash"] == built["hash"]
+
+    src.write_text("this is not C++\n")
+    broken = load()
+    assert not broken["available"]
+    assert "native build failed" in broken["error"]
 
 
 def test_xxh64_parity():

@@ -98,7 +98,7 @@ def test_capability_reporting_is_honest():
         persistent_supported,
     )
 
-    ok, reason = persistent_supported("cpu")
+    ok, reason = persistent_supported("cpu", 256, 8, 8)
     assert not ok
     assert "cpu" in reason and "interpret" in reason
 

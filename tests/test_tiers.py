@@ -4,7 +4,7 @@ A drain's round rides the smallest compiled shape that holds its active
 lanes — the device transfer scales with traffic, not with the configured
 max batch — and a full round must NEVER be truncated (batch_size is
 always a tier).  These are the invariants the small-shape latency path
-(colocated_latency_bound's 0.05ms/step exec) rests on.
+rests on.
 """
 import numpy as np
 
