@@ -1,0 +1,194 @@
+"""BENCHMARK.json and the benchmark's data files: loading, and the checks
+the harness makes on them before a run (bench/tests runs the same checks
+over every file).  Everything that belongs to one configuration, one
+traffic mix or one per-layer metric is found by its name:
+
+  bench/configs/<config>.json          bench/traffic/<traffic>.json
+  bench/layer_metrics/<metric>.json    bench/readers/<metric>.py (code)
+"""
+from __future__ import annotations
+
+import json
+import os
+import re
+from typing import Dict, List
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(BENCH)
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+METRIC_KEYS = {"name", "unit", "better", "source", "layer", "moves",
+               "workloads"}
+
+
+class SpecError(ValueError):
+    pass
+
+
+def load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def benchmark() -> dict:
+    return load_json(os.path.join(REPO, "BENCHMARK.json"))
+
+
+def config_path(bm: dict, config: str) -> str:
+    for c in bm["configs"]:
+        if c["name"] == config:
+            return os.path.join(REPO, c["file"])
+    raise SpecError(f"no configuration {config!r} in BENCHMARK.json")
+
+
+def traffic_path(traffic: str) -> str:
+    return os.path.join(BENCH, "traffic", traffic + ".json")
+
+
+def layer_metric_path(name: str) -> str:
+    return os.path.join(BENCH, "layer_metrics", name + ".json")
+
+
+def workload(bm: dict, name: str) -> dict:
+    for w in bm["workloads"]:
+        if w["name"] == name:
+            return w
+    raise SpecError(f"no workload {name!r} in BENCHMARK.json")
+
+
+def metrics_of(bm: dict, group: str, cell: str) -> List[dict]:
+    """The metrics of `group` ("end_to_end" / "per_layer") a cell reports."""
+    return [
+        m for m in bm[group]
+        if "workloads" not in m or cell in m["workloads"]
+    ]
+
+
+def _need(cond: bool, msg: str) -> None:
+    if not cond:
+        raise SpecError(msg)
+
+
+def check_traffic(t: dict, where: str) -> None:
+    _need(t.get("loop") in ("open", "closed"), f"{where}: loop kind")
+    _need(float(t.get("deadline_s", 0)) >= 5.0,
+          f"{where}: a client deadline under 5 s makes a slow answer a "
+          "failed one")
+    c = t.get("checks_per_rpc", {})
+    _need(1 <= int(c.get("min", 0)) <= int(c.get("max", 0)),
+          f"{where}: checks_per_rpc")
+    _need(int(t.get("connections", 0)) >= 1, f"{where}: connections")
+    _need(float(t.get("warm_in_s", 0)) > 0, f"{where}: warm_in_s")
+    _need(t.get("keys", {}).get("distribution") == "uniform",
+          f"{where}: key distribution")
+    if t["loop"] == "open":
+        _need(int(t.get("outstanding_cap", 0)) >= 1,
+              f"{where}: an open loop needs an outstanding cap")
+        a = t.get("arrivals", {})
+        _need(a.get("process") == "poisson"
+              and float(a.get("rate_rpc_per_s", 0)) > 0,
+              f"{where}: arrivals")
+    else:
+        _need(int(t.get("in_flight", 0)) >= 1, f"{where}: in_flight")
+        _need(float(t.get("pool_rpc_per_s", 0)) > 0,
+              f"{where}: pool_rpc_per_s")
+
+
+def check_config(c: dict, where: str) -> None:
+    for k in ("source", "chips", "daemon", "universe", "guarantees",
+              "reduced", "assumed", "background_timers_s"):
+        _need(k in c, f"{where}: missing {k!r}")
+    u = c["universe"]
+    for k in ("keys", "ways", "shards", "limit", "duration_ms",
+              "preload_remaining_below"):
+        _need(int(u.get(k, 0)) >= 1, f"{where}: universe.{k}")
+    _need(c["chips"] in (1, 4) and int(u["shards"]) in (1, c["chips"]),
+          f"{where}: chips/shards")
+    _need(all(k.startswith("GUBER_") for k in c["daemon"]),
+          f"{where}: daemon settings are GUBER_* variables")
+
+
+def check_layer_metric(m: dict, where: str) -> None:
+    for k in ("name", "layer", "unit", "better", "source", "moves",
+              "workloads", "read", "what"):
+        _need(k in m, f"{where}: missing {k!r}")
+    kind = m["read"].get("kind")
+    _need(kind in ("ratio", "code"), f"{where}: read.kind")
+    if kind == "ratio":
+        _need(bool(m["read"].get("num")), f"{where}: read.num")
+    else:
+        _need(os.path.isfile(
+            os.path.join(BENCH, "readers", m["name"] + ".py")
+        ), f"{where}: no bench/readers/{m['name']}.py")
+
+
+def check_benchmark(bm: dict) -> None:
+    """The contract's static rules that the harness depends on, and the
+    agreement between BENCHMARK.json and the data files."""
+    _need(set(bm) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"},
+          "BENCHMARK.json: keys")
+    names: Dict[str, set] = {"config": set(), "cell": set(), "metric": set()}
+    for c in bm["configs"]:
+        _need(NAME.match(c["name"]) and c["name"] not in names["config"],
+              f"configuration name {c['name']!r}")
+        names["config"].add(c["name"])
+        _need(c["file"].startswith(tuple(p + "/" for p in bm["paths"])),
+              f"{c['name']}: file outside paths")
+        cfg = load_json(os.path.join(REPO, c["file"]))
+        check_config(cfg, c["file"])
+        _need(cfg["source"] == c["source"], f"{c['name']}: source differs")
+        _need(cfg["reduced"] == c["reduced"], f"{c['name']}: reduced differs")
+    pairs = set()
+    four = 0
+    for w in bm["workloads"]:
+        _need(NAME.match(w["name"]) and w["name"] not in names["cell"],
+              f"workload name {w['name']!r}")
+        names["cell"].add(w["name"])
+        _need(w["config"] in names["config"], f"{w['name']}: config")
+        _need(NAME.match(w["traffic"]), f"{w['name']}: traffic name")
+        _need((w["config"], w["traffic"]) not in pairs,
+              f"{w['name']}: pair appears twice")
+        pairs.add((w["config"], w["traffic"]))
+        _need(len(w["why"]) <= 200 and "\n" not in w["why"],
+              f"{w['name']}: why")
+        check_traffic(load_json(traffic_path(w["traffic"])),
+                      "traffic/" + w["traffic"])
+        cfg = load_json(config_path(bm, w["config"]))
+        _need(cfg["chips"] == w["chips"], f"{w['name']}: chips")
+        four += w["chips"] == 4
+    _need(four <= max(1, len(bm["workloads"]) // 2), "too many 4-chip cells")
+    e2e = set()
+    for m in bm["end_to_end"] + bm["per_layer"]:
+        _need(NAME.match(m["name"]) and m["name"] not in names["metric"],
+              f"metric name {m['name']!r}")
+        names["metric"].add(m["name"])
+        _need(UNIT.match(m["unit"]), f"{m['name']}: unit {m['unit']!r}")
+        _need(m["better"] in ("lower", "higher"), f"{m['name']}: better")
+        _need(m["source"] in SOURCES, f"{m['name']}: source")
+        for cell in m.get("workloads", []):
+            _need(cell in names["cell"], f"{m['name']}: cell {cell!r}")
+    for m in bm["end_to_end"]:
+        _need(set(m) <= {"name", "unit", "better", "bound", "source",
+                         "workloads"}, f"{m['name']}: keys")
+        _need(0 < m["bound"] <= 0.25, f"{m['name']}: bound")
+        _need(m["source"] in ("host_clock", "device_trace"),
+              f"{m['name']}: end-to-end source")
+        e2e.add(m["name"])
+    _need("setup_s" in e2e, "no setup_s")
+    for m in bm["per_layer"]:
+        _need(set(m) <= METRIC_KEYS, f"{m['name']}: keys")
+        _need(m["moves"] in e2e, f"{m['name']}: moves {m['moves']!r}")
+        spec = load_json(layer_metric_path(m["name"]))
+        check_layer_metric(spec, "layer_metrics/" + m["name"])
+        for k in ("name", "unit", "better", "source", "layer", "moves",
+                  "workloads"):
+            _need(spec[k] == m.get(k), f"{m['name']}: {k} differs from "
+                  "its data file")
+    for w in bm["workloads"]:
+        mine = metrics_of(bm, "end_to_end", w["name"])
+        _need(len(mine) >= 2 and any(m["name"] == "setup_s" for m in mine),
+              f"{w['name']}: needs setup_s and one more end-to-end metric")
+        _need(metrics_of(bm, "per_layer", w["name"]),
+              f"{w['name']}: no per-layer metric")

@@ -1,0 +1,266 @@
+"""The key universe of a deployment, made from the seed: key names, their
+fingerprints, the rows a preloaded table holds and where each row sits.
+
+The harness (bench/run.py) builds it from (config, seed) to know what the
+reference must answer, and hands the daemon launcher (bench/serve.py) the
+resident rows to install.  Nothing here imports JAX.
+
+Placement is the set-associative arithmetic of ops/step.py's lookup
+(copied, not imported, so a later change to the program cannot move it):
+a fingerprint's bucket is ``h & (buckets_per_shard - 1)``, its shard
+``(h >> 32) % shards``, a bucket holds ``ways`` rows.  Of the universe
+keys that fall in one bucket the first ``ways`` in universe order are
+resident; the rest were "evicted" before the run starts (chip_smoke.py's
+expected_resident counts the same thing).
+"""
+from __future__ import annotations
+
+import hashlib
+from dataclasses import dataclass
+from typing import Dict
+
+import numpy as np
+
+NAMES = 16                      # rate-limit names (tenants) keys spread over
+NAME_LEN = KEY_LEN = 9          # b"bench_tNN", b"kXXXXXXXX"
+ALGO_TOKEN, ALGO_LEAKY = 0, 1
+BEHAVIOR_GLOBAL = 2             # core/types.Behavior.GLOBAL
+_HASH_CHUNK = 1 << 19
+
+_HEX4 = np.frombuffer(
+    b"".join(b"%04x" % v for v in range(1 << 16)), dtype=np.uint8
+).reshape(1 << 16, 4)
+_NAME_BLOB = np.frombuffer(
+    b"".join(b"bench_t%02d" % t for t in range(NAMES)), dtype=np.uint8
+).reshape(NAMES, NAME_LEN)
+
+
+def derive_seed(seed: int, path: str) -> int:
+    """A stable 64-bit sub-seed for `path` (loadgen/schedule.py's idiom):
+    the same in every process, unlike salted hash()."""
+    digest = hashlib.sha512(f"{seed}/{path}".encode()).digest()
+    return int.from_bytes(digest[:8], "big")
+
+
+def key_ids(index: np.ndarray, seed: int) -> np.ndarray:
+    """Distinct 32-bit key ids for universe positions: an odd multiplier
+    is a bijection mod 2^32, the seed moves the whole set."""
+    mix = np.uint64(derive_seed(seed, "universe") & 0xFFFFFFFF)
+    i = index.astype(np.uint64)
+    i *= np.uint64(2654435761)
+    i += mix
+    i &= np.uint64(0xFFFFFFFF)
+    return i
+
+
+def key_bytes(ids: np.ndarray):
+    """(names uint8[n, 9], keys uint8[n, 9]) for key ids."""
+    n = len(ids)
+    keys = np.empty((n, KEY_LEN), dtype=np.uint8)
+    keys[:, 0] = ord("k")
+    keys[:, 1:5] = _HEX4[(ids >> np.uint64(16)).astype(np.intp)]
+    keys[:, 5:9] = _HEX4[(ids & np.uint64(0xFFFF)).astype(np.intp)]
+    names = _NAME_BLOB[(ids % np.uint64(NAMES)).astype(np.intp)]
+    return names, keys
+
+
+def key_string(key_id: int) -> str:
+    """The reference's hash_key() of one key id: name + "_" + unique_key."""
+    return "bench_t%02d_k%08x" % (key_id % NAMES, key_id)
+
+
+def encode_rpc(native, ids, hits, limit, duration, algo, behavior) -> bytes:
+    """Wire bytes of one GetRateLimits RPC from columns."""
+    names, keys = key_bytes(ids)
+    off = np.arange(len(ids) + 1, dtype=np.int64) * KEY_LEN
+    return native.encode_req_columns(
+        names.tobytes(), off, keys.tobytes(), off,
+        hits, limit, duration, algo, behavior,
+        np.zeros(len(ids), dtype=np.int64),
+    )
+
+
+def global_bucket(fp: np.ndarray, slots: int, ways: int,
+                  shards: int) -> np.ndarray:
+    """shard * buckets_per_shard + bucket of int64 fingerprints: the
+    placement arithmetic of the module docstring, in one place."""
+    nb_local = slots // shards // ways
+    u = fp.view(np.uint64)
+    gb = (u >> np.uint64(32)) % np.uint64(shards) * np.uint64(nb_local)
+    gb += u & np.uint64(nb_local - 1)
+    return gb.astype(np.int64)
+
+
+def fingerprints(native, ids: np.ndarray) -> np.ndarray:
+    """int64 table fingerprints of key ids, through the program's own wire
+    parser so they are the server's to the bit."""
+    one = np.ones(len(ids), dtype=np.int64)
+    payload = encode_rpc(native, ids, one, one, one, one * 0, one * 0)
+    return native.parse_reqs(payload).hash
+
+
+@dataclass
+class Universe:
+    """Everything the harness knows about the keys before the first RPC.
+    Narrow dtypes on purpose: on the chip's host every fresh page costs,
+    and ten million keys are many pages."""
+
+    ids: np.ndarray          # uint32[U] key ids
+    fp: np.ndarray           # int64[U] fingerprints
+    algo: np.ndarray         # uint8[U] 0 token / 1 leaky
+    is_global: np.ndarray    # bool[U]
+    remaining0: np.ndarray   # uint8[U] preloaded remaining
+    gbucket: np.ndarray      # int32[U] shard * buckets_per_shard + bucket
+    way: np.ndarray          # int8[U] rank among the bucket's arrivals
+    resident: np.ndarray     # bool[U] preloaded (way < ways, not GLOBAL)
+    crowded: np.ndarray      # bool[U] bucket has more arrivals than ways
+    slot_order: np.ndarray   # uint32[U] universe indexes in table-slot order
+    limit: int
+    global_limit: int
+    duration_ms: int
+    slots: int
+    ways: int
+    shards: int
+
+    @property
+    def n_resident(self) -> int:
+        return int(self.resident.sum())
+
+
+def build_universe(native, cfg: dict, seed: int, slots: int) -> Universe:
+    """`cfg` is the configuration file's "universe" group; `slots` the
+    table size in use (the CPU dry run overrides it).  Works in chunks so
+    that temporaries are reused instead of freshly mapped."""
+    n = int(cfg["keys"])
+    ways, shards = int(cfg["ways"]), int(cfg["shards"])
+    n_global = int(cfg.get("global_keys", 0))
+    below = int(cfg["preload_remaining_below"])
+    nb_local = slots // shards // ways
+    if nb_local & (nb_local - 1):
+        raise ValueError(f"buckets per shard ({nb_local}) not a power of two")
+    if n >= 1 << 31 or slots // ways >= 1 << 31 or ways > 100 or below > 256:
+        raise ValueError("universe outside the packed layout's range")
+    ids = np.empty(n, dtype=np.uint32)
+    fp = np.empty(n, dtype=np.int64)
+    algo = np.empty(n, dtype=np.uint8)
+    remaining0 = np.empty(n, dtype=np.uint8)
+    gbucket = np.empty(n, dtype=np.int32)
+    # (bucket, index) packed into one word: a plain in-place sort ranks
+    # every key among its bucket's arrivals, in universe order.
+    packed = np.empty(n, dtype=np.uint64)
+    for lo in range(0, n, _HASH_CHUNK):
+        hi = min(n, lo + _HASH_CHUNK)
+        index = np.arange(lo, hi, dtype=np.uint64)
+        c_ids = key_ids(index, seed)
+        c_fp = fingerprints(native, c_ids)
+        c_gb = global_bucket(c_fp, slots, ways, shards).view(np.uint64)
+        ids[lo:hi] = c_ids
+        fp[lo:hi] = c_fp
+        algo[lo:hi] = (c_ids >> np.uint64(4)) & np.uint64(1)
+        remaining0[lo:hi] = (c_ids >> np.uint64(8)) % np.uint64(below)
+        gbucket[lo:hi] = c_gb
+        c_gb <<= np.uint64(32)
+        c_gb |= index
+        packed[lo:hi] = c_gb
+    is_global = np.zeros(n, dtype=bool)
+    is_global[:n_global] = True
+    algo[:n_global] = ALGO_TOKEN
+    packed.sort()
+    order = packed.astype(np.uint32)        # the low word: universe index
+    packed >>= np.uint64(32)                # now the sorted buckets
+    is_start = np.empty(n, dtype=bool)
+    is_start[0] = True
+    np.not_equal(packed[1:], packed[:-1], out=is_start[1:])
+    del packed
+    pos = np.arange(n, dtype=np.int32)
+    run_start = np.where(is_start, pos, np.int32(0))
+    np.maximum.accumulate(run_start, out=run_start)
+    np.subtract(pos, run_start, out=run_start)
+    way = np.empty(n, dtype=np.int8)
+    way[order] = np.minimum(run_start, 127)
+    del pos, run_start, is_start
+    counts = np.minimum(
+        np.bincount(gbucket, minlength=nb_local * shards), 127
+    ).astype(np.int8)
+    crowded = counts[gbucket] > ways
+    # GLOBAL keys are created by traffic (the engine syncs them into the
+    # owner's bucket), so their way is reserved but left empty at preload.
+    resident = (way < ways) & ~is_global
+    return Universe(
+        ids=ids, fp=fp, algo=algo, is_global=is_global,
+        remaining0=remaining0, gbucket=gbucket, way=way, resident=resident,
+        crowded=crowded, slot_order=order, limit=int(cfg["limit"]),
+        global_limit=int(cfg.get("global_limit", 0)),
+        duration_ms=int(cfg["duration_ms"]), slots=slots, ways=ways,
+        shards=shards,
+    )
+
+
+def handoff(u: Universe, seed: int, n_probe: int) -> Dict[str, np.ndarray]:
+    """What the daemon launcher needs to preload, so that it does not
+    build the universe a second time: the resident rows in slot order, and
+    a seeded sample of keys with whether the table must find each."""
+    sel = u.slot_order[u.resident[u.slot_order]]
+    rng = np.random.default_rng(derive_seed(seed, "probe"))
+    idx = rng.choice(len(u.fp), size=min(n_probe, len(u.fp)), replace=False)
+    return {
+        "slot": u.gbucket[sel].astype(np.int64) * u.ways + u.way[sel],
+        "fp": u.fp[sel],
+        "algo": u.algo[sel],
+        "remaining0": u.remaining0[sel],
+        "probe_fp": u.fp[idx],
+        "probe_found": u.resident[idx],
+        "geometry": np.array(
+            [u.slots, u.limit, u.duration_ms], dtype=np.int64
+        ),
+    }
+
+
+def table_arrays(h: Dict[str, np.ndarray], t0_ms: int) -> Dict[str, np.ndarray]:
+    """Host columns of the preloaded table (ops/state.SlotTable's fields,
+    the layout runtime/checkpoint.py restores through _install_table):
+    every resident key as a bucket row created at `t0_ms` with
+    `remaining0` tokens left.  `h` is handoff()'s dict; its rows are in
+    slot order, so every column is written front to back."""
+    s, limit, duration_ms = (int(x) for x in h["geometry"])
+    slot = h["slot"]
+    leaky = h["algo"] == ALGO_LEAKY
+
+    def col(dtype, values):
+        a = np.zeros(s, dtype=dtype)
+        a[slot] = values
+        return a
+
+    rem = h["remaining0"]
+    return {
+        "key": col(np.int64, h["fp"]),
+        "algo": col(np.int32, h["algo"]),
+        "kind": np.zeros(s, dtype=np.int32),          # KIND_BUCKET
+        "limit": col(np.int64, limit),
+        "duration": col(np.int64, duration_ms),
+        "remaining": col(np.int64, np.where(leaky, 0, rem)),
+        "remaining_f": col(np.float64, np.where(leaky, rem, 0)),
+        "t0": col(np.int64, t0_ms),
+        "status": np.zeros(s, dtype=np.int32),        # UNDER_LIMIT
+        "burst": col(np.int64, limit),
+        "expire_at": col(np.int64, t0_ms + duration_ms),
+        "touched": col(np.int64, t0_ms),
+    }
+
+
+def expected_occupancy(u: Universe, touched_index: np.ndarray,
+                       extra_fp: np.ndarray) -> int:
+    """Rows the table holds once the keys at `touched_index` (and the
+    fingerprints `extra_fp` of keys outside the universe) have been
+    served: a bucket keeps min(distinct arrivals, ways), nothing expires
+    inside a run."""
+    nb = u.slots // u.ways
+    present = u.resident.copy()
+    present[touched_index] = True
+    counts = np.bincount(u.gbucket[present], minlength=nb)
+    if len(extra_fp):
+        counts = counts + np.bincount(
+            global_bucket(np.unique(extra_fp), u.slots, u.ways, u.shards),
+            minlength=nb,
+        )
+    return int(np.minimum(counts, u.ways).sum())

@@ -1,0 +1,628 @@
+#!/usr/bin/env python3
+"""The benchmark's one command.
+
+    python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+One cell of BENCHMARK.json, one run: start the daemon (bench/serve.py: the
+server CLI's own start-up, the configuration's key universe preloaded and
+every reachable fetch program warmed), check special cases over the wire,
+drive the cell's traffic from a client process that never imports JAX
+(bench/client.py) through a warm-in and a window of --seconds, compare what
+came back with core/pymodel.py, and print — as the LAST line of stdout —
+{"correct", "attempted", "failed", "metrics", "device"[, "breakdown"]}.
+`attempted` and `failed` count checks.  --trace 0 reports the cell's
+end-to-end metrics, --trace 1 its per-layer metrics from a run that also
+traces a short span of the window with the profiler.
+
+This process never imports JAX (the daemon child holds the chip).  It
+fails, printing no result, when the daemon finds no TPU or fewer chips
+than the cell asks for.  `--platform cpu --slots 65536` is a dry run for
+rehearsing the harness: it is asked for explicitly, never fallen back to,
+and its last line always says "correct": false.
+
+Logs of the run land in chiprun_out/bench/<cell>-<seed>-t<trace>/ (git and
+the chip copy ignore chiprun_out/).
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import queue
+import signal
+import socket
+import shutil
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import urllib.request
+
+T_PROC = time.monotonic()
+
+BENCH = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(BENCH)
+sys.path[:0] = [BENCH, REPO]
+
+import numpy as np  # noqa: E402
+
+from lib import oracle, readers, schedule, shapes, spec  # noqa: E402
+from lib import universe as universe_mod  # noqa: E402
+from lib.percentile import beyond, percentile  # noqa: E402
+
+READY_TIMEOUT_S = 1100.0
+# A traced run profiles a short span near the END of its window: starting
+# and stopping the profiler stalls the daemon for up to a second, so the
+# per-layer numbers that are not read from the trace are taken over the
+# part of the window before it.
+TRACE_SPAN_S = 2.0
+TRACE_TAIL_S = 3.0        # the span starts this long before the window ends
+
+
+class Refused(Exception):
+    """The run cannot produce a result (no chip, a child died, ...)."""
+
+
+def log(msg: str) -> None:
+    print("[%8.2fs] %s" % (time.monotonic() - T_PROC, msg), flush=True)
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def http(addr: str, path: str, timeout: float = 30.0) -> bytes:
+    with urllib.request.urlopen(f"http://{addr}{path}", timeout=timeout) as r:
+        return r.read()
+
+
+def cache_dir() -> str:
+    """Where the program keeps JAX's persistent cache (ops/__init__.py's
+    rule, restated): the variable if set, else .jax_cache/ in the
+    checkout."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        REPO, ".jax_cache"
+    )
+
+
+def cache_entries() -> set:
+    d = cache_dir()
+    if not os.path.isdir(d):
+        return set()
+    return {f for f in os.listdir(d) if f.endswith("-cache")}
+
+
+class Child:
+    """A child process in its own group, its stdout read line by line."""
+
+    def __init__(self, name: str, cmd, env, log_path: str) -> None:
+        self.name = name
+        self.lines: queue.Queue = queue.Queue()
+        self._log = open(log_path, "wb")
+        self.proc = subprocess.Popen(
+            cmd, env=env, cwd=REPO, stdin=subprocess.PIPE,
+            stdout=subprocess.PIPE, stderr=self._log,
+            start_new_session=True,
+        )
+        self._reader = threading.Thread(
+            target=self._read, name=name + "-stdout", daemon=True
+        )
+        self._reader.start()
+
+    def _read(self) -> None:
+        for raw in self.proc.stdout:
+            self.lines.put(raw.decode("utf-8", "replace").strip())
+        self.lines.put(None)
+
+    def next_json(self, timeout: float) -> dict:
+        deadline = time.monotonic() + timeout
+        while True:
+            left = deadline - time.monotonic()
+            if left <= 0:
+                raise Refused(f"{self.name}: no answer in {timeout:.0f}s")
+            try:
+                line = self.lines.get(timeout=min(left, 1.0))
+            except queue.Empty:
+                continue
+            if line is None:
+                raise Refused(
+                    f"{self.name}: exited rc={self.proc.wait()} "
+                    "(see its log)"
+                )
+            if line.startswith("{"):
+                return json.loads(line)
+
+    def send(self, obj=None) -> None:
+        data = (json.dumps(obj) if obj is not None else "") + "\n"
+        self.proc.stdin.write(data.encode())
+        self.proc.stdin.flush()
+
+    def stop(self, grace_s: float = 60.0) -> None:
+        """Ask, wait, then make sure nothing of the group is left."""
+        try:
+            if self.proc.poll() is None:
+                try:
+                    self.proc.stdin.close()
+                except OSError:
+                    pass
+                try:
+                    self.proc.wait(timeout=grace_s)
+                except subprocess.TimeoutExpired:
+                    self.proc.send_signal(signal.SIGTERM)
+                    try:
+                        self.proc.wait(timeout=20)
+                    except subprocess.TimeoutExpired:
+                        pass
+        finally:
+            try:
+                os.killpg(self.proc.pid, signal.SIGKILL)
+            except (ProcessLookupError, PermissionError):
+                pass
+            self.proc.wait()
+            self._log.close()
+
+
+class Snapshot:
+    """/debug/vars and /metrics, and the compile cache's entries."""
+
+    def __init__(self, http_addr: str) -> None:
+        self.t = time.monotonic()
+        self.vars = json.loads(http(http_addr, "/debug/vars"))
+        self.metrics = readers.parse_prometheus(
+            http(http_addr, "/metrics").decode()
+        )
+        self.cache = cache_entries()
+
+
+class Compare:
+    """Every number compared, printed beside its limit."""
+
+    def __init__(self) -> None:
+        self.ok = True
+
+    def __call__(self, name: str, value, limit=0) -> None:
+        good = value <= limit
+        self.ok &= bool(good)
+        print(f"compare {name}: {value} (limit {limit}) "
+              f"{'ok' if good else 'FAILED'}", flush=True)
+
+
+def server_env(args, cfg: dict, grpc_addr: str, http_addr: str) -> dict:
+    env = os.environ.copy()
+    env.update(cfg["daemon"])
+    env.update(
+        GUBER_GRPC_ADDRESS=grpc_addr, GUBER_HTTP_ADDRESS=http_addr,
+        GUBER_TPU_PLATFORM=args.platform,
+    )
+    if args.platform == "cpu":
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = (
+            env.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={cfg['chips']}"
+        ).strip()
+    return env
+
+
+def window_stats(traffic: dict, rec: dict, plan, tw0: float,
+                 tw1: float) -> dict:
+    """The end-to-end numbers and the client's own layer numbers over
+    [tw0, tw1) on the machine's monotonic clock."""
+    seconds = tw1 - tw0
+    sizes = np.diff(plan.offsets)[rec["plan_idx"]]
+    ok = rec["code"] == oracle.OK
+    out = {}
+    if traffic["loop"] == "closed":
+        sent = (rec["t_send"] >= tw0) & (rec["t_send"] < tw1)
+        done = ok & (rec["t_done"] >= tw0) & (rec["t_done"] < tw1)
+        out["decisions_per_s"] = float(sizes[done].sum()) / seconds
+        per_s = np.bincount(
+            np.clip((rec["t_done"][done] - tw0).astype(int), 0,
+                    int(seconds) - 1 if seconds >= 1 else 0),
+        )
+        out["answered_per_second"] = per_s.tolist()
+    else:
+        sent = (rec["t_due"] >= tw0) & (rec["t_due"] < tw1)
+        lat = np.where(
+            ok, rec["t_done"] - rec["t_due"], float(traffic["deadline_s"])
+        )[sent] * 1e3
+        late = (rec["t_send"] - rec["t_due"])[sent] * 1e3
+        out.update(
+            rpc_p50_ms=percentile(lat, 0.50), rpc_p95_ms=percentile(lat, 0.95),
+            rpc_p99_ms=percentile(lat, 0.99), rpc_max_ms=float(lat.max())
+            if len(lat) else float("nan"),
+            samples=len(lat), beyond_p95=beyond(lat, 0.95),
+            gen_late_p99_ms=percentile(late, 0.99),
+            cap_waited=int(rec["waited"][sent].sum()),
+            arrivals=int(sent.sum()),
+        )
+    out["attempted"] = int(sizes[sent].sum())
+    out["failed"] = int(sizes[sent & ~ok].sum())
+    out["rpcs_sent"] = int(sent.sum())
+    out["rpcs_failed_anywhere"] = int((~ok).sum())
+    return out
+
+
+def reduce_trace(trace_dir: str) -> dict:
+    env = os.environ.copy()
+    env["JAX_PLATFORMS"] = "cpu"
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "lib", "trace.py"), trace_dir],
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=300,
+    )
+    if p.returncode != 0:
+        raise Refused("trace reduction failed:\n" + p.stderr[-2000:])
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def run(args) -> dict:
+    """One run; its large files (the preload handoff, the client's record,
+    the profiler's trace) live in a temporary directory and go with it."""
+    tmp = tempfile.mkdtemp(prefix="gubbench-")
+    try:
+        return _run(args, tmp)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+
+
+def _run(args, tmp: str) -> dict:
+    from gubernator_tpu import native
+
+    native.require()
+    bm = spec.benchmark()
+    spec.check_benchmark(bm)
+    cell = spec.workload(bm, args.workload)
+    cfg_path = spec.config_path(bm, cell["config"])
+    traffic_path = spec.traffic_path(cell["traffic"])
+    cfg = spec.load_json(cfg_path)
+    traffic = spec.load_json(traffic_path)
+    out_dir = args.out or os.path.join(
+        REPO, "chiprun_out", "bench",
+        f"{args.workload}-{args.seed}-t{args.trace}"
+        + (f"-r{args.rate:g}" if args.rate else ""),
+    )
+    os.makedirs(out_dir, exist_ok=True)
+    if args.slots or args.keys:
+        # The dry run's smaller deployment, as a file the children read.
+        if args.slots:
+            cfg["daemon"]["GUBER_TPU_NUM_SLOTS"] = str(args.slots)
+        if args.keys:
+            cfg["universe"]["keys"] = args.keys
+        cfg_path = os.path.join(out_dir, "config.dryrun.json")
+        with open(cfg_path, "w") as f:
+            json.dump(cfg, f)
+    if args.rate:
+        # A sweep point: the cell's traffic at another arrival rate.
+        traffic["arrivals"]["rate_rpc_per_s"] = args.rate
+        traffic_path = os.path.join(out_dir, "traffic.sweep.json")
+        with open(traffic_path, "w") as f:
+            json.dump(traffic, f)
+    warm_in = float(traffic["warm_in_s"])
+    timers = max(cfg["background_timers_s"].values(), default=0.0)
+    if warm_in < timers:
+        raise spec.SpecError(
+            f"warm-in {warm_in}s is shorter than a background timer "
+            f"({timers}s) the configuration leaves on"
+        )
+    slots = int(cfg["daemon"]["GUBER_TPU_NUM_SLOTS"])
+    batch = int(cfg["daemon"]["GUBER_TPU_BATCH_SIZE"])
+    lanes = shapes.round_lane_bounds(
+        traffic, cfg["universe"], batch, smallest_tier=min(128, batch)
+    )
+
+    grpc_addr = f"127.0.0.1:{free_port()}"
+    http_addr = f"127.0.0.1:{free_port()}"
+    cache_at_start = len(cache_entries())
+    server = Child(
+        "server",
+        [sys.executable, os.path.join(BENCH, "serve.py"),
+         "--preload", os.path.join(tmp, "preload.npz"),
+         "--lanes", json.dumps(lanes)]
+        + (["--control", args.control] if args.control else []),
+        server_env(args, cfg, grpc_addr, http_addr),
+        os.path.join(out_dir, "serve.log"),
+    )
+    client = None
+    try:
+        rec_path = os.path.join(tmp, "client.npz")
+        client_env = os.environ.copy()
+        client_env["JAX_PLATFORMS"] = "cpu"   # it never imports JAX anyway
+        client = Child(
+            "client",
+            [sys.executable, os.path.join(BENCH, "client.py"),
+             "--addr", grpc_addr, "--config", cfg_path,
+             "--traffic", traffic_path, "--seed", str(args.seed),
+             "--warm-in", str(warm_in), "--seconds", str(args.seconds),
+             "--out", rec_path],
+            client_env, os.path.join(out_dir, "client.log"),
+        )
+        uni = universe_mod.build_universe(
+            native, cfg["universe"], args.seed, slots
+        )
+        np.savez(os.path.join(tmp, "handoff.npz"),
+                 **universe_mod.handoff(uni, args.seed, 262144))
+        os.rename(os.path.join(tmp, "handoff.npz"),
+                  os.path.join(tmp, "preload.npz"))
+        plan = schedule.build_plan(
+            traffic, cfg["universe"], args.seed, warm_in + args.seconds
+        )
+        log(f"universe: {len(uni.fp)} keys, {uni.n_resident} resident, "
+            f"{int(uni.crowded.sum())} in crowded buckets, "
+            f"{int(uni.is_global.sum())} GLOBAL; plan {len(plan)} RPCs, "
+            f"{int(plan.offsets[-1])} checks")
+
+        ready = server.next_json(READY_TIMEOUT_S)
+        dev = ready["device"]
+        log(f"daemon ready: start {ready['daemon_start_s']}s (warm-up "
+            f"{dev['warmup_s']}s), device {dev}; preload "
+            f"{ready.get('preload')}; fetch shapes {ready['fetch_shapes']}; "
+            f"lanes {lanes}; compile cache {cache_at_start} -> "
+            f"{len(cache_entries())} entries")
+        if dev["platform"] != args.platform:
+            raise Refused(f"daemon runs on {dev['platform']!r}, "
+                          f"not {args.platform!r}")
+        if dev["device_count"] < cell["chips"] or len(
+            set(dev["table_device_ids"])
+        ) != int(cfg["universe"]["shards"]):
+            raise Refused(f"cell needs {cell['chips']} chips, daemon has "
+                          f"{dev}")
+        compare = Compare()
+        pre = ready["preload"]
+        compare("preload_occupancy_differs",
+                abs(pre["occupancy"] - uni.n_resident))
+        compare("probe_differs_from_placement", pre["probe_differs"])
+
+        from lib import wirecheck
+
+        wire = wirecheck.verify_wire(grpc_addr, args.seed)
+        aux_fp = wire.resident_hashes().view(np.int64)
+        aux_seen = wire.seen_hashes().view(np.int64)
+        # Buckets the wire check touched: a row of theirs may be evicted.
+        aux_buckets = np.unique(universe_mod.global_bucket(
+            aux_seen, uni.slots, uni.ways, uni.shards
+        ))
+        log(f"wire check: {wire.checked} answers against the reference"
+            + (f"; first mismatch {wire.first}" if wire.first else ""))
+        compare("wire_check_mismatches", wire.mismatches)
+        snap_ready = Snapshot(http_addr)
+        compare("compiled_lane_missing",
+                int(snap_ready.vars["device"].get("compiled_lane") is not True))
+
+        planned = client.next_json(600)
+        if planned.get("digest") != plan.digest():
+            raise Refused("client and harness built different plans")
+        client.send()                      # go
+        mark = client.next_json(warm_in + 60)
+        assert mark["mark"] == "window_start", mark
+        setup_s = time.monotonic() - T_PROC
+        snap0 = Snapshot(http_addr)
+        trace_dir = os.path.join(tmp, "trace")
+        tsnaps = None
+        layer_end = None
+        if args.trace and args.seconds >= 2.0:
+            offset = max(1.0, args.seconds - TRACE_TAIL_S)
+            span = min(TRACE_SPAN_S, args.seconds - offset - 0.5)
+            time.sleep(max(0.0, snap0.t + offset - time.monotonic()))
+            snap_pre = Snapshot(http_addr)
+            layer_end = snap_pre.t
+            server.send({"cmd": "trace_start", "dir": trace_dir})
+            server.next_json(120)
+            ta = Snapshot(http_addr)
+            time.sleep(max(0.1, span))
+            tb = Snapshot(http_addr)
+            server.send({"cmd": "trace_stop"})
+            server.next_json(300)
+            tsnaps = (ta, tb)
+        mark = client.next_json(args.seconds + 360)
+        assert mark["mark"] == "window_end", mark
+        snap1 = Snapshot(http_addr)
+        log("window closed")
+        saved = client.next_json(traffic["deadline_s"] + 300)
+        assert saved["mark"] == "saved", saved
+        client.stop(grace_s=30)
+        client = None
+
+        with np.load(rec_path) as z:
+            rec = {k: z[k] for k in z.files}
+        extra = json.loads(str(rec.pop("extra")))
+        run_stats = {}
+        answers = oracle.flatten(plan, rec)
+        if uni.is_global.any():
+            g = uni.is_global[answers.key]
+            gsel = np.flatnonzero(uni.is_global)
+            # Keys of crowded buckets are read but not held to it: the
+            # owner's row may be evicted, as any row of such a bucket.
+            strict = ~uni.crowded[gsel] & ~np.isin(
+                uni.gbucket[gsel], aux_buckets
+            )
+            bad = (rec["gb_differs"] & strict[None, :]).sum(axis=1)
+            agreed = np.flatnonzero(bad == 0)
+            gb = {
+                "differs": int(bad[-1]) if len(bad) else int(strict.sum()),
+                "polls": len(bad), "keys_compared": int(strict.sum()),
+            }
+            if len(agreed):
+                run_stats["global_visible_ms"] = float(
+                    rec["gb_t"][agreed[0]]
+                ) * 1e3
+            log(f"GLOBAL: {int(g.sum())} acknowledged hits on {len(gsel)} "
+                f"keys ({gb['keys_compared']} outside crowded buckets); "
+                f"after the last answer the replicated read and the owner's "
+                f"row agreed with them in "
+                f"{run_stats.get('global_visible_ms', float('nan')):.0f} ms "
+                f"({gb['polls']} polls, {gb['differs']} keys differ at the "
+                f"last)")
+        server.send({"cmd": "memory"})
+        memory = server.next_json(60)
+        snap_end = Snapshot(http_addr)
+        server.send({"cmd": "quit"})
+        server.stop()
+        server = None
+    finally:
+        for ch in (client, server):
+            if ch is not None:
+                ch.stop(grace_s=0)
+
+    # -- what the window measured ----------------------------------------
+    stats = window_stats(traffic, rec, plan, extra["tw0"], extra["tw1"])
+    if "answered_per_second" in stats:
+        log(f"answered RPCs in each second of the window: "
+            f"{stats.pop('answered_per_second')}")
+    if "samples" in stats:
+        print(f"rpc latency samples: {stats['samples']} "
+              f"({stats['beyond_p95']} beyond the 95th percentile); p50 "
+              f"{stats['rpc_p50_ms']:.2f} p95 {stats['rpc_p95_ms']:.2f} p99 "
+              f"{stats['rpc_p99_ms']:.2f} max {stats['rpc_max_ms']:.2f} ms; "
+              f"generator late p99 {stats['gen_late_p99_ms']:.2f} ms, "
+              f"{stats['cap_waited']} arrivals waited for the cap")
+    log(f"window: {stats['rpcs_sent']} RPCs sent, {stats['attempted']} "
+        f"checks, {stats['failed']} failed; {stats['rpcs_failed_anywhere']}"
+        f" RPCs failed in the whole run; client {extra}")
+
+    # -- correct ----------------------------------------------------------
+    verdict = oracle.Verdict()
+    oracle.screen(answers, uni, verdict)
+    aside = oracle.unanswered_keys(plan, rec)
+    t_chk = time.monotonic()
+    oracle.replay_sample(
+        answers, rec, uni, ready["preload"]["t0_ms"], args.seed, aside,
+        aux_buckets, verdict,
+    )
+    log(f"checker: {verdict.notes} in {time.monotonic() - t_chk:.1f}s; "
+        f"{len(aside)} keys set aside for unanswered RPCs"
+        + (f"; first mismatch {verdict.first}" if verdict.first else ""))
+    for name in ("errors", "wrong_limit", "malformed_answers",
+                 "wrong_answers", "wrong_reset_time",
+                 "out_of_order_duplicates"):
+        compare(name, verdict.counts[name])
+    compare("sampled_answers_missing",
+            int(verdict.notes["sampled_answers"] == 0))
+    if uni.is_global.any():
+        compare("global_not_under", verdict.counts["global_not_under"])
+        compare("global_readback_differs", gb["differs"])
+    new = sorted(snap1.cache - snap0.cache)
+    log(f"compile-cache entries written inside the window: {len(new)} "
+        f"{[n[:40] for n in new[:8]]}; before the window, since the daemon "
+        f"was ready: {len(snap0.cache - snap_ready.cache)}")
+    compare("compiled_in_window", len(new))
+    fp0, fp1 = snap_ready.vars["fastpath"], snap_end.vars["fastpath"]
+    compare("fastpath_fallbacks_grown", fp1["fallbacks"] - fp0["fallbacks"])
+    compare("serve_mode_degraded",
+            int(fp1["effective_serve_mode"] != fp1["serve_mode"]))
+    plain = ~uni.is_global[answers.key]
+    lo_keys = np.unique(answers.key[plain])
+    hi_keys = np.unique(np.concatenate([answers.key, aside]))
+    be0, be1 = snap_ready.vars["backend"], snap_end.vars["backend"]
+    not_persisted = be1["not_persisted"] - be0["not_persisted"]
+    occ = be1["occupancy"]
+    hi = universe_mod.expected_occupancy(uni, hi_keys, aux_fp)
+    # A wire-check bucket that came and went may have evicted a row.
+    lo = (universe_mod.expected_occupancy(uni, lo_keys, aux_fp)
+          - not_persisted - (len(aux_seen) - len(aux_fp)))
+    log(f"occupancy {occ}, expected {lo}..{hi} ({not_persisted} lanes not "
+        f"persisted)")
+    compare("occupancy_beyond_expected", max(0, occ - hi))
+    compare("occupancy_below_expected", max(0, lo - occ))
+    if args.platform != "tpu":
+        compare("not_a_tpu_run", 1)
+    if args.rate:
+        compare("not_the_cells_rate", 1)
+
+    # -- metrics ----------------------------------------------------------
+    device = {
+        "platform": dev["platform"], "kind": dev["device_kind"],
+        "count": dev["device_count"],
+        "memory_peak_bytes": memory["memory_peak_bytes"],
+    }
+    result = {
+        "correct": compare.ok, "attempted": stats["attempted"],
+        "failed": stats["failed"], "metrics": {}, "device": device,
+    }
+    e2e = dict(stats, setup_s=setup_s)
+    if not args.trace:
+        for m in spec.metrics_of(bm, "end_to_end", args.workload):
+            result["metrics"][m["name"]] = {
+                "value": e2e[m["name"]], "unit": m["unit"],
+            }
+        return result
+    trace = reduce_trace(trace_dir) if tsnaps else {}
+    # Everything not read from the trace: the window up to the profiler's
+    # start.
+    if layer_end is not None:
+        stats = window_stats(traffic, rec, plan, extra["tw0"], layer_end)
+        snap1 = snap_pre
+        log(f"per-layer numbers over the first "
+            f"{layer_end - extra['tw0']:.2f}s of the window, before the "
+            f"profiler started")
+    flat = {f"client:{k}": v for k, v in stats.items()}
+    flat.update({f"run:{k}": v for k, v in run_stats.items()})
+    flat.update({f"trace:{k}": v for k, v in trace.items()
+                 if isinstance(v, (int, float))})
+    if tsnaps:
+        for path in ("backend.checks",):
+            a = readers.lookup_vars(tsnaps[0].vars, path)
+            b = readers.lookup_vars(tsnaps[1].vars, path)
+            if a is not None and b is not None:
+                flat["tracevars:" + path] = b - a
+        device["busy_s"] = trace["busy_s"]
+        device["window_s"] = trace["window_s"]
+        result["breakdown"] = {
+            "device_ops": trace["device_ops"],
+            "idle_gaps": trace["idle_gaps"],
+        }
+        log(f"trace: {trace['chips_traced']} chips, busy {trace['busy_s']:.3f}"
+            f"s of {trace['window_s']:.3f}s; programs "
+            f"{ {k: v[0] for k, v in trace['modules'].items()} }")
+    ctx = {
+        "snaps": tuple(
+            {"vars": s.vars, "metrics": s.metrics, "flat": flat}
+            for s in (snap0, snap1)
+        ),
+        "flat": flat, "trace": trace, "device": device, "ways": uni.ways,
+    }
+    for m in spec.metrics_of(bm, "per_layer", args.workload):
+        value = readers.evaluate(
+            spec.load_json(spec.layer_metric_path(m["name"])), ctx
+        )
+        if value is not None:
+            result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
+    log(f"end-to-end numbers of this traced run (not reported): "
+        f"{ {k: v for k, v in e2e.items() if isinstance(v, float)} }")
+    return result
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--platform", choices=("tpu", "cpu"), default="tpu",
+                    help="cpu: a dry run, asked for explicitly; its result "
+                    "says correct false")
+    ap.add_argument("--slots", type=int, default=0,
+                    help="table slots for the cpu dry run")
+    ap.add_argument("--keys", type=int, default=0,
+                    help="universe size for the cpu dry run")
+    ap.add_argument("--rate", type=float, default=0.0,
+                    help="an open cell at another RPC/s: a sweep point, "
+                    "whose result says correct false")
+    ap.add_argument("--control", default="",
+                    help="run the daemon's lower-precision control (f32)")
+    ap.add_argument("--out", default="")
+    args = ap.parse_args()
+    if args.platform == "tpu" and (args.slots or args.keys):
+        ap.error("--slots and --keys are for the cpu dry run")
+    try:
+        result = run(args)
+    except (Refused, spec.SpecError) as e:
+        print(f"bench/run.py: no result: {e}", file=sys.stderr)
+        return 1
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
