@@ -10,6 +10,7 @@ depends on this, cluster/cluster.go:111-146).
 from __future__ import annotations
 
 import asyncio
+import json
 import logging
 import time
 from typing import List, Optional, Sequence
@@ -87,10 +88,13 @@ class _StatsInterceptor(grpc.aio.ServerInterceptor):
 
     def __init__(self, metrics: Metrics) -> None:
         self.metrics = metrics
+        self._stages = tracing.ledger_of(metrics)
 
     async def _observed_call(self, inner, method, request, context):
         m = self.metrics
-        start = time.monotonic()
+        # wire.rpc: the ledger's row is the measurement; the duration
+        # series below is its view.
+        rpc = self._stages.begin("wire.rpc", "wire")
         failed = "false"
         try:
             out = await inner(request, context)
@@ -113,7 +117,7 @@ class _StatsInterceptor(grpc.aio.ServerInterceptor):
             failed = "true"
             raise
         finally:
-            dur = time.monotonic() - start
+            dur = rpc.end() / 1e9
             m.grpc_request_counts.labels(
                 method=method, failed=failed
             ).inc()
@@ -150,6 +154,18 @@ class _StatsInterceptor(grpc.aio.ServerInterceptor):
         )
 
 
+async def _raw_rpc(stages, serve, payload: bytes, context):
+    """A raw handler's whole: the ledger's wire.handler, and the
+    daemon's empty/occupied state clock around it."""
+    stages.rpc_enter()
+    handler = stages.stage("wire.handler", "wire")
+    try:
+        return await serve(payload, context)
+    finally:
+        handler.end()
+        stages.rpc_exit()
+
+
 class _V1Servicer:
     """Wire <-> Service adapter for the client-facing V1 service.
 
@@ -162,6 +178,11 @@ class _V1Servicer:
         self.d = daemon
 
     async def GetRateLimits(self, payload: bytes, context):
+        return await _raw_rpc(
+            self.d.metrics.stages, self._get_rate_limits, payload, context
+        )
+
+    async def _get_rate_limits(self, payload: bytes, context):
         try:
             fp = self.d.fastpath
             if fp is not None:
@@ -199,6 +220,12 @@ class _PeersServicer:
         self.d = daemon
 
     async def GetPeerRateLimits(self, payload: bytes, context):
+        return await _raw_rpc(
+            self.d.metrics.stages, self._get_peer_rate_limits, payload,
+            context,
+        )
+
+    async def _get_peer_rate_limits(self, payload: bytes, context):
         try:
             fp = self.d.fastpath
             if fp is not None:
@@ -634,6 +661,12 @@ class Daemon:
             await self.service.close()
         if self.flightrec is not None:
             await self.flightrec.close()
+        # The served path's budget over this daemon's life, for whoever
+        # reads the log after /debug/vars is gone.
+        log.info(
+            "stage ledger at close: %s",
+            json.dumps(self.metrics.stages.debug_vars(), sort_keys=True),
+        )
 
     # -- HTTP gateway (daemon.go:231-270) --------------------------------
     async def _start_http(self) -> None:
@@ -907,6 +940,10 @@ class Daemon:
             # waited_drains, bubble_ms_total, occupancy) — the knobs an
             # operator reads when tuning GUBER_PIPELINE_DEPTH.
             out["fastpath"] = fp.debug_vars()
+        # The stage ledger (runtime/tracing.py): count / ms_total /
+        # ms_max of every step of the served path, by lane
+        # (docs/observability.md).
+        out["stages"] = self.metrics.stages.debug_vars()
         # Attribution plane (runtime/tracing.py): enabled, sampler,
         # honest exporter status, spans started/exported/dropped.
         out["tracing"] = tracing.debug_vars()

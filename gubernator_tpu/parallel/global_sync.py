@@ -508,17 +508,20 @@ class GlobalEngine:
             with self.b._lock, self._lock:
                 self._seed_uniq_from_store(uniq, now_ms)
         now = np.int64(now_ms)
+        lock_wait = self.b._stages.stage("backend.lock_wait")
         with self._lock:
+            lock_wait.end()
             resps = []
-            for db in rounds:
-                t = tier_of(db.active, self.b._tiers)
-                batch = jax.device_put(
-                    pack_grid_batch(db)[:, :, :t], self.b._psharding
-                )
-                self.cache_table, r = self._ingest(
-                    self.cache_table, batch, now
-                )
-                resps.append(r)
+            with self.b._stages.stage("backend.dispatch"):
+                for db in rounds:
+                    t = tier_of(db.active, self.b._tiers)
+                    batch = jax.device_put(
+                        pack_grid_batch(db)[:, :, :t], self.b._psharding
+                    )
+                    self.cache_table, r = self._ingest(
+                        self.cache_table, batch, now
+                    )
+                    resps.append(r)
             for req, hits, src_dev in pend_items:
                 key = req.hash_key()
                 p = self.pending.get(key)
@@ -583,6 +586,12 @@ class GlobalEngine:
             pending, self.pending = self.pending, {}
         if not pending:
             return 0
+        # Low-rate, so it carries the clock anchor (time.time_ns() at
+        # its start, as an argument of the profiler event).
+        with self.b._stages.stage("global.sync_tick", "global", anchor=True):
+            return self._sync_pending(pending)
+
+    def _sync_pending(self, pending) -> int:
         now_dt = self.clock.now()
         chunks = self._build_chunks(pending, now_dt)
         now = np.int64(self.clock.millisecond_now())

@@ -35,6 +35,7 @@ from gubernator_tpu.ops.devices import device_info
 from gubernator_tpu.ops.state import SlotTable, init_table, table_to_host
 from gubernator_tpu.ops.step import DeviceBatchJ, apply_batch_packed_impl
 from gubernator_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_of_hash
+from gubernator_tpu.runtime import tracing
 from gubernator_tpu.runtime.backend import (
     PersistenceHost,
     _row_to_item,
@@ -352,6 +353,7 @@ class MeshBackend(PersistenceHost):
         track_keys: bool = False,
     ) -> None:
         self.metrics = metrics
+        self._stages = tracing.ledger_of(metrics)
         self.store = store
         self._keymap: Optional[Dict[int, str]] = (
             {} if (store is not None or track_keys) else None
@@ -444,18 +446,15 @@ class MeshBackend(PersistenceHost):
         same single-writer section as every other table mutation).
         Returns the un-synced device (responses[k, n, 9, B], per-shard
         seq words); the ring runner fetches them off the request path."""
-        import time as time_mod
-
-        t_start = time_mod.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
-            batch = jax.device_put(qs, self._qsharding)
-            self.table, resps, seq = self._ring_step(
-                self.table, batch, np.asarray(nows, dtype=np.int64), seq
-            )
-        if self.metrics is not None:
-            self.metrics.device_step_duration.observe(
-                time_mod.monotonic() - t_start
-            )
+            lock_wait.end()
+            with self._stages.stage("backend.dispatch"):
+                batch = jax.device_put(qs, self._qsharding)
+                self.table, resps, seq = self._ring_step(
+                    self.table, batch, np.asarray(nows, dtype=np.int64),
+                    seq,
+                )
         return resps, seq
 
     def ring_mega_dispatch(self, qs: np.ndarray, nows: np.ndarray, seq):
@@ -465,18 +464,15 @@ class MeshBackend(PersistenceHost):
         the lock.  Returns the un-synced device
         (responses[r, s, n, 9, B], per-shard seq words); the ring
         runner flattens the (r, s) round axes back on the host."""
-        import time as time_mod
-
-        t_start = time_mod.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
-            batch = jax.device_put(qs, self._mega_qsharding)
-            self.table, resps, seq = self._mega_ring_step(
-                self.table, batch, np.asarray(nows, dtype=np.int64), seq
-            )
-        if self.metrics is not None:
-            self.metrics.device_step_duration.observe(
-                time_mod.monotonic() - t_start
-            )
+            lock_wait.end()
+            with self._stages.stage("backend.dispatch"):
+                batch = jax.device_put(qs, self._mega_qsharding)
+                self.table, resps, seq = self._mega_ring_step(
+                    self.table, batch, np.asarray(nows, dtype=np.int64),
+                    seq,
+                )
         return resps, seq
 
     def device_info(self) -> dict:
@@ -536,17 +532,23 @@ class MeshBackend(PersistenceHost):
         round_resps = []
         captured = None
         t_start = time_mod.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
+            lock_wait.end()
             if self.store is not None:
                 self._seed_from_store(reqs, packed, now_ms)
-            for db in packed.rounds:
-                # ONE sharded put for the whole batch, ONE packed readback.
-                t = tier_of(db.active, self._tiers)
-                batch = jax.device_put(
-                    pack_grid_batch(db)[:, :, :t], self._psharding
-                )
-                self.table, resp = self._step_packed(self.table, batch, now)
-                round_resps.append(resp)
+            with self._stages.stage("backend.dispatch"):
+                for db in packed.rounds:
+                    # ONE sharded put for the whole batch, ONE packed
+                    # readback.
+                    t = tier_of(db.active, self._tiers)
+                    batch = jax.device_put(
+                        pack_grid_batch(db)[:, :, :t], self._psharding
+                    )
+                    self.table, resp = self._step_packed(
+                        self.table, batch, now
+                    )
+                    round_resps.append(resp)
             if self.store is not None:
                 # Read-back inside the lock: a concurrent batch must not
                 # mutate a key between this batch's step and on_change.
@@ -556,8 +558,6 @@ class MeshBackend(PersistenceHost):
                 wt_seq = self._wt_ticket()
         try:
             step_s = time_mod.monotonic() - t_start
-            if self.metrics is not None:
-                self.metrics.device_step_duration.observe(step_s)
             out, tally = unmarshal_responses(
                 len(reqs), packed.errors, packed.positions,
                 packed_grid_rounds_to_host(round_resps),
@@ -593,7 +593,9 @@ class MeshBackend(PersistenceHost):
         may run while the next merge dispatches."""
         from gubernator_tpu.runtime.backend import tally_from_rounds
 
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
+            lock_wait.end()
             round_resps = self._dispatch_rounds_locked(rounds)
 
         def fetch() -> List[Dict[str, np.ndarray]]:
@@ -607,22 +609,16 @@ class MeshBackend(PersistenceHost):
     def _dispatch_rounds_locked(self, rounds) -> list:
         """Dispatch grid rounds; caller holds `_lock` (see
         DeviceBackend._dispatch_rounds_locked)."""
-        import time as time_mod
-
         now = np.int64(self.clock.millisecond_now())
-        t_start = time_mod.monotonic()
         round_resps = []
-        for db in rounds:
-            t = tier_of(db.active, self._tiers)
-            batch = jax.device_put(
-                pack_grid_batch(db)[:, :, :t], self._psharding
-            )
-            self.table, resp = self._step_packed(self.table, batch, now)
-            round_resps.append(resp)
-        if self.metrics is not None:
-            self.metrics.device_step_duration.observe(
-                time_mod.monotonic() - t_start
-            )
+        with self._stages.stage("backend.dispatch"):
+            for db in rounds:
+                t = tier_of(db.active, self._tiers)
+                batch = jax.device_put(
+                    pack_grid_batch(db)[:, :, :t], self._psharding
+                )
+                self.table, resp = self._step_packed(self.table, batch, now)
+                round_resps.append(resp)
         return round_resps
 
     def warmup(self) -> None:

@@ -43,6 +43,7 @@ from gubernator_tpu.ops.step import (
     probe_batch,
     store_cached_rows,
 )
+from gubernator_tpu.runtime import tracing
 
 
 def pack_batch_q(db) -> np.ndarray:
@@ -441,6 +442,7 @@ class DeviceBackend(PersistenceHost):
         metrics=None,
     ) -> None:
         self.metrics = metrics
+        self._stages = tracing.ledger_of(metrics)
         self.cfg = cfg or DeviceConfig()
         self.clock = clock or clock_mod.default_clock()
         self._lock = threading.Lock()
@@ -534,12 +536,12 @@ class DeviceBackend(PersistenceHost):
         round_resps = []
         captured = None
         t_start = time.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
+            lock_wait.end()
             if self.store is not None:
                 self._seed_from_store(reqs, packed, now)
-            from gubernator_tpu.runtime.tracing import device_step_annotation
-
-            with device_step_annotation():
+            with self._stages.stage("backend.dispatch"):
                 for db in packed.rounds:
                     t = tier_of(db.active, self._tiers)
                     self.table, packed_resp = self._step_packed_q(
@@ -556,7 +558,6 @@ class DeviceBackend(PersistenceHost):
         try:
             step_s = time.monotonic() - t_start
             if self.metrics is not None:
-                self.metrics.device_step_duration.observe(step_s)
                 self.metrics.pool_queue_length.observe(len(reqs))
             # One packed sync per round (one transfer instead of six).
             out, tally = unmarshal_responses(
@@ -605,7 +606,9 @@ class DeviceBackend(PersistenceHost):
         next merge dispatches — the two-stage drain discipline
         (fastpath._Coalescer)."""
         t_start = time.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
+            lock_wait.end()
             round_resps = self._dispatch_rounds_locked(rounds)
 
         def fetch() -> List[Dict[str, np.ndarray]]:
@@ -629,18 +632,14 @@ class DeviceBackend(PersistenceHost):
         cascade section syncs inside the lock (its critical window spans
         the sync) while the plain path syncs after release."""
         now = np.int64(self.clock.millisecond_now())
-        t_start = time.monotonic()
         round_resps = []
-        for db in rounds:
-            t = tier_of(db.active, self._tiers)
-            self.table, packed_resp = self._step_packed_q(
-                self.table, pack_batch_q(db)[:, :t], now
-            )
-            round_resps.append(packed_resp)
-        if self.metrics is not None:
-            self.metrics.device_step_duration.observe(
-                time.monotonic() - t_start
-            )
+        with self._stages.stage("backend.dispatch"):
+            for db in rounds:
+                t = tier_of(db.active, self._tiers)
+                self.table, packed_resp = self._step_packed_q(
+                    self.table, pack_batch_q(db)[:, :t], now
+                )
+                round_resps.append(packed_resp)
         return round_resps
 
     # -- ring drain discipline (runtime/ring.py) -------------------------
@@ -679,15 +678,13 @@ class DeviceBackend(PersistenceHost):
         request path."""
         from gubernator_tpu.ops.ring import ring_step
 
-        t_start = time.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
-            self.table, resps, seq = ring_step(
-                self.table, qs, nows, seq, ways=self.cfg.ways
-            )
-        if self.metrics is not None:
-            self.metrics.device_step_duration.observe(
-                time.monotonic() - t_start
-            )
+            lock_wait.end()
+            with self._stages.stage("backend.dispatch"):
+                self.table, resps, seq = ring_step(
+                    self.table, qs, nows, seq, ways=self.cfg.ways
+                )
         return resps, seq
 
     def ring_mega_dispatch(self, qs: np.ndarray, nows: np.ndarray, seq):
@@ -699,15 +696,13 @@ class DeviceBackend(PersistenceHost):
         ring runner flattens the (r, s) round axes back on the host."""
         from gubernator_tpu.ops.ring import mega_ring_step
 
-        t_start = time.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
-            self.table, resps, seq = mega_ring_step(
-                self.table, qs, nows, seq, ways=self.cfg.ways
-            )
-        if self.metrics is not None:
-            self.metrics.device_step_duration.observe(
-                time.monotonic() - t_start
-            )
+            lock_wait.end()
+            with self._stages.stage("backend.dispatch"):
+                self.table, resps, seq = mega_ring_step(
+                    self.table, qs, nows, seq, ways=self.cfg.ways
+                )
         return resps, seq
 
     # -- persistent serve kernel (ops/pallas/serve_kernel.py) ------------
@@ -745,21 +740,19 @@ class DeviceBackend(PersistenceHost):
             persistent_serve_step_impl,
         )
 
-        t_start = time.monotonic()
+        lock_wait = self._stages.stage("backend.lock_wait")
         with self._lock:
-            if self._persistent_interpret:
-                self.table, resps, seq = persistent_serve_step_impl(
-                    self.table, qs, nows, seq, ways=self.cfg.ways,
-                    interpret=True,
-                )
-            else:
-                self.table, resps, seq = persistent_serve_step(
-                    self.table, qs, nows, seq, ways=self.cfg.ways
-                )
-        if self.metrics is not None:
-            self.metrics.device_step_duration.observe(
-                time.monotonic() - t_start
-            )
+            lock_wait.end()
+            with self._stages.stage("backend.dispatch"):
+                if self._persistent_interpret:
+                    self.table, resps, seq = persistent_serve_step_impl(
+                        self.table, qs, nows, seq, ways=self.cfg.ways,
+                        interpret=True,
+                    )
+                else:
+                    self.table, resps, seq = persistent_serve_step(
+                        self.table, qs, nows, seq, ways=self.cfg.ways
+                    )
         return resps, seq
 
     def _probe_padded(self, hashes: np.ndarray, now: int) -> np.ndarray:
@@ -1300,6 +1293,11 @@ def fetch_ravel(arrs) -> List[np.ndarray]:
     merge's N response buffers pay one fetch, not N."""
     if not arrs:
         return []
+    with tracing.stage("backend.d2h_wait"):
+        return _fetch_ravel(arrs)
+
+
+def _fetch_ravel(arrs) -> List[np.ndarray]:
     if len(arrs) == 1:
         return [np.asarray(arrs[0])]
     # Mixed dtypes would silently promote under concatenate and come back

@@ -154,21 +154,50 @@ class _Coalescer:
         self.pipeline_depth = pipeline_depth
         self._metrics = metrics
         self._lane = lane
+        # The stage ledger (runtime/tracing.py) times every step of a
+        # drain; the daemon's when there are metrics, a private one
+        # otherwise.  The stage/bubble series and the flight recorder's
+        # bubble records are views it feeds.
+        self._stages = st = (
+            getattr(metrics, "stages", None) or tracing.StageLedger()
+        )
+        st.register(lane, tracing.LANE_STAGES)
+        st.register("wire", ("wire.wake",))
+        if metrics is not None:
+            hist = metrics.fastpath_stage_duration
+            st.observe(
+                "lane.dispatch_stage",
+                hist.labels(lane=lane, stage="dispatch").observe, lane,
+            )
+            st.observe(
+                "lane.fetch_stage",
+                hist.labels(lane=lane, stage="fetch").observe, lane,
+            )
+            st.observe("lane.slot_wait", self._on_bubble, lane)
+        # Per entry: the open wait (queue_wait, then in_drain, then
+        # wake), by id(entry) — entry types are the callers' own.
+        self._waits: Dict[int, object] = {}
         # Observability: total drains / drains that rode a sparse fetch
         # slot / drains that had to wait for a fetch slot (each wait is
-        # one pipeline bubble; bubble_s accumulates the idle time).
+        # one pipeline bubble: the ledger's lane.slot_wait).
         self.drains = 0
         self.overlap_drains = 0
         self.waited_drains = 0
-        self.bubble_s = 0.0
-        # Cumulative stage wall time (the bench artifact's dispatch vs
-        # fetch budget split; mirrors fastpath_stage_duration sums).
-        self.dispatch_s = 0.0
-        self.fetch_s = 0.0
         # Merges currently in flight (dispatch or fetch stage) and the
         # peak ever observed — the pipeline-occupancy view.
         self.inflight = 0
         self.max_inflight_seen = 0
+
+    def _stage_s(self, stage: str) -> float:
+        return self._stages.totals(self._lane, stage)[1] / 1e9
+
+    # Cumulative wall time of the pipeline bubble and of the two stages
+    # from the coalescer's side of run_in_executor: the ledger's rows.
+    bubble_s = property(lambda self: self._stage_s("lane.slot_wait"))
+    dispatch_s = property(
+        lambda self: self._stage_s("lane.dispatch_stage")
+    )
+    fetch_s = property(lambda self: self._stage_s("lane.fetch_stage"))
 
     def debug_vars(self) -> dict:
         """The /debug/vars view of this lane's drain discipline."""
@@ -179,7 +208,6 @@ class _Coalescer:
             "bubble_ms_total": round(self.bubble_s * 1e3, 3),
             "dispatch_ms_total": round(self.dispatch_s * 1e3, 3),
             "fetch_ms_total": round(self.fetch_s * 1e3, 3),
-            "inflight": self.inflight,
             "max_inflight_seen": self.max_inflight_seen,
             "pipeline_depth": self.pipeline_depth,
         }
@@ -189,43 +217,59 @@ class _Coalescer:
         if m is not None:
             m.fastpath_drains.labels(lane=self._lane, kind=kind).inc()
 
-    def _note_stage(self, stage: str, dt_s: float) -> None:
-        if stage == "dispatch":
-            self.dispatch_s += dt_s
-        else:
-            self.fetch_s += dt_s
+    def _on_bubble(self, dt_s: float) -> None:
+        """The ledger's lane.slot_wait, as the bubble counter and a
+        flight-recorder record."""
         m = self._metrics
-        if m is not None:
-            m.fastpath_stage_duration.labels(
-                lane=self._lane, stage=stage
-            ).observe(dt_s)
+        m.fastpath_bubble_seconds.labels(lane=self._lane).inc(dt_s)
+        fr = getattr(m, "flightrec", None)
+        if fr is not None:
+            fr.record_bubble(self._lane, dt_s * 1e3)
 
-    def _note_bubble(self, dt_s: float) -> None:
-        self.bubble_s += dt_s
-        m = self._metrics
-        if m is not None:
-            m.fastpath_bubble_seconds.labels(lane=self._lane).inc(dt_s)
-            fr = getattr(m, "flightrec", None)
-            if fr is not None:
-                fr.record_bubble(self._lane, dt_s * 1e3)
-
-    async def do(self, entry):
-        """Submit an entry and await its result."""
+    async def do(self, entry, ingress=None):
+        """Submit an entry and await its result.  `ingress` is the
+        caller's open wire.ingress stage, ended at the enqueue."""
         if self._closed:
             raise RuntimeError("fastpath closed")
         entry.fut = asyncio.get_running_loop().create_future()
+        ctx = None
         if tracing.enabled():
             # Carry the request's trace context across the coalescer
             # seam: the merge dispatch runs on a pool thread where the
             # submitting task's contextvars are invisible.
+            ctx = tracing.current_context()
             try:
-                entry.trace_ctx = tracing.current_context()
+                entry.trace_ctx = ctx
             except AttributeError:
                 pass  # foreign entry types (tests) without the slot
         if self._task is None:
             self._task = asyncio.ensure_future(self._run())
+        if ingress is not None:
+            ingress.end()
+        self._waits[id(entry)] = self._stages.begin(
+            "lane.queue_wait", self._lane, ctx
+        )
         await self._queue.put(entry)
-        return await entry.fut
+        try:
+            return await entry.fut
+        finally:
+            # wire.wake (begun where the result was set) ends here, on
+            # the resumed handler; a cancelled wait ends what is open.
+            wake = self._waits.pop(id(entry), None)
+            if wake is not None:
+                wake.end()
+
+    def _next_wait(self, entries, stage: str, lane: str) -> None:
+        """End each entry's open wait and begin its next one."""
+        waits = self._waits
+        begin = self._stages.begin
+        for en in entries:
+            w = waits.get(id(en))
+            if w is not None:
+                w.end()
+                waits[id(en)] = begin(
+                    stage, lane, getattr(en, "trace_ctx", None)
+                )
 
     def _drain_into(self, entries: list) -> None:
         while True:
@@ -257,9 +301,11 @@ class _Coalescer:
         # accumulating and ship as ONE bigger merge.
         self.waited_drains += 1
         self._count_drain("waited")
-        t0 = time.monotonic()
-        await self._fetch.acquire()
-        self._note_bubble(time.monotonic() - t0)
+        bubble = self._stages.begin("lane.slot_wait", self._lane)
+        try:
+            await self._fetch.acquire()
+        finally:
+            bubble.end()
         self._drain_into(entries)
         return self._fetch
 
@@ -267,6 +313,7 @@ class _Coalescer:
         loop = asyncio.get_running_loop()
         while True:
             first = await self._queue.get()
+            drain = self._stages.begin("lane.drain", self._lane)
             entries = [first]
             self._drain_into(entries)
             self.drains += 1
@@ -278,13 +325,20 @@ class _Coalescer:
                 # stage is short (no response sync), so this rarely
                 # blocks; any arrivals during a wait still merge in.
                 if self._dispatch_sem.locked():
-                    await self._dispatch_sem.acquire()
+                    held = self._stages.begin(
+                        "lane.dispatch_wait", self._lane
+                    )
+                    try:
+                        await self._dispatch_sem.acquire()
+                    finally:
+                        held.end()
                     self._drain_into(entries)
                 else:
                     await self._dispatch_sem.acquire()
             except asyncio.CancelledError:
                 # Shutdown while holding dequeued entries: fail them
                 # instead of orphaning their awaiting handlers.
+                drain.end()
                 if fetch_sem is not None:
                     fetch_sem.release()
                 for en in entries:
@@ -302,7 +356,7 @@ class _Coalescer:
                     lane=self._lane
                 ).observe(self.inflight)
             task = asyncio.ensure_future(
-                self._dispatch(loop, entries, fetch_sem)
+                self._dispatch(loop, entries, fetch_sem, drain)
             )
             self._dispatches.add(task)
             task.add_done_callback(self._dispatches.discard)
@@ -352,39 +406,59 @@ class _Coalescer:
             )
         return msp, (msp.context if msp is not None else parent)
 
-    async def _dispatch(self, loop, entries, fetch_sem) -> None:
+    def _on_pool(self, fn, stage_ctx):
+        """`fn` as one stage's pool-thread pass: ends lane.handoff on its
+        first line, runs `fn` with this lane's ledger scope and the
+        merge's span context bound (contextvars do not cross
+        run_in_executor), and begins lane.resume on its last."""
+        stages, lane = self._stages, self._lane
+        handoff = stages.begin("lane.handoff", lane, stage_ctx)
+
+        def run():
+            handoff.end()
+            with tracing.scope(stages, lane), tracing.use_context(
+                stage_ctx
+            ):
+                out = fn()
+            return out, stages.begin("lane.resume", lane, stage_ctx)
+
+        return run
+
+    async def _staged(self, loop, stage: str, fn, stage_ctx):
+        """One pipeline stage from the coalescer's side."""
+        whole = self._stages.begin(stage, self._lane, stage_ctx)
+        try:
+            out, resume = await loop.run_in_executor(
+                self._pool, self._on_pool(fn, whole.context or stage_ctx)
+            )
+            resume.end()
+        finally:
+            whole.end()
+        return out
+
+    async def _dispatch(self, loop, entries, fetch_sem, drain) -> None:
         """One merge's pipeline: dispatch stage on a pool thread (holds
         the dispatch slot), then — if `process` returned a continuation —
         the fetch stage on another pool pass (holds only the fetch slot,
         so the next merge dispatches concurrently)."""
         fetch_fn = None
         msp, stage_ctx = self._merge_span(entries)
+        self._next_wait(entries, "lane.in_drain", self._lane)
         try:
-            t0 = time.monotonic()
             try:
-                res = await loop.run_in_executor(
-                    self._pool,
-                    tracing.wrap(
-                        lambda: self._process(entries),
-                        "fastpath.dispatch", stage_ctx, lane=self._lane,
-                    ),
+                res = await self._staged(
+                    loop, "lane.dispatch_stage",
+                    lambda: self._process(entries), stage_ctx,
                 )
             finally:
                 # Dispatch stage over (or failed): the next merge may
                 # dispatch while this one fetches.
                 self._dispatch_sem.release()
-                self._note_stage("dispatch", time.monotonic() - t0)
             if callable(res):
                 fetch_fn = self._once(res)
-                t0 = time.monotonic()
-                outs = await loop.run_in_executor(
-                    self._pool,
-                    tracing.wrap(
-                        fetch_fn,
-                        "fastpath.fetch", stage_ctx, lane=self._lane,
-                    ),
+                outs = await self._staged(
+                    loop, "lane.fetch_stage", fetch_fn, stage_ctx
                 )
-                self._note_stage("fetch", time.monotonic() - t0)
             else:
                 outs = res  # single-phase process
         except BaseException as e:  # CancelledError is a BaseException
@@ -407,16 +481,19 @@ class _Coalescer:
                 RuntimeError("fastpath closed")
                 if isinstance(e, asyncio.CancelledError) else e
             )
+            self._next_wait(entries, "wire.wake", "wire")
             for en in entries:
                 if not en.fut.done():
                     en.fut.set_exception(err)
             if isinstance(e, asyncio.CancelledError):
                 raise
         else:
+            self._next_wait(entries, "wire.wake", "wire")
             for en, out in zip(entries, outs):
                 if not en.fut.done():
                     en.fut.set_result(out)
         finally:
+            drain.end()
             self.inflight -= 1
             fetch_sem.release()
             if msp is not None:
@@ -489,6 +566,8 @@ class FastPath:
         serve_mode = normalize_serve_mode(serve_mode)
         self.s = service
         metrics = service.metrics
+        self._stages = tracing.ledger_of(metrics)
+        self._stages.register("wire", tracing.WIRE_STAGES)
         # Drain discipline (docs/ring.md): classic = strict depth-1,
         # pipelined = depth-k fetch overlap, ring = the device-resident
         # serving loop (runtime/ring.py) with NO blocking fetch on the
@@ -686,6 +765,20 @@ class FastPath:
         the compiled lane; None = caller must take the object path.
         Raises ApiError on an oversized batch (same contract as the
         object path)."""
+        # wire.ingress: from here to the first coalescer enqueue
+        # (_Coalescer.do ends it); the `finally` ends it for an RPC that
+        # never enqueues (fallback, empty, oversized).
+        ingress = self._stages.begin(
+            "wire.ingress", "wire", tracing.current_context()
+        )
+        try:
+            return await self._check_raw(payload, peer_rpc, ingress)
+        finally:
+            ingress.end()
+
+    async def _check_raw(
+        self, payload: bytes, peer_rpc: bool, ingress
+    ) -> Optional[bytes]:
         from gubernator_tpu.runtime.service import ApiError
 
         if not self._eligible():
@@ -788,10 +881,10 @@ class FastPath:
         try:
             if routed:
                 return await self._serve_routed(
-                    payload, cols, n, is_global, sk
+                    payload, cols, n, is_global, sk, ingress
                 )
             return await self._serve(
-                payload, cols, n, is_global, sk, peer_rpc
+                payload, cols, n, is_global, sk, peer_rpc, ingress
             )
         finally:
             if not peer_rpc:
@@ -850,7 +943,8 @@ class FastPath:
         return out
 
     async def _serve_cols(
-        self, payload, cols, is_greg, ge, gd, use_cached=None
+        self, payload, cols, is_greg, ge, gd, use_cached=None,
+        ingress=None,
     ) -> Tuple[np.ndarray, ...]:
         """Submit columns to the coalescing batcher; returns the seven
         response arrays (status, limit, remaining, reset_time, stored,
@@ -868,7 +962,7 @@ class FastPath:
                 use_cached if use_cached is not None
                 else np.zeros(cols.n, dtype=bool)
             ),
-        ))
+        ), ingress)
 
     def _decode_req(self, payload, cols, i: int):
         """Decode ONE request's spliced wire frame into a RateLimitReq."""
@@ -1079,7 +1173,8 @@ class FastPath:
             mgr.queue_hits(dc_replace(req, hits=total))
 
     async def _serve_split(
-        self, payload, cols, is_greg, ge, gd, use_cached, sk, eng=None
+        self, payload, cols, is_greg, ge, gd, use_cached, sk, eng=None,
+        ingress=None,
     ) -> Tuple[np.ndarray, ...]:
         """Serve a column set, splitting sketch-named lanes to the CMS
         step and engine lanes (node-owned GLOBAL on a mesh service) to
@@ -1090,7 +1185,8 @@ class FastPath:
         no_eng = eng is None or not eng.any()
         if no_sk and no_eng:
             return await self._serve_cols(
-                payload, cols, is_greg, ge, gd, use_cached=use_cached
+                payload, cols, is_greg, ge, gd, use_cached=use_cached,
+                ingress=ingress,
             )
         n = cols.n
         sk_m = sk if sk is not None else np.zeros(n, dtype=bool)
@@ -1115,7 +1211,7 @@ class FastPath:
             hh = cols.hits[sk_idx]
             ll = cols.limit[sk_idx]
             st, rem, rst = await self._sketch_lane.do(
-                _SketchEntry(kh, hh, ll)
+                _SketchEntry(kh, hh, ll), ingress
             )
             status[sk_idx] = st
             out_lim[sk_idx] = ll
@@ -1124,7 +1220,8 @@ class FastPath:
 
         async def run_engine() -> None:
             st, lm, rem, rst = await self._engine_lane.do(
-                _EngineEntry(payload, cols, eng_idx, is_greg, ge, gd)
+                _EngineEntry(payload, cols, eng_idx, is_greg, ge, gd),
+                ingress,
             )
             status[eng_idx] = st
             out_lim[eng_idx] = lm
@@ -1143,6 +1240,7 @@ class FastPath:
                 use_cached=(
                     use_cached[ex_idx] if use_cached is not None else None
                 ),
+                ingress=ingress,
             )
             status[ex_idx] = st
             out_lim[ex_idx] = lm
@@ -1163,6 +1261,13 @@ class FastPath:
         return status, out_lim, remaining, reset, stored, stored_st, cap_ok
 
     def _engine_process(self, entries):
+        pack = tracing.stage("lane.pack")  # ended at the hand-over
+        try:
+            return self._engine_process_packed(entries, pack)
+        finally:
+            pack.end()
+
+    def _engine_process_packed(self, entries, pack):
         """Merged columnar serving for node-owned GLOBAL lanes on the
         mesh GlobalEngine — one coalescer drain = ONE engine lock hold
         and dispatch chain (runs on the engine lane's worker thread).
@@ -1251,11 +1356,15 @@ class FastPath:
                 pend.append(
                     (req, int(hits_sum[j]), int(sh[off + j]))
                 )
+        pack.end()
         resps, want_sync = engine.serve_packed(rounds, pend)
 
         def fetch_body() -> List[Tuple[np.ndarray, ...]]:
             host = packed_grid_rounds_to_host(resps)
+            with tracing.stage("lane.unpack"):
+                return unpack(host)
 
+        def unpack(host) -> List[Tuple[np.ndarray, ...]]:
             mt = len(h_all)
             st_u = np.zeros(mt, dtype=np.int64)
             lm_u = np.zeros(mt, dtype=np.int64)
@@ -1324,7 +1433,8 @@ class FastPath:
         return b"".join(metas), off
 
     async def _serve(
-        self, payload, cols, n: int, is_global, sk, peer_rpc=False
+        self, payload, cols, n: int, is_global, sk, peer_rpc=False,
+        ingress=None,
     ) -> bytes:
         """Single-node / peer-RPC path: everything is local (and owned,
         so GLOBAL lanes serve authoritatively and queue broadcast
@@ -1342,8 +1452,11 @@ class FastPath:
                 eng = None
         status, limit, remaining, reset, stored, stored_st, cap_ok = (
             await self._serve_split(
-                payload, cols, is_greg, ge, gd, None, sk, eng
+                payload, cols, is_greg, ge, gd, None, sk, eng, ingress
             )
+        )
+        egress = self._stages.begin(
+            "wire.egress", "wire", tracing.current_context()
         )
         if eng is not None:
             # Metric parity: the object path's routing counts engine
@@ -1370,10 +1483,12 @@ class FastPath:
         np.cumsum([len(e) for e in errs], out=err_off[1:])
         meta_blob, meta_off = self._sketch_meta(n, sk)
         self.served += n
-        return native.serialize_resps(
+        out = native.serialize_resps(
             status, limit, remaining, reset, b"".join(errs), err_off,
             meta_blob, meta_off,
         )
+        egress.end()
+        return out
 
     def _can_route(self) -> bool:
         """Columnar routing serves every selectable ring hash: xx rings
@@ -1388,7 +1503,7 @@ class FastPath:
         return self.s.local_picker.hash_fn in (xx_64, fnv1_64, fnv1a_64)
 
     async def _serve_routed(
-        self, payload: bytes, cols, n: int, is_global, sk
+        self, payload: bytes, cols, n: int, is_global, sk, ingress=None
     ) -> bytes:
         """Multi-node client path: vectorized consistent-hash routing with
         zero-copy forwards.
@@ -1483,7 +1598,7 @@ class FastPath:
                     sub_eng = None
             st, lm, rem, rst, sto, sst, cok = await self._serve_split(
                 payload, sub, is_greg, ge, gd, glob_cached[idx], sub_sk,
-                sub_eng,
+                sub_eng, ingress,
             )
             status[idx] = st
             out_lim[idx] = lm
@@ -1641,6 +1756,9 @@ class FastPath:
                 idx = remote_idx[owner[remote_idx] == pi]
                 tasks.append(forward(peers[int(pi)], idx))
         await asyncio.gather(*tasks)
+        egress = self._stages.begin(
+            "wire.egress", "wire", tracing.current_context()
+        )
 
         if is_global.any():
             # Deferred GLOBAL replication (gubernator.go:429-432, 617):
@@ -1677,10 +1795,12 @@ class FastPath:
         meta_off = np.zeros(n + 1, dtype=np.int64)
         np.cumsum([len(m) for m in metas], out=meta_off[1:])
         self.served += n
-        return native.serialize_resps(
+        out = native.serialize_resps(
             status, out_lim, remaining, reset,
             b"".join(errs), err_off, b"".join(metas), meta_off,
         )
+        egress.end()
+        return out
 
     # -- persistence SPI on the lane -------------------------------------
     def _persist_decode(self, entries) -> Dict[int, list]:
@@ -1886,13 +2006,16 @@ class FastPath:
         pre-chunk estimate — the CMS's documented batch-granularity
         approximation).  Dispatch stage: concat + device dispatch under
         the sketch lock; the returned closure is the fetch stage."""
-        if len(entries) == 1:
-            kh, hh, ll = entries[0].kh, entries[0].hits, entries[0].limits
-        else:
-            kh = np.concatenate([e.kh for e in entries])
-            hh = np.concatenate([e.hits for e in entries])
-            ll = np.concatenate([e.limits for e in entries])
-        fetch_cols = self.s.sketch_backend.check_cols_begin(kh, hh, ll)
+        with tracing.stage("lane.pack"):
+            if len(entries) == 1:
+                e = entries[0]
+                kh, hh, ll = e.kh, e.hits, e.limits
+            else:
+                kh = np.concatenate([e.kh for e in entries])
+                hh = np.concatenate([e.hits for e in entries])
+                ll = np.concatenate([e.limits for e in entries])
+        with tracing.stage("backend.dispatch"):
+            fetch_cols = self.s.sketch_backend.check_cols_begin(kh, hh, ll)
         wait_cols = None
         ring = self._ring_live()
         if ring is not None:
@@ -1907,11 +2030,12 @@ class FastPath:
                 wait_cols = None
 
         def fetch() -> List[Tuple[np.ndarray, ...]]:
-            if wait_cols is not None:
-                st, rem, rst = wait_cols()
-            else:
-                self.blocking_fetches["sketch"] += 1
-                st, rem, rst = fetch_cols()
+            with tracing.stage("backend.d2h_wait"):
+                if wait_cols is not None:
+                    st, rem, rst = wait_cols()
+                else:
+                    self.blocking_fetches["sketch"] += 1
+                    st, rem, rst = fetch_cols()
             outs: List[Tuple[np.ndarray, ...]] = []
             off = 0
             for e in entries:
@@ -1924,6 +2048,15 @@ class FastPath:
         return fetch
 
     def _process(self, entries: Sequence["_Entry"]):
+        # lane.pack: from here to where the step is handed to the device
+        # (_process_packed ends it there; the `finally` only on a raise).
+        pack = tracing.stage("lane.pack")
+        try:
+            return self._process_packed(entries, pack)
+        finally:
+            pack.end()
+
+    def _process_packed(self, entries: Sequence["_Entry"], pack):
         """Pack -> step for a coalesced entry list (runs on a fast-lane
         pool thread; everything here is numpy/C++/device).  This is the
         DISPATCH stage of the pipelined drain: it returns a zero-arg
@@ -2085,6 +2218,7 @@ class FastPath:
                 # runner, off the request path entirely.
                 from gubernator_tpu.runtime.ring import RingClosedError
 
+                pack.end()
                 try:
                     wait_rounds = ring.submit_q(ring_qs)
                 except RingClosedError:
@@ -2102,15 +2236,20 @@ class FastPath:
                     )
                 else:
                     def fetch_ring() -> List[Tuple[np.ndarray, ...]]:
-                        host_box.append(wait_rounds())
-                        gather(host_box[0])
-                        return finish()
+                        # The fetch itself is the ring runner's; the
+                        # request path waits for its publication.
+                        with tracing.stage("backend.d2h_wait"):
+                            host_box.append(wait_rounds())
+                        with tracing.stage("lane.unpack"):
+                            gather(host_box[0])
+                            return finish()
 
                     return fetch_ring
             # Plain merge: dispatch under the backend lock; the response
             # sync rides the coalescer's FETCH stage, so the next
             # maximal merge dispatches while this one's response syncs
             # (depth bounded by GUBER_PIPELINE_DEPTH).
+            pack.end()
             fetch_host = backend.step_rounds_begin(
                 rounds, add_tally=False
             )
@@ -2118,8 +2257,9 @@ class FastPath:
             def fetch_plain() -> List[Tuple[np.ndarray, ...]]:
                 host_box.append(fetch_host())
                 self.blocking_fetches["mach"] += 1
-                gather(host_box[0])
-                return finish()
+                with tracing.stage("lane.unpack"):
+                    gather(host_box[0])
+                    return finish()
 
             return fetch_plain
 
@@ -2153,17 +2293,22 @@ class FastPath:
             # dispatch order against ring steps.  One nonlocal set: the
             # captures fetch_locked_merge needs.
             nonlocal cap_token, wt_seq, cap_fps, int_hosts
+            lock_wait = tracing.stage("backend.lock_wait")
             with backend._lock:
+                lock_wait.end()
                 resps = backend._dispatch_rounds_locked(rounds)
                 if plan is not None:
                     host_box.append(to_host(resps))
+                    cascade = tracing.stage("lane.cascade")
                     gather(host_box[0])
                     wb = _run_cascade(
                         plan, h, hits, lim, dur, algo, burst,
                         status, out_lim, remaining, reset, stored, cachedv,
                         foundv, stored_st,
                     )
-                    if wb is not None:
+                    if wb is None:
+                        cascade.end()
+                    else:
                         (wb_h, wb_hits, wb_lim, wb_dur, wb_algo,
                          wb_burst) = wb
                         wb_sh = (
@@ -2188,6 +2333,7 @@ class FastPath:
                             else np.zeros(m, dtype=np.int32),
                             wn, n_shards, B,
                         )
+                        cascade.end()
                         backend._dispatch_rounds_locked(wb_rounds)
                 if do_store:
                     from gubernator_tpu.runtime.backend import (
@@ -2247,6 +2393,7 @@ class FastPath:
                 wait_locked = ring.submit_host(locked_merge)
             except RingClosedError:
                 ring = None
+        pack.end()
         if ring is None:
             self.blocking_fetches["mach"] += 1
             locked_merge()
@@ -2288,7 +2435,8 @@ class FastPath:
                     # cond.wait) — hence the rf sync sits INSIDE this
                     # try as well.
                     backend._deliver_write_through(captured, wt_seq)
-            return finish()
+            with tracing.stage("lane.unpack"):
+                return finish()
 
         return fetch_locked_merge
 

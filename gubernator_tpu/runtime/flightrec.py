@@ -31,8 +31,8 @@ off the hot path:
 
 On breach it can also start a time-boxed `jax.profiler` trace
 (`profile_secs` > 0) so the host-side records line up with XLA traces —
-runtime/tracing.py's device_step_annotation marks the device steps
-inside them.
+runtime/tracing.py's stage ledger annotates every step of the served
+path inside them (`gub.*`).
 
 Discipline (gubguard-enforced): nothing here touches a device array
 (host-sync), dump writes and profiler start/stop run in an executor

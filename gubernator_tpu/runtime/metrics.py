@@ -30,6 +30,8 @@ from prometheus_client import (
     generate_latest,
 )
 
+from gubernator_tpu.runtime import tracing
+
 # Shared latency buckets (seconds), 50µs .. 2.5s.  2e-3 is a bucket
 # boundary on purpose: the north-star SLO is p99 < 2ms, so breach
 # accounting from a scrape never interpolates across the target.
@@ -595,9 +597,19 @@ class Metrics:
         # -- TPU-specific -------------------------------------------------
         self.device_step_duration = Histogram(
             "gubernator_tpu_device_step_duration",
-            "Wall time of one jitted device batch step in seconds.",
+            "Host time to enqueue one merge's rounds on the device in "
+            "seconds (pack, shard_args, one program launch per round) "
+            "- NOT the device's step time; the stage ledger's "
+            "backend.dispatch, as a histogram.",
             buckets=LATENCY_BUCKETS,
             registry=r,
+        )
+        # The stage ledger (runtime/tracing.py): the served path's
+        # budget, rendered at /debug/vars `stages`.  The series above
+        # and the fastpath stage/bubble series are views it feeds.
+        self.stages = tracing.StageLedger()
+        self.stages.observe(
+            "backend.dispatch", self.device_step_duration.observe
         )
         self.device_occupancy = Gauge(
             "gubernator_tpu_slot_occupancy",

@@ -80,7 +80,6 @@ from gubernator_tpu.ops.ring import (
     ring_tier_of,
 )
 from gubernator_tpu.runtime import tracing
-from gubernator_tpu.runtime.tracing import device_step_annotation
 
 
 class _Job:
@@ -544,13 +543,12 @@ class RingBackend:
             if m is not None:
                 m.fastpath_ring_loop_lag.set(self.loop_lag_s)
         self._last_dispatch = t0
-        # The profiler annotation makes ring rounds visible in
-        # jax.profiler captures exactly like classic dispatches
-        # (runtime/backend.py wraps its step loop the same way), so the
-        # ring loop-lag gauges line up with the device timeline.
+        # The backend's dispatch is a ledger stage (gub.backend.dispatch,
+        # lane "ring"), so ring rounds are visible in jax.profiler
+        # captures like every other dispatch and the ring loop-lag
+        # gauges line up with the device timeline.
         with tracing.use_context(isp.context if isp is not None else None):
-            with device_step_annotation("gubernator_ring_step"):
-                resps, mega = self._dispatch_raw(qs, nows)
+            resps, mega = self._dispatch_raw(qs, nows)
         seq_out = self._seq_dev
         self.iterations += 1
         if mega or (self.persistent and tier > self.slots):
@@ -654,6 +652,12 @@ class RingBackend:
             self._cond.notify_all()
 
     def _run(self) -> None:
+        # Everything the runner does to the backend (dispatch, lock
+        # wait, the device->host fetch) is charged to the lane "ring".
+        with tracing.scope(tracing.ledger_of(self._metrics), "ring"):
+            self._run_loop()
+
+    def _run_loop(self) -> None:
         inflight = None  # dispatched, responses not yet fetched
         while True:
             with self._cond:

@@ -34,10 +34,17 @@ checks one module global and returns before allocating anything — the
 hot path creates zero spans and zero contexts until `init_tracing()`
 arms the plane (tests/test_tracing.py pins this).
 
-`device_step_annotation` additionally marks device steps with
-`jax.profiler.TraceAnnotation` so host spans line up with XLA traces in
-profiler dumps (the classic dispatch path and the ring runner both use
-it).
+The **stage ledger** (second half of this module) is the always-on
+budget of the served path: every step of a decision — handler, parse,
+coalescer waits, pack, lock wait, dispatch, device->host wait, unpack,
+serialize — is timed where it happens by ONE primitive (`stage` /
+`begin`) that feeds three sinks at once: the ledger's integer cells
+(`/debug/vars` `stages`), a `jax.profiler.TraceAnnotation` named
+`gub.<stage>` (so the section is an event on the profiler's clock, on
+the thread that ended it), and — only when the span plane above is
+armed and the parent sampled — a `Span`.  It has no switch: the cells
+cost a clock read and three integer adds, the annotation one atomic
+check while no profiler session is active (docs/tracing.md).
 """
 from __future__ import annotations
 
@@ -45,10 +52,13 @@ import contextlib
 import contextvars
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, Iterator, List, Optional, Sequence
+from typing import (
+    Callable, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple,
+)
 
 log = logging.getLogger("gubernator_tpu.tracing")
 
@@ -477,18 +487,6 @@ def wrap(fn, name: str, parent: Optional[SpanContext], **attrs):
     return _traced
 
 
-@contextlib.contextmanager
-def device_step_annotation(name: str = "gubernator_device_step"):
-    """XLA-profiler-visible annotation around a device step, nested in
-    the current trace context when tracing is armed — host spans and
-    profiler TraceMe marks then line up in a capture."""
-    import jax
-
-    with span(name, require_parent=True):
-        with jax.profiler.TraceAnnotation(name):
-            yield
-
-
 # -- lifecycle / introspection -------------------------------------------
 
 def _resolve_sampler(sampler: Optional[str], sampler_arg) -> tuple:
@@ -661,3 +659,364 @@ def recent_spans_for(
         if sp.context.trace_id_hex() in want
     ]
     return out[-limit:]
+
+
+# -- the stage ledger ------------------------------------------------------
+#
+# The vocabulary: `<layer>.<stage>`, each name placed once, where the
+# work happens.  The profiler event is `gub.<layer>.<stage>`; the ledger
+# key is (lane, stage) and /debug/vars renders `stages.<lane>.<stage>`
+# with the layer dropped (the stage halves are unique).  `lane` is
+# "wire" for the per-RPC stages, the coalescer lane's own name
+# (mach / sketch / engine) for everything a drain does, "ring" on the
+# ring runner, "direct" for the object path and library callers, and
+# the layer itself for the process-wide rows (global, xla).
+STAGES: Dict[str, str] = {
+    # per RPC, event loop
+    "wire.rpc": "stats interceptor entry -> return, every unary method "
+                "(feeds gubernator_grpc_request_duration)",
+    "wire.handler": "raw GetRateLimits / GetPeerRateLimits handler "
+                    "entry -> return",
+    "wire.ingress": "handler entry -> the first coalescer enqueue "
+                    "(eligibility, parse_reqs, validation, _prep_greg)",
+    "wire.wake": "fut.set_result -> the handler coroutine resumes",
+    "wire.egress": "resume -> return (captures, GLOBAL queueing, error "
+                   "strings, serialize_resps)",
+    "wire.empty": "state: no raw RPC between handler entry and return",
+    "wire.occupied": "state: at least one raw RPC in the daemon",
+    # per entry, event loop
+    "lane.queue_wait": "do() put -> the entry's merge is handed to the "
+                       "pool",
+    "lane.in_drain": "merge handed to the pool -> fut.set_result",
+    # per drain
+    "lane.drain": "first entry dequeued -> results set (the whole the "
+                  "per-drain stages divide)",
+    "lane.slot_wait": "waiting for a fetch slot (the pipeline bubble)",
+    "lane.dispatch_wait": "waiting for the dispatch slot",
+    "lane.handoff": "run_in_executor submit -> first line on the pool "
+                    "thread, dispatch and fetch stage each",
+    "lane.resume": "last line on the pool thread -> the coalescer task "
+                   "resumes on the loop, dispatch and fetch stage each",
+    "lane.pack": "process() up to the device dispatch (concatenate, "
+                 "cascade plan, assign_rounds, build rounds)",
+    "lane.cascade": "inside a cascade merge's locked window: gather, "
+                    "host replay, write-back rounds",
+    "lane.unpack": "gather + finish (tallies, capture mask, per-entry "
+                   "split) after the answer is on the host",
+    "lane.dispatch_stage": "the coalescer's side of the dispatch stage "
+                           "(feeds fastpath_stage_duration)",
+    "lane.fetch_stage": "the coalescer's side of the fetch stage",
+    "backend.lock_wait": "blocked on backend._lock / engine._lock",
+    "backend.dispatch": "pack_batch_q / shard_args and the enqueue of "
+                        "each round's program (feeds "
+                        "gubernator_tpu_device_step_duration)",
+    "backend.d2h_wait": "fetch_ravel: blocked until the answer is on "
+                        "the host",
+    # per tick / process
+    "global.sync_tick": "one GLOBAL psum sync: staging, dispatch, "
+                        "write-through read-back",
+    "xla.compile": "backend compiles seen by jax.monitoring (count, ms)",
+}
+
+# What a daemon's raw handlers and a coalescer lane time: the rows they
+# create at start-up (StageLedger.register).
+WIRE_STAGES = tuple(
+    s for s in STAGES if s.startswith("wire.") and s != "wire.wake"
+)
+LANE_STAGES = tuple(
+    s for s in STAGES if s.startswith(("lane.", "backend."))
+)
+
+_TRACE_ME = None  # jax.profiler.TraceAnnotation, resolved on first use
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _Cell:
+    """One (lane, stage) row: plain integers, written under the owning
+    ledger's leaf lock (two fetch stages of one lane may end the same
+    stage on two pool threads)."""
+
+    __slots__ = ("lane", "stage", "trace_name", "count", "ns_total",
+                 "ns_max", "observe")
+
+    def __init__(self, lane: str, stage: str, observe=None) -> None:
+        if stage not in STAGES:
+            raise KeyError(f"stage {stage!r} is not in tracing.STAGES")
+        self.lane = lane
+        self.stage = stage
+        self.trace_name = "gub." + stage
+        self.count = 0
+        self.ns_total = 0
+        self.ns_max = 0
+        self.observe = observe
+
+    def add(self, ns: int) -> None:
+        self.count += 1
+        self.ns_total += ns
+        if ns > self.ns_max:
+            self.ns_max = ns
+
+
+_COMPILES = _Cell("xla", "xla.compile")  # process-wide, lock-free reads
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        _COMPILES.add(int(duration_secs * 1e9))
+
+
+def _trace_me():
+    """The profiler's TraceMe class, imported the first time a stage is
+    timed (a process that times none — the load generator — never
+    imports JAX); the compile listener is installed with it."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        import jax.monitoring
+        from jax.profiler import TraceAnnotation
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration
+        )
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME
+
+
+class _Open:
+    """One open stage.  `with ledger.stage(...)` for a section on one
+    thread; `tok = ledger.begin(...)` ... `tok.end()` for a wait that
+    spans an await or a thread hand-off (the TraceMe records
+    (name, start, end) on the thread that ends it).  `end` is
+    idempotent and returns the duration in ns."""
+
+    __slots__ = ("_ledger", "_cell", "_t0", "_tm", "_span", "_token")
+
+    def __init__(self, ledger, cell, parent, bind, anchor) -> None:
+        self._ledger = ledger
+        self._cell = cell
+        self._span = None
+        self._token = None
+        st = _state
+        if st is not None:
+            if bind:
+                parent = _current.get()
+            if parent is not None:
+                self._span, ctx = _begin(
+                    st, cell.trace_name, parent, [], {"lane": cell.lane}
+                )
+                if bind:
+                    self._token = _current.set(ctx)
+        if anchor:
+            # The clock anchor: the program's epoch clock at the
+            # event's start, to be laid against the profiler's stamp.
+            tm = (_TRACE_ME or _trace_me())(
+                cell.trace_name, t_ns=time.time_ns()
+            )
+        else:
+            tm = (_TRACE_ME or _trace_me())(cell.trace_name)
+        tm.__enter__()
+        self._tm = tm
+        self._t0 = time.perf_counter_ns()
+
+    @property
+    def context(self) -> Optional[SpanContext]:
+        """The stage's span context when it has a span (armed plane,
+        sampled parent), for parenting what runs inside it elsewhere."""
+        return self._span.context if self._span is not None else None
+
+    def end(self, error: Optional[str] = None) -> int:
+        cell = self._cell
+        if cell is None:
+            return 0
+        dt = time.perf_counter_ns() - self._t0
+        self._cell = None
+        self._tm.__exit__(None, None, None)
+        if self._token is not None:
+            _current.reset(self._token)
+        if self._span is not None:
+            self._span.end(error)
+        self._ledger._add(cell, dt)
+        return dt
+
+    def __enter__(self) -> "_Open":
+        return self
+
+    def __exit__(self, _et, ev, _tb) -> None:
+        self.end(repr(ev) if ev is not None else None)
+
+
+class StageLedger:
+    """count / ns_total / ns_max per (lane, stage), always on.  One per
+    daemon (`Metrics.stages`); a component built without metrics gets
+    its own.  `_lock` is a leaf: held for three integer adds, never
+    across another lock (tools/gubguard/lockorder.py)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._cells: Dict[Tuple[str, str], _Cell] = {}
+        self._observers: Dict[Tuple[Optional[str], str], Callable] = {}
+        # wire.empty / wire.occupied: raw RPCs between handler entry and
+        # return.  Event-loop thread only; the clock starts with the
+        # first RPC.
+        self._rpcs = 0
+        self._since: Optional[int] = None
+        self._empty_tm = None
+        if "jax" in sys.modules:
+            # A daemon's ledger: count its start-up compiles too.  (A
+            # process that has not imported JAX is not made to.)
+            _trace_me()
+
+    # -- registration ------------------------------------------------------
+    def observe(self, stage: str, fn: Callable[[float], None],
+                lane: Optional[str] = None) -> None:
+        """Feed `fn(seconds)` from every end of `stage` (on `lane`, or
+        on every lane): how a Prometheus series stays a view of the
+        ledger instead of a second measurement."""
+        if stage not in STAGES:
+            raise KeyError(f"stage {stage!r} is not in tracing.STAGES")
+        self._observers[(lane, stage)] = fn
+        with self._lock:
+            for (ln, st), cell in self._cells.items():
+                if st == stage and lane in (None, ln):
+                    cell.observe = fn
+
+    def register(self, lane: str, stages: Iterable[str]) -> None:
+        """Create rows ahead of their first use, so that /debug/vars
+        shows a lane's whole vocabulary (at zero) from the start."""
+        for stage in stages:
+            self.cell(lane, stage)
+
+    def cell(self, lane: str, stage: str) -> _Cell:
+        c = self._cells.get((lane, stage))
+        if c is None:
+            fn = self._observers.get((lane, stage)) or self._observers.get(
+                (None, stage)
+            )
+            with self._lock:
+                c = self._cells.setdefault(
+                    (lane, stage), _Cell(lane, stage, fn)
+                )
+        return c
+
+    def _add(self, cell: _Cell, ns: int) -> None:
+        with self._lock:
+            cell.add(ns)
+        if cell.observe is not None:
+            cell.observe(ns / 1e9)
+
+    # -- the primitive -----------------------------------------------------
+    def stage(self, stage: str, lane: Optional[str] = None,
+              anchor: bool = False) -> _Open:
+        """A synchronous section on one thread (`with`).  Armed span
+        plane: a child span of the current context, bound for the
+        section so nested spans and flight-recorder records attribute
+        to it."""
+        if lane is None:
+            lane = _scope.get()[1]
+        return _Open(self, self.cell(lane, stage), None, True, anchor)
+
+    def begin(self, stage: str, lane: Optional[str] = None,
+              parent: Optional[SpanContext] = None) -> _Open:
+        """The begin of a begin-end pair; `parent` is the explicitly
+        carried span context (nothing is bound: the wait crosses tasks
+        or threads)."""
+        if lane is None:
+            lane = _scope.get()[1]
+        return _Open(self, self.cell(lane, stage), parent, False, False)
+
+    # -- the wire.empty / wire.occupied state clock --------------------------
+    def rpc_enter(self) -> None:
+        if self._rpcs == 0:
+            now = time.perf_counter_ns()
+            if self._since is not None:
+                self._add(self.cell("wire", "wire.empty"), now - self._since)
+                self._empty_tm.__exit__(None, None, None)
+            self._since = now
+        self._rpcs += 1
+
+    def rpc_exit(self) -> None:
+        self._rpcs -= 1
+        if self._rpcs == 0:
+            now = time.perf_counter_ns()
+            self._add(self.cell("wire", "wire.occupied"), now - self._since)
+            self._since = now
+            self._empty_tm = (_TRACE_ME or _trace_me())("gub.wire.empty")
+            self._empty_tm.__enter__()
+
+    # -- rendering -----------------------------------------------------------
+    def totals(self, lane: str, stage: str) -> Tuple[int, int, int]:
+        """(count, ns_total, ns_max) of one row; zeros if never timed."""
+        c = self._cells.get((lane, stage))
+        if c is None:
+            return 0, 0, 0
+        with self._lock:
+            return c.count, c.ns_total, c.ns_max
+
+    def debug_vars(self) -> Dict:
+        """The /debug/vars `stages` block:
+        stages.<lane>.<stage>.{count, ms_total, ms_max}.  The open
+        empty/occupied interval is counted up to now."""
+        with self._lock:
+            rows = [
+                (c.lane, c.stage, c.count, c.ns_total, c.ns_max)
+                for c in self._cells.values()
+            ]
+        rows.append((
+            _COMPILES.lane, _COMPILES.stage, _COMPILES.count,
+            _COMPILES.ns_total, _COMPILES.ns_max,
+        ))
+        since, rpcs = self._since, self._rpcs
+        if since is not None:
+            state = "wire.occupied" if rpcs else "wire.empty"
+            open_ns = max(0, time.perf_counter_ns() - since)
+            for i, (lane, stage, n, tot, mx) in enumerate(rows):
+                if lane == "wire" and stage == state:
+                    rows[i] = (lane, stage, n, tot + open_ns, max(mx, open_ns))
+                    break
+            else:
+                rows.append(("wire", state, 0, open_ns, open_ns))
+        out: Dict[str, Dict] = {}
+        for lane, stage, n, tot, mx in rows:
+            out.setdefault(lane, {})[stage.split(".", 1)[1]] = {
+                "count": n,
+                "ms_total": round(tot / 1e6, 6),
+                "ms_max": round(mx / 1e6, 6),
+            }
+        return out
+
+
+# The ambient (ledger, lane): what a stage placed in code that serves
+# many callers (backend dispatch, fetch_ravel) is charged to.  A
+# coalescer binds its own on the pool thread for each stage it runs.
+PROCESS_LEDGER = StageLedger()
+_scope: contextvars.ContextVar[Tuple[StageLedger, str]] = (
+    contextvars.ContextVar(
+        "gubernator_tpu_stage_scope", default=(PROCESS_LEDGER, "direct")
+    )
+)
+
+
+def ledger_of(metrics) -> StageLedger:
+    """A daemon's ledger (`Metrics.stages`); the process's for a
+    component built without metrics."""
+    return getattr(metrics, "stages", None) or PROCESS_LEDGER
+
+
+@contextlib.contextmanager
+def scope(ledger: StageLedger, lane: str) -> Iterator[None]:
+    token = _scope.set((ledger, lane))
+    try:
+        yield
+    finally:
+        _scope.reset(token)
+
+
+def stage(stage_name: str, lane: Optional[str] = None,
+          anchor: bool = False) -> _Open:
+    """`StageLedger.stage` on the ambient ledger."""
+    return _scope.get()[0].stage(stage_name, lane, anchor)
+
+
+def begin(stage_name: str, lane: Optional[str] = None,
+          parent: Optional[SpanContext] = None) -> _Open:
+    """`StageLedger.begin` on the ambient ledger."""
+    return _scope.get()[0].begin(stage_name, lane, parent)

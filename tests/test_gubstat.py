@@ -422,7 +422,15 @@ def test_debug_vars_schema_golden(stats_cluster):
         "inflight_checks",
         "global", "multi_region_sends", "peers", "circuits", "degraded",
         "hotkeys", "leases", "reshard", "tenants", "table", "fastpath",
-        "tracing", "flightrec",
+        "stages", "tracing", "flightrec",
+    }
+    # The stage ledger's block (docs/observability.md): lane -> stage ->
+    # {count, ms_total, ms_max}, a lane's rows there from the start.
+    assert {"wire", "mach", "xla"} <= set(v["stages"])
+    assert {"handler", "ingress", "egress", "wake", "empty",
+            "occupied"} <= set(v["stages"]["wire"])
+    assert set(v["stages"]["mach"]["pack"]) == {
+        "count", "ms_total", "ms_max",
     }
     # Where the daemon runs, as JAX reports it (tests are held to the
     # CPU; chip_smoke.py requires "tpu" here).

@@ -1,0 +1,487 @@
+"""The stage ledger (runtime/tracing.py): one primitive, three sinks.
+
+Pinned here:
+
+  * the ledger's arithmetic — count / total / max per (lane, stage),
+    per-entry against per-drain weighting, the empty/occupied state
+    clock including its open interval;
+  * the two identities on an in-process daemon serving real RPCs: per
+    RPC, handler = ingress + queue_wait + in_drain + wake + egress; per
+    drain, dequeue -> results set = the pipeline's stages (residuals
+    generous: this is a CPU under test load);
+  * the series and /debug/vars numbers that used to be measured a second
+    time are views of the ledger's rows;
+  * the span plane: disarmed, a stage allocates no Span and no context;
+    armed, `fastpath.merge` parents the stage spans;
+  * the profiler: a short CPU trace holds `gub.*` events for a wait held
+    across an await and for a stage on a pool thread, and the clock
+    anchor's argument can be read back.
+"""
+from __future__ import annotations
+
+import asyncio
+import glob
+import os
+import time
+import types
+from concurrent.futures import ThreadPoolExecutor
+
+import pytest
+
+from gubernator_tpu.proto import gubernator_pb2 as pb
+from gubernator_tpu.runtime import tracing
+from gubernator_tpu.runtime.fastpath import _Coalescer
+from gubernator_tpu.runtime.metrics import Metrics
+from gubernator_tpu.testing.tracing import memory_tracing
+
+
+class _Clock:
+    """perf_counter_ns under the test's control."""
+
+    def __init__(self) -> None:
+        self.now = 1_000
+
+    def perf_counter_ns(self) -> int:
+        return self.now
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = _Clock()
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        perf_counter_ns=c.perf_counter_ns, time_ns=time.time_ns,
+    ))
+    return c
+
+
+class _E:
+    __slots__ = ("fut", "trace_ctx")
+
+    def __init__(self) -> None:
+        self.fut = None
+        self.trace_ctx = None
+
+
+# -- arithmetic -------------------------------------------------------------
+
+def test_count_total_max(clock):
+    ledger = tracing.StageLedger()
+    for dt in (5, 40, 12):
+        with ledger.stage("lane.pack", "mach"):
+            clock.now += dt
+    assert ledger.totals("mach", "lane.pack") == (3, 57, 40)
+    assert ledger.totals("engine", "lane.pack") == (0, 0, 0)
+    row = ledger.debug_vars()["mach"]["pack"]
+    assert row == {"count": 3, "ms_total": 57e-6, "ms_max": 40e-6}
+
+
+def test_begin_end_pair_is_idempotent_and_returns_ns(clock):
+    ledger = tracing.StageLedger()
+    wait = ledger.begin("lane.handoff", "mach")
+    clock.now += 250
+    assert wait.end() == 250
+    clock.now += 999
+    assert wait.end() == 0          # already ended: nothing added
+    assert ledger.totals("mach", "lane.handoff") == (1, 250, 250)
+
+
+def test_unknown_stage_is_refused():
+    ledger = tracing.StageLedger()
+    with pytest.raises(KeyError):
+        ledger.stage("lane.no_such_stage", "mach")
+    with pytest.raises(KeyError):
+        ledger.observe("lane.no_such_stage", lambda s: None)
+
+
+def test_ambient_scope_names_the_lane(clock):
+    """Code that serves many callers (backend dispatch, fetch_ravel)
+    names no lane: the stage lands on whoever bound the scope."""
+    ledger = tracing.StageLedger()
+    with tracing.scope(ledger, "engine"):
+        with tracing.stage("backend.d2h_wait"):
+            clock.now += 7
+    assert ledger.totals("engine", "backend.d2h_wait") == (1, 7, 7)
+    before = tracing.PROCESS_LEDGER.totals("direct", "backend.d2h_wait")[0]
+    with tracing.stage("backend.d2h_wait"):
+        pass
+    after = tracing.PROCESS_LEDGER.totals("direct", "backend.d2h_wait")[0]
+    assert after == before + 1
+
+
+def test_observer_is_fed_by_the_ledger(clock):
+    """A Prometheus series stays a view: the observer gets the very
+    duration the row got, in seconds, for the lane it was bound to."""
+    ledger = tracing.StageLedger()
+    seen = []
+    ledger.observe("lane.slot_wait", seen.append, "mach")
+    for lane in ("mach", "engine"):
+        w = ledger.begin("lane.slot_wait", lane)
+        clock.now += 2_000_000
+        w.end()
+    assert seen == [0.002]
+    every = []
+    ledger.observe("backend.dispatch", every.append)
+    for lane in ("mach", "ring"):
+        with ledger.stage("backend.dispatch", lane):
+            clock.now += 1_000
+    assert every == [1e-6, 1e-6]
+
+
+def test_empty_occupied_state_clock(clock):
+    ledger = tracing.StageLedger()
+    assert "wire" not in ledger.debug_vars()    # starts with the first RPC
+    clock.now = 100
+    ledger.rpc_enter()
+    clock.now = 150
+    ledger.rpc_enter()                           # two inside
+    clock.now = 260
+    ledger.rpc_exit()
+    clock.now = 300
+    ledger.rpc_exit()                            # occupied 100..300
+    clock.now = 450
+    wire = ledger.debug_vars()["wire"]
+    assert wire["occupied"] == {
+        "count": 1, "ms_total": 200e-6, "ms_max": 200e-6,
+    }
+    # The open interval is counted up to "now", and is not yet a count.
+    assert wire["empty"] == {
+        "count": 0, "ms_total": 150e-6, "ms_max": 150e-6,
+    }
+    clock.now = 500
+    ledger.rpc_enter()                           # empty 300..500 closes
+    clock.now = 530
+    wire = ledger.debug_vars()["wire"]
+    assert wire["empty"] == {
+        "count": 1, "ms_total": 200e-6, "ms_max": 200e-6,
+    }
+    assert wire["occupied"]["ms_total"] == 230e-6   # 200 + the open 30
+    assert wire["occupied"]["count"] == 1
+    ledger.rpc_exit()
+
+
+def test_per_entry_against_per_drain_weighting():
+    """Three entries ride ONE drain: the waits are counted per entry,
+    the pipeline's stages per drain."""
+
+    def process(entries):
+        with tracing.stage("lane.pack"):
+            time.sleep(0.002)
+
+        def fetch():
+            with tracing.stage("lane.unpack"):
+                return [i for i, _ in enumerate(entries)]
+
+        return fetch
+
+    async def scenario():
+        pool = ThreadPoolExecutor(2)
+        co = _Coalescer(pool, process, lane="mach")
+        outs = await asyncio.gather(*(co.do(_E()) for _ in range(3)))
+        await co.close()
+        pool.shutdown(wait=True)
+        return co, outs
+
+    co, outs = asyncio.run(scenario())
+    assert sorted(outs) == [0, 1, 2]
+    assert co.drains == 1
+    count = lambda lane, st: co._stages.totals(lane, st)[0]  # noqa: E731
+    for st in ("lane.queue_wait", "lane.in_drain"):
+        assert count("mach", st) == 3, st
+    assert count("wire", "wire.wake") == 3
+    for st in ("lane.drain", "lane.pack", "lane.unpack",
+               "lane.dispatch_stage", "lane.fetch_stage"):
+        assert count("mach", st) == 1, st
+    # Both stages crossed to the pool and back.
+    assert count("mach", "lane.handoff") == 2
+    assert count("mach", "lane.resume") == 2
+    # Every entry was in the drain at least as long as its pack.
+    assert co._stages.totals("mach", "lane.in_drain")[1] >= 3 * 2_000_000
+    # The legacy accumulators are the ledger's rows.
+    assert co.dispatch_s == co._stages.totals(
+        "mach", "lane.dispatch_stage")[1] / 1e9
+    assert co.dispatch_s >= 0.002 and co.fetch_s > 0.0
+    assert co.debug_vars()["dispatch_ms_total"] == round(
+        co.dispatch_s * 1e3, 3)
+    assert not co._waits                         # nothing left open
+
+
+def test_bubble_is_the_slot_wait_row():
+    """Depth 1, a slow fetch, two drains: the second waits for the
+    fetch slot.  One measurement feeds the row, the bubble counter and
+    the flight recorder."""
+
+    class _FR:
+        def __init__(self):
+            self.bubbles = []
+
+        def record_bubble(self, lane, wait_ms):
+            self.bubbles.append((lane, wait_ms))
+
+    metrics = Metrics()
+    metrics.flightrec = _FR()
+
+    def process(entries):
+        def fetch():
+            time.sleep(0.05)
+            return [0 for _ in entries]
+
+        return fetch
+
+    async def scenario():
+        pool = ThreadPoolExecutor(3)
+        co = _Coalescer(pool, process, pipeline_depth=1, metrics=metrics,
+                        lane="mach")
+        first = asyncio.ensure_future(co.do(_E()))
+        await asyncio.sleep(0.01)        # its drain is in flight
+        await co.do(_E())
+        await first
+        await co.close()
+        pool.shutdown(wait=True)
+        return co
+
+    co = asyncio.run(scenario())
+    n, ns, _mx = metrics.stages.totals("mach", "lane.slot_wait")
+    assert n == co.waited_drains == 1
+    assert ns > 10_000_000
+    assert co.bubble_s == ns / 1e9
+    (lane, wait_ms), = metrics.flightrec.bubbles
+    assert lane == "mach" and wait_ms == pytest.approx(ns / 1e6)
+    text = metrics.render().decode()
+    line = next(
+        ln for ln in text.splitlines()
+        if ln.startswith("gubernator_fastpath_bubble_seconds_total{")
+    )
+    assert float(line.split()[-1]) == pytest.approx(ns / 1e9)
+
+
+# -- the identities, on a daemon --------------------------------------------
+
+def _payload(i: int, n: int = 6) -> bytes:
+    return pb.GetRateLimitsReq(requests=[
+        pb.RateLimitReq(
+            name="stages", unique_key=f"k{(i * n + j) % 97}", hits=1,
+            limit=1_000_000, duration=60_000,
+        )
+        for j in range(n)
+    ]).SerializeToString()
+
+
+def _ms(stages: dict, lane: str, *names: str) -> float:
+    return sum(stages[lane][n]["ms_total"] for n in names
+               if n in stages.get(lane, {}))
+
+
+def test_identities_and_views_on_a_daemon():
+    """300 RPCs, 8 in flight, over real gRPC through the raw handler and
+    check_raw: both identities close, and the numbers that used to be
+    measured separately equal the ledger's rows."""
+    import grpc.aio
+
+    from gubernator_tpu.testing.cluster import Cluster
+
+    assert not tracing.enabled()
+    cluster = Cluster.start(1)
+    try:
+        d = cluster.daemon_at(0)
+
+        async def drive():
+            ch = grpc.aio.insecure_channel(d.grpc_address)
+            rpc = ch.unary_unary("/pb.gubernator.V1/GetRateLimits")
+            sem = asyncio.Semaphore(8)
+
+            async def one(i):
+                async with sem:
+                    raw = await rpc(_payload(i))
+                    resp = pb.GetRateLimitsResp.FromString(raw)
+                    assert len(resp.responses) == 6
+                    assert not resp.responses[0].error
+
+            try:
+                await asyncio.gather(*(one(i) for i in range(300)))
+            finally:
+                await ch.close()
+
+        cluster.run(drive(), timeout=120)
+        stages = d.metrics.stages.debug_vars()
+        lanes = d.fastpath.debug_vars()["lanes"]
+        metrics_text = d.metrics.render().decode()
+        assert d.fastpath.fallbacks == 0
+    finally:
+        cluster.stop()
+
+    wire, mach = stages["wire"], stages["mach"]
+    assert wire["handler"]["count"] == 300
+    assert wire["ingress"]["count"] == 300
+    assert wire["egress"]["count"] == 300
+    assert wire["rpc"]["count"] >= 300
+    # One entry per RPC here: the waits are per entry.
+    for row in (mach["queue_wait"], mach["in_drain"], wire["wake"]):
+        assert row["count"] == 300
+    drains = lanes["mach"]["drains"]
+    assert mach["drain"]["count"] == drains > 0
+
+    # Identity 1, per RPC.  The parts are disjoint and inside the
+    # handler, so they can only fall short of it: by the hops between
+    # them, which a loaded CPU stretches.
+    handler = wire["handler"]["ms_total"]
+    parts = (_ms(stages, "wire", "ingress", "wake", "egress")
+             + _ms(stages, "mach", "queue_wait", "in_drain"))
+    assert 0.80 * handler <= parts <= 1.001 * handler, (parts, handler)
+
+    # Identity 2, per drain.
+    drain = mach["drain"]["ms_total"]
+    parts = _ms(stages, "mach", "slot_wait", "dispatch_wait", "handoff",
+                "pack", "lock_wait", "dispatch", "cascade", "d2h_wait",
+                "unpack", "resume")
+    assert 0.75 * drain <= parts <= 1.001 * drain, (parts, drain)
+    assert mach["handoff"]["count"] == mach["resume"]["count"] == 2 * drains
+    assert mach["pack"]["count"] == mach["unpack"]["count"] == drains
+
+    # The state clock: the window divides into empty and occupied, and
+    # the handlers overlapped (8 in flight), so occupied < sum(handler).
+    occupied = wire["occupied"]["ms_total"]
+    assert 0 < occupied <= handler
+    assert wire["occupied"]["count"] >= 1
+
+    # Views fed by the ledger, not second measurements.
+    assert lanes["mach"]["dispatch_ms_total"] == round(
+        mach["dispatch_stage"]["ms_total"], 3)
+    assert lanes["mach"]["fetch_ms_total"] == round(
+        mach["fetch_stage"]["ms_total"], 3)
+    assert lanes["mach"]["bubble_ms_total"] == round(
+        mach.get("slot_wait", {"ms_total": 0.0})["ms_total"], 3)
+
+    def series(name, needle=""):
+        return sum(
+            float(ln.split()[-1]) for ln in metrics_text.splitlines()
+            if ln.startswith(name) and needle in ln
+        )
+
+    dispatches = sum(
+        lane["dispatch"]["count"] for lane in stages.values()
+        if "dispatch" in lane
+    )
+    assert series("gubernator_tpu_device_step_duration_count") == dispatches
+    assert series(
+        "gubernator_grpc_request_duration_count", "V1/GetRateLimits"
+    ) == 300
+    assert series(
+        "gubernator_grpc_request_duration_sum", "V1/GetRateLimits"
+    ) <= wire["rpc"]["ms_total"] / 1e3 + 1e-9
+    assert series(
+        "gubernator_fastpath_stage_duration_count", 'stage="dispatch"'
+    ) == drains
+    # Compiles are visible to an operator (the daemon compiled to start).
+    assert stages["xla"]["compile"]["count"] > 0
+    assert stages["xla"]["compile"]["ms_total"] > 0
+    # Disarmed: all of the above allocated no span.
+    assert tracing.debug_vars() == {"enabled": False}
+
+
+# -- the span plane ---------------------------------------------------------
+
+def test_merge_parents_the_stage_spans_when_armed():
+    def process(entries):
+        with tracing.stage("lane.pack"):
+            pass
+        return [0 for _ in entries]
+
+    async def scenario():
+        pool = ThreadPoolExecutor(1)
+        co = _Coalescer(pool, process, lane="mach")
+        with tracing.span("req") as root:
+            await co.do(_E())
+        await co.close()
+        pool.shutdown(wait=True)
+        return root
+
+    with memory_tracing() as exp:
+        root = asyncio.run(scenario())
+        by_name = {s.name: s for s in exp.spans()}
+    merge = by_name["fastpath.merge"]
+    assert merge.parent_id == root.context.span_id
+    stage = by_name["gub.lane.dispatch_stage"]
+    assert stage.parent_id == merge.context.span_id
+    assert stage.attributes == {"lane": "mach"}
+    for name in ("gub.lane.handoff", "gub.lane.pack", "gub.lane.resume"):
+        assert by_name[name].parent_id == stage.context.span_id, name
+    for name in ("gub.lane.queue_wait", "gub.lane.in_drain",
+                 "gub.wire.wake"):
+        assert by_name[name].parent_id == root.context.span_id, name
+    # Span records keep the epoch clock (docs/tracing.md: the offset to
+    # the profiler's clock is stated, not assumed).
+    assert abs(stage.start_ns - time.time_ns()) < 60e9
+
+
+def test_disarmed_stage_allocates_no_span_and_no_context():
+    assert not tracing.enabled()
+    ledger = tracing.StageLedger()
+    with ledger.stage("lane.pack", "mach") as st:
+        assert st.context is None
+        assert tracing.current_context() is None
+    w = ledger.begin("lane.queue_wait", "mach", parent=None)
+    assert w.context is None
+    w.end()
+    with memory_tracing() as exp:
+        assert len(exp) == 0
+
+
+# -- the profiler -----------------------------------------------------------
+
+def test_profiler_trace_holds_gub_events(tmp_path):
+    """A wait held across an await, a stage on a pool thread and the
+    clock anchor, read back from the .xplane.pb of a short CPU trace."""
+    import jax
+    from jax.profiler import ProfileData
+
+    def process(entries):
+        with tracing.stage("lane.pack"):
+            time.sleep(0.002)
+        return [0 for _ in entries]
+
+    async def scenario(ledger):
+        pool = ThreadPoolExecutor(1)
+        co = _Coalescer(pool, process, lane="mach")
+        held = ledger.begin("lane.slot_wait", "mach")
+        await asyncio.sleep(0.005)               # held across an await
+        held.end()
+        await co.do(_E())
+        await co.close()
+        pool.shutdown(wait=True)
+
+    ledger = tracing.StageLedger()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t_prog = time.time_ns()
+        with ledger.stage("global.sync_tick", "global", anchor=True):
+            time.sleep(0.001)
+        asyncio.run(scenario(ledger))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    events = {}
+    threads = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):    # one line per thread
+            for ev in line.events:
+                if ev.name.startswith("gub."):
+                    events.setdefault(ev.name, ev)
+                    threads[ev.name] = i
+    for name in ("gub.lane.slot_wait", "gub.lane.queue_wait",
+                 "gub.lane.in_drain", "gub.wire.wake", "gub.lane.handoff",
+                 "gub.lane.pack", "gub.lane.resume",
+                 "gub.global.sync_tick"):
+        assert name in events, (name, sorted(events))
+    assert events["gub.lane.slot_wait"].duration_ns >= 4_000_000
+    assert events["gub.lane.pack"].duration_ns >= 1_500_000
+    # The pool-thread stage is on another thread's line than the waits.
+    assert threads["gub.lane.pack"] != threads["gub.lane.slot_wait"]
+    # The anchor: the program's time.time_ns() at the event's start is an
+    # argument of the event, readable beside the profiler's own stamp.
+    anchor = events["gub.global.sync_tick"]
+    stats = dict(anchor.stats)
+    assert t_prog <= int(stats["t_ns"]) <= t_prog + 1_000_000_000
+    assert anchor.start_ns > 0

@@ -166,10 +166,20 @@ def test_span_tree_per_serve_mode(mode):
         assert merge.parent_id == root.context.span_id
         assert merge.attributes["lane"] == "mach"
         assert merge.attributes["entries"] == 1
-        dispatch = by_name["fastpath.dispatch"]
-        fetch = by_name["fastpath.fetch"]
+        # The ledger's stages are the stage spans (runtime/tracing.py):
+        # the two pipeline stages under the merge, and what runs on the
+        # pool thread under its stage.
+        dispatch = by_name["gub.lane.dispatch_stage"]
+        fetch = by_name["gub.lane.fetch_stage"]
         assert dispatch.parent_id == merge.context.span_id
         assert fetch.parent_id == merge.context.span_id
+        assert dispatch.attributes["lane"] == "mach"
+        assert by_name["gub.lane.pack"].parent_id == dispatch.context.span_id
+        assert by_name["gub.lane.unpack"].parent_id == fetch.context.span_id
+        # The per-entry waits hang off the request itself.
+        for wait in ("gub.lane.queue_wait", "gub.lane.in_drain",
+                     "gub.wire.wake"):
+            assert by_name[wait].parent_id == root.context.span_id
         if mode == "ring":
             it = by_name["ring.iteration"]
             # The monotone sequence word pins the exact device round
@@ -179,10 +189,12 @@ def test_span_tree_per_serve_mode(mode):
             pubs = [s for s in spans if s.name == "ring.fetch_publish"]
             assert pubs and pubs[0].parent_id == it.context.span_id
             assert pubs[0].attributes["ring.seq"] == it.attributes["ring.seq"]
-            # Satellite: ring iterations carry the profiler annotation
-            # span nested under the iteration.
-            step = by_name["gubernator_ring_step"]
+            # Satellite: the ring round's dispatch — a ledger stage,
+            # hence also a profiler annotation — nests under the
+            # iteration, on the runner's own lane.
+            step = by_name["gub.backend.dispatch"]
             assert step.parent_id == it.context.span_id
+            assert step.attributes["lane"] == "ring"
         else:
             assert "ring.iteration" not in by_name
         # The fetch stage's flight-recorder record is trace-tagged
@@ -319,9 +331,18 @@ def test_disabled_serving_creates_zero_spans():
         assert len(exp) == 0
 
 
-def test_device_step_annotation_noop_when_disabled():
-    with tracing.device_step_annotation("x"):
+def test_stage_allocates_no_span_when_disabled():
+    """The pin, for the ledger's primitive: disarmed, a stage binds no
+    context and creates no Span — it only counts."""
+    ledger = tracing.StageLedger()
+    with ledger.stage("backend.dispatch", "mach") as st:
         assert tracing.current_context() is None
+        assert st.context is None
+    wait = ledger.begin("lane.handoff", "mach")
+    assert wait.context is None
+    wait.end()
+    assert ledger.totals("mach", "backend.dispatch")[0] == 1
+    assert tracing.debug_vars() == {"enabled": False}
 
 
 # -- cross-daemon propagation (in-process cluster) ------------------------

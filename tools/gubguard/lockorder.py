@@ -54,6 +54,7 @@ CLASS_LOCK_MAP = {
     ("FlightRecorder", "_lock"): "flightrec._lock",
     ("_TraceState", "_lock"): "tracing._lock",
     ("MemorySpanExporter", "_lock"): "tracing.exporter._lock",
+    ("StageLedger", "_lock"): "tracing.stages._lock",
     ("SketchBackend", "_compile_lock"): "sketch._compile_lock",
     ("SketchBackend", "_spill_lock"): "sketch._spill_lock",
     ("Clock", "_lock"): "clock._lock",
@@ -176,6 +177,11 @@ RANK = {
     # another lock while holding its own (exports run outside it).
     "tracing._lock": 70,
     "tracing.exporter._lock": 71,
+    # tracing.stages._lock (runtime/tracing.py StageLedger rows) is a
+    # leaf like the two above: a stage may end under ANY layer's lock
+    # (backend.dispatch ends inside backend._lock), the critical section
+    # is three integer adds, and observers run after release.
+    "tracing.stages._lock": 72,
     # clock._lock (core/clock.py frozen-time guard) ranks dead last:
     # now_ns() may be called under ANY other lock (timestamps are
     # taken everywhere), the critical section is two loads, and the
