@@ -47,26 +47,25 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gubernator_tpu.ops.state import SlotTable
+from gubernator_tpu.ops.state import SlotTable, init_table
 from gubernator_tpu.ops.step import apply_batch_packed_q_impl
 
 _I0 = np.int32(0)  # i32 index-map constant (cms_kernel's x64 rule)
 
-_N_COLS = len(SlotTable._fields)  # 12 table leaves
 
-
-def _serve_kernel(ways, *refs):
+def _serve_kernel(ways, treedef, *refs):
     """One grid step = one packed round against the kernel-resident
-    table.  Refs: (qs, nows, seq, 12 table cols in) then
-    (12 table cols out, resps, seq out).  The table accumulates in the
+    table.  Refs: (qs, nows, seq, table leaves in) then
+    (table leaves out, resps, seq out).  The table accumulates in the
     OUT refs across sequential grid steps (the cms_kernel pattern), so
     round b observes rounds [0, b)'s effects exactly like the ring
     scan's carry."""
+    n = treedef.num_leaves  # physical leaves: an int64 field is two
     q_ref, now_ref, seq_ref = refs[0:3]
-    tin = refs[3:3 + _N_COLS]
-    tout = refs[3 + _N_COLS:3 + 2 * _N_COLS]
-    resp_ref = refs[3 + 2 * _N_COLS]
-    seq_out_ref = refs[4 + 2 * _N_COLS]
+    tin = refs[3:3 + n]
+    tout = refs[3 + n:3 + 2 * n]
+    resp_ref = refs[3 + 2 * n]
+    seq_out_ref = refs[4 + 2 * n]
     b = pl.program_id(0)
     k = pl.num_programs(0)
 
@@ -79,11 +78,11 @@ def _serve_kernel(ways, *refs):
         # verify against the mirror) is unchanged from ring_step.
         seq_out_ref[...] = seq_ref[...] + jnp.int64(k)
 
-    table = SlotTable(*[o_ref[...] for o_ref in tout])
+    table = treedef.unflatten([o_ref[...] for o_ref in tout])
     tbl2, resp = apply_batch_packed_q_impl(
         table, q_ref[0], now_ref[0], ways=ways
     )
-    for o_ref, col in zip(tout, tbl2):
+    for o_ref, col in zip(tout, jax.tree_util.tree_leaves(tbl2)):
         o_ref[...] = col
     resp_ref[0, :, :] = resp
 
@@ -101,26 +100,27 @@ def persistent_serve_step_impl(
     ring_step contract, differentially pinned bit-exact."""
     k, rows, B = qs.shape
     S = table.key.shape[0]
+    leaves, treedef = jax.tree_util.tree_flatten(table)
+    n = len(leaves)
     seq1 = jnp.asarray(seq, dtype=jnp.int64).reshape(1)
 
     def col_spec():
         return pl.BlockSpec((S,), lambda b: (_I0,))
 
     outs = pl.pallas_call(
-        functools.partial(_serve_kernel, ways),
+        functools.partial(_serve_kernel, ways, treedef),
         grid=(k,),
         in_specs=[
             pl.BlockSpec((1, rows, B), lambda b: (b, _I0, _I0)),
             pl.BlockSpec((1,), lambda b: (b,)),
             pl.BlockSpec((1,), lambda b: (_I0,)),
-        ] + [col_spec() for _ in range(_N_COLS)],
-        out_specs=[col_spec() for _ in range(_N_COLS)] + [
+        ] + [col_spec() for _ in range(n)],
+        out_specs=[col_spec() for _ in range(n)] + [
             pl.BlockSpec((1, 9, B), lambda b: (b, _I0, _I0)),
             pl.BlockSpec((1,), lambda b: (_I0,)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((S,), jnp.asarray(a).dtype)
-            for a in table
+            jax.ShapeDtypeStruct((S,), a.dtype) for a in leaves
         ] + [
             jax.ShapeDtypeStruct((k, 9, B), jnp.int64),
             jax.ShapeDtypeStruct((1,), jnp.int64),
@@ -135,12 +135,12 @@ def persistent_serve_step_impl(
         jnp.asarray(qs, dtype=jnp.int64),
         jnp.asarray(nows, dtype=jnp.int64),
         seq1,
-        *table,
+        *leaves,
     )
     return (
-        SlotTable(*outs[:_N_COLS]),
-        outs[_N_COLS],
-        outs[_N_COLS + 1][0],
+        treedef.unflatten(outs[:n]),
+        outs[n],
+        outs[n + 1][0],
     )
 
 
@@ -158,14 +158,7 @@ def probe_compile(
     kernel on the default backend, abstractly (no device memory is
     allocated).  Returns (ok, reason) — the honest capability signal
     GUBER_SERVE_MODE=persistent gates on."""
-    i64 = jax.ShapeDtypeStruct((num_slots,), jnp.int64)
-    i32 = jax.ShapeDtypeStruct((num_slots,), jnp.int32)
-    f64 = jax.ShapeDtypeStruct((num_slots,), jnp.float64)
-    table = SlotTable(
-        key=i64, algo=i32, kind=i32, limit=i64, duration=i64,
-        remaining=i64, remaining_f=f64, t0=i64, status=i32, burst=i64,
-        expire_at=i64, touched=i64,
-    )
+    table = jax.eval_shape(lambda: init_table(num_slots))
     try:
         persistent_serve_step.lower(
             table,

@@ -10,11 +10,15 @@ bucket-local pseudo-LRU rather than the reference's global list LRU
 makes early eviction safe: it can only briefly over-admit.
 
 All arrays share leading dimension S = num_slots so the table shards cleanly
-along axis 0 over a device mesh (see gubernator_tpu.parallel.mesh).
+along axis 0 over a device mesh (see gubernator_tpu.parallel.mesh) — every
+PHYSICAL column does: an int64 field is two uint32[S] columns on the device
+("Physical layout" below); the schema, the kernels' arithmetic and the host
+format stay int64.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+import sys
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,62 +29,186 @@ KIND_BUCKET = 0
 KIND_CACHED_RESP = 1  # non-owner's cached GLOBAL broadcast (gubernator.go:464-479)
 
 
+# --------------------------------------------------------------------------
+# Physical layout.  The LOGICAL schema is twelve fields, eight of them
+# int64; the PHYSICAL table holds every int64 field as a low and a high
+# uint32[S] column.  A TPU has no 64-bit registers: XLA rewrites each
+# s64 array into a pair of 32-bit arrays, which is free inside a program
+# but not at its boundary, where a 64-bit parameter is split
+# (X64SplitLow/High) and a 64-bit result rebuilt (X64Combine) over ALL S
+# rows every time the program runs — 27 table-length custom calls a step
+# at nine 64-bit columns, to touch a few hundred rows.  Stored as halves,
+# the table crosses the boundary as it is, and 64-bit values exist only
+# on the gathered [B] / [B, ways] lanes.
+#
+# `gather64` / `scatter64` (and the host twins `_halves_to_host` /
+# `_host_to_halves`) are the only code that knows the layout; `Col64`
+# gives them the array spelling (`col[idx]`, `col.at[tgt].set(v)`) so a
+# kernel reads the same for a split column as for an int32 one.
+# `remaining_f` stays ONE float64[S] column: the TPU's X64 pass cannot
+# rewrite a 64-bit bitcast-convert, and any float split loses bits on
+# the CPU (docs/architecture.md).
+# --------------------------------------------------------------------------
+
+if sys.byteorder != "little":  # the host twins view int64 as (lo, hi) words
+    raise ImportError("ops/state.py splits int64 by view: little-endian only")
+
+
+def gather64(col: "Col64", idx) -> jax.Array:
+    """Read both halves at `idx` and combine them: int64[idx.shape]."""
+    lo = col.lo[idx].astype(jnp.uint64)
+    hi = col.hi[idx].astype(jnp.uint64)
+    return ((hi << jnp.uint64(32)) | lo).astype(jnp.int64)
+
+
+def scatter64(col: "Col64", tgt, v, mode=None) -> "Col64":
+    """Split int64 `v` and write both halves at `tgt` (lossless: the low
+    word by mask, the high word by logical shift)."""
+    u = jnp.asarray(v).astype(jnp.int64).astype(jnp.uint64)
+    lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
+    hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
+    return Col64(
+        col.lo.at[tgt].set(lo, mode=mode),
+        col.hi.at[tgt].set(hi, mode=mode),
+    )
+
+
+def _halves_to_host(lo, hi) -> np.ndarray:
+    out = np.empty(np.shape(lo), dtype=np.int64)
+    pairs = out.view(np.uint32).reshape(out.shape + (2,))
+    pairs[..., 0] = np.asarray(lo)
+    pairs[..., 1] = np.asarray(hi)
+    return out
+
+
+def _host_to_halves(arr) -> Tuple[np.ndarray, np.ndarray]:
+    a = np.ascontiguousarray(arr, dtype=np.int64)
+    pairs = a.view(np.uint32).reshape(a.shape + (2,))
+    return pairs[..., 0], pairs[..., 1]
+
+
+class _Col64At:
+    __slots__ = ("_col", "_idx")
+
+    def __init__(self, col: "Col64", idx=None) -> None:
+        self._col, self._idx = col, idx
+
+    def __getitem__(self, idx) -> "_Col64At":
+        return _Col64At(self._col, idx)
+
+    def set(self, v, mode=None) -> "Col64":
+        return scatter64(self._col, self._idx, v, mode=mode)
+
+
+@jax.tree_util.register_pytree_with_keys_class
+class Col64:
+    """One logical int64[S] column held as two uint32[S] leaves."""
+
+    __slots__ = ("lo", "hi")
+    dtype = np.dtype(np.int64)  # the LOGICAL dtype (`val.astype(col.dtype)`)
+
+    def __init__(self, lo, hi) -> None:
+        self.lo, self.hi = lo, hi
+
+    def tree_flatten_with_keys(self):
+        k = jax.tree_util.GetAttrKey
+        return ((k("lo"), self.lo), (k("hi"), self.hi)), None
+
+    @classmethod
+    def tree_unflatten(cls, _aux, children) -> "Col64":
+        return cls(*children)
+
+    @property
+    def shape(self):
+        return self.lo.shape
+
+    def __getitem__(self, idx) -> jax.Array:
+        return gather64(self, idx)
+
+    @property
+    def at(self) -> _Col64At:
+        return _Col64At(self)
+
+    def occupied(self) -> jax.Array:
+        """bool[S]: logical value != 0, without leaving 32 bits."""
+        return (self.lo | self.hi) != 0
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        out = _halves_to_host(self.lo, self.hi)
+        return out if dtype is None else out.astype(dtype)
+
+
 class SlotTable(NamedTuple):
     """Struct-of-arrays; one row = one CacheItem (cache.go:30-42) flattened
     together with its TokenBucketItem / LeakyBucketItem payload
-    (store.go:29-43)."""
+    (store.go:29-43).  Comments give the LOGICAL type; the int64 fields
+    are Col64 (two uint32[S] leaves each)."""
 
-    key: jax.Array         # int64[S]; xxhash64 fingerprint; 0 = empty
+    key: Col64             # int64[S]; xxhash64 fingerprint; 0 = empty
     algo: jax.Array        # int32[S]; Algorithm enum
     kind: jax.Array        # int32[S]; KIND_*
-    limit: jax.Array       # int64[S]
-    duration: jax.Array    # int64[S]
-    remaining: jax.Array   # int64[S]; token-bucket remaining / cached-resp remaining
+    limit: Col64           # int64[S]
+    duration: Col64        # int64[S]
+    remaining: Col64       # int64[S]; token-bucket remaining / cached-resp remaining
     remaining_f: jax.Array  # float64[S]; leaky-bucket fractional remaining
-    t0: jax.Array          # int64[S]; token CreatedAt / leaky UpdatedAt
+    t0: Col64              # int64[S]; token CreatedAt / leaky UpdatedAt
     status: jax.Array      # int32[S]; token-bucket sticky status / cached-resp status
-    burst: jax.Array       # int64[S]
-    expire_at: jax.Array   # int64[S]; unix ms (CacheItem.ExpireAt)
-    touched: jax.Array     # int64[S]; last-access stamp for victim choice
+    burst: Col64           # int64[S]
+    expire_at: Col64       # int64[S]; unix ms (CacheItem.ExpireAt)
+    touched: Col64         # int64[S]; last-access stamp for victim choice
 
     @property
     def num_slots(self) -> int:
         return self.key.shape[0]
 
     def occupancy(self) -> jax.Array:
-        return jnp.sum(self.key != 0)
+        return jnp.sum(self.key.occupied())
+
+
+# The logical fields stored as Col64.
+INT64_FIELDS = (
+    "key", "limit", "duration", "remaining", "t0", "burst", "expire_at",
+    "touched",
+)
 
 
 def init_table(num_slots: int) -> SlotTable:
     """All-empty table.  num_slots must keep num_slots/ways a power of two
     (enforced at step-build time) so bucket selection is a mask, not a mod."""
-    i64 = lambda: jnp.zeros((num_slots,), dtype=jnp.int64)
-    i32 = lambda: jnp.zeros((num_slots,), dtype=jnp.int32)
-    return SlotTable(
-        key=i64(),
-        algo=i32(),
-        kind=i32(),
-        limit=i64(),
-        duration=i64(),
-        remaining=i64(),
-        remaining_f=jnp.zeros((num_slots,), dtype=jnp.float64),
-        t0=i64(),
-        status=i32(),
-        burst=i64(),
-        expire_at=i64(),
-        touched=i64(),
-    )
+    def zeros(dtype):
+        return jnp.zeros((num_slots,), dtype=dtype)
+
+    def column(f):
+        if f in INT64_FIELDS:
+            return Col64(zeros(jnp.uint32), zeros(jnp.uint32))
+        return zeros(jnp.float64 if f == "remaining_f" else jnp.int32)
+
+    return SlotTable(**{f: column(f) for f in SlotTable._fields})
 
 
 def table_to_host(table: SlotTable) -> dict:
     """DMA the table down as numpy for snapshot/Loader-save
     (the device analog of WorkerPool.Store streaming cache.Each(),
-    workers.go:467-530)."""
+    workers.go:467-530): the twelve LOGICAL arrays, int64 fields
+    reassembled on the host — the checkpoint format."""
     return {f: np.asarray(getattr(table, f)) for f in table._fields}
 
 
 def table_from_host(arrs: dict) -> SlotTable:
-    return SlotTable(**{f: jnp.asarray(arrs[f]) for f in SlotTable._fields})
+    """The inverse: twelve logical numpy arrays in, physical table out
+    (the halves are host views of the int64 arrays)."""
+    cols = {f: jnp.asarray(arrs[f]) for f in SlotTable._fields
+            if f not in INT64_FIELDS}
+    for f in INT64_FIELDS:
+        lo, hi = _host_to_halves(arrs[f])
+        cols[f] = Col64(jnp.asarray(lo), jnp.asarray(hi))
+    return SlotTable(**cols)
+
+
+def read_rows(table: SlotTable, idx) -> dict:
+    """Logical rows at `idx` (an index array or a slice) as numpy, one
+    entry per field — the host-side point read."""
+    return {f: np.asarray(getattr(table, f)[idx]) for f in table._fields}
 
 
 # --------------------------------------------------------------------------
@@ -273,10 +401,10 @@ def demote_extract_impl(
     the eligible population come back with key 0 and clear nothing."""
     S = table.key.shape[0]
     now = jnp.asarray(now, dtype=jnp.int64)
-    alive = (table.key != 0) & (table.expire_at > now)
+    alive = table.key.occupied() & (table.expire_at[...] > now)
     eligible = alive & (table.kind == KIND_BUCKET)
     protected = (
-        (table.key[:, None] == protect[None, :])
+        (table.key[...][:, None] == protect[None, :])
         & (protect[None, :] != 0)
     ).any(axis=1)
     eligible = eligible & ~protected
@@ -285,7 +413,7 @@ def demote_extract_impl(
     # eligible rows (the bucket-local pseudo-LRU word, applied
     # table-wide).
     big = jnp.iinfo(jnp.int64).max
-    score = jnp.where(eligible, table.touched, big)
+    score = jnp.where(eligible, table.touched[...], big)
     neg, idx = jax.lax.top_k(-score, batch)
     idx = idx.astype(jnp.int64)
     sel = neg != -big
@@ -382,8 +510,9 @@ def table_stats_impl(
     S = table.key.shape[0]
     nb = S // ways
     now = jnp.asarray(now, dtype=jnp.int64)
-    resident = table.key != 0
-    alive = resident & (table.expire_at > now)
+    resident = table.key.occupied()
+    expire_at = table.expire_at[...]
+    alive = resident & (expire_at > now)
     occupancy = jnp.sum(resident, dtype=jnp.int64)
     live = jnp.sum(alive, dtype=jnp.int64)
 
@@ -411,18 +540,18 @@ def table_stats_impl(
         onehot = (idx[:, None] == bins[None, :]) & alive[:, None]
         return jnp.sum(onehot, axis=0, dtype=jnp.int64)
 
-    slot_age = hist(now - table.t0)
-    ttl_remaining = hist(table.expire_at - now)
+    slot_age = hist(now - table.t0[...])
+    ttl_remaining = hist(expire_at - now)
 
     # Remaining-fraction distribution per algorithm.  Two licensed
     # to_f64 casts (remaining and limit — exact below 2^53 like the
     # step kernels' float sites); the bin index narrows to int32 (one
     # licensed to_i32 — FRAC_BINS bounds it).
-    lim_f = jnp.maximum(table.limit.astype(jnp.float64), 1.0)
+    lim_f = jnp.maximum(table.limit[...].astype(jnp.float64), 1.0)
     rem_f = jnp.where(
         table.algo == 1,
         table.remaining_f,
-        table.remaining.astype(jnp.float64),
+        table.remaining[...].astype(jnp.float64),
     )
     frac = jnp.clip(rem_f / lim_f, 0.0, 1.0)
     fbin = jnp.minimum(

@@ -32,7 +32,12 @@ from gubernator_tpu.core.hashing import key_hash64
 from gubernator_tpu.core.types import CacheItem, RateLimitReq, RateLimitResp
 from gubernator_tpu.ops.batch import PackedGrid, pack_requests_grid
 from gubernator_tpu.ops.devices import device_info
-from gubernator_tpu.ops.state import SlotTable, init_table, table_to_host
+from gubernator_tpu.ops.state import (
+    SlotTable,
+    init_table,
+    read_rows,
+    table_to_host,
+)
 from gubernator_tpu.ops.step import DeviceBatchJ, apply_batch_packed_impl
 from gubernator_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_of_hash
 from gubernator_tpu.runtime import tracing
@@ -958,10 +963,7 @@ class MeshBackend(PersistenceHost):
         if not found.any():
             return out
         sel = np.flatnonzero(found)
-        rows = {
-            f: np.asarray(getattr(self.table, f)[gslot[sel]])
-            for f in self.table._fields
-        }
+        rows = read_rows(self.table, gslot[sel])
         for r_i, j in enumerate(sel):
             if rows["kind"][r_i] == KIND_CACHED_RESP and not include_cached:
                 continue
@@ -1002,9 +1004,9 @@ class MeshBackend(PersistenceHost):
 
         with self._lock:
             counts = jnp.sum(
-                self.table.key.reshape(
+                self.table.key.occupied().reshape(
                     self.cfg.num_shards, self.local_slots
-                ) != 0,
+                ),
                 axis=1,
             )
         return [int(c) for c in np.asarray(counts)]
@@ -1032,10 +1034,8 @@ class MeshBackend(PersistenceHost):
         """Dispatch the cluster resident count under the lock; the
         returned zero-arg fetch closure pulls the scalar off the runner
         (DeviceBackend.occupancy_dispatch's contract)."""
-        import jax.numpy as jnp
-
         with self._lock:
-            occ = jnp.sum(self.table.key != 0)
+            occ = self.table.occupancy()
 
         def fetch() -> int:
             return int(np.asarray(occ))

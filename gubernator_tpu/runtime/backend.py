@@ -33,7 +33,12 @@ from gubernator_tpu.core.types import (
 )
 from gubernator_tpu.ops.batch import DeviceBatch, pack_requests
 from gubernator_tpu.ops.devices import device_info, platform_devices
-from gubernator_tpu.ops.state import SlotTable, init_table, table_to_host
+from gubernator_tpu.ops.state import (
+    SlotTable,
+    init_table,
+    read_rows,
+    table_to_host,
+)
 from gubernator_tpu.ops.step import (
     BucketRows,
     CachedRows,
@@ -1031,10 +1036,7 @@ class DeviceBackend(PersistenceHost):
             padded = np.zeros(B, dtype=np.int64)
             padded[: len(chunk_keys)] = hashes[lo:lo + B]
             found, slot = self._probe(self.table, padded, np.int64(now))
-            rows = {
-                f: np.asarray(getattr(self.table, f)[slot])
-                for f in self.table._fields
-            }
+            rows = read_rows(self.table, slot)
             found = np.asarray(found)
             for j, k in enumerate(chunk_keys):
                 if not found[j]:
@@ -1415,10 +1417,7 @@ def probe_bucket(
     misses."""
     from gubernator_tpu.ops.state import KIND_CACHED_RESP
 
-    rows = {
-        f: np.asarray(getattr(table, f)[lo:lo + ways])
-        for f in table._fields
-    }
+    rows = read_rows(table, slice(lo, lo + ways))
     h = int(np.uint64(key_hash64(key)).view(np.int64))
     for w in range(ways):
         if rows["key"][w] == h and rows["expire_at"][w] > now:

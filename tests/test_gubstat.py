@@ -30,6 +30,7 @@ from gubernator_tpu.ops.state import (
     AGE_BIN_EDGES_MS,
     SHADOW_PLANES,
     init_table,
+    table_from_host,
     table_stats,
 )
 from gubernator_tpu.runtime.gubstat import (
@@ -148,7 +149,7 @@ def test_table_stats_matches_numpy_reference():
     grid[3, 0] = 10**9 + 33                          # enumerated, absent
     grid[4, 0] = plant(10**9 + 41, now + 60_000)     # live region carve
 
-    table = type(table)(**leaves)
+    table = table_from_host(leaves)
     st = table_stats(table, grid, np.int64(now), ways=ways)
 
     (occ, live, exp_res, fill, age, ttl, frac, shadow) = _numpy_census(

@@ -150,7 +150,7 @@ def test_apply_batch_consumes_donated_table():
     from tools.gubtrace.registry import _device_batch
 
     table = init_table(4096)
-    leaves = list(table)
+    leaves = jax.tree_util.tree_leaves(table)  # the 20 physical columns
     new_table, resp = apply_batch(table, _device_batch(64), np.int64(0))
     jax.block_until_ready(new_table)
     deleted = [leaf.is_deleted() for leaf in leaves]

@@ -45,6 +45,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from tools.gubrange import units as U
 from tools.gubrange.interval import (
+    WORD,
     AbsVal,
     add_bounds,
     div_bounds_float,
@@ -56,6 +57,7 @@ from tools.gubrange.interval import (
     mul_bounds,
     rem_bounds_int,
     sub_bounds,
+    half_of,
     top_of,
     trunc_to_int_bounds,
 )
@@ -98,6 +100,13 @@ def _strip_rows(a: AbsVal) -> AbsVal:
     if a.rows is None:
         return a
     return replace(a, rows=None, rows_axis=0)
+
+
+def _half(a: AbsVal, part: str) -> Optional[AbsVal]:
+    """The logical bound `a` carries as split-column word `part`."""
+    if a.half is not None and a.half[0] == part:
+        return a.half[1]
+    return None
 
 
 def _size(shape) -> int:
@@ -400,9 +409,23 @@ class RangeWalk:
             if dtype == "bool":
                 return [AbsVal(0, 1)]
             if name == "and":
+                # The split's low word: `u & 0xFFFFFFFF` of a whole
+                # value (tagged at its int64 -> uint64 convert).
+                for x, m in (ins, ins[::-1]):
+                    whole = _half(x, "u64")
+                    if whole is not None and m.is_exact() \
+                            and m.lo == WORD:
+                        return [half_of(whole, "lo")]
                 nonneg = [v for v in ins if v.lo >= 0]
                 if nonneg:
                     return [AbsVal(0, min(v.hi for v in nonneg))]
+            if name == "or":
+                # The combine: `(hi << 32) | lo` of one column's words
+                # is a value inside that column's logical bound.
+                for x, y in (ins, ins[::-1]):
+                    hi32, lo = _half(x, "hi32"), _half(y, "lo")
+                    if hi32 is not None and lo is not None:
+                        return [self._whole(eqn, hi32, lo, dtype)]
             if name in ("or", "xor") and all(v.lo >= 0 for v in ins):
                 m = max(v.hi for v in ins)
                 return [AbsVal(0, (1 << max(int(m), 1).bit_length()) - 1)]
@@ -412,6 +435,14 @@ class RangeWalk:
                     "shift_right_arithmetic"):
             a, s = ins
             dtype = _aval_dtype(eqn.outvars[0])
+            if s.is_exact() and s.lo == 32 and dtype == "uint64":
+                hi = _half(a, "hi")
+                if name == "shift_left" and hi is not None:
+                    return [AbsVal(int(a.lo) << 32, int(a.hi) << 32,
+                                   half=("hi32", hi))]
+                whole = _half(a, "u64")
+                if name == "shift_right_logical" and whole is not None:
+                    return [half_of(whole, "hi")]
             if a.is_exact() and s.is_exact():
                 x, sh = int(a.lo), int(s.lo)
                 if name == "shift_left":
@@ -491,7 +522,14 @@ class RangeWalk:
             upd = ins[-1] if name == "dynamic_update_slice" else ins[2]
             unit = self._unit2(eqn, U.join, op, upd)
             lo, hi, top = join_bounds(op, upd)
-            return [AbsVal(lo, hi, unit=unit, top=top)]
+            half = None
+            if op.half is not None and upd.half is not None \
+                    and op.half[0] == upd.half[0]:
+                # A word of a split column overwritten by the same word
+                # of a split value: the column's logical bound joins.
+                half = (op.half[0],
+                        self._join_logical(eqn, op.half[1], upd.half[1]))
+            return [AbsVal(lo, hi, unit=unit, top=top, half=half)]
 
         if name in ("scatter-add", "scatter_add"):
             op, upd = ins[0], ins[2]
@@ -558,7 +596,7 @@ class RangeWalk:
                     for _ in eqn.outvars]
 
         # -- structured control flow --------------------------------------
-        if name == "pjit" or (
+        if name in ("pjit", "jit") or (
             "jaxpr" in p and name in ("closed_call", "shard_map",
                                       "remat", "checkpoint")
         ):
@@ -606,6 +644,25 @@ class RangeWalk:
 
     # -- helpers ----------------------------------------------------------
 
+    def _join_logical(self, eqn, a: AbsVal, b: AbsVal) -> AbsVal:
+        unit = self._unit2(eqn, U.join, a, b)
+        lo, hi, top = join_bounds(a, b)
+        return AbsVal(lo, hi, unit=unit, top=top)
+
+    def _whole(self, eqn, hi: AbsVal, lo: AbsVal, dtype: str) -> AbsVal:
+        """`(hi << 32) | lo` in uint64, carrying the joined logical
+        bound of the two words' column to the int64 convert.  (The
+        domain is non-relational: that the two words come from the same
+        ROW is ops/state.py's gather64 contract, pinned by
+        tests/test_table_layout.py, not something an interval can
+        see.)"""
+        logical = self._join_logical(eqn, hi, lo)
+        rlo, rhi = dtype_range(dtype)
+        if logical.lo >= 0:
+            return AbsVal(int(logical.lo), int(logical.hi),
+                          half=("u64", logical))
+        return AbsVal(rlo, rhi, half=("u64", logical))
+
     def _check_negative_duration(self, eqn, a: AbsVal, b: AbsVal) -> None:
         for x, y in ((a, b), (b, a)):
             if U.is_epoch(x.unit) and not U.is_epoch(y.unit) and \
@@ -631,12 +688,23 @@ class RangeWalk:
             lo, hi = trunc_to_int_bounds(a, dst)
             return AbsVal(lo, hi, unit=a.unit)
         rlo, rhi = dtype_range(dst)
+        # The slot table's split columns (interval.AbsVal.half): the
+        # whole value leaves its uint64 guise with its logical bound,
+        # enters it tagged with that bound, and a word keeps its tag
+        # through the uint32 <-> uint64 converts of combine and split.
+        whole = _half(a, "u64")
+        if whole is not None and dst == "int64":
+            return whole
+        half = a.half if sk == "uint" and dk == "uint" else None
+        if src == "int64" and dst == "uint64":
+            half = ("u64", replace(_strip_rows(a), half=None))
         if a.lo >= rlo and a.hi <= rhi:
-            return AbsVal(int(a.lo), int(a.hi), unit=a.unit, top=a.top)
+            return AbsVal(int(a.lo), int(a.hi), unit=a.unit, top=a.top,
+                          half=half)
         # Out-of-range int->int reinterpretation: the dtype-taint plane
         # (gubtrace) governs narrowing legality; range-wise it's the
         # full destination range.
-        return AbsVal(rlo, rhi, unit=a.unit, top=a.top)
+        return AbsVal(rlo, rhi, unit=a.unit, top=a.top, half=half)
 
     def _scan(self, eqn, ins: List[AbsVal]) -> List[AbsVal]:
         p = eqn.params

@@ -32,6 +32,15 @@ the flattened args, first match wins — the same matching the gubtrace
 counter taint uses.  `expect_peak` is a STRING because JSON numbers
 lose integer precision past 2^53.  Every budget entry requires a
 written reason.
+
+Split columns.  The slot table stores each int64 field as a low and a
+high uint32 leaf (`[0].limit.lo`, `[0].limit.hi`; ops/state.py).  A
+rule still declares the LOGICAL int64 bound under the field's name
+(`.limit` matches both leaves): `seed` gives each leaf the bound of its
+word and tags it with the logical bound, and the interpreter attaches
+that bound where the kernel combines the words — so the bound a
+reviewer reads here is the bound the arithmetic is proved under, no
+wider than before the split.
 """
 from __future__ import annotations
 
@@ -41,9 +50,11 @@ from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from tools.gubrange.interval import (
+    WORD,
     AbsVal,
     dtype_range,
     from_rows,
+    half_of,
     top_of,
 )
 
@@ -189,10 +200,26 @@ def seed(
                     ))
             seeds.append(from_rows(row_vals, rule.rows_axis))
             continue
+        part = _word_of(key, dtype)
+        if part is not None:
+            ilo, ihi = dtype_range("int64")
+            seeds.append(half_of(
+                AbsVal(max(rule.min, ilo), min(rule.max, ihi),
+                       unit=rule.unit),
+                part,
+            ))
+            continue
         lo, hi = max(rule.min, rlo), min(rule.max, rhi)
         seeds.append(AbsVal(lo, hi, unit=rule.unit))
     unused = [r.pattern for r in env.inputs if r.pattern not in used]
     return seeds, unmatched, unused
+
+
+def _word_of(key: str, dtype: str) -> Optional[str]:
+    """"lo" | "hi" for a leaf that is one word of a split int64 column."""
+    if dtype == "uint32" and key.endswith((".lo", ".hi")):
+        return key[-2:]
+    return None
 
 
 def corner_args(env: Envelope, args: tuple, corner: str = "max") -> tuple:
@@ -220,6 +247,11 @@ def corner_args(env: Envelope, args: tuple, corner: str = "max") -> tuple:
                     arr[tuple(idx)] = min(max(int(v), rlo), rhi)
             else:
                 v = rule.max if corner == "max" else rule.min
+                part = _word_of(key, arr.dtype.name)
+                if part is not None:  # the word of the LOGICAL corner
+                    ilo, ihi = dtype_range("int64")
+                    v = min(max(v, ilo), ihi) % 2**64
+                    v = (v >> 32) if part == "hi" else (v & WORD)
                 arr = np.full_like(arr, min(max(v, rlo), rhi))
         leaves.append(arr)
     return jax.tree_util.tree_unflatten(treedef, leaves)

@@ -80,6 +80,16 @@ class AbsVal:
     top: bool = False
     rows: Optional[tuple] = None
     rows_axis: int = 0
+    # Split-column refinement (the slot table's physical layout,
+    # ops/state.py): `half = (part, logical)` marks a value that is one
+    # word of an int64 whose LOGICAL bound is the AbsVal `logical` —
+    # part "lo"/"hi" for the uint32 words (as stored, gathered and
+    # scattered), "hi32" for the high word shifted into place, "u64"
+    # for the whole value in its uint64 guise.  lo/hi/unit of the
+    # carrier stay honest bounds of the word itself, so a transfer that
+    # ignores `half` is conservative; the combine and the split
+    # (absint.py) use it to hand the logical bound across exactly.
+    half: Optional[tuple] = None
 
     def with_unit(self, unit: Optional[str]) -> "AbsVal":
         return replace(self, unit=unit)
@@ -91,7 +101,8 @@ class AbsVal:
         u = f" {self.unit}" if self.unit else ""
         t = " TOP" if self.top else ""
         r = f" rows@{self.rows_axis}x{len(self.rows)}" if self.rows else ""
-        return f"[{self.lo}, {self.hi}]{u}{t}{r}"
+        h = f" {self.half[0]}-of {self.half[1]}" if self.half else ""
+        return f"[{self.lo}, {self.hi}]{u}{t}{r}{h}"
 
 
 def from_rows(rows, axis: int) -> AbsVal:
@@ -111,6 +122,24 @@ def from_rows(rows, axis: int) -> AbsVal:
 def top_of(dtype_name: str, unit: Optional[str] = None) -> AbsVal:
     lo, hi = dtype_range(dtype_name)
     return AbsVal(lo, hi, unit=unit, top=True)
+
+
+WORD = 0xFFFFFFFF
+
+
+def half_of(logical: AbsVal, part: str) -> AbsVal:
+    """The uint32 word `part` ("lo" | "hi") of an int64 bounded by
+    `logical`, tagged with that bound."""
+    a, b = int(logical.lo), int(logical.hi)
+    if a < 0:  # two's complement: a negative value's words span all
+        lo, hi = 0, WORD
+    elif part == "hi":
+        lo, hi = a >> 32, b >> 32
+    elif (a >> 32) == (b >> 32):
+        lo, hi = a & WORD, b & WORD
+    else:
+        lo, hi = 0, WORD
+    return AbsVal(lo, hi, half=(part, logical))
 
 
 def exact(v: Num, unit: Optional[str] = None) -> AbsVal:

@@ -22,6 +22,20 @@ Casts are bucketed by destination:
             kernel's contract bounds the value, e.g. CMS cells)
   to_i32 / narrower — wrap/truncation (budget only for fields whose
             contract bounds them, e.g. packed algo enums)
+  split64 — the slot table's physical layout (ops/state.py): an int64
+            column is stored as a low and a high uint32 column, and a
+            write splits the value LOSSLESSLY — `(u & 0xFFFFFFFF)` and
+            `(u >> 32)` of the SAME value, both narrowed to uint32 and
+            both stored.  One split64 is charged per value split so
+            (declared per kernel like every other class); a lone half,
+            or a half that is never stored, is what it looks like — a
+            truncation — and is charged to to_i32.
+The reverse direction needs no budget: a table half is a tainted uint32
+input whose lineage (gather, select, scatter) stays tainted, and the
+combine `(hi.astype(u64) << 32) | lo.astype(u64)` re-enters the 64-bit
+lineage at its widening converts — the taint on logical values starts
+at the combine's output.  A half cast anywhere else (to a float, to
+int32) is charged like a counter cast.
 Casts to bool (lane predicates) and within the 64-bit integer family
 are free — they cannot corrupt a counter.  Also free: *index* casts,
 i64→i32 whose every (transitive, through shape-only ops) consumer is
@@ -46,6 +60,7 @@ from tools.gubtrace.core import (
 )
 
 _WIDE_INT = ("int64", "uint64")
+_HALF = "uint32"  # a table half: one of an int64 column's two words
 
 
 def _bucket(dtype_name: str) -> str:
@@ -69,7 +84,7 @@ def _bucket(dtype_name: str) -> str:
 # moves the lineage between devices without consuming it.
 _SHAPE_ONLY = frozenset({
     "broadcast_in_dim", "reshape", "concatenate", "slice", "squeeze",
-    "expand_dims", "transpose", "rev", "copy", "pbroadcast",
+    "expand_dims", "transpose", "rev", "copy", "pbroadcast", "pvary",
 })
 
 
@@ -87,6 +102,64 @@ def _index_positions(eqn) -> List[int]:
     if name == "dynamic_update_slice":
         return list(range(2, n))
     return []
+
+
+def _scalar_literal(v, producer):
+    """The Python int of a scalar integer Literal operand — seen through
+    the shape-only ops shard_map wraps a constant in (pvary) — else
+    None."""
+    for _ in range(4):
+        eqn = producer.get(id(v))
+        if hasattr(v, "val") or eqn is None or \
+                eqn.primitive.name not in _SHAPE_ONLY:
+            break
+        v = eqn.invars[0]
+    val = getattr(v, "val", None)
+    if val is None or getattr(val, "shape", ()) != ():
+        return None
+    try:
+        return int(val)
+    except (TypeError, ValueError):
+        return None
+
+
+def _split_part(eqn, producer):
+    """(`lo`|`hi`, source var) when `eqn` computes one word of the
+    lossless 64-bit split — `x & 0xFFFFFFFF` or `x >> 32` — else None."""
+    if eqn is None or len(eqn.invars) != 2:
+        return None
+    name = eqn.primitive.name
+    a, b = eqn.invars
+    if name == "and":
+        for x, c in ((a, b), (b, a)):
+            if _scalar_literal(c, producer) == 0xFFFFFFFF \
+                    and not hasattr(x, "val"):
+                return ("lo", x)
+    if name == "shift_right_logical" and not hasattr(a, "val") \
+            and _scalar_literal(b, producer) == 32:
+        return ("hi", a)
+    return None
+
+
+def _is_stored(var, consumers, outvar_ids) -> bool:
+    """True when `var` reaches (through shape-only ops) the updates
+    operand of a scatter, or leaves the jaxpr — a split word that is
+    written somewhere, not computed and dropped."""
+    seen = set()
+    stack = [var]
+    while stack:
+        v = stack.pop()
+        if id(v) in seen:
+            continue
+        seen.add(id(v))
+        if id(v) in outvar_ids:
+            return True
+        for eqn in consumers.get(id(v), ()):
+            if eqn.primitive.name == "scatter" and eqn.invars[2] is v:
+                return True
+            if eqn.primitive.name in _SHAPE_ONLY:
+                stack.extend(eqn.outvars)
+    return False
 
 
 def _is_index_only(var, eqn_of_var, consumers, outvar_ids) -> bool:
@@ -128,11 +201,19 @@ class _Walk:
     def _tainted_outs(self, eqn, tin: List[bool]) -> List[bool]:
         """Default propagation: any tainted input taints every wide-int
         output (float/bool/narrow outputs are only reached via an
-        explicit convert, which is handled separately)."""
+        explicit convert, which is handled separately), and a tainted
+        table half (uint32) taints every uint32 output — the half
+        lineage through gather/select/scatter."""
         if not any(tin):
             return [False] * len(eqn.outvars)
+        half_in = any(
+            t and str(v.aval.dtype) == _HALF
+            for v, t in zip(eqn.invars, tin)
+        )
         return [
-            str(v.aval.dtype) in _WIDE_INT for v in eqn.outvars
+            str(v.aval.dtype) in _WIDE_INT
+            or (half_in and str(v.aval.dtype) == _HALF)
+            for v in eqn.outvars
         ]
 
     def walk(self, jaxpr, taint_in: List[bool]) -> List[bool]:
@@ -140,11 +221,17 @@ class _Walk:
         j = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
         tainted: Set[int] = set()
         consumers: Dict[int, list] = {}
+        producer: Dict[int, object] = {}
         for eqn in j.eqns:
             for v in eqn.invars:
                 if not hasattr(v, "val"):
                     consumers.setdefault(id(v), []).append(eqn)
+            for v in eqn.outvars:
+                producer[id(v)] = eqn
         outvar_ids = {id(v) for v in j.outvars}
+        # id(source var) -> {"lo"/"hi": (stored, site)}: the words of
+        # each value this jaxpr splits (settled after the loop).
+        splits: Dict[int, Dict[str, Tuple[bool, str]]] = {}
 
         def is_t(v) -> bool:
             return not hasattr(v, "val") and id(v) in tainted
@@ -159,19 +246,37 @@ class _Walk:
             if name == "convert_element_type" and tin[0]:
                 src = str(eqn.invars[0].aval.dtype)
                 dst = str(eqn.outvars[0].aval.dtype)
+                site = f"{src}->{dst} at {eqn_source(eqn) or '?'}"
                 if src in _WIDE_INT:
                     b = _bucket(dst)
                     if b in ("to_i32", "to_i8") and _is_index_only(
                         eqn.outvars[0], eqn, consumers, outvar_ids
                     ):
                         continue  # index lineage — bounded by geometry
-                    if b:
-                        self.casts[b] += 1
-                        self.sites.setdefault(b, []).append(
-                            f"{src}->{dst} at {eqn_source(eqn) or '?'}"
+                    part = _split_part(
+                        producer.get(id(eqn.invars[0])), producer
+                    ) \
+                        if dst == _HALF else None
+                    if part is not None:
+                        stored = _is_stored(
+                            eqn.outvars[0], consumers, outvar_ids
                         )
+                        splits.setdefault(id(part[1]), {})[part[0]] = (
+                            stored, site
+                        )
+                        continue
+                    if b:
+                        self._charge(b, site)
                         continue  # converted lineage is not re-tainted
-                # wide-int <-> wide-int keeps taint
+                elif src == _HALF and dst not in _WIDE_INT:
+                    # A table half cast to anything but the combine's
+                    # widening (or a bool predicate) is a counter cast.
+                    b = _bucket(dst)
+                    if b:
+                        self._charge(b, site)
+                    continue
+                # wide-int <-> wide-int keeps taint; half -> wide-int is
+                # the combine, where the logical value's taint starts
                 if dst in _WIDE_INT:
                     tainted.add(id(eqn.outvars[0]))
                 continue
@@ -179,12 +284,26 @@ class _Walk:
             for v, t in zip(eqn.outvars, tout):
                 if t:
                     tainted.add(id(v))
+        for words in splits.values():
+            whole = (
+                set(words) == {"lo", "hi"}
+                and all(stored for stored, _ in words.values())
+            )
+            if whole:
+                self._charge("split64", words["lo"][1])
+            else:  # a lone or dropped half IS a truncation
+                for _stored, site in words.values():
+                    self._charge("to_i32", site)
         return [is_t(v) for v in j.outvars]
+
+    def _charge(self, bucket: str, site: str) -> None:
+        self.casts[bucket] += 1
+        self.sites.setdefault(bucket, []).append(site)
 
     def _descend(self, eqn, tin: List[bool]) -> List[bool]:
         name = eqn.primitive.name
         p = eqn.params
-        if name == "pjit" or (
+        if name in ("pjit", "jit") or (
             "jaxpr" in p and name in ("closed_call", "shard_map")
         ):
             return self.walk(p["jaxpr"], tin)

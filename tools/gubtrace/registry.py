@@ -74,9 +74,14 @@ SKETCH_DEPTH = 4
 SKETCH_WIDTH = 1024
 SKETCH_B = 128
 
-# Table int64 counter/timestamp columns (SlotTable has 12 leaves; the
-# int32 enums algo/kind/status and the float remaining_f are excluded —
-# their contracts bound them).
+# Table int64 counter/timestamp columns (SlotTable has 12 logical
+# fields; the int32 enums algo/kind/status and the float remaining_f are
+# excluded — their contracts bound them).  Each is PHYSICALLY two uint32
+# leaves (`[0].limit.lo`, `[0].limit.hi`, ops/state.py), which these
+# patterns match as substrings: the dtype plane carries the halves'
+# taint to the combine, where the logical int64 lineage starts, and
+# charges each lossless write-back split to the `split64` class — never
+# to `to_i32`, which stays the truncation budget (tools/gubtrace/dtype.py).
 _TABLE_COUNTERS = (
     ".key", ".limit", ".duration", ".remaining", ".t0", ".burst",
     ".expire_at", ".touched",
@@ -185,12 +190,15 @@ def _step_spec(
 # runs in float64 through the _trunc_i64 saturation contract; each is
 # exact below 2^53, the float64 mantissa).  The 15th would be a
 # regression.
-_APPLY_CASTS = {"to_f64": 14}
+# split64: the write-back stores eight int64 columns (key, limit,
+# duration, remaining, t0, burst, expire_at, touched), one lossless
+# split each.
+_APPLY_CASTS = {"to_f64": 14, "split64": 8}
 _APPLY_COUNTERS = _TABLE_COUNTERS + _BATCH_COUNTERS + (".limit",
                                                        ".duration", "[2]")
 # Packed q-form: one widened-int64 row is narrowed back to the int32
 # algo enum (values 0/1 by wire contract).
-_APPLY_Q_CASTS = {"to_f64": 14, "to_i32": 1}
+_APPLY_Q_CASTS = {"to_f64": 14, "to_i32": 1, "split64": 8}
 
 
 def _migrate_spec(name: str, fn_name: str, impl_name: str,
@@ -202,7 +210,9 @@ def _migrate_spec(name: str, fn_name: str, impl_name: str,
     the packed int64 stack); the inject is probe+load+merge in one,
     with ONE licensed to_f64 — the conflict merge's leaky-bucket
     consumed budget (limit - remaining_f), exact below 2^53 like the
-    step kernels' float sites."""
+    step kernels' float sites — and nine split64: load_rows' eight
+    column writes plus the merged `remaining`.  The extracts clear with
+    a constant 0, which is no counter lineage and splits nothing."""
 
     def build() -> BuiltKernel:
         import gubernator_tpu.ops.state as state
@@ -304,7 +314,7 @@ def _mega_ring_spec() -> KernelSpec:
                 ),
             },
             recompile_budget=3,
-            expect_aliased=12,  # table only — seq deliberately kept
+            expect_aliased=20,  # table only — seq deliberately kept
         )
 
     return KernelSpec(
@@ -354,7 +364,7 @@ def _persistent_serve_spec() -> KernelSpec:
                 ),
             },
             recompile_budget=3,
-            expect_aliased=12,  # table only — seq deliberately kept
+            expect_aliased=20,  # table only — seq deliberately kept
         )
 
     return KernelSpec(
@@ -400,7 +410,7 @@ def _ring_spec() -> KernelSpec:
                 ),
             },
             recompile_budget=3,
-            expect_aliased=12,  # table only — seq deliberately kept
+            expect_aliased=20,  # table only — seq deliberately kept
         )
 
     return KernelSpec(name="ring_step", where="gubernator_tpu/ops/ring.py",
@@ -595,14 +605,23 @@ def _global_sync_spec(psum: bool = False) -> KernelSpec:
             ),
             # Two apply_batch passes ride inside the sync step; the
             # broadcast re-read runs with hits=0 (a literal, untainted)
-            # so its _f64(r_hits) does not count: 14 + 13.  The psum
-            # form shares the budget — it swaps the aggregation
-            # collective (one psum vs all_to_all + sort/segment), not
-            # the apply passes.
-            allowed_casts={"to_f64": 27},
+            # so its _f64(r_hits) does not count: 14 + 13; the writes
+            # split 8 + 8 auth columns and the cache table's 5.  The
+            # psum form swaps the aggregation collective (one psum vs
+            # all_to_all + sort/segment), not the apply passes — but
+            # its aggregated hits arrive through _psum_mod64's four
+            # 16-bit limbs per value (XLA:TPU lowers no 64-bit integer
+            # all-reduce, PERF.md PR 21): 7 values x 4 masked, lossless
+            # u64->u32 narrowings, licensed here, after which the
+            # aggregate is no longer tainted lineage — so only the
+            # table-side float sites (4) and splits (16) are charged.
+            allowed_casts=(
+                {"to_f64": 4, "to_i32": 28, "split64": 16} if psum
+                else {"to_f64": 27, "split64": 21}
+            ),
             perturbations={},
             recompile_budget=1,
-            expect_aliased=24,  # auth + cache tables, 12 leaves each
+            expect_aliased=40,  # auth + cache tables, 20 leaves each
         )
 
     return KernelSpec(
@@ -650,7 +669,7 @@ def _mesh_ring_spec() -> KernelSpec:
             # Two slot tiers, mesh callers always normalize `now`
             # (np.int64 in ring_step_dispatch) — no weak variant.
             recompile_budget=2,
-            expect_aliased=12,  # table only — per-shard seq kept
+            expect_aliased=20,  # table only — per-shard seq kept
         )
 
     return KernelSpec(
@@ -732,13 +751,13 @@ def specs() -> List[KernelSpec]:
         _step_spec(
             "apply_batch", "apply_batch", "apply_batch_impl",
             lambda B: (_device_batch(B),),
-            _APPLY_COUNTERS, dict(_APPLY_CASTS), donated=12,
+            _APPLY_COUNTERS, dict(_APPLY_CASTS), donated=20,
         ),
         _step_spec(
             "load_rows", "load_rows", "load_rows_impl",
             lambda B: (_bucket_rows(B),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {}, donated=12,
+            {"split64": 8}, donated=20,  # all eight int64 columns
         ),
         _step_spec(
             "probe_batch", "probe_batch", "probe_batch_impl",
@@ -755,32 +774,34 @@ def specs() -> List[KernelSpec]:
             "store_cached_rows_impl",
             lambda B: (_cached_rows(B),),
             _TABLE_COUNTERS + (".key_hash", ".reset_time", "[2]"),
-            {}, donated=12,
+            # key, limit, remaining, expire_at, touched; duration, t0
+            # and burst are written as constant zeros (no lineage).
+            {"split64": 5}, donated=20,
         ),
         _step_spec(
             "apply_batch_packed", "apply_batch_packed",
             "apply_batch_packed_impl",
             lambda B: (_device_batch(B),),
-            _APPLY_COUNTERS, dict(_APPLY_CASTS), donated=12,
+            _APPLY_COUNTERS, dict(_APPLY_CASTS), donated=20,
         ),
         _step_spec(
             "apply_batch_packed_q", "apply_batch_packed_q",
             "apply_batch_packed_q_impl",
             lambda B: (np.zeros((12, B), np.int64),),
             _TABLE_COUNTERS + ("[1]", "[2]"),
-            dict(_APPLY_Q_CASTS), donated=12,
+            dict(_APPLY_Q_CASTS), donated=20,
         ),
         # -- ops/state.py: live-migration row kernels -------------------
         _migrate_spec(
             "migrate_extract", "migrate_extract", "migrate_extract_impl",
             lambda B: (np.zeros(B, np.int64),),
-            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=12,
+            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=20,
         ),
         _migrate_spec(
             "migrate_inject", "migrate_inject", "migrate_inject_impl",
             lambda B: (_bucket_rows(B),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {"to_f64": 1}, donated=12,
+            {"to_f64": 1, "split64": 9}, donated=20,
         ),
         # -- ops/state.py: the tier demotion kernel (docs/tiering.md) --
         # Same gather+clear atomicity shape as migrate_extract, but the
@@ -789,7 +810,7 @@ def specs() -> List[KernelSpec]:
         _migrate_spec(
             "demote_extract", "demote_extract", "demote_extract_impl",
             lambda B: (np.zeros(B, np.int64),),
-            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=12,
+            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=20,
         ),
         # -- ops/state.py: the gubstat state census ---------------------
         _table_stats_spec(),
@@ -809,21 +830,21 @@ def specs() -> List[KernelSpec]:
             "sharded_step_packed", f_step("sharded_step_packed"),
             lambda: (_packed_grid(),),
             _TABLE_COUNTERS + ("[1]", "[2]"),
-            dict(_APPLY_Q_CASTS), donated=12,
+            dict(_APPLY_Q_CASTS), donated=20,
         ),
         _mesh_spec(
             "sharded_load_rows",
             row_factory("load_rows_impl", "BucketRows"),
             lambda: (_row_grid(_bucket_rows),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {}, donated=12,
+            {"split64": 8}, donated=20,
         ),
         _mesh_spec(
             "sharded_store_cached",
             row_factory("store_cached_rows_impl", "CachedRows"),
             lambda: (_row_grid(_cached_rows),),
             _TABLE_COUNTERS + (".key_hash", ".reset_time", "[2]"),
-            {}, donated=12,
+            {"split64": 5}, donated=20,
         ),
         _mesh_spec(
             "sharded_probe", f_step("sharded_probe"),
@@ -838,7 +859,7 @@ def specs() -> List[KernelSpec]:
         _mesh_spec(
             "sharded_demote_extract", demote_factory,
             lambda: (np.zeros(8, np.int64),),
-            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=12,
+            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=20,
         ),
         _mesh_spec(
             "sharded_table_stats", f_step("sharded_table_stats"),
