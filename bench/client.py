@@ -57,13 +57,13 @@ def encode_plan(native, plan, uni_cfg: dict, seed: int) -> list:
     behavior = np.where(is_global, universe.BEHAVIOR_GLOBAL, 0).astype(
         np.int64
     )
-    one = np.ones(len(ids), dtype=np.int64)
-    dur = one * int(uni_cfg["duration_ms"])
+    hits = plan.hits.astype(np.int64)
+    dur = np.full(len(ids), int(uni_cfg["duration_ms"]), dtype=np.int64)
     out = []
     for j in range(len(plan)):
         s = slice(int(plan.offsets[j]), int(plan.offsets[j + 1]))
         out.append(universe.encode_rpc(
-            native, ids[s], one[s], limit[s], dur[s], algo[s], behavior[s]
+            native, ids[s], hits[s], limit[s], dur[s], algo[s], behavior[s]
         ))
     return out
 

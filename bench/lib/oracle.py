@@ -15,11 +15,15 @@ reference, field by field:
                              send/receive wall-clock bounds of the RPC that
                              fixed it (chip_smoke.py's WireOracle rule).
 
-Order.  One RPC's duplicates must decrement in order.  Two RPCs that were
-in flight at the same time may reach the table in either order, so the
-answers of such a group are matched to the reference's sequence as a
-multiset (every check of a key carries the same request, so the sequence
-itself does not depend on the order).
+Order.  One RPC's duplicates must be answered in order.  Two RPCs that
+were in flight at the same time may reach the table in either order, so the
+answers of such a group are matched to the reference as a multiset: an order
+is sought in which the reference gives exactly these answers, each RPC's own
+checks kept in their order (`linearize`).  A bucket only runs down inside a
+run (nothing expires or leaks a whole token), so a peek (`hits` 0, which
+answers and changes nothing) is taken as soon as the reference's state
+matches it and a spend when no peek can be: with `hits` of 0 and one other
+value that finds an order whenever one exists.
 
 Crowded buckets.  A bucket that more than `ways` keys of the universe map
 to evicts, and which row goes depends on the server's millisecond stamps.
@@ -29,6 +33,7 @@ impossible and the replay is strict.
 """
 from __future__ import annotations
 
+import copy
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -46,6 +51,7 @@ class Answers:
 
     key: np.ndarray        # universe index
     rpc: np.ndarray        # row of the client's record
+    hits: np.ndarray       # what the check asked for
     status: np.ndarray
     limit: np.ndarray
     remaining: np.ndarray
@@ -61,10 +67,11 @@ def flatten(plan, rec: Dict[str, np.ndarray]) -> Answers:
     rpc = np.repeat(ok, sizes)
     first = np.repeat(np.cumsum(sizes) - sizes, sizes)
     pos = np.arange(total, dtype=np.int64) - first
-    key = plan.key_index[np.repeat(starts, sizes) + pos]
+    at = np.repeat(starts, sizes) + pos
     lo = np.repeat(rec["ans_off"][ok], sizes) + pos
     return Answers(
-        key=key, rpc=rpc, status=rec["status"][lo], limit=rec["limit"][lo],
+        key=plan.key_index[at], rpc=rpc, hits=plan.hits[at],
+        status=rec["status"][lo], limit=rec["limit"][lo],
         remaining=rec["remaining"][lo], reset_time=rec["reset_time"][lo],
         err_len=rec["err_len"][lo],
     )
@@ -100,9 +107,12 @@ def screen(a: Answers, uni: Universe, v: Verdict) -> None:
     want_limit = np.where(g, uni.global_limit, uni.limit)
     v.counts["errors"] = int((a.err_len != 0).sum())
     v.counts["wrong_limit"] = int((a.limit != want_limit).sum())
+    # An admitted spend leaves at most limit - hits; a peek or a refusal
+    # may show the whole limit.
+    most = want_limit - np.where(a.status == 0, a.hits, 0)
     v.counts["malformed_answers"] = int((
         (a.status < 0) | (a.status > 1) | (a.remaining < 0)
-        | (a.remaining > want_limit)
+        | (a.remaining > most)
     ).sum())
     # GLOBAL limits are far above what a run can send: never over.
     v.counts["global_not_under"] = int((g & (a.status != 0)).sum())
@@ -117,10 +127,49 @@ def _canonical(rows: List[tuple]) -> List[tuple]:
     return sorted(rows, key=lambda r: (r[0], -r[1]))
 
 
+def linearize(model, reqs: dict, hkey: str, obs: List[tuple]) -> List[tuple]:
+    """An order of `obs` — (status, remaining, reset_time, rpc, hits) of one
+    key's answers from RPCs in flight together, each RPC's in request
+    order — in which the reference answers as observed; what cannot be
+    placed comes last, in its own order.  The reference's state of the key
+    is put back as it was."""
+    queues: Dict[int, List[tuple]] = {}
+    for o in obs:
+        queues.setdefault(o[3], []).append(o)
+    before = copy.copy(model.cache.get(hkey))
+
+    def restore(item) -> None:
+        if item is None:
+            model.cache.pop(hkey, None)
+        else:
+            model.cache[hkey] = item
+
+    out: List[tuple] = []
+    while queues:
+        heads = sorted((q[0] for q in queues.values()),
+                       key=lambda o: (o[4], o[3]))
+        for o in heads:
+            keep = copy.copy(model.cache.get(hkey))
+            want = model.get_rate_limit(reqs[o[4]])
+            if (int(want.status), want.remaining) == o[:2]:
+                break
+            restore(keep)
+        else:
+            break
+        out.append(o)
+        queues[o[3]].pop(0)
+        if not queues[o[3]]:
+            del queues[o[3]]
+    restore(before)
+    return out + [o for q in queues.values() for o in q]
+
+
 def replay_sample(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
                   set_aside: np.ndarray, extra_crowded: np.ndarray,
-                  v: Verdict) -> None:
-    """Replay every answer on the sampled keys through core/pymodel.py."""
+                  v: Verdict, always: Optional[np.ndarray] = None) -> None:
+    """Replay every answer on the sampled keys through core/pymodel.py.
+    The keys of `always` (a skewed traffic's hottest) are in the sample
+    whatever the seed draws."""
     from gubernator_tpu.core import clock as clock_mod
     from gubernator_tpu.core.pymodel import PyRateLimiter
     from gubernator_tpu.core.types import (
@@ -130,6 +179,8 @@ def replay_sample(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
     plain = ~uni.is_global[a.key]
     modulus = max(1, int(plain.sum()) // TARGET_SAMPLE)
     pick = plain & (a.key % modulus == seed % modulus)
+    if always is not None and modulus > 1:
+        pick |= plain & np.isin(a.key, always)
     if len(set_aside):
         pick &= ~np.isin(a.key, set_aside)
     idx = np.flatnonzero(pick)
@@ -155,17 +206,19 @@ def replay_sample(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
         leaky = int(uni.algo[k]) == ALGO_LEAKY
         hkey = key_string(kid)
         weak = bool(uni.crowded[k]) or int(uni.gbucket[k]) in crowded_set
-        req = RateLimitReq(
-            name=hkey[:9], unique_key=hkey[10:], hits=1, limit=uni.limit,
-            duration=dur,
-            algorithm=(Algorithm.LEAKY_BUCKET if leaky
-                       else Algorithm.TOKEN_BUCKET),
-        )
+        algorithm = (Algorithm.LEAKY_BUCKET if leaky
+                     else Algorithm.TOKEN_BUCKET)
+        reqs = {
+            h: RateLimitReq(
+                name=hkey[:9], unique_key=hkey[10:], hits=h,
+                limit=uni.limit, duration=dur, algorithm=algorithm,
+            ) for h in np.unique(a.hits[rows]).tolist()
+        }
         model.cache.clear()
         created = None  # wall bounds of the RPC that created the bucket
         if uni.resident[k]:
             model.cache[hkey] = CacheItem(
-                key=hkey, algorithm=req.algorithm,
+                key=hkey, algorithm=algorithm,
                 expire_at=t0_ms + dur, limit=uni.limit, duration=dur,
                 remaining=(float(uni.remaining0[k]) if leaky
                            else int(uni.remaining0[k])),
@@ -189,21 +242,26 @@ def replay_sample(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
                 ambiguous += len(grp)
                 break  # later answers depend on an unknowable eviction
             obs = [(int(a.status[r]), int(a.remaining[r]),
-                    int(a.reset_time[r]), int(a.rpc[r])) for r in grp]
-            # In-order duplicates: within one RPC the canonical order.
+                    int(a.reset_time[r]), int(a.rpc[r]), int(a.hits[r]))
+                   for r in grp]
+            # In-order duplicates: one request repeated inside one RPC is
+            # answered in the canonical order; an RPC that mixes peeks
+            # and spends is held to its order by the replay itself.
             per_rpc: Dict[int, List[tuple]] = {}
             for o in obs:
                 per_rpc.setdefault(o[3], []).append(o)
             for q, lst in per_rpc.items():
-                if lst != _canonical(lst):
+                if len({o[4] for o in lst}) == 1 and lst != _canonical(lst):
                     v.bad("out_of_order_duplicates", key=hkey, rpc=q,
                           got=[o[:2] for o in lst])
             if len(rpcs) > 1:
-                obs = _canonical(obs)
+                obs = (_canonical(obs) if len(reqs) == 1
+                       else linearize(model, reqs, hkey, obs))
             lo = min(int(rec["wall_send"][q]) for q in rpcs)
             hi = max(int(rec["wall_recv"][q]) for q in rpcs)
             for o in obs:
                 fresh = hkey not in model.cache
+                req = reqs[o[4]]
                 want = model.get_rate_limit(req)
                 if weak and not fresh and (
                     (int(want.status), want.remaining) != o[:2]

@@ -11,8 +11,9 @@ everything a run observed into one namespace and evaluates the file:
                           whose labels include these
 
 `read.kind` "ratio": scale * sum(num) / sum(den), over the window's
-difference when "delta" is true.  "code": bench/readers/<name>.py's
-read(ctx, spec).  A reader that finds nothing to read returns nothing and
+difference when "delta" is true.  "code": read(ctx, spec) of
+bench/readers/<name>.py, or of the reader the file names under
+`read.reader`, so that a metric of another cell's kind brings no code.  A reader that finds nothing to read returns nothing and
 the metric is left out of the line.
 """
 from __future__ import annotations
@@ -90,7 +91,7 @@ def evaluate(spec: dict, ctx: dict) -> Optional[float]:
     """The metric's value, or None when there is nothing to read."""
     read = spec["read"]
     if read["kind"] == "code":
-        path = os.path.join(spec_mod.BENCH, "readers", spec["name"] + ".py")
+        path = spec_mod.reader_path(spec)
         mod_spec = importlib.util.spec_from_file_location(
             "bench_reader_" + re.sub(r"\W", "_", spec["name"]), path
         )
@@ -101,6 +102,6 @@ def evaluate(spec: dict, ctx: dict) -> Optional[float]:
     delta = bool(read.get("delta"))
     num = _side(read["num"], snaps, delta)
     den = _side(read["den"], snaps, delta) if read.get("den") else 1.0
-    if num is None or den is None or den == 0:
-        return None
+    if num is None or den is None or den == 0 or num != num:
+        return None         # nothing there, or a reading that is no number
     return float(read.get("scale", 1.0)) * num / den

@@ -15,6 +15,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Sequence, Tuple
 
+from lib.spec import can_peek
+
 
 def outstanding_bound(traffic: dict) -> int:
     """Most RPCs the generator ever has outstanding."""
@@ -28,11 +30,17 @@ def round_lane_bounds(traffic: dict, universe: dict, batch: int,
     """Per lane of the program, the most lanes round r of one drain can
     hold on one shard (all keys on one shard is the worst case).
 
-    mach    plain keys.  Every duplicate group of this traffic (same
-            limit, duration and algorithm, hits > 0) is served by the host
-            cascade from ONE read lane, so rounds beyond the first exist
-            only when a shard's distinct keys exceed `batch`; one spare
-            small round is warmed beyond that.
+    mach    plain keys.  Where every check has hits > 0, every duplicate
+            group of this traffic (same limit, duration and algorithm) is
+            served by the host cascade from ONE read lane, so rounds
+            beyond the first exist only when a shard's distinct keys
+            exceed `batch`; one spare small round is warmed beyond that.
+            Where the traffic file can send hits 0, a duplicate group that
+            holds a peek is NOT cascaded (fastpath._plan_cascade): each
+            occurrence takes a lane, occurrence k in a later round than
+            k-1, so a drain can have as many rounds as checks and round r
+            holds at most total/(r+1) lanes (rounds never grow, so r+1
+            rounds of c lanes need (r+1) x c checks).
     engine  GLOBAL keys (mesh).  An RPC's duplicates share a lane; the same
             key in r+1 different RPCs of a drain reaches round r, so round
             r holds at most total/(r+1) lanes.
@@ -42,11 +50,14 @@ def round_lane_bounds(traffic: dict, universe: dict, batch: int,
     n_global = int(universe.get("global_keys", 0))
     g_per_rpc = int(traffic.get("global_per_rpc", 0)) if n_global else 0
     total = rpcs * (per_rpc - g_per_rpc)
-    mach = []
-    while total > 0:
-        mach.append(min(batch, total))
-        total -= batch
-    mach.append(smallest_tier)
+    if can_peek(traffic):
+        mach = [min(batch, total // (r + 1)) for r in range(total)]
+    else:
+        mach = []
+        while total > 0:
+            mach.append(min(batch, total))
+            total -= batch
+        mach.append(smallest_tier)
     lanes = {"mach": mach}
     if g_per_rpc:
         g_total = rpcs * g_per_rpc
@@ -66,16 +77,17 @@ def tier_sequences(round_lanes: Sequence[int],
     tiers = sorted(tiers)
     below = {t: (tiers[i - 1] if i else 0) for i, t in enumerate(tiers)}
     out: List[Tuple[int, ...]] = []
-
-    def extend(prefix: Tuple[int, ...]) -> None:
+    # Depth first without recursion: a peeking traffic's bound is hundreds
+    # of rounds long.
+    stack: List[Tuple[int, ...]] = [()]
+    while stack:
+        prefix = stack.pop()
         r = len(prefix)
         if r >= 2:
             out.append(prefix)
         if r >= len(round_lanes):
-            return
-        for t in tiers:
+            continue
+        for t in reversed(tiers):
             if round_lanes[r] > below[t] and (not prefix or t <= prefix[-1]):
-                extend(prefix + (t,))
-
-    extend(())
+                stack.append(prefix + (t,))
     return out

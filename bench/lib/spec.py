@@ -50,6 +50,14 @@ def layer_metric_path(name: str) -> str:
     return os.path.join(BENCH, "layer_metrics", name + ".json")
 
 
+def reader_path(m: dict) -> str:
+    """The code of a metric whose reader is code: the file its data file
+    names under `read.reader`, else the one of its own name."""
+    return os.path.join(
+        BENCH, "readers", m["read"].get("reader", m["name"]) + ".py"
+    )
+
+
 def workload(bm: dict, name: str) -> dict:
     for w in bm["workloads"]:
         if w["name"] == name:
@@ -70,6 +78,85 @@ def _need(cond: bool, msg: str) -> None:
         raise SpecError(msg)
 
 
+KEY_FORMS = ('{"distribution": "uniform"}', '{"distribution": "zipfian", '
+             '"constant": 0 < c < 1, "scramble": true | false}')
+HITS_FORMS = ("a whole number >= 0", '{"values": [whole numbers >= 0], '
+              '"weights": [whole numbers >= 1]} of equal length')
+ARRIVAL_FORMS = ('{"process": "poisson", "rate_rpc_per_s": r > 0}',
+                 '{"process": "square", "period_s", "burst_s", '
+                 '"burst_start_s" (0 if absent), "base_rate_rpc_per_s", '
+                 '"burst_rate_rpc_per_s"} with the burst inside the period '
+                 "and warm_in_s a whole number of periods")
+
+
+def _whole(x) -> bool:
+    return isinstance(x, int) and not isinstance(x, bool)
+
+
+def _number(x) -> bool:
+    return isinstance(x, (int, float)) and not isinstance(x, bool)
+
+
+def check_keys(k, where: str) -> None:
+    known = f"{where}: keys is none of the known forms: " + "; ".join(
+        KEY_FORMS)
+    _need(isinstance(k, dict), known)
+    kind = k.get("distribution")
+    if kind == "uniform":
+        _need(set(k) == {"distribution"}, known)
+    elif kind == "zipfian":
+        _need(set(k) <= {"distribution", "constant", "scramble"}
+              and _number(k.get("constant")) and 0 < k["constant"] < 1
+              and isinstance(k.get("scramble", False), bool), known)
+    else:
+        raise SpecError(known)
+
+
+def check_hits(h, where: str) -> None:
+    known = f"{where}: hits is none of the known forms: " + "; ".join(
+        HITS_FORMS)
+    if isinstance(h, dict):
+        v, w = h.get("values"), h.get("weights")
+        _need(set(h) == {"values", "weights"} and isinstance(v, list)
+              and isinstance(w, list) and len(v) == len(w) >= 1
+              and all(_whole(x) and x >= 0 for x in v)
+              and all(_whole(x) and x >= 1 for x in w)
+              and len(set(v)) == len(v), known)
+    else:
+        _need(_whole(h) and h >= 0, known)
+
+
+def can_peek(t: dict) -> bool:
+    """Whether the traffic can send `hits` 0: a duplicate group holding a
+    peek is not served by the host cascade (bench/lib/shapes.py)."""
+    h = t.get("hits", 1)
+    return 0 in (h["values"] if isinstance(h, dict) else [h])
+
+
+def check_arrivals(t: dict, where: str) -> None:
+    known = f"{where}: arrivals is none of the known forms: " + "; ".join(
+        ARRIVAL_FORMS)
+    a = t.get("arrivals")
+    _need(isinstance(a, dict), known)
+    if a.get("process") == "poisson":
+        _need(set(a) == {"process", "rate_rpc_per_s"}
+              and _number(a["rate_rpc_per_s"]) and a["rate_rpc_per_s"] > 0,
+              known)
+    elif a.get("process") == "square":
+        need = {"process", "period_s", "burst_s", "base_rate_rpc_per_s",
+                "burst_rate_rpc_per_s"}
+        _need(need <= set(a) <= need | {"burst_start_s"}
+              and all(_number(a[k]) and a[k] > 0 for k in need - {"process"})
+              and _number(a.get("burst_start_s", 0))
+              and a.get("burst_start_s", 0) >= 0
+              and a.get("burst_start_s", 0) + a["burst_s"] <= a["period_s"],
+              known)
+        periods = float(t.get("warm_in_s", 0)) / a["period_s"]
+        _need(periods >= 1 and periods == int(periods), known)
+    else:
+        raise SpecError(known)
+
+
 def check_traffic(t: dict, where: str) -> None:
     _need(t.get("loop") in ("open", "closed"), f"{where}: loop kind")
     _need(float(t.get("deadline_s", 0)) >= 5.0,
@@ -80,15 +167,12 @@ def check_traffic(t: dict, where: str) -> None:
           f"{where}: checks_per_rpc")
     _need(int(t.get("connections", 0)) >= 1, f"{where}: connections")
     _need(float(t.get("warm_in_s", 0)) > 0, f"{where}: warm_in_s")
-    _need(t.get("keys", {}).get("distribution") == "uniform",
-          f"{where}: key distribution")
+    check_keys(t.get("keys"), where)
+    check_hits(t.get("hits", 1), where)
     if t["loop"] == "open":
         _need(int(t.get("outstanding_cap", 0)) >= 1,
               f"{where}: an open loop needs an outstanding cap")
-        a = t.get("arrivals", {})
-        _need(a.get("process") == "poisson"
-              and float(a.get("rate_rpc_per_s", 0)) > 0,
-              f"{where}: arrivals")
+        check_arrivals(t, where)
     else:
         _need(int(t.get("in_flight", 0)) >= 1, f"{where}: in_flight")
         _need(float(t.get("pool_rpc_per_s", 0)) > 0,
@@ -111,16 +195,17 @@ def check_config(c: dict, where: str) -> None:
 
 def check_layer_metric(m: dict, where: str) -> None:
     for k in ("name", "layer", "unit", "better", "source", "moves",
-              "workloads", "read", "what"):
+              "read", "what"):
         _need(k in m, f"{where}: missing {k!r}")
+    _need("workloads" not in m, f"{where}: BENCHMARK.json alone says "
+          "which cells report a metric")
     kind = m["read"].get("kind")
     _need(kind in ("ratio", "code"), f"{where}: read.kind")
     if kind == "ratio":
         _need(bool(m["read"].get("num")), f"{where}: read.num")
     else:
-        _need(os.path.isfile(
-            os.path.join(BENCH, "readers", m["name"] + ".py")
-        ), f"{where}: no bench/readers/{m['name']}.py")
+        _need(os.path.isfile(reader_path(m)),
+              f"{where}: no {os.path.relpath(reader_path(m), REPO)}")
 
 
 def check_benchmark(bm: dict) -> None:
@@ -182,10 +267,13 @@ def check_benchmark(bm: dict) -> None:
         _need(m["moves"] in e2e, f"{m['name']}: moves {m['moves']!r}")
         spec = load_json(layer_metric_path(m["name"]))
         check_layer_metric(spec, "layer_metrics/" + m["name"])
-        for k in ("name", "unit", "better", "source", "layer", "moves",
-                  "workloads"):
+        for k in ("name", "unit", "better", "source", "layer", "moves"):
             _need(spec[k] == m.get(k), f"{m['name']}: {k} differs from "
                   "its data file")
+        for cell in m.get("workloads", []):
+            _need(any(e["name"] == m["moves"] for e in
+                      metrics_of(bm, "end_to_end", cell)),
+                  f"{m['name']}: {cell} does not report {m['moves']}")
     for w in bm["workloads"]:
         mine = metrics_of(bm, "end_to_end", w["name"])
         _need(len(mine) >= 2 and any(m["name"] == "setup_s" for m in mine),

@@ -17,8 +17,12 @@ host's threads are lines of the plane "/host:CPU".
   modules      program launches: {short name: [count, seconds]}.
   ops          op time: {short name: [count, seconds]}, names cut to the
                HLO result name ("fusion.7", "while.36", "all-reduce.1").
-  idle_gaps    the longest gaps of chip 0, each named by the host event
-               that overlaps it most (the most specific on a tie).
+  idle_gaps    the longest gaps of chip 0, over every host thread: each
+               named by the shortest of the program's own `gub.*` stages
+               (runtime/tracing.py) that covers the whole gap, else by the
+               host event that overlaps it most (the most specific on a
+               tie) — a stage outranks the runtime events nested in it.
+  host_stages  every `gub.*` event traced: {name: [count, seconds]}.
 """
 from __future__ import annotations
 
@@ -37,6 +41,7 @@ COLLECTIVES = (
     "collective-permute", "collective-broadcast",
 )
 MAX_GAPS = 2000
+STAGE_PREFIX = "gub."
 
 
 def short_op(name: str) -> str:
@@ -91,18 +96,25 @@ def _totals(names, starts, ends, shorten) -> Dict[str, List[float]]:
 
 
 def name_gaps(gap_s, gap_e, host) -> Dict[str, float]:
-    """Seconds of idle gap by the host event overlapping each gap most."""
+    """Seconds of idle gap by what the host was doing: the shortest stage
+    of the program's own that covers the gap, else the host event
+    overlapping it most."""
     h_names, h_s, h_e = host
     out: Dict[str, float] = {}
     keep = np.argsort(gap_e - gap_s)[::-1][:MAX_GAPS]
     h_len = h_e - h_s
+    is_stage = np.array([n.startswith(STAGE_PREFIX) for n in h_names],
+                        dtype=bool)
     for g in keep:
         a, b = gap_s[g], gap_e[g]
         name = "no_host_span"
         if len(h_s):
             ov = np.minimum(h_e, b) - np.maximum(h_s, a)
             best = ov.max()
-            if best > 0:
+            covering = np.flatnonzero(is_stage & (ov >= (b - a) * 0.999))
+            if len(covering):
+                name = stable(h_names[covering[np.argmin(h_len[covering])]])
+            elif best > 0:
                 cand = np.flatnonzero(ov >= best * 0.999)
                 name = stable(h_names[cand[np.argmin(h_len[cand])]])
         out[name] = out.get(name, 0.0) + (b - a) / 1e9
@@ -120,15 +132,18 @@ def reduce_xplane(path: str) -> dict:
         m = DEVICE_PLANE.match(plane.name)
         if m is None and plane.name != "/host:CPU":
             continue
-        lines = {ln.name: _events(ln) for ln in plane.lines}
         if m is None:
-            if lines:
+            # Every thread is a line, and every Python thread's line has
+            # the same name: take them by position, not by name.
+            evs = [_events(ln) for ln in plane.lines]
+            if evs:
                 host = (
-                    [n for v in lines.values() for n in v[0]],
-                    np.concatenate([v[1] for v in lines.values()]),
-                    np.concatenate([v[2] for v in lines.values()]),
+                    [n for v in evs for n in v[0]],
+                    np.concatenate([v[1] for v in evs]),
+                    np.concatenate([v[2] for v in evs]),
                 )
             continue
+        lines = {ln.name: _events(ln) for ln in plane.lines}
         ops = lines.get("XLA Ops") or ([], np.zeros(0), np.zeros(0))
         mods = lines.get("XLA Modules") or ([], np.zeros(0), np.zeros(0))
         busy, ms, me = union_seconds(ops[1], ops[2])
@@ -162,6 +177,7 @@ def reduce_xplane(path: str) -> dict:
     coll = sum(
         s for k, (_c, s) in ops.items() if k.startswith(COLLECTIVES)
     )
+    staged = [i for i, n in enumerate(host[0]) if n.startswith(STAGE_PREFIX)]
     top = lambda t: sorted(  # noqa: E731
         ([k, v[1]] for k, v in t.items()), key=lambda kv: -kv[1]
     )[:10]
@@ -171,6 +187,10 @@ def reduce_xplane(path: str) -> dict:
         "window_s": window_s,
         "collective_s": coll,
         "modules": modules,
+        "host_stages": _totals(
+            [host[0][i] for i in staged], host[1][staged], host[2][staged],
+            stable,
+        ),
         "device_ops": top(ops),
         "idle_gaps": sorted(
             ([k, v] for k, v in gaps.items()), key=lambda kv: -kv[1]

@@ -1,6 +1,7 @@
-"""idle_named_share.open: of the idle-gap seconds bench/lib/trace.py lists
-for chip 0 (each gap named by the most specific host event over it), the
-share under the program's own stage names.
+"""idle_named_share.* (every kind of cell reads through this file): of the
+idle-gap seconds bench/lib/trace.py lists for chip 0 (each gap named by the
+shortest gub.* stage covering it, else by the most specific host event over
+it), the share under the program's own stage names.
 
 `spec["read"]["prefix"]` is the prefix those names carry ("gub.", the stage
 ledger's profiler annotations, runtime/tracing.py).  A program without the

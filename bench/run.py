@@ -9,8 +9,10 @@ every reachable fetch program warmed), check special cases over the wire,
 drive the cell's traffic from a client process that never imports JAX
 (bench/client.py) through a warm-in and a window of --seconds, compare what
 came back with core/pymodel.py, and print — as the LAST line of stdout —
-{"correct", "attempted", "failed", "metrics", "device"[, "breakdown"]}.
-`attempted` and `failed` count checks.  --trace 0 reports the cell's
+{"correct", "attempted", "failed", "metrics", "device", "window"[,
+"breakdown"], "compared"}: `window` is what the generator saw (for sweeps),
+`compared` every number compared beside its limit, also the last lines of
+stderr.  `attempted` and `failed` count checks.  --trace 0 reports the cell's
 end-to-end metrics, --trace 1 its per-layer metrics from a run that also
 traces a short span of the window with the profiler.
 
@@ -58,6 +60,7 @@ READY_TIMEOUT_S = 1100.0
 # part of the window before it.
 TRACE_SPAN_S = 2.0
 TRACE_TAIL_S = 3.0        # the span starts this long before the window ends
+HOT_KEYS = 1000           # of a skewed traffic: always in the replayed sample
 
 
 class Refused(Exception):
@@ -182,10 +185,12 @@ class Compare:
 
     def __init__(self) -> None:
         self.ok = True
+        self.seen: dict = {}
 
     def __call__(self, name: str, value, limit=0) -> None:
         good = value <= limit
         self.ok &= bool(good)
+        self.seen[name] = [value, limit]
         print(f"compare {name}: {value} (limit {limit}) "
               f"{'ok' if good else 'FAILED'}", flush=True)
 
@@ -320,7 +325,8 @@ def _run(args, tmp: str) -> dict:
         [sys.executable, os.path.join(BENCH, "serve.py"),
          "--preload", os.path.join(tmp, "preload.npz"),
          "--lanes", json.dumps(lanes)]
-        + (["--control", args.control] if args.control else []),
+        + (["--control", args.control] if args.control else [])
+        + (["--step-bytes"] if args.trace else []),
         server_env(args, cfg, grpc_addr, http_addr),
         os.path.join(out_dir, "serve.log"),
     )
@@ -486,9 +492,11 @@ def _run(args, tmp: str) -> dict:
     oracle.screen(answers, uni, verdict)
     aside = oracle.unanswered_keys(plan, rec)
     t_chk = time.monotonic()
+    skewed = traffic["keys"]["distribution"] != "uniform"
     oracle.replay_sample(
         answers, rec, uni, ready["preload"]["t0_ms"], args.seed, aside,
         aux_buckets, verdict,
+        always=schedule.hottest_keys(plan, HOT_KEYS) if skewed else None,
     )
     log(f"checker: {verdict.notes} in {time.monotonic() - t_chk:.1f}s; "
         f"{len(aside)} keys set aside for unanswered RPCs"
@@ -540,12 +548,20 @@ def _run(args, tmp: str) -> dict:
         "correct": compare.ok, "attempted": stats["attempted"],
         "failed": stats["failed"], "metrics": {}, "device": device,
     }
+    # What the window's generator saw, for sweeps (the driver ignores it).
+    result["window"] = {
+        k: stats[k] for k in (
+            "rpc_p50_ms", "rpc_p95_ms", "rpc_p99_ms", "gen_late_p99_ms",
+            "cap_waited", "arrivals", "decisions_per_s",
+        ) if k in stats
+    }
     e2e = dict(stats, setup_s=setup_s)
     if not args.trace:
         for m in spec.metrics_of(bm, "end_to_end", args.workload):
             result["metrics"][m["name"]] = {
                 "value": e2e[m["name"]], "unit": m["unit"],
             }
+        result["compared"] = compare.seen
         return result
     trace = reduce_trace(trace_dir) if tsnaps else {}
     # Everything not read from the trace: the window up to the profiler's
@@ -580,7 +596,8 @@ def _run(args, tmp: str) -> dict:
             {"vars": s.vars, "metrics": s.metrics, "flat": flat}
             for s in (snap0, snap1)
         ),
-        "flat": flat, "trace": trace, "device": device, "ways": uni.ways,
+        "flat": flat, "trace": trace, "device": device,
+        "step_bytes": ready.get("step_bytes"),
     }
     for m in spec.metrics_of(bm, "per_layer", args.workload):
         value = readers.evaluate(
@@ -590,6 +607,7 @@ def _run(args, tmp: str) -> dict:
             result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
     log(f"end-to-end numbers of this traced run (not reported): "
         f"{ {k: v for k, v in e2e.items() if isinstance(v, float)} }")
+    result["compared"] = compare.seen
     return result
 
 
@@ -620,6 +638,11 @@ def main() -> int:
     except (Refused, spec.SpecError) as e:
         print(f"bench/run.py: no result: {e}", file=sys.stderr)
         return 1
+    # Every number compared beside its limit: the last lines of stderr,
+    # and the last key of the result line.
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

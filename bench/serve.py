@@ -201,6 +201,41 @@ def warm_fetch_shapes(service, lanes: dict) -> dict:
     return out
 
 
+def step_bytes(service) -> dict:
+    """`bytes accessed` of the compiled step program at the smallest tier,
+    from the compiler's own cost analysis: what one launch moves at least
+    (the table-length operations are the same at every tier).  Lowering
+    traces the kernel again and the compile is a cache hit, a second or
+    two: asked for by traced runs only.  Nothing where it cannot be
+    read."""
+    import jax
+
+    backend = service.backend
+    try:
+        # The arguments as the served path passes them, so that the
+        # compile is the served program's and a cache hit.
+        t = min(backend._tiers)
+        now = np.int64(backend.clock.millisecond_now())
+        if backend.cfg.num_shards > 1:
+            q = jax.device_put(
+                np.zeros((12, backend.cfg.num_shards, t), dtype=np.int64),
+                backend._psharding,
+            )
+            lowered = backend._step_packed.lower(backend.table, q, now)
+        else:
+            step = backend._step_packed_q          # functools.partial
+            lowered = step.func.lower(
+                backend.table, np.zeros((12, t), dtype=np.int64), now,
+                **step.keywords,
+            )
+        cost = lowered.compile().cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        return {"tier": int(t), "bytes_accessed": float(cost["bytes accessed"])}
+    except (AttributeError, TypeError, KeyError, IndexError) as e:
+        log.warning("step bytes not read: %s: %s", type(e).__name__, e)
+        return {}
+
+
 def memory_stats(service) -> dict:
     import jax
 
@@ -240,6 +275,10 @@ async def run(args) -> None:
     report["fetch_shapes"] = await loop.run_in_executor(
         None, warm_fetch_shapes, daemon.service, json.loads(args.lanes)
     )
+    if args.step_bytes:
+        report["step_bytes"] = await loop.run_in_executor(
+            None, step_bytes, daemon.service
+        )
     say(report)
 
     stop = asyncio.Event()
@@ -295,6 +334,8 @@ def main() -> None:
                     help="the harness's handoff file (.npz) to install")
     ap.add_argument("--lanes", default="{}")
     ap.add_argument("--control", default="")
+    ap.add_argument("--step-bytes", action="store_true",
+                    help="report the compiled step's bytes accessed")
     args = ap.parse_args()
     apply_control(args.control)
     asyncio.run(run(args))

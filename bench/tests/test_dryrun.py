@@ -15,13 +15,13 @@ import pytest
 from lib import spec
 
 
-def dry_run(tmp_path, cell, *extra):
+def dry_run(tmp_path, cell, *extra, seconds="2"):
     env = dict(os.environ)
     env.pop("BENCH_RUN", None)
     env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     p = subprocess.run(
         [sys.executable, os.path.join(spec.BENCH, "run.py"),
-         "--workload", cell, "--seed", "2246822519", "--seconds", "2",
+         "--workload", cell, "--seed", "2246822519", "--seconds", seconds,
          "--trace", "0", "--platform", "cpu", "--slots", "65536",
          "--keys", "39000", "--out", str(tmp_path / "out"), *extra],
         env=env, cwd=spec.REPO, capture_output=True, text=True, timeout=600,
@@ -43,21 +43,35 @@ def test_sound_dry_run_fails_only_for_not_being_a_tpu(tmp_path):
             "fastpath_fallbacks_grown"} <= compared
     assert result["correct"] is False
     assert result["device"]["platform"] == "cpu"
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "window", "compared"]
+    assert result["compared"]["not_a_tpu_run"] == [1, 0]
+    assert result["compared"]["wrong_answers"] == [0, 0]
     assert set(result["metrics"]) == {"decisions_per_s", "setup_s"}
     assert result["attempted"] > 0 and result["failed"] == 0
 
 
+@pytest.mark.parametrize("cell", [
+    "exact10m.rpc2.open", "exact10m.zipf99.rpc16.closed",
+])
 @pytest.mark.parametrize("control, must_fail", [
     ("alter", "wrong_answers"),
     ("f32", "wrong_reset_time"),
 ])
-def test_broken_daemon_comes_out_not_correct(tmp_path, control, must_fail):
-    result, failed, _ = dry_run(
-        tmp_path, "exact10m.rpc2.open", "--control", control)
+def test_broken_daemon_comes_out_not_correct(tmp_path, cell, control,
+                                             must_fail):
+    result, failed, _ = dry_run(tmp_path, cell, "--control", control)
     assert must_fail in failed and "wire_check_mismatches" in failed
     assert result["correct"] is False
+    assert result["compared"][must_fail][0] > 0
+
+
+def test_the_zipf_cell_rehearses_sound(tmp_path):
+    result, failed, _ = dry_run(tmp_path, "exact10m.zipf99.rpc16.closed",
+                                seconds="4")
+    assert failed == {"not_a_tpu_run"}
+    assert set(result["metrics"]) == {"decisions_per_s", "setup_s"}
+    assert result["attempted"] > 0 and result["failed"] == 0
 
 
 def test_no_accelerator_means_no_result(tmp_path):

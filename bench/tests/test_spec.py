@@ -89,7 +89,6 @@ def test_peaks_table_knows_the_v5e_and_refuses_the_rest():
     assert p["hbm_bytes_per_s"] == 819e9 and p["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError):
         roofline.peaks("TPU v9000")
-    assert roofline.bytes_per_decision(8) == 512
 
 
 def test_readme_worked_example_is_valid_data():

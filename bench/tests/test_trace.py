@@ -59,19 +59,33 @@ def test_short_names_are_stable():
 
 
 def test_code_readers_read_the_reduction(reduced):
-    flat = {"tracevars:backend.checks": 1000.0}
-    ctx = {"trace": reduced, "flat": flat, "ways": 8,
-           "device": {"kind": "TPU v5 lite"}, "snaps": ({}, {})}
+    ctx = {"trace": reduced, "flat": {}, "snaps": ({}, {}),
+           "device": {"kind": "TPU v5 lite"},
+           "step_bytes": {"tier": 128, "bytes_accessed": 4.0e8}}
     m = spec.load_json(spec.layer_metric_path("step_device_ms.closed"))
     m["read"]["program_regex"] = "bench_small"
     assert readers.evaluate(m, ctx) == pytest.approx(
         reduced["busy_s"] / 10 * 1e3)
+    o = spec.load_json(spec.layer_metric_path("step_device_ms.open"))
+    assert o["read"]["reader"] == "step_device_ms.closed"   # no new code
+    o["read"]["program_regex"] = "bench_small"
+    assert readers.evaluate(o, ctx) == readers.evaluate(m, ctx)
+    # The compiled program's own bytes x launches, over the peak, over
+    # the busy time.
     h = spec.load_json(spec.layer_metric_path("step_hbm_share.closed"))
-    want = 512 * 1000 / 819e9 / reduced["busy_s"] * 100
+    h["read"]["program_regex"] = "bench_small"
+    want = 4.0e8 * 10 / 819e9 / reduced["busy_s"] * 100
     assert readers.evaluate(h, ctx) == pytest.approx(want)
+    assert readers.evaluate(h, dict(ctx, step_bytes={})) is None
     ctx["device"]["kind"] = "unknown chip"
     with pytest.raises(KeyError):
         readers.evaluate(h, ctx)
+    # Rounds per drain: step launches over the drains' own stage events.
+    r = spec.load_json(spec.layer_metric_path("lane_rounds_per_drain.open"))
+    r["read"]["program_regex"] = "bench_small"
+    staged = dict(reduced, host_stages={"gub.lane.pack": [4, 0.001]})
+    assert readers.evaluate(r, dict(ctx, trace=staged)) == 10 / 4
+    assert readers.evaluate(r, ctx) is None    # no stage events traced
     # Nothing traced: the reader returns nothing, the metric is left out.
     assert readers.evaluate(m, dict(ctx, trace={})) is None
 
@@ -102,3 +116,20 @@ def test_ratio_readers_take_the_difference_over_the_window():
     assert get("lane_checks_per_drain.closed") == pytest.approx(400 / 8)
     assert get("rpc_tail_p99_ms.open") == 7.5
     assert get("backend_step_ms.closed") is None     # series absent
+
+
+def test_a_stage_outranks_the_runtime_events_nested_in_it():
+    """Idle gaps are named over every host thread; the shortest gub.* stage
+    that covers a gap names it, whatever runtime event sits inside."""
+    gap_s, gap_e = np.array([100.0, 1000.0]), np.array([200.0, 1100.0])
+    host = (
+        ["gub.lane.drain", "gub.backend.d2h_wait", "ReadSyncFlag",
+         "XlaLinearize", "gub.lane.pack"],
+        np.array([0.0, 90.0, 110.0, 1000.0, 1050.0]),
+        np.array([500.0, 210.0, 190.0, 1100.0, 1090.0]),
+    )
+    named = trace.name_gaps(gap_s, gap_e, host)
+    # Gap 1: d2h_wait covers it (ReadSyncFlag overlaps more narrowly).
+    # Gap 2: no stage covers it whole, so the most-overlapping event.
+    assert named == {"gub.backend.d2h_wait": 100 / 1e9,
+                     "XlaLinearize": 100 / 1e9}
