@@ -871,6 +871,8 @@ class Daemon:
                 "reread_batches": s.global_mgr.reread_batches,
                 "reread_keys": s.global_mgr.reread_keys,
             }
+            if s.global_engine is not None:
+                out["global"]["engine"] = s.global_engine.debug_vars()
             out["multi_region_sends"] = s.multi_region_mgr.region_sends
             out["peers"] = {
                 p.info().grpc_address: len(p.last_errors())

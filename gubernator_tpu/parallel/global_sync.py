@@ -89,7 +89,9 @@ def make_global_sync_step(mesh, ways: int):
     """Build the jitted collective sync:
     (auth, cache, delta, now) -> (auth', cache')."""
 
-    def _local(auth: SlotTable, cache: SlotTable, delta: DeltaGrid, now):
+    def _global_sync_a2a(
+        auth: SlotTable, cache: SlotTable, delta: DeltaGrid, now
+    ):
         d = DeltaGrid(*[a[0] for a in delta])  # local [n_dst, D]
         # sendHits: deltas travel to their owning shard over ICI.
         recv = DeltaGrid(
@@ -159,7 +161,7 @@ def make_global_sync_step(mesh, ways: int):
         return auth, cache
 
     sharded = _shard_map(
-        _local,
+        _global_sync_a2a,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P()),
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -208,9 +210,15 @@ def make_global_sync_step_psum(mesh, ways: int):
     own row (`axis_index`), applies it to its auth shard, and the
     broadcast rows all_gather into the replicated cache exactly as in
     the a2a step.  Differentially pinned bit-identical to the a2a step
-    (tests/test_differential.py)."""
+    (tests/test_differential.py).
 
-    def _local(auth: SlotTable, cache: SlotTable, delta: DeltaGrid, now):
+    The shard_map body's name is the program's in a profiler trace:
+    `jit__global_sync`, apart from the serve step's `jit__local`
+    (bench/layer_metrics/global_sync_device_ms.mesh.json reads it)."""
+
+    def _global_sync(
+        auth: SlotTable, cache: SlotTable, delta: DeltaGrid, now
+    ):
         d = DeltaGrid(*[a[0] for a in delta])  # local [n_dst, D]
 
         # sendHits, as ONE collective: per-source grids are disjoint by
@@ -276,7 +284,7 @@ def make_global_sync_step_psum(mesh, ways: int):
         return auth, cache
 
     sharded = _shard_map(
-        _local,
+        _global_sync,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P()),
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -378,6 +386,7 @@ class GlobalEngine:
         self.syncs = 0
         self.sync_keys = 0
         self.dropped = 0
+        self.sync_bytes_accessed: Optional[float] = None  # set by warmup
         # Post-sync hook: called with the synced pending dict (may run on a
         # device-executor thread).  The service uses it to bridge collective
         # syncs to the RPC tier — broadcasting owner-authoritative statuses
@@ -588,27 +597,38 @@ class GlobalEngine:
             return 0
         # Low-rate, so it carries the clock anchor (time.time_ns() at
         # its start, as an argument of the profiler event).
-        with self.b._stages.stage("global.sync_tick", "global", anchor=True):
-            return self._sync_pending(pending)
+        with self.b._stages.stage(
+            "global.sync_tick", "global", anchor=True
+        ) as tick:
+            tick.tally(keys=len(pending))
+            return self._sync_pending(pending, tick)
 
-    def _sync_pending(self, pending) -> int:
-        now_dt = self.clock.now()
-        chunks = self._build_chunks(pending, now_dt)
-        now = np.int64(self.clock.millisecond_now())
+    def _sync_pending(self, pending, tick) -> int:
+        stages = self.b._stages
         # Transfers don't read table state — stage them BEFORE taking the
         # locks so concurrent checks only block for the sync steps, not
         # the host->device puts.
-        staged = [
-            DeltaGrid(*[jax.device_put(a, self.b._bsharding) for a in grid])
-            for grid in chunks
-        ]
+        with stages.stage("global.build_chunks", "global"):
+            chunks = self._build_chunks(pending, self.clock.now())
+            now = np.int64(self.clock.millisecond_now())
+            staged = [
+                DeltaGrid(
+                    *[jax.device_put(a, self.b._bsharding) for a in grid]
+                )
+                for grid in chunks
+            ]
+        tick.tally(chunks=len(chunks))
         cap_keys = cap_token = wt_seq = None
         # Lock order: auth (backend) before cache (self).
+        # Under its own name: `backend.lock_wait` is what a drain waits.
+        lock_wait = stages.stage("global.wait_locks", "global")
         with self.b._lock, self._lock:
+            lock_wait.end()
             for sharded in staged:
-                self.b.table, self.cache_table = self._sync_step(
-                    self.b.table, self.cache_table, sharded, now
-                )
+                with stages.stage("global.sync_step", "global"):
+                    self.b.table, self.cache_table = self._sync_step(
+                        self.b.table, self.cache_table, sharded, now
+                    )
             if self.b.store is not None:
                 # Post-sync auth rows -> Store.on_change (the write-through
                 # of algorithms.go:154-158, batch-granular at the sync
@@ -767,6 +787,41 @@ class GlobalEngine:
                 self.cache_table, _ = self._ingest(
                     self.cache_table, batch, now
                 )
+            self.sync_bytes_accessed = self._sync_cost(sharded, now)
+
+    def _sync_cost(self, sharded: DeltaGrid, now) -> Optional[float]:
+        """`bytes accessed` of the sync program as compiled for one
+        device, from the compiler's own cost analysis: what one chunk's
+        launch moves through HBM (for a roofline share; /debug/vars
+        `global.engine`).  Lowered with the arguments the tick passes, so
+        the compile is the served program's and a compile-cache hit.
+        None where the compiler does not say."""
+        try:
+            cost = self._sync_step.lower(
+                self.b.table, self.cache_table, sharded, now
+            ).compile().cost_analysis()
+            cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+            return float(cost["bytes accessed"])
+        except (AttributeError, TypeError, KeyError, IndexError):
+            return None
+
+    def debug_vars(self) -> dict:
+        """The /debug/vars `global.engine` block: the tick's counts, and
+        the geometry and compiled cost of one chunk's sync program."""
+        with self._lock:
+            out = {
+                "syncs": self.syncs,
+                "sync_keys": self.sync_keys,
+                "dropped": self.dropped,
+                "pending": len(self.pending),
+            }
+        out["sync_program"] = {
+            "collective": self.collective,
+            "shards": self.n,
+            "delta_slots": self.delta_slots,
+            "bytes_accessed": self.sync_bytes_accessed,
+        }
+        return out
 
     # -- point reads (tests / HealthCheck) -------------------------------
     def _cache_bucket_offset(self, key: str, shard: int) -> int:

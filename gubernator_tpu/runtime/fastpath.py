@@ -1356,6 +1356,9 @@ class FastPath:
                 pend.append(
                     (req, int(hits_sum[j]), int(sh[off + j]))
                 )
+        # What this drain held: checks as the RPCs sent them, and the
+        # device rounds they took.
+        pack.tally(checks=sum(len(e.idx) for e in entries), rounds=n_rounds)
         pack.end()
         resps, want_sync = engine.serve_packed(rounds, pend)
 

@@ -714,7 +714,15 @@ STAGES: Dict[str, str] = {
                         "the host",
     # per tick / process
     "global.sync_tick": "one GLOBAL psum sync: staging, dispatch, "
-                        "write-through read-back",
+                        "write-through read-back; counters keys (pending "
+                        "keys flushed, a lane each), chunks",
+    "global.build_chunks": "inside a tick, before the locks: the pending "
+                           "dict packed into delta grids and their "
+                           "host->device puts",
+    "global.wait_locks": "inside a tick: blocked on backend._lock, then "
+                         "engine._lock (the serve path holds them)",
+    "global.sync_step": "inside a tick, under both locks: the enqueue of "
+                        "one chunk's sync program",
     "xla.compile": "backend compiles seen by jax.monitoring (count, ms)",
 }
 
@@ -737,7 +745,7 @@ class _Cell:
     stage on two pool threads)."""
 
     __slots__ = ("lane", "stage", "trace_name", "count", "ns_total",
-                 "ns_max", "observe")
+                 "ns_max", "observe", "counters")
 
     def __init__(self, lane: str, stage: str, observe=None) -> None:
         if stage not in STAGES:
@@ -749,6 +757,9 @@ class _Cell:
         self.ns_total = 0
         self.ns_max = 0
         self.observe = observe
+        # Named whole-number counters of what the stage worked on (keys
+        # a tick flushed, checks a drain packed): rendered beside count.
+        self.counters: Dict[str, int] = {}
 
     def add(self, ns: int) -> None:
         self.count += 1
@@ -757,6 +768,7 @@ class _Cell:
             self.ns_max = ns
 
 
+_RESERVED = frozenset(("count", "ms_total", "ms_max"))  # a row's own keys
 _COMPILES = _Cell("xla", "xla.compile")  # process-wide, lock-free reads
 
 
@@ -822,6 +834,12 @@ class _Open:
         """The stage's span context when it has a span (armed plane,
         sampled parent), for parenting what runs inside it elsewhere."""
         return self._span.context if self._span is not None else None
+
+    def tally(self, **counts: int) -> None:
+        """Add to the stage's named counters (what this pass worked on);
+        a no-op once the stage has ended."""
+        if self._cell is not None:
+            self._ledger._tally(self._cell, counts)
 
     def end(self, error: Optional[str] = None) -> int:
         cell = self._cell
@@ -903,6 +921,13 @@ class StageLedger:
         if cell.observe is not None:
             cell.observe(ns / 1e9)
 
+    def _tally(self, cell: _Cell, counts: Dict[str, int]) -> None:
+        if not _RESERVED.isdisjoint(counts):
+            raise KeyError(f"a counter may not be named {sorted(_RESERVED)}")
+        with self._lock:
+            for name, n in counts.items():
+                cell.counters[name] = cell.counters.get(name, 0) + int(n)
+
     # -- the primitive -----------------------------------------------------
     def stage(self, stage: str, lane: Optional[str] = None,
               anchor: bool = False) -> _Open:
@@ -953,13 +978,18 @@ class StageLedger:
 
     def debug_vars(self) -> Dict:
         """The /debug/vars `stages` block:
-        stages.<lane>.<stage>.{count, ms_total, ms_max}.  The open
-        empty/occupied interval is counted up to now."""
+        stages.<lane>.<stage>.{count, ms_total, ms_max} and the stage's
+        named counters.  The open empty/occupied interval is counted up
+        to now."""
         with self._lock:
             rows = [
                 (c.lane, c.stage, c.count, c.ns_total, c.ns_max)
                 for c in self._cells.values()
             ]
+            tallies = {
+                (c.lane, c.stage): dict(c.counters)
+                for c in self._cells.values() if c.counters
+            }
         rows.append((
             _COMPILES.lane, _COMPILES.stage, _COMPILES.count,
             _COMPILES.ns_total, _COMPILES.ns_max,
@@ -980,6 +1010,7 @@ class StageLedger:
                 "count": n,
                 "ms_total": round(tot / 1e6, 6),
                 "ms_max": round(mx / 1e6, 6),
+                **tallies.get((lane, stage), {}),
             }
         return out
 

@@ -378,6 +378,149 @@ def test_identities_and_views_on_a_daemon():
     assert tracing.debug_vars() == {"enabled": False}
 
 
+def test_counters_ride_on_a_stage(clock):
+    """`tally` adds named whole numbers to the stage's row, rendered beside
+    count / ms_total / ms_max; a row's own keys are refused."""
+    ledger = tracing.StageLedger()
+    for keys in (3, 5):
+        with ledger.stage("global.sync_tick", "global") as tick:
+            clock.now += 1_000_000
+            tick.tally(keys=keys, chunks=1)
+    row = ledger.debug_vars()["global"]["sync_tick"]
+    assert row == {"count": 2, "ms_total": 2.0, "ms_max": 1.0,
+                   "keys": 8, "chunks": 2}
+    with pytest.raises(KeyError):
+        tick = ledger.stage("global.sync_tick", "global")
+        try:
+            tick.tally(count=1)
+        finally:
+            tick.end()
+    tick.tally(keys=1)          # ended: a no-op
+    assert ledger.debug_vars()["global"]["sync_tick"]["keys"] == 8
+
+
+def test_the_sync_tick_divides_and_its_counters_add_up():
+    """GLOBAL checks over real gRPC into a four-shard mesh daemon: the
+    tick is its chunk building, its wait for the locks and its steps; the
+    tick's counters add up to the keys the engine flushed, the engine
+    lane's to the checks the RPCs sent, and the owners' rows to their
+    hits."""
+    import grpc.aio
+
+    from gubernator_tpu.core.config import DeviceConfig
+    from gubernator_tpu.testing.cluster import Cluster
+
+    rpcs, per_rpc, keys = 40, 50, 700
+    cluster = Cluster.start(1, device=DeviceConfig(
+        num_slots=1 << 14, ways=8, batch_size=128, num_shards=4,
+    ))
+    try:
+        d = cluster.daemon_at(0)
+        eng = d.service.global_engine
+        eng.delta_slots = 32       # 128 keys a chunk: ticks of many chunks
+
+        def payload(i):
+            return pb.GetRateLimitsReq(requests=[
+                pb.RateLimitReq(
+                    name="tick", unique_key=f"t{(i * 37 + j * j) % keys}",
+                    hits=1 + j % 3, limit=1_000_000_000, duration=600_000,
+                    behavior=pb.GLOBAL,
+                )
+                for j in range(per_rpc)
+            ]).SerializeToString()
+
+        async def drive():
+            ch = grpc.aio.insecure_channel(d.grpc_address)
+            rpc = ch.unary_unary("/pb.gubernator.V1/GetRateLimits")
+            sem = asyncio.Semaphore(8)
+
+            async def one(i):
+                async with sem:
+                    raw = await rpc(payload(i))
+                    resp = pb.GetRateLimitsResp.FromString(raw)
+                    assert not any(r.error for r in resp.responses)
+
+            try:
+                await asyncio.gather(*(one(i) for i in range(rpcs)))
+            finally:
+                await ch.close()
+
+        async def owners_rows(names):
+            # The same keys without the flag: the owner's row, hits 0.
+            ch = grpc.aio.insecure_channel(d.grpc_address)
+            try:
+                raw = await ch.unary_unary(
+                    "/pb.gubernator.V1/GetRateLimits"
+                )(pb.GetRateLimitsReq(requests=[
+                    pb.RateLimitReq(name="tick", unique_key=k, hits=0,
+                                    limit=1_000_000_000, duration=600_000)
+                    for k in names
+                ]).SerializeToString())
+            finally:
+                await ch.close()
+            return pb.GetRateLimitsResp.FromString(raw)
+
+        cluster.run(drive(), timeout=120)
+
+        def scrape():
+            return d.metrics.stages.debug_vars(), eng.debug_vars()
+
+        def at_rest(stages, engine_vars):
+            # No tick between its first line and its last: one that has
+            # taken the pending dict has tallied its keys, one that has
+            # counted its sync has yet to close its stage.
+            tick = stages.get("global", {}).get("sync_tick", {})
+            return (engine_vars["pending"] == 0
+                    and tick.get("count") == engine_vars["syncs"]
+                    and tick.get("keys") == engine_vars["sync_keys"])
+
+        deadline = time.monotonic() + 60
+        last = None
+        while time.monotonic() < deadline:
+            time.sleep(0.05)       # the background loop flushes the rest
+            now = scrape()
+            if at_rest(*now) and (now[0]["global"], now[1]) == last:
+                break
+            last = (now[0].get("global"), now[1])
+        stages, engine_vars = now
+        assert at_rest(stages, engine_vars), (stages.get("global"),
+                                              engine_vars)
+        # Every acknowledged hit is on its owner's row.
+        spent = 0
+        for lo in range(0, keys, 100):
+            resp = cluster.run(owners_rows(
+                [f"t{k}" for k in range(lo, min(keys, lo + 100))]
+            ), timeout=60)
+            assert not any(r.error for r in resp.responses)
+            spent += sum(1_000_000_000 - r.remaining
+                         for r in resp.responses)
+        assert d.fastpath.fallbacks == 0
+    finally:
+        cluster.stop()
+
+    hits_sent = rpcs * sum(1 + j % 3 for j in range(per_rpc))
+    g, lane = stages["global"], stages["engine"]
+    tick = g["sync_tick"]
+    assert tick["count"] == engine_vars["syncs"] > 0
+    assert tick["keys"] == engine_vars["sync_keys"] > 0
+    assert spent == hits_sent
+    assert g["build_chunks"]["count"] == tick["count"]
+    assert g["sync_step"]["count"] == tick["chunks"] >= tick["count"]
+    assert tick["chunks"] >= -(-tick["keys"] // 128)
+    parts = _ms(stages, "global", "build_chunks", "wait_locks", "sync_step")
+    assert "lock_wait" not in g      # backend.lock_wait is a drain's wait
+    assert 0.75 * tick["ms_total"] <= parts <= 1.001 * tick["ms_total"], (
+        parts, tick)
+    # The engine lane under its own name: drains, checks, rounds.
+    assert lane["pack"]["checks"] == rpcs * per_rpc
+    assert lane["pack"]["rounds"] >= lane["drain"]["count"] > 0
+    assert lane["pack"]["count"] == lane["drain"]["count"]
+    # One chunk's sync program, as /debug/vars describes it.
+    prog = engine_vars["sync_program"]
+    assert prog["shards"] == 4 and prog["collective"] == "psum"
+    assert prog["bytes_accessed"] is None or prog["bytes_accessed"] > 0
+
+
 # -- the span plane ---------------------------------------------------------
 
 def test_merge_parents_the_stage_spans_when_armed():
