@@ -2128,6 +2128,11 @@ class FastPath:
             h_mach[plan.occ] = 0          # divert cascade occurrences
             h_mach[plan.firsts] = h[plan.firsts]  # keep one READ lane
             hits_mach[plan.firsts] = 0
+            # What the replay will serve, for the lane.cascade row.
+            casc_counts = dict(
+                groups=len(plan.groups), occ=int(plan.occ.sum()),
+                peeks=int((hits[plan.occ] == 0).sum()),
+            )
 
         if n_shards > 1:
             from gubernator_tpu.parallel.mesh import shard_of_hash
@@ -2303,6 +2308,7 @@ class FastPath:
                 if plan is not None:
                     host_box.append(to_host(resps))
                     cascade = tracing.stage("lane.cascade")
+                    cascade.tally(**casc_counts)
                     gather(host_box[0])
                     wb = _run_cascade(
                         plan, h, hits, lim, dur, algo, burst,
@@ -2703,9 +2709,11 @@ def _plan_cascade(h, hits, reset_remaining, is_greg, lim, dur, algo, burst,
     """Pick duplicate-key groups the host can serve without one device
     round per occurrence.
 
-    Exact-cascade groups: >1 occurrence of a key where every occurrence
-    has positive hits, no RESET_REMAINING, no Gregorian duration, and
-    identical limit/duration/algorithm/burst.  use_cached (GLOBAL
+    Exact-cascade groups: >1 occurrence of a key where no occurrence
+    has negative hits, RESET_REMAINING or a Gregorian duration, and all
+    share limit/duration/algorithm/burst.  A peek (hits == 0) belongs:
+    the read lane is one, and what a later one answers is the running
+    state, unchanged (_run_cascade).  use_cached (GLOBAL
     non-owner) groups qualify too when the flag is UNIFORM across the
     group — the replay branches on the read lane's `cached` flag: a
     verbatim broadcast-row serve copies to every occurrence (the device
@@ -2737,7 +2745,7 @@ def _plan_cascade(h, hits, reset_remaining, is_greg, lim, dur, algo, burst,
         inv, weights=cached_mixed.astype(np.float64), minlength=nb
     ) == 0
 
-    bad_occ = (hits <= 0) | reset_remaining | is_greg
+    bad_occ = (hits < 0) | reset_remaining | is_greg
     grp_bad = np.bincount(
         inv, weights=bad_occ.astype(np.float64), minlength=nb
     ) > 0
@@ -2771,9 +2779,22 @@ def _run_cascade(plan, h, hits, lim, dur, algo, burst,
     identical row.  One branch is NOT on the lattice: a leaky bucket
     the read lane just CREATED (`foundv` 0) whose first occurrence asks
     for more than its burst is stored empty (algorithms.go:470-476),
-    where an existing bucket's over-ask mutates nothing.  Deliberate,
-    documented divergences: the table's sticky Status field holds the
-    write-back's value rather than the last occurrence's, a
+    where an existing bucket's over-ask mutates nothing.  That branch
+    tests the FIRST occurrence only: where the first is a peek, the
+    read lane has created the bucket full exactly as that peek does,
+    and a later over-ask meets an existing bucket.
+
+    A peek (hits == 0) mutates nothing and is answered from the running
+    state (ops/step.py: token h0 -> rem0, s_status, te_expire; leaky
+    r_hits == 0 -> l_take subtracts 0.0, le_expire keeps s_expire): the
+    sticky status with the replay's flips so far for token, UNDER for
+    leaky, the running remaining, the reset time that remaining gives.
+    It adds nothing to the write-back, and a leaky group's expiry is
+    refreshed only where some occurrence spent; a group of peeks alone
+    writes nothing back.
+
+    Deliberate, documented divergences: the table's sticky Status field
+    holds the write-back's value rather than the last occurrence's, a
     fully-drained leaky group's expiry refresh rides an over-limit
     touch lane, and a leaky group that re-creates a resident row of
     the other algorithm replays as an existing bucket."""
@@ -2812,12 +2833,17 @@ def _run_cascade(plan, h, hits, lim, dur, algo, burst,
         # response status IS the stored status.  Leaky reports fresh.
         st0 = int(status[fi])
         flip = False  # an over-at-zero occurred (token stored -> OVER)
+        spent = bool(hits[occ].any())  # not a group of peeks alone
         r = r0
         if leaky and not foundv[fi] and int(hits[fi]) > r:
             r = 0  # new bucket, over-asked: stored empty, reports 0
         for i in occ:
             hc = int(hits[i])
-            if r == 0:
+            if hc == 0:
+                if i == fi:
+                    continue  # the read lane WAS this peek: its answer
+                st, rr = (0 if leaky else st0), r
+            elif r == 0:
                 if not leaky and not flip:
                     flip = True  # sticky stored-status transition
                     st0 = 1
@@ -2852,7 +2878,7 @@ def _run_cascade(plan, h, hits, lim, dur, algo, burst,
         eff = r0 - r
         if eff > 0:
             wb_lane(eff)
-        elif leaky:
+        elif leaky and spent:
             # Over-limit "touch": refreshes the sliding expiry the way
             # every nonzero-hit occurrence does, mutating nothing else.
             wb_lane(int(burst[fi]) + 1)

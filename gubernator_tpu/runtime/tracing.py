@@ -700,7 +700,9 @@ STAGES: Dict[str, str] = {
     "lane.pack": "process() up to the device dispatch (concatenate, "
                  "cascade plan, assign_rounds, build rounds)",
     "lane.cascade": "inside a cascade merge's locked window: gather, "
-                    "host replay, write-back rounds",
+                    "host replay, write-back rounds; counters groups "
+                    "(duplicate groups replayed), occ (their occurrences), "
+                    "peeks (of those, hits == 0)",
     "lane.unpack": "gather + finish (tallies, capture mask, per-entry "
                    "split) after the answer is on the host",
     "lane.dispatch_stage": "the coalescer's side of the dispatch stage "

@@ -665,6 +665,221 @@ def test_fastpath_new_leaky_bucket_over_asked_in_a_duplicate_group(
     asyncio.run(scenario())
 
 
+_DAY = 86_400_000
+
+
+def _B(*items, limit=10, duration=_DAY):
+    """One RPC of the peek differential: hits on the case's key `k`, or
+    (key, hits) for a bystander key."""
+    return ("batch", items, (limit, duration))
+
+
+def _A(ms):
+    return ("advance", ms, None)
+
+
+# id -> (algorithm, steps).  Token: limit 10 unless a batch says
+# otherwise; leaky: limit 10, burst 100, a token leaks every 0.1 day.
+# Every case ends on the NEXT batches — a lone peek and a lone spend,
+# served by the plain machinery from the table row — so that a wrong or
+# missing write-back shows.
+_PEEK_CASES = {
+    "token-resident-peek-first": (0, [
+        _B(1), _B(0, 1, 1), _B(0), _B(1)]),
+    "token-resident-peek-middle": (0, [
+        _B(1), _B(2, 0, 3), _B(0), _B(1)]),
+    "token-resident-peek-last": (0, [
+        _B(1), _B(2, 3, 0), _B(0), _B(1)]),
+    "token-resident-peeks-only": (0, [
+        _B(3), _B(0, 0, 0), _B(1, 0), _B(0), _B(1)]),
+    "token-new-peek-first": (0, [
+        _B(0, 1, 0, 2), _B(0), _B(1)]),
+    "token-new-peeks-only": (0, [
+        _B(0, 0), _B(1, 1), _B(0), _B(1)]),
+    "token-new-over-asked-after-a-peek": (0, [
+        _B(0, 11, 1), _B(0), _B(1)]),
+    # r reaches 0 mid-group, a spend flips the stored status, the peek
+    # after it reports the flip; the raised limit then shows the sticky
+    # OVER that only the flip lane can have written.
+    "token-peek-after-the-flip": (0, [
+        _B(1, 1, 0, 1, 0, limit=2), _B(0, 1, 0, limit=4), _B(0, limit=4),
+        _B(1, limit=4)]),
+    # A peek at r == 0 is not an over-at-zero: no flip, no flip lane.
+    "token-peek-at-zero-does-not-flip": (0, [
+        _B(1, 1, 0, 0, limit=2), _B(0, 1, 0, limit=4), _B(0, limit=4),
+        _B(1, limit=4)]),
+    # The first peek renews the bucket (a shorter duration, already
+    # past): it answers the remaining from BEFORE the renewal, as the
+    # read lane did; the occurrences after it see the renewed bucket.
+    "token-peek-first-renews": (0, [
+        _B(10), _A(_DAY * 6 // 10),
+        _B(0, 1, 0, duration=_DAY // 2), _B(0, duration=_DAY // 2),
+        _B(1, duration=_DAY // 2)]),
+    "token-all-over-limit-with-a-peek": (0, [
+        _B(8), _B(5, 0, 5), _B(0), _B(1)]),
+    "token-two-groups-and-a-bystander": (0, [
+        _B(1, ("b", 1)), _B(0, ("b", 2), 1, ("c", 1), ("b", 0), 0),
+        _B(0), _B(("b", 0)), _B(1, ("b", 1))]),
+    "leaky-resident-peek-first": (1, [
+        _B(5), _B(0, 1, 1), _B(0), _B(1)]),
+    "leaky-resident-peek-middle": (1, [
+        _B(5), _B(2, 0, 3), _B(0), _B(1)]),
+    "leaky-resident-peek-last": (1, [
+        _B(5), _B(2, 3, 0), _B(0), _B(1)]),
+    "leaky-new-peek-first": (1, [
+        _B(0, 1, 0, 2), _B(0), _B(1)]),
+    # The bucket is created FULL by the peek; the over-ask then meets an
+    # existing bucket and mutates nothing (where it comes first it
+    # stores the new bucket empty).
+    "leaky-new-over-asked-after-a-peek": (1, [
+        _B(0, 400, 1), _B(0), _B(1)]),
+    "leaky-new-over-asked-first": (1, [
+        _B(400, 0, 1), _B(0), _B(1)]),
+    "leaky-drained-mid-group": (1, [
+        _B(98), _B(1, 0, 1, 0, 1, 0), _B(0), _B(1)]),
+    "leaky-peek-at-zero": (1, [
+        _B(100), _B(0, 0), _B(0, 1, 0), _B(0), _B(1)]),
+    # Every spend over the limit, eff == 0: the touch lane refreshes the
+    # sliding expiry, so past the OLD expiry the bucket still stands
+    # (57 tokens: 45 + two leaks of 6) where a fresh one would hold 100.
+    "leaky-all-over-limit-with-a-peek-touches": (1, [
+        _B(50, 5), _A(_DAY * 6 // 10), _B(200, 0, 200),
+        _A(_DAY * 6 // 10), _B(0, 0), _B(1)]),
+    # Peeks alone refresh nothing: past the old expiry the bucket is
+    # gone on both sides and the read creates a full one.
+    "leaky-peeks-only-leave-the-expiry": (1, [
+        _B(50, 5), _A(_DAY * 6 // 10), _B(0, 0, 0),
+        _A(_DAY * 6 // 10), _B(0, 0), _B(1)]),
+    # A negative hit keeps its group on the round-per-occurrence path.
+    "token-negative-hits-take-rounds": (0, [
+        _B(3), _B(1, -1, 0), _B(0), _B(1)]),
+    "leaky-negative-hits-take-rounds": (1, [
+        _B(5), _B(0, -2, 1), _B(0), _B(1)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PEEK_CASES))
+def test_fastpath_peeks_in_a_duplicate_group(frozen_clock, case):
+    """A duplicate group may hold peeks (hits == 0) and still be served
+    by the host cascade: every occurrence, and every answer of the
+    batches after it, equals core/pymodel.py's — status, remaining and
+    reset_time — and the lane.cascade counters say the replay (or, for
+    negative hits, the rounds) did the work."""
+    import asyncio
+
+    from gubernator_tpu.core.config import Config
+    from gubernator_tpu.core.pymodel import PyRateLimiter
+    from gubernator_tpu.net.grpc_api import reqs_from_pb
+    from gubernator_tpu.proto import gubernator_pb2 as pb
+    from gubernator_tpu.runtime.fastpath import FastPath
+    from gubernator_tpu.runtime.service import Service
+
+    algo, steps = _PEEK_CASES[case]
+
+    async def scenario():
+        dev = DeviceConfig(num_slots=1024, ways=8, batch_size=64)
+        svc = Service(Config(device=dev), clock=frozen_clock)
+        await svc.start()
+        fp = FastPath(svc)
+        oracle = PyRateLimiter(clock=frozen_clock)
+        groups = occ = peeks = 0
+        for n_step, (kind, arg, lim_dur) in enumerate(steps):
+            if kind == "advance":
+                frozen_clock.advance(arg)
+                continue
+            limit, duration = lim_dur
+            items = [i if isinstance(i, tuple) else ("k", i) for i in arg]
+            reqs = [
+                pb.RateLimitReq(
+                    name="peek", unique_key=key, hits=h,
+                    limit=limit, duration=duration,
+                    burst=100 if algo else 0, algorithm=algo,
+                )
+                for key, h in items
+            ]
+            for key in {key for key, _ in items}:
+                hs = [h for k, h in items if k == key]
+                if len(hs) > 1 and min(hs) >= 0:
+                    groups += 1
+                    occ += len(hs)
+                    peeks += hs.count(0)
+            payload = pb.GetRateLimitsReq(requests=reqs).SerializeToString()
+            out = await fp.check_raw(payload, peer_rpc=False)
+            got = pb.GetRateLimitsResp.FromString(out).responses
+            for j, (g, r) in enumerate(zip(got, reqs_from_pb(reqs))):
+                w = oracle.get_rate_limit(r)
+                assert (g.error, g.status, g.limit, g.remaining,
+                        g.reset_time) == (
+                    "", int(w.status), w.limit, w.remaining, w.reset_time
+                ), (n_step, j)
+        row = fp._stages.debug_vars()["mach"]["cascade"]
+        assert fp.fallbacks == 0
+        await fp.close()
+        await svc.close()
+        return row, groups, occ, peeks
+
+    row, groups, occ, peeks = asyncio.run(scenario())
+    if "negative" in case:
+        assert row["count"] == 0 and "groups" not in row
+    else:
+        assert peeks > 0
+        assert (row["groups"], row["occ"], row["peeks"]) == (
+            groups, occ, peeks)
+
+
+def _plan(hits, **cols):
+    """_plan_cascade over one column set: keys 7,7,... unless given."""
+    import numpy as np
+
+    from gubernator_tpu.runtime.fastpath import _plan_cascade
+
+    n = len(hits)
+    z = np.zeros(n, dtype=bool)
+    lim = np.full(n, 10, dtype=np.int64)
+    c = dict(
+        h=np.full(n, 7, dtype=np.int64), reset_remaining=z, is_greg=z,
+        lim=lim, dur=lim * 1000, algo=np.zeros(n, dtype=np.int32),
+        burst=lim, use_cached=z,
+    )
+    c.update({k: np.asarray(v, dtype=c[k].dtype) for k, v in cols.items()})
+    plan = _plan_cascade(
+        c["h"], np.asarray(hits, dtype=np.int64), c["reset_remaining"],
+        c["is_greg"], c["lim"], c["dur"], c["algo"], c["burst"],
+        c["use_cached"],
+    )
+    return None if plan is None else plan.occ.tolist()
+
+
+@pytest.mark.parametrize("hits,cols,occ", [
+    ([1, 1], {}, [True, True]),
+    ([0, 1], {}, [True, True]),                # a peek belongs
+    ([1, 0, 2, 0], {}, [True] * 4),
+    ([0, 0], {}, [True, True]),                # peeks alone too
+    ([1], {}, None),                           # no duplicate
+    ([1, 2, 0], {"h": [7, 8, 9]}, None),
+    ([0, 0], {"h": [0, 0]}, None),             # errored lanes: h == 0
+    ([1, -1], {}, None),                       # negative hits: rounds
+    ([0, -1, 0], {}, None),
+    ([0, 1], {"reset_remaining": [False, True]}, None),
+    ([0, 1], {"is_greg": [True, False]}, None),
+    ([0, 1], {"lim": [10, 11]}, None),
+    ([0, 1], {"dur": [10_000, 20_000]}, None),
+    ([0, 1], {"algo": [0, 1]}, None),
+    ([0, 1], {"burst": [10, 20]}, None),
+    ([0, 1], {"use_cached": [True, False]}, None),
+    ([0, 1], {"use_cached": [True, True]}, [True, True]),
+    # Two groups and a single: only the group without the negative hit.
+    ([0, 1, -1, 1, 0, 5], {"h": [7, 7, 8, 8, 9, 7]},
+     [True, True, False, False, False, True]),
+])
+def test_plan_cascade_eligibility(hits, cols, occ):
+    """Which duplicate groups the host cascade takes: more than one
+    occurrence, one set of limit / duration / algorithm / burst, a
+    uniform use_cached, and no occurrence with negative hits,
+    RESET_REMAINING or a Gregorian duration; hits == 0 is no bar."""
+    assert _plan(hits, **cols) == occ
+
+
 def test_multinode_columnar_routing():
     """Multi-node client path on the compiled lane: vectorized ring
     lookup, zero-copy forwards to owners, owner metadata on forwarded

@@ -271,37 +271,46 @@ def _ms(stages: dict, lane: str, *names: str) -> float:
                if n in stages.get(lane, {}))
 
 
+# The stages a drain divides into (identity 2, docs/tracing.md).
+_DRAIN_PARTS = ("slot_wait", "dispatch_wait", "handoff", "pack",
+                "lock_wait", "dispatch", "cascade", "d2h_wait", "unpack",
+                "resume")
+
+
+def _drive(cluster, d, payload, n_rpcs: int, n_checks: int) -> None:
+    """`n_rpcs` raw GetRateLimits of `payload(i)`, 8 in flight, over real
+    gRPC; every answer whole and free of errors."""
+    import grpc.aio
+
+    async def drive():
+        ch = grpc.aio.insecure_channel(d.grpc_address)
+        rpc = ch.unary_unary("/pb.gubernator.V1/GetRateLimits")
+        sem = asyncio.Semaphore(8)
+
+        async def one(i):
+            async with sem:
+                resp = pb.GetRateLimitsResp.FromString(await rpc(payload(i)))
+                assert [r.error for r in resp.responses] == [""] * n_checks
+
+        try:
+            await asyncio.gather(*(one(i) for i in range(n_rpcs)))
+        finally:
+            await ch.close()
+
+    cluster.run(drive(), timeout=120)
+
+
 def test_identities_and_views_on_a_daemon():
     """300 RPCs, 8 in flight, over real gRPC through the raw handler and
     check_raw: both identities close, and the numbers that used to be
     measured separately equal the ledger's rows."""
-    import grpc.aio
-
     from gubernator_tpu.testing.cluster import Cluster
 
     assert not tracing.enabled()
     cluster = Cluster.start(1)
     try:
         d = cluster.daemon_at(0)
-
-        async def drive():
-            ch = grpc.aio.insecure_channel(d.grpc_address)
-            rpc = ch.unary_unary("/pb.gubernator.V1/GetRateLimits")
-            sem = asyncio.Semaphore(8)
-
-            async def one(i):
-                async with sem:
-                    raw = await rpc(_payload(i))
-                    resp = pb.GetRateLimitsResp.FromString(raw)
-                    assert len(resp.responses) == 6
-                    assert not resp.responses[0].error
-
-            try:
-                await asyncio.gather(*(one(i) for i in range(300)))
-            finally:
-                await ch.close()
-
-        cluster.run(drive(), timeout=120)
+        _drive(cluster, d, _payload, 300, 6)
         stages = d.metrics.stages.debug_vars()
         lanes = d.fastpath.debug_vars()["lanes"]
         metrics_text = d.metrics.render().decode()
@@ -330,9 +339,7 @@ def test_identities_and_views_on_a_daemon():
 
     # Identity 2, per drain.
     drain = mach["drain"]["ms_total"]
-    parts = _ms(stages, "mach", "slot_wait", "dispatch_wait", "handoff",
-                "pack", "lock_wait", "dispatch", "cascade", "d2h_wait",
-                "unpack", "resume")
+    parts = _ms(stages, "mach", *_DRAIN_PARTS)
     assert 0.75 * drain <= parts <= 1.001 * drain, (parts, drain)
     assert mach["handoff"]["count"] == mach["resume"]["count"] == 2 * drains
     assert mach["pack"]["count"] == mach["unpack"]["count"] == drains
@@ -376,6 +383,49 @@ def test_identities_and_views_on_a_daemon():
     assert stages["xla"]["compile"]["ms_total"] > 0
     # Disarmed: all of the above allocated no span.
     assert tracing.debug_vars() == {"enabled": False}
+
+
+def test_the_cascade_row_counts_its_groups_and_peeks():
+    """120 RPCs, 8 in flight, six checks each on three hot keys, every
+    other check a peek: every drain holds duplicate groups with peeks in
+    them and the host cascade replays them.  The lane.cascade row says
+    so — groups replayed, their occurrences, the peeks among those —
+    and the per-drain identity closes on such drains as on any other."""
+    from gubernator_tpu.testing.cluster import Cluster
+
+    def payload(i: int) -> bytes:
+        return pb.GetRateLimitsReq(requests=[
+            pb.RateLimitReq(
+                name="stages_peek", unique_key=f"hot{j % 3}",
+                hits=(i + j) % 2, limit=1_000_000, duration=60_000,
+                algorithm=j % 3 % 2,
+            )
+            for j in range(6)
+        ]).SerializeToString()
+
+    cluster = Cluster.start(1)
+    try:
+        d = cluster.daemon_at(0)
+        _drive(cluster, d, payload, 120, 6)
+        stages = d.metrics.stages.debug_vars()
+        drains = d.fastpath.debug_vars()["lanes"]["mach"]["drains"]
+        assert d.fastpath.fallbacks == 0
+    finally:
+        cluster.stop()
+
+    mach = stages["mach"]
+    row = mach["cascade"]
+    # Every RPC holds each of its three keys twice, a peek and a spend:
+    # every drain is a cascade merge of three groups.
+    assert row["count"] == drains == mach["drain"]["count"] > 0
+    assert row["groups"] == 3 * drains
+    assert row["occ"] == 120 * 6
+    assert row["peeks"] == 120 * 3
+    assert mach["pack"]["count"] == mach["unpack"]["count"] == drains
+    drain = mach["drain"]["ms_total"]
+    parts = _ms(stages, "mach", *_DRAIN_PARTS)
+    assert 0.75 * drain <= parts <= 1.001 * drain, (parts, drain)
+    assert row["ms_total"] > 0
 
 
 def test_counters_ride_on_a_stage(clock):
