@@ -29,7 +29,6 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
-import os
 import time
 from typing import List, Tuple
 
@@ -106,11 +105,7 @@ def build_payload(names_keys, hits=1, limit=1_000_000_000, duration=3_600_000,
 
 def bench(seconds: float, concurrency: int,
           depth_sweep: Tuple[int, ...] = (1, 2, 4),
-          serve_sweep: Tuple[str, ...] = (
-              "classic", "pipelined", "ring", "megaround", "persistent",
-          ),
           workload: str = "",
-          mesh_shards: int = 0,
           client_modes: Tuple[str, ...] = ("python", "native", "leased"),
           ) -> None:
     """Sync driver: client coroutines run on each cluster's OWN loop —
@@ -142,25 +137,13 @@ def bench(seconds: float, concurrency: int,
     from gubernator_tpu.core.config import (
         fastpath_sparse_from_env,
         pipeline_depth_from_env,
-        ring_linger_us_from_env,
-        ring_rounds_from_env,
-        ring_slots_from_env,
-        serve_mode_from_env,
     )
 
     sparse = fastpath_sparse_from_env()
     depth = pipeline_depth_from_env()
-    serve_mode = serve_mode_from_env()
-    ring_slots = ring_slots_from_env()
-    ring_rounds = ring_rounds_from_env()
-    ring_linger = ring_linger_us_from_env()
 
     def conf(**kw) -> DaemonConfig:
         kw.setdefault("pipeline_depth", depth)
-        kw.setdefault("serve_mode", serve_mode)
-        kw.setdefault("ring_slots", ring_slots)
-        kw.setdefault("ring_rounds", ring_rounds)
-        kw.setdefault("ring_max_linger_us", ring_linger)
         return DaemonConfig(fastpath_sparse=sparse, **kw)
 
     rng = np.random.default_rng(7)
@@ -286,36 +269,13 @@ def bench(seconds: float, concurrency: int,
                 "waited": mach.waited_drains,
                 "max_inflight_seen": mach.max_inflight_seen,
             }
-            # Ring acceptance split (docs/ring.md): blocking device->
-            # host fetches performed ON the request path, per check —
-            # 0 in steady-state ring mode — plus the ring's own
-            # slot-wait (the backpressure term that replaces the
-            # pipelined bubble).
+            # Blocking device->host fetches performed ON the request
+            # path, per check.
             bf = sum(fp.blocking_fetches.values())
-            budget["serve_mode"] = fp.effective_serve_mode
             budget["blocking_fetches"] = dict(fp.blocking_fetches)
             budget["blocking_fetches_per_check"] = round(
                 bf / fp.served, 6
             )
-            if fp._ring is not None:
-                rdv = fp._ring.debug_vars()
-                budget["ring_slot_wait_us_per_1000"] = round(
-                    rdv["slot_wait_ms_total"] * 1e3 / per_k
-                )
-                # Dispatch-amortization split (docs/ring.md megaround):
-                # the per-ROUND dispatch overhead — the fixed XLA-entry
-                # tax megaround amortizes — plus the running
-                # amortization factor and device dispatches per 1000
-                # served checks.
-                rounds_done = max(rdv["rounds_consumed"], 1)
-                budget["dispatch_us_per_round"] = round(
-                    mach.dispatch_s * 1e6 / rounds_done
-                )
-                budget["rounds_per_dispatch"] = rdv["rounds_per_dispatch"]
-                budget["dispatches_per_1000"] = round(
-                    rdv["iterations"] / per_k, 3
-                )
-                budget["ring"] = rdv
         results.append(budget)
         print(json.dumps(budget), flush=True)
 
@@ -344,173 +304,6 @@ def bench(seconds: float, concurrency: int,
             fail({"config": "table_census", "error": str(e)})
     finally:
         c.stop()
-
-    # ---- serve-mode sweep: classic/pipelined/ring/megaround/persistent -
-    # Re-run the two throughput configs and the small-batch latency
-    # config per drain discipline on fresh single-node daemons; the
-    # acceptance bars are ring-mode blocking_fetches_per_check == 0 with
-    # small-batch p50 at or below the pipelined baseline, and — under
-    # the dispatch-SATURATION config (many tiny merges at high
-    # concurrency: the workload whose cost IS the per-dispatch tax) —
-    # megaround cutting dispatches-per-check vs plain ring by the
-    # configured round factor (docs/ring.md).  "persistent" is
-    # platform-honest: where the Pallas kernel cannot compile the
-    # stages line reports the megaround fallback and the probe reason.
-    for mode in serve_sweep:
-        try:
-            c = Cluster.start_with(
-                [""], device=dev_cfg,
-                conf_template=conf(serve_mode=mode),
-            )
-            try:
-                addr = [c.daemons[0].grpc_address]
-                sweep_seconds = max(2.0, seconds / 2)
-                pays = [build_payload(
-                    [("bench_token", f"k{i}") for i in range(1000)]
-                )]
-                zipf_pays = []
-                for _ in range(32):
-                    ks = rng.zipf(1.3, size=1000) % 1_000_000
-                    zipf_pays.append(build_payload(
-                        [("bench_leaky", f"z{k}") for k in ks],
-                        algorithm=1, limit=1_000_000, duration=60_000,
-                    ))
-                small = [build_payload(
-                    [("bench_lat", f"l{j}") for j in range(10)]
-                )]
-                for name, pl, batch, cc in (
-                    ("token_1k_batch1000", pays, 1000, concurrency),
-                    ("leaky_1m_zipfian", zipf_pays, 1000, concurrency),
-                    ("latency_small_batch", small, 10, 4),
-                ):
-                    c.run(drive(addr, pl, 0.5, cc), timeout=120)  # warm
-                    t0 = time.perf_counter()
-                    rpcs, lat = c.run(
-                        drive(addr, pl, sweep_seconds, cc), timeout=120
-                    )
-                    emit(f"serve_sweep_{name}", rpcs * batch, rpcs,
-                         lat, time.perf_counter() - t0,
-                         {"serve_mode": mode, "concurrency": cc})
-                fp = c.daemons[0].fastpath
-                mach = fp._mach
-                bf = sum(fp.blocking_fetches.values())
-                line = {
-                    "config": "serve_sweep_stages",
-                    "serve_mode": mode,
-                    "effective_serve_mode": fp.effective_serve_mode,
-                    "dispatch_s": round(mach.dispatch_s, 3),
-                    "fetch_s": round(mach.fetch_s, 3),
-                    "bubble_s": round(mach.bubble_s, 3),
-                    "drains": mach.drains,
-                    "served": fp.served,
-                    "blocking_fetches": dict(fp.blocking_fetches),
-                    "blocking_fetches_per_check": round(
-                        bf / max(fp.served, 1), 6
-                    ),
-                }
-                if fp._ring is not None:
-                    rdv = fp._ring.debug_vars()
-                    line["ring"] = rdv
-                    line["rounds_per_dispatch"] = (
-                        rdv["rounds_per_dispatch"]
-                    )
-                    line["dispatches_per_check"] = round(
-                        rdv["iterations"] / max(fp.served, 1), 6
-                    )
-                    line["dispatch_us_per_round"] = round(
-                        mach.dispatch_s * 1e6
-                        / max(rdv["rounds_consumed"], 1)
-                    )
-                if fp.persistent_status is not None:
-                    line["persistent"] = dict(fp.persistent_status)
-                results.append(line)
-                print(json.dumps(line), flush=True)
-            finally:
-                c.stop()
-
-            # Dispatch-SATURATION on a DEDICATED small-ring cluster
-            # (ring_slots=2, same for every mode): many tiny merges at
-            # high concurrency make the per-dispatch XLA-entry tax THE
-            # cost, and the deliberately small base tier means plain
-            # ring amortizes at most 2 rounds/dispatch while megaround
-            # may widen to 2 x GUBER_RING_ROUNDS — the ISSUE-12
-            # acceptance comparison (dispatches-per-check reduced by
-            # ~the round factor under saturating load).  The linger is
-            # pinned at 2ms here — the explicit bounded-add-latency
-            # trade this config exists to price — and the ring deltas
-            # are measured across the timed window only (warmup
-            # excluded).
-            c2 = Cluster.start_with(
-                [""], device=dev_cfg,
-                conf_template=conf(serve_mode=mode, ring_slots=2,
-                                   ring_max_linger_us=2000.0),
-            )
-            try:
-                from gubernator_tpu.proto import gubernator_pb2 as pb
-
-                addr2 = [c2.daemons[0].grpc_address]
-                # Duplicate-heavy admission with zero-hit status peeks:
-                # same-key occurrences must observe each other, so the
-                # packer explodes each merge into SEQUENTIAL rounds
-                # (hits=0 peeks break cascade eligibility — the
-                # documented multi-round ring workload, docs/ring.md).
-                # Dispatch count is then round count / block tier, so
-                # the megaround-vs-ring dispatch ratio IS the round
-                # factor once both saturate.
-                dup = [pb.GetRateLimitsReq(requests=[
-                    pb.RateLimitReq(
-                        name="bench_dup", unique_key="hot",
-                        hits=(j % 2), limit=1_000_000_000,
-                        duration=3_600_000,
-                    )
-                    for j in range(10)
-                ]).SerializeToString()]
-                cc = max(concurrency * 4, 32)
-                c2.run(drive(addr2, dup, 0.5, cc), timeout=120)
-                fp2 = c2.daemons[0].fastpath
-                rdv0 = (
-                    fp2._ring.debug_vars()
-                    if fp2._ring is not None else None
-                )
-                t0 = time.perf_counter()
-                rpcs, lat = c2.run(
-                    drive(addr2, dup, sweep_seconds, cc), timeout=120
-                )
-                extra = {
-                    "serve_mode": mode, "concurrency": cc,
-                    "ring_slots": 2, "max_linger_us": 2000,
-                    "effective_serve_mode": (
-                        c2.daemons[0].fastpath.effective_serve_mode
-                    ),
-                }
-                if rdv0 is not None:
-                    rdv1 = fp2._ring.debug_vars()
-                    it = rdv1["iterations"] - rdv0["iterations"]
-                    rc = (
-                        rdv1["rounds_consumed"]
-                        - rdv0["rounds_consumed"]
-                    )
-                    checks = max(rpcs * 10, 1)
-                    extra.update({
-                        "iterations": it,
-                        "rounds_consumed": rc,
-                        "rounds_per_dispatch": round(rc / max(it, 1), 3),
-                        "dispatches_per_check": round(it / checks, 6),
-                        "mega_iterations": (
-                            rdv1["mega_iterations"]
-                            - rdv0["mega_iterations"]
-                        ),
-                        "lingers": rdv1["lingers"] - rdv0["lingers"],
-                    })
-                emit("serve_sweep_dispatch_saturation", rpcs * 10,
-                     rpcs, lat, time.perf_counter() - t0, extra)
-            finally:
-                c2.stop()
-        except Exception as e:  # noqa: BLE001 — isolate sweep failures
-            fail({
-                "config": "serve_sweep", "serve_mode": mode,
-                "error": str(e),
-            })
 
     # ---- client-mode sweep: python vs native vs leased -----------------
     # The CLIENT half of the E2E budget (ISSUE 10): the same steady
@@ -618,80 +411,6 @@ def bench(seconds: float, concurrency: int,
         except Exception as e:  # noqa: BLE001 — isolate sweep failures
             fail({
                 "config": "client_sweep", "error": str(e),
-            })
-
-    # ---- mesh serve-mode sweep: the deployment-mode benchmark ----------
-    # Re-run the throughput + small-batch configs per drain discipline
-    # on a MESH daemon (--mesh-shards; the production shape: one daemon
-    # owning a device mesh with the table sharded over it).  Each line
-    # reports per-shard occupancy and — in ring mode — the ring budget
-    # split (slot-wait, per-shard seq), turning MULTICHIP from a dryrun
-    # artifact into a deployment-mode benchmark.
-    for mode in (serve_sweep if mesh_shards > 1 else ()):
-        try:
-            mesh_cfg = DeviceConfig(
-                num_slots=mesh_shards * 8 * 2048,
-                ways=8,
-                batch_size=1024,
-                num_shards=mesh_shards,
-            )
-            c = Cluster.start_with(
-                [""], device=mesh_cfg,
-                conf_template=conf(serve_mode=mode),
-            )
-            try:
-                addr = [c.daemons[0].grpc_address]
-                sweep_seconds = max(2.0, seconds / 2)
-                pays = [build_payload(
-                    [("bench_token", f"k{i}") for i in range(1000)]
-                )]
-                small = [build_payload(
-                    [("bench_lat", f"l{j}") for j in range(10)]
-                )]
-                for name, pl, batch, cc in (
-                    ("token_1k_batch1000", pays, 1000, concurrency),
-                    ("latency_small_batch", small, 10, 4),
-                ):
-                    c.run(drive(addr, pl, 0.5, cc), timeout=120)  # warm
-                    t0 = time.perf_counter()
-                    rpcs, lat = c.run(
-                        drive(addr, pl, sweep_seconds, cc), timeout=120
-                    )
-                    emit(f"mesh_serve_sweep_{name}", rpcs * batch, rpcs,
-                         lat, time.perf_counter() - t0,
-                         {"serve_mode": mode, "concurrency": cc,
-                          "mesh_shards": mesh_shards})
-                fp = c.daemons[0].fastpath
-                be = c.daemons[0].service.backend
-                bf = sum(fp.blocking_fetches.values())
-                line = {
-                    "config": "mesh_serve_sweep_stages",
-                    "serve_mode": mode,
-                    "effective_serve_mode": fp.effective_serve_mode,
-                    "mesh_shards": mesh_shards,
-                    "served": fp.served,
-                    "blocking_fetches": dict(fp.blocking_fetches),
-                    "blocking_fetches_per_check": round(
-                        bf / max(fp.served, 1), 6
-                    ),
-                    "shard_occupancy": be.shard_occupancy(),
-                }
-                if fp._ring is not None:
-                    rdv = fp._ring.debug_vars()
-                    line["ring"] = rdv
-                    if fp.served:
-                        line["ring_slot_wait_us_per_1000"] = round(
-                            rdv["slot_wait_ms_total"] * 1e3
-                            / (fp.served / 1000.0)
-                        )
-                results.append(line)
-                print(json.dumps(line), flush=True)
-            finally:
-                c.stop()
-        except Exception as e:  # noqa: BLE001 — isolate sweep failures
-            fail({
-                "config": "mesh_serve_sweep", "serve_mode": mode,
-                "mesh_shards": mesh_shards, "error": str(e),
             })
 
     # ---- pipeline-depth sweep: the tentpole A/B ------------------------
@@ -1010,7 +729,6 @@ def bench(seconds: float, concurrency: int,
                 fp = d0.fastpath
                 if fp is not None and fp.served:
                     bf = sum(fp.blocking_fetches.values())
-                    extra["serve_mode"] = fp.effective_serve_mode
                     extra["blocking_fetches_per_check"] = round(
                         bf / fp.served, 6
                     )
@@ -1087,13 +805,7 @@ def bench(seconds: float, concurrency: int,
         "fastpath_sparse": sparse,
         "pipeline_depth": depth,
         "pipeline_depth_sweep": list(depth_sweep),
-        "serve_mode": serve_mode,
-        "ring_slots": ring_slots,
-        "ring_rounds": ring_rounds,
-        "ring_max_linger_us": ring_linger,
-        "serve_mode_sweep": list(serve_sweep),
         "client_mode_sweep": list(client_modes),
-        "mesh_shards": mesh_shards,
         "device": {
             "num_slots": dev_cfg.num_slots,
             "batch_size": dev_cfg.batch_size,
@@ -1120,17 +832,6 @@ def main() -> None:
         "throughput + small-batch configs per depth (empty disables)",
     )
     ap.add_argument(
-        "--serve-mode",
-        default="classic,pipelined,ring,megaround,persistent",
-        help="comma-separated GUBER_SERVE_MODE sweep re-running the "
-        "throughput + small-batch + dispatch-saturation configs per "
-        "drain discipline (empty disables); ring entries report the "
-        "fetch-free budget split plus the dispatch-amortization "
-        "columns (rounds_per_dispatch, dispatches_per_check, "
-        "dispatch_us_per_round — docs/ring.md), and persistent "
-        "reports its capability probe honestly",
-    )
-    ap.add_argument(
         "--client-mode", default="python,native,leased",
         help="comma-separated client-SDK sweep over a steady single-key "
         "load, measuring each tier's own machinery (V1Client python "
@@ -1148,35 +849,15 @@ def main() -> None:
         "reports cold-hit rate, promote latency, and demotion rate "
         "(docs/tiering.md); empty disables",
     )
-    ap.add_argument(
-        "--mesh-shards", type=int, default=0,
-        help="re-run the serve-mode sweep on an N-shard mesh daemon "
-        "(the deployment-mode benchmark: per-shard occupancy + ring "
-        "budget split; 0 disables).  On CPU, N virtual devices are "
-        "forced before jax initializes.",
-    )
     args = ap.parse_args()
-    if args.mesh_shards > 1:
-        # Must land before the first jax import (bench() imports jax):
-        # a CPU run needs N virtual devices for an N-shard mesh.
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count="
-                f"{args.mesh_shards}"
-            ).strip()
     sweep = tuple(
         int(d) for d in args.pipeline_depth.split(",") if d.strip()
-    )
-    modes = tuple(
-        m.strip() for m in args.serve_mode.split(",") if m.strip()
     )
     cmodes = tuple(
         m.strip() for m in args.client_mode.split(",") if m.strip()
     )
     bench(args.seconds, args.concurrency, depth_sweep=sweep,
-          serve_sweep=modes, workload=args.workload,
-          mesh_shards=args.mesh_shards, client_modes=cmodes)
+          workload=args.workload, client_modes=cmodes)
 
 
 if __name__ == "__main__":

@@ -23,8 +23,8 @@ holder of the chip while it lives.
                 first is cold only where the cache directory was empty).
   differential  a DeviceBackend at the same geometry under a frozen,
                 stepped clock: ~10^5 mixed token/leaky operations against
-                the oracle, all four fields exact; then the two Pallas
-                kernels are compiled for real and the verdict recorded.
+                the oracle, all four fields exact; then the Pallas
+                kernel is compiled for real and the verdict recorded.
   server_mesh4  (--chips 4, or when the one-chip daemon saw >= 4 devices)
                 the same server with GUBER_MESH_WAYS=4: four device ids,
                 four balanced shard occupancies, GLOBAL read-back
@@ -854,27 +854,16 @@ def phase_differential(args) -> int:
 
 
 def compile_kernels(args) -> dict:
-    """Compile the two opt-in Pallas kernels WITHOUT interpret at the
-    sizes the daemon would run them.  A refusal is recorded word for
-    word, not failed: both are opt-in (GUBER_SERVE_MODE=persistent,
-    GUBER_SKETCH_USE_PALLAS)."""
+    """Compile the opt-in Pallas kernel WITHOUT interpret at the size
+    the daemon would run it.  A refusal is recorded word for word, not
+    failed: the kernel is opt-in (GUBER_SKETCH_USE_PALLAS)."""
     import jax
 
     from gubernator_tpu.core.config import SketchTierConfig
     from gubernator_tpu.ops.pallas.cms_kernel import cms_step_pallas
-    from gubernator_tpu.ops.pallas.serve_kernel import probe_compile
     from gubernator_tpu.ops.sketch import cms_step, init_sketch
 
     out: dict = {}
-    t0 = time.monotonic()
-    ok, reason = probe_compile(
-        num_slots=args.slots, ways=WAYS, batch=args.batch
-    )
-    out["persistent_serve"] = {
-        "ok": ok, "reason": reason, "num_slots": args.slots,
-        "batch": args.batch, "seconds": round(time.monotonic() - t0, 2),
-    }
-
     sk = SketchTierConfig()
     rng = np.random.default_rng(args.seed)
     B = sk.batch_size

@@ -12,8 +12,7 @@ by default; `--watch N` refreshes every N seconds; `--json` emits the
 raw merged scrape for scripting.
 
 Per node: table occupancy (live/expired split and per-shard skew),
-rounds-per-dispatch (the megaround amortization factor), rolling
-p50/p99 vs the SLO target with the pressure flag, breaker/degraded/
+rolling p50/p99 vs the SLO target with the pressure flag, breaker/degraded/
 reshard state, and the shadow-plane census.  Cluster-wide: the merged
 top-K tenants by hits with per-plane over-admission.
 """
@@ -44,7 +43,6 @@ def _node_lines(addr: str, v: Dict) -> List[str]:
         return [f"{addr:<22} UNREACHABLE: {v['error']}"]
     be = v.get("backend", {})
     table = v.get("table", {})
-    fp = v.get("fastpath", {})
     fr = v.get("flightrec", {})
     occ = table.get("occupancy", be.get("occupancy", 0))
     live = table.get("live")
@@ -55,9 +53,6 @@ def _node_lines(addr: str, v: Dict) -> List[str]:
     shards = table.get("per_shard_occupancy") or be.get("shard_occupancy")
     if shards and len(shards) > 1:
         occ_s += " shards=" + "/".join(str(s) for s in shards)
-    ring = fp.get("ring") or {}
-    rpd = ring.get("rounds_per_dispatch", v.get("rounds_per_dispatch"))
-    rpd_s = f" r/d={rpd:.2f}" if isinstance(rpd, (int, float)) else ""
     slo = ""
     if fr:
         slo = " p50=%.2fms p99=%.2fms" % (
@@ -83,8 +78,8 @@ def _node_lines(addr: str, v: Dict) -> List[str]:
     if hk.get("shed", {}).get("served"):
         flags.append("shed=%d" % hk["shed"]["served"])
     lines = [
-        "%-22s checks=%-10s %s%s%s %s" % (
-            addr, be.get("checks", 0), occ_s, rpd_s, slo,
+        "%-22s checks=%-10s %s%s %s" % (
+            addr, be.get("checks", 0), occ_s, slo,
             " ".join(flags),
         )
     ]

@@ -541,8 +541,8 @@ class StatsConfig:
     not).
 
     The sampler dispatches the read-only ops/state.table_stats census
-    every `interval_s` as a ring host job (or an executor call outside
-    ring mode), so the request path never blocks on it.  `top_k`
+    every `interval_s` on an executor thread, so the request path
+    never blocks on it.  `top_k`
     bounds the per-tenant accounting surface (names tracked exactly;
     hit totals ride the existing HostCMS sketch, so cardinality is
     bounded however many tenants appear).  `peek` gates the
@@ -738,49 +738,6 @@ def peer_debounce_ms_from_env() -> int:
     )
 
 
-# Fast-lane drain disciplines (runtime/fastpath.py; docs/ring.md):
-#   classic    — strict depth-1: every merge's dispatch AND fetch
-#                serialize end to end (the pre-PR5 discipline);
-#   pipelined  — dispatch serialized, device->host fetches overlapped at
-#                GUBER_PIPELINE_DEPTH (PR 5);
-#   ring       — the device-resident serving loop (runtime/ring.py):
-#                merges enter a request ring, ONE runner thread drives
-#                bounded jitted multi-round scans and publishes
-#                responses, and the request path never blocks on a
-#                device->host fetch.  Served natively by BOTH the
-#                single-table backend and the mesh (the shard_map ring
-#                step, parallel/sharded.make_mesh_ring_step) — ring on
-#                a mesh no longer silently falls back; only a backend
-#                without ring support degrades to pipelined.
-#   megaround  — ring plus the adaptive round accumulator: the ring
-#                capacity multiplies to GUBER_RING_SLOTS x
-#                GUBER_RING_ROUNDS and a backlog past the base tier
-#                dispatches as ONE mega scan (ops/ring.mega_ring_step)
-#                — the XLA entry amortized across the whole block,
-#                with add-latency bounded by GUBER_RING_MAX_LINGER_US.
-#                A shallow queue dispatches immediately at base tiers.
-#   persistent — the ring protocol served by the persistent Pallas
-#                decision kernel (ops/pallas/serve_kernel.py): one
-#                kernel LAUNCH drains the whole block with the table
-#                resident across rounds.  TPU-only; capability is
-#                PROBED at arm time and the daemon degrades to
-#                megaround with the reason in /debug/vars where the
-#                kernel cannot compile (docs/ring.md's matrix).
-SERVE_MODES = ("classic", "pipelined", "ring", "megaround", "persistent")
-
-
-def normalize_serve_mode(value: str) -> str:
-    """Canonicalize a serve mode; raise on anything unknown — a typo
-    must not silently drop the daemon to a slower discipline."""
-    v = (value or "").strip().lower() or "pipelined"
-    if v not in SERVE_MODES:
-        raise ValueError(
-            f"unknown serve mode {value!r}; expected one of "
-            + ", ".join(repr(m) for m in SERVE_MODES)
-        )
-    return v
-
-
 @dataclass
 class DeviceConfig:
     """TPU-specific geometry (no reference analog — replaces the Go worker
@@ -969,27 +926,6 @@ class DaemonConfig:
     # serialized end to end); raise past 2 only if pipeline-occupancy
     # telemetry shows the depth saturated AND bubble time is nonzero.
     pipeline_depth: int = 2
-    # Fast-lane drain discipline (SERVE_MODES; docs/ring.md).  "ring"
-    # takes host fetches off the request path entirely: enqueue ->
-    # poll response slot, with the device loop fed by a request ring.
-    serve_mode: str = "pipelined"
-    # Request-ring capacity in ROUNDS (GUBER_RING_SLOTS): how many
-    # packed [12, B] rounds one ring iteration may consume (the bounded
-    # jitted scan's slot budget) and how many may queue before
-    # producers block (backpressure, measured as ring slot-wait).
-    # Each power-of-two tier up to this costs one XLA compile at
-    # warmup.
-    ring_slots: int = 8
-    # Megaround multiplier (GUBER_RING_ROUNDS; serve_mode=megaround or
-    # persistent): ring capacity widens to ring_slots x ring_rounds and
-    # a backlog past the base tier dispatches as ONE mega scan — the
-    # XLA entry amortized across the block (docs/ring.md).  1 disables.
-    ring_rounds: int = 4
-    # Adaptive accumulator's bounded add-latency in MICROSECONDS
-    # (GUBER_RING_MAX_LINGER_US): how long the runner may wait for a
-    # mega block to fill once the queue is already past the base tier.
-    # A shallow queue never waits.  0 disables lingering.
-    ring_max_linger_us: float = 200.0
     # Flight recorder / SLO telemetry (runtime/flightrec.py).  Off by
     # default: the ring + sampler are cheap, but dumps write to disk and
     # operators should choose the directory.
@@ -1214,71 +1150,6 @@ def pipeline_depth_from_env() -> int:
     )
 
 
-def serve_mode_from_env() -> str:
-    """The fast-lane drain-discipline knob (GUBER_SERVE_MODE), parsed/
-    validated exactly as the daemon does — rejects unknown modes at
-    startup (same harness contract as pipeline_depth_from_env)."""
-    return normalize_serve_mode(_env("GUBER_SERVE_MODE", "pipelined"))
-
-
-def ring_slots_from_env() -> int:
-    """The request-ring capacity knob (GUBER_RING_SLOTS), validated at
-    daemon startup: fewer than 1 slot cannot hold a round, and past
-    1024 the per-tier XLA compiles + the padded scan's wasted work
-    outgrow any coalescing win — both are config mistakes, not
-    tunings."""
-    v = _require_min(
-        "GUBER_RING_SLOTS", _env_int("GUBER_RING_SLOTS", 8), 1
-    )
-    if v > 1024:
-        raise ValueError(f"GUBER_RING_SLOTS must be <= 1024, got {v}")
-    return v
-
-
-def ring_rounds_from_env() -> int:
-    """The megaround multiplier (GUBER_RING_ROUNDS): how many base-tier
-    ring rounds one mega dispatch may amortize — capacity becomes
-    GUBER_RING_SLOTS x GUBER_RING_ROUNDS rounds (docs/ring.md).  1
-    disables megaround (the plain ring ladder); past 64 the mega-tier
-    compiles and the scan's padded work outgrow the amortization win —
-    a config mistake, rejected at startup.  The combined
-    slots x rounds capacity is bounded in setup_daemon_config (the two
-    knobs compose)."""
-    v = _require_min(
-        "GUBER_RING_ROUNDS", _env_int("GUBER_RING_ROUNDS", 4), 1
-    )
-    if v > 64:
-        raise ValueError(f"GUBER_RING_ROUNDS must be <= 64, got {v}")
-    return v
-
-
-def ring_linger_us_from_env() -> float:
-    """The megaround accumulator's add-latency bound
-    (GUBER_RING_MAX_LINGER_US, microseconds): how long the runner may
-    wait for a mega block to fill once the queue is already past the
-    base tier.  0 disables lingering (backlog still widens blocks to
-    whatever has queued); past 1s it stops being a linger and starts
-    being an outage — rejected at startup."""
-    raw = _env("GUBER_RING_MAX_LINGER_US", "200")
-    try:
-        v = float(raw)
-    except ValueError:
-        raise ValueError(
-            f"GUBER_RING_MAX_LINGER_US must be a number of "
-            f"microseconds, got {raw!r}"
-        ) from None
-    if v < 0:
-        raise ValueError(
-            f"GUBER_RING_MAX_LINGER_US must be >= 0, got {raw!r}"
-        )
-    if v > 1_000_000:
-        raise ValueError(
-            "GUBER_RING_MAX_LINGER_US must be <= 1000000 (1s), got "
-            f"{raw!r}"
-        )
-    return v
-
-
 def mesh_ways_from_env() -> int:
     """The mesh axis size (GUBER_MESH_WAYS — the deployment-mode
     spelling for "shards mapped onto mesh axes"; GUBER_TPU_NUM_SHARDS
@@ -1300,6 +1171,21 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
     """Build a DaemonConfig from GUBER_* env vars (config.go:253-459)."""
     if config_file:
         load_config_file(config_file)
+
+    # Settings removed with the drain disciplines they selected: refused
+    # by name, never ignored in silence.
+    mode = _env("GUBER_SERVE_MODE").strip().lower()
+    if mode not in ("", "pipelined"):
+        raise ValueError(
+            f"GUBER_SERVE_MODE={mode!r} is not supported: the served path "
+            "has one drain discipline ('pipelined'); unset the variable"
+        )
+    for name in sorted(os.environ):
+        if name.startswith("GUBER_RING") and os.environ[name]:
+            raise ValueError(
+                f"{name} is not supported: the ring drain disciplines "
+                "were removed; unset the variable"
+            )
 
     behaviors = BehaviorConfig(
         batch_timeout_s=_env_float_s("GUBER_BATCH_TIMEOUT", DEFAULT_BATCH_TIMEOUT_S),
@@ -1386,14 +1272,6 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
             "GUBER_DEGRADED_SHADOW_FRACTION must be in (0, 1], got "
             f"{shadow_fraction}"
         )
-    ring_rounds = ring_rounds_from_env()
-    if ring_slots_from_env() * ring_rounds > 4096:
-        # The knobs compose: capacity = slots x rounds bounds both the
-        # mega-tier compile ladder and the padded scan's worst case.
-        raise ValueError(
-            "GUBER_RING_SLOTS x GUBER_RING_ROUNDS must be <= 4096, got "
-            f"{ring_slots_from_env()} x {ring_rounds}"
-        )
     return DaemonConfig(
         grpc_listen_address=_env("GUBER_GRPC_ADDRESS", "localhost:1051"),
         http_listen_address=_env("GUBER_HTTP_ADDRESS", "localhost:1050"),
@@ -1434,10 +1312,6 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
         ),
         fastpath_sparse=fastpath_sparse_from_env(),
         pipeline_depth=pipeline_depth_from_env(),
-        serve_mode=serve_mode_from_env(),
-        ring_slots=ring_slots_from_env(),
-        ring_rounds=ring_rounds,
-        ring_max_linger_us=ring_linger_us_from_env(),
         flightrec=_env("GUBER_FLIGHTREC") in ("1", "true"),
         flightrec_dir=_env("GUBER_FLIGHTREC_DIR", "flightrec-dumps"),
         flightrec_ring=_require_min(

@@ -372,12 +372,10 @@ class Daemon:
         self._warmup_s = 0.0
         self.fastpath = None
         # Gubstat census sampler (runtime/gubstat.py): armed in start()
-        # per GUBER_STATS_ENABLED, closed before the fastpath (its ring
-        # host jobs need the runner alive).
+        # per GUBER_STATS_ENABLED, closed before the fastpath.
         self.stats_sampler = None
         # Guberberg tier manager (runtime/coldtier.py): armed in
-        # start() per GUBER_TIER_ENABLED, closed before the fastpath
-        # (its promote jobs ride the ring's host-job lane).
+        # start() per GUBER_TIER_ENABLED, closed before the fastpath.
         self.tier = None
         self._grpc_server: Optional[grpc.aio.Server] = None
         self._grpc_tls_proxy = None  # net.tls.TLSTerminatingProxy
@@ -444,19 +442,7 @@ class Daemon:
             max_inflight=getattr(self.conf, "fastpath_inflight", 1),
             sparse_limit=getattr(self.conf, "fastpath_sparse", 64),
             pipeline_depth=getattr(self.conf, "pipeline_depth", 2),
-            serve_mode=getattr(self.conf, "serve_mode", "pipelined"),
-            ring_slots=getattr(self.conf, "ring_slots", 8),
-            ring_rounds=getattr(self.conf, "ring_rounds", 4),
-            ring_max_linger_us=getattr(
-                self.conf, "ring_max_linger_us", 200.0
-            ),
         )
-        if self.fastpath._ring is not None:
-            # Compile every ring block shape up front — a cold scan
-            # compile inside a serving iteration is a p99 cliff.
-            await asyncio.get_running_loop().run_in_executor(
-                None, self.fastpath._ring.warmup
-            )
         # Table build + every start-up compile (or compile-cache load).
         self._warmup_s = time.monotonic() - t_warm
         if cfg.stats.enabled:
@@ -468,7 +454,6 @@ class Daemon:
 
             self.stats_sampler = TableStatsSampler(
                 self.service,
-                fastpath=self.fastpath,
                 metrics=self.metrics,
                 interval_s=cfg.stats.interval_s,
             )
@@ -480,14 +465,13 @@ class Daemon:
         if cfg.tier.enabled:
             # Guberberg tier manager (runtime/coldtier.py;
             # docs/tiering.md): host-RAM cold tier under the HBM table,
-            # promote-on-access through the ring's host-job lane,
-            # watermark demotion on its own worker thread.
+            # promote-on-access and watermark demotion on its own
+            # worker thread.
             from gubernator_tpu.runtime.coldtier import TierManager
 
             self.tier = TierManager(
                 self.service,
                 cfg.tier,
-                fastpath=self.fastpath,
                 metrics=self.metrics,
             )
             self.service.tier = self.tier
@@ -642,14 +626,12 @@ class Daemon:
             await self._http_runner.cleanup()
             self._http_runner = None
         if self.stats_sampler is not None:
-            # Before the fastpath: an in-flight sample may hold a ring
-            # host job that needs the runner to drain it.
+            # Before the fastpath and the service close under it.
             await self.stats_sampler.close()
             self.stats_sampler = None
         if self.tier is not None:
-            # Same ordering rule: the tier worker's promote/demote jobs
-            # ride the ring host-job lane, so stop it while the runner
-            # can still drain them.
+            # Same ordering rule for the tier worker's promote/demote
+            # jobs.
             await asyncio.get_running_loop().run_in_executor(
                 None, self.tier.close
             )
@@ -752,8 +734,8 @@ class Daemon:
                     self.service.global_engine.cache_occupancy()
                 )
             # Per-shard mesh gauges (docs/architecture.md): occupancy
-            # skew and ring sequence words, refreshed at scrape like
-            # the aggregate occupancy above.
+            # skew, refreshed at scrape like the aggregate occupancy
+            # above.
             shard_occ = getattr(
                 self.service.backend, "shard_occupancy", None
             )
@@ -762,12 +744,6 @@ class Daemon:
                     self.metrics.shard_occupancy.labels(
                         shard=str(s)
                     ).set(occ)
-            fp = self.fastpath
-            if fp is not None and fp._ring is not None:
-                for s, word in enumerate(fp._ring.seq_shards):
-                    self.metrics.shard_ring_seq.labels(
-                        shard=str(s)
-                    ).set(word)
             # Gubstat top-K tenant gauges: refreshed at scrape (stale
             # tenant labels removed); the table census gauges refresh
             # on the sampler's own cadence, never here.
@@ -859,8 +835,7 @@ class Daemon:
                 "not_persisted": be.not_persisted,
                 "occupancy": be.occupancy(),
             }
-            # Mesh backends: the per-shard skew view (docs/ring.md's
-            # per-shard seq rides the fastpath `ring` block below).
+            # Mesh backends: the per-shard skew view.
             shard_occ = getattr(be, "shard_occupancy", None)
             if shard_occ is not None:
                 out["backend"]["shard_occupancy"] = shard_occ()

@@ -461,7 +461,7 @@ demote_extract = jax.jit(
 # pressure), slot-age and TTL-expiry histograms, the remaining-fraction
 # distribution per algorithm, and a census of the reserved shadow-slot
 # classes — in ONE non-donated device pass, so a periodic sampler can
-# ride the ring runner's host-job queue without ever touching the
+# run it from an executor thread without ever touching the
 # request path (the table is read, never written, and never donated).
 # --------------------------------------------------------------------------
 

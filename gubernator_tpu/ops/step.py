@@ -723,11 +723,6 @@ def apply_batch_packed_impl(
     return new_table, packed
 
 
-apply_batch_packed = jax.jit(
-    apply_batch_packed_impl, static_argnames=("ways",), donate_argnums=(0,)
-)
-
-
 def unpack_batch_q(q) -> DeviceBatchJ:
     """Device-side unpack of ONE int64[12, B] request array (row order =
     DeviceBatch field order; bools/int32s travel widened as int64)."""

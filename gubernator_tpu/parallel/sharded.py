@@ -78,7 +78,7 @@ def pack_requests_sharded(
 # A per-field path would cost 12 sharded host->device puts and 9
 # device->host reads per round.  Here the whole DeviceBatch travels as
 # ONE int64[12, n, B] array and the response returns as ONE
-# int64[n, 9, B] array (the mesh analog of ops/step.apply_batch_packed).
+# int64[n, 9, B] array (the mesh analog of ops/step.apply_batch_packed_q).
 
 
 def pack_grid_batch(db) -> np.ndarray:
@@ -107,7 +107,7 @@ def make_sharded_step_packed(mesh, ways: int):
     """Jitted multi-device step over packed transfers:
     table'[n·S], resp[n, 9, B] = step(table[n·S], batch[12, n, B], now).
 
-    Response row order is apply_batch_packed's: status, limit, remaining,
+    Response row order is apply_batch_packed_q's: status, limit, remaining,
     reset_time, persisted, found, stored, cached, stored_status (one
     shared packer, ops/step.py).
     """
@@ -138,74 +138,6 @@ def packed_grid_rounds_to_host(round_resps) -> List[Dict[str, np.ndarray]]:
     return [
         _packed_resp_dict(a) for a in fetch_ravel(list(round_resps))
     ]
-
-
-def make_mesh_ring_step(mesh, ways: int):
-    """The ring drain's bounded multi-round scan, lifted to the sharded
-    grid table (docs/ring.md):
-
-        table'[n·S], resps[k, n, 9, B], seq'[n] =
-            mesh_ring_step(table[n·S], qs[k, 12, n, B], nows[k], seq[n])
-
-    Each shard runs ops/ring.ring_step_impl — the EXACT single-table
-    scan body — on its local [k, 12, B] request block, so mesh-ring ≡
-    one ring per shard by construction.  The table is donated (the loop
-    updates each shard's HBM block in place); the per-shard sequence
-    words are NOT (the double-buffered response protocol must still
-    fetch iteration N's words after iteration N+1 dispatched with them
-    as input — the same keep rule as the single-device seq).  The hot
-    path needs NO collectives: routing already placed every lane on its
-    owner shard, so the scan compiles to independent per-device loops
-    over ICI-free local work."""
-    from gubernator_tpu.ops.ring import ring_step_impl
-
-    def _local(table: SlotTable, qs, nows, seq):
-        t2, resps, s2 = ring_step_impl(
-            table, qs[:, :, 0, :], nows, seq[0], ways=ways
-        )
-        return t2, resps[:, None], s2[None]
-
-    sharded = _shard_map(
-        _local,
-        mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(None, None, SHARD_AXIS), P(),
-                  P(SHARD_AXIS)),
-        out_specs=(P(SHARD_AXIS), P(None, SHARD_AXIS), P(SHARD_AXIS)),
-    )
-    return jax.jit(sharded, donate_argnums=(0,))
-
-
-def make_mesh_mega_ring_step(mesh, ways: int):
-    """Megaround serving on the mesh (docs/ring.md):
-
-        table'[n·S], resps[r, s, n, 9, B], seq'[n] =
-            mesh_mega_ring_step(table[n·S], qs[r, s, 12, n, B],
-                                nows[r, s], seq[n])
-
-    The same composition rule as the base mesh ring: each shard runs
-    ops/ring.mega_ring_step_impl — the EXACT single-table megaround
-    scan-of-scans — on its local [r, s, 12, B] block, so
-    mesh-megaround ≡ one megaround loop per shard by construction.
-    Donation/keep rules are unchanged (table donated, per-shard seq
-    words kept for the double-buffered response protocol), and the hot
-    path still needs NO collectives."""
-    from gubernator_tpu.ops.ring import mega_ring_step_impl
-
-    def _local(table: SlotTable, qs, nows, seq):
-        t2, resps, s2 = mega_ring_step_impl(
-            table, qs[:, :, :, 0, :], nows, seq[0], ways=ways
-        )
-        return t2, resps[:, :, None], s2[None]
-
-    sharded = _shard_map(
-        _local,
-        mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(None, None, None, SHARD_AXIS), P(),
-                  P(SHARD_AXIS)),
-        out_specs=(P(SHARD_AXIS), P(None, None, SHARD_AXIS),
-                   P(SHARD_AXIS)),
-    )
-    return jax.jit(sharded, donate_argnums=(0,))
 
 
 def make_sharded_row_op(mesh, ways: int, impl, row_type):
@@ -394,18 +326,6 @@ class MeshBackend(PersistenceHost):
         self._tiers = resolve_tiers(cfg)
         # Batch input sharding: [12, n, B] split on the shard axis (dim 1).
         self._psharding = NamedSharding(self.mesh, P(None, SHARD_AXIS))
-        # Ring request-block sharding: [k, 12, n, B] split on dim 2.
-        self._qsharding = NamedSharding(
-            self.mesh, P(None, None, SHARD_AXIS)
-        )
-        self._ring_step = make_mesh_ring_step(self.mesh, cfg.ways)
-        # Megaround request-block sharding: [r, s, 12, n, B] on dim 3.
-        self._mega_qsharding = NamedSharding(
-            self.mesh, P(None, None, None, SHARD_AXIS)
-        )
-        self._mega_ring_step = make_mesh_mega_ring_step(
-            self.mesh, cfg.ways
-        )
         self._cached_store = make_sharded_row_op(
             self.mesh, cfg.ways, store_cached_rows_impl, CachedRows
         )
@@ -419,80 +339,9 @@ class MeshBackend(PersistenceHost):
         self.over_limit = 0
         self.not_persisted = 0
 
-    # -- ring drain discipline (runtime/ring.py; docs/ring.md) -----------
-    def ring_supported(self) -> bool:
-        """The mesh serves ring mode natively: make_mesh_ring_step is the
-        shard_map lift of the single-table scan, so GUBER_SERVE_MODE=ring
-        on a mesh service arms a real device loop instead of falling back
-        to the pipelined discipline (the pre-mesh-ring fallback rule is
-        retired; docs/ring.md)."""
-        return True
-
-    def ring_q_shape(self, tb: int) -> tuple:
-        """Per-round request-slot shape at batch tier `tb` — the grid
-        form [12, n_shards, tb] (the ring runner builds blocks of
-        (slot_tier,) + this shape)."""
-        return (12, self.cfg.num_shards, tb)
-
-    def ring_pack_round(self, db, tb: int) -> np.ndarray:
-        """One [n, B] grid DeviceBatch -> its ring slot [12, n, tb]."""
-        return pack_grid_batch(db)[:, :, :tb]
-
-    def ring_seq_init(self):
-        """Fresh per-shard sequence words (int64[n], sharded)."""
-        return jax.device_put(
-            np.zeros(self.cfg.num_shards, dtype=np.int64),
-            self._bsharding,
-        )
-
-    def ring_step_dispatch(self, qs: np.ndarray, nows: np.ndarray, seq):
-        """Dispatch one bounded mesh ring iteration — `qs`
-        int64[k, 12, n, B] stacked grid rounds — under the lock (the
-        same single-writer section as every other table mutation).
-        Returns the un-synced device (responses[k, n, 9, B], per-shard
-        seq words); the ring runner fetches them off the request path."""
-        lock_wait = self._stages.stage("backend.lock_wait")
-        with self._lock:
-            lock_wait.end()
-            with self._stages.stage("backend.dispatch"):
-                batch = jax.device_put(qs, self._qsharding)
-                self.table, resps, seq = self._ring_step(
-                    self.table, batch, np.asarray(nows, dtype=np.int64),
-                    seq,
-                )
-        return resps, seq
-
-    def ring_mega_dispatch(self, qs: np.ndarray, nows: np.ndarray, seq):
-        """Dispatch one MEGAROUND mesh iteration — `qs`
-        int64[r, s, 12, n, B] stacked ring rounds applied in order by
-        the shard_map megaround scan (make_mesh_mega_ring_step) — under
-        the lock.  Returns the un-synced device
-        (responses[r, s, n, 9, B], per-shard seq words); the ring
-        runner flattens the (r, s) round axes back on the host."""
-        lock_wait = self._stages.stage("backend.lock_wait")
-        with self._lock:
-            lock_wait.end()
-            with self._stages.stage("backend.dispatch"):
-                batch = jax.device_put(qs, self._mega_qsharding)
-                self.table, resps, seq = self._mega_ring_step(
-                    self.table, batch, np.asarray(nows, dtype=np.int64),
-                    seq,
-                )
-        return resps, seq
-
     def device_info(self) -> dict:
         return device_info(
             list(self.mesh.devices.flat), self.cfg.platform
-        )
-
-    def persistent_serve_supported(self):
-        """The persistent Pallas decision kernel owns ONE table block;
-        the sharded grid table has no shard_map lift for it yet —
-        honest capability reporting per docs/ring.md: megaround is the
-        mesh's dispatch-amortization tier."""
-        return False, (
-            "persistent serve kernel is single-table only; mesh "
-            "backends serve megaround (the shard_map mega ring step)"
         )
 
     def _add_tally(self, tally) -> None:
@@ -1079,8 +928,8 @@ class MeshBackend(PersistenceHost):
         """Promote-path inject for the mesh: the generic
         PersistenceHost.migrate_inject_rows path already serializes on
         self._lock, so the whole probe+upsert+merge runs inside the
-        fetch closure on the tier manager's executor — off the ring
-        runner, same lock discipline, same (injected, merged) result."""
+        fetch closure on the tier manager's executor thread — same
+        lock discipline, same (injected, merged) result."""
         def fetch():
             return self.migrate_inject_rows(cols)
 

@@ -468,7 +468,7 @@ class DeviceBackend(PersistenceHost):
         self._tiers = resolve_tiers(self.cfg)
         self._load_rows = functools.partial(load_rows, ways=self.cfg.ways)
         self._probe = functools.partial(probe_batch, ways=self.cfg.ways)
-        # Module-level jits (apply_batch_packed/load_rows/probe_batch/
+        # Module-level jits (apply_batch_packed_q/load_rows/probe_batch/
         # store_cached_rows) share one compile cache across backends — the
         # in-process cluster fixture runs many daemons per process and
         # per-instance jits would recompile per daemon.
@@ -479,9 +479,6 @@ class DeviceBackend(PersistenceHost):
             gather_rows, ways=self.cfg.ways
         )
         self.store = store
-        # Force the persistent serve kernel's interpret emulation
-        # (tests/smokes on CPU; see persistent_serve_supported).
-        self._persistent_interpret = False
         # fingerprint -> hash-key string, maintained when persistence needs
         # to reconstruct key strings from device rows (save path).
         self._keymap: Optional[Dict[int, str]] = (
@@ -646,119 +643,6 @@ class DeviceBackend(PersistenceHost):
                 )
                 round_resps.append(packed_resp)
         return round_resps
-
-    # -- ring drain discipline (runtime/ring.py) -------------------------
-    def ring_supported(self) -> bool:
-        """Single-table backends scan ops/ring.ring_step directly; the
-        mesh backend serves the same protocol through its shard_map lift
-        (parallel/sharded.make_mesh_ring_step) — both report True, and
-        the RingBackend shapes its blocks via ring_q_shape()."""
-        return True
-
-    def ring_q_shape(self, tb: int) -> tuple:
-        """Per-round request-slot shape at batch tier `tb`: [12, tb]
-        (pack_batch_q row order).  The mesh backend returns the grid
-        form [12, n_shards, tb]; the ring runner is layout-agnostic —
-        it only stacks rounds along a leading slot axis."""
-        return (12, tb)
-
-    def ring_pack_round(self, db, tb: int) -> np.ndarray:
-        """One [B] DeviceBatch -> its ring slot layout [12, tb]."""
-        return pack_batch_q(db)[:, :tb]
-
-    def ring_seq_init(self):
-        """A fresh device-resident sequence word for a RingBackend."""
-        import jax.numpy as jnp
-
-        with jax.default_device(self._device):
-            return jnp.zeros((), dtype=jnp.int64)
-
-    def ring_step_dispatch(self, qs: np.ndarray, nows: np.ndarray, seq):
-        """Dispatch one bounded ring iteration — `qs` int64[k, 12, B]
-        stacked rounds applied in order by ops/ring.ring_step — under
-        the lock (the same single-writer section as every other table
-        mutation, so store write-through and the object path dispatch-
-        order against ring steps).  Returns the un-synced device
-        (responses, new seq word); the ring runner fetches them off the
-        request path."""
-        from gubernator_tpu.ops.ring import ring_step
-
-        lock_wait = self._stages.stage("backend.lock_wait")
-        with self._lock:
-            lock_wait.end()
-            with self._stages.stage("backend.dispatch"):
-                self.table, resps, seq = ring_step(
-                    self.table, qs, nows, seq, ways=self.cfg.ways
-                )
-        return resps, seq
-
-    def ring_mega_dispatch(self, qs: np.ndarray, nows: np.ndarray, seq):
-        """Dispatch one MEGAROUND iteration — `qs` int64[r, s, 12, B]
-        stacked ring rounds applied in order by ops/ring.mega_ring_step
-        (ONE XLA entry for r*s rounds; docs/ring.md's
-        dispatch-amortization tier) — under the lock.  Returns the
-        un-synced device (responses[r, s, 9, B], new seq word); the
-        ring runner flattens the (r, s) round axes back on the host."""
-        from gubernator_tpu.ops.ring import mega_ring_step
-
-        lock_wait = self._stages.stage("backend.lock_wait")
-        with self._lock:
-            lock_wait.end()
-            with self._stages.stage("backend.dispatch"):
-                self.table, resps, seq = mega_ring_step(
-                    self.table, qs, nows, seq, ways=self.cfg.ways
-                )
-        return resps, seq
-
-    # -- persistent serve kernel (ops/pallas/serve_kernel.py) ------------
-    def persistent_serve_supported(self):
-        """(ok, reason) capability report for GUBER_SERVE_MODE=
-        persistent: a real probe compile on this backend's platform
-        (docs/ring.md's capability matrix), or the forced interpret
-        mode tests/smokes use to exercise the persistent serving path
-        on CPU.  The runtime falls back to megaround when not ok and
-        surfaces the reason in /debug/vars."""
-        if self._persistent_interpret:
-            return True, (
-                "interpret mode forced (CPU emulation; differential "
-                "tests/smokes only — not a performance mode)"
-            )
-        from gubernator_tpu.ops.pallas.serve_kernel import (
-            persistent_supported,
-        )
-
-        return persistent_supported(
-            self._device.platform, self.cfg.num_slots, self.cfg.ways,
-            self.cfg.batch_size,
-        )
-
-    def persistent_serve_dispatch(
-        self, qs: np.ndarray, nows: np.ndarray, seq
-    ):
-        """Dispatch one persistent-kernel iteration — `qs`
-        int64[k, 12, B] stacked rounds drained inside ONE Pallas launch
-        — under the lock.  Same contract as ring_step_dispatch; the
-        interpret form runs the un-jitted emulation (exact, slow — the
-        differential path, never a deployment mode)."""
-        from gubernator_tpu.ops.pallas.serve_kernel import (
-            persistent_serve_step,
-            persistent_serve_step_impl,
-        )
-
-        lock_wait = self._stages.stage("backend.lock_wait")
-        with self._lock:
-            lock_wait.end()
-            with self._stages.stage("backend.dispatch"):
-                if self._persistent_interpret:
-                    self.table, resps, seq = persistent_serve_step_impl(
-                        self.table, qs, nows, seq, ways=self.cfg.ways,
-                        interpret=True,
-                    )
-                else:
-                    self.table, resps, seq = persistent_serve_step(
-                        self.table, qs, nows, seq, ways=self.cfg.ways
-                    )
-        return resps, seq
 
     def _probe_padded(self, hashes: np.ndarray, now: int) -> np.ndarray:
         """found-mask for a host hash vector, probing in fixed batch_size
@@ -1129,8 +1013,8 @@ class DeviceBackend(PersistenceHost):
         closure.  The kernel is read-only and NON-donated, so the
         serving table is untouched and the dispatched result buffers
         are pinned to this table version — the sampler fetches them
-        off the request path (a ring host job or an executor thread)
-        while the lock is long released.  Every leaf of the fetched
+        on its own executor thread, off the request path, while the
+        lock is long released.  Every leaf of the fetched
         TableStats carries a leading shard axis (length 1 here; the
         mesh backend returns one row per shard)."""
         from gubernator_tpu.ops.state import TableStats, table_stats
@@ -1149,9 +1033,9 @@ class DeviceBackend(PersistenceHost):
     def occupancy_dispatch(self):
         """Dispatch the resident-slot count under the lock and return a
         zero-arg fetch closure — the tier manager's watermark read.
-        Split from occupancy() so a ring host job never blocks the
-        runner on the device->host scalar sync (the manager fetches on
-        its own executor, the gubstat discipline)."""
+        Split from occupancy() so the lock is not held through the
+        device->host scalar sync (the manager fetches on its own
+        executor thread, the gubstat discipline)."""
         with self._lock:
             occ = self.table.occupancy()
 
@@ -1167,7 +1051,8 @@ class DeviceBackend(PersistenceHost):
         rows, gathers their fields, and clears the slots atomically.
         Returns a zero-arg fetch closure yielding (packed int64
         [10, batch] in DEMOTE_ROW_FIELDS order, float64[batch]
-        remaining_f) — dispatched on the ring runner, fetched off it."""
+        remaining_f) — dispatched and fetched on the tier manager's
+        executor thread."""
         from gubernator_tpu.ops.state import demote_extract
 
         now = np.int64(self.clock.millisecond_now())
@@ -1321,7 +1206,7 @@ def _fetch_ravel(arrs) -> List[np.ndarray]:
 
 
 def _packed_resp_dict(a: np.ndarray) -> Dict[str, np.ndarray]:
-    """apply_batch_packed row order -> named host columns; `a` is
+    """apply_batch_packed_q row order -> named host columns; `a` is
     [9, B] (single table) or [n, 9, B] (grid, leading shard dim)."""
     sl = (slice(None),) * (a.ndim - 2)
     return {
@@ -1338,7 +1223,7 @@ def _packed_resp_dict(a: np.ndarray) -> Dict[str, np.ndarray]:
 
 
 def packed_rounds_to_host(round_packed) -> List[Dict[str, np.ndarray]]:
-    """Host view of packed int64[9, B] responses (apply_batch_packed row
+    """Host view of packed int64[9, B] responses (apply_batch_packed_q row
     order) — ONE transfer for all rounds (fetch_ravel)."""
     return [
         _packed_resp_dict(a) for a in fetch_ravel(list(round_packed))

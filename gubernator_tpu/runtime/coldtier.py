@@ -19,7 +19,7 @@ holds everybody else.  Two pieces:
   frequency and sends only the provably-coldest to the cold tier,
   re-injecting the rest.  Promotion is access-driven: the request path
   calls ``note_access`` with each served batch; a fingerprint that
-  hits the cold tier rides a FIFO host job (ring.submit_host) that
+  hits the cold tier is handed to the manager's worker thread, which
   pops the row and injects it via the ``migrate_inject`` merge path —
   the request that observed the miss was already served from a fresh
   row, the NEXT round sees the merged history.  The inject retries
@@ -49,7 +49,7 @@ import logging
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -252,8 +252,8 @@ class ColdTier:
 
 class TierManager:
     """The two-tier residency policy: watermark-driven demotion on a
-    background worker, access-driven promotion through the ring's FIFO
-    host-job lane.  One instance per daemon, armed by
+    background worker, access-driven promotion on the same worker
+    thread.  One instance per daemon, armed by
     ``GUBER_TIER_ENABLED`` (daemon.py wires ``service.tier`` so the
     request path's ``note_traffic`` feeds it)."""
 
@@ -263,7 +263,6 @@ class TierManager:
         self,
         service: Any,
         cfg: Any,
-        fastpath: Optional[Any] = None,
         metrics: Optional[Any] = None,
     ) -> None:
         from gubernator_tpu.runtime.metrics import LATENCY_BUCKETS
@@ -272,7 +271,6 @@ class TierManager:
         self.service = service
         self.backend = service.backend
         self.cfg = cfg
-        self.fastpath = fastpath
         self.metrics = metrics
         self.cold = ColdTier(cfg.cold_capacity)
         # The manager's OWN sketch: residency ranking must reflect
@@ -316,7 +314,7 @@ class TierManager:
         """One served batch: feed the residency sketch, and schedule a
         promote for any fingerprint that is cold-resident.  Cheap by
         contract — a CMS update plus a set probe; the actual promote
-        rides the worker thread + ring host-job lane."""
+        rides the worker thread."""
         if not len(key_hashes):
             return
         kh = np.asarray(key_hashes, dtype=np.int64)
@@ -373,25 +371,9 @@ class TierManager:
                     self.demote_once_sync()
                     self.publish()
                 except Exception:
-                    # A closing ring/backend mid-tick is expected at
+                    # A closing backend mid-tick is expected at
                     # shutdown; pressure returns next tick.
                     log.debug("demote tick failed", exc_info=True)
-
-    def _run_job(self, fn: Callable[[], Any]) -> Any:
-        """Run a dispatch callable FIFO with the serving rounds when a
-        ring is live (never on the request path, never blocking the
-        runner beyond the dispatch itself); direct call otherwise.
-        Returns fn's result — by convention a zero-arg fetch closure
-        the CALLER resolves on this worker thread."""
-        from gubernator_tpu.runtime.ring import RingClosedError
-
-        ring = getattr(self.fastpath, "_ring", None)
-        if ring is not None and ring.available():
-            try:
-                return ring.submit_host(fn)()
-            except RingClosedError:
-                pass
-        return fn()
 
     # -- promote path --------------------------------------------------
     def _promote(self, fps: List[int], t0: float) -> int:
@@ -403,21 +385,12 @@ class TierManager:
             return 0
         try:
             try:
-                fetch = self._run_job(
-                    lambda: self.backend.migrate_inject_dispatch(cols)
-                )
-                fetch()
+                self.backend.migrate_inject_dispatch(cols)()
             except Exception:
-                # Retry ONCE (a broken ring falls back to a direct
-                # dispatch); then conserve the rows back to cold.
+                # Retry ONCE; then conserve the rows back to cold.
                 self.promote_retries += 1
                 try:
-                    fetch = self._run_job(
-                        lambda: self.backend.migrate_inject_dispatch(
-                            cols
-                        )
-                    )
-                    fetch()
+                    self.backend.migrate_inject_dispatch(cols)()
                 except Exception:
                     self.promote_failures += 1
                     self.cold.put_rows(cols)
@@ -470,7 +443,7 @@ class TierManager:
         need is met or the device runs out of eligible victims.
         Returns rows demoted to cold."""
         self.ticks += 1
-        occ = self._run_job(self.backend.occupancy_dispatch)()
+        occ = self.backend.occupancy_dispatch()()
         need = self.demote_need(occ)
         if need <= 0:
             return 0
@@ -480,12 +453,9 @@ class TierManager:
             if need <= 0:
                 break
             grid = self._protect_grid()
-            fetch = self._run_job(
-                lambda: self.backend.demote_extract_dispatch(
-                    grid, batch
-                )
-            )
-            packed, rf = fetch()
+            packed, rf = self.backend.demote_extract_dispatch(
+                grid, batch
+            )()
             self.demote_passes += 1
             sel = np.flatnonzero(packed[0] != 0)
             if not len(sel):
@@ -505,9 +475,7 @@ class TierManager:
             self.demotes += int(ncold)
             if len(keep_idx):
                 keep = self._cols_from_packed(packed, rf, keep_idx)
-                self._run_job(
-                    lambda: self.backend.migrate_inject_dispatch(keep)
-                )()
+                self.backend.migrate_inject_dispatch(keep)()
             need -= int(ncold)
             total += int(ncold)
         return total

@@ -531,29 +531,11 @@ class FastPath:
     backend lock; cascade merges hold that lock across their whole
     read -> replay -> write-back window, which serializes them against
     every other mutation path (this lane, the object path, the GLOBAL
-    managers) exactly like any other single-writer section.
-
-    `serve_mode` picks the drain discipline (docs/ring.md): "classic"
-    forces depth 1, "pipelined" is the depth-k overlap above, and
-    "ring" hands plain merges to the device-resident serving loop
-    (runtime/ring.py) — packed straight into ring slot layout, fetched
-    by the ring runner off the request path — with locked cascade/
-    store merges, sketch readbacks, and engine (GLOBAL collective)
-    readbacks riding the runner as FIFO host jobs.  Both the
-    single-table and the mesh backend serve ring mode (the mesh via the
-    shard_map ring step, parallel/sharded.make_mesh_ring_step); only a
-    backend without ring support — or a broken ring — falls back to the
-    pipelined discipline."""
+    managers) exactly like any other single-writer section."""
 
     def __init__(self, service, max_inflight: int = 1,
                  sparse_limit: int = 64,
-                 pipeline_depth: int = 2,
-                 serve_mode: str = "pipelined",
-                 ring_slots: int = 8,
-                 ring_rounds: int = 4,
-                 ring_max_linger_us: float = 200.0) -> None:
-        from gubernator_tpu.core.config import normalize_serve_mode
-
+                 pipeline_depth: int = 2) -> None:
         if max_inflight < 1:
             raise ValueError(
                 f"fastpath max_inflight must be >= 1, got {max_inflight}"
@@ -563,74 +545,13 @@ class FastPath:
                 f"fastpath pipeline_depth must be >= 1, "
                 f"got {pipeline_depth}"
             )
-        serve_mode = normalize_serve_mode(serve_mode)
         self.s = service
         metrics = service.metrics
         self._stages = tracing.ledger_of(metrics)
         self._stages.register("wire", tracing.WIRE_STAGES)
-        # Drain discipline (docs/ring.md): classic = strict depth-1,
-        # pipelined = depth-k fetch overlap, ring = the device-resident
-        # serving loop (runtime/ring.py) with NO blocking fetch on the
-        # request path, megaround = ring plus the adaptive round
-        # accumulator (dispatch amortized across up to
-        # ring_slots x ring_rounds rounds), persistent = the ring
-        # protocol served by the persistent Pallas decision kernel.
-        # Single-table AND mesh backends serve ring/megaround; only a
-        # backend without ring support degrades to pipelined, and
-        # persistent degrades to megaround wherever the kernel cannot
-        # compile — with the probe's reason kept for /debug/vars
-        # (docs/ring.md's capability matrix).
-        self.serve_mode = serve_mode  # requested
-        self._ring = None
-        self.persistent_status = None
-        if serve_mode == "classic":
-            pipeline_depth = 1
-        elif serve_mode in ("ring", "megaround", "persistent"):
-            backend = service.backend
-            persistent = False
-            if serve_mode == "persistent":
-                ok, reason = getattr(
-                    backend, "persistent_serve_supported",
-                    lambda: (
-                        False, "backend has no persistent serve kernel"
-                    ),
-                )()
-                self.persistent_status = {
-                    "supported": bool(ok), "reason": reason,
-                }
-                if ok:
-                    persistent = True
-                else:
-                    # Honest fallback: megaround is the next-best
-                    # dispatch-amortization tier, everywhere.
-                    serve_mode = "megaround"
-            rounds = 1 if serve_mode == "ring" else max(ring_rounds, 1)
-            if getattr(backend, "ring_supported", lambda: False)():
-                from gubernator_tpu.runtime.ring import RingBackend
-
-                self._ring = RingBackend(
-                    backend, slots=ring_slots, metrics=metrics,
-                    rounds=rounds,
-                    max_linger_us=(
-                        ring_max_linger_us if rounds > 1 else 0.0
-                    ),
-                    persistent=persistent,
-                )
-                # The coalescer's fetch stage in ring mode only waits on
-                # a published slot (cheap), so let enough merges be
-                # outstanding to keep the ring runner fed — and in
-                # megaround mode, enough to let a backlog actually form
-                # past the base tier (the accumulator's load signal).
-                pipeline_depth = max(
-                    pipeline_depth, min(ring_slots * rounds, 8)
-                )
-            else:
-                serve_mode = "pipelined"  # docs/ring.md fallback rule
-        self.effective_serve_mode = serve_mode
         # Blocking device->host fetches performed ON the request path
-        # (a coalescer dispatch/fetch stage), by lane.  The ring
-        # acceptance criterion: steady-state == 0 in ring mode
-        # (scripts/ring_smoke.py; bench_e2e budget split).
+        # (a coalescer dispatch/fetch stage), by lane.  The background
+        # planes (census, tiering) must leave it unchanged.
         self.blocking_fetches = {"mach": 0, "sketch": 0, "engine": 0}
         # Worker budget: one thread per concurrent dispatch stage plus
         # one per outstanding fetch (pipeline depth + sparse overlap
@@ -694,25 +615,14 @@ class FastPath:
             "served": self.served,
             "fallbacks": self.fallbacks,
             "pipeline_depth": self.pipeline_depth,
-            "serve_mode": self.serve_mode,
-            "effective_serve_mode": self.effective_serve_mode,
+            # One drain discipline; the benchmark compares these two
+            # keys (bench/run.py serve_mode_degraded).
+            "serve_mode": "pipelined",
+            "effective_serve_mode": "pipelined",
             "blocking_fetches": dict(self.blocking_fetches),
             "lanes": lanes,
         }
-        if self._ring is not None:
-            out["ring"] = self._ring.debug_vars()
-        if self.persistent_status is not None:
-            # Honest capability reporting for GUBER_SERVE_MODE=
-            # persistent: whether the Pallas serve kernel armed, and
-            # the probe's reason when it degraded to megaround.
-            out["persistent"] = dict(self.persistent_status)
         return out
-
-    def _ring_live(self):
-        """The RingBackend, if this merge may enter it (None once the
-        ring broke or closed — the per-merge fallback to pipelined)."""
-        r = self._ring
-        return r if (r is not None and r.available()) else None
 
     # -- eligibility -----------------------------------------------------
     def _eligible(self) -> bool:
@@ -1362,11 +1272,6 @@ class FastPath:
         pack.end()
         resps, want_sync = engine.serve_packed(rounds, pend)
 
-        def fetch_body() -> List[Tuple[np.ndarray, ...]]:
-            host = packed_grid_rounds_to_host(resps)
-            with tracing.stage("lane.unpack"):
-                return unpack(host)
-
         def unpack(host) -> List[Tuple[np.ndarray, ...]]:
             mt = len(h_all)
             st_u = np.zeros(mt, dtype=np.int64)
@@ -1400,25 +1305,11 @@ class FastPath:
                 ))
             return outs
 
-        # Ring discipline: the engine readback (and a triggered sync's
-        # collective + write-through) runs on the ring runner, FIFO with
-        # the ring iterations — the mesh request path stays fetch-free
-        # even for GLOBAL lanes (the sketch-lane pattern).
-        wait_body = None
-        ring = self._ring_live()
-        if ring is not None:
-            from gubernator_tpu.runtime.ring import RingClosedError
-
-            try:
-                wait_body = ring.submit_host(fetch_body)
-            except RingClosedError:
-                wait_body = None
-
         def fetch() -> List[Tuple[np.ndarray, ...]]:
-            if wait_body is not None:
-                return wait_body()
             self.blocking_fetches["engine"] += 1
-            return fetch_body()
+            host = packed_grid_rounds_to_host(resps)
+            with tracing.stage("lane.unpack"):
+                return unpack(host)
 
         return fetch
 
@@ -2019,26 +1910,11 @@ class FastPath:
                 ll = np.concatenate([e.limits for e in entries])
         with tracing.stage("backend.dispatch"):
             fetch_cols = self.s.sketch_backend.check_cols_begin(kh, hh, ll)
-        wait_cols = None
-        ring = self._ring_live()
-        if ring is not None:
-            # Ring discipline: the CMS readback runs on the ring runner
-            # (sketch state is independent of the slot table, so FIFO
-            # placement is for fetch-offloading, not ordering).
-            from gubernator_tpu.runtime.ring import RingClosedError
-
-            try:
-                wait_cols = ring.submit_host(fetch_cols)
-            except RingClosedError:
-                wait_cols = None
 
         def fetch() -> List[Tuple[np.ndarray, ...]]:
             with tracing.stage("backend.d2h_wait"):
-                if wait_cols is not None:
-                    st, rem, rst = wait_cols()
-                else:
-                    self.blocking_fetches["sketch"] += 1
-                    st, rem, rst = fetch_cols()
+                self.blocking_fetches["sketch"] += 1
+                st, rem, rst = fetch_cols()
             outs: List[Tuple[np.ndarray, ...]] = []
             off = 0
             for e in entries:
@@ -2154,30 +2030,9 @@ class FastPath:
             is_greg=is_greg, greg_expire=ge, greg_duration=gd,
             use_cached=use_cached,
         )
-        # Ring-eligible merge (plain): scatter the parsed columns
-        # STRAIGHT into ring slot layout — no DeviceBatch objects exist
-        # between the C++ parse and the device loop.  On a mesh backend
-        # the scatter targets shard-grid slots ([n_shards, tb] per field
-        # row), so the columns land exactly where the shard_map ring
-        # step reads them.
-        ring = (
-            self._ring_live()
-            if (plan is None and not do_store)
-            else None
+        rounds, order, bounds = _build_rounds(
+            values, rnd, lane, sh_all, n_rounds, n_shards, B
         )
-        ring_qs = None
-        if ring is not None:
-            ring_qs, order, bounds = _build_rounds_q(
-                values, rnd, lane, n_rounds, backend._tiers,
-                sh_all=sh_all if n_shards > 1 else None,
-                n_shards=n_shards,
-            )
-            rounds = [_QRound(ring_qs[i, 10] != 0)
-                      for i in range(n_rounds)]
-        else:
-            rounds, order, bounds = _build_rounds(
-                values, rnd, lane, sh_all, n_rounds, n_shards, B
-            )
 
         status = np.zeros(n, dtype=np.int64)
         out_lim = np.zeros(n, dtype=np.int64)
@@ -2218,41 +2073,6 @@ class FastPath:
             )
 
         if plan is None and not do_store:
-            if ring is not None:
-                # Ring merge (docs/ring.md): the pre-packed slots enter
-                # the request ring and the device loop applies them; this
-                # fetch stage only WAITS on the published response slot —
-                # the actual device->host readback happens on the ring
-                # runner, off the request path entirely.
-                from gubernator_tpu.runtime.ring import RingClosedError
-
-                pack.end()
-                try:
-                    wait_rounds = ring.submit_q(ring_qs)
-                except RingClosedError:
-                    # Broke/closed between the check and the submit,
-                    # with NOTHING enqueued: rebuild DeviceBatch rounds
-                    # and take the pipelined path below (rare; the ring
-                    # never reopens).  A multi-chunk submit that loses
-                    # the ring part-way raises PartialSubmitError
-                    # instead — deliberately NOT caught here: the
-                    # queued chunks' device effects may already have
-                    # landed, so re-dispatching would double-apply
-                    # them; the error propagates and fails the merge.
-                    rounds, order, bounds = _build_rounds(
-                        values, rnd, lane, sh_all, n_rounds, n_shards, B
-                    )
-                else:
-                    def fetch_ring() -> List[Tuple[np.ndarray, ...]]:
-                        # The fetch itself is the ring runner's; the
-                        # request path waits for its publication.
-                        with tracing.stage("backend.d2h_wait"):
-                            host_box.append(wait_rounds())
-                        with tracing.stage("lane.unpack"):
-                            gather(host_box[0])
-                            return finish()
-
-                    return fetch_ring
             # Plain merge: dispatch under the backend lock; the response
             # sync rides the coalescer's FETCH stage, so the next
             # maximal merge dispatches while this one's response syncs
@@ -2292,120 +2112,96 @@ class FastPath:
         # fetch stage is the rf fetch + write-through delivery below.
         cap_token = wt_seq = None
         cap_fps = int_hosts = None
-
-        def locked_merge() -> None:
-            # The whole locked window, wrapped so the ring discipline can
-            # run it verbatim on the ring runner (submit_host) — its
-            # in-lock host syncs then happen off the request path, FIFO
-            # with the ring iterations, and write-through tickets keep
-            # dispatch order against ring steps.  One nonlocal set: the
-            # captures fetch_locked_merge needs.
-            nonlocal cap_token, wt_seq, cap_fps, int_hosts
-            lock_wait = tracing.stage("backend.lock_wait")
-            with backend._lock:
-                lock_wait.end()
-                resps = backend._dispatch_rounds_locked(rounds)
-                if plan is not None:
-                    host_box.append(to_host(resps))
-                    cascade = tracing.stage("lane.cascade")
-                    cascade.tally(**casc_counts)
-                    gather(host_box[0])
-                    wb = _run_cascade(
-                        plan, h, hits, lim, dur, algo, burst,
-                        status, out_lim, remaining, reset, stored, cachedv,
-                        foundv, stored_st,
-                    )
-                    if wb is None:
-                        cascade.end()
-                    else:
-                        (wb_h, wb_hits, wb_lim, wb_dur, wb_algo,
-                         wb_burst) = wb
-                        wb_sh = (
-                            shard_of_hash(wb_h, n_shards).astype(np.int32)
-                            if n_shards > 1 else None
-                        )
-                        wrnd, wlane, wn = native.assign_rounds(
-                            wb_h, wb_sh, n_shards, B
-                        )
-                        m = len(wb_h)
-                        wvals = dict(
-                            key_hash=wb_h, hits=wb_hits, limit=wb_lim,
-                            duration=wb_dur, algo=wb_algo, burst=wb_burst,
-                            reset_remaining=np.zeros(m, dtype=bool),
-                            is_greg=np.zeros(m, dtype=bool),
-                            greg_expire=np.zeros(m, dtype=np.int64),
-                            greg_duration=np.zeros(m, dtype=np.int64),
-                        )
-                        wb_rounds, _, _ = _build_rounds(
-                            wvals, wrnd, wlane,
-                            wb_sh if wb_sh is not None
-                            else np.zeros(m, dtype=np.int32),
-                            wn, n_shards, B,
-                        )
-                        cascade.end()
-                        backend._dispatch_rounds_locked(wb_rounds)
-                if do_store:
-                    from gubernator_tpu.runtime.backend import (
-                        _packed_resp_dict,
-                        fetch_ravel,
-                    )
-
-                    now_ms = backend.clock.millisecond_now()
-                    cap_fps = np.array(
-                        [fp for fp, v in uniq.items() if v[2] is not None],
-                        dtype=np.int64,
-                    )
-                    # Optimistic capture: dispatched with the step so the
-                    # warm path fetches response + capture in ONE
-                    # round-trip; a repair below re-dispatches it.
-                    cap_token = backend._gather_rows_dispatch(
-                        cap_fps, now_ms
-                    )
-                    cap_ints = backend._gather_rows_int_arrays(cap_token)
-                    if plan is None:
-                        hosts = fetch_ravel(list(resps) + cap_ints)
-                        nr = len(resps)
-                        host_box.append(
-                            [_packed_resp_dict(hh) for hh in hosts[:nr]]
-                        )
-                        gather(host_box[0])
-                        int_hosts = hosts[nr:]
-                    else:
-                        int_hosts = fetch_ravel(cap_ints)
-                    rep = self._repair_cold_store_keys(
-                        backend, uniq, foundv, h, dict(
-                            hits=hits, limit=lim, duration=dur, algo=algo,
-                            burst=burst, reset_remaining=reset_remaining,
-                            is_greg=is_greg, greg_expire=ge,
-                            greg_duration=gd, use_cached=use_cached,
-                        ),
-                        sh_all, n_shards, B, now_ms,
-                        (status, out_lim, remaining, reset, stored,
-                         cachedv, stored_st),
-                    )
-                    if rep is not None:
-                        # Rows changed under the optimistic capture —
-                        # refetch it (packed with the repair responses
-                        # inside _repair_cold_store_keys).
-                        cap_token, int_hosts = rep
-                    wt_seq = backend._wt_ticket()
-
-        ring = self._ring_live()
-        wait_locked = None
-        if ring is not None:
-            # Ring discipline: the locked window (with its in-lock host
-            # syncs) runs on the ring runner, FIFO with the ring
-            # iterations — the request path only waits on the result.
-            from gubernator_tpu.runtime.ring import RingClosedError
-
-            try:
-                wait_locked = ring.submit_host(locked_merge)
-            except RingClosedError:
-                ring = None
         pack.end()
-        if ring is None:
-            self.blocking_fetches["mach"] += 1
-            locked_merge()
+        self.blocking_fetches["mach"] += 1
+        lock_wait = tracing.stage("backend.lock_wait")
+        with backend._lock:
+            lock_wait.end()
+            resps = backend._dispatch_rounds_locked(rounds)
+            if plan is not None:
+                host_box.append(to_host(resps))
+                cascade = tracing.stage("lane.cascade")
+                cascade.tally(**casc_counts)
+                gather(host_box[0])
+                wb = _run_cascade(
+                    plan, h, hits, lim, dur, algo, burst,
+                    status, out_lim, remaining, reset, stored, cachedv,
+                    foundv, stored_st,
+                )
+                if wb is None:
+                    cascade.end()
+                else:
+                    (wb_h, wb_hits, wb_lim, wb_dur, wb_algo,
+                     wb_burst) = wb
+                    wb_sh = (
+                        shard_of_hash(wb_h, n_shards).astype(np.int32)
+                        if n_shards > 1 else None
+                    )
+                    wrnd, wlane, wn = native.assign_rounds(
+                        wb_h, wb_sh, n_shards, B
+                    )
+                    m = len(wb_h)
+                    wvals = dict(
+                        key_hash=wb_h, hits=wb_hits, limit=wb_lim,
+                        duration=wb_dur, algo=wb_algo, burst=wb_burst,
+                        reset_remaining=np.zeros(m, dtype=bool),
+                        is_greg=np.zeros(m, dtype=bool),
+                        greg_expire=np.zeros(m, dtype=np.int64),
+                        greg_duration=np.zeros(m, dtype=np.int64),
+                    )
+                    wb_rounds, _, _ = _build_rounds(
+                        wvals, wrnd, wlane,
+                        wb_sh if wb_sh is not None
+                        else np.zeros(m, dtype=np.int32),
+                        wn, n_shards, B,
+                    )
+                    cascade.end()
+                    backend._dispatch_rounds_locked(wb_rounds)
+            if do_store:
+                from gubernator_tpu.runtime.backend import (
+                    _packed_resp_dict,
+                    fetch_ravel,
+                )
+
+                now_ms = backend.clock.millisecond_now()
+                cap_fps = np.array(
+                    [fp for fp, v in uniq.items() if v[2] is not None],
+                    dtype=np.int64,
+                )
+                # Optimistic capture: dispatched with the step so the
+                # warm path fetches response + capture in ONE
+                # round-trip; a repair below re-dispatches it.
+                cap_token = backend._gather_rows_dispatch(
+                    cap_fps, now_ms
+                )
+                cap_ints = backend._gather_rows_int_arrays(cap_token)
+                if plan is None:
+                    hosts = fetch_ravel(list(resps) + cap_ints)
+                    nr = len(resps)
+                    host_box.append(
+                        [_packed_resp_dict(hh) for hh in hosts[:nr]]
+                    )
+                    gather(host_box[0])
+                    int_hosts = hosts[nr:]
+                else:
+                    int_hosts = fetch_ravel(cap_ints)
+                rep = self._repair_cold_store_keys(
+                    backend, uniq, foundv, h, dict(
+                        hits=hits, limit=lim, duration=dur, algo=algo,
+                        burst=burst, reset_remaining=reset_remaining,
+                        is_greg=is_greg, greg_expire=ge,
+                        greg_duration=gd, use_cached=use_cached,
+                    ),
+                    sh_all, n_shards, B, now_ms,
+                    (status, out_lim, remaining, reset, stored,
+                     cachedv, stored_st),
+                )
+                if rep is not None:
+                    # Rows changed under the optimistic capture —
+                    # refetch it (packed with the repair responses
+                    # inside _repair_cold_store_keys).
+                    cap_token, int_hosts = rep
+                wt_seq = backend._wt_ticket()
 
         def fetch_locked_merge() -> List[Tuple[np.ndarray, ...]]:
             # Fetch stage of a cascade/store merge: the response host
@@ -2414,8 +2210,6 @@ class FastPath:
             # capture build, and the Store.on_change delivery — user
             # code plus a ticket wait that must never block the next
             # merge's dispatch.
-            if wait_locked is not None:
-                wait_locked()
             if do_store:
                 from gubernator_tpu.runtime.backend import fetch_ravel
 
@@ -2423,10 +2217,8 @@ class FastPath:
                 try:
                     rf_hosts = None
                     if bool((algo == 1).any()):
-                        # The one residual request-path sync a store
-                        # drain keeps in ring mode: the leaky-capture
-                        # remaining_f readback (ordering-free, so it
-                        # needn't ride the runner).
+                        # The leaky-capture remaining_f readback
+                        # (ordering-free, so it needn't sit in the lock).
                         self.blocking_fetches["mach"] += 1
                         rf_hosts = fetch_ravel(
                             backend._gather_rows_rf_arrays(cap_token)
@@ -2554,17 +2346,12 @@ class FastPath:
     async def close(self) -> None:
         # Machinery first (its in-flight dispatches may still fan into
         # the sketch lane), then the sketch lane; both refuse new work
-        # the moment their close() starts.  The ring closes AFTER the
-        # coalescers: their in-flight fetch stages wait on ring slots,
-        # so the runner must stay alive until they drain (ring.close
-        # then publishes/fails whatever is left).
+        # the moment their close() starts.
         await self._mach.close()
         if self._sketch_lane is not None:
             await self._sketch_lane.close()
         if self._engine_lane is not None:
             await self._engine_lane.close()
-        if self._ring is not None:
-            self._ring.close()
         self._pool.shutdown(wait=True)
         self._sketch_pool.shutdown(wait=True)
         self._engine_pool.shutdown(wait=True)
@@ -2641,56 +2428,6 @@ def _build_rounds(values, rnd, lane, sh_all, n_rounds, n_shards, B):
             grid if n_shards > 1 else DeviceBatch(*[a[0] for a in grid])
         )
     return rounds, order, bounds
-
-
-# Ring slot row order == DeviceBatch field order == unpack_batch_q rows.
-_Q_ROW = {
-    f: i for i, f in enumerate((
-        "key_hash", "hits", "limit", "duration", "algo", "burst",
-        "reset_remaining", "is_greg", "greg_expire", "greg_duration",
-        "active", "use_cached",
-    ))
-}
-
-
-class _QRound:
-    """tally_from_rounds-compatible view of one prepacked ring slot
-    (only `.active` is ever read on the ring path)."""
-
-    __slots__ = ("active",)
-
-    def __init__(self, active: np.ndarray) -> None:
-        self.active = active
-
-
-def _build_rounds_q(values, rnd, lane, n_rounds, tiers,
-                    sh_all=None, n_shards=1):
-    """Scatter columnar values STRAIGHT into ring slot layout — one
-    int64[k, 12, tb] stacked request block (pack_batch_q row order), or
-    int64[k, 12, n_shards, tb] on a mesh backend, where the parser's
-    columns land in shard-grid slots with one scatter per field —
-    skipping DeviceBatch assembly entirely.  Returns (qs, order, bounds)
-    with order/bounds exactly as _build_rounds computes them."""
-    ok = np.flatnonzero(rnd >= 0)
-    order = ok[np.argsort(rnd[ok], kind="stable")]
-    bounds = np.searchsorted(rnd[order], np.arange(n_rounds + 1))
-    # Lanes fill contiguously from 0 per (round, shard) (assign_rounds),
-    # so the max assigned lane bounds the highest used one — the same
-    # compiled-tier rule as backend.tier_of.
-    occ = int(lane[ok].max()) + 1 if len(ok) else 0
-    tb = next((t for t in tiers if occ <= t), tiers[-1])
-    grid = n_shards > 1
-    shape = (n_rounds, 12, n_shards, tb) if grid else (n_rounds, 12, tb)
-    qs = np.zeros(shape, dtype=np.int64)
-    for r_idx in range(n_rounds):
-        sel = order[bounds[r_idx]:bounds[r_idx + 1]]
-        l_m = lane[sel]
-        q = qs[r_idx]
-        idx = (sh_all[sel], l_m) if grid else (l_m,)
-        for f, v in values.items():
-            q[(_Q_ROW[f],) + idx] = v[sel]
-        q[(_Q_ROW["active"],) + idx] = 1
-    return qs, order, bounds
 
 
 class _CascadePlan:

@@ -144,7 +144,7 @@ class FlightRecorder:
 
         When the producer runs inside a sampled trace (the span plane
         binds its context on whichever thread executes a stage — the
-        coalescer's fetch stage, the ring runner, the event loop), the
+        coalescer's fetch stage, the event loop), the
         record carries the trace/span ids, so a breach dump's ring can
         be joined against the trace behind its p99 bucket."""
         rec = {"ts": time.time(), "kind": kind}
@@ -165,21 +165,13 @@ class FlightRecorder:
         errors: int = 0,
         peer: str = "",
         kind: str = "device_step",
-        rounds_per_dispatch: float = None,
     ) -> None:
         """One device step / peer batch: the ISSUE's record shape
-        (batch size, outcome mix, peer, step wall time).  Ring records
-        (kind="ring_iter") carry the running dispatch-amortization
-        factor so a breach dump shows whether megaround was actually
-        amortizing when the tail spiked (docs/ring.md)."""
+        (batch size, outcome mix, peer, step wall time)."""
         self.record(
             kind, size=int(size), step_ms=round(step_ms, 3),
             over_limit=int(over_limit), errors=int(errors),
             **({"peer": peer} if peer else {}),
-            **(
-                {"rounds_per_dispatch": float(rounds_per_dispatch)}
-                if rounds_per_dispatch is not None else {}
-            ),
         )
 
     def record_bubble(self, lane: str, wait_ms: float) -> None:

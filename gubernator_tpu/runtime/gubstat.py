@@ -6,11 +6,11 @@ Two planes live here, deliberately decoupled from the request path:
 **TableStatsSampler** periodically runs the `table_stats` census kernel
 (ops/state.py) against the live serving table.  The kernel is read-only
 and non-donated, so a sample never perturbs serving state; the dispatch
-is serialized with serving steps (backend lock, or a FIFO host job on
-the ring runner in ring mode) but the device->host FETCH always happens
-on an executor thread — the ring runner and the event loop never block
-on a stats readback, and the fast lane's `blocking_fetches` ledger
-stays untouched (pinned by tests/test_gubstat.py).
+is serialized with serving steps by the backend lock, and both it and
+the device->host FETCH happen on an executor thread — the event loop
+never blocks on a stats readback, and the fast lane's
+`blocking_fetches` ledger stays untouched (pinned by
+tests/test_gubstat.py).
 
 **TenantAccounting** attributes admitted/denied/shed HITS to limit
 names, bounded to a top-K working set: a count-min sketch (HostCMS)
@@ -322,23 +322,19 @@ class TableStatsSampler:
 
     Each sample: enumerate the service's derived-key fingerprints per
     shadow plane, pad to a power-of-two grid (bounded recompiles),
-    dispatch `table_stats` against the live table — via a FIFO host
-    job on the ring runner when the fast lane's ring is armed (the
-    dispatch slots between serving rounds), else directly under the
-    backend lock on an executor thread — then fetch the result on an
-    executor thread and publish it to /debug/vars, the
+    dispatch `table_stats` against the live table under the backend
+    lock on an executor thread, then fetch the result on an executor
+    thread and publish it to /debug/vars, the
     gubernator_table_* gauges, and the flight recorder.
     """
 
     def __init__(
         self,
         service,
-        fastpath=None,
         metrics=None,
         interval_s: float = 5.0,
     ) -> None:
         self.service = service
-        self.fastpath = fastpath
         self.metrics = metrics
         self.interval_s = float(interval_s)
         self.samples = 0
@@ -367,7 +363,7 @@ class TableStatsSampler:
                 raise
             except Exception:
                 # Sampling must never take the daemon down; a closing
-                # ring/backend mid-sample is expected at shutdown.
+                # backend mid-sample is expected at shutdown.
                 self.errors += 1
                 log.debug("table-stats sample failed", exc_info=True)
             await asyncio.sleep(self.interval_s)
@@ -389,8 +385,6 @@ class TableStatsSampler:
 
     async def sample(self) -> dict:
         """Take one census now; returns the published table block."""
-        from gubernator_tpu.runtime.ring import RingClosedError
-
         backend = self.service.backend
         grid = self._shadow_grid()
         loop = asyncio.get_running_loop()
@@ -398,20 +392,7 @@ class TableStatsSampler:
         def dispatch():
             return backend.table_stats_dispatch(grid)
 
-        ring = getattr(self.fastpath, "_ring", None)
-        fetch = None
-        if ring is not None:
-            # Ring mode: the dispatch must interleave with the runner's
-            # serving loop — submit it as a FIFO host job.  wait() only
-            # blocks THIS executor thread for the runner's round edge;
-            # the fetch below never runs on the runner.
-            try:
-                wait = ring.submit_host(dispatch)
-                fetch = await loop.run_in_executor(None, wait)
-            except RingClosedError:
-                fetch = None
-        if fetch is None:
-            fetch = await loop.run_in_executor(None, dispatch)
+        fetch = await loop.run_in_executor(None, dispatch)
         st = await loop.run_in_executor(None, fetch)
         block = self._publish(st, grid)
         return block

@@ -560,40 +560,6 @@ class Metrics:
             registry=r,
         )
 
-        # -- ring drain discipline (runtime/ring.py; docs/ring.md) --------
-        self.fastpath_ring_occupancy = Histogram(
-            "gubernator_fastpath_ring_occupancy",
-            "Request-ring rounds consumed per device-loop iteration "
-            "(before padding to the compiled slot tier) — sustained "
-            "occupancy at GUBER_RING_SLOTS with nonzero slot-wait means "
-            "a bigger ring may help.",
-            buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0),
-            registry=r,
-        )
-        self.fastpath_ring_slot_wait = Histogram(
-            "gubernator_fastpath_ring_slot_wait",
-            "Time a merge spent blocked waiting for free request-ring "
-            "slots (ring-full backpressure) in seconds.",
-            buckets=LATENCY_BUCKETS,
-            registry=r,
-        )
-        self.fastpath_ring_loop_lag = Gauge(
-            "gubernator_fastpath_ring_loop_lag_seconds",
-            "Latest gap between consecutive ring device-loop dispatches "
-            "— the serving loop's heartbeat (large values while traffic "
-            "queues mean the runner is stuck on a host job or fetch).",
-            registry=r,
-        )
-        self.ring_rounds_per_dispatch = Gauge(
-            "gubernator_ring_rounds_per_dispatch",
-            "Running dispatch-amortization factor: real (un-padded) "
-            "rounds served per device dispatch since the ring armed.  "
-            "Megaround serving (GUBER_RING_ROUNDS > 1) exists to raise "
-            "this under load; ~1.0 under saturating traffic means every "
-            "round still pays its own XLA entry (docs/ring.md).",
-            registry=r,
-        )
-
         # -- TPU-specific -------------------------------------------------
         self.device_step_duration = Histogram(
             "gubernator_tpu_device_step_duration",
@@ -625,8 +591,7 @@ class Metrics:
         # Per-shard mesh observability (docs/architecture.md mesh
         # deployment mode): the aggregate occupancy hides skew — a
         # production key set piling onto one shard is visible only
-        # per-shard, and a lagging per-shard ring sequence word means
-        # that shard's loop dropped or replayed a block.
+        # per-shard.
         self.shard_occupancy = Gauge(
             "gubernator_shard_occupancy",
             "Occupied slots per mesh shard (mesh backends only; skewed "
@@ -634,15 +599,6 @@ class Metrics:
             ["shard"],
             registry=r,
         )
-        self.shard_ring_seq = Gauge(
-            "gubernator_shard_ring_seq",
-            "Per-shard ring sequence word at the last fetched iteration "
-            "(ring mode; every shard must match the host mirror — see "
-            "docs/ring.md's sequence protocol).",
-            ["shard"],
-            registry=r,
-        )
-
         # -- gubstat: device-table census (runtime/gubstat.py;
         #    docs/observability.md).  All refreshed on the sampler's
         #    cadence (GUBER_STATS_INTERVAL), not at scrape — the census

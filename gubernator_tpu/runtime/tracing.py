@@ -4,8 +4,7 @@ The reference wraps nearly every function in holster/OTel scopes
 (gubernator.go:118-121, workers.go:250-253, algorithms.go:32-35) and
 exports to Jaeger/OTLP via standard env vars (jaegertracing.md).  This
 runtime's request path is a deep async pipeline — coalesced merges,
-dispatch/fetch stages, ring slots, a runner thread, FIFO host jobs, peer
-forwards — so a span plane that only knows the RPC boundary cannot
+dispatch/fetch stages on pool threads, peer forwards — so a span plane that only knows the RPC boundary cannot
 answer "where did the 300ms go".  This module is the attribution core:
 
   * **Spans** are lightweight in-process records (trace/span ids,
@@ -16,10 +15,10 @@ answer "where did the 300ms go".  This module is the attribution core:
     OTLP; otherwise they stay in-process (a bounded recent-span ring
     that the flight recorder attaches to breach dumps).
   * **Context** rides a contextvar on the event loop and is carried
-    EXPLICITLY across every thread hand-off (coalescer entries, ring
-    jobs) — contextvars do not cross `run_in_executor`, so each async
+    EXPLICITLY across every thread hand-off (coalescer entries) —
+    contextvars do not cross `run_in_executor`, so each async
     seam stores the submitting context and re-binds it on the worker
-    (`wrap` / `use_context`).
+    (`use_context`).
   * **Cross-peer**: `grpc_metadata()` renders the current context as a
     w3c `traceparent` header for outbound peer RPCs;
     `parse_traceparent()` is the server-side extract (daemon.py's
@@ -405,8 +404,8 @@ def start_span(
     **attrs,
 ) -> Optional[Span]:
     """Manually managed span (caller must `end()` it) with an EXPLICIT
-    parent — the form the cross-thread seams use (coalescer merges, ring
-    iterations), where the submitting context was captured earlier.
+    parent — the form the cross-thread seams use (coalescer merges),
+    where the submitting context was captured earlier.
     Returns None when tracing is disabled or the parent is unsampled."""
     st = _state
     if st is None or parent is None or not parent.sampled:
@@ -460,8 +459,8 @@ def span(
 
 @contextlib.contextmanager
 def use_context(ctx: Optional[SpanContext]) -> Iterator[None]:
-    """Bind an explicitly carried context on the current thread (ring
-    runner, pool workers) without opening a new span."""
+    """Bind an explicitly carried context on the current thread (pool
+    workers) without opening a new span."""
     if _state is None or ctx is None:
         yield
         return
@@ -470,21 +469,6 @@ def use_context(ctx: Optional[SpanContext]) -> Iterator[None]:
         yield
     finally:
         _current.reset(token)
-
-
-def wrap(fn, name: str, parent: Optional[SpanContext], **attrs):
-    """Wrap a zero-arg callable in a child span of `parent`, binding the
-    context on whichever thread runs it.  Returns `fn` unchanged when
-    tracing is disabled or there is no parent — the executor seams call
-    this unconditionally and pay nothing in the disabled path."""
-    if _state is None or parent is None:
-        return fn
-
-    def _traced():
-        with span(name, parent=parent, **attrs):
-            return fn()
-
-    return _traced
 
 
 # -- lifecycle / introspection -------------------------------------------
@@ -668,9 +652,9 @@ def recent_spans_for(
 # key is (lane, stage) and /debug/vars renders `stages.<lane>.<stage>`
 # with the layer dropped (the stage halves are unique).  `lane` is
 # "wire" for the per-RPC stages, the coalescer lane's own name
-# (mach / sketch / engine) for everything a drain does, "ring" on the
-# ring runner, "direct" for the object path and library callers, and
-# the layer itself for the process-wide rows (global, xla).
+# (mach / sketch / engine) for everything a drain does, "direct" for
+# the object path and library callers, and the layer itself for the
+# process-wide rows (global, xla).
 STAGES: Dict[str, str] = {
     # per RPC, event loop
     "wire.rpc": "stats interceptor entry -> return, every unary method "

@@ -26,7 +26,7 @@ from gubernator_tpu.runtime.tracing import (
 
 class MemorySpanExporter:
     """Collects finished spans; thread-safe (spans finish on the event
-    loop, pool workers, and the ring runner alike)."""
+    loop and pool workers alike)."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()

@@ -1,8 +1,8 @@
 """Trace smoke: ONE connected trace across a 2-daemon cluster, and a
 trace-tagged breach dump — the ISSUE 7 acceptance run.
 
-Three phases against real daemons (in-process cluster, ring serve mode,
-flight recorder armed):
+Three phases against real daemons (in-process cluster, flight recorder
+armed):
 
   0. DISABLED — tracing unconfigured: traffic flows, the span plane
      reports {"enabled": False}, zero spans exist, and flight-recorder
@@ -10,10 +10,10 @@ flight recorder armed):
   1. ONE TRACE — a client root context rides w3c `traceparent` into
      daemon A, whose zero-copy forward carries it to the owner daemon
      B; the trace must contain: both daemons' `rpc.server` spans, the
-     `peer.forward` hop, the owner's `fastpath.merge`, and a
-     `ring.iteration` span carrying the monotone sequence-word
-     attribute (`ring.seq`) — client -> coalescer merge -> ring round
-     -> peer forward, one trace id end to end.
+     `peer.forward` hop, the owner's `fastpath.merge` and the
+     merge's `gub.backend.dispatch` stage span — client -> peer
+     forward -> coalescer merge -> device dispatch, one trace id end to
+     end.
   2. BREACH DUMP — the owner daemon's SLO target is dropped to an
      unmeetable value; the forced dump's flightrec records carry the
      matching trace id AND the dump embeds the trace's spans
@@ -60,8 +60,6 @@ def main() -> None:
     from gubernator_tpu.testing.tracing import MemorySpanExporter
 
     conf = DaemonConfig(
-        serve_mode="ring",
-        ring_slots=4,
         flightrec=True,
         flightrec_dir=DUMP_DIR,
     )
@@ -137,16 +135,14 @@ def main() -> None:
             fail(f"peer.forward span missing (got {names})", exporter)
         if not any(s.name == "fastpath.merge" for s in spans):
             fail(f"fastpath.merge span missing (got {names})", exporter)
-        its = [s for s in spans if s.name == "ring.iteration"]
-        if not its or "ring.seq" not in its[0].attributes:
+        if not any(s.name == "gub.backend.dispatch" for s in spans):
             fail(
-                f"ring.iteration with ring.seq missing (got {names})",
+                f"gub.backend.dispatch span missing (got {names})",
                 exporter,
             )
         print(
             "trace_smoke: phase 1 OK — one trace "
-            f"({len(spans)} spans: {names}), ring.seq="
-            f"{its[0].attributes['ring.seq']}"
+            f"({len(spans)} spans: {names})"
         )
 
         # -- phase 2: trace-tagged breach dump --------------------------

@@ -20,7 +20,7 @@ sys.modules.setdefault("bench_gate", bench_gate)
 _SPEC.loader.exec_module(bench_gate)
 
 
-def _artifact(platform="cpu", p50=10.0, cps=1000.0, mode="ring"):
+def _artifact(platform="cpu", p50=10.0, cps=1000.0, mode="m1"):
     return {
         "round": 1,
         "platform": platform,
@@ -101,10 +101,11 @@ def test_warn_only_flag_downgrades(capsys):
 
 
 def test_mode_keys_never_cross_compare():
-    """A megaround line must never be judged against a ring baseline —
-    the key includes serve_mode, so disjoint modes simply don't match."""
-    base = _artifact(p50=10.0, mode="ring")
-    new = _artifact(p50=1000.0, mode="megaround")
+    """A line of one mode must never be judged against another mode's
+    baseline — the key includes serve_mode, so disjoint modes simply
+    don't match."""
+    base = _artifact(p50=10.0, mode="m1")
+    new = _artifact(p50=1000.0, mode="m2")
     assert bench_gate.gate(base, new, 0.25, False) == 0
 
 

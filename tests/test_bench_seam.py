@@ -1,0 +1,351 @@
+"""The program's side of the benchmark's seam.
+
+`bench/` (BENCHMARK.json's harness) reads the program through three
+doors: `/debug/vars` paths, `/metrics` series, and — in `bench/serve.py`,
+which starts the daemon in-process — a handful of private names.  The
+driver's chip run is the only thing that notices when one of them is
+renamed, as `output_malformed`, at the cost of a PR.  These cases notice
+here.  The files under `bench/` are read, never written and never
+imported: a path named by a layer metric is found by parsing its JSON;
+a name the harness's code reaches for is found by reading, so those are
+written out below, each with the line that uses it.
+
+One daemon of each kind per module: one chip for the `mach` and `wire`
+rows, a 4-shard mesh with GLOBAL traffic and one sync tick for the
+`engine` and `global` rows.
+"""
+from __future__ import annotations
+
+import json
+import re
+import time
+import urllib.request
+from pathlib import Path
+
+import pytest
+
+from gubernator_tpu.core.config import DeviceConfig
+from gubernator_tpu.proto import gubernator_pb2 as pb
+
+BENCH = Path(__file__).resolve().parent.parent / "bench"
+GET_RATE_LIMITS = "/pb.gubernator.V1/GetRateLimits"
+
+
+def _layer_reads():
+    """(vars paths, series) named by bench/layer_metrics/*.json."""
+    paths, series = set(), set()
+
+    def walk(node):
+        if isinstance(node, str) and node.startswith("vars:"):
+            paths.add(node[len("vars:"):])
+        elif isinstance(node, dict) and "metrics" in node:
+            series.add((node["metrics"],
+                        tuple(sorted(node.get("labels", {}).items()))))
+        elif isinstance(node, dict):
+            for v in node.values():
+                walk(v)
+        elif isinstance(node, list):
+            for v in node:
+                walk(v)
+
+    for f in sorted((BENCH / "layer_metrics").glob("*.json")):
+        walk(json.loads(f.read_text())["read"])
+    return sorted(paths), sorted(series)
+
+
+LAYER_VARS, LAYER_SERIES = _layer_reads()
+
+# /debug/vars keys the harness's code reads: (path, what it must hold).
+CODE_VARS = [
+    ("device.compiled_lane", True),               # bench/run.py:397
+    ("fastpath.fallbacks", "number"),             # bench/run.py:519
+    ("fastpath.serve_mode", "pipelined"),         # bench/run.py:521
+    ("fastpath.effective_serve_mode", "pipelined"),   # bench/run.py:521
+    ("backend.not_persisted", "number"),          # bench/run.py:526
+    ("backend.occupancy", "number"),              # bench/run.py:527
+    ("backend.checks", "number"),                 # bench/run.py:580
+    # bench/readers/global_sync_roofline_share.mesh.py:22
+    ("global.engine.sync_program.bytes_accessed", "number"),
+    ("global.engine.sync_program.shards", "number"),
+    ("global.engine.sync_program.delta_slots", "number"),
+    ("stages", "block"),       # bench/readers/idle_named_share.open.py:15
+]
+
+# Jitted programs the traced metrics find by name
+# (`read.program_regex` of bench/layer_metrics/*.json): regex, daemon,
+# where the program hangs.
+STEP_REGEX = "^jit_(apply_batch|sharded|_local)"
+SYNC_REGEX = "^jit__global_sync"
+PROGRAMS = [
+    (STEP_REGEX, "one_chip", "backend._step_packed_q"),
+    (STEP_REGEX, "mesh", "backend._step_packed"),
+    (SYNC_REGEX, "mesh", "global_engine._sync_step"),
+]
+
+# Names of the program bench/serve.py reaches for: (module or object,
+# attribute, "callable" | "attr", daemon).  Objects are spelled from the
+# service, as the harness spells them.
+MODULE_NAMES = [
+    ("gubernator_tpu.ops.step", "_f64", "callable"),              # :64
+    ("gubernator_tpu.runtime.backend", "_packed_resp_dict",
+     "callable"),                                                 # :70
+    ("gubernator_tpu.runtime.backend", "packed_rounds_to_host",
+     "callable"),                                                 # :139
+    ("gubernator_tpu.parallel.sharded", "packed_grid_rounds_to_host",
+     "callable"),                                                 # :135
+    ("gubernator_tpu.core.config", "setup_daemon_config",
+     "callable"),                                                 # :254
+    ("gubernator_tpu.daemon", "Daemon", "callable"),              # :256
+]
+OBJECT_NAMES = [
+    ("backend", "_install_table", "callable", "one_chip"),        # :100
+    ("backend", "_install_table", "callable", "mesh"),
+    ("backend", "occupancy", "callable", "one_chip"),             # :101
+    ("backend", "_found_mask", "callable", "one_chip"),           # :107
+    ("backend", "_found_mask", "callable", "mesh"),
+    ("backend", "_lock", "attr", "one_chip"),                     # :106
+    ("backend", "clock", "attr", "one_chip"),                     # :97
+    ("backend", "cfg", "attr", "one_chip"),                       # :105
+    ("backend", "table", "attr", "one_chip"),                     # :161
+    ("backend", "_tiers", "attr", "one_chip"),                    # :183
+    ("backend", "_step_packed_q", "callable", "one_chip"),        # :167
+    ("backend", "_step_packed", "callable", "mesh"),              # :161
+    ("backend", "_psharding", "attr", "mesh"),                    # :149
+    ("backend", "device_info", "callable", "one_chip"),           # :242
+    ("global_engine", "_lock", "attr", "mesh"),                   # :145
+    ("global_engine", "_ingest", "callable", "mesh"),             # :151
+    ("global_engine", "cache_table", "attr", "mesh"),             # :151
+    ("global_engine", "n", "attr", "mesh"),                       # :148
+]
+
+
+def _is_mesh_row(path: str) -> bool:
+    return re.search(r"(^|\.)(engine|global)(\.|$)", path) is not None
+
+
+def _resolve(tree, path: str):
+    """Every node at `path`; a `*` is every key of its level and must
+    match at least one."""
+    nodes = [tree]
+    for part in path.split("."):
+        nxt = []
+        for n in nodes:
+            if not isinstance(n, dict):
+                continue
+            if part == "*":
+                nxt.extend(n.values())
+            elif part in n:
+                nxt.append(n[part])
+        nodes = nxt
+    return nodes
+
+
+def _is_number(x) -> bool:
+    return isinstance(x, (int, float)) and not isinstance(x, bool)
+
+
+def _series(text: str):
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"^([A-Za-z_:][\w:]*)(\{.*\})?\s+(\S+)$", line)
+        if m and not line.startswith("#"):
+            out.append((m.group(1),
+                        dict(re.findall(r'(\w+)="((?:[^"\\]|\\.)*)"',
+                                        m.group(2) or ""))))
+    return out
+
+
+class _Seam:
+    """A started daemon and what the harness would have scraped."""
+
+    def __init__(self, cluster, global_keys: int) -> None:
+        import grpc.aio
+
+        self.cluster = cluster
+        self.daemon = d = cluster.daemon_at(0)
+
+        async def drive():
+            ch = grpc.aio.insecure_channel(d.grpc_address)
+            rpc = ch.unary_unary(GET_RATE_LIMITS)
+            try:
+                for i in range(4):
+                    reqs = [
+                        pb.RateLimitReq(
+                            name="seam", unique_key=f"k{i}_{j % 5}",
+                            hits=1, limit=100, duration=60_000,
+                            algorithm=j % 2,
+                        )
+                        for j in range(12)
+                    ] + [
+                        pb.RateLimitReq(
+                            name="seamg", unique_key=f"g{j}", hits=1,
+                            limit=1_000_000, duration=60_000,
+                            behavior=pb.GLOBAL,
+                        )
+                        for j in range(global_keys)
+                    ]
+                    raw = await rpc(pb.GetRateLimitsReq(
+                        requests=reqs
+                    ).SerializeToString())
+                    resp = pb.GetRateLimitsResp.FromString(raw)
+                    assert not any(r.error for r in resp.responses)
+            finally:
+                await ch.close()
+
+        cluster.run(drive(), timeout=120)
+        eng = d.service.global_engine
+        if eng is not None:
+            deadline = time.monotonic() + 60
+            while eng.debug_vars()["syncs"] == 0:
+                assert time.monotonic() < deadline, "no sync tick"
+                time.sleep(0.05)
+        self.vars = json.loads(self._get("/debug/vars"))
+        self.series = _series(self._get("/metrics").decode())
+
+    def _get(self, path: str) -> bytes:
+        with urllib.request.urlopen(
+            f"http://{self.daemon.http_address}{path}", timeout=30
+        ) as r:
+            return r.read()
+
+    def obj(self, name: str):
+        return getattr(self.daemon.service, name)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from gubernator_tpu import native
+    from gubernator_tpu.testing.cluster import Cluster
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    c = Cluster.start(1, device=DeviceConfig(
+        num_slots=1 << 12, ways=8, batch_size=128,
+    ))
+    try:
+        yield _Seam(c, global_keys=0)
+    finally:
+        c.stop()
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    from gubernator_tpu import native
+    from gubernator_tpu.testing.cluster import Cluster
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    c = Cluster.start(1, device=DeviceConfig(
+        num_slots=1 << 14, ways=8, batch_size=128, num_shards=4,
+    ))
+    try:
+        yield _Seam(c, global_keys=6)
+    finally:
+        c.stop()
+
+
+def _seam_for(request, path: str) -> _Seam:
+    return request.getfixturevalue(
+        "mesh" if _is_mesh_row(path) else "one_chip"
+    )
+
+
+# -- (a) /debug/vars ------------------------------------------------------
+
+@pytest.mark.parametrize("path", LAYER_VARS)
+def test_layer_metric_vars_path_resolves(request, path):
+    nodes = _resolve(_seam_for(request, path).vars, path)
+    assert nodes, f"/debug/vars has nothing at {path}"
+    assert all(_is_number(n) for n in nodes), (path, nodes)
+
+
+@pytest.mark.parametrize("path,want", CODE_VARS,
+                         ids=[p for p, _ in CODE_VARS])
+def test_harness_vars_key_resolves(request, path, want):
+    nodes = _resolve(_seam_for(request, path).vars, path)
+    assert len(nodes) == 1, f"/debug/vars has nothing at {path}"
+    got = nodes[0]
+    if want == "number":
+        assert _is_number(got), (path, got)
+    elif want == "block":
+        assert isinstance(got, dict) and got, (path, got)
+    else:
+        assert got == want and type(got) is type(want), (path, got)
+
+
+def test_layer_metrics_were_found():
+    # The parse above is the test's own; if the files change form it
+    # must fail here and not pass on nothing.
+    assert len(LAYER_VARS) >= 29 and len(LAYER_SERIES) >= 2
+
+
+# -- (b) private names ----------------------------------------------------
+
+@pytest.mark.parametrize(
+    "module,name,kind", MODULE_NAMES,
+    ids=[f"{m.rsplit('.', 1)[-1]}.{n}" for m, n, _ in MODULE_NAMES],
+)
+def test_module_name_bench_serve_uses(module, name, kind):
+    import importlib
+
+    got = getattr(importlib.import_module(module), name)
+    assert callable(got) == (kind == "callable")
+
+
+@pytest.mark.parametrize(
+    "obj,name,kind,daemon", OBJECT_NAMES,
+    ids=[f"{d}.{o}.{n}" for o, n, _, d in OBJECT_NAMES],
+)
+def test_object_name_bench_serve_uses(request, obj, name, kind, daemon):
+    target = request.getfixturevalue(daemon).obj(obj)
+    assert target is not None, obj
+    got = getattr(target, name)
+    if kind == "callable":
+        assert callable(got)
+    else:
+        assert got is not None and not callable(got)
+
+
+@pytest.mark.parametrize("daemon", ["one_chip", "mesh"])
+def test_device_info_and_warmup(request, daemon):
+    seam = request.getfixturevalue(daemon)
+    info = seam.obj("backend").device_info()    # bench/serve.py:242, :268
+    for key in ("platform", "device_kind", "device_count",
+                "table_device_ids"):
+        assert key in info, key
+    assert len(info["table_device_ids"]) == (4 if daemon == "mesh" else 1)
+    assert seam.daemon._warmup_s > 0            # bench/serve.py:270
+
+
+def test_step_packed_q_is_a_partial_of_a_jitted_function(one_chip):
+    # bench/serve.py:226-231 lowers `step.func` with `step.keywords`.
+    step = one_chip.obj("backend")._step_packed_q
+    assert callable(step.func.lower) and "ways" in step.keywords
+
+
+@pytest.mark.parametrize(
+    "regex,daemon,where", PROGRAMS,
+    ids=[f"{d}.{w}" for _, d, w in PROGRAMS],
+)
+def test_program_name_matches_the_traced_metrics_regex(
+    request, regex, daemon, where
+):
+    obj, attr = where.split(".")
+    fn = getattr(request.getfixturevalue(daemon).obj(obj), attr)
+    fn = getattr(fn, "func", fn)          # through a functools.partial
+    # XLA names a jitted program "jit_" + the function's name.
+    assert re.match(regex, "jit_" + fn.__name__), fn.__name__
+
+
+# -- (c) /metrics ---------------------------------------------------------
+
+@pytest.mark.parametrize(
+    "series,labels", LAYER_SERIES,
+    ids=[s for s, _ in LAYER_SERIES],
+)
+def test_layer_metric_series_is_exported(one_chip, series, labels):
+    want = dict(labels)
+    assert any(
+        name == series and all(lab.get(k) == v for k, v in want.items())
+        for name, lab in one_chip.series
+    ), f"/metrics has no {series}{want}"

@@ -70,9 +70,9 @@ def test_dry_run_summary(tmp_path):
     assert d["differential"]["token_mismatches"] == 0
     assert d["differential"]["leaky_status_mismatches"] == 0
     assert d["trunc_corners"]["mismatches"] == 0
-    # Both Pallas kernels refuse a non-interpret compile on the CPU; the
+    # The Pallas kernel refuses a non-interpret compile on the CPU; the
     # refusal is recorded, not failed.
-    assert set(d["kernels"]) == {"persistent_serve", "cms_pallas"}
+    assert set(d["kernels"]) == {"cms_pallas"}
     assert all(k["ok"] is False and k["reason"]
                for k in d["kernels"].values())
     assert json.loads((tmp_path / "out" / "summary.json").read_text()) == s

@@ -145,6 +145,32 @@ def test_fastpath_sparse_env(monkeypatch):
         fastpath_sparse_from_env()
 
 
+# The settings that selected a drain discipline are gone; a daemon must
+# refuse them by name, not ignore them.  The ring family is matched by
+# its prefix.
+_RING = "GUBER_RING"
+
+
+@pytest.mark.parametrize("name,value", [
+    ("GUBER_SERVE_MODE", "ring"),
+    ("GUBER_SERVE_MODE", "megaround"),
+    ("GUBER_SERVE_MODE", "persistent"),
+    ("GUBER_SERVE_MODE", "classic"),
+    (_RING + "_SLOTS", "8"),
+    (_RING + "_ROUNDS", "4"),
+    (_RING + "_MAX_LINGER_US", "200"),
+])
+def test_removed_drain_settings_are_refused(monkeypatch, name, value):
+    monkeypatch.setenv(name, value)
+    with pytest.raises(ValueError, match=name):
+        setup_daemon_config()
+
+
+def test_the_one_drain_discipline_is_accepted_by_name(monkeypatch):
+    monkeypatch.setenv("GUBER_SERVE_MODE", "pipelined")
+    assert setup_daemon_config().pipeline_depth == 2
+
+
 def test_device_config_validation():
     with pytest.raises(ValueError):
         DeviceConfig(num_slots=100, ways=8)

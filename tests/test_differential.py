@@ -428,13 +428,11 @@ def test_pipeline_depth_differential(frozen_clock):
     assert deep_drains >= 2  # traffic really coalesced into many merges
 
 
-def test_ring_mode_differential(frozen_clock):
-    """Ring mode is bit-identical to the classic depth-1 drain (ISSUE 6
-    acceptance): the same mixed token/leaky/GLOBAL/store traffic through
-    a classic and a ring compiled fast lane produces identical responses
-    and final table rows, while the ring run performs ZERO blocking
-    device->host fetches on the request path and its sequence word never
-    disagrees with the host mirror."""
+def test_pipeline_depth_differential_store_and_global(frozen_clock):
+    """The depth differential above with a Store attached and GLOBAL
+    keys in the mix (every merge takes the locked store arm): the same
+    traffic through a depth-1 and a depth-2 compiled fast lane produces
+    identical responses and final table rows."""
     import asyncio
 
     from gubernator_tpu import native
@@ -457,9 +455,9 @@ def test_ring_mode_differential(frozen_clock):
         # composition-dependent moment (cap_ok differs when merges
         # compose differently), and a re-read with CHANGED params (or
         # RESET_REMAINING) mutates the row — that schedule noise would
-        # make even two classic runs diverge.  With constant params and
+        # make even two depth-1 runs diverge.  With constant params and
         # a frozen clock the re-read is a no-op, so any difference left
-        # is a real ring bug.  Exact-tier keys (k0..k5) keep the full
+        # is a real pipelining bug.  Exact-tier keys (k0..k5) keep the full
         # op mix including param churn, resets, and Gregorian.
         payloads = []
         for _ in range(per_worker):
@@ -502,15 +500,14 @@ def test_ring_mode_differential(frozen_clock):
 
     schedules = [worker_payloads(w) for w in range(n_workers)]
 
-    def run_mode(mode: str):
+    def run_depth(depth: int):
         async def scenario():
             store = MockStore()
             svc = Service(
                 Config(device=dev, store=store), clock=frozen_clock
             )
             await svc.start()
-            fp = FastPath(svc, serve_mode=mode, ring_slots=4,
-                          ring_rounds=2, ring_max_linger_us=2000.0)
+            fp = FastPath(svc, pipeline_depth=depth)
             results: dict = {}
 
             async def worker(w: int):
@@ -546,32 +543,13 @@ def test_ring_mode_differential(frozen_clock):
 
         return asyncio.run(scenario())
 
-    base_results, base_rows, base_dv = run_mode("classic")
-    ring_results, ring_rows, ring_dv = run_mode("ring")
-    assert ring_results == base_results
-    assert ring_rows == base_rows
-    # The classic run fetched on the request path; the ring run did the
-    # machinery readbacks on the runner — 0 blocking fetches (the rf
-    # leaky-capture sync is the documented store-mode residual, so the
-    # assertion pins the machinery response path specifically).
-    assert base_dv["blocking_fetches"]["mach"] > 0
-    assert ring_dv["ring"]["iterations"] + ring_dv["ring"]["host_jobs"] > 0
-    assert ring_dv["ring"]["seq_mismatches"] == 0
-    # Three-way (ISSUE 12): MEGAROUND — the adaptive accumulator over
-    # mega dispatch tiers — must be bit-identical too, still with zero
-    # request-path blocking fetches and the sequence word monotone/
-    # mirror-consistent across whatever mix of base and mega tiers the
-    # schedule produced (seq_mismatches == 0 IS that assertion: every
-    # fetched device word matched the host mirror's running total).
-    mega_results, mega_rows, mega_dv = run_mode("megaround")
-    assert mega_results == base_results
-    assert mega_rows == base_rows
-    mr = mega_dv["ring"]
-    assert mr["rounds"] == 2 and mr["capacity"] == 8
-    assert mr["iterations"] + mr["host_jobs"] > 0
-    assert mr["seq_mismatches"] == 0
-    # Store-attached merges ride the runner as host jobs (no ring
-    # iterations); whenever ring iterations DID happen, the factor is
-    # well-formed.
-    if mr["iterations"]:
-        assert mr["rounds_per_dispatch"] >= 1.0
+    base_results, base_rows, base_dv = run_depth(1)
+    deep_results, deep_rows, deep_dv = run_depth(2)
+    assert deep_results == base_results
+    assert deep_rows == base_rows
+    assert base_dv["pipeline_depth"] == 1 and deep_dv["pipeline_depth"] == 2
+    # Both runs served on the lane, with their fetches on the request
+    # path's fetch stage.
+    for dv in (base_dv, deep_dv):
+        assert dv["fallbacks"] == 0
+        assert dv["blocking_fetches"]["mach"] > 0

@@ -205,7 +205,7 @@ def test_loosened_envelope_is_rejected(tmp_path):
 
 
 def test_real_kernel_is_strict_clean():
-    # One representative of the apply family; the full 28-kernel sweep
+    # One representative of the apply family; the full 23-kernel sweep
     # is the CI gubrange job (scripts/gubrange_smoke.py).
     fs = run(select=["ranges"], kernel="apply_batch", root=REPO)
     assert fs == [], "\n".join(f.render() for f in fs)

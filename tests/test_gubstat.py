@@ -7,7 +7,7 @@ The load-bearing pins:
     seeded table (every histogram leaf, shadow probe included);
   * the mesh census row-per-shard view agrees with the backend's own
     shard accounting, and totals are additive;
-  * sampling in ring mode never touches the fast lane's
+  * sampling never touches the fast lane's
     blocking_fetches ledger — introspection stays off the request path;
   * /debug/vars keeps its top-level schema (an operator dashboard
     contract — drift fails here first);
@@ -223,19 +223,18 @@ def test_mesh_census_rows_match_shard_occupancy(frozen_clock):
 # Sampler dispatch discipline: off the request path, always.
 # ---------------------------------------------------------------------------
 
-def test_sampler_ring_mode_never_blocks_request_path(frozen_clock):
-    """Sampling through the ring runner leaves the fast lane's
-    blocking_fetches ledger untouched — the acceptance criterion that
-    introspection rides host jobs + executor fetches, never a request-
-    path device->host readback."""
+def test_sampler_never_blocks_request_path(frozen_clock):
+    """Sampling leaves the fast lane's blocking_fetches ledger
+    untouched — the acceptance criterion that introspection dispatches
+    and fetches on executor threads, never as a request-path
+    device->host readback."""
     from gubernator_tpu.runtime.fastpath import FastPath
     from gubernator_tpu.runtime.service import Service
 
     async def scenario():
         svc = Service(Config(device=DEV), clock=frozen_clock)
         await svc.start()
-        fp = FastPath(svc, serve_mode="ring", ring_slots=2)
-        assert fp.effective_serve_mode == "ring"
+        fp = FastPath(svc)
         try:
             await svc._check_local([
                 RateLimitReq(name="r", unique_key=f"k{i}", hits=1,
@@ -243,7 +242,7 @@ def test_sampler_ring_mode_never_blocks_request_path(frozen_clock):
                 for i in range(10)
             ])
             before = dict(fp.blocking_fetches)
-            sampler = TableStatsSampler(svc, fastpath=fp)
+            sampler = TableStatsSampler(svc)
             for _ in range(3):
                 block = await sampler.sample()
             assert block["occupancy"] >= 10

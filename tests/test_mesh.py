@@ -96,3 +96,54 @@ def test_mesh_point_read(frozen_clock):
     assert item is not None
     assert item.remaining == 7
     assert dev.get_cache_item("pr_missing") is None
+
+
+def test_mesh_shard_occupancy(frozen_clock):
+    """Per-shard occupancy sums to the aggregate and reflects routed
+    inserts (the skew view /debug/vars + gubernator_shard_occupancy
+    export)."""
+    be = _mesh_backend(frozen_clock, num_slots=8 * 8 * 64)
+    be.check([
+        RateLimitReq(
+            name="mocc", unique_key=f"k{i % 13}", hits=1 + (i % 2),
+            limit=40, duration=60_000,
+            algorithm=(
+                Algorithm.LEAKY_BUCKET if i % 3 == 0
+                else Algorithm.TOKEN_BUCKET
+            ),
+        )
+        for i in range(40)
+    ])
+    per = be.shard_occupancy()
+    assert len(per) == 8
+    assert sum(per) == be.occupancy() > 0
+
+
+def test_mesh_ways_env_knob(monkeypatch):
+    """GUBER_MESH_WAYS drives the mesh axis size (overriding the
+    GUBER_TPU_NUM_SHARDS alias) and invalid geometries are rejected AT
+    STARTUP with the env surface named — not deep inside MeshBackend
+    construction."""
+    from gubernator_tpu.core.config import (
+        mesh_ways_from_env,
+        setup_daemon_config,
+    )
+
+    assert mesh_ways_from_env() == 0  # unset defers to the alias
+    monkeypatch.setenv("GUBER_TPU_NUM_SLOTS", str(8 * 8 * 64))
+    monkeypatch.setenv("GUBER_TPU_NUM_SHARDS", "2")
+    monkeypatch.setenv("GUBER_MESH_WAYS", "8")
+    conf = setup_daemon_config()
+    assert conf.device.num_shards == 8  # MESH_WAYS wins over the alias
+    monkeypatch.setenv("GUBER_MESH_WAYS", "0")
+    with pytest.raises(ValueError, match="GUBER_MESH_WAYS"):
+        setup_daemon_config()
+    # Slots not divisible by ways*mesh_ways: startup rejection that
+    # names the geometry env surface.
+    monkeypatch.setenv("GUBER_MESH_WAYS", "7")
+    with pytest.raises(ValueError, match="GUBER_MESH_WAYS"):
+        setup_daemon_config()
+    monkeypatch.delenv("GUBER_MESH_WAYS")
+    monkeypatch.setenv("GUBER_TPU_NUM_SHARDS", "0")
+    with pytest.raises(ValueError, match="GUBER_TPU_NUM_SHARDS"):
+        setup_daemon_config()

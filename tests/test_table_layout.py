@@ -21,7 +21,6 @@ from gubernator_tpu.core.hashing import key_hash64
 from gubernator_tpu.core.types import RateLimitReq
 from gubernator_tpu.ops import state as st
 from gubernator_tpu.ops import step as sp
-from gubernator_tpu.ops.ring import mega_ring_step, ring_step
 from gubernator_tpu.ops.state import (
     INT64_FIELDS,
     Col64,
@@ -103,8 +102,6 @@ def _q(n=1):
 _TABLE_KERNELS = {
     "apply_batch": lambda t: sp.apply_batch(
         t, _device_batch(), NOW, ways=WAYS)[0],
-    "apply_batch_packed": lambda t: sp.apply_batch_packed(
-        t, _device_batch(), NOW, ways=WAYS)[0],
     "apply_batch_packed_q": lambda t: sp.apply_batch_packed_q(
         t, _q()[0], NOW, ways=WAYS)[0],
     "load_rows": lambda t: sp.load_rows(t, _rows(), NOW, ways=WAYS),
@@ -116,11 +113,6 @@ _TABLE_KERNELS = {
         t, _rows(), NOW, ways=WAYS)[0],
     "demote_extract": lambda t: st.demote_extract(
         t, np.zeros(4, np.int64), NOW, ways=WAYS, batch=8)[0],
-    "ring_step": lambda t: ring_step(
-        t, _q(2), np.full(2, NOW), np.int64(0), ways=WAYS)[0],
-    "mega_ring_step": lambda t: mega_ring_step(
-        t, _q(4).reshape(2, 2, 12, B), np.full((2, 2), NOW), np.int64(0),
-        ways=WAYS)[0],
 }
 
 

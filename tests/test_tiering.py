@@ -13,7 +13,7 @@ back).
 
 Correctness tier: the demote -> touch -> promote race differentially
 against the pymodel oracle — at most ONE extra limit window per cycle,
-merge conserves budget bit-exactly; the ring-mode request path stays
+merge conserves budget bit-exactly; the request path stays
 blocking-fetch-free through a full tier cycle; a checkpoint restores
 BOTH tiers geometry-independently; the GUBER_TIER_* env surface
 validates at startup.
@@ -576,15 +576,14 @@ def test_promote_failure_conserves_rows_back_to_cold(frozen_clock):
 
 
 # ---------------------------------------------------------------------
-# correctness tier: ring-mode request path stays fetch-free
+# correctness tier: the request path stays fetch-free
 # ---------------------------------------------------------------------
 
-def test_tier_ring_request_path_fetch_free(frozen_clock):
-    """A full tier cycle in ring serve mode — demote, cold-hit serve,
-    promote — leaves the fast lane's blocking_fetches ledger untouched:
-    tier dispatches ride the ring's host-job lane and their syncs
-    resolve off the request path (the acceptance pin bench_e2e's churn
-    workload measures end to end)."""
+def test_tier_request_path_fetch_free(frozen_clock):
+    """A full tier cycle — demote, cold-hit serve, promote — leaves the
+    fast lane's blocking_fetches ledger untouched: tier dispatches and
+    their syncs run on the manager's own thread, off the request
+    path."""
     from gubernator_tpu.runtime.fastpath import FastPath
     from gubernator_tpu.runtime.service import Service
 
@@ -594,14 +593,12 @@ def test_tier_ring_request_path_fetch_free(frozen_clock):
         # Expire the backend's __warmup__ probe row (duration
         # 1ms) so extractions see only the test's keys.
         frozen_clock.advance(5)
-        fp = FastPath(svc, serve_mode="ring", ring_slots=2)
-        assert fp.effective_serve_mode == "ring"
+        fp = FastPath(svc)
         tm = TierManager(
             svc,
             TierConfig(enabled=True, cold_capacity=4096,
                        high_water=0.6, low_water=0.4,
                        demote_batch=64, interval_s=1.0),
-            fastpath=fp,
         )
         svc.tier = tm
         try:
@@ -609,13 +606,10 @@ def test_tier_ring_request_path_fetch_free(frozen_clock):
             await svc.get_rate_limits(reqs)
             before = dict(fp.blocking_fetches)
 
-            # Demote everything through the ring host-job lane, then
-            # touch the now-cold keys (served from fresh rows) and
-            # drain the promotes.
-            packed, rf = tm._run_job(
-                lambda: svc.backend.demote_extract_dispatch(
-                    tm._protect_grid(), 16
-                )
+            # Demote everything, then touch the now-cold keys (served
+            # from fresh rows) and drain the promotes.
+            packed, rf = svc.backend.demote_extract_dispatch(
+                tm._protect_grid(), 16
             )()
             idx = np.flatnonzero(packed[0] != 0)
             assert len(idx) == 12

@@ -2,10 +2,9 @@
 
 What is pinned here, per ISSUE 7:
 
-  * span-TREE shape for the classic / pipelined / ring serve modes via
-    the in-memory exporter (no collector needed): request -> coalescer
-    merge (member contexts as span links) -> dispatch/fetch stages ->
-    ring iteration carrying the monotone sequence word;
+  * span-TREE shape at pipeline depth 1 and 2 via the in-memory
+    exporter (no collector needed): request -> coalescer merge (member
+    contexts as span links) -> dispatch/fetch stages;
   * w3c traceparent propagation client -> daemon -> peer through the
     in-process cluster (one trace id across two real daemons);
   * exemplar emission on a forced SLO breach, and breach dumps that
@@ -134,15 +133,15 @@ def test_init_tracing_reports_missing_otlp_exporter(monkeypatch):
     assert not tracing.enabled()
 
 
-# -- span-tree shape per serve mode ---------------------------------------
+# -- span-tree shape per pipeline depth ------------------------------------
 
-async def _serve_once(mode: str):
+async def _serve_once(depth: int):
     metrics = Metrics()
     fr = FlightRecorder(metrics=metrics, dump_dir="flightrec-dumps")
     metrics.flightrec = fr
     svc = Service(Config(device=DEV), metrics=metrics)
     await svc.start()
-    fp = FastPath(svc, serve_mode=mode, ring_slots=4)
+    fp = FastPath(svc, pipeline_depth=depth)
     try:
         with tracing.span("client.request") as root:
             raw = await fp.check_raw(_payload(), peer_rpc=False)
@@ -153,10 +152,10 @@ async def _serve_once(mode: str):
     return root, fr
 
 
-@pytest.mark.parametrize("mode", ["classic", "pipelined", "ring"])
-def test_span_tree_per_serve_mode(mode):
+@pytest.mark.parametrize("depth", [1, 2])
+def test_span_tree_per_pipeline_depth(depth):
     with memory_tracing() as exp:
-        root, fr = asyncio.run(_serve_once(mode))
+        root, fr = asyncio.run(_serve_once(depth))
         tid = root.context.trace_id_hex()
         spans = exp.spans_for_trace(tid)
         by_name = {s.name: s for s in spans}
@@ -180,25 +179,8 @@ def test_span_tree_per_serve_mode(mode):
         for wait in ("gub.lane.queue_wait", "gub.lane.in_drain",
                      "gub.wire.wake"):
             assert by_name[wait].parent_id == root.context.span_id
-        if mode == "ring":
-            it = by_name["ring.iteration"]
-            # The monotone sequence word pins the exact device round
-            # this trace rode.
-            assert isinstance(it.attributes["ring.seq"], int)
-            assert it.attributes["ring.rounds"] >= 1
-            pubs = [s for s in spans if s.name == "ring.fetch_publish"]
-            assert pubs and pubs[0].parent_id == it.context.span_id
-            assert pubs[0].attributes["ring.seq"] == it.attributes["ring.seq"]
-            # Satellite: the ring round's dispatch — a ledger stage,
-            # hence also a profiler annotation — nests under the
-            # iteration, on the runner's own lane.
-            step = by_name["gub.backend.dispatch"]
-            assert step.parent_id == it.context.span_id
-            assert step.attributes["lane"] == "ring"
-        else:
-            assert "ring.iteration" not in by_name
         # The fetch stage's flight-recorder record is trace-tagged
-        # (context bound on the pool thread / ring runner).
+        # (context bound on the pool thread).
         recs = [
             r for r in fr.snapshot()["ring"]
             if r.get("trace_id") == tid
@@ -320,7 +302,7 @@ def test_disabled_serving_creates_zero_spans():
     """The hard guarantee: with tracing disarmed, a full fast-lane serve
     allocates no spans and leaves no trace state behind."""
     assert not tracing.enabled()
-    root, fr = asyncio.run(_serve_once("pipelined"))
+    root, fr = asyncio.run(_serve_once(2))
     assert root is None  # span() yielded None
     assert tracing.debug_vars() == {"enabled": False}
     assert all(

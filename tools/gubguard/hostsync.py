@@ -25,18 +25,14 @@ from tools.gubguard.core import Checker, Finding, ModuleInfo, dotted_name
 ALLOWED_SUFFIXES = (
     "runtime/backend.py",
     "runtime/fastpath.py",
-    # The ring runner thread IS the fetch side of the response ring —
-    # the one place ring-mode device->host syncs are supposed to live
-    # (docs/ring.md; the request path stays fetch-free).
-    "runtime/ring.py",
     # The gubstat sampler fetches census leaves on the executor thread
-    # (host-job submit + run_in_executor), and the tenant ledger only
+    # (run_in_executor), and the tenant ledger only
     # regroups arrays the fast lane already fetched — its np.asarray
     # calls are host->host (docs/observability.md).
     "runtime/gubstat.py",
     "runtime/checkpoint.py",
-    # The tier manager's fetches run on its own worker thread through
-    # the ring's host-job lane (docs/tiering.md), and the cold store
+    # The tier manager's fetches run on its own worker thread
+    # (docs/tiering.md), and the cold store
     # itself is pure host numpy — its np.asarray calls are host->host;
     # the request-path touch (note_access) is a set probe, no device
     # arrays in reach.

@@ -44,11 +44,6 @@ WAIVERS = {
         "sleep/wake, never guards shared state against the request "
         "path (the data it signals about rides backend._lock)"
     ),
-    "RingBackend._cond": (
-        "host-job FIFO Condition: wakes the ring worker when a job "
-        "lands; the queue itself is only touched under the Condition's "
-        "own lock, taken alone"
-    ),
     "TierManager._cv": (
         "tier-worker Condition: demote/promote wakeup only; row state "
         "is guarded by coldtier._lock (rank 54), not by this"

@@ -27,7 +27,6 @@ WATCHED_MODULES = (
     "gubernator_tpu/ops/step.py",
     "gubernator_tpu/ops/sketch.py",
     "gubernator_tpu/ops/pallas/cms_kernel.py",
-    "gubernator_tpu/ops/ring.py",
     "gubernator_tpu/parallel/sharded.py",
     "gubernator_tpu/parallel/global_sync.py",
 )

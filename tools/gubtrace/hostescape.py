@@ -27,6 +27,7 @@ FORBIDDEN = frozenset({
     "pure_callback",
     "io_callback",
     "debug_callback",
+    "debug_print",  # what jax.debug.print lowers to since jax 0.9
     "host_callback_call",
     "outside_call",
     "infeed",
@@ -47,7 +48,7 @@ class HostEscapeChecker(Checker):
                     out.append(Finding(
                         checker=self.name, kernel=spec.name,
                         message=(
-                            f"[{sig_name}] host-transition primitive "
+                            f"[{sig_name}] host callback primitive "
                             f"'{name}' compiled into the kernel"
                         ),
                         where=eqn_source(eqn),

@@ -38,7 +38,7 @@ The lease plane (docs/leases.md) likewise adds NO kernels: grants,
 reconciles, and carve-slot drops are host/client-side orchestration
 whose device work is ordinary checks through the already-registered
 step entrypoints (the `.lease-grant` slot is a normal table row), so
-the 20 verified kernels and their goldens are unchanged.
+the verified kernels and their goldens are unchanged.
 
 The reshard plane (docs/resharding.md) adds TWO kernels in
 ops/state.py: migrate_extract (gather+clear fused — the atomic
@@ -47,15 +47,6 @@ new-owner injection that can never clobber newer state).  The mesh
 backend's migration path deliberately adds none: it rides the
 registered sharded gather/load kernels through the generic
 PersistenceHost helpers.
-
-Megaround serving (docs/ring.md) adds TWO kernels: mega_ring_step
-(ops/ring.py — the scan OF the ring scan) and persistent_serve_step
-(ops/pallas/serve_kernel.py — the persistent decision kernel, traced
-through the interpret shim like cms_step_pallas).  The mesh megaround
-lift (parallel/sharded.make_mesh_mega_ring_step) deliberately adds
-none: it is the same shard_map composition mesh_ring_step already
-verifies, over the registered mega body — a factory, not a
-module-level jit, so the completeness checker's contract is unchanged.
 """
 from __future__ import annotations
 
@@ -246,7 +237,7 @@ def _table_stats_spec() -> KernelSpec:
     histograms, per-algorithm remaining-fraction distribution, and the
     shadow-slot census over host-enumerated derived-key fingerprints.
     Read-only and NON-donated by contract (it dispatches against the
-    live serving table as a ring host job); two licensed to_f64 casts
+    live serving table from the sampler's thread); two licensed to_f64 casts
     (remaining and limit at the fraction site, exact below 2^53 like
     the step kernels' float sites — the f64->i32 bin index that
     follows rides converted float lineage, so it is not charged)."""
@@ -276,145 +267,6 @@ def _table_stats_spec() -> KernelSpec:
 
     return KernelSpec(name="table_stats",
                       where="gubernator_tpu/ops/state.py", build=build)
-
-
-def _mega_ring_spec() -> KernelSpec:
-    """ops/ring.py mega_ring_step: megaround serving's scan OF the ring
-    scan (docs/ring.md) — up to GUBER_RING_ROUNDS x GUBER_RING_SLOTS
-    stacked rounds per dispatch.  The outer scan threads (table, seq)
-    through ring_step_impl, so the taint and cast contract is exactly
-    ring_step's (14 to_f64 leaky float sites + 1 to_i32 algo narrowing
-    propagated through the nested scan carries); donation is table-only
-    — the seq word's keep rule is inherited from the base ring."""
-
-    def build() -> BuiltKernel:
-        import gubernator_tpu.ops.ring as ring_mod
-
-        def sig(r: int, s: int):
-            return lambda: (
-                _table(),
-                np.zeros((r, s, 12, 64), np.int64),
-                np.zeros((r, s), np.int64),
-                np.zeros((), np.int64),
-            )
-
-        return BuiltKernel(
-            fn=ring_mod.mega_ring_step,
-            trace_fn=functools.partial(
-                ring_mod.mega_ring_step_impl, ways=WAYS
-            ),
-            signatures={"r2s2": sig(2, 2), "r4s2": sig(4, 2)},
-            counters=_TABLE_COUNTERS + ("[1]", "[2]", "[3]"),
-            allowed_casts=dict(_APPLY_Q_CASTS),
-            perturbations={
-                # Caller-mistake replay: a python-int seq traces weak.
-                "weak-seq": lambda: (
-                    _table(), np.zeros((2, 2, 12, 64), np.int64),
-                    np.zeros((2, 2), np.int64), 0,
-                ),
-            },
-            recompile_budget=3,
-            expect_aliased=20,  # table only — seq deliberately kept
-        )
-
-    return KernelSpec(
-        name="mega_ring_step", where="gubernator_tpu/ops/ring.py",
-        build=build,
-    )
-
-
-def _persistent_serve_spec() -> KernelSpec:
-    """ops/pallas/serve_kernel.py persistent_serve_step: the persistent
-    decision kernel — one Pallas launch drains the whole request queue
-    with the table resident across grid steps (docs/ring.md).  Traced
-    through the interpret shim like cms_step_pallas (Mosaic needs a
-    real TPU; the interpret emulation is differentially pinned
-    bit-exact against ring_step).  The decision body runs INSIDE the
-    pallas_call, so the jaxpr-level cast walk sees only the wrapper's
-    input normalization — zero licensed casts (the body's leaky float
-    sites are covered where they are verified, on ring_step /
-    apply_batch_packed_q); donation is table-only via the jit wrapper
-    — the seq word rides the response queue un-donated, the ring keep
-    rule."""
-
-    def build() -> BuiltKernel:
-        import gubernator_tpu.ops.pallas.serve_kernel as sk
-
-        def sig(k: int):
-            return lambda: (
-                _table(),
-                np.zeros((k, 12, 64), np.int64),
-                np.zeros(k, np.int64),
-                np.zeros((), np.int64),
-            )
-
-        return BuiltKernel(
-            fn=_PallasInterpretShim(sk.persistent_serve_step),
-            trace_fn=functools.partial(
-                sk.persistent_serve_step_impl, ways=WAYS,
-                interpret=True,
-            ),
-            signatures={"k1": sig(1), "k2": sig(2)},
-            counters=_TABLE_COUNTERS + ("[1]", "[2]", "[3]"),
-            allowed_casts={},
-            perturbations={
-                "weak-seq": lambda: (
-                    _table(), np.zeros((1, 12, 64), np.int64),
-                    np.zeros(1, np.int64), 0,
-                ),
-            },
-            recompile_budget=3,
-            expect_aliased=20,  # table only — seq deliberately kept
-        )
-
-    return KernelSpec(
-        name="persistent_serve_step",
-        where="gubernator_tpu/ops/pallas/serve_kernel.py",
-        build=build,
-    )
-
-
-def _ring_spec() -> KernelSpec:
-    """ops/ring.py ring_step: the ring discipline's bounded multi-round
-    scan (docs/ring.md).  The scan body is apply_batch_packed_q traced
-    once, so the int64 counter taint propagates through the lax.scan
-    carry and the licensed casts are exactly the q-form step's (14
-    to_f64 leaky float sites + 1 to_i32 algo narrowing); the sequence
-    word is tainted int64 arithmetic with no cast.  Only the table is
-    donated — the seq word's output buffer must survive the next
-    iteration's dispatch (the double-buffered response protocol spins
-    on it), so donating it would be a correctness bug, not a win."""
-
-    def build() -> BuiltKernel:
-        import gubernator_tpu.ops.ring as ring_mod
-
-        def sig(k: int):
-            return lambda: (
-                _table(),
-                np.zeros((k, 12, 64), np.int64),
-                np.zeros(k, np.int64),
-                np.zeros((), np.int64),
-            )
-
-        return BuiltKernel(
-            fn=ring_mod.ring_step,
-            trace_fn=functools.partial(ring_mod.ring_step_impl, ways=WAYS),
-            signatures={"k1": sig(1), "k2": sig(2)},
-            counters=_TABLE_COUNTERS + ("[1]", "[2]", "[3]"),
-            allowed_casts=dict(_APPLY_Q_CASTS),
-            perturbations={
-                # Caller-mistake replay: a python-int seq traces weak.
-                "weak-seq": lambda: (
-                    _table(), np.zeros((1, 12, 64), np.int64),
-                    np.zeros(1, np.int64), 0,
-                ),
-            },
-            recompile_budget=3,
-            expect_aliased=20,  # table only — seq deliberately kept
-        )
-
-    return KernelSpec(name="ring_step", where="gubernator_tpu/ops/ring.py",
-                      build=build)
 
 
 def _sketch_state():
@@ -631,54 +483,6 @@ def _global_sync_spec(psum: bool = False) -> KernelSpec:
     )
 
 
-def _mesh_ring_spec() -> KernelSpec:
-    """parallel/sharded.py make_mesh_ring_step: the ring discipline's
-    bounded scan lifted to the sharded grid table (docs/ring.md).  Each
-    shard runs ops/ring.ring_step_impl verbatim, so the taint and cast
-    contract is exactly ring_step's (14 to_f64 leaky float sites + 1
-    to_i32 algo narrowing propagated through the shard_map + scan
-    carry); the per-shard sequence words are tainted int64 arithmetic
-    with no cast.  Only the table is donated — the seq words' output
-    buffers must survive the next iteration's dispatch (the
-    double-buffered response protocol), exactly the single-device keep
-    rule."""
-
-    def build() -> BuiltKernel:
-        from gubernator_tpu.parallel.sharded import make_mesh_ring_step
-
-        fn = make_mesh_ring_step(_mesh(), WAYS)
-
-        def sig(k: int):
-            return lambda: (
-                _mesh_table(),
-                _sharded(
-                    np.zeros((k, 12, N_SHARDS, MESH_B), np.int64),
-                    (None, None, "shard"),
-                ),
-                np.zeros(k, np.int64),
-                _sharded(np.zeros(N_SHARDS, np.int64), ("shard",)),
-            )
-
-        return BuiltKernel(
-            fn=fn,
-            trace_fn=fn,
-            signatures={"k1": sig(1), "k2": sig(2)},
-            counters=_TABLE_COUNTERS + ("[1]", "[2]", "[3]"),
-            allowed_casts=dict(_APPLY_Q_CASTS),
-            perturbations={},
-            # Two slot tiers, mesh callers always normalize `now`
-            # (np.int64 in ring_step_dispatch) — no weak variant.
-            recompile_budget=2,
-            expect_aliased=20,  # table only — per-shard seq kept
-        )
-
-    return KernelSpec(
-        name="mesh_ring_step",
-        where="gubernator_tpu/parallel/sharded.py",
-        build=build,
-    )
-
-
 def _sketch_multi_spec() -> KernelSpec:
     def build() -> BuiltKernel:
         from gubernator_tpu.ops.sketch import cms_step_scatter_impl
@@ -779,12 +583,6 @@ def specs() -> List[KernelSpec]:
             {"split64": 5}, donated=20,
         ),
         _step_spec(
-            "apply_batch_packed", "apply_batch_packed",
-            "apply_batch_packed_impl",
-            lambda B: (_device_batch(B),),
-            _APPLY_COUNTERS, dict(_APPLY_CASTS), donated=20,
-        ),
-        _step_spec(
             "apply_batch_packed_q", "apply_batch_packed_q",
             "apply_batch_packed_q_impl",
             lambda B: (np.zeros((12, B), np.int64),),
@@ -814,11 +612,6 @@ def specs() -> List[KernelSpec]:
         ),
         # -- ops/state.py: the gubstat state census ---------------------
         _table_stats_spec(),
-        # -- ops/ring.py: the ring-fed device loop ----------------------
-        _ring_spec(),
-        _mega_ring_spec(),
-        # -- ops/pallas/serve_kernel.py: the persistent decision kernel -
-        _persistent_serve_spec(),
         # -- ops/sketch.py + the fused Pallas form ----------------------
         _sketch_spec("cms_step_onehot", "cms_step_onehot",
                      "cms_step_impl"),
@@ -867,7 +660,6 @@ def specs() -> List[KernelSpec]:
             _TABLE_COUNTERS + ("[1]", "[2]"),
             {"to_f64": 2}, donated=0,
         ),
-        _mesh_ring_spec(),
         _global_sync_spec(),
         _global_sync_spec(psum=True),
         # -- runtime/sketch_backend.py: the merge-scan step -------------
