@@ -20,10 +20,19 @@ Design notes:
   items, lrucache.go:115-127); a request whose own slot expired prefers
   reusing that slot.
 - Within-batch insert conflicts (two new keys choosing the same victim slot)
-  are resolved with sort-based claim rounds — no O(num_slots) temporaries.
-  After INSERT_ROUNDS, unresolved lanes are answered as "transient" new items
-  (correct response, state not persisted) — the same acceptable-loss contract
-  as reference cache eviction (architecture.md:5-11).
+  are resolved in INSERT_ROUNDS claim rounds: every lane still in need takes
+  its best candidate slot that is not reserved, and of the lanes taking one
+  slot the lowest lane wins.  A slot is `bucket * ways + way`, so only lanes
+  of one bucket can contend: the lanes are sorted ONCE by (bucket, lane), on
+  32-bit operands, and each round is elementwise work plus running max/min
+  inside a bucket's segment (`_claim_ways`) — no O(num_slots) temporaries,
+  nothing wider than [B, ways], no loop, and the same cost whether two lanes
+  miss or all of them.  The result is the rounds' definition exactly
+  (tests/test_locate_slots.py keeps it written out over B x ways int64 slots
+  and holds the two bit-identical).  After INSERT_ROUNDS, unresolved lanes
+  are answered as "transient" new items (correct response, state not
+  persisted) — the same acceptable-loss contract as reference cache eviction
+  (architecture.md:5-11).
 - Duplicate keys within a batch are the host packer's job (ops/batch.py
   rounds); this kernel assumes each active key appears once.
 """
@@ -172,28 +181,85 @@ def _sat_sub_i64(a: jax.Array, b: jax.Array) -> jax.Array:
     return a - jnp.clip(b, b_lo, b_hi)
 
 
-def _first_claim(tgt: jax.Array, attempt: jax.Array) -> jax.Array:
-    """Of all lanes attempting the same target slot, the lowest lane wins.
-
-    Sort-based, O(B log B), no table-sized temporaries.  Returns bool[B]
-    winner mask.
+def _rank_ways(vscore: jax.Array, eligible: jax.Array) -> jax.Array:
+    """int32[B, W]: each way's place in its lane's victim order — lowest
+    `vscore` first, the lower way on a tie (what `argmin` picks) — and W
+    for a way the lane may never take.  The int64 scores are compared
+    here once, elementwise; the claim rounds see only these small ranks.
     """
-    sent = jnp.int64(1) << 62
-    v = jnp.where(attempt, tgt, sent)
-    order = jnp.argsort(v, stable=True)  # stable: equal slots -> lane order
-    v_sorted = v[order]
-    first = jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), v_sorted[1:] != v_sorted[:-1]]
+    ways = vscore.shape[1]
+    w = jnp.arange(ways, dtype=jnp.int32)
+    mine, other = vscore[:, :, None], vscore[:, None, :]
+    ahead = (other < mine) | ((other == mine) & (w[None, :] < w[:, None]))
+    rank = jnp.sum(ahead, axis=2, dtype=jnp.int32)
+    return jnp.where(eligible, rank, ways)
+
+
+def _claim_ways(
+    bucket: jax.Array,
+    match_way: jax.Array,
+    rank: jax.Array,
+) -> jax.Array:
+    """The claim rounds.  int32[B]: the way each lane won, -1 for none.
+
+    `bucket` int32[B]; `match_way` int32[B]: the way a `found` lane
+    matched, -1 for the others; `rank` int32[B, W] from `_rank_ways`.
+
+    A slot is `bucket * ways + way`, so only lanes of ONE bucket can ever
+    contend for it.  The lanes are therefore sorted once by (bucket,
+    lane): a bucket's lanes become one contiguous segment, in lane order,
+    and every question a round asks — is this way reserved in my bucket,
+    does an earlier lane of my bucket want the same way — is a running
+    max/min of lane positions along the sorted axis compared with the
+    segment's bounds.  Nothing is table-sized or wider than [B, W], and
+    all of it is 32-bit.
+    """
+    B, ways = rank.shape
+    lane = jnp.arange(B, dtype=jnp.int32)
+    bkt, lane_s, match_way, *cols = jax.lax.sort(
+        (bucket, lane, match_way) + tuple(rank[:, w] for w in range(ways)),
+        num_keys=2,
     )
-    win_sorted = first & (v_sorted != sent)
-    return jnp.zeros(tgt.shape, dtype=bool).at[order].set(win_sorted)
+    rank = jnp.stack(cols, axis=1)
 
+    edge = bkt[1:] != bkt[:-1]
+    yes = jnp.ones((1,), dtype=bool)
+    seg_lo = jax.lax.cummax(
+        jnp.where(jnp.concatenate([yes, edge]), lane, 0))
+    seg_hi = jax.lax.cummin(
+        jnp.where(jnp.concatenate([edge, yes]), lane, B - 1), reverse=True)
+    seg_lo, seg_hi, pos = seg_lo[:, None], seg_hi[:, None], lane[:, None]
+    w = jnp.arange(ways, dtype=jnp.int32)[None, :]
+    none_yet = jnp.full((1, ways), -1, dtype=jnp.int32)
 
-def _member_of(sorted_vals: jax.Array, queries: jax.Array) -> jax.Array:
-    """Membership of `queries` in `sorted_vals` via searchsorted."""
-    pos = jnp.searchsorted(sorted_vals, queries)
-    pos = jnp.clip(pos, 0, sorted_vals.shape[0] - 1)
-    return sorted_vals[pos] == queries
+    def last_at_or_before(hit):
+        return jax.lax.cummax(jnp.where(hit, pos, -1), axis=0)
+
+    def in_segment(hit, before):
+        """Per way: does any lane of my segment hit it."""
+        after = jax.lax.cummin(jnp.where(hit, pos, B), axis=0, reverse=True)
+        return (before >= seg_lo) | (after <= seg_hi)
+
+    taken = w == match_way[:, None]
+    blocked = in_segment(taken, last_at_or_before(taken))
+    won_way = jnp.full((B,), -1, dtype=jnp.int32)
+    for r in range(INSERT_ROUNDS):
+        # Each lane still in need goes for its best way not yet reserved
+        # in its bucket; of the lanes going for one way the first wins.
+        open_rank = jnp.where(blocked, ways, rank)
+        pick = jnp.argmin(open_rank, axis=1).astype(jnp.int32)
+        attempt = (won_way < 0) & (jnp.min(open_rank, axis=1) < ways)
+        hit = attempt[:, None] & (w == pick[:, None])
+        before = last_at_or_before(hit)
+        earlier = jnp.concatenate([none_yet, before[:-1]]) >= seg_lo
+        win = attempt & ~jnp.any(hit & earlier, axis=1)
+        won_way = jnp.where(win, pick, won_way)
+        if r + 1 < INSERT_ROUNDS:
+            # A way someone went for is a way someone won.
+            blocked = blocked | in_segment(hit, before)
+
+    _, won_way = jax.lax.sort((lane_s, won_way), num_keys=1)
+    return won_way
 
 
 def locate_slots(
@@ -209,12 +275,22 @@ def locate_slots(
     slot at `slot`; `persist & ~found` lanes won an insert victim at `slot`;
     `~persist` lanes could not claim a slot (transient).  Each active key
     must appear at most once in the batch (the packer's contract).
+
+    The claim is DEFINED as INSERT_ROUNDS rounds over the whole batch: in
+    each, every lane still in need takes the lowest-scored candidate slot
+    that no `found` lane matched and no earlier round gave away (the first
+    such way on a tie; none left: no attempt), and of the lanes taking one
+    slot the lowest lane wins it.  `_claim_ways` computes exactly that,
+    bucket by bucket (tests/test_locate_slots.py holds it bit-identical to
+    the rounds written out over B x W int64 slots), at a cost that does
+    not depend on how many lanes miss.
     """
     S = table.key.shape[0]
     nb = S // ways
     if nb & (nb - 1):
         raise ValueError(f"num_buckets ({nb}) must be a power of two")
-    B = h.shape[0]
+    if nb > 1 << 31:
+        raise ValueError(f"num_buckets ({nb}) must fit 32 bits")
 
     bucket = (h.astype(jnp.uint64) & jnp.uint64(nb - 1)).astype(jnp.int64)
     sidx = bucket[:, None] * ways + jnp.arange(ways, dtype=jnp.int64)[None, :]
@@ -227,7 +303,8 @@ def locate_slots(
     live = cand_expire > now
     match = keymatch & live
     found = match.any(axis=1)
-    match_slot = bucket * ways + jnp.argmax(match, axis=1)
+    match_way = jnp.argmax(match, axis=1)
+    match_slot = bucket * ways + match_way
 
     # ---- victim scoring for inserts ------------------------------------
     # Preference: my own expired slot > empty > other expired > oldest touch.
@@ -239,31 +316,20 @@ def locate_slots(
     vscore = klass * (jnp.int64(1) << 48) + cand_touched  # touched < 2^48 ms
 
     need = active & ~found
+    # A round never takes a way scored at or past `inf` (the mark of a
+    # reserved way in the rounds' definition; no real score reaches it).
     inf = jnp.int64(1) << 62
-    insert_slot = jnp.full((B,), -1, dtype=jnp.int64)
-    won = jnp.zeros((B,), dtype=bool)
-
-    for _ in range(INSERT_ROUNDS):
-        # Slots reserved this batch: live matches + already-won inserts.
-        reserved = jnp.sort(
-            jnp.concatenate(
-                [
-                    jnp.where(found, match_slot, -1),
-                    jnp.where(won, insert_slot, -1),
-                ]
-            )
-        )
-        blocked = _member_of(reserved, sidx.ravel()).reshape(sidx.shape)
-        vs = jnp.where(blocked, inf, vscore)
-        vmin = jnp.min(vs, axis=1)
-        vslot = bucket * ways + jnp.argmin(vs, axis=1)
-        attempt = need & ~won & (vmin < inf)
-        win_now = _first_claim(vslot, attempt)
-        insert_slot = jnp.where(win_now, vslot, insert_slot)
-        won = won | win_now
+    won_way = _claim_ways(
+        bucket.astype(jnp.int32),
+        jnp.where(found, match_way, -1).astype(jnp.int32),
+        _rank_ways(vscore, need[:, None] & (vscore < inf)),
+    )
+    won = won_way >= 0
 
     persist = found | won
-    slot = jnp.where(found, match_slot, jnp.where(won, insert_slot, 0))
+    slot = jnp.where(
+        found, match_slot, jnp.where(won, bucket * ways + won_way, 0)
+    )
     slot_safe = jnp.clip(slot, 0, S - 1)
     return found, persist, slot, slot_safe
 

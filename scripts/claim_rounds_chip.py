@@ -1,0 +1,210 @@
+#!/usr/bin/env python3
+"""`locate_slots` on the device: the same answers, and what they cost.
+
+    chiprun --timeout 900 -- python scripts/claim_rounds_chip.py
+    JAX_PLATFORMS=cpu python scripts/claim_rounds_chip.py --platform cpu \\
+        --slots 65536 --reps 3                        # dry run here
+
+Three things, one JSON object on the last line of stdout (also written to
+--out/summary.json):
+
+  identical  seeded tables and batches (tests/test_locate_slots.py's
+             generator: the served geometry with 2 % of the lanes missing,
+             a cold table, the misses crowded into three buckets, every
+             bucket full) through the served `locate_slots` and through
+             the three-round reference frozen in that test file, on THIS
+             device, at 4096 and 128 lanes: (found, persist, slot,
+             slot_safe) must be bit-identical, or the exit code is 1.
+  ms         host clock around `block_until_ready`, per call: the
+             reference and the served `locate_slots` alone, and the whole
+             step program `apply_batch_packed_q`, at both tiers.
+  ops        a profiler trace of --reps launches of the 4096-lane step:
+             every HLO op's device time per launch with the `op_name` the
+             compiled program gives it (--out/ops.json holds all of them,
+             the summary the first 25) — what the step is made of.
+
+It fails where there is no TPU unless `--platform cpu` is given, and a
+number from such a run is a rehearsal, not a device time.
+"""
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import os
+import shutil
+import sys
+import time
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
+sys.path.insert(0, str(REPO / "tests"))
+
+WAYS = 8
+# (seed, fill, expired, resident, inactive, crowd) of _random_case.
+CASES = {
+    "served": (3, 0.6, 0.0, 0.98, 0.0, 0),
+    "cold": (1, 0.0, 0.0, 0.0, 0.0, 0),
+    "crowded": (4, 0.9, 0.5, 0.3, 0.2, 3),
+    "full": (5, 1.0, 0.0, 0.1, 0.05, 0),
+}
+
+
+def _load(name: str, path: Path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _ms_per_call(fn, reps: int) -> float:
+    import jax
+
+    jax.block_until_ready(fn())
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn()
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps * 1e3
+
+
+def _op_names(hlo: str) -> dict:
+    """HLO result name -> the op_name metadata of its line (the patterns
+    are scripts/step_hlo.py's)."""
+    step_hlo = _load("step_hlo", REPO / "scripts" / "step_hlo.py")
+    out = {}
+    for line in hlo.splitlines():
+        m = step_hlo._OP_RE.match(line)
+        n = step_hlo._OPNAME_RE.search(line)
+        if m and n:
+            out.setdefault(m.group("name"), n.group(1))
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--platform", default="tpu", choices=("tpu", "cpu"))
+    ap.add_argument("--slots", type=int, default=1 << 24)
+    ap.add_argument("--reps", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=str(REPO / "chiprun_out" / "claim_rounds"))
+    args = ap.parse_args()
+    if args.platform == "cpu":
+        os.environ["JAX_PLATFORMS"] = "cpu"
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import gubernator_tpu.ops  # noqa: F401 — x64 on, compile cache
+    import test_locate_slots as ref
+    from gubernator_tpu.ops import step as sp
+
+    dev = jax.devices()[0]
+    if dev.platform != args.platform:
+        print(f"wanted {args.platform}, found {dev.platform}", file=sys.stderr)
+        return 2
+    out_dir = Path(args.out)
+    out_dir.mkdir(parents=True, exist_ok=True)
+    nb = args.slots // WAYS
+    now = jnp.int64(ref.NOW)
+    summary = {
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "slots": args.slots, "identical": {}, "ms": {},
+    }
+
+    ok = True
+    served = {}
+    for B in (4096, 128):
+        for name, (seed, *shape) in CASES.items():
+            # The served geometry once a tier; the conflict-heavy cases on
+            # B / 4 buckets, where every round has contenders.
+            case_nb = nb if name == "served" else max(B // 4, 1)
+            table, h, active = ref._random_case(
+                args.seed + seed * 1000 + B, B, WAYS, case_nb, *shape)
+            try:
+                found, persist, _, _ = ref._agree(table, h, active, WAYS)
+                summary["identical"][f"{name}.B{B}"] = {
+                    "identical": True, "found": int(found.sum()),
+                    "won": int((persist & ~found).sum()),
+                    "transient": int((active & ~persist).sum()),
+                }
+            except AssertionError as e:
+                ok = False
+                summary["identical"][f"{name}.B{B}"] = {
+                    "identical": False, "differs": str(e)[:400]}
+            if name == "served":
+                served[B] = (table, jnp.asarray(h), jnp.asarray(active))
+
+    for B, (table, h, active) in served.items():
+        summary["ms"][f"locate_slots.reference.B{B}"] = _ms_per_call(
+            lambda: ref.ref_locate_slots(table, h, active, now, ways=WAYS),
+            args.reps)
+        summary["ms"][f"locate_slots.served.B{B}"] = _ms_per_call(
+            lambda: ref.new_locate_slots(table, h, active, now, ways=WAYS),
+            args.reps)
+
+    # The whole step, table donated and fed back, as the backend runs it.
+    steps = {}
+    for B, (table, h, active) in served.items():
+        q = np.zeros((12, B), dtype=np.int64)
+        q[0], q[1], q[2], q[3] = np.asarray(h), 1, 1000, 30 * ref.DAY
+        q[10] = np.asarray(active)
+        q = jnp.asarray(q)
+        state = {"table": jax.tree_util.tree_map(jnp.copy, table)}
+
+        def step(state=state, q=q):
+            state["table"], resp = sp.apply_batch_packed_q(
+                state["table"], q, now, ways=WAYS)
+            return resp
+
+        summary["ms"][f"apply_batch_packed_q.B{B}"] = _ms_per_call(
+            step, args.reps)
+        steps[B] = step
+
+    trace_dir = out_dir / "trace"
+    jax.profiler.start_trace(str(trace_dir))
+    for _ in range(args.reps):
+        resp = steps[4096]()
+    jax.block_until_ready(resp)
+    jax.profiler.stop_trace()
+    if dev.platform == "tpu":
+        from jax.profiler import ProfileData
+
+        # The benchmark's reduction, by path: its name is the standard
+        # library's.
+        trace_lib = _load("bench_trace", REPO / "bench" / "lib" / "trace.py")
+        table, h, _ = served[4096]
+        q = jax.ShapeDtypeStruct((12, 4096), jnp.int64)
+        names = _op_names(sp.apply_batch_packed_q.lower(
+            table, q, now, ways=WAYS).compile().as_text())
+        totals: dict = {}
+        pd = ProfileData.from_file(trace_lib.find_xplane(str(trace_dir)))
+        for plane in pd.planes:
+            if not trace_lib.DEVICE_PLANE.match(plane.name):
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                for ev in line.events:
+                    k = trace_lib.short_op(ev.name)
+                    totals[k] = totals.get(k, 0.0) + ev.duration_ns
+        rows = sorted(
+            ([k, v / args.reps / 1e6, names.get(k, "")]
+             for k, v in totals.items()), key=lambda r: -r[1])
+        (out_dir / "ops.json").write_text(json.dumps(rows, indent=0) + "\n")
+        summary["ops_ms_per_launch"] = rows[:25]
+        summary["ops_counted"] = len(rows)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+
+    summary["ok"] = bool(ok)
+    line = json.dumps(summary)
+    (out_dir / "summary.json").write_text(line + "\n")
+    print(line)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -575,13 +575,41 @@ def _assert_no_boundary_conversion(rep: dict) -> None:
         "memory"]
 
 
+@pytest.fixture(scope="module")
+def one_chip_report(step_hlo, topo):
+    """The compiled one-chip step's account, one compile a tier."""
+    reports = {}
+
+    def report(lanes: int) -> dict:
+        if lanes not in reports:
+            reports[lanes] = step_hlo.analyze_step(topo, 1 << 24, lanes)
+        return reports[lanes]
+
+    return report
+
+
 @pytest.mark.parametrize("lanes", [128, 4096])
 def test_one_chip_step_has_no_table_length_x64_conversion(
-        step_hlo, topo, lanes):
-    rep = step_hlo.analyze_step(topo, 1 << 24, lanes)
+        one_chip_report, lanes):
+    rep = one_chip_report(lanes)
     _assert_no_boundary_conversion(rep)
     if lanes == 128:   # 875 MB on the parent: the int64 columns' halves
         assert rep["memory"]["temp_size_in_bytes"] < 200e6, rep["memory"]
+
+
+@pytest.mark.parametrize("lanes", [128, 4096])
+def test_one_chip_step_has_two_sorts_and_no_loop(one_chip_report, lanes):
+    """The claim rounds of `locate_slots` (ops/step.py).  Until PR 32
+    they compiled to three `while` loops (`searchsorted` over B x 8 int64
+    slots) and six sorts at 128 lanes, nine at 4096 (three of them the
+    TPU's lowering of `_first_claim`'s scatter): 55 % of the 4096-lane
+    step's device time (PERF.md PR 31).  Now: the sort of the lanes by
+    (bucket, lane) and the sort back to lane order, and nothing that
+    loops."""
+    loops = one_chip_report(lanes)["loops"]
+    assert [r["name"] for r in loops if r["opcode"] == "while"] == []
+    sorts = [r["name"] for r in loops if r["opcode"] == "sort"]
+    assert len(sorts) == 2, loops
 
 
 def test_mesh_step_has_no_table_length_x64_conversion(step_hlo, topo):

@@ -1,7 +1,7 @@
 """primitive-budget: golden per-kernel counts of expensive primitives.
 
 The hot-path kernels earn their throughput by a known, reviewed set of
-expensive XLA ops — apply_batch is "bucket gather → claim sort →
+expensive XLA ops — apply_batch is "bucket gather → claim sort and scans →
 lane arithmetic → scatter" and nothing else.  A refactor that quietly
 adds one more `gather` (a stray fancy-index), a `sort`, or an extra
 collective doubles a measured cost without any test failing.  Each
@@ -29,13 +29,18 @@ from tools.gubtrace.core import (
 )
 
 # The expensive-primitive watchlist: memory-bound data movement
-# (gather/scatter), O(n log n) work (sort), control flow that defeats
-# fusion (while/scan/cond), and inter-chip collectives.
+# (gather/scatter), O(n log n) work (sort), running reductions along an
+# axis (the claim rounds of ops/step.py are made of cummax/cummin),
+# control flow that defeats fusion (while/scan/cond), and inter-chip
+# collectives.
 BUDGETED = (
     "gather",
     "scatter",
     "scatter-add",
     "sort",
+    "cumsum",
+    "cummax",
+    "cummin",
     "while",
     "scan",
     "cond",

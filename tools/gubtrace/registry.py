@@ -184,12 +184,17 @@ def _step_spec(
 # split64: the write-back stores eight int64 columns (key, limit,
 # duration, remaining, t0, burst, expire_at, touched), one lossless
 # split each.
-_APPLY_CASTS = {"to_f64": 14, "split64": 8}
+# to_i32: `locate_slots` narrows each lane's bucket index
+# (`h & (num_buckets - 1)`, under 2^31 by its trace-time check) once, for
+# the 32-bit sort its claim rounds run on — charged to every kernel that
+# locates a slot, once per call.
+_BUCKET_I32 = 1
+_APPLY_CASTS = {"to_f64": 14, "to_i32": _BUCKET_I32, "split64": 8}
 _APPLY_COUNTERS = _TABLE_COUNTERS + _BATCH_COUNTERS + (".limit",
                                                        ".duration", "[2]")
 # Packed q-form: one widened-int64 row is narrowed back to the int32
 # algo enum (values 0/1 by wire contract).
-_APPLY_Q_CASTS = {"to_f64": 14, "to_i32": 1, "split64": 8}
+_APPLY_Q_CASTS = {"to_f64": 14, "to_i32": 1 + _BUCKET_I32, "split64": 8}
 
 
 def _migrate_spec(name: str, fn_name: str, impl_name: str,
@@ -467,9 +472,12 @@ def _global_sync_spec(psum: bool = False) -> KernelSpec:
             # u64->u32 narrowings, licensed here, after which the
             # aggregate is no longer tainted lineage — so only the
             # table-side float sites (4) and splits (16) are charged.
+            # Both forms locate a slot three times (two applies, one
+            # store): three bucket narrowings, charged where the keys
+            # are still tainted lineage (not behind the psum's limbs).
             allowed_casts=(
                 {"to_f64": 4, "to_i32": 28, "split64": 16} if psum
-                else {"to_f64": 27, "split64": 21}
+                else {"to_f64": 27, "to_i32": 3 * _BUCKET_I32, "split64": 21}
             ),
             perturbations={},
             recompile_budget=1,
@@ -561,7 +569,8 @@ def specs() -> List[KernelSpec]:
             "load_rows", "load_rows", "load_rows_impl",
             lambda B: (_bucket_rows(B),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {"split64": 8}, donated=20,  # all eight int64 columns
+            # all eight int64 columns
+            {"to_i32": _BUCKET_I32, "split64": 8}, donated=20,
         ),
         _step_spec(
             "probe_batch", "probe_batch", "probe_batch_impl",
@@ -580,7 +589,7 @@ def specs() -> List[KernelSpec]:
             _TABLE_COUNTERS + (".key_hash", ".reset_time", "[2]"),
             # key, limit, remaining, expire_at, touched; duration, t0
             # and burst are written as constant zeros (no lineage).
-            {"split64": 5}, donated=20,
+            {"to_i32": _BUCKET_I32, "split64": 5}, donated=20,
         ),
         _step_spec(
             "apply_batch_packed_q", "apply_batch_packed_q",
@@ -599,7 +608,7 @@ def specs() -> List[KernelSpec]:
             "migrate_inject", "migrate_inject", "migrate_inject_impl",
             lambda B: (_bucket_rows(B),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {"to_f64": 1, "split64": 9}, donated=20,
+            {"to_f64": 1, "to_i32": _BUCKET_I32, "split64": 9}, donated=20,
         ),
         # -- ops/state.py: the tier demotion kernel (docs/tiering.md) --
         # Same gather+clear atomicity shape as migrate_extract, but the
@@ -630,14 +639,14 @@ def specs() -> List[KernelSpec]:
             row_factory("load_rows_impl", "BucketRows"),
             lambda: (_row_grid(_bucket_rows),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {"split64": 8}, donated=20,
+            {"to_i32": _BUCKET_I32, "split64": 8}, donated=20,
         ),
         _mesh_spec(
             "sharded_store_cached",
             row_factory("store_cached_rows_impl", "CachedRows"),
             lambda: (_row_grid(_cached_rows),),
             _TABLE_COUNTERS + (".key_hash", ".reset_time", "[2]"),
-            {"split64": 5}, donated=20,
+            {"to_i32": _BUCKET_I32, "split64": 5}, donated=20,
         ),
         _mesh_spec(
             "sharded_probe", f_step("sharded_probe"),
