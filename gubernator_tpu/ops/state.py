@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gubernator_tpu.ops import f64bits
+
 # Slot `kind` values.
 KIND_BUCKET = 0
 KIND_CACHED_RESP = 1  # non-owner's cached GLOBAL broadcast (gubernator.go:464-479)
@@ -45,9 +47,12 @@ KIND_CACHED_RESP = 1  # non-owner's cached GLOBAL broadcast (gubernator.go:464-4
 # `_host_to_halves`) are the only code that knows the layout; `Col64`
 # gives them the array spelling (`col[idx]`, `col.at[tgt].set(v)`) so a
 # kernel reads the same for a split column as for an int32 one.
-# `remaining_f` stays ONE float64[S] column: the TPU's X64 pass cannot
-# rewrite a 64-bit bitcast-convert, and any float split loses bits on
-# the CPU (docs/architecture.md).
+# `remaining_f`, the leaky bucket's float64 remaining, is a Col64 too:
+# the 64 BITS of the binary64 (ops/f64bits.py), which the step computes
+# on in integer words — a TPU has no float64, and XLA's stand-in (a pair
+# of float32s) is neither IEEE nor able to hold a binary64.  No column
+# and no value of a float dtype is left on the device; the host formats
+# keep float64 and cross by view (`table_to_host` / `table_from_host`).
 # --------------------------------------------------------------------------
 
 if sys.byteorder != "little":  # the host twins view int64 as (lo, hi) words
@@ -150,7 +155,7 @@ class SlotTable(NamedTuple):
     limit: Col64           # int64[S]
     duration: Col64        # int64[S]
     remaining: Col64       # int64[S]; token-bucket remaining / cached-resp remaining
-    remaining_f: jax.Array  # float64[S]; leaky-bucket fractional remaining
+    remaining_f: Col64     # float64[S] as its bits; leaky fractional remaining
     t0: Col64              # int64[S]; token CreatedAt / leaky UpdatedAt
     status: jax.Array      # int32[S]; token-bucket sticky status / cached-resp status
     burst: Col64           # int64[S]
@@ -165,11 +170,14 @@ class SlotTable(NamedTuple):
         return jnp.sum(self.key.occupied())
 
 
-# The logical fields stored as Col64.
+# The logical int64 fields, stored as Col64.
 INT64_FIELDS = (
     "key", "limit", "duration", "remaining", "t0", "burst", "expire_at",
     "touched",
 )
+# Stored as Col64 too, holding a float64's bits: float64 on the host.
+F64_FIELDS = ("remaining_f",)
+COL64_FIELDS = INT64_FIELDS + F64_FIELDS
 
 
 def init_table(num_slots: int) -> SlotTable:
@@ -179,28 +187,41 @@ def init_table(num_slots: int) -> SlotTable:
         return jnp.zeros((num_slots,), dtype=dtype)
 
     def column(f):
-        if f in INT64_FIELDS:
+        if f in COL64_FIELDS:
             return Col64(zeros(jnp.uint32), zeros(jnp.uint32))
-        return zeros(jnp.float64 if f == "remaining_f" else jnp.int32)
+        return zeros(jnp.int32)
 
     return SlotTable(**{f: column(f) for f in SlotTable._fields})
+
+
+def _floats_back(cols: dict) -> dict:
+    """Host columns as fetched -> the logical format: a float64 field's
+    bits viewed as float64 again."""
+    for f in F64_FIELDS:
+        cols[f] = f64bits.from_bits(cols[f])
+    return cols
 
 
 def table_to_host(table: SlotTable) -> dict:
     """DMA the table down as numpy for snapshot/Loader-save
     (the device analog of WorkerPool.Store streaming cache.Each(),
     workers.go:467-530): the twelve LOGICAL arrays, int64 fields
-    reassembled on the host — the checkpoint format."""
-    return {f: np.asarray(getattr(table, f)) for f in table._fields}
+    reassembled on the host and `remaining_f` a float64 array again —
+    the checkpoint format."""
+    return _floats_back({
+        f: np.asarray(getattr(table, f)) for f in table._fields
+    })
 
 
 def table_from_host(arrs: dict) -> SlotTable:
     """The inverse: twelve logical numpy arrays in, physical table out
-    (the halves are host views of the int64 arrays)."""
+    (the halves are host views of the int64 arrays, and of the float64
+    array's bits)."""
     cols = {f: jnp.asarray(arrs[f]) for f in SlotTable._fields
-            if f not in INT64_FIELDS}
-    for f in INT64_FIELDS:
-        lo, hi = _host_to_halves(arrs[f])
+            if f not in COL64_FIELDS}
+    for f in COL64_FIELDS:
+        a = f64bits.to_bits(arrs[f]) if f in F64_FIELDS else arrs[f]
+        lo, hi = _host_to_halves(a)
         cols[f] = Col64(jnp.asarray(lo), jnp.asarray(hi))
     return SlotTable(**cols)
 
@@ -208,7 +229,9 @@ def table_from_host(arrs: dict) -> SlotTable:
 def read_rows(table: SlotTable, idx) -> dict:
     """Logical rows at `idx` (an index array or a slice) as numpy, one
     entry per field — the host-side point read."""
-    return {f: np.asarray(getattr(table, f)[idx]) for f in table._fields}
+    return _floats_back({
+        f: np.asarray(getattr(table, f)[idx]) for f in table._fields
+    })
 
 
 # --------------------------------------------------------------------------
@@ -238,7 +261,7 @@ def migrate_extract_impl(
     """Probe `h`, gather each found row's fields, and CLEAR the matched
     slots (key=0, expire_at=0) in the same step.  Returns
     (new_table, packed int64[10, B] in ops.step.GATHER_ROW_FIELDS order,
-    float64[B] remaining_f)."""
+    int64[B] remaining_f bits)."""
     S = table.key.shape[0]
     nb = S // ways
     now = jnp.asarray(now, dtype=jnp.int64)
@@ -322,8 +345,8 @@ def migrate_inject_impl(
     active = rows.key_hash != 0
     conflict = found & active
     consumed_i = jnp.maximum(rows.limit - rows.remaining, 0)
-    consumed_f = jnp.maximum(
-        rows.limit.astype(jnp.float64) - rows.remaining_f, 0.0
+    consumed_f = f64bits.max0(
+        f64bits.sub(f64bits.from_i64(rows.limit), rows.remaining_f)
     )
     is_leaky = rows.algo == 1
     src = jnp.where(conflict, slot, 0)
@@ -332,11 +355,10 @@ def migrate_inject_impl(
         - jnp.where(is_leaky, 0, consumed_i),
         0,
     )
-    merged_rf = jnp.maximum(
-        new_table.remaining_f[src]
-        - jnp.where(is_leaky, consumed_f, 0.0),
-        0.0,
-    )
+    merged_rf = f64bits.max0(f64bits.sub(
+        new_table.remaining_f[src],
+        jnp.where(is_leaky, consumed_f, f64bits.ZERO),
+    ))
     S = table.key.shape[0]
     tgt = jnp.where(conflict, slot, S)
     new_table = new_table._replace(
@@ -379,7 +401,7 @@ migrate_inject = jax.jit(
 # Packed demote row layout: GATHER_ROW_FIELDS with the `found` word
 # replaced by the row's own key fingerprint (the caller did not name the
 # keys — the kernel picked them; 0 = inactive lane).  remaining_f rides
-# alongside as float64[batch], exactly the migrate_extract wire shape.
+# alongside as int64[batch] bits, exactly the migrate_extract wire shape.
 DEMOTE_ROW_FIELDS = (
     "key", "kind", "algo", "limit", "duration", "remaining", "t0",
     "status", "burst", "expire_at",
@@ -397,7 +419,7 @@ def demote_extract_impl(
     KIND_BUCKET residents not on the `protect` list, gather their rows,
     and CLEAR the matched slots (key=0, expire_at=0) in the same
     donated step.  Returns (new_table, packed int64[10, batch] in
-    DEMOTE_ROW_FIELDS order, float64[batch] remaining_f); lanes past
+    DEMOTE_ROW_FIELDS order, int64[batch] remaining_f bits); lanes past
     the eligible population come back with key 0 and clear nothing."""
     S = table.key.shape[0]
     now = jnp.asarray(now, dtype=jnp.int64)
@@ -434,7 +456,7 @@ def demote_extract_impl(
         g(table.burst),
         g(table.expire_at),
     ])
-    rf = jnp.where(sel, table.remaining_f[src], 0.0)
+    rf = jnp.where(sel, table.remaining_f[src], f64bits.ZERO)
     # Clear exactly like migrate_extract: drop the fingerprint AND the
     # expiry so the slot reads empty to every probe and first-choice to
     # every victim claim.
@@ -543,21 +565,26 @@ def table_stats_impl(
     slot_age = hist(now - table.t0[...])
     ttl_remaining = hist(expire_at - now)
 
-    # Remaining-fraction distribution per algorithm.  Two licensed
-    # to_f64 casts (remaining and limit — exact below 2^53 like the
-    # step kernels' float sites); the bin index narrows to int32 (one
-    # licensed to_i32 — FRAC_BINS bounds it).
-    lim_f = jnp.maximum(table.limit[...].astype(jnp.float64), 1.0)
+    # Remaining-fraction distribution per algorithm: the bin of
+    # clip(remaining / max(limit, 1), 0, 1) * FRAC_BINS, the quotient in
+    # binary64 as the host would compute it — on the bits (f64bits), so
+    # there is no float here either and the bins are the same on every
+    # backend.  A NaN (no stored row holds one) falls in bin 0.
+    lim_f = f64bits.from_i64(jnp.maximum(table.limit[...], 1))
     rem_f = jnp.where(
         table.algo == 1,
-        table.remaining_f,
-        table.remaining[...].astype(jnp.float64),
+        table.remaining_f[...],
+        f64bits.from_i64(table.remaining[...]),
     )
-    frac = jnp.clip(rem_f / lim_f, 0.0, 1.0)
-    fbin = jnp.minimum(
-        (frac * FRAC_BINS).astype(jnp.int32), FRAC_BINS - 1
+    frac = f64bits.div(rem_f, lim_f)
+    fbin = jnp.where(
+        f64bits.ge_one(frac),
+        FRAC_BINS - 1,
+        f64bits.trunc_i64(f64bits.mul(
+            f64bits.max0(frac), f64bits.const(float(FRAC_BINS))
+        )),
     )
-    fbins = jnp.arange(FRAC_BINS, dtype=jnp.int32)
+    fbins = jnp.arange(FRAC_BINS, dtype=jnp.int64)
     onehot = fbin[:, None] == fbins[None, :]
     rows = []
     for algo in (0, 1):

@@ -44,6 +44,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from gubernator_tpu.ops import f64bits as F
 from gubernator_tpu.ops.state import KIND_BUCKET, KIND_CACHED_RESP, SlotTable
 
 ALGO_TOKEN = 0
@@ -105,11 +106,26 @@ class DeviceBatchJ(NamedTuple):
 
 
 def _f64(x: jax.Array) -> jax.Array:
-    return x.astype(jnp.float64)
+    """The seam every int64 operand of the leaky lanes passes on its way
+    into float64 (`_fb`): the identity here, since the conversion itself
+    is `f64bits.from_i64`.  The benchmark's lower-precision control
+    replaces it (bench/serve.py: the operand rounded through float32 and
+    handed on as a float64 array, which `from_i64` takes by value)."""
+    return x
+
+
+def _fb(x: jax.Array) -> jax.Array:
+    """float64(x) of an int64 operand, as bits (ops/f64bits.py)."""
+    return F.from_i64(_f64(x))
 
 
 def _trunc_i64(x: jax.Array) -> jax.Array:
-    """Go's int64(float64): truncation toward zero.
+    """Go's int64(float64): truncation toward zero — on an XLA float64
+    array.  The step no longer computes in XLA's float64 (its leaky
+    lanes are `f64bits`, whose `trunc_i64` keeps this contract on the
+    bits); this stays as the contract's float spelling for the callers
+    that hold a float array (tests/test_differential.py, chip_smoke.py,
+    bench/witness/).
 
     The edge semantics are spelled out here, not left to the backend's
     convert, and differentially pinned against the oracle
@@ -422,67 +438,87 @@ def apply_batch_impl(
     tn_expire = jnp.where(is_greg, greg_exp, _sat_add_i64(now, r_dur))
     tn_resp_status = jnp.where(tn_over, OVER, UNDER)
 
-    # ==== leaky bucket, existing item (algorithms.go:327-426) ===========
-    lb0 = jnp.where(reset & req_leaky, _f64(r_burst), s_rem_f)
-    grow = (s_burst != r_burst) & (r_burst > _trunc_i64(lb0))
-    lb1 = jnp.where(grow, _f64(r_burst), lb0)
-    l_dur_c = jnp.where(is_greg, greg_exp - now, r_dur)
-    safe_lim = jnp.where(r_lim == 0, 1, r_lim)
-    l_rate = jnp.where(
-        r_lim == 0,
-        0.0,
-        jnp.where(is_greg, _f64(greg_dur), _f64(r_dur)) / _f64(safe_lim),
-    )
-    # l_dur_c may be negative under Gregorian (greg_exp already passed);
-    # saturating add keeps a hostile wire expiry from wrapping the epoch.
-    le_expire = jnp.where(r_hits != 0, _sat_add_i64(now, l_dur_c), s_expire)
-    elapsed = _f64(now - s_t0)
-    leak = jnp.where(l_rate != 0.0, elapsed / l_rate, 0.0)
-    leaked = _trunc_i64(leak) > 0
-    lb2 = jnp.where(leaked, lb1 + leak, lb1)
-    le_t0 = jnp.where(leaked, now, s_t0)
-    lb3 = jnp.where(_trunc_i64(lb2) > r_burst, _f64(r_burst), lb2)
-    lrem_i = _trunc_i64(lb3)
-    lrate_i = _trunc_i64(l_rate)
+    # ==== leaky bucket (algorithms.go:327-492) ==========================
+    # Go's float64, operation for operation — on the BITS, in integer
+    # words (ops/f64bits.py): IEEE binary64 on every backend, where XLA's
+    # float64 is a pair of float32s on a TPU.  `lb*`, `*_rate`, `leak`
+    # and `ln_rem_f` are int64 bit patterns; no value here is a float.
+    with jax.named_scope("leaky_f64bits"):
+        f_burst = _fb(r_burst)
+        f_dur = _fb(r_dur)
+        f_hits = _fb(r_hits)
+        f_now = _fb(now)
+        f_lim = _fb(r_lim)
+        safe_lim = jnp.where(r_lim == 0, 1, r_lim)
+        # Both rates in one division: the existing item's (the Gregorian
+        # duration where there is one) and the new item's (quirk
+        # preserved: RAW r.duration even under Gregorian — algorithms.go:441
+        # computes rate before the adjustment).
+        rates = F.div(
+            jnp.stack([jnp.where(is_greg, _fb(greg_dur), f_dur), f_dur]),
+            _fb(safe_lim)[None, :],
+        )
+        rates = jnp.where((r_lim == 0)[None, :], F.ZERO, rates)
+        l_rate, ln_rate = rates[0], rates[1]
 
-    l_over_zero = (lrem_i == 0) & (r_hits > 0)
-    l_exact = ~l_over_zero & (lrem_i == r_hits)
-    l_over_more = ~l_over_zero & ~l_exact & (r_hits > lrem_i)
-    l_take = l_exact | (~l_over_zero & ~l_exact & ~l_over_more & (r_hits != 0))
-    lb4 = jnp.where(l_take, lb3 - _f64(r_hits), lb3)
-    le_resp_rem = jnp.where(
-        l_exact, 0, jnp.where(l_take, _trunc_i64(lb4), lrem_i)
-    )
-    # ResetTime = now + (limit - remaining) * rate computed in float64 and
-    # truncated through the _trunc_i64 saturation contract: exact below
-    # 2^53 (every realistic envelope), saturating instead of wrapping for
-    # hostile wire limits/durations.  The oracle mirrors the same
-    # float64 evaluation order bit-for-bit (core/pymodel.py).
-    f_now = _f64(now)
-    f_lim = _f64(r_lim)
-    f_lrate = _f64(lrate_i)
-    le_resp_reset = _trunc_i64(jnp.where(
-        l_take,
-        f_now + (f_lim - _f64(le_resp_rem)) * f_lrate,
-        f_now + (f_lim - _f64(lrem_i)) * f_lrate,
-    ))
-    le_resp_status = jnp.where(l_over_zero | l_over_more, OVER, UNDER)
+        # ---- existing item (algorithms.go:327-426) ---------------------
+        lb0 = jnp.where(reset & req_leaky, f_burst, s_rem_f)
+        grow = (s_burst != r_burst) & (r_burst > F.trunc_i64(lb0))
+        lb1 = jnp.where(grow, f_burst, lb0)
+        l_dur_c = jnp.where(is_greg, greg_exp - now, r_dur)
+        # l_dur_c may be negative under Gregorian (greg_exp already
+        # passed); saturating add keeps a hostile wire expiry from
+        # wrapping the epoch.
+        le_expire = jnp.where(
+            r_hits != 0, _sat_add_i64(now, l_dur_c), s_expire
+        )
+        # (x / 0 is IEEE's inf or NaN in f64bits too, and dropped here.)
+        leak = jnp.where(
+            F.is_zero(l_rate), F.ZERO, F.div(_fb(now - s_t0), l_rate)
+        )
+        leaked = F.trunc_i64(leak) > 0
+        lb2 = jnp.where(leaked, F.add(lb1, leak), lb1)
+        le_t0 = jnp.where(leaked, now, s_t0)
+        lb3 = jnp.where(F.trunc_i64(lb2) > r_burst, f_burst, lb2)
+        lrem_i = F.trunc_i64(lb3)
+        lrate_i = F.trunc_i64(l_rate)
 
-    # ==== leaky bucket, new item (algorithms.go:433-492) ================
-    # Quirk preserved: rate uses RAW r.duration even under Gregorian
-    # (algorithms.go:441 computes rate before the adjustment).
-    ln_rate_i = _trunc_i64(
-        jnp.where(r_lim == 0, 0.0, _f64(r_dur) / _f64(safe_lim))
-    )
-    ln_dur = jnp.where(is_greg, greg_exp - now, r_dur)
-    ln_over = r_hits > r_burst
-    ln_rem_f = jnp.where(ln_over, 0.0, _f64(r_burst - r_hits))
-    ln_resp_rem = jnp.where(ln_over, 0, r_burst - r_hits)
-    ln_resp_reset = _trunc_i64(
-        f_now + (f_lim - _f64(ln_resp_rem)) * _f64(ln_rate_i)
-    )
-    ln_resp_status = jnp.where(ln_over, OVER, UNDER)
-    ln_expire = _sat_add_i64(now, ln_dur)
+        l_over_zero = (lrem_i == 0) & (r_hits > 0)
+        l_exact = ~l_over_zero & (lrem_i == r_hits)
+        l_over_more = ~l_over_zero & ~l_exact & (r_hits > lrem_i)
+        l_take = l_exact | (
+            ~l_over_zero & ~l_exact & ~l_over_more & (r_hits != 0)
+        )
+        lb4 = jnp.where(l_take, F.sub(lb3, f_hits), lb3)
+        le_stored = F.trunc_i64(lb4)
+        le_resp_rem = jnp.where(
+            l_exact, 0, jnp.where(l_take, le_stored, lrem_i)
+        )
+        le_resp_status = jnp.where(l_over_zero | l_over_more, OVER, UNDER)
+
+        # ---- new item (algorithms.go:433-492) --------------------------
+        ln_rate_i = F.trunc_i64(ln_rate)
+        ln_dur = jnp.where(is_greg, greg_exp - now, r_dur)
+        ln_over = r_hits > r_burst
+        ln_rem_f = jnp.where(ln_over, F.ZERO, _fb(r_burst - r_hits))
+        ln_resp_rem = jnp.where(ln_over, 0, r_burst - r_hits)
+        ln_stored = F.trunc_i64(ln_rem_f)
+        ln_resp_status = jnp.where(ln_over, OVER, UNDER)
+        ln_expire = _sat_add_i64(now, ln_dur)
+
+        # ResetTime = now + (limit - remaining) * rate computed in float64
+        # and truncated through the trunc_i64 saturation contract: exact
+        # below 2^53 (every realistic envelope), rounding above it and
+        # saturating instead of wrapping for hostile wire limits/durations.
+        # The oracle mirrors the same float64 evaluation order bit-for-bit
+        # (core/pymodel.py).  Both items' products in one pass.
+        resets = F.trunc_i64(F.add(f_now, F.mul(
+            F.sub(f_lim[None, :], _fb(jnp.stack([
+                jnp.where(l_take, le_resp_rem, lrem_i), ln_resp_rem,
+            ]))),
+            _fb(jnp.stack([lrate_i, ln_rate_i])),
+        )))
+        le_resp_reset, ln_resp_reset = resets[0], resets[1]
 
     # ==== select per-lane outputs =======================================
     tok_new = is_new & req_token
@@ -521,9 +557,7 @@ def apply_batch_impl(
         stored=jnp.where(
             cached_hit,
             s_rem,
-            sel(
-                te_rem, tn_rem, _trunc_i64(lb4), _trunc_i64(ln_rem_f), r_lim
-            ),
+            sel(te_rem, tn_rem, le_stored, ln_stored, r_lim),
         ),
         cached=cached_hit,
         # Mirrors the write-back's n_status below (kept in sync).
@@ -544,7 +578,7 @@ def apply_batch_impl(
     # but leaky-new stores the COMPUTED duration (algorithms.go:457).
     n_dur = sel(r_dur, r_dur, r_dur, ln_dur, 0)
     n_rem = sel(te_rem, tn_rem, 0, 0, 0)
-    n_rem_f = sel(0.0, 0.0, lb4, ln_rem_f, 0.0)
+    n_rem_f = sel(F.ZERO, F.ZERO, lb4, ln_rem_f, F.ZERO)
     n_t0 = sel(te_t0, now, le_t0, now, 0)
     n_status = sel(te_status, UNDER, 0, 0, 0).astype(jnp.int32)
     n_burst = sel(s_burst, 0, r_burst, r_burst, 0)
@@ -586,7 +620,7 @@ class BucketRows(NamedTuple):
     limit: jax.Array       # int64[B]
     duration: jax.Array    # int64[B]
     remaining: jax.Array   # int64[B]
-    remaining_f: jax.Array  # float64[B]
+    remaining_f: jax.Array  # int64[B]: binary64 BITS (f64bits.to_bits)
     t0: jax.Array          # int64[B]
     status: jax.Array      # int32[B]
     burst: jax.Array       # int64[B]
@@ -600,6 +634,11 @@ def load_rows_impl(
     ways: int = 8,
 ) -> SlotTable:
     """Upsert full bucket rows (KIND_BUCKET).  Keys unique within the batch."""
+    if rows.remaining_f.dtype != jnp.int64:
+        raise TypeError(
+            "BucketRows.remaining_f carries binary64 BITS (int64): convert "
+            f"on the host with f64bits.to_bits, got {rows.remaining_f.dtype}"
+        )
     S = table.key.shape[0]
     now = jnp.asarray(now, dtype=jnp.int64)
     active = rows.key_hash != 0
@@ -660,9 +699,9 @@ def probe_batch_impl(
 probe_batch = jax.jit(probe_batch_impl, static_argnames=("ways",))
 
 
-# Row order of gather_rows' packed int output (remaining_f travels as a
-# separate float64 array: TPU's X64-emulation pass cannot rewrite an s64
-# bitcast-convert, so the float is NOT bit-packed into the int stack).
+# Row order of gather_rows' packed int output.  remaining_f travels as a
+# second int64 array of binary64 BITS, which the host views as float64
+# (f64bits.from_bits): the seams above it keep their float64 column.
 GATHER_ROW_FIELDS = (
     "found", "kind", "algo", "limit", "duration", "remaining",
     "t0", "status", "burst", "expire_at",
@@ -677,7 +716,7 @@ def gather_rows_impl(
 ) -> Tuple[jax.Array, jax.Array]:
     """Columnar row read-back: probe + gather every CacheItem field for a
     hash batch as (int64[10, B] in GATHER_ROW_FIELDS order,
-    float64[B] remaining_f) — two buffers fetched in one sync where
+    int64[B] remaining_f bits) — two buffers fetched in one sync where
     per-field reads would cost a transfer each.  The compiled fast lane's
     Store.on_change capture (the batched analog of the read the reference
     does inline at algorithms.go:154-158); h=0 lanes read as not-found."""
@@ -748,7 +787,7 @@ def store_cached_rows_impl(
         limit=scat(table.limit, rows.limit),
         duration=scat(table.duration, z),
         remaining=scat(table.remaining, rows.remaining),
-        remaining_f=scat(table.remaining_f, z.astype(jnp.float64)),
+        remaining_f=scat(table.remaining_f, z),
         t0=scat(table.t0, z),
         status=scat(table.status, rows.status),
         burst=scat(table.burst, z),

@@ -30,6 +30,7 @@ from gubernator_tpu.core import clock as clock_mod
 from gubernator_tpu.core.config import DeviceConfig
 from gubernator_tpu.core.hashing import key_hash64
 from gubernator_tpu.core.types import CacheItem, RateLimitReq, RateLimitResp
+from gubernator_tpu.ops import f64bits
 from gubernator_tpu.ops.batch import PackedGrid, pack_requests_grid
 from gubernator_tpu.ops.devices import device_info
 from gubernator_tpu.ops.state import (
@@ -182,8 +183,8 @@ def make_sharded_probe(mesh, ways: int):
 
 def make_sharded_gather(mesh, ways: int):
     """Sharded columnar row read-back: (int64[n, 10, B] packed CacheItem
-    fields in ops/step.GATHER_ROW_FIELDS order, float64[n, B]
-    remaining_f) for a shard-routed hash grid — one sync where per-field
+    fields in ops/step.GATHER_ROW_FIELDS order, int64[n, B]
+    remaining_f bits) for a shard-routed hash grid — one sync where per-field
     fancy-index reads would cost a transfer each (the mesh analog of
     ops/step.gather_rows; the fast lane's Store.on_change capture)."""
     from gubernator_tpu.ops.step import gather_rows_impl
@@ -210,7 +211,7 @@ def make_sharded_demote_extract(mesh, ways: int, batch: int):
     fingerprint grid is replicated (P()): a shadow key only matches on
     its home shard, so protection is exact.  Output carries the leading
     [n] shard axis: packed int64[n, 10, batch] (DEMOTE_ROW_FIELDS
-    order), remaining_f float64[n, batch]."""
+    order), remaining_f bits int64[n, batch]."""
     from gubernator_tpu.ops.state import demote_extract_impl
 
     def _local(table: SlotTable, protect, now):
@@ -460,10 +461,11 @@ class MeshBackend(PersistenceHost):
 
         return fetch
 
-    def _dispatch_rounds_locked(self, rounds) -> list:
-        """Dispatch grid rounds; caller holds `_lock` (see
-        DeviceBackend._dispatch_rounds_locked)."""
-        now = np.int64(self.clock.millisecond_now())
+    def _dispatch_rounds_locked(self, rounds, now=None) -> list:
+        """Dispatch grid rounds under the clock `now`; caller holds
+        `_lock` (see DeviceBackend._dispatch_rounds_locked: one clock a
+        drain; None reads it here)."""
+        now = np.int64(self.clock.millisecond_now() if now is None else now)
         round_resps = []
         with self._stages.stage("backend.dispatch"):
             for db in rounds:
@@ -714,7 +716,9 @@ class MeshBackend(PersistenceHost):
         rf = np.zeros(m, dtype=np.float64)
         for i, (_devs, jv) in enumerate(token):
             a = int_hosts[i]     # [n_shards, 10, B]
-            f = rf_hosts[i] if rf_hosts is not None else None
+            # remaining_f arrives as its bits (ops/f64bits.py).
+            f = (f64bits.from_bits(rf_hosts[i])
+                 if rf_hosts is not None else None)
             for s in range(a.shape[0]):
                 sel = jv[s] >= 0
                 if sel.any():
@@ -781,6 +785,9 @@ class MeshBackend(PersistenceHost):
                 getattr(grid, f)[s, lane] = rd[f]
 
         for grid in drain_to_grids(per_shard, B, make_grid, fill):
+            grid = grid._replace(
+                remaining_f=f64bits.to_bits(grid.remaining_f)
+            )
             table = self._load_rows_sharded(
                 table,
                 type(grid)(*[
@@ -915,7 +922,7 @@ class MeshBackend(PersistenceHost):
 
         def fetch():
             p = np.asarray(packed)  # [n, 10, batch]
-            r = np.asarray(rf)  # [n, batch]
+            r = f64bits.from_bits(np.asarray(rf))  # [n, batch]
             return (
                 np.concatenate([p[s] for s in range(p.shape[0])],
                                axis=1),

@@ -25,6 +25,7 @@ import gubernator_tpu.ops  # noqa: F401  (enables x64)
 from gubernator_tpu.core import clock as clock_mod
 from gubernator_tpu.core.config import DeviceConfig
 from gubernator_tpu.core.hashing import key_hash64
+from gubernator_tpu.ops import f64bits
 from gubernator_tpu.core.types import (
     CacheItem,
     RateLimitReq,
@@ -628,12 +629,21 @@ class DeviceBackend(PersistenceHost):
 
         return fetch
 
-    def _dispatch_rounds_locked(self, rounds) -> list:
+    def _dispatch_rounds_locked(self, rounds, now=None) -> list:
         """Dispatch pre-packed rounds; caller holds `_lock`.  Returns the
         device response handles WITHOUT syncing them — the fast lane's
         cascade section syncs inside the lock (its critical window spans
-        the sync) while the plain path syncs after release."""
-        now = np.int64(self.clock.millisecond_now())
+        the sync) while the plain path syncs after release.
+
+        `now` (ms) is the clock the rounds run under.  ONE CLOCK A DRAIN:
+        everything a drain dispatches under one hold of `_lock` — read
+        rounds, the cascade's write-back rounds, a repair's rounds, the
+        store capture — takes the reading its holder took once
+        (fastpath._process_packed).  A write-back under a later reading
+        finds a window ended or a leak completed that the answers it
+        writes back never saw (PERF.md section 7, PR 33).  None: this
+        dispatch is the hold's only one, and reads the clock itself."""
+        now = np.int64(self.clock.millisecond_now() if now is None else now)
         round_resps = []
         with self._stages.stage("backend.dispatch"):
             for db in rounds:
@@ -684,7 +694,7 @@ class DeviceBackend(PersistenceHost):
         return [d for d, _rf in token]
 
     def _gather_rows_rf_arrays(self, token) -> list:
-        """The token's float64 remaining_f buffers (needed only when a
+        """The token's remaining_f buffers, int64 bits (needed only when a
         leaky row may have been captured — token rows read remaining from
         the int columns)."""
         return [rf for _d, rf in token]
@@ -702,9 +712,10 @@ class DeviceBackend(PersistenceHost):
                 np.zeros(0),
             )
         packed = np.concatenate(int_hosts, axis=1)[:, :m]
+        # The device hands remaining_f over as its bits (ops/f64bits.py).
         rf = (
-            np.concatenate(rf_hosts)[:m] if rf_hosts is not None
-            else np.zeros(m)
+            f64bits.from_bits(np.concatenate(rf_hosts)[:m])
+            if rf_hosts is not None else np.zeros(m)
         )
         return packed, rf
 
@@ -744,7 +755,7 @@ class DeviceBackend(PersistenceHost):
         n = len(fps)
         return (
             np.concatenate(ints, axis=1)[:, :n],
-            np.concatenate(rfs)[:n],
+            f64bits.from_bits(np.concatenate(rfs)[:n]),
         )
 
     def migrate_inject_rows(self, cols: Dict[str, np.ndarray]):
@@ -776,7 +787,9 @@ class DeviceBackend(PersistenceHost):
                     limit=col("limit", np.int64),
                     duration=col("duration", np.int64),
                     remaining=col("remaining", np.int64),
-                    remaining_f=col("remaining_f", np.float64),
+                    remaining_f=f64bits.to_bits(
+                        col("remaining_f", np.float64)
+                    ),
                     t0=col("t0", np.int64),
                     status=col("status", np.int32),
                     burst=col("burst", np.int64),
@@ -874,22 +887,24 @@ class DeviceBackend(PersistenceHost):
         for lo in range(0, len(rows), B):
             chunk = rows[lo:lo + B]
             pad = B - len(chunk)
+            cols = {
+                f: np.array(
+                    [c[f] for c in chunk] + [0] * pad,
+                    dtype=np.float64 if f == "remaining_f" else (
+                        np.int32 if f in ("algo", "status") else np.int64
+                    ),
+                )
+                for f in (
+                    "algo", "limit", "duration", "remaining",
+                    "remaining_f", "t0", "status", "burst", "expire_at",
+                )
+            }
+            cols["remaining_f"] = f64bits.to_bits(cols["remaining_f"])
             br = BucketRows(
                 key_hash=np.concatenate([
                     h64[lo:lo + B], np.zeros(pad, dtype=np.int64)
                 ]),
-                **{
-                    f: np.array(
-                        [c[f] for c in chunk] + [0] * pad,
-                        dtype=np.float64 if f == "remaining_f" else (
-                            np.int32 if f in ("algo", "status") else np.int64
-                        ),
-                    )
-                    for f in (
-                        "algo", "limit", "duration", "remaining",
-                        "remaining_f", "t0", "status", "burst", "expire_at",
-                    )
-                },
+                **cols,
             )
             self.table = self._load_rows(self.table, br, np.int64(now))
 
@@ -1065,7 +1080,7 @@ class DeviceBackend(PersistenceHost):
         def fetch():
             return (
                 fetch_ravel([packed])[0].reshape(10, batch),
-                fetch_ravel([rf])[0],
+                f64bits.from_bits(fetch_ravel([rf])[0]),
             )
 
         return fetch
@@ -1122,7 +1137,9 @@ class DeviceBackend(PersistenceHost):
                     limit=col("limit", np.int64),
                     duration=col("duration", np.int64),
                     remaining=col("remaining", np.int64),
-                    remaining_f=col("remaining_f", np.float64),
+                    remaining_f=f64bits.to_bits(
+                        col("remaining_f", np.float64)
+                    ),
                     t0=col("t0", np.int64),
                     status=col("status", np.int32),
                     burst=col("burst", np.int64),

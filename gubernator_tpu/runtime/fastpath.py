@@ -571,6 +571,10 @@ class FastPath:
             pipeline_depth=pipeline_depth,
             metrics=metrics, lane="mach",
         )
+        # What PR 34's two counters count may never happen in a run (no
+        # merge cascades, every key is resident): they read 0, not nothing.
+        self._stages.declare("mach", "lane.cascade", "wb_lanes")
+        self._stages.declare("mach", "lane.unpack", "new_windows")
         # The sketch and engine lanes each coalesce cross-RPC into one
         # maximal merge at a time, on DEDICATED workers so machinery
         # syncs can't starve them (and vice versa); each lane pipelines
@@ -1834,7 +1838,7 @@ class FastPath:
         r_rounds, r_order, r_bounds = _build_rounds(
             rvals, rrnd, rlane, r_sh, rn, n_shards, B
         )
-        r_resps = backend._dispatch_rounds_locked(r_rounds)
+        r_resps = backend._dispatch_rounds_locked(r_rounds, now_ms)
         cap_fps = np.array(
             [fp for fp, v in uniq.items() if v[2] is not None],
             dtype=np.int64,
@@ -2065,7 +2069,14 @@ class FastPath:
         t_step0 = time.monotonic()
         host_box: List = []  # [host] once the response reaches host
 
-        def finish() -> List[Tuple[np.ndarray, ...]]:
+        def finish(unpack) -> List[Tuple[np.ndarray, ...]]:
+            # Device read lanes the step answered with `found` = 0: a
+            # key's first arrival or, where windows elapse inside a run,
+            # a new window opened (its own row expired).  Read from the
+            # fetched response; no device output is added for it.
+            unpack.tally(
+                new_windows=int(((foundv == 0) & (h_mach != 0)).sum())
+            )
             return self._finish_process(
                 entries, host_box[0], rounds, h, h_mach, foundv, persv,
                 status, out_lim, remaining, reset, stored, stored_st,
@@ -2085,9 +2096,9 @@ class FastPath:
             def fetch_plain() -> List[Tuple[np.ndarray, ...]]:
                 host_box.append(fetch_host())
                 self.blocking_fetches["mach"] += 1
-                with tracing.stage("lane.unpack"):
+                with tracing.stage("lane.unpack") as unpack:
                     gather(host_box[0])
-                    return finish()
+                    return finish(unpack)
 
             return fetch_plain
 
@@ -2117,7 +2128,14 @@ class FastPath:
         lock_wait = tracing.stage("backend.lock_wait")
         with backend._lock:
             lock_wait.end()
-            resps = backend._dispatch_rounds_locked(rounds)
+            # One clock a drain: the read rounds, the write-back rounds,
+            # a repair's rounds and the store capture all run under this
+            # reading.  A write-back under a later one would find a token
+            # window ended, or a leak completed, in the gap its own fetch
+            # and replay took — and spend the group's hits in a window no
+            # RPC was answered in (PERF.md section 7, PR 33).
+            now_ms = backend.clock.millisecond_now()
+            resps = backend._dispatch_rounds_locked(rounds, now_ms)
             if plan is not None:
                 host_box.append(to_host(resps))
                 cascade = tracing.stage("lane.cascade")
@@ -2155,15 +2173,15 @@ class FastPath:
                         else np.zeros(m, dtype=np.int32),
                         wn, n_shards, B,
                     )
+                    cascade.tally(wb_lanes=m)
                     cascade.end()
-                    backend._dispatch_rounds_locked(wb_rounds)
+                    backend._dispatch_rounds_locked(wb_rounds, now_ms)
             if do_store:
                 from gubernator_tpu.runtime.backend import (
                     _packed_resp_dict,
                     fetch_ravel,
                 )
 
-                now_ms = backend.clock.millisecond_now()
                 cap_fps = np.array(
                     [fp for fp, v in uniq.items() if v[2] is not None],
                     dtype=np.int64,
@@ -2236,8 +2254,8 @@ class FastPath:
                     # cond.wait) — hence the rf sync sits INSIDE this
                     # try as well.
                     backend._deliver_write_through(captured, wt_seq)
-            with tracing.stage("lane.unpack"):
-                return finish()
+            with tracing.stage("lane.unpack") as unpack:
+                return finish(unpack)
 
         return fetch_locked_merge
 

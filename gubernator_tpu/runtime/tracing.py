@@ -686,9 +686,12 @@ STAGES: Dict[str, str] = {
     "lane.cascade": "inside a cascade merge's locked window: gather, "
                     "host replay, write-back rounds; counters groups "
                     "(duplicate groups replayed), occ (their occurrences), "
-                    "peeks (of those, hits == 0)",
+                    "peeks (of those, hits == 0), wb_lanes (lanes of the "
+                    "write-back rounds the merge sent)",
     "lane.unpack": "gather + finish (tallies, capture mask, per-entry "
-                   "split) after the answer is on the host",
+                   "split) after the answer is on the host; counter "
+                   "new_windows (machinery lane: device read lanes "
+                   "answered with found = 0)",
     "lane.dispatch_stage": "the coalescer's side of the dispatch stage "
                            "(feeds fastpath_stage_duration)",
     "lane.fetch_stage": "the coalescer's side of the fetch stage",
@@ -888,6 +891,12 @@ class StageLedger:
         shows a lane's whole vocabulary (at zero) from the start."""
         for stage in stages:
             self.cell(lane, stage)
+
+    def declare(self, lane: str, stage: str, *counters: str) -> None:
+        """Create named counters of a row at zero ahead of their first
+        count: a reader that divides by or into them (the benchmark's
+        ratio metrics) finds a number from the start, not nothing."""
+        self._tally(self.cell(lane, stage), dict.fromkeys(counters, 0))
 
     def cell(self, lane: str, stage: str) -> _Cell:
         c = self._cells.get((lane, stage))

@@ -215,7 +215,7 @@ def test_envelope_budget_requires_reason():
     env = load_envelope(
         Path("tools/gubrange/envelopes/apply_batch.json")
     )
-    env.reasons.pop("float-div-zero")
+    env.reasons.pop("negative-duration")
     errs = env.validate()
     assert any("no written reason" in e for e in errs)
     env.budgets["overflow"] = 1

@@ -32,6 +32,7 @@ from gubernator_tpu.ops.state import (
     init_table,
     table_from_host,
     table_stats,
+    table_to_host,
 )
 from gubernator_tpu.runtime.gubstat import (
     PLANE_LABELS,
@@ -55,7 +56,7 @@ def _numpy_census(table, shadow_fps, now, ways):
     algo = np.asarray(table.algo)
     limit = np.asarray(table.limit)
     remaining = np.asarray(table.remaining)
-    remaining_f = np.asarray(table.remaining_f)
+    remaining_f = table_to_host(table)["remaining_f"]  # float64 again
     S = key.shape[0]
     nb = S // ways
 
@@ -115,8 +116,7 @@ def test_table_stats_matches_numpy_reference():
     now = 1_000_000_000
 
     table = init_table(S)
-    leaves = {f: np.asarray(getattr(table, f)).copy()
-              for f in table._fields}
+    leaves = {f: a.copy() for f, a in table_to_host(table).items()}
     n_fill = 300
     slots = rng.choice(S, size=n_fill, replace=False)
     leaves["key"][slots] = rng.integers(1, 2**62, size=n_fill)

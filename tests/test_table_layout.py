@@ -19,9 +19,11 @@ import pytest
 from gubernator_tpu.core.config import DeviceConfig
 from gubernator_tpu.core.hashing import key_hash64
 from gubernator_tpu.core.types import RateLimitReq
+from gubernator_tpu.ops import f64bits
 from gubernator_tpu.ops import state as st
 from gubernator_tpu.ops import step as sp
 from gubernator_tpu.ops.state import (
+    COL64_FIELDS,
     INT64_FIELDS,
     Col64,
     SlotTable,
@@ -47,10 +49,8 @@ F64_CORNERS = np.array(
      np.inf],
     dtype=np.float64,
 )
-# The one declared exception to "32-bit leaves": the TPU's X64 pass cannot
-# rewrite a 64-bit bitcast-convert and every float split loses bits on
-# the CPU, so the leaky remainder stays ONE float64[S] column.
-F64_LEAVES = {"remaining_f"}
+# No exception to "32-bit leaves" is left: the leaky remainder is the two
+# words of its binary64's BITS (ops/f64bits.py), float64 on the host only.
 
 
 def _leaf_dtypes(table) -> dict:
@@ -64,13 +64,11 @@ def _leaf_dtypes(table) -> dict:
 def _assert_physical(table) -> None:
     assert isinstance(table, SlotTable)
     dtypes = _leaf_dtypes(table)
-    assert len(dtypes) == 2 * len(INT64_FIELDS) + 4
+    assert len(dtypes) == 2 * len(COL64_FIELDS) + 3
     for key, dt in dtypes.items():
-        if key.lstrip(".") in F64_LEAVES:
-            assert dt == np.float64, (key, dt)
-        else:
-            assert dt.itemsize == 4, f"{key} is {dt}: not a 32-bit column"
-    for f in INT64_FIELDS:
+        assert dt.itemsize == 4 and dt.kind in "iu", (
+            f"{key} is {dt}: not a 32-bit integer column")
+    for f in COL64_FIELDS:
         assert dtypes[f".{f}.lo"] == dtypes[f".{f}.hi"] == np.uint32
 
 
@@ -284,7 +282,8 @@ def _rows(keys=None):
     return sp.BucketRows(
         key_hash=keys, algo=np.arange(n, dtype=np.int32) % 2,
         limit=np.roll(c, 1), duration=np.roll(c, 2), remaining=np.roll(c, 3),
-        remaining_f=np.resize(F64_CORNERS, n), t0=np.roll(c, 4),
+        remaining_f=f64bits.to_bits(np.resize(F64_CORNERS, n)),
+        t0=np.roll(c, 4),
         status=np.zeros(n, np.int32), burst=np.roll(c, 5),
         # alive: every expiry is past NOW, with high words in use
         expire_at=np.int64(2**40) + np.arange(n, dtype=np.int64),
@@ -338,7 +337,8 @@ def _check_gather_rows():
     assert packed[0].all()
     for i, f in enumerate(sp.GATHER_ROW_FIELDS[1:], start=1):
         np.testing.assert_array_equal(packed[i], host[f][slot], f)
-    np.testing.assert_array_equal(np.asarray(rf), host["remaining_f"][slot])
+    np.testing.assert_array_equal(
+        np.asarray(rf), f64bits.to_bits(host["remaining_f"][slot]))
 
 
 def _check_probe_batch():
@@ -431,7 +431,7 @@ def _check_apply_batch():
         key_hash=_KEYS[:n], algo=np.zeros(n, np.int32),
         limit=np.full(n, big), duration=np.full(n, 2**35, np.int64),
         remaining=big - np.arange(n, dtype=np.int64),
-        remaining_f=np.zeros(n), t0=np.full(n, NOW),
+        remaining_f=np.zeros(n, np.int64), t0=np.full(n, NOW),
         status=np.zeros(n, np.int32), burst=np.zeros(n, np.int64),
         expire_at=np.full(n, NOW + 2**35, np.int64),
     )
@@ -566,10 +566,9 @@ def topo(step_hlo):
 def _assert_no_boundary_conversion(rep: dict) -> None:
     x64 = [r for r in rep["table_length_ops"]
            if r["target"] in ("X64SplitLow", "X64SplitHigh", "X64Combine")]
-    # At most remaining_f's three: one split each way in, one combine out.
-    assert len(x64) <= 3, x64
-    for r in x64:
-        assert r["shape"].startswith(("f32[", "f64[", "(f32[")), r
+    # None: remaining_f's three (a float64 column split on the way in and
+    # combined on the way out) went with the column's float dtype.
+    assert x64 == [], x64
     # Donation holds: the whole table is updated in place.
     assert rep["memory"]["alias_size_in_bytes"] == rep["table_bytes"], rep[
         "memory"]
@@ -593,21 +592,28 @@ def test_one_chip_step_has_no_table_length_x64_conversion(
         one_chip_report, lanes):
     rep = one_chip_report(lanes)
     _assert_no_boundary_conversion(rep)
-    if lanes == 128:   # 875 MB on the parent: the int64 columns' halves
-        assert rep["memory"]["temp_size_in_bytes"] < 200e6, rep["memory"]
+    # 875 MB with int64 columns, 136 MB while remaining_f was a float64
+    # column (its two converted halves), 2-5 MB since it is bits.
+    assert rep["memory"]["temp_size_in_bytes"] < 20e6, rep["memory"]
 
 
 @pytest.mark.parametrize("lanes", [128, 4096])
-def test_one_chip_step_has_two_sorts_and_no_loop(one_chip_report, lanes):
+def test_one_chip_step_has_two_sorts_and_no_loop_but_the_division(
+        one_chip_report, lanes):
     """The claim rounds of `locate_slots` (ops/step.py).  Until PR 32
     they compiled to three `while` loops (`searchsorted` over B x 8 int64
     slots) and six sorts at 128 lanes, nine at 4096 (three of them the
     TPU's lowering of `_first_claim`'s scatter): 55 % of the 4096-lane
     step's device time (PERF.md PR 31).  Now: the sort of the lanes by
-    (bucket, lane) and the sort back to lane order, and nothing that
-    loops."""
+    (bucket, lane) and the sort back to lane order, and nothing of the
+    claim that loops.  The only loops are the leaky lanes' two binary64
+    divisions (ops/f64bits.py `div`: a fixed 7 trips of 8 quotient bits
+    over the [B] lanes — unrolled whole they cost the compiler 21 s)."""
     loops = one_chip_report(lanes)["loops"]
-    assert [r["name"] for r in loops if r["opcode"] == "while"] == []
+    whiles = [r for r in loops if r["opcode"] == "while"]
+    assert len(whiles) <= 2, loops
+    for r in whiles:
+        assert "leaky_f64bits" in (r["op_name"] or ""), r
     sorts = [r["name"] for r in loops if r["opcode"] == "sort"]
     assert len(sorts) == 2, loops
 

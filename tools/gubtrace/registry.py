@@ -112,7 +112,7 @@ def _bucket_rows(B: int):
     return BucketRows(
         key_hash=z64(), algo=np.zeros(B, np.int32), limit=z64(),
         duration=z64(), remaining=z64(),
-        remaining_f=np.zeros(B, np.float64), t0=z64(),
+        remaining_f=z64(), t0=z64(),  # binary64 bits
         status=np.zeros(B, np.int32), burst=z64(), expire_at=z64(),
     )
 
@@ -172,29 +172,26 @@ def _step_spec(
 
 
 # -- deliberate-cast budgets (ops/step.py) -------------------------------
-# apply_batch taints every int64 table/batch counter.  The licensed
-# casts are the leaky bucket's Go-float arithmetic — algorithms.go
-# computes burst/rate/leak/hits in float64, re-derived here as the 14
-# tainted `_f64(...)` sites in apply_batch_impl (lb0, lb1, l_rate x3,
-# elapsed, lb4, ln_rate x2, ln_rem_f, plus the saturating ResetTime
-# rewrite's f_now, f_lim and _f64(ln_resp_rem) — the reset product now
-# runs in float64 through the _trunc_i64 saturation contract; each is
-# exact below 2^53, the float64 mantissa).  The 15th would be a
-# regression.
-# split64: the write-back stores eight int64 columns (key, limit,
-# duration, remaining, t0, burst, expire_at, touched), one lossless
-# split each.
+# apply_batch taints every int64 table/batch counter.  It licenses NO
+# float cast: the leaky bucket's Go-float arithmetic (algorithms.go
+# computes burst/rate/leak/hits in float64) runs on the binary64's BITS
+# in uint64/int64 words (ops/f64bits.py) — casts inside the 64-bit
+# integer family are free, and a `to_f64` anywhere in a step kernel is a
+# regression (tests/test_f64bits.py holds the jaxpr free of floats).
+# split64: the write-back stores nine 64-bit columns (key, limit,
+# duration, remaining, remaining_f's bits, t0, burst, expire_at,
+# touched), one lossless split each.
 # to_i32: `locate_slots` narrows each lane's bucket index
 # (`h & (num_buckets - 1)`, under 2^31 by its trace-time check) once, for
 # the 32-bit sort its claim rounds run on — charged to every kernel that
 # locates a slot, once per call.
 _BUCKET_I32 = 1
-_APPLY_CASTS = {"to_f64": 14, "to_i32": _BUCKET_I32, "split64": 8}
+_APPLY_CASTS = {"to_i32": _BUCKET_I32, "split64": 9}
 _APPLY_COUNTERS = _TABLE_COUNTERS + _BATCH_COUNTERS + (".limit",
                                                        ".duration", "[2]")
 # Packed q-form: one widened-int64 row is narrowed back to the int32
 # algo enum (values 0/1 by wire contract).
-_APPLY_Q_CASTS = {"to_f64": 14, "to_i32": 1 + _BUCKET_I32, "split64": 8}
+_APPLY_Q_CASTS = {"to_i32": 1 + _BUCKET_I32, "split64": 9}
 
 
 def _migrate_spec(name: str, fn_name: str, impl_name: str,
@@ -204,10 +201,10 @@ def _migrate_spec(name: str, fn_name: str, impl_name: str,
     extract is gather+clear in one donated dispatch (no licensed casts
     — the only conversions are widenings of the int32 enum columns into
     the packed int64 stack); the inject is probe+load+merge in one,
-    with ONE licensed to_f64 — the conflict merge's leaky-bucket
-    consumed budget (limit - remaining_f), exact below 2^53 like the
-    step kernels' float sites — and nine split64: load_rows' eight
-    column writes plus the merged `remaining`.  The extracts clear with
+    with no float cast — the conflict merge's leaky-bucket consumed
+    budget (limit - remaining_f) is computed on the bits (ops/f64bits.py)
+    — and eleven split64: load_rows' nine column writes plus the merged
+    `remaining` and `remaining_f`.  The extracts clear with
     a constant 0, which is no counter lineage and splits nothing."""
 
     def build() -> BuiltKernel:
@@ -242,10 +239,9 @@ def _table_stats_spec() -> KernelSpec:
     histograms, per-algorithm remaining-fraction distribution, and the
     shadow-slot census over host-enumerated derived-key fingerprints.
     Read-only and NON-donated by contract (it dispatches against the
-    live serving table from the sampler's thread); two licensed to_f64 casts
-    (remaining and limit at the fraction site, exact below 2^53 like
-    the step kernels' float sites — the f64->i32 bin index that
-    follows rides converted float lineage, so it is not charged)."""
+    live serving table from the sampler's thread); no licensed cast: the
+    fraction site divides on the binary64's bits (ops/f64bits.py) and the
+    bin index stays int64."""
 
     def build() -> BuiltKernel:
         import gubernator_tpu.ops.state as state
@@ -260,7 +256,7 @@ def _table_stats_spec() -> KernelSpec:
             trace_fn=functools.partial(state.table_stats_impl, ways=WAYS),
             signatures={"M8": sig(8), "M16": sig(16)},
             counters=_TABLE_COUNTERS + ("[1]", "[2]"),
-            allowed_casts={"to_f64": 2},
+            allowed_casts={},
             perturbations={
                 "weak-now": lambda: (
                     _table(), np.zeros((4, 8), np.int64), 0
@@ -460,10 +456,10 @@ def _global_sync_spec(psum: bool = False) -> KernelSpec:
             counters=_TABLE_COUNTERS + _BATCH_COUNTERS + (
                 ".limit", ".duration", "[3]",
             ),
-            # Two apply_batch passes ride inside the sync step; the
-            # broadcast re-read runs with hits=0 (a literal, untainted)
-            # so its _f64(r_hits) does not count: 14 + 13; the writes
-            # split 8 + 8 auth columns and the cache table's 5.  The
+            # Two apply_batch passes ride inside the sync step (no float
+            # cast: ops/f64bits.py); the writes split 9 + 9 auth columns
+            # and the cache table's 5 (its zeroed remaining_f is a
+            # constant, no counter lineage).  The
             # psum form swaps the aggregation collective (one psum vs
             # all_to_all + sort/segment), not the apply passes — but
             # its aggregated hits arrive through _psum_mod64's four
@@ -471,17 +467,17 @@ def _global_sync_spec(psum: bool = False) -> KernelSpec:
             # all-reduce, PERF.md PR 21): 7 values x 4 masked, lossless
             # u64->u32 narrowings, licensed here, after which the
             # aggregate is no longer tainted lineage — so only the
-            # table-side float sites (4) and splits (16) are charged.
+            # table-side splits (18) are charged.
             # Both forms locate a slot three times (two applies, one
             # store): three bucket narrowings, charged where the keys
             # are still tainted lineage (not behind the psum's limbs).
             allowed_casts=(
-                {"to_f64": 4, "to_i32": 28, "split64": 16} if psum
-                else {"to_f64": 27, "to_i32": 3 * _BUCKET_I32, "split64": 21}
+                {"to_i32": 28, "split64": 18} if psum
+                else {"to_i32": 3 * _BUCKET_I32, "split64": 23}
             ),
             perturbations={},
             recompile_budget=1,
-            expect_aliased=40,  # auth + cache tables, 20 leaves each
+            expect_aliased=42,  # auth + cache tables, 21 leaves each
         )
 
     return KernelSpec(
@@ -563,14 +559,14 @@ def specs() -> List[KernelSpec]:
         _step_spec(
             "apply_batch", "apply_batch", "apply_batch_impl",
             lambda B: (_device_batch(B),),
-            _APPLY_COUNTERS, dict(_APPLY_CASTS), donated=20,
+            _APPLY_COUNTERS, dict(_APPLY_CASTS), donated=21,
         ),
         _step_spec(
             "load_rows", "load_rows", "load_rows_impl",
             lambda B: (_bucket_rows(B),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            # all eight int64 columns
-            {"to_i32": _BUCKET_I32, "split64": 8}, donated=20,
+            # all nine 64-bit columns (remaining_f's bits among them)
+            {"to_i32": _BUCKET_I32, "split64": 9}, donated=21,
         ),
         _step_spec(
             "probe_batch", "probe_batch", "probe_batch_impl",
@@ -589,26 +585,26 @@ def specs() -> List[KernelSpec]:
             _TABLE_COUNTERS + (".key_hash", ".reset_time", "[2]"),
             # key, limit, remaining, expire_at, touched; duration, t0
             # and burst are written as constant zeros (no lineage).
-            {"to_i32": _BUCKET_I32, "split64": 5}, donated=20,
+            {"to_i32": _BUCKET_I32, "split64": 5}, donated=21,
         ),
         _step_spec(
             "apply_batch_packed_q", "apply_batch_packed_q",
             "apply_batch_packed_q_impl",
             lambda B: (np.zeros((12, B), np.int64),),
             _TABLE_COUNTERS + ("[1]", "[2]"),
-            dict(_APPLY_Q_CASTS), donated=20,
+            dict(_APPLY_Q_CASTS), donated=21,
         ),
         # -- ops/state.py: live-migration row kernels -------------------
         _migrate_spec(
             "migrate_extract", "migrate_extract", "migrate_extract_impl",
             lambda B: (np.zeros(B, np.int64),),
-            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=20,
+            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=21,
         ),
         _migrate_spec(
             "migrate_inject", "migrate_inject", "migrate_inject_impl",
             lambda B: (_bucket_rows(B),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {"to_f64": 1, "to_i32": _BUCKET_I32, "split64": 9}, donated=20,
+            {"to_i32": _BUCKET_I32, "split64": 11}, donated=21,
         ),
         # -- ops/state.py: the tier demotion kernel (docs/tiering.md) --
         # Same gather+clear atomicity shape as migrate_extract, but the
@@ -617,7 +613,7 @@ def specs() -> List[KernelSpec]:
         _migrate_spec(
             "demote_extract", "demote_extract", "demote_extract_impl",
             lambda B: (np.zeros(B, np.int64),),
-            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=20,
+            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=21,
         ),
         # -- ops/state.py: the gubstat state census ---------------------
         _table_stats_spec(),
@@ -632,21 +628,21 @@ def specs() -> List[KernelSpec]:
             "sharded_step_packed", f_step("sharded_step_packed"),
             lambda: (_packed_grid(),),
             _TABLE_COUNTERS + ("[1]", "[2]"),
-            dict(_APPLY_Q_CASTS), donated=20,
+            dict(_APPLY_Q_CASTS), donated=21,
         ),
         _mesh_spec(
             "sharded_load_rows",
             row_factory("load_rows_impl", "BucketRows"),
             lambda: (_row_grid(_bucket_rows),),
             _TABLE_COUNTERS + (".key_hash", ".limit", ".duration", "[2]"),
-            {"to_i32": _BUCKET_I32, "split64": 8}, donated=20,
+            {"to_i32": _BUCKET_I32, "split64": 9}, donated=21,
         ),
         _mesh_spec(
             "sharded_store_cached",
             row_factory("store_cached_rows_impl", "CachedRows"),
             lambda: (_row_grid(_cached_rows),),
             _TABLE_COUNTERS + (".key_hash", ".reset_time", "[2]"),
-            {"to_i32": _BUCKET_I32, "split64": 5}, donated=20,
+            {"to_i32": _BUCKET_I32, "split64": 5}, donated=21,
         ),
         _mesh_spec(
             "sharded_probe", f_step("sharded_probe"),
@@ -661,13 +657,13 @@ def specs() -> List[KernelSpec]:
         _mesh_spec(
             "sharded_demote_extract", demote_factory,
             lambda: (np.zeros(8, np.int64),),
-            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=20,
+            _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=21,
         ),
         _mesh_spec(
             "sharded_table_stats", f_step("sharded_table_stats"),
             lambda: (np.zeros((4, 8), np.int64),),
             _TABLE_COUNTERS + ("[1]", "[2]"),
-            {"to_f64": 2}, donated=0,
+            {}, donated=0,
         ),
         _global_sync_spec(),
         _global_sync_spec(psum=True),
