@@ -66,16 +66,15 @@ def gather64(col: "Col64", idx) -> jax.Array:
     return ((hi << jnp.uint64(32)) | lo).astype(jnp.int64)
 
 
-def scatter64(col: "Col64", tgt, v, mode=None) -> "Col64":
+def scatter64(col: "Col64", tgt, v, **kw) -> "Col64":
     """Split int64 `v` and write both halves at `tgt` (lossless: the low
-    word by mask, the high word by logical shift)."""
+    word by mask, the high word by logical shift).  `kw` is `.at[].set`'s
+    own (`mode`, `indices_are_sorted`, ...) and goes to both halves'
+    scatters."""
     u = jnp.asarray(v).astype(jnp.int64).astype(jnp.uint64)
     lo = (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)
     hi = (u >> jnp.uint64(32)).astype(jnp.uint32)
-    return Col64(
-        col.lo.at[tgt].set(lo, mode=mode),
-        col.hi.at[tgt].set(hi, mode=mode),
-    )
+    return Col64(col.lo.at[tgt].set(lo, **kw), col.hi.at[tgt].set(hi, **kw))
 
 
 def _halves_to_host(lo, hi) -> np.ndarray:
@@ -101,8 +100,8 @@ class _Col64At:
     def __getitem__(self, idx) -> "_Col64At":
         return _Col64At(self._col, idx)
 
-    def set(self, v, mode=None) -> "Col64":
-        return scatter64(self._col, self._idx, v, mode=mode)
+    def set(self, v, **kw) -> "Col64":
+        return scatter64(self._col, self._idx, v, **kw)
 
 
 @jax.tree_util.register_pytree_with_keys_class
@@ -192,6 +191,64 @@ def init_table(num_slots: int) -> SlotTable:
         return zeros(jnp.int32)
 
     return SlotTable(**{f: column(f) for f in SlotTable._fields})
+
+
+# The TPU's compiler has two scatters (PERF.md section 5.3, measured on a
+# v5e).  Told nothing it walks the UPDATES, one row after another: 91 ns a
+# row at 4096 lanes, 110 at 128, whatever the column's length.  Told the
+# targets are sorted it streams the COLUMN through fast memory at the
+# memory system's pace: 0.215 ms a 2^24-row column, 0.04 ms a 2^22-row
+# one, whatever the number of updates.  The second is cheaper while a
+# lane has fewer than some 7,000 rows to itself: at 4096 lanes into 2^24
+# rows (4,096 each) 1.7 times cheaper, at 128 lanes (131,072 each) 14
+# times dearer.
+SORTED_ROWS_A_LANE = 8192
+
+
+def sorts_write_back(num_slots: int, lanes: int) -> bool:
+    """Whether `write_rows` sorts its targets, and says so, when `lanes`
+    rows go into columns of `num_slots` rows — static shapes of the
+    program being traced, whose ratio is what the compiler's own choice
+    between its two scatters follows too."""
+    return num_slots <= SORTED_ROWS_A_LANE * lanes
+
+
+def write_order(do_write, slot, num_slots: int, sort: bool, vals=()):
+    """(int32[B] scatter targets, `vals`) as `write_rows` hands them to
+    the scatter.  Lane i goes to `slot[i]` where `do_write[i]` and past
+    the table's end where not (`mode="drop"` drops it).  With `sort` the
+    targets come back in order, and the [B] vectors `vals` in the same
+    order: they ride along as the sort's operands."""
+    if slot.dtype != jnp.int32:
+        raise TypeError(f"write targets are 32-bit, got {slot.dtype}")
+    if num_slots >= 1 << 31:
+        raise ValueError(f"num_slots ({num_slots}) must fit 31 bits")
+    tgt = jnp.where(do_write, slot, num_slots)
+    if sort:
+        tgt, *vals = jax.lax.sort((tgt, *vals), num_keys=1)
+    return tgt, list(vals)
+
+
+def write_rows(table: SlotTable, do_write, slot, rows: SlotTable) -> SlotTable:
+    """The kernels' write-back: lane i's row — `rows` holds the twelve
+    LOGICAL value vectors, [B] each — replaces the table's row `slot[i]`
+    (int32[B], from `locate_slots`) where `do_write[i]`.  The written
+    slots are pairwise different by the kernels' contract (a key once a
+    batch, a claimed way to one lane); were they not, the last lane
+    would win, as it always did — XLA is promised nothing about that.
+    The one place that hands rows to XLA's scatter: every physical
+    column is scattered at the same targets, sorted and said to be where
+    `sorts_write_back` finds it pays at these shapes."""
+    S = table.num_slots
+    sort = sorts_write_back(S, slot.shape[0])
+    tgt, vals = write_order(
+        do_write, slot, S, sort,
+        [v.astype(a.dtype) for a, v in zip(table, rows)],
+    )
+    return SlotTable(*(
+        a.at[tgt].set(v, mode="drop", indices_are_sorted=sort)
+        for a, v in zip(table, vals)
+    ))
 
 
 def _floats_back(cols: dict) -> dict:

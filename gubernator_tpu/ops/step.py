@@ -45,7 +45,12 @@ import jax
 import jax.numpy as jnp
 
 from gubernator_tpu.ops import f64bits as F
-from gubernator_tpu.ops.state import KIND_BUCKET, KIND_CACHED_RESP, SlotTable
+from gubernator_tpu.ops.state import (
+    KIND_BUCKET,
+    KIND_CACHED_RESP,
+    SlotTable,
+    write_rows,
+)
 
 ALGO_TOKEN = 0
 ALGO_LEAKY = 1
@@ -284,13 +289,18 @@ def locate_slots(
     active: jax.Array,
     now: jax.Array,
     ways: int,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Set-associative lookup + insert-victim claim for a batch of keys.
 
-    Returns (found, persist, slot, slot_safe): `found` lanes matched a live
-    slot at `slot`; `persist & ~found` lanes won an insert victim at `slot`;
-    `~persist` lanes could not claim a slot (transient).  Each active key
-    must appear at most once in the batch (the packer's contract).
+    Returns (found, persist, slot, slot_safe, slot32): `found` lanes matched
+    a live slot at `slot`; `persist & ~found` lanes won an insert victim at
+    `slot`; `~persist` lanes could not claim a slot (transient).  Each
+    active key must appear at most once in the batch (the packer's
+    contract), so the `persist` lanes' slots are pairwise different.
+    `slot32` is `slot` as int32, put together from the 32-bit bucket and
+    way the claim runs on — what `write_rows` scatters at; it holds
+    `slot` where the table has fewer than 2^31 slots, which `write_rows`
+    checks.
 
     The claim is DEFINED as INSERT_ROUNDS rounds over the whole batch: in
     each, every lane still in need takes the lowest-scored candidate slot
@@ -335,9 +345,11 @@ def locate_slots(
     # A round never takes a way scored at or past `inf` (the mark of a
     # reserved way in the rounds' definition; no real score reaches it).
     inf = jnp.int64(1) << 62
+    bucket32 = bucket.astype(jnp.int32)
+    match_way32 = jnp.where(found, match_way, -1).astype(jnp.int32)
     won_way = _claim_ways(
-        bucket.astype(jnp.int32),
-        jnp.where(found, match_way, -1).astype(jnp.int32),
+        bucket32,
+        match_way32,
         _rank_ways(vscore, need[:, None] & (vscore < inf)),
     )
     won = won_way >= 0
@@ -347,7 +359,10 @@ def locate_slots(
         found, match_slot, jnp.where(won, bucket * ways + won_way, 0)
     )
     slot_safe = jnp.clip(slot, 0, S - 1)
-    return found, persist, slot, slot_safe
+    slot32 = jnp.where(
+        persist, bucket32 * ways + jnp.where(found, match_way32, won_way), 0
+    )
+    return found, persist, slot, slot_safe, slot32
 
 
 def apply_batch_impl(
@@ -361,12 +376,13 @@ def apply_batch_impl(
     Un-jitted traceable core — call `apply_batch` directly, or wrap this in
     `shard_map` for the mesh-sharded table (gubernator_tpu.parallel).
     """
-    S = table.key.shape[0]
     now = jnp.asarray(now, dtype=jnp.int64)
 
     h = batch.key_hash
     active = batch.active
-    found, persist, slot, slot_safe = locate_slots(table, h, active, now, ways)
+    found, persist, _, slot_safe, slot32 = locate_slots(
+        table, h, active, now, ways
+    )
 
     # ---- gather current rows -------------------------------------------
     g = lambda a: a[slot_safe]
@@ -568,7 +584,6 @@ def apply_batch_impl(
 
     # ==== write back ====================================================
     do_write = persist & active & ~cached_hit
-    tgt = jnp.where(do_write, slot, S)  # S -> dropped by scatter mode
 
     n_key = jnp.where(tok_clear, 0, h)
     n_algo = jnp.where(tok_clear, 0, batch.algo).astype(jnp.int32)
@@ -585,23 +600,20 @@ def apply_batch_impl(
     n_expire = sel(te_expire, tn_expire, le_expire, ln_expire, 0)
     n_touched = jnp.where(tok_clear, 0, now)
 
-    def scat(arr, val):
-        return arr.at[tgt].set(val.astype(arr.dtype), mode="drop")
-
-    new_table = SlotTable(
-        key=scat(table.key, n_key),
-        algo=scat(table.algo, n_algo),
-        kind=scat(table.kind, n_kind),
-        limit=scat(table.limit, n_limit),
-        duration=scat(table.duration, n_dur),
-        remaining=scat(table.remaining, n_rem),
-        remaining_f=scat(table.remaining_f, n_rem_f),
-        t0=scat(table.t0, n_t0),
-        status=scat(table.status, n_status),
-        burst=scat(table.burst, n_burst),
-        expire_at=scat(table.expire_at, n_expire),
-        touched=scat(table.touched, n_touched),
-    )
+    new_table = write_rows(table, do_write, slot32, SlotTable(
+        key=n_key,
+        algo=n_algo,
+        kind=n_kind,
+        limit=n_limit,
+        duration=n_dur,
+        remaining=n_rem,
+        remaining_f=n_rem_f,
+        t0=n_t0,
+        status=n_status,
+        burst=n_burst,
+        expire_at=n_expire,
+        touched=n_touched,
+    ))
     return new_table, resp
 
 
@@ -639,30 +651,25 @@ def load_rows_impl(
             "BucketRows.remaining_f carries binary64 BITS (int64): convert "
             f"on the host with f64bits.to_bits, got {rows.remaining_f.dtype}"
         )
-    S = table.key.shape[0]
     now = jnp.asarray(now, dtype=jnp.int64)
     active = rows.key_hash != 0
-    _, persist, slot, _ = locate_slots(table, rows.key_hash, active, now, ways)
-    do_write = persist & active
-    tgt = jnp.where(do_write, slot, S)
-
-    def scat(arr, val):
-        return arr.at[tgt].set(val.astype(arr.dtype), mode="drop")
-
-    return SlotTable(
-        key=scat(table.key, rows.key_hash),
-        algo=scat(table.algo, rows.algo),
-        kind=scat(table.kind, jnp.full_like(rows.algo, KIND_BUCKET)),
-        limit=scat(table.limit, rows.limit),
-        duration=scat(table.duration, rows.duration),
-        remaining=scat(table.remaining, rows.remaining),
-        remaining_f=scat(table.remaining_f, rows.remaining_f),
-        t0=scat(table.t0, rows.t0),
-        status=scat(table.status, rows.status),
-        burst=scat(table.burst, rows.burst),
-        expire_at=scat(table.expire_at, rows.expire_at),
-        touched=scat(table.touched, jnp.full_like(rows.key_hash, now)),
+    _, persist, _, _, slot32 = locate_slots(
+        table, rows.key_hash, active, now, ways
     )
+    return write_rows(table, persist & active, slot32, SlotTable(
+        key=rows.key_hash,
+        algo=rows.algo,
+        kind=jnp.full_like(rows.algo, KIND_BUCKET),
+        limit=rows.limit,
+        duration=rows.duration,
+        remaining=rows.remaining,
+        remaining_f=rows.remaining_f,
+        t0=rows.t0,
+        status=rows.status,
+        burst=rows.burst,
+        expire_at=rows.expire_at,
+        touched=jnp.full_like(rows.key_hash, now),
+    ))
 
 
 load_rows = jax.jit(
@@ -767,33 +774,26 @@ def store_cached_rows_impl(
     (gubernator.go:464-479): the stored item IS the response, with
     ExpireAt = status.ResetTime.  Keys must be unique within the batch.
     """
-    S = table.key.shape[0]
     now = jnp.asarray(now, dtype=jnp.int64)
     active = rows.key_hash != 0
-    found, persist, slot, _ = locate_slots(
+    _, persist, _, _, slot32 = locate_slots(
         table, rows.key_hash, active, now, ways
     )
-    do_write = persist & active
-    tgt = jnp.where(do_write, slot, S)
-
-    def scat(arr, val):
-        return arr.at[tgt].set(val.astype(arr.dtype), mode="drop")
-
     z = jnp.zeros_like(rows.key_hash)
-    return SlotTable(
-        key=scat(table.key, rows.key_hash),
-        algo=scat(table.algo, rows.algo),
-        kind=scat(table.kind, jnp.full_like(rows.algo, KIND_CACHED_RESP)),
-        limit=scat(table.limit, rows.limit),
-        duration=scat(table.duration, z),
-        remaining=scat(table.remaining, rows.remaining),
-        remaining_f=scat(table.remaining_f, z),
-        t0=scat(table.t0, z),
-        status=scat(table.status, rows.status),
-        burst=scat(table.burst, z),
-        expire_at=scat(table.expire_at, rows.reset_time),
-        touched=scat(table.touched, jnp.full_like(rows.key_hash, now)),
-    )
+    return write_rows(table, persist & active, slot32, SlotTable(
+        key=rows.key_hash,
+        algo=rows.algo,
+        kind=jnp.full_like(rows.algo, KIND_CACHED_RESP),
+        limit=rows.limit,
+        duration=z,
+        remaining=rows.remaining,
+        remaining_f=z,
+        t0=z,
+        status=rows.status,
+        burst=z,
+        expire_at=rows.reset_time,
+        touched=jnp.full_like(rows.key_hash, now),
+    ))
 
 
 store_cached_rows = jax.jit(

@@ -5,7 +5,7 @@
     JAX_PLATFORMS=cpu python scripts/claim_rounds_chip.py --platform cpu \\
         --slots 65536 --reps 3                        # dry run here
 
-Three things, one JSON object on the last line of stdout (also written to
+Four things, one JSON object on the last line of stdout (also written to
 --out/summary.json):
 
   identical  seeded tables and batches (tests/test_locate_slots.py's
@@ -22,6 +22,22 @@ Three things, one JSON object on the last line of stdout (also written to
              every HLO op's device time per launch with the `op_name` the
              compiled program gives it (--out/ops.json holds all of them,
              the summary the first 25) — what the step is made of.
+
+  write_back the step at both tiers under each way of handing the
+             write-back's rows to XLA's scatter: {int64, int32} targets x
+             {sorted once or not} x {told unique or not}
+             (tests/test_write_back.py `variant_write_rows`;
+             `int64.unsorted.any` is the plain scatter the step had until
+             PR 36; a sorted one puts the values in the targets' order by
+             a gather a vector), the sorted one with the values riding
+             through the sort as its operands, and `served`, which is
+             `ops/state.py` `write_rows` as it stands.  Each must leave the
+             table and the response bit-identical with the plain one's —
+             `served` on all four seeded cases — or the exit code is 1.
+             Per variant: ms a launch by the host clock, and from a trace
+             of --reps launches the device's ms a launch, of which the
+             table scatters', the sorts' and the gathers'
+             (--out/write_back.json holds every op).
 
 It fails where there is no TPU unless `--platform cpu` is given, and a
 number from such a run is a rehearsal, not a device time.
@@ -82,6 +98,144 @@ def _op_names(hlo: str) -> dict:
     return out
 
 
+def _trace_ops(fn, reps: int, trace_dir: Path, names: dict, traced: bool):
+    """Trace `reps` calls of `fn`: (the device's ms a launch by the
+    programs' own events, [[op, ms a launch, op_name]] longest first).
+    Where there is no TPU the calls are made and nothing is read."""
+    import jax
+
+    jax.profiler.start_trace(str(trace_dir))
+    for _ in range(reps):
+        out = fn()
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    launch_ms, rows = None, []
+    if traced:
+        from jax.profiler import ProfileData
+
+        # The benchmark's reduction, by path: its name is the standard
+        # library's.
+        trace_lib = _load("bench_trace", REPO / "bench" / "lib" / "trace.py")
+        totals: dict = {}
+        module_ns = 0.0
+        pd = ProfileData.from_file(trace_lib.find_xplane(str(trace_dir)))
+        for plane in pd.planes:
+            if not trace_lib.DEVICE_PLANE.match(plane.name):
+                continue
+            for line in plane.lines:
+                if line.name == "XLA Modules":
+                    module_ns += sum(ev.duration_ns for ev in line.events)
+                if line.name != "XLA Ops":
+                    continue
+                for ev in line.events:
+                    k = trace_lib.short_op(ev.name)
+                    totals[k] = totals.get(k, 0.0) + ev.duration_ns
+        launch_ms = module_ns / reps / 1e6
+        rows = sorted(
+            ([k, v / reps / 1e6, names.get(k, "")]
+             for k, v in totals.items()), key=lambda r: -r[1])
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return launch_ms, rows
+
+
+def _write_back(served: dict, args, now, trace_dir: Path, traced: bool):
+    """The step under each variant of the write-back (module docstring):
+    (all identical, {tier: {variant: readings}}, {tier.variant: ops})."""
+    import jax
+    import jax.numpy as jnp
+
+    import test_locate_slots as ref
+    import test_write_back as wb
+    from gubernator_tpu.ops import state as st
+    from gubernator_tpu.ops import step as sp
+
+    variants = {"served": st.write_rows}
+    variants.update(
+        (name, wb.variant_write_rows(*v)) for name, v in wb.VARIANTS.items())
+    variants["int32.sorted.any.through_the_sort"] = wb.variant_write_rows(
+        jnp.int32, True, False, via="sort")
+    plain = "int64.unsorted.any"
+
+    def compiled_step(write, table, q):
+        # `write_rows` is looked up when the step is traced, and a trace
+        # is cached by the function traced: a new one for every variant.
+        def step(table, q, now):
+            return sp.apply_batch_packed_q_impl(table, q, now, ways=WAYS)
+
+        sp.write_rows = write
+        try:
+            return jax.jit(step, donate_argnums=(0,)).lower(
+                table, q, now).compile()
+        finally:
+            sp.write_rows = st.write_rows
+
+    def same(a, b) -> bool:
+        return all(bool(jnp.array_equal(x, y)) for x, y in zip(
+            jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b),
+            strict=True))
+
+    def copy(table):
+        return jax.tree_util.tree_map(jnp.copy, table)
+
+    ok, report, ops = True, {}, {}
+    for B, (table, h, active) in served.items():
+        q = jnp.asarray(_batch_q(h, active))
+        report[f"B{B}"] = tier = {}
+        want = compiled_step(variants[plain], table, q)(copy(table), q, now)
+        for name, write in variants.items():
+            step = compiled_step(write, table, q)
+            identical = same(step(copy(table), q, now), want)
+            ok = ok and identical
+            state = {"table": copy(table)}
+
+            def launch(step=step, state=state, q=q):
+                state["table"], resp = step(state["table"], q, now)
+                return resp
+
+            ms = _ms_per_call(launch, args.reps)
+            device_ms, rows = _trace_ops(
+                launch, args.reps, trace_dir, _op_names(step.as_text()),
+                traced)
+
+            def of(suffix):
+                return sum(r[1] for r in rows if r[2].endswith(suffix))
+
+            tier[name] = {"identical": identical, "ms": ms}
+            if traced:
+                tier[name].update(
+                    device_ms=device_ms, scatter_ms=of("/scatter"),
+                    sort_ms=of("/sort"), gather_ms=of("/gather"))
+                ops[f"B{B}.{name}"] = rows
+            del state, step
+        # `served` against the plain scatter on the conflict-heavy cases
+        # (B / 4 buckets: transient lanes, inactive lanes, full buckets).
+        for case, (seed, *shape) in CASES.items():
+            if case == "served":
+                continue
+            t, ch, ca = ref._random_case(
+                args.seed + seed * 1000 + B, B, WAYS, max(B // 4, 1), *shape)
+            cq = jnp.asarray(_batch_q(ch, ca))
+            identical = same(
+                compiled_step(st.write_rows, t, cq)(copy(t), cq, now),
+                compiled_step(variants[plain], t, cq)(copy(t), cq, now))
+            ok = ok and identical
+            tier[f"served.identical.{case}"] = identical
+    return ok, report, ops
+
+
+def _batch_q(h, active):
+    """int64[12, B] request rows for the keys `h`: one hit against 1000 in
+    30 days, token and leaky lanes alternating."""
+    import numpy as np
+
+    import test_locate_slots as ref
+
+    q = np.zeros((12, len(h)), dtype=np.int64)
+    q[0], q[1], q[2], q[3] = np.asarray(h), 1, 1000, 30 * ref.DAY
+    q[4], q[5], q[10] = np.arange(len(h)) % 2, 1000, np.asarray(active)
+    return q
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--platform", default="tpu", choices=("tpu", "cpu"))
@@ -95,7 +249,6 @@ def main() -> int:
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     import gubernator_tpu.ops  # noqa: F401 — x64 on, compile cache
     import test_locate_slots as ref
@@ -149,10 +302,7 @@ def main() -> int:
     # The whole step, table donated and fed back, as the backend runs it.
     steps = {}
     for B, (table, h, active) in served.items():
-        q = np.zeros((12, B), dtype=np.int64)
-        q[0], q[1], q[2], q[3] = np.asarray(h), 1, 1000, 30 * ref.DAY
-        q[10] = np.asarray(active)
-        q = jnp.asarray(q)
+        q = jnp.asarray(_batch_q(h, active))
         state = {"table": jax.tree_util.tree_map(jnp.copy, table)}
 
         def step(state=state, q=q):
@@ -164,40 +314,24 @@ def main() -> int:
             step, args.reps)
         steps[B] = step
 
-    trace_dir = out_dir / "trace"
-    jax.profiler.start_trace(str(trace_dir))
-    for _ in range(args.reps):
-        resp = steps[4096]()
-    jax.block_until_ready(resp)
-    jax.profiler.stop_trace()
-    if dev.platform == "tpu":
-        from jax.profiler import ProfileData
-
-        # The benchmark's reduction, by path: its name is the standard
-        # library's.
-        trace_lib = _load("bench_trace", REPO / "bench" / "lib" / "trace.py")
-        table, h, _ = served[4096]
-        q = jax.ShapeDtypeStruct((12, 4096), jnp.int64)
-        names = _op_names(sp.apply_batch_packed_q.lower(
-            table, q, now, ways=WAYS).compile().as_text())
-        totals: dict = {}
-        pd = ProfileData.from_file(trace_lib.find_xplane(str(trace_dir)))
-        for plane in pd.planes:
-            if not trace_lib.DEVICE_PLANE.match(plane.name):
-                continue
-            for line in plane.lines:
-                if line.name != "XLA Ops":
-                    continue
-                for ev in line.events:
-                    k = trace_lib.short_op(ev.name)
-                    totals[k] = totals.get(k, 0.0) + ev.duration_ns
-        rows = sorted(
-            ([k, v / args.reps / 1e6, names.get(k, "")]
-             for k, v in totals.items()), key=lambda r: -r[1])
+    # What the 4096-lane step is made of, op by op.
+    traced = dev.platform == "tpu"
+    table, h, _ = served[4096]
+    q = jax.ShapeDtypeStruct((12, 4096), jnp.int64)
+    names = _op_names(sp.apply_batch_packed_q.lower(
+        table, q, now, ways=WAYS).compile().as_text())
+    _, rows = _trace_ops(steps[4096], args.reps, out_dir / "trace", names,
+                         traced)
+    if traced:
         (out_dir / "ops.json").write_text(json.dumps(rows, indent=0) + "\n")
         summary["ops_ms_per_launch"] = rows[:25]
         summary["ops_counted"] = len(rows)
-    shutil.rmtree(trace_dir, ignore_errors=True)
+
+    wb_ok, summary["write_back"], wb_ops = _write_back(
+        served, args, now, out_dir / "trace", traced)
+    ok = ok and wb_ok
+    (out_dir / "write_back.json").write_text(
+        json.dumps(wb_ops, indent=0) + "\n")
 
     summary["ok"] = bool(ok)
     line = json.dumps(summary)

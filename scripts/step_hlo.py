@@ -14,10 +14,18 @@ at the deployment geometry and prints what a device trace only names:
     costs on a machine with 32-bit registers show up here, as do the
     compiler's own table-length copies;
   * every `while` and `sort`, with the `op_name` that says which line of
-    the kernel it is.
+    the kernel it is;
+  * every scatter into a table-length column (the write-back,
+    `ops/state.py` `write_rows`): its index type, what it promises about
+    its indices (`indices_are_sorted`, `unique_indices`) and the scoped
+    memory its fusion uses, which tells the compiler's two scatter
+    emitters apart (some 132 KB: it walks the updates; 16 MB: it streams
+    the column).
 
-Programs: the one-chip `apply_batch_packed_q` at each step tier, and the
-mesh step (`make_sharded_step_packed`) over the four described devices.
+Programs: the one-chip `apply_batch_packed_q` at each step tier, and over
+the four described devices the mesh step (`make_sharded_step_packed`) and
+the GLOBAL sync program (`make_global_sync_step_psum`: two applies and a
+store of broadcast rows a launch).
 
     JAX_PLATFORMS=cpu python scripts/step_hlo.py                # 2^24 slots
     JAX_PLATFORMS=cpu python scripts/step_hlo.py --json out.json
@@ -48,6 +56,10 @@ _OP_RE = re.compile(
 _DIMS_RE = re.compile(r"\[([\d,]*)\]")
 _TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
 _OPNAME_RE = re.compile(r'op_name="([^"]+)"')
+_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+_SCOPED_RE = re.compile(
+    r'"used_scoped_memory_configs":\[\{[^\]]*?"size":"(\d+)"')
+_SCATTER_ARGS_RE = re.compile(r"\bscatter\(([^)]*)\)")
 
 
 def describe(topology_name: str = "v5e:2x2"):
@@ -78,6 +90,47 @@ def _dims(shape: str) -> List[int]:
         int(d) for m in _DIMS_RE.finditer(shape)
         for d in m.group(1).split(",") if d
     ]
+
+
+def table_scatters(hlo: str, table_len: int) -> List[dict]:
+    """Every `scatter` of the module whose result is of table length —
+    inside the fusion the compiler wrapped it in — with its index type
+    and promises, and the fusion's name and scoped memory."""
+    shapes: Dict[str, str] = {}
+    computation = ""
+    fusions: Dict[str, dict] = {}
+    found = []
+    for line in hlo.splitlines():
+        if line and not line[0].isspace():
+            head = line.split("(", 1)[0].split()
+            computation = head[-1].lstrip("%") if head else ""
+            continue
+        m = _OP_RE.match(line)
+        if m is None:
+            continue
+        shapes[m.group("name")] = m.group("shape")
+        if m.group("opcode") == "fusion":
+            calls, scoped = _CALLS_RE.search(line), _SCOPED_RE.search(line)
+            if calls:
+                fusions[calls.group(1)] = {
+                    "fusion": m.group("name"),
+                    "scoped_bytes": int(scoped.group(1)) if scoped else 0,
+                }
+        if m.group("opcode") == "scatter" \
+                and table_len in _dims(m.group("shape")):
+            found.append((computation, m.group("name"), line))
+    out = []
+    for computation, name, line in found:
+        args = _SCATTER_ARGS_RE.search(line).group(1).split(",")
+        index = shapes.get(args[1].strip().lstrip("%"), "?")
+        out.append({
+            "name": name,
+            "index_dtype": index.split("[", 1)[0],
+            "indices_are_sorted": "indices_are_sorted=true" in line,
+            "unique_indices": "unique_indices=true" in line,
+            **fusions.get(computation, {"fusion": None, "scoped_bytes": 0}),
+        })
+    return out
 
 
 def summarize(compiled, table_len: int) -> dict:
@@ -122,6 +175,7 @@ def summarize(compiled, table_len: int) -> dict:
             for t in X64_TARGETS
         },
         "loops": loops,
+        "table_scatters": table_scatters(hlo, table_len),
     }
 
 
@@ -198,6 +252,40 @@ def analyze_mesh_step(topo, num_slots: int, lanes: int,
     return out
 
 
+def analyze_global_sync(topo, num_slots: int, delta_slots: int = 256,
+                        ways: int = 8) -> dict:
+    """`GlobalEngine`'s sync program over every described device, at the
+    engine's defaults: `delta_slots` lanes an owner, a replicated cache
+    table of `num_slots` rows beside the authoritative one."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from gubernator_tpu.parallel.global_sync import (
+        make_global_sync_step_psum,
+        zero_delta_grid,
+    )
+    from gubernator_tpu.parallel.mesh import SHARD_AXIS, make_mesh
+
+    n = len(topo.devices)
+    mesh = make_mesh(n, devices=topo.devices)
+    rows = NamedSharding(mesh, P(SHARD_AXIS))
+    table = _abstract_table(num_slots, rows)
+    delta = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows),
+        zero_delta_grid(n, delta_slots),
+    )
+    now = jax.ShapeDtypeStruct(
+        (), "int64", sharding=NamedSharding(mesh, P())
+    )
+    compiled = make_global_sync_step_psum(mesh, ways).lower(
+        table, table, delta, now
+    ).compile()
+    out = summarize(compiled, num_slots // n)
+    out.update(program=f"global_sync_step_psum[n={n}, D={delta_slots}]",
+               table_bytes=2 * table_bytes(table) // n)
+    return out
+
+
 def _print(rep: dict) -> None:
     mem = rep["memory"]
     print(f"== {rep['program']}  (table length {rep['table_len']}, "
@@ -216,6 +304,12 @@ def _print(rep: dict) -> None:
     for r in rep["loops"]:
         print(f"     {r['name']:28s} {r['opcode']:6s} "
               f"{(r['op_name'] or '')[-80:]}")
+    print(f"   table-length scatters ({len(rep['table_scatters'])}):")
+    for r in rep["table_scatters"]:
+        print(f"     {r['fusion'] or r['name']:28s} index {r['index_dtype']:4s}"
+              f" sorted={r['indices_are_sorted']!s:5s} "
+              f"unique={r['unique_indices']!s:5s} "
+              f"scoped {r['scoped_bytes']:>10,d} B")
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -236,9 +330,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         rep = analyze_step(topo, args.slots, lanes)
         reports[rep["program"]] = rep
         _print(rep)
-    rep = analyze_mesh_step(topo, args.slots, args.mesh_lanes)
-    reports[rep["program"]] = rep
-    _print(rep)
+    for rep in (analyze_mesh_step(topo, args.slots, args.mesh_lanes),
+                analyze_global_sync(topo, args.slots)):
+        reports[rep["program"]] = rep
+        _print(rep)
     if args.json:
         Path(args.json).write_text(json.dumps(reports, indent=1) + "\n")
     return 0

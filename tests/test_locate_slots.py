@@ -141,10 +141,13 @@ def _agree(table: SlotTable, h, active, ways: int, now: int = NOW):
     active = jnp.asarray(active, dtype=bool)
     now = jnp.int64(now)
     want = ref_locate_slots(table, h, active, now, ways=ways)
-    got = new_locate_slots(table, h, active, now, ways=ways)
-    for name, w, g in zip(FIELDS, want, got):
+    *got, slot32 = new_locate_slots(table, h, active, now, ways=ways)
+    for name, w, g in zip(FIELDS, want, got, strict=True):
         assert g.dtype == w.dtype and g.shape == w.shape, name
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    # The write-back's 32-bit spelling of `slot` (ops/state.py write_rows).
+    assert slot32.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(slot32), np.asarray(got[2]))
     return tuple(np.asarray(g) for g in got)
 
 
@@ -371,5 +374,5 @@ def test_under_shard_map_on_four_virtual_devices():
         per_shard, mesh=mesh, in_specs=(P("shard"), P("shard"), P("shard")),
         out_specs=P("shard"),
     ))(table, h, active)
-    for name, g, want in zip(FIELDS, got, zip(*one)):
+    for name, g, want in zip(FIELDS, got[:4], zip(*one), strict=True):
         np.testing.assert_array_equal(np.asarray(g), np.stack(want), name)

@@ -523,6 +523,35 @@ def test_dtype_plane_flags_a_low_word_only_store():
     assert "to_i32" not in errs["undeclared_split"], fs
 
 
+def _narrow_beside_a_sorted_counter(which: int):
+    """A counter ([2]) sorted beside an untainted vector ([1]), as the
+    write-back's values ride beside their targets; then ONE of the two
+    sorted operands narrowed to int32."""
+    import jax.numpy as jnp
+
+    def impl(col, other, counter):
+        out = jax.lax.sort((other, counter), num_keys=1)
+        return col, out[which].astype(jnp.int32)
+
+    return impl
+
+
+def test_dtype_plane_follows_a_sort_operand_by_operand():
+    from tools.gubtrace import run
+
+    fs = run(select=["dtype-taint"], root=REPO, specs=[
+        _split_spec("ok_narrow_the_other", _narrow_beside_a_sorted_counter(0),
+                    {}),
+        _split_spec("viol_narrow_the_counter",
+                    _narrow_beside_a_sorted_counter(1), {}),
+    ])
+    errs = {f.kernel: f.message for f in fs if f.severity == "error"}
+    # No value crosses from one operand of a sort into another...
+    assert "ok_narrow_the_other" not in errs, fs
+    # ...and a counter is still a counter when it comes out sorted.
+    assert "to_i32" in errs["viol_narrow_the_counter"], fs
+
+
 def test_range_plane_rejects_an_envelope_wider_than_the_logical_bound(
         tmp_path):
     """The bound declared under a field's name is the bound the
@@ -597,8 +626,18 @@ def test_one_chip_step_has_no_table_length_x64_conversion(
     assert rep["memory"]["temp_size_in_bytes"] < 20e6, rep["memory"]
 
 
+# The write-back (ops/state.py `write_rows`) at each one-chip tier of the
+# 2^24-slot table, as `sorts_write_back` picks from lanes and rows:
+# whether every table scatter is handed sorted targets, and the step's
+# sorts — the claim rounds' two, and the write-back's one where it sorts.
+_WRITE_BACK = {
+    128: {"indices_are_sorted": False, "sorts": 2},
+    4096: {"indices_are_sorted": True, "sorts": 3},
+}
+
+
 @pytest.mark.parametrize("lanes", [128, 4096])
-def test_one_chip_step_has_two_sorts_and_no_loop_but_the_division(
+def test_one_chip_step_has_its_sorts_and_no_loop_but_the_division(
         one_chip_report, lanes):
     """The claim rounds of `locate_slots` (ops/step.py).  Until PR 32
     they compiled to three `while` loops (`searchsorted` over B x 8 int64
@@ -606,7 +645,9 @@ def test_one_chip_step_has_two_sorts_and_no_loop_but_the_division(
     TPU's lowering of `_first_claim`'s scatter): 55 % of the 4096-lane
     step's device time (PERF.md PR 31).  Now: the sort of the lanes by
     (bucket, lane) and the sort back to lane order, and nothing of the
-    claim that loops.  The only loops are the leaky lanes' two binary64
+    claim that loops; since PR 36 also the write-back's one sort of its
+    (target, lane) pairs, at a tier whose scatters are told their targets
+    are sorted.  The only loops are the leaky lanes' two binary64
     divisions (ops/f64bits.py `div`: a fixed 7 trips of 8 quotient bits
     over the [B] lanes — unrolled whole they cost the compiler 21 s)."""
     loops = one_chip_report(lanes)["loops"]
@@ -615,9 +656,65 @@ def test_one_chip_step_has_two_sorts_and_no_loop_but_the_division(
     for r in whiles:
         assert "leaky_f64bits" in (r["op_name"] or ""), r
     sorts = [r["name"] for r in loops if r["opcode"] == "sort"]
-    assert len(sorts) == 2, loops
+    assert len(sorts) == _WRITE_BACK[lanes]["sorts"], loops
 
 
-def test_mesh_step_has_no_table_length_x64_conversion(step_hlo, topo):
-    rep = step_hlo.analyze_mesh_step(topo, 1 << 24, 4096)
+def _assert_table_scatters(rep: dict, want: dict) -> None:
+    """21 scatters, one a physical column, each on 32-bit targets, told
+    they are sorted exactly where they are, and never told unique (it
+    bought nothing on the chip, and would be a wrong promise for a batch
+    that broke the kernels' contract)."""
+    scatters = rep["table_scatters"]
+    assert len(scatters) == 2 * len(COL64_FIELDS) + 3, scatters
+    for r in scatters:
+        assert r["index_dtype"] == "s32", r
+        assert r["indices_are_sorted"] == want["indices_are_sorted"], r
+        assert not r["unique_indices"], r
+
+
+@pytest.mark.parametrize("lanes", [128, 4096])
+def test_one_chip_table_scatters_carry_their_tier_s_promises(
+        one_chip_report, lanes):
+    _assert_table_scatters(one_chip_report(lanes), _WRITE_BACK[lanes])
+    assert st.sorts_write_back(1 << 24, lanes) == _WRITE_BACK[lanes][
+        "indices_are_sorted"]
+
+
+@pytest.fixture(scope="module")
+def mesh_report(step_hlo, topo):
+    return step_hlo.analyze_mesh_step(topo, 1 << 24, 4096)
+
+
+def test_mesh_step_has_no_table_length_x64_conversion(mesh_report):
+    _assert_no_boundary_conversion(mesh_report)
+
+
+def test_mesh_step_sorts_its_write_back_once(mesh_report):
+    """Under `shard_map` the compiler sorted the targets of EVERY table
+    scatter itself — 21 sorts of the same 4,096 targets a launch, each
+    with `op_name` `.../scatter` (23 sorts in all, PR 34).  Handed targets
+    sorted once and told so, it adds none."""
+    sorts = [r for r in mesh_report["loops"] if r["opcode"] == "sort"]
+    assert not [r for r in sorts
+                if (r["op_name"] or "").endswith("/scatter")], sorts
+    assert len(sorts) == 3, sorts
+    _assert_table_scatters(mesh_report, {"indices_are_sorted": True})
+
+
+def test_global_sync_program_writes_back_by_its_own_shapes(step_hlo, topo):
+    """`GlobalEngine`'s sync program (two applies of 256 lanes and a store
+    of the 1,024 gathered rows, each into a shard's 2^22 rows): the
+    helper picks per write-back, from its lanes and rows — the applies
+    say nothing (16,384 rows a lane), the store sorts (4,096) — and
+    no scatter anywhere makes the compiler sort for it."""
+    rep = step_hlo.analyze_global_sync(topo, 1 << 24)
     _assert_no_boundary_conversion(rep)
+    sorts = [r for r in rep["loops"] if r["opcode"] == "sort"]
+    assert not [r for r in sorts
+                if (r["op_name"] or "").endswith("/scatter")], sorts
+    assert len(sorts) == 3 * 2 + 1, sorts
+    columns = 2 * len(COL64_FIELDS) + 3
+    told = [r["indices_are_sorted"] for r in rep["table_scatters"]]
+    assert told.count(True) == columns and told.count(False) == 2 * columns
+    assert not any(r["unique_indices"] for r in rep["table_scatters"])
+

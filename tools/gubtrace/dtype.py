@@ -332,6 +332,12 @@ class _Walk:
                     a or b for a, b in zip(outs, o)
                 ]
             return outs or [False] * len(eqn.outvars)
+        if name == "sort":
+            # A sort permutes each operand on its own: the keys decide
+            # the order, and no value crosses from one operand into
+            # another (the write-back's values ride through one sort
+            # beside their targets, ops/state.py `write_order`).
+            return list(tin)
         if name == "pallas_call":
             # Opaque: the Pallas kernel body is differentially tested
             # bit-exact against its XLA reference; taint stops here.

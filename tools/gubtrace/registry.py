@@ -184,7 +184,16 @@ def _step_spec(
 # to_i32: `locate_slots` narrows each lane's bucket index
 # (`h & (num_buckets - 1)`, under 2^31 by its trace-time check) once, for
 # the 32-bit sort its claim rounds run on — charged to every kernel that
-# locates a slot, once per call.
+# locates a slot, once per call.  The write-back's 32-bit targets
+# (`slot32`, ops/state.py `write_rows`) are put together from that SAME
+# narrowed bucket and the claim's int32 way: no int64 slot is narrowed,
+# and the budget stays one.  At the registry's geometry (4096 slots, 64
+# or 128 lanes: few rows a lane) the write-back sorts its targets, so the
+# goldens hold one sort more than the claim's two: the targets are its
+# key and the twelve logical value vectors ride along as operands
+# (PR 36).  The dtype plane follows a sort operand by operand, so a
+# constant column stays untainted beside a counter (tools/gubtrace/
+# dtype.py) and the `split64` budgets are what they were.
 _BUCKET_I32 = 1
 _APPLY_CASTS = {"to_i32": _BUCKET_I32, "split64": 9}
 _APPLY_COUNTERS = _TABLE_COUNTERS + _BATCH_COUNTERS + (".limit",
