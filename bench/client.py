@@ -13,6 +13,12 @@ gRPC through the warm-in and the window without a pause between them:
           Nothing is dropped or re-timed: latency always counts from the
           intended send, and the wait is reported.
 
+`--addr` may list several daemons of one cluster, comma-separated: the
+mix's `connections` are spread over them round-robin (connection c goes to
+daemon c mod N — a load balancer in front of the cluster, upstream README's
+"any peer"), and the record says which connection carried each RPC.  With
+one address nothing differs.
+
 A slow answer is a latency sample.  An RPC fails by an RPC error, by the
 traffic file's deadline, or by being unanswered when the drain ends.
 Everything it saw goes into one .npz for the harness; stdout carries the
@@ -69,11 +75,13 @@ def encode_plan(native, plan, uni_cfg: dict, seed: int) -> list:
 
 
 class Recorder:
-    """Per sent RPC: which plan entry, when it was due, sent and answered
-    (monotonic seconds and wall-clock ms), how it ended, its raw answer."""
+    """Per sent RPC: which plan entry, on which connection, when it was
+    due, sent and answered (monotonic seconds and wall-clock ms), how it
+    ended, its raw answer."""
 
     def __init__(self) -> None:
         self.plan_idx: list = []
+        self.conn: list = []
         self.t_due: list = []
         self.t_send: list = []
         self.t_done: list = []
@@ -83,9 +91,11 @@ class Recorder:
         self.raw: list = []
         self.waited: list = []
 
-    def open(self, plan_idx: int, t_due: float, waited: bool = False) -> int:
+    def open(self, plan_idx: int, t_due: float, waited: bool = False,
+             conn: int = 0) -> int:
         k = len(self.plan_idx)
         self.plan_idx.append(plan_idx)
+        self.conn.append(conn)
         self.t_due.append(t_due)
         self.t_send.append(time.monotonic())
         self.wall_send.append(time.time_ns() // 1_000_000)
@@ -122,16 +132,17 @@ async def one_rpc(call, payload: bytes, deadline_s: float, rec: Recorder,
 async def closed_loop(calls, payloads, traffic, t_end, rec) -> dict:
     nxt = 0
 
-    async def caller(call) -> None:
+    async def caller(conn: int) -> None:
         nonlocal nxt
         while time.monotonic() < t_end:
             j = nxt % len(payloads)
             nxt += 1
-            k = rec.open(j, time.monotonic())
-            await one_rpc(call, payloads[j], traffic["deadline_s"], rec, k)
+            k = rec.open(j, time.monotonic(), conn=conn)
+            await one_rpc(calls[conn], payloads[j], traffic["deadline_s"],
+                          rec, k)
 
     n = int(traffic["in_flight"])
-    await asyncio.gather(*[caller(calls[i % len(calls)]) for i in range(n)])
+    await asyncio.gather(*[caller(i % len(calls)) for i in range(n)])
     return {"pool": len(payloads), "pool_used": nxt}
 
 
@@ -140,10 +151,11 @@ async def open_loop(calls, payloads, times, traffic, t0, rec) -> dict:
     tasks = set()
     waited = 0
 
-    async def send(j: int, call, over_cap: bool) -> None:
+    async def send(j: int, conn: int, over_cap: bool) -> None:
         try:
-            k = rec.open(j, t0 + float(times[j]), over_cap)
-            await one_rpc(call, payloads[j], traffic["deadline_s"], rec, k)
+            k = rec.open(j, t0 + float(times[j]), over_cap, conn)
+            await one_rpc(calls[conn], payloads[j], traffic["deadline_s"],
+                          rec, k)
         finally:
             cap.release()
 
@@ -160,7 +172,7 @@ async def open_loop(calls, payloads, times, traffic, t0, rec) -> dict:
         await cap.acquire()
         if full:
             held_until = time.monotonic()
-        t = asyncio.ensure_future(send(j, calls[j % len(calls)], over_cap))
+        t = asyncio.ensure_future(send(j, j % len(calls), over_cap))
         tasks.add(t)
         t.add_done_callback(tasks.discard)
     if tasks:
@@ -172,16 +184,17 @@ async def drive(args, traffic, payloads, plan) -> dict:
     import grpc.aio
 
     rec = Recorder()
+    addrs = args.addr.split(",")
     channels = [
         grpc.aio.insecure_channel(
-            args.addr,
+            addrs[c % len(addrs)],
             options=[
                 ("grpc.use_local_subchannel_pool", 1),
                 ("grpc.max_receive_message_length", 64 << 20),
                 ("grpc.max_send_message_length", 64 << 20),
             ],
         )
-        for _ in range(int(traffic["connections"]))
+        for c in range(int(traffic["connections"]))
     ]
     calls = [c.unary_unary(METHOD) for c in channels]
     try:
@@ -256,6 +269,7 @@ def save(native, path: str, plan, rec: Recorder, extra: dict,
         f: np.zeros(int(ans_off[-1]), dtype=np.int64)
         for f in ("status", "limit", "remaining", "reset_time", "err_len")
     }
+    first_error = ""
     for k in range(n):
         if code[k] != OK:
             continue
@@ -269,20 +283,30 @@ def save(native, path: str, plan, rec: Recorder, extra: dict,
             continue
         for f in cols:
             cols[f][lo:hi] = getattr(parsed, f)
+        if not first_error and parsed.err_len.any():
+            # What the first answer with an error said, for the log.
+            j = int(np.flatnonzero(parsed.err_len)[0])
+            o = int(parsed.err_off[j])
+            first_error = rec.raw[k][o:o + int(parsed.err_len[j])].decode(
+                "utf-8", "replace")
     np.savez(
         path, plan_idx=np.array(rec.plan_idx, dtype=np.int64),
+        conn=np.array(rec.conn, dtype=np.int64),
         t_due=np.array(rec.t_due), t_send=np.array(rec.t_send),
         t_done=np.array(rec.t_done),
         wall_send=np.array(rec.wall_send, dtype=np.int64),
         wall_recv=np.array(rec.wall_recv, dtype=np.int64),
         code=code, ans_off=ans_off, waited=np.array(rec.waited, dtype=bool),
-        extra=json.dumps(extra), **cols, **more,
+        extra=json.dumps(dict(extra, first_error=first_error)), **cols,
+        **more,
     )
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--addr", required=True)
+    ap.add_argument("--addr", required=True,
+                    help="the daemon's gRPC address; a cluster's, comma-"
+                    "separated")
     ap.add_argument("--config", required=True)
     ap.add_argument("--traffic", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -312,7 +336,8 @@ def main() -> None:
     g = int(traffic.get("global_per_rpc", 0))
     if int(uni_cfg.get("global_keys", 0)) and g:
         more = global_readback(
-            native, args.addr, uni_cfg, args.seed, plan, got["rec"], g
+            native, args.addr.split(",")[0], uni_cfg, args.seed, plan,
+            got["rec"], g
         )
     save(native, args.out, plan, got["rec"], got["extra"], more)
     mark("saved", rpcs=len(got["rec"].plan_idx))

@@ -22,7 +22,7 @@ for n in 1 2; do
   echo "== cold run $n of $w from an empty $d"
   JAX_COMPILATION_CACHE_DIR="$d" python3 bench/run.py --workload "$w" \
     --seed $((seed + n)) --seconds "$secs" --trace 0 ${BENCH_EXTRA:-} > "$log" 2>&1 || rc=$?
-  grep -E "daemon ready|compile-cache entries|compare compiled_in_window|FAILED|window:|^\{|no result" "$log" | cut -c1-1500
+  grep -E "daemon[.0-9]* ready|forward hop|compile-cache entries|compare compiled_in_window|FAILED|window:|^\{|no result" "$log" | cut -c1-1500
   echo "   entries in the cache afterwards: $(ls "$d" | grep -c -- '-cache$')"
   rm -rf "$d"
 done

@@ -240,17 +240,22 @@ def _sample(a: Answers, rec, uni: Universe, seed: int,
 
 class _Reference:
     """core/pymodel.py on a clock the replay sets, holding one key at a
-    time as the harness preloaded it."""
+    time as the harness preloaded it.  `t0_ms` is the preload stamp; of a
+    cluster, every daemon's (each stamps the rows it installs with its own
+    clock), and `self.t0_ms` is then the stamp of the daemon that owns the
+    key last started."""
 
-    def __init__(self, uni: Universe, t0_ms: int,
+    def __init__(self, uni: Universe, t0_ms,
                  extra_crowded: np.ndarray) -> None:
         from gubernator_tpu.core import clock as clock_mod
         from gubernator_tpu.core import types
         from gubernator_tpu.core.pymodel import PyRateLimiter
 
-        self.uni, self.t0_ms, self.types = uni, t0_ms, types
+        self.uni, self.types = uni, types
+        self.t0_by_daemon = [int(t) for t in np.atleast_1d(t0_ms)]
+        self.t0_ms = self.t0_by_daemon[0]
         self.clk = clock_mod.Clock()
-        self.at(t0_ms)
+        self.at(self.t0_ms)
         self.model = PyRateLimiter(clock=self.clk)
         self.crowded = set(extra_crowded.tolist())
 
@@ -262,6 +267,9 @@ class _Reference:
         the key's hash key, whether it is leaky, a request for each value
         of `hits`, and whether its bucket may evict (a weak key)."""
         uni, t = self.uni, self.types
+        if uni.owner is not None:
+            self.t0_ms = self.t0_by_daemon[int(uni.owner[k])]
+            self.at(self.t0_ms)
         leaky = int(uni.algo[k]) == ALGO_LEAKY
         hkey = key_string(int(uni.ids[k]))
         weak = bool(uni.crowded[k]) or int(uni.gbucket[k]) in self.crowded
@@ -376,7 +384,7 @@ def replay_sample(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
                           leaky=leaky)
                     continue
                 if leaky or created is not None:
-                    off = want.reset_time - t0_ms
+                    off = want.reset_time - ref.t0_ms
                     w = (lo, hi) if leaky else created
                     if len(rpcs) == 1 and leaky:
                         w = (int(rec["wall_send"][o[3]]),
@@ -386,8 +394,8 @@ def replay_sample(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
                     good = o[2] == want.reset_time
                 if not good:
                     v.bad("wrong_reset_time", key=hkey, rpc=o[3],
-                          want=want.reset_time - t0_ms, got=o[2] - t0_ms,
-                          leaky=leaky, created=created)
+                          want=want.reset_time - ref.t0_ms,
+                          got=o[2] - ref.t0_ms, leaky=leaky, created=created)
     for name in FROZEN_COUNTS:
         v.counts.setdefault(name, 0)
     v.notes["crowded_restarts"] = restarts
@@ -418,6 +426,7 @@ def replay_moving(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
         rows = order[cuts[c]:cuts[c + 1]]
         k = int(keys[cuts[c]])
         hkey, leaky, reqs, weak = ref.start_key(k, a.hits[rows])
+        t0_ms = ref.t0_ms           # the owner's stamp; below only in notes
         if leaky:
             clock = a.reset_time[rows] - (limit - a.remaining[rows]) * rate_i
         else:

@@ -13,6 +13,8 @@ import os
 import re
 from typing import Dict, List
 
+from lib import ring
+
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(BENCH)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -117,6 +119,15 @@ CLOCK_FORMS = ('absent or "frozen": the clock of the reference stands at the '
 
 ALGORITHM_FORMS = ('absent or "mixed": bit 4 of a key id picks token or '
                    'leaky, half each', '"token": every key a token bucket')
+PEERS_FORMS = ('absent or 1: one daemon on all of the configuration\'s chips',
+               '"peers": N, N = "chips" > 1: N daemons, a chip each, on one '
+               "consistent-hash ring of 512 vnodes a peer; then "
+               "universe.shards is 1, universe.global_keys 0 or absent "
+               "(GLOBAL over gRPC is not judged yet), daemon.GUBER_PEERS "
+               "lists N distinct advertise addresses 127.0.0.1:<port>, and "
+               'daemon.GUBER_PEER_PICKER_HASH is "xx" (the ring hash is the '
+               "table fingerprint; no configuration needs another yet)")
+_LOOPBACK = re.compile(r"^127\.0\.0\.1:([1-9][0-9]{3,4})$")
 # No run may outlast this (the contract: 360 s, 1200 s where it compiles).
 LONGEST_RUN_MS = 1_200_000
 
@@ -224,6 +235,42 @@ def clock_moves(u: dict) -> bool:
     return u.get("clock", "frozen") == "moving"
 
 
+def peers_of(c: dict) -> int:
+    """How many daemons a configuration runs (absent: one)."""
+    return c.get("peers", 1)
+
+
+def peer_addresses(c: dict) -> List[str]:
+    """The advertise addresses of a cluster's daemons, in ring order."""
+    return [a.strip() for a in c["daemon"]["GUBER_PEERS"].split(",")]
+
+
+def ring_of(c: dict):
+    """The ring (bench/lib/ring.py) a configuration states, or None where
+    it runs one daemon."""
+    if peers_of(c) == 1:
+        return None
+    return ring.build(peer_addresses(c), c["daemon"]["GUBER_PEER_PICKER_HASH"])
+
+
+def check_peers(c: dict, where: str) -> None:
+    known = f"{where}: peers is none of the known forms: " + "; ".join(
+        PEERS_FORMS)
+    n = peers_of(c)
+    _need(_whole(n) and n in (1, c["chips"]), known)
+    if n == 1:
+        _need("GUBER_PEERS" not in c["daemon"], known)
+        return
+    u, d = c["universe"], c["daemon"]
+    _need(int(u["shards"]) == 1 and int(u.get("global_keys", 0)) == 0, known)
+    _need(isinstance(d.get("GUBER_PEERS"), str)
+          and d.get("GUBER_PEER_PICKER_HASH") == "xx", known)
+    addrs = peer_addresses(c)
+    _need(len(addrs) == n == len(set(addrs))
+          and all(_LOOPBACK.match(a) and int(a.rpartition(":")[2]) < 65536
+                  for a in addrs), known)
+
+
 def check_config(c: dict, where: str) -> None:
     for k in ("source", "chips", "daemon", "universe", "guarantees",
               "reduced", "assumed", "background_timers_s"):
@@ -247,6 +294,7 @@ def check_config(c: dict, where: str) -> None:
           f"{where}: chips/shards")
     _need(all(k.startswith("GUBER_") for k in c["daemon"]),
           f"{where}: daemon settings are GUBER_* variables")
+    check_peers(c, where)
 
 
 def check_layer_metric(m: dict, where: str) -> None:

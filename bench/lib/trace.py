@@ -6,7 +6,9 @@ harness's own process never imports JAX.  What a v5e trace holds (looked at
 by hand first): one plane per chip, "/device:TPU:<n>", with a line
 "XLA Modules" (one event per program launch, "jit_<name>(<hash>)") and a
 line "XLA Ops" (one event per HLO op, named by its whole HLO text); the
-host's threads are lines of the plane "/host:CPU".
+host's threads are lines of the plane "/host:CPU".  Given several
+directories, a cluster's trace a daemon, it prints bench/lib/cluster.py
+`combine_traces` of their reductions.
 
   busy_s       per chip, the union of its "XLA Ops" intervals; averaged
                over the chips that ran anything.
@@ -208,7 +210,14 @@ def find_xplane(trace_dir: str) -> str:
 
 
 if __name__ == "__main__":
-    target = sys.argv[1]
-    if os.path.isdir(target):
-        target = find_xplane(target)
-    print(json.dumps(reduce_xplane(target)))
+    reduced = [
+        reduce_xplane(find_xplane(t) if os.path.isdir(t) else t)
+        for t in sys.argv[1:]
+    ]
+    if len(reduced) > 1:
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        from lib.cluster import combine_traces
+
+        reduced = [combine_traces(reduced)]
+    print(json.dumps(reduced[0]))

@@ -12,15 +12,23 @@ a fingerprint's bucket is ``h & (buckets_per_shard - 1)``, its shard
 keys that fall in one bucket the first ``ways`` in universe order are
 resident; the rest were "evicted" before the run starts (chip_smoke.py's
 expected_resident counts the same thing).
+
+A cluster (a configuration that says `"peers": N`, bench/lib/ring.py) is N
+daemons of one shard each: a key's daemon is its owner on the consistent-
+hash ring, its bucket there ``h & (buckets_per_daemon - 1)``, and
+``gbucket = owner * buckets_per_daemon + bucket``; `slots` counts every
+daemon's.  With `peers` absent every array is what it was before the form
+existed, bit for bit.
 """
 from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 
+from lib import ring as ring_mod
 from lib.spec import clock_moves
 
 NAMES = 16                      # rate-limit names (tenants) keys spread over
@@ -82,13 +90,18 @@ def encode_rpc(native, ids, hits, limit, duration, algo, behavior) -> bytes:
     )
 
 
-def global_bucket(fp: np.ndarray, slots: int, ways: int,
-                  shards: int) -> np.ndarray:
+def global_bucket(fp: np.ndarray, slots: int, ways: int, shards: int,
+                  owner: Optional[np.ndarray] = None,
+                  peers: int = 1) -> np.ndarray:
     """shard * buckets_per_shard + bucket of int64 fingerprints: the
-    placement arithmetic of the module docstring, in one place."""
-    nb_local = slots // shards // ways
+    placement arithmetic of the module docstring, in one place.  In a
+    cluster `owner` is each key's daemon on the ring and takes the shard's
+    place (a daemon of a cluster has one shard)."""
+    nb_local = slots // (shards * peers) // ways
     u = fp.view(np.uint64)
-    gb = (u >> np.uint64(32)) % np.uint64(shards) * np.uint64(nb_local)
+    part = ((u >> np.uint64(32)) % np.uint64(shards) if owner is None
+            else owner.astype(np.uint64))
+    gb = part * np.uint64(nb_local)
     gb += u & np.uint64(nb_local - 1)
     return gb.astype(np.int64)
 
@@ -129,25 +142,51 @@ class Universe:
     limit: int
     global_limit: int
     duration_ms: int
-    slots: int
+    slots: int               # of the whole deployment: every daemon's
     ways: int
     shards: int
     moving: bool = False     # the configuration's windows elapse in a run
+    ring: Optional[ring_mod.Ring] = None    # a cluster's; None: one daemon
+    owner: Optional[np.ndarray] = None      # uint8[U] daemon, with a ring
 
     @property
     def n_resident(self) -> int:
         return int(self.resident.sum())
 
+    @property
+    def peers(self) -> int:
+        return 1 if self.ring is None else self.ring.n
 
-def build_universe(native, cfg: dict, seed: int, slots: int) -> Universe:
+    def resident_by_daemon(self) -> np.ndarray:
+        """Preloaded rows a daemon: int64[peers]."""
+        if self.ring is None:
+            return np.array([self.n_resident], dtype=np.int64)
+        return np.bincount(self.owner[self.resident], minlength=self.peers)
+
+    def bucket_of(self, fp: np.ndarray) -> np.ndarray:
+        """Global buckets of keys in or outside the universe, from their
+        fingerprints (a cluster's ring hashes a key to its fingerprint)."""
+        owner = (None if self.ring is None
+                 else self.ring.owner(fp.view(np.uint64)))
+        return global_bucket(fp, self.slots, self.ways, self.shards, owner,
+                             self.peers)
+
+
+def build_universe(native, cfg: dict, seed: int, slots: int,
+                   ring=None) -> Universe:
     """`cfg` is the configuration file's "universe" group; `slots` the
-    table size in use (the CPU dry run overrides it).  Works in chunks so
+    table size in use (the CPU dry run overrides it), ONE daemon's where
+    `ring` (bench/lib/ring.py) says there are several.  Works in chunks so
     that temporaries are reused instead of freshly mapped."""
     n = int(cfg["keys"])
     ways, shards = int(cfg["ways"]), int(cfg["shards"])
     n_global = int(cfg.get("global_keys", 0))
     below = int(cfg["preload_remaining_below"])
-    nb_local = slots // shards // ways
+    peers = 1 if ring is None else ring.n
+    if ring is not None and ring.hash != "xx":
+        raise ValueError("only an xx ring hashes a key to its fingerprint")
+    slots *= peers
+    nb_local = slots // (shards * peers) // ways
     if nb_local & (nb_local - 1):
         raise ValueError(f"buckets per shard ({nb_local}) not a power of two")
     if n >= 1 << 31 or slots // ways >= 1 << 31 or ways > 100 or below > 1 << 32:
@@ -157,6 +196,7 @@ def build_universe(native, cfg: dict, seed: int, slots: int) -> Universe:
     algo = np.empty(n, dtype=np.uint8)
     remaining0 = np.empty(n, dtype=np.uint8 if below <= 256 else np.uint32)
     gbucket = np.empty(n, dtype=np.int32)
+    owner = None if ring is None else np.empty(n, dtype=np.uint8)
     # (bucket, index) packed into one word: a plain in-place sort ranks
     # every key among its bucket's arrivals, in universe order.
     packed = np.empty(n, dtype=np.uint64)
@@ -165,7 +205,12 @@ def build_universe(native, cfg: dict, seed: int, slots: int) -> Universe:
         index = np.arange(lo, hi, dtype=np.uint64)
         c_ids = key_ids(index, seed)
         c_fp = fingerprints(native, c_ids)
-        c_gb = global_bucket(c_fp, slots, ways, shards).view(np.uint64)
+        c_owner = None
+        if ring is not None:
+            c_owner = owner[lo:hi] = ring.owner(c_fp.view(np.uint64))
+        c_gb = global_bucket(
+            c_fp, slots, ways, shards, c_owner, peers
+        ).view(np.uint64)
         ids[lo:hi] = c_ids
         fp[lo:hi] = c_fp
         algo[lo:hi] = key_algorithms(c_ids, cfg)
@@ -192,7 +237,7 @@ def build_universe(native, cfg: dict, seed: int, slots: int) -> Universe:
     way[order] = np.minimum(run_start, 127)
     del pos, run_start, is_start
     counts = np.minimum(
-        np.bincount(gbucket, minlength=nb_local * shards), 127
+        np.bincount(gbucket, minlength=nb_local * shards * peers), 127
     ).astype(np.int8)
     crowded = counts[gbucket] > ways
     # GLOBAL keys are created by traffic (the engine syncs them into the
@@ -204,30 +249,40 @@ def build_universe(native, cfg: dict, seed: int, slots: int) -> Universe:
         crowded=crowded, slot_order=order, limit=int(cfg["limit"]),
         global_limit=int(cfg.get("global_limit", 0)),
         duration_ms=int(cfg["duration_ms"]), slots=slots, ways=ways,
-        shards=shards, moving=clock_moves(cfg),
+        shards=shards, moving=clock_moves(cfg), ring=ring, owner=owner,
     )
 
 
-def handoff(u: Universe, seed: int, n_probe: int) -> Dict[str, np.ndarray]:
+def handoff(u: Universe, seed: int, n_probe: int,
+            daemon: int = 0) -> Dict[str, np.ndarray]:
     """What the daemon launcher needs to preload, so that it does not
     build the universe a second time: the resident rows in slot order, and
-    a seeded sample of keys with whether the table must find each."""
+    a seeded sample of keys with whether the table must find each.  In a
+    cluster, one file a daemon: the rows it owns, at its own slots, and the
+    WHOLE sample — a key another daemon owns must not be found here."""
     sel = u.slot_order[u.resident[u.slot_order]]
     rng = np.random.default_rng(derive_seed(seed, "probe"))
     idx = rng.choice(len(u.fp), size=min(n_probe, len(u.fp)), replace=False)
+    slot = u.gbucket[sel].astype(np.int64) * u.ways + u.way[sel]
+    found = u.resident[idx]
+    slots = u.slots // u.peers
+    if u.ring is not None:
+        mine = u.owner[sel] == daemon
+        sel, slot = sel[mine], slot[mine] - daemon * slots
+        found = found & (u.owner[idx] == daemon)
     # Rows whose window is a second are expired before they are probed:
     # the launcher then asks the lookup at the stamp they were made at.
     at_stamp = {"probe_at_preload_stamp": np.ones(1, bool)} if u.moving else {}
     return {
         **at_stamp,
-        "slot": u.gbucket[sel].astype(np.int64) * u.ways + u.way[sel],
+        "slot": slot,
         "fp": u.fp[sel],
         "algo": u.algo[sel],
         "remaining0": u.remaining0[sel],
         "probe_fp": u.fp[idx],
-        "probe_found": u.resident[idx],
+        "probe_found": found,
         "geometry": np.array(
-            [u.slots, u.limit, u.duration_ms], dtype=np.int64
+            [slots, u.limit, u.duration_ms], dtype=np.int64
         ),
     }
 
@@ -268,7 +323,9 @@ def expected_occupancy(u: Universe, touched_index: np.ndarray,
                        extra_fp: np.ndarray) -> int:
     """Rows the table holds once the keys at `touched_index` (and the
     fingerprints `extra_fp` of keys outside the universe) have been
-    served: a bucket keeps min(distinct arrivals, ways).  That holds where
+    served: a bucket keeps min(distinct arrivals, ways).  In a cluster the
+    sum over daemons, each bucket on its key's ring owner: a row written on
+    a daemon that does not own its key is a row beyond it.  That holds where
     windows elapse inside a run too: the table counts a row whether or not
     its window has elapsed (ops/state.py `occupancy`: key != 0), nothing
     clears one, a returning key takes its own expired row first, and an
@@ -281,7 +338,6 @@ def expected_occupancy(u: Universe, touched_index: np.ndarray,
     counts = np.bincount(u.gbucket[present], minlength=nb)
     if len(extra_fp):
         counts = counts + np.bincount(
-            global_bucket(np.unique(extra_fp), u.slots, u.ways, u.shards),
-            minlength=nb,
+            u.bucket_of(np.unique(extra_fp)), minlength=nb,
         )
     return int(np.minimum(counts, u.ways).sum())

@@ -16,6 +16,14 @@ stderr.  `attempted` and `failed` count checks.  --trace 0 reports the cell's
 end-to-end metrics, --trace 1 its per-layer metrics from a run that also
 traces a short span of the window with the profiler.
 
+A configuration that says `"peers": N` (bench/README.md) is N daemons, a
+chip each, on one consistent-hash ring: N bench/serve.py children, each
+with its own addresses, preload file and chip (pinned by its environment,
+bench/lib/cluster.py), the client's connections spread over them, every
+snapshot, `memory` and trace command sent to each — and ONE result line:
+counters summed, the fullest chip's memory, the mean chip's busy time, and
+a `daemons` block that says what each one held and did.
+
 This process never imports JAX (the daemon child holds the chip).  It
 fails, printing no result, when the daemon finds no TPU or fewer chips
 than the cell asks for.  `--platform cpu --slots 65536` is a dry run for
@@ -31,6 +39,7 @@ import argparse
 import json
 import os
 import queue
+import re
 import signal
 import socket
 import shutil
@@ -49,7 +58,7 @@ sys.path[:0] = [BENCH, REPO]
 
 import numpy as np  # noqa: E402
 
-from lib import oracle, readers, schedule, shapes, spec  # noqa: E402
+from lib import cluster, oracle, readers, schedule, shapes, spec  # noqa: E402
 from lib import universe as universe_mod  # noqa: E402
 from lib.percentile import beyond, percentile  # noqa: E402
 
@@ -61,6 +70,9 @@ READY_TIMEOUT_S = 1100.0
 TRACE_SPAN_S = 2.0
 TRACE_TAIL_S = 3.0        # the span starts this long before the window ends
 HOT_KEYS = 1000           # of a skewed traffic: always in the replayed sample
+# Checks a daemon served by where they went, label `calltype`: "local" where
+# it owns the key, "forward" where it sent the check to the owner.
+HOP_SERIES = "gubernator_getratelimit_counter_total"
 
 
 class Refused(Exception):
@@ -169,15 +181,27 @@ class Child:
 
 
 class Snapshot:
-    """/debug/vars and /metrics, and the compile cache's entries."""
+    """/debug/vars and /metrics, and the compile cache's entries.  Of a
+    cluster: `each` daemon's /debug/vars, `vars` their sum
+    (bench/lib/cluster.py `sum_vars`), `metrics` every daemon's series (a
+    reader sums the series that match) and `metrics_each` the same a
+    daemon."""
 
-    def __init__(self, http_addr: str) -> None:
+    def __init__(self, http_addrs) -> None:
         self.t = time.monotonic()
-        self.vars = json.loads(http(http_addr, "/debug/vars"))
-        self.metrics = readers.parse_prometheus(
-            http(http_addr, "/metrics").decode()
-        )
+        self.each = [json.loads(http(a, "/debug/vars")) for a in http_addrs]
+        self.vars = (self.each[0] if len(self.each) == 1
+                     else cluster.sum_vars(self.each))
+        self.metrics_each = [
+            readers.parse_prometheus(http(a, "/metrics").decode())
+            for a in http_addrs
+        ]
+        self.metrics = [row for m in self.metrics_each for row in m]
         self.cache = cache_entries()
+
+    def sum_each(self, path: str) -> int:
+        """A whole number of /debug/vars that every daemon has, summed."""
+        return sum(int(readers.lookup_vars(v, path)) for v in self.each)
 
 
 class Compare:
@@ -195,20 +219,97 @@ class Compare:
               f"{'ok' if good else 'FAILED'}", flush=True)
 
 
-def server_env(args, cfg: dict, grpc_addr: str, http_addr: str) -> dict:
+def server_env(args, cfg: dict, grpc_addr: str, http_addr: str,
+               daemon: int = 0) -> dict:
+    """The environment of daemon number `daemon`.  One of a cluster also
+    advertises the address it listens on (its name on the ring) and is
+    given chip `daemon` of the host and no other."""
     env = os.environ.copy()
     env.update(cfg["daemon"])
     env.update(
         GUBER_GRPC_ADDRESS=grpc_addr, GUBER_HTTP_ADDRESS=http_addr,
         GUBER_TPU_PLATFORM=args.platform,
     )
+    peers = spec.peers_of(cfg)
+    if peers > 1:
+        env["GUBER_ADVERTISE_ADDRESS"] = grpc_addr
+        if args.platform == "tpu":
+            env.update(cluster.pin_env(daemon, free_port()))
     if args.platform == "cpu":
         env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={cfg['chips']}"
+            + " --xla_force_host_platform_device_count="
+            + str(cfg["chips"] // peers)
         ).strip()
     return env
+
+
+def daemon_addresses(cfg: dict) -> list:
+    """The gRPC address of every daemon: a free port for one daemon; a
+    cluster's are its configuration's, since a peer's place on the ring
+    follows from its address — and a port of those that is taken is a
+    run that cannot be made."""
+    if spec.peers_of(cfg) == 1:
+        return [f"127.0.0.1:{free_port()}"]
+    addrs = spec.peer_addresses(cfg)
+    for a in addrs:
+        host, _, port = a.rpartition(":")
+        with socket.socket() as s:
+            # The last run's connections may linger in TIME_WAIT; only a
+            # listener takes the port.
+            s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+            try:
+                s.bind((host, int(port)))
+            except OSError as e:
+                raise Refused(f"the configuration's peer address {a} is "
+                              f"taken: {e}") from e
+    return addrs
+
+
+def check_chips(args, cell: dict, cfg: dict, readies: list) -> None:
+    """Refuses a run whose daemons do not hold what the cell asks for."""
+    for ready in readies:
+        dev = ready["device"]
+        if dev["platform"] != args.platform:
+            raise Refused(f"daemon runs on {dev['platform']!r}, "
+                          f"not {args.platform!r}")
+    if len(readies) == 1:
+        dev = readies[0]["device"]
+        if dev["device_count"] < cell["chips"] or len(
+            set(dev["table_device_ids"])
+        ) != int(cfg["universe"]["shards"]):
+            raise Refused(f"cell needs {cell['chips']} chips, daemon has "
+                          f"{dev}")
+        return
+    # A cluster: every daemon one table on one device; on the TPU a daemon
+    # sees its own chip alone, and no two the same one.
+    chips = [r["chip"] for r in readies]
+    if any(len(set(r["device"]["table_device_ids"])) != 1 for r in readies):
+        raise Refused(f"a daemon of a cluster holds one device: {chips}")
+    if args.platform == "tpu":
+        # A pinned process sees ONE device, numbered 0 whichever chip it
+        # is, so the chips are told apart by the pin and by the kernel's
+        # device file each process holds (/dev/vfio/<k> on a v5e;
+        # /dev/vfio/vfio is the container every process opens).
+        files = [set(c["dev_files"]) - {"/dev/vfio/vfio"} for c in chips]
+        shared = [a & b for i, a in enumerate(files) for b in files[:i]]
+        if (any(r["device"]["device_count"] != 1 for r in readies)
+                or any(c["pinned"] == "" for c in chips)
+                or len({c["pinned"] for c in chips}) != len(chips)
+                or any(shared)):
+            raise Refused("every daemon of a cluster has to hold one chip of "
+                          f"its own, pinned by its environment: {chips}")
+    if sum(r["device"]["device_count"] for r in readies) < cell["chips"]:
+        raise Refused(f"cell needs {cell['chips']} chips, the daemons hold "
+                      f"{chips}")
+
+
+def ask_all(servers: list, msgs: list, timeout: float) -> list:
+    """One command to every daemon, then every answer: they work at once."""
+    for sv, msg in zip(servers, msgs):
+        sv.send(msg)
+    return [sv.next_json(timeout) for sv in servers]
 
 
 def window_stats(traffic: dict, rec: dict, plan, tw0: float,
@@ -250,11 +351,11 @@ def window_stats(traffic: dict, rec: dict, plan, tw0: float,
     return out
 
 
-def reduce_trace(trace_dir: str) -> dict:
+def reduce_trace(trace_dirs: list) -> dict:
     env = os.environ.copy()
     env["JAX_PLATFORMS"] = "cpu"
     p = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "lib", "trace.py"), trace_dir],
+        [sys.executable, os.path.join(BENCH, "lib", "trace.py"), *trace_dirs],
         env=env, cwd=REPO, capture_output=True, text=True, timeout=300,
     )
     if p.returncode != 0:
@@ -289,12 +390,15 @@ def _run(args, tmp: str) -> dict:
         + (f"-r{args.rate:g}" if args.rate else ""),
     )
     os.makedirs(out_dir, exist_ok=True)
-    if args.slots or args.keys:
-        # The dry run's smaller deployment, as a file the children read.
+    if args.slots or args.keys or args.daemon:
+        # The dry run's smaller deployment, or a reading at another
+        # daemon setting, as a file the children read.
         if args.slots:
             cfg["daemon"]["GUBER_TPU_NUM_SLOTS"] = str(args.slots)
         if args.keys:
             cfg["universe"]["keys"] = args.keys
+        cfg["daemon"].update(kv.split("=", 1) for kv in args.daemon)
+        spec.check_config(cfg, "the configuration as overridden")
         cfg_path = os.path.join(out_dir, "config.dryrun.json")
         with open(cfg_path, "w") as f:
             json.dump(cfg, f)
@@ -317,67 +421,76 @@ def _run(args, tmp: str) -> dict:
         traffic, cfg["universe"], batch, smallest_tier=min(128, batch)
     )
 
-    grpc_addr = f"127.0.0.1:{free_port()}"
-    http_addr = f"127.0.0.1:{free_port()}"
+    ring = spec.ring_of(cfg)
+    peers = spec.peers_of(cfg)
+    # One daemon is "server", with serve.log and preload.npz, as before
+    # there could be several; daemon k of a cluster carries its number.
+    tags = [""] if peers == 1 else [f".{k}" for k in range(peers)]
+    grpc_addrs = daemon_addresses(cfg)
+    grpc_addr = grpc_addrs[0]       # the wire check's entry daemon
+    http_addrs = [f"127.0.0.1:{free_port()}" for _ in grpc_addrs]
     cache_at_start = len(cache_entries())
-    server = Child(
-        "server",
-        [sys.executable, os.path.join(BENCH, "serve.py"),
-         "--preload", os.path.join(tmp, "preload.npz"),
-         "--lanes", json.dumps(lanes)]
-        + (["--control", args.control] if args.control else []),
-        server_env(args, cfg, grpc_addr, http_addr),
-        os.path.join(out_dir, "serve.log"),
-    )
+    servers: list = []
     client = None
     try:
+        for k, tag in enumerate(tags):
+            servers.append(Child(
+                "server" + tag.replace(".", ""),
+                [sys.executable, os.path.join(BENCH, "serve.py"),
+                 "--preload", os.path.join(tmp, f"preload{tag}.npz"),
+                 "--lanes", json.dumps(lanes)]
+                + (["--control", args.control] if args.control else []),
+                server_env(args, cfg, grpc_addrs[k], http_addrs[k], k),
+                os.path.join(out_dir, f"serve{tag}.log"),
+            ))
         rec_path = os.path.join(tmp, "client.npz")
         client_env = os.environ.copy()
         client_env["JAX_PLATFORMS"] = "cpu"   # it never imports JAX anyway
         client = Child(
             "client",
             [sys.executable, os.path.join(BENCH, "client.py"),
-             "--addr", grpc_addr, "--config", cfg_path,
+             "--addr", ",".join(grpc_addrs), "--config", cfg_path,
              "--traffic", traffic_path, "--seed", str(args.seed),
              "--warm-in", str(warm_in), "--seconds", str(args.seconds),
              "--out", rec_path],
             client_env, os.path.join(out_dir, "client.log"),
         )
         uni = universe_mod.build_universe(
-            native, cfg["universe"], args.seed, slots
+            native, cfg["universe"], args.seed, slots, ring
         )
-        np.savez(os.path.join(tmp, "handoff.npz"),
-                 **universe_mod.handoff(uni, args.seed, 262144))
-        os.rename(os.path.join(tmp, "handoff.npz"),
-                  os.path.join(tmp, "preload.npz"))
+        for k, tag in enumerate(tags):
+            np.savez(os.path.join(tmp, "handoff.npz"),
+                     **universe_mod.handoff(uni, args.seed, 262144, k))
+            os.rename(os.path.join(tmp, "handoff.npz"),
+                      os.path.join(tmp, f"preload{tag}.npz"))
         plan = schedule.build_plan(
             traffic, cfg["universe"], args.seed, warm_in + args.seconds
         )
         log(f"universe: {len(uni.fp)} keys, {uni.n_resident} resident, "
             f"{int(uni.crowded.sum())} in crowded buckets, "
             f"{int(uni.is_global.sum())} GLOBAL; plan {len(plan)} RPCs, "
-            f"{int(plan.offsets[-1])} checks")
+            f"{int(plan.offsets[-1])} checks"
+            + (f"; resident a daemon {uni.resident_by_daemon().tolist()}"
+               if peers > 1 else ""))
 
-        ready = server.next_json(READY_TIMEOUT_S)
-        dev = ready["device"]
-        log(f"daemon ready: start {ready['daemon_start_s']}s (warm-up "
-            f"{dev['warmup_s']}s), device {dev}; preload "
-            f"{ready.get('preload')}; fetch shapes {ready['fetch_shapes']}; "
-            f"lanes {lanes}; compile cache {cache_at_start} -> "
-            f"{len(cache_entries())} entries")
-        if dev["platform"] != args.platform:
-            raise Refused(f"daemon runs on {dev['platform']!r}, "
-                          f"not {args.platform!r}")
-        if dev["device_count"] < cell["chips"] or len(
-            set(dev["table_device_ids"])
-        ) != int(cfg["universe"]["shards"]):
-            raise Refused(f"cell needs {cell['chips']} chips, daemon has "
-                          f"{dev}")
+        readies = [sv.next_json(READY_TIMEOUT_S) for sv in servers]
+        for tag, ready in zip(tags, readies):
+            dev = ready["device"]
+            log(f"daemon{tag} ready: start {ready['daemon_start_s']}s "
+                f"(warm-up {dev['warmup_s']}s), device {dev}, chip "
+                f"{ready.get('chip')}; preload {ready.get('preload')}; "
+                f"fetch shapes {ready['fetch_shapes']}; "
+                f"lanes {lanes}; compile cache {cache_at_start} -> "
+                f"{len(cache_entries())} entries")
+        check_chips(args, cell, cfg, readies)
         compare = Compare()
-        pre = ready["preload"]
-        compare("preload_occupancy_differs",
-                abs(pre["occupancy"] - uni.n_resident))
-        compare("probe_differs_from_placement", pre["probe_differs"])
+        # Every daemon against its own share of the placement.
+        compare("preload_occupancy_differs", sum(
+            abs(r["preload"]["occupancy"] - int(n))
+            for r, n in zip(readies, uni.resident_by_daemon())
+        ))
+        compare("probe_differs_from_placement",
+                sum(r["preload"]["probe_differs"] for r in readies))
 
         from lib import wirecheck
 
@@ -385,15 +498,15 @@ def _run(args, tmp: str) -> dict:
         aux_fp = wire.resident_hashes().view(np.int64)
         aux_seen = wire.seen_hashes().view(np.int64)
         # Buckets the wire check touched: a row of theirs may be evicted.
-        aux_buckets = np.unique(universe_mod.global_bucket(
-            aux_seen, uni.slots, uni.ways, uni.shards
-        ))
+        aux_buckets = np.unique(uni.bucket_of(aux_seen))
         log(f"wire check: {wire.checked} answers against the reference"
             + (f"; first mismatch {wire.first}" if wire.first else ""))
         compare("wire_check_mismatches", wire.mismatches)
-        snap_ready = Snapshot(http_addr)
-        compare("compiled_lane_missing",
-                int(snap_ready.vars["device"].get("compiled_lane") is not True))
+        snap_ready = Snapshot(http_addrs)
+        compare("compiled_lane_missing", sum(
+            int(v["device"].get("compiled_lane") is not True)
+            for v in snap_ready.each
+        ))
 
         planned = client.next_json(600)
         if planned.get("digest") != plan.digest():
@@ -402,27 +515,28 @@ def _run(args, tmp: str) -> dict:
         mark = client.next_json(warm_in + 60)
         assert mark["mark"] == "window_start", mark
         setup_s = time.monotonic() - T_PROC
-        snap0 = Snapshot(http_addr)
-        trace_dir = os.path.join(tmp, "trace")
+        snap0 = Snapshot(http_addrs)
+        trace_dirs = [os.path.join(tmp, "trace" + tag) for tag in tags]
         tsnaps = None
         layer_end = None
         if args.trace and args.seconds >= 2.0:
             offset = max(1.0, args.seconds - TRACE_TAIL_S)
             span = min(TRACE_SPAN_S, args.seconds - offset - 0.5)
             time.sleep(max(0.0, snap0.t + offset - time.monotonic()))
-            snap_pre = Snapshot(http_addr)
+            snap_pre = Snapshot(http_addrs)
             layer_end = snap_pre.t
-            server.send({"cmd": "trace_start", "dir": trace_dir})
-            server.next_json(120)
-            ta = Snapshot(http_addr)
+            # Every daemon at once: a profiler takes up to a second to
+            # start, and the daemons' spans should cover the same time.
+            ask_all(servers, [{"cmd": "trace_start", "dir": d}
+                              for d in trace_dirs], 120)
+            ta = Snapshot(http_addrs)
             time.sleep(max(0.1, span))
-            tb = Snapshot(http_addr)
-            server.send({"cmd": "trace_stop"})
-            server.next_json(300)
+            tb = Snapshot(http_addrs)
+            ask_all(servers, [{"cmd": "trace_stop"}] * peers, 300)
             tsnaps = (ta, tb)
         mark = client.next_json(args.seconds + 360)
         assert mark["mark"] == "window_end", mark
-        snap1 = Snapshot(http_addr)
+        snap1 = Snapshot(http_addrs)
         log("window closed")
         saved = client.next_json(traffic["deadline_s"] + 300)
         assert saved["mark"] == "saved", saved
@@ -459,14 +573,16 @@ def _run(args, tmp: str) -> dict:
                 f"{run_stats.get('global_visible_ms', float('nan')):.0f} ms "
                 f"({gb['polls']} polls, {gb['differs']} keys differ at the "
                 f"last)")
-        server.send({"cmd": "memory"})
-        memory = server.next_json(60)
-        snap_end = Snapshot(http_addr)
-        server.send({"cmd": "quit"})
-        server.stop()
-        server = None
+        memories = ask_all(servers, [{"cmd": "memory"}] * peers, 60)
+        # The fullest chip's.
+        memory = max(memories, key=lambda m: m["memory_peak_bytes"])
+        snap_end = Snapshot(http_addrs)
+        for sv in servers:
+            sv.send({"cmd": "quit"})
+        while servers:
+            servers.pop().stop()
     finally:
-        for ch in (client, server):
+        for ch in [client] + servers:
             if ch is not None:
                 ch.stop(grace_s=0)
 
@@ -497,7 +613,8 @@ def _run(args, tmp: str) -> dict:
         else (oracle.replay_sample, oracle.FROZEN_COUNTS)
     )
     replay(
-        answers, rec, uni, ready["preload"]["t0_ms"], args.seed, aside,
+        answers, rec, uni, [r["preload"]["t0_ms"] for r in readies],
+        args.seed, aside,
         aux_buckets, verdict,
         always=schedule.hottest_keys(plan, HOT_KEYS) if skewed else None,
     )
@@ -530,10 +647,13 @@ def _run(args, tmp: str) -> dict:
         f"{[n[:40] for n in new[:8]]}; before the window, since the daemon "
         f"was ready: {len(snap0.cache - snap_ready.cache)}")
     compare("compiled_in_window", len(new))
-    fp0, fp1 = snap_ready.vars["fastpath"], snap_end.vars["fastpath"]
-    compare("fastpath_fallbacks_grown", fp1["fallbacks"] - fp0["fallbacks"])
-    compare("serve_mode_degraded",
-            int(fp1["effective_serve_mode"] != fp1["serve_mode"]))
+    compare("fastpath_fallbacks_grown",
+            snap_end.sum_each("fastpath.fallbacks")
+            - snap_ready.sum_each("fastpath.fallbacks"))
+    compare("serve_mode_degraded", sum(
+        int(v["fastpath"]["effective_serve_mode"] != v["fastpath"]["serve_mode"])
+        for v in snap_end.each
+    ))
     plain = ~uni.is_global[answers.key]
     lo_keys = np.unique(answers.key[plain])
     hi_keys = np.unique(np.concatenate([answers.key, aside]))
@@ -548,8 +668,53 @@ def _run(args, tmp: str) -> dict:
         f"persisted)")
     compare("occupancy_beyond_expected", max(0, occ - hi))
     compare("occupancy_below_expected", max(0, lo - occ))
+    daemons = None
+    if peers > 1:
+        # The forward hop, counted twice: by the daemons (their
+        # gubernator_getratelimit_counter series, by calltype, since the wire
+        # check) and from the plan (of every RPC sent, the checks whose
+        # ring owner is not the daemon its connection entered by).  A
+        # cluster that served everything where it arrived cannot pass.
+        hops = cluster.planned_hops(
+            plan, uni.owner, peers, rec["plan_idx"], rec["conn"] % peers
+        )
+        counted = {
+            kind: [
+                int(readers.window_delta([{
+                    "metrics": HOP_SERIES,
+                    "labels": {"calltype": kind},
+                }], [{"metrics": a}, {"metrics": b}], absent=0.0))
+                for a, b in zip(snap_ready.metrics_each,
+                                snap_end.metrics_each)
+            ] for kind in ("forward", "local")
+        }
+        log(f"forward hop: the daemons counted {counted}, the plan says "
+            f"{hops}")
+        compare("forwarded_checks_differ", sum(
+            abs(c - h) for c, h in zip(counted["forward"], hops["forward"])))
+        compare("local_checks_differ", sum(
+            abs(c - h) for c, h in zip(counted["local"], hops["local"])))
+        daemons = [
+            {
+                "grpc": grpc_addrs[k], "chip": readies[k].get("chip"),
+                "device": {x: readies[k]["device"][x] for x in (
+                    "platform", "device_kind", "device_count",
+                    "table_device_ids")},
+                "daemon_start_s": readies[k]["daemon_start_s"],
+                "memory_peak_bytes": memories[k]["memory_peak_bytes"],
+                "preloaded": readies[k]["preload"]["occupancy"],
+                "occupancy": snap_end.each[k]["backend"]["occupancy"],
+                "served": snap_end.each[k]["fastpath"]["served"]
+                - snap_ready.each[k]["fastpath"]["served"],
+                "forward": counted["forward"][k],
+                "local": counted["local"][k],
+            } for k in range(peers)
+        ]
     if args.platform != "tpu":
         compare("not_a_tpu_run", 1)
+    if args.daemon:
+        # A reading at another setting is not the configuration's.
+        compare("daemon_setting_overridden", len(args.daemon))
     if args.rate:
         compare("not_the_cells_rate", 1)
     if args.held_out:
@@ -558,7 +723,8 @@ def _run(args, tmp: str) -> dict:
     # -- metrics ----------------------------------------------------------
     device = {
         "platform": dev["platform"], "kind": dev["device_kind"],
-        "count": dev["device_count"],
+        # A daemon of a cluster sees the one chip it holds.
+        "count": sum(r["device"]["device_count"] for r in readies),
         "memory_peak_bytes": memory["memory_peak_bytes"],
     }
     result = {
@@ -575,6 +741,9 @@ def _run(args, tmp: str) -> dict:
     if uni.moving:
         # What the moving-clock replay saw (the driver ignores it).
         result["replay"] = {k: verdict.notes[k] for k in oracle.MOVING_SEEN}
+    if daemons:
+        # What each daemon of the cluster held and did (the same).
+        result["daemons"] = daemons
     e2e = dict(stats, setup_s=setup_s)
     if not args.trace:
         for m in spec.metrics_of(bm, "end_to_end", args.workload):
@@ -583,7 +752,7 @@ def _run(args, tmp: str) -> dict:
             }
         result["compared"] = compare.seen
         return result
-    trace = reduce_trace(trace_dir) if tsnaps else {}
+    trace = reduce_trace(trace_dirs) if tsnaps else {}
     # Everything not read from the trace: the window up to the profiler's
     # start.
     if layer_end is not None:
@@ -608,7 +777,13 @@ def _run(args, tmp: str) -> dict:
             "device_ops": trace["device_ops"],
             "idle_gaps": trace["idle_gaps"],
         }
-        log(f"trace: {trace['chips_traced']} chips, busy {trace['busy_s']:.3f}"
+        if daemons:
+            for d, busy, window in zip(daemons, trace["busy_s_by_chip"],
+                                       trace["window_s_by_chip"]):
+                d.update(busy_s=busy, window_s=window)
+        log(f"trace: {trace.get('daemons_traced', 1)} daemons, "
+            f"{trace['chips_traced']} chips a program, busy "
+            f"{trace['busy_s']:.3f}"
             f"s of {trace['window_s']:.3f}s; programs "
             f"{ {k: v[0] for k, v in trace['modules'].items()} }")
     ctx = {
@@ -649,14 +824,21 @@ def main() -> int:
     ap.add_argument("--held-out", action="store_true",
                     help="also know the cells of bench/held_out.json; the "
                     "result says correct false")
+    ap.add_argument("--daemon", action="append", default=[],
+                    metavar="GUBER_X=value",
+                    help="a daemon setting other than the configuration's: "
+                    "a second reading, whose result says correct false")
     ap.add_argument("--control", default="",
                     help="break the daemon on purpose: f32 (the lower-"
-                    "precision control), alter; oneclock is a witness "
+                    "precision control), alter, noforward (a cluster whose "
+                    "daemons serve all where it arrives); oneclock is a witness "
                     "(bench/witness/oneclock.py)")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
     if args.platform == "tpu" and (args.slots or args.keys):
         ap.error("--slots and --keys are for the cpu dry run")
+    if not all(re.match(r"^GUBER_[A-Z0-9_]+=", kv) for kv in args.daemon):
+        ap.error("--daemon takes GUBER_X=value")
     try:
         result = run(args)
     except (Refused, spec.SpecError) as e:

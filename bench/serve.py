@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The daemon under test, started the way `python -m gubernator_tpu.cli.server`
 starts it (GUBER_* environment -> setup_daemon_config -> Daemon.start), plus
-the three things a benchmark run needs from inside the process that holds
+the four things a benchmark run needs from inside the process that holds
 the chip:
 
   preload   the configuration's resident rows, made from the seed by the
@@ -14,6 +14,9 @@ the chip:
             can ask for (`fetch_ravel` concatenates one program per
             sequence of round shapes), so that none compiles on the
             request path;
+  chip      the ready report says which chip the process holds (what its
+            environment pinned, the devices' ids and coordinates), so that
+            the harness can refuse a cluster whose daemons share one;
   commands  one JSON object per line on stdin: {"cmd": "trace_start",
             "dir": ...}, {"cmd": "trace_stop"}, {"cmd": "memory"},
             {"cmd": "quit"}; one JSON answer per line on stdout.  The first
@@ -24,8 +27,9 @@ Serving itself is untouched: gRPC handlers, compiled lane, default
 that the correctness check fails when it should (never used by a benchmark
 run): `f32` is the lower-precision control, the leaky bucket's float64
 operands rounded to float32 before use; `alter` changes one answer in 97
-where the fetched response is unpacked; `oneclock` is no control but a
-witness (bench/witness/oneclock.py).
+where the fetched response is unpacked; `noforward` gives a daemon of a
+cluster a ring of itself alone, so that it serves every check where it
+arrives; `oneclock` is no control but a witness (bench/witness/oneclock.py).
 """
 from __future__ import annotations
 
@@ -78,6 +82,10 @@ def apply_control(kind: str) -> None:
             return out
 
         backend._packed_resp_dict = altered
+    elif kind == "noforward":
+        # A cluster that serves every check where it arrives: this daemon's
+        # ring holds itself alone, so nothing is forwarded to an owner.
+        os.environ["GUBER_PEERS"] = os.environ["GUBER_ADVERTISE_ADDRESS"]
     elif kind == "oneclock":
         # No control but a witness, kept apart from this launcher.
         from witness import oneclock
@@ -210,6 +218,30 @@ def warm_fetch_shapes(service, lanes: dict) -> dict:
     return out
 
 
+def chip_report(service) -> dict:
+    """Which chip this process holds, for a harness that has to tell the
+    daemons of a cluster apart: what its environment pinned
+    (TPU_VISIBLE_CHIPS; "" where nothing did), and what JAX and the
+    kernel say of the table's devices."""
+    import jax
+
+    ids = set(service.backend.device_info()["table_device_ids"])
+    devs = [d for d in jax.devices() if d.id in ids]
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            held.add(os.readlink(f"/proc/self/fd/{fd}"))
+        except OSError:
+            pass
+    return {
+        "pinned": os.environ.get("TPU_VISIBLE_CHIPS", ""),
+        "ids": [int(d.id) for d in devs],
+        "coords": [list(getattr(d, "coords", ())) for d in devs],
+        "dev_files": sorted(h for h in held if h.startswith("/dev/")
+                            and ("accel" in h or "vfio" in h)),
+    }
+
+
 def memory_stats(service) -> dict:
     import jax
 
@@ -242,6 +274,7 @@ async def run(args) -> None:
         "device": daemon.service.backend.device_info(),
     }
     report["device"]["warmup_s"] = round(daemon._warmup_s, 3)
+    report["chip"] = chip_report(daemon.service)
     if args.preload:
         report["preload"] = await loop.run_in_executor(
             None, preload, daemon.service, args.preload
