@@ -24,7 +24,12 @@ from gubernator_tpu.core.config import Config, DaemonConfig
 from gubernator_tpu.core.types import PeerInfo
 from gubernator_tpu.net import grpc_api
 from gubernator_tpu.net.netutil import resolve_host_ip
-from gubernator_tpu.net.peer_client import PRESSURE_METADATA_KEY
+from gubernator_tpu.net.forward_once import ForwardOnce
+from gubernator_tpu.net.peer_client import (
+    FORWARD_ID_KEY,
+    FORWARD_ONCE_KEY,
+    PRESSURE_METADATA_KEY,
+)
 from gubernator_tpu.net.tls import TLSBundle, setup_tls
 from gubernator_tpu.proto import gubernator_pb2 as pb
 from gubernator_tpu.proto import peers_pb2
@@ -106,10 +111,14 @@ class _StatsInterceptor(grpc.aio.ServerInterceptor):
             fr = m.flightrec
             if fr is not None and fr.pressure_active():
                 try:
-                    context.set_trailing_metadata((
-                        (PRESSURE_METADATA_KEY,
-                         "%.3f" % max(fr.pressure_ratio(), 1.0)),
-                    ))
+                    # (Added to what the handler set: the forward-once
+                    # echo of GetPeerRateLimits.)
+                    context.set_trailing_metadata(
+                        tuple(context.trailing_metadata() or ()) + (
+                            (PRESSURE_METADATA_KEY,
+                             "%.3f" % max(fr.pressure_ratio(), 1.0)),
+                        )
+                    )
                 except Exception:  # noqa: BLE001 — advisory only
                     pass
             return out
@@ -186,7 +195,14 @@ class _V1Servicer:
         try:
             fp = self.d.fastpath
             if fp is not None:
-                out = await fp.check_raw(payload, peer_rpc=False)
+                # The client's own deadline bounds the re-asks of a
+                # forward that times out (docs/cluster.md).
+                left = context.time_remaining()
+                out = await fp.check_raw(
+                    payload, peer_rpc=False,
+                    deadline=None if left is None
+                    else time.monotonic() + left,
+                )
                 if out is not None:
                     return out
             try:
@@ -226,25 +242,44 @@ class _PeersServicer:
         )
 
     async def _get_peer_rate_limits(self, payload: bytes, context):
+        # A forward that names itself is applied once however often it
+        # arrives (net/forward_once.py); one that does not (an upstream
+        # peer's, the object path's batches) is applied as it comes.
+        fid = None
+        for key, value in context.invocation_metadata() or ():
+            if key == FORWARD_ID_KEY:
+                fid = value
+                break
         try:
-            fp = self.d.fastpath
-            if fp is not None:
-                out = await fp.check_raw(payload, peer_rpc=True)
-                if out is not None:
-                    return out
-            try:
-                request = peers_pb2.GetPeerRateLimitsReq.FromString(payload)
-            except Exception as e:  # noqa: BLE001
-                await context.abort(
-                    grpc.StatusCode.INVALID_ARGUMENT,
-                    f"failed to parse GetPeerRateLimitsReq: {e}",
+            if fid is None:
+                out = await self._apply_forward(payload)
+            else:
+                out = await self.d.forwards.apply(
+                    fid, lambda: self._apply_forward(payload)
                 )
-            reqs = grpc_api.reqs_from_pb(request.requests)
-            resps = await self.d.service.get_peer_rate_limits(reqs)
         except ApiError as e:
             await context.abort(
                 _GRPC_CODES.get(e.code, grpc.StatusCode.INTERNAL), str(e)
             )
+        # What lets the caller ask a timed-out forward again.
+        context.set_trailing_metadata(((FORWARD_ONCE_KEY, "1"),))
+        return out
+
+    async def _apply_forward(self, payload: bytes) -> bytes:
+        fp = self.d.fastpath
+        if fp is not None:
+            out = await fp.check_raw(payload, peer_rpc=True)
+            if out is not None:
+                return out
+        try:
+            request = peers_pb2.GetPeerRateLimitsReq.FromString(payload)
+        except Exception as e:  # noqa: BLE001 — DecodeError etc.
+            raise ApiError(
+                "INVALID_ARGUMENT",
+                f"failed to parse GetPeerRateLimitsReq: {e}",
+            ) from e
+        reqs = grpc_api.reqs_from_pb(request.requests)
+        resps = await self.d.service.get_peer_rate_limits(reqs)
         return peers_pb2.GetPeerRateLimitsResp(
             rate_limits=grpc_api.resps_to_pb(resps)
         ).SerializeToString()
@@ -371,6 +406,11 @@ class Daemon:
         self.service: Optional[Service] = None
         self._warmup_s = 0.0
         self.fastpath = None
+        # Forwards this daemon owns, by the id their entry daemon gave
+        # them: applied once, re-asks joined (net/forward_once.py).
+        self.forwards = ForwardOnce(
+            self.conf.behaviors.batch_timeout_s, self.metrics.stages
+        )
         # Gubstat census sampler (runtime/gubstat.py): armed in start()
         # per GUBER_STATS_ENABLED, closed before the fastpath.
         self.stats_sampler = None

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import logging
 import time
 from typing import Deque, List, Optional, Tuple
 
@@ -39,6 +40,8 @@ from gubernator_tpu.net.breaker import CircuitBreaker, CircuitState
 from gubernator_tpu.proto import peers_pb2
 from gubernator_tpu.runtime import tracing
 
+log = logging.getLogger(__name__)
+
 ERROR_WINDOW_S = 300.0  # keep peer errors 5 min (peer_client.go:282)
 
 # Trailing-metadata key a pressured daemon stamps on its RPC responses
@@ -48,6 +51,20 @@ ERROR_WINDOW_S = 300.0  # keep peer errors 5 min (peer_client.go:282)
 # owner — answering RPCs, clean error window, breaker closed — is
 # otherwise indistinguishable from a healthy one.
 PRESSURE_METADATA_KEY = "x-guber-pressure"
+
+# A forward's identity (docs/cluster.md).  The entry daemon sends
+# FORWARD_ID_KEY with every raw GetPeerRateLimits — a few bytes, its
+# instance and a counter — and an owner that keeps id -> answer
+# (net/forward_once.py) says so by FORWARD_ONCE_KEY in the trailing
+# metadata.  Only to such an owner is a timed-out forward asked again:
+# it applies an id once, so the re-ask can spend nothing twice.  A peer
+# that knows neither key (upstream's) ignores the one and never sends
+# the other.
+FORWARD_ID_KEY = "x-guber-forward-id"
+FORWARD_ONCE_KEY = "x-guber-forward-once"
+# Asks of one forward whose client set no deadline; with one, the
+# client's deadline bounds them.
+FORWARD_TRIES = 4
 
 
 class PeerNotReadyError(RuntimeError):
@@ -123,6 +140,7 @@ class PeerClient:
     ) -> None:
         self.peer_info = info
         self.metrics = metrics
+        self._stages = tracing.ledger_of(metrics)
         self.behavior = behavior or BehaviorConfig()
         # Per-peer circuit breaker (net/breaker.py): fed by the same
         # failures as the health window, gates every RPC path.  A None
@@ -170,6 +188,9 @@ class PeerClient:
         # no further RPC flows.
         self._pressure_ttl_s = pressure_ttl_s
         self._pressure = (0.0, 0.0)
+        # Has this peer ever answered FORWARD_ONCE_KEY?  Sticky: the
+        # capability is the peer's program, not its load.
+        self._applies_once = False
         # Structural unsent-classification state: has this channel EVER
         # reached READY?  Set by the `_ensure_ready` pre-dial gate (and
         # by any RPC completing).  While False, NO RPC has ever been
@@ -233,18 +254,18 @@ class PeerClient:
         the gate that activates hot-key mirroring toward this owner."""
         return self.pressure_ratio() >= 1.0
 
-    def _note_pressure_md(self, md) -> None:
-        """Scan RPC trailing metadata for the pressure advertisement
-        (cheap: absent on healthy peers, one small pair otherwise)."""
-        if not md:
-            return
-        for key, value in md:
+    def _note_trailing_md(self, md) -> None:
+        """Scan an answer's trailing metadata: the pressure
+        advertisement (absent on healthy peers) and the forward-once
+        capability (one small pair an answered forward)."""
+        for key, value in md or ():
             if key == PRESSURE_METADATA_KEY:
                 try:
                     self.note_pressure(float(value))
                 except (TypeError, ValueError):
                     pass
-                return
+            elif key == FORWARD_ONCE_KEY:
+                self._applies_once = True
 
     def _on_circuit_transition(
         self, old: CircuitState, new: CircuitState
@@ -427,12 +448,22 @@ class PeerClient:
         finally:
             self._track_inflight(-1)
 
-    async def get_peer_rate_limits_raw(self, payload: bytes) -> bytes:
+    async def get_peer_rate_limits_raw(
+        self, payload: bytes, forward_id: Optional[str] = None,
+        deadline: Optional[float] = None,
+    ) -> bytes:
         """One pre-encoded GetPeerRateLimitsReq as a raw-bytes RPC — the
         compiled router's zero-copy forward.  Same shutdown/error
-        accounting as the batch path; retry-safety stays with the caller
-        (the router falls back to the object path's ownership-retry loop
-        per request on failure)."""
+        accounting as the batch path.
+
+        `forward_id` rides the call's metadata (FORWARD_ID_KEY).  An ask
+        that ends DEADLINE_EXCEEDED is made again under the same id while
+        the peer has said it applies an id once and `deadline`
+        (time.monotonic(): the client's own) leaves time — FORWARD_TRIES
+        asks where the client set none.  Every other failure, and the
+        last timeout, is the caller's: the router falls back to the
+        object path's ownership-retry loop per request, or answers with
+        the error."""
         if self._shutdown:
             raise PeerNotReadyError(
                 f"peer {self.peer_info.grpc_address} is shut down"
@@ -448,27 +479,65 @@ class PeerClient:
             # forward (readiness gate included) and its context rides
             # the RPC as w3c `traceparent` metadata, so the owner
             # daemon's server span joins this trace (docs/tracing.md).
+            # The stage ledger's peer.forward row times the same stretch
+            # (no span of its own: the OTel span above is its view).
             with tracing.span(
                 "peer.forward", require_parent=True,
                 peer=self.peer_info.grpc_address,
                 method="GetPeerRateLimits",
             ):
+                hop = self._stages.begin("peer.forward", "peer")
                 try:
                     budget = await self._ensure_ready()
-                    if self.chaos is not None:
-                        await self.chaos.on_client(
-                            self.peer_info.grpc_address,
-                            "GetPeerRateLimits",
-                        )
-                    call = self._raw_get_peer_rate_limits(
-                        payload, timeout=budget,
-                        metadata=tracing.grpc_metadata(),
-                    )
-                    out = await call
-                    self._note_pressure_md(await call.trailing_metadata())
+                    md = tracing.grpc_metadata() or ()
+                    if forward_id is not None:
+                        md += ((FORWARD_ID_KEY, forward_id),)
+                    asks = 0
+                    while True:
+                        asks += 1
+                        try:
+                            if self.chaos is not None:
+                                await self.chaos.on_client(
+                                    self.peer_info.grpc_address,
+                                    "GetPeerRateLimits",
+                                )
+                            call = self._raw_get_peer_rate_limits(
+                                payload, timeout=budget,
+                                metadata=md or None,
+                            )
+                            out = await call
+                            break
+                        except grpc.aio.AioRpcError as e:
+                            if e.code() != grpc.StatusCode.DEADLINE_EXCEEDED:
+                                raise
+                            self._stages.tally(
+                                "peer", "peer.forward", timeouts=1
+                            )
+                            budget = self._reask_budget(
+                                forward_id, deadline, asks
+                            )
+                            log.warning(
+                                "forward %s to %s: ask %d ended "
+                                "DEADLINE_EXCEEDED; %s", forward_id,
+                                self.peer_info.grpc_address, asks,
+                                "not asked again" if budget is None
+                                else "asked again, %.0f ms" % (budget * 1e3),
+                            )
+                            if budget is None:
+                                raise
+                            # Not a peer error yet: the health window and
+                            # the breaker see the forward's outcome, or
+                            # sixteen forwards held up by one stall would
+                            # open the breaker on an owner that answers.
+                            self._stages.tally(
+                                "peer", "peer.forward", reasked=1
+                            )
+                    self._note_trailing_md(await call.trailing_metadata())
                 except asyncio.CancelledError:
                     self._record_cancelled("GetPeerRateLimits[raw]")
                     raise
+                finally:
+                    hop.end()
             self._record_success()
             return out
         except grpc.aio.AioRpcError as e:
@@ -476,6 +545,25 @@ class PeerClient:
             raise
         finally:
             self._track_inflight(-1)
+
+    def _reask_budget(
+        self, forward_id: Optional[str], deadline: Optional[float],
+        asks: int,
+    ) -> Optional[float]:
+        """Seconds the next ask of a timed-out forward may take, or None
+        where it may not be asked again: no id, a peer that never said
+        it applies an id once (the re-ask could spend the hits twice),
+        the client's deadline gone, or the tries spent."""
+        if forward_id is None or not self._applies_once or self._shutdown:
+            return None
+        if deadline is None:
+            if asks >= FORWARD_TRIES:
+                return None
+            return self.behavior.batch_timeout_s
+        left = deadline - time.monotonic()
+        if left <= 0.005:
+            return None
+        return min(self.behavior.batch_timeout_s, left)
 
     async def update_peer_globals(
         self, globals_: List[UpdatePeerGlobal]
@@ -561,7 +649,7 @@ class PeerClient:
                         metadata=tracing.grpc_metadata(),
                     )
                     resp = await call
-                    self._note_pressure_md(await call.trailing_metadata())
+                    self._note_trailing_md(await call.trailing_metadata())
                 except asyncio.CancelledError:
                     self._record_cancelled("Lease")
                     raise
@@ -614,7 +702,7 @@ class PeerClient:
                         metadata=tracing.grpc_metadata(),
                     )
                     resp = await call
-                    self._note_pressure_md(await call.trailing_metadata())
+                    self._note_trailing_md(await call.trailing_metadata())
                 except asyncio.CancelledError:
                     self._record_cancelled("Reconcile")
                     raise
@@ -924,7 +1012,7 @@ class PeerClient:
                     metadata=tracing.grpc_metadata(),
                 )
                 pb_resp = await call
-                self._note_pressure_md(await call.trailing_metadata())
+                self._note_trailing_md(await call.trailing_metadata())
             except asyncio.CancelledError:
                 self._record_cancelled("GetPeerRateLimits")
                 raise

@@ -1192,34 +1192,18 @@ def resp_rounds_to_host(round_resps) -> List[Dict[str, np.ndarray]]:
 
 
 def fetch_ravel(arrs) -> List[np.ndarray]:
-    """ONE device->host round-trip for many same-dtype device arrays: ravel-
-    concat on device, single transfer, split + reshape on host — a
-    merge's N response buffers pay one fetch, not N."""
+    """Many device arrays to the host behind ONE wait: every copy is
+    started before the first is read, so a merge's N response buffers
+    travel together.  No program runs, on purpose: a concatenate on the
+    device is one XLA program per SEQUENCE of shapes, compiled on the
+    request path, inside the drain, the first time a drain has more
+    rounds than any before it (PERF.md section 6, PR 39)."""
     if not arrs:
         return []
     with tracing.stage("backend.d2h_wait"):
-        return _fetch_ravel(arrs)
-
-
-def _fetch_ravel(arrs) -> List[np.ndarray]:
-    if len(arrs) == 1:
-        return [np.asarray(arrs[0])]
-    # Mixed dtypes would silently promote under concatenate and come back
-    # cast; callers must pack per-dtype groups separately.
-    assert all(a.dtype == arrs[0].dtype for a in arrs), (
-        [a.dtype for a in arrs]
-    )
-    import jax.numpy as jnp
-
-    flat = jnp.concatenate([a.ravel() for a in arrs])
-    host = np.asarray(flat)
-    out = []
-    off = 0
-    for a in arrs:
-        n = int(np.prod(a.shape))
-        out.append(host[off:off + n].reshape(a.shape))
-        off += n
-    return out
+        for a in arrs:
+            a.copy_to_host_async()
+        return [np.asarray(a) for a in arrs]
 
 
 def _packed_resp_dict(a: np.ndarray) -> Dict[str, np.ndarray]:

@@ -58,6 +58,8 @@ the semantic reference:
 from __future__ import annotations
 
 import asyncio
+import itertools
+import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -549,6 +551,17 @@ class FastPath:
         metrics = service.metrics
         self._stages = tracing.ledger_of(metrics)
         self._stages.register("wire", tracing.WIRE_STAGES)
+        # The forward hop's rows: a daemon that never routes (a single
+        # node, the mesh daemon) shows them at zero.
+        self._stages.register("peer", tracing.PEER_STAGES)
+        self._stages.declare(
+            "peer", "peer.forward", *tracing.PEER_FORWARD_COUNTERS
+        )
+        # The identity forwards carry (docs/cluster.md): random a lane,
+        # so that a restarted daemon's counter cannot meet an id its
+        # owner still keeps.
+        self._forward_instance = os.urandom(6).hex()
+        self._forward_seq = itertools.count()
         # Blocking device->host fetches performed ON the request path
         # (a coalescer dispatch/fetch stage), by lane.  The background
         # planes (census, tiering) must leave it unchanged.
@@ -660,6 +673,10 @@ class FastPath:
             self._owner_frames[addr] = f
         return f
 
+    def _next_forward_id(self) -> str:
+        """A forward's identity: this lane's instance and a counter."""
+        return "%s-%x" % (self._forward_instance, next(self._forward_seq))
+
     def _single_node(self) -> bool:
         """True when no request can need a peer forward: an empty picker,
         or a one-peer picker where that peer is this node."""
@@ -673,12 +690,15 @@ class FastPath:
 
     # -- entry point -----------------------------------------------------
     async def check_raw(
-        self, payload: bytes, peer_rpc: bool
+        self, payload: bytes, peer_rpc: bool,
+        deadline: Optional[float] = None,
     ) -> Optional[bytes]:
         """Serve a GetRateLimits(Req) / GetPeerRateLimits(Req) payload on
         the compiled lane; None = caller must take the object path.
         Raises ApiError on an oversized batch (same contract as the
-        object path)."""
+        object path).  `deadline` (time.monotonic()) is the client's own:
+        all a routed RPC does with it is bound the re-asks of a forward
+        that times out (_serve_routed)."""
         # wire.ingress: from here to the first coalescer enqueue
         # (_Coalescer.do ends it); the `finally` ends it for an RPC that
         # never enqueues (fallback, empty, oversized).
@@ -686,12 +706,14 @@ class FastPath:
             "wire.ingress", "wire", tracing.current_context()
         )
         try:
-            return await self._check_raw(payload, peer_rpc, ingress)
+            return await self._check_raw(
+                payload, peer_rpc, ingress, deadline
+            )
         finally:
             ingress.end()
 
     async def _check_raw(
-        self, payload: bytes, peer_rpc: bool, ingress
+        self, payload: bytes, peer_rpc: bool, ingress, deadline=None
     ) -> Optional[bytes]:
         from gubernator_tpu.runtime.service import ApiError
 
@@ -795,7 +817,7 @@ class FastPath:
         try:
             if routed:
                 return await self._serve_routed(
-                    payload, cols, n, is_global, sk, ingress
+                    payload, cols, n, is_global, sk, ingress, deadline
                 )
             return await self._serve(
                 payload, cols, n, is_global, sk, peer_rpc, ingress
@@ -1401,7 +1423,8 @@ class FastPath:
         return self.s.local_picker.hash_fn in (xx_64, fnv1_64, fnv1a_64)
 
     async def _serve_routed(
-        self, payload: bytes, cols, n: int, is_global, sk, ingress=None
+        self, payload: bytes, cols, n: int, is_global, sk, ingress=None,
+        deadline: Optional[float] = None,
     ) -> bytes:
         """Multi-node client path: vectorized consistent-hash routing with
         zero-copy forwards.
@@ -1413,7 +1436,11 @@ class FastPath:
         no re-encoding in either direction (the reference's asyncRequests
         + peer batcher, gubernator.go:327-416, with the per-request python
         replaced by array ops).  Failed forwards fall back to the object
-        path's ownership-retry loop per request."""
+        path's ownership-retry loop per request; one that times out is
+        asked again under its id while `deadline`, the client's, allows
+        (net/peer_client.py; the owner applies an id once)."""
+        stages = self._stages
+        route = stages.stage("peer.route", "peer")
         picker = self.s.local_picker
         ring, ring_idx, peers = picker.ring_arrays()
         # check_raw gated on a non-empty ring with no await in between;
@@ -1464,6 +1491,17 @@ class FastPath:
                 mirror_mask &= ~sk
             if not mirror_mask.any():
                 mirror_mask = None
+        local_idx = np.flatnonzero(local_mask)
+        forwardable = ~local_mask
+        if mirror_mask is not None:
+            forwardable &= ~mirror_mask
+        remote_idx = np.flatnonzero(forwardable)
+        remote_owner = owner[remote_idx]
+        forwards = [
+            (peers[int(pi)], remote_idx[remote_owner == pi])
+            for pi in np.unique(remote_owner)
+        ]
+        route.end()
 
         status = np.zeros(n, dtype=np.int64)
         out_lim = np.zeros(n, dtype=np.int64)
@@ -1525,48 +1563,12 @@ class FastPath:
             if len(idx) - n_glob:
                 m.labels("local").inc(len(idx) - n_glob)
 
-        async def forward(peer, idx: np.ndarray) -> None:
-            import grpc as grpc_mod
-
-            from gubernator_tpu.net.peer_client import PeerNotReadyError
-
-            addr = peer.info().grpc_address.encode()
-            sub_pay = b"".join(
-                payload[cols.msg_off[i]:cols.msg_off[i] + cols.msg_len[i]]
-                for i in idx
-            )
-            self.s.metrics.getratelimit_counter.labels("forward").inc(
-                len(idx)
-            )
-            try:
-                raw = await peer.get_peer_rate_limits_raw(sub_pay)
-            except Exception as e:  # noqa: BLE001
-                # Retry ONLY the failures the object path retries
-                # (NotReady / UNAVAILABLE / CANCELLED, which _forward
-                # re-resolves with backoff — gubernator.go:382-395).
-                # Anything else may follow a delivered batch, and a
-                # re-send would double-count the hits.
-                retriable = isinstance(e, PeerNotReadyError) or (
-                    isinstance(e, grpc_mod.aio.AioRpcError)
-                    and e.code() in (
-                        grpc_mod.StatusCode.UNAVAILABLE,
-                        grpc_mod.StatusCode.CANCELLED,
-                    )
-                )
-                if retriable:
-                    await forward_fallback(peer, idx)
-                else:
-                    msg = (
-                        "Error while fetching rate limit from peer "
-                        f"'{peer.info().grpc_address}': {e}"
-                    ).encode()
-                    for i in idx:
-                        errs[int(i)] = msg
-                return
+        def assemble(peer, addr: bytes, idx: np.ndarray, raw: bytes) -> None:
             rc = native.parse_resps(raw)
             if rc is None or rc.n != len(idx):
                 # A response ARRIVED, so the peer applied the batch —
                 # never re-send; report the protocol error instead.
+                stages.tally("peer", "peer.forward", refused=1)
                 msg = (
                     "peer '%s' returned %s responses for %d requests"
                     % (
@@ -1595,6 +1597,60 @@ class FastPath:
                     o = int(rc.meta_off[j])
                     m = raw[o:o + int(rc.meta_len[j])]
                 metas[i] = m + owner_frame
+
+        async def forward(peer, idx: np.ndarray) -> None:
+            import grpc as grpc_mod
+
+            from gubernator_tpu.net.peer_client import PeerNotReadyError
+
+            addr = peer.info().grpc_address.encode()
+            with stages.stage("peer.splice", "peer"):
+                sub_pay = b"".join(
+                    payload[cols.msg_off[i]:cols.msg_off[i] + cols.msg_len[i]]
+                    for i in idx
+                )
+            self.s.metrics.getratelimit_counter.labels("forward").inc(
+                len(idx)
+            )
+            stages.tally("peer", "peer.forward", checks=len(idx))
+            try:
+                # peer.forward is timed where the RPC is made
+                # (net/peer_client.py), readiness gate and re-asks
+                # included.
+                raw = await peer.get_peer_rate_limits_raw(
+                    sub_pay, forward_id=self._next_forward_id(),
+                    deadline=deadline,
+                )
+            except Exception as e:  # noqa: BLE001
+                # Retry ONLY the failures the object path retries
+                # (NotReady / UNAVAILABLE / CANCELLED, which _forward
+                # re-resolves with backoff — gubernator.go:382-395).
+                # Anything else may follow a delivered batch, and a
+                # re-send would double-count the hits.
+                retriable = isinstance(e, PeerNotReadyError) or (
+                    isinstance(e, grpc_mod.aio.AioRpcError)
+                    and e.code() in (
+                        grpc_mod.StatusCode.UNAVAILABLE,
+                        grpc_mod.StatusCode.CANCELLED,
+                    )
+                )
+                if retriable:
+                    stages.tally("peer", "peer.forward", retried=1)
+                    await forward_fallback(peer, idx)
+                else:
+                    # (A timeout here is one nobody may ask again:
+                    # the client's deadline or the tries are spent, or
+                    # the peer never said it applies an id once.)
+                    stages.tally("peer", "peer.forward", refused=1)
+                    msg = (
+                        "Error while fetching rate limit from peer "
+                        f"'{peer.info().grpc_address}': {e}"
+                    ).encode()
+                    for i in idx:
+                        errs[int(i)] = msg
+                return
+            with stages.stage("peer.assemble", "peer"):
+                assemble(peer, addr, idx, raw)
 
         async def forward_fallback(peer, idx: np.ndarray) -> None:
             """Re-route failed forwards through the object path's retry
@@ -1640,21 +1696,38 @@ class FastPath:
 
             await asyncio.gather(*(one(int(i)) for i in idx))
 
-        tasks = []
-        local_idx = np.flatnonzero(local_mask)
-        if len(local_idx):
-            tasks.append(serve_local(local_idx))
-        forwardable = ~local_mask
+        # The hop's tasks start at the loop's next turn, so the RPC's own
+        # lanes are enqueued first, as when one gather ran them all.
+        hops = [
+            asyncio.ensure_future(forward(peer, idx))
+            for peer, idx in forwards
+        ]
         if mirror_mask is not None:
-            forwardable = forwardable & ~mirror_mask
-            tasks.append(serve_mirror(np.flatnonzero(mirror_mask)))
-        remote_idx = np.flatnonzero(forwardable)
-        if len(remote_idx):
-            for pi in np.unique(owner[remote_idx]):
-                idx = remote_idx[owner[remote_idx] == pi]
-                tasks.append(forward(peers[int(pi)], idx))
-        await asyncio.gather(*tasks)
-        egress = self._stages.begin(
+            hops.append(asyncio.ensure_future(
+                serve_mirror(np.flatnonzero(mirror_mask))
+            ))
+        try:
+            if len(local_idx):
+                await serve_local(local_idx)
+            elif ingress is not None:
+                # It owns none of its checks: nothing will enqueue, and
+                # wire.ingress must not stretch over the forwards.
+                ingress.end()
+            if hops:
+                # wire.peer_wait: what the forwards take beyond the
+                # RPC's own lanes (nothing, if they answered first).
+                waited = stages.begin(
+                    "wire.peer_wait", "wire", tracing.current_context()
+                )
+                try:
+                    await asyncio.gather(*hops)
+                finally:
+                    waited.end()
+        except BaseException:
+            for t in hops:
+                t.cancel()
+            raise
+        egress = stages.begin(
             "wire.egress", "wire", tracing.current_context()
         )
 

@@ -654,7 +654,7 @@ def recent_spans_for(
 # "wire" for the per-RPC stages, the coalescer lane's own name
 # (mach / sketch / engine) for everything a drain does, "direct" for
 # the object path and library callers, and the layer itself for the
-# process-wide rows (global, xla).
+# forward hop (peer) and the process-wide rows (global, xla).
 STAGES: Dict[str, str] = {
     # per RPC, event loop
     "wire.rpc": "stats interceptor entry -> return, every unary method "
@@ -663,9 +663,15 @@ STAGES: Dict[str, str] = {
                     "entry -> return",
     "wire.ingress": "handler entry -> the first coalescer enqueue "
                     "(eligibility, parse_reqs, validation, _prep_greg)",
-    "wire.wake": "fut.set_result -> the handler coroutine resumes",
+    "wire.wake": "fut.set_result -> the handler coroutine resumes (twice "
+                 "for a forward applied under an id: the lane's future, "
+                 "then net/forward_once.py's)",
     "wire.egress": "resume -> return (captures, GLOBAL queueing, error "
                    "strings, serialize_resps)",
+    "wire.peer_wait": "a routed RPC on its entry daemon: its own lanes "
+                      "resumed (wire.wake's end; wire.ingress's end where "
+                      "it owns none of its checks) -> the last forward's "
+                      "answer is in place",
     "wire.empty": "state: no raw RPC between handler entry and return",
     "wire.occupied": "state: at least one raw RPC in the daemon",
     # per entry, event loop
@@ -701,6 +707,22 @@ STAGES: Dict[str, str] = {
                         "gubernator_tpu_device_step_duration)",
     "backend.d2h_wait": "fetch_ravel: blocked until the answer is on "
                         "the host",
+    # the forward hop, on the entry daemon's loop (lane `peer`)
+    "peer.route": "once a routed RPC: the ring lookup, the owner masks, "
+                  "the per-owner index sets",
+    "peer.splice": "per forward: the GetPeerRateLimits payload joined from "
+                   "the request's own bytes",
+    "peer.forward": "per forward: send -> raw answer (the readiness gate, "
+                    "the RPC, the owner's whole handler, every re-ask); "
+                    "counters checks (checks sent), and by event: timeouts "
+                    "(an ask that ended DEADLINE_EXCEEDED), reasked (the "
+                    "same forward asked again under its id), joined (OWNER "
+                    "side: an arrival that found its id applied or in "
+                    "progress and took that answer), retried (handed to "
+                    "the object path's ownership-retry loop), refused "
+                    "(answered with an error, or a wrong response count)",
+    "peer.assemble": "per forward: parse_resps, and the per-check copy of "
+                     "errors and the owner's metadata frame",
     # per tick / process
     "global.sync_tick": "one GLOBAL psum sync: staging, dispatch, "
                         "write-through read-back; counters keys (pending "
@@ -722,6 +744,12 @@ WIRE_STAGES = tuple(
 )
 LANE_STAGES = tuple(
     s for s in STAGES if s.startswith(("lane.", "backend."))
+)
+# The forward hop's rows (lane `peer`) and its counters: at zero on every
+# daemon from start-up, whether or not it ever routes.
+PEER_STAGES = tuple(s for s in STAGES if s.startswith("peer."))
+PEER_FORWARD_COUNTERS = (
+    "checks", "timeouts", "reasked", "joined", "retried", "refused",
 )
 
 _TRACE_ME = None  # jax.profiler.TraceAnnotation, resolved on first use
@@ -897,6 +925,10 @@ class StageLedger:
         count: a reader that divides by or into them (the benchmark's
         ratio metrics) finds a number from the start, not nothing."""
         self._tally(self.cell(lane, stage), dict.fromkeys(counters, 0))
+
+    def tally(self, lane: str, stage: str, **counts: int) -> None:
+        """Add to a row's named counters where no stage is open."""
+        self._tally(self.cell(lane, stage), counts)
 
     def cell(self, lane: str, stage: str) -> _Cell:
         c = self._cells.get((lane, stage))

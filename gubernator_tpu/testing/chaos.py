@@ -21,7 +21,10 @@ fault sequences a first-class, reproducible test input:
   runs (the request was delivered but never applied); `phase="after"`
   rules run the handler — hits ARE applied — then fail the RPC anyway:
   the delivered-but-unanswered window that makes blind retries double
-  count.
+  count.  A `delay` rule stalls the RPC there instead of failing it:
+  before the handler (nothing applied yet) or after it (applied, the
+  answer held back) — the two stalls a forward must survive
+  (docs/cluster.md).
 
 * **Partition** — `injector.partition(group_a, group_b, ...)` makes
   every cross-group client call fail with UNAVAILABLE and a
@@ -345,7 +348,11 @@ class ChaosServerInterceptor(grpc.aio.ServerInterceptor):
                     )
             out = await inner(request, context)
             rule = inj.server_rule(addr_fn(), method, "after")
-            if rule is not None and rule.op != "delay":
+            if rule is not None and rule.op == "delay":
+                # The handler RAN and its answer is held back: an owner
+                # that stalls between applying and answering.
+                await asyncio.sleep(rule.delay_s)
+            elif rule is not None:
                 # The handler RAN — hits were applied — and the caller
                 # sees a failure anyway: the delivered-but-unanswered
                 # window.  A client that blind-retries this double

@@ -19,6 +19,7 @@ from dataclasses import replace
 from typing import Awaitable, List, Optional, Sequence, TypeVar
 
 from gubernator_tpu.core.config import (
+    BehaviorConfig,
     DaemonConfig,
     DeviceConfig,
     fast_test_behaviors,
@@ -62,9 +63,13 @@ class Cluster:
         datacenters: Sequence[str],
         device: Optional[DeviceConfig] = None,
         conf_template: Optional[DaemonConfig] = None,
+        behaviors: Optional[BehaviorConfig] = None,
     ) -> "Cluster":
         """Start one daemon per entry of `datacenters`
-        (cluster.StartWith, cluster/cluster.go:111-146)."""
+        (cluster.StartWith, cluster/cluster.go:111-146).  `behaviors`
+        replaces the short test windows (`fast_test_behaviors`): a test
+        that is not about the forward's time limit gives its cluster one
+        that a loaded sandbox's event-loop stall cannot reach."""
         c = cls()
 
         async def boot() -> None:
@@ -75,7 +80,7 @@ class Cluster:
                     grpc_listen_address="127.0.0.1:0",
                     http_listen_address="127.0.0.1:0",
                     data_center=dc,
-                    behaviors=fast_test_behaviors(),
+                    behaviors=behaviors or fast_test_behaviors(),
                     device=device or TEST_DEVICE,
                 )
                 d = Daemon(conf)

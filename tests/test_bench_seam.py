@@ -349,3 +349,130 @@ def test_layer_metric_series_is_exported(one_chip, series, labels):
         name == series and all(lab.get(k) == v for k, v in want.items())
         for name, lab in one_chip.series
     ), f"/metrics has no {series}{want}"
+
+
+# -- (d) the cluster cell: the forward hop (PR 39) --------------------------
+#
+# `peers4-10m.batch.closed` reads a routed daemon: bench/run.py holds the
+# `calltype` series to the plan's own count of the hop
+# (forwarded_checks_differ, local_checks_differ), the five "peer hop"
+# metrics read the hop's ledger rows, and the harness starts each daemon
+# of the cluster from the environment and refuses the run by the names of
+# its ready report.
+
+HOP_SERIES = "gubernator_getratelimit_counter_total"   # bench/run.py:77
+PEER_HOP_METRICS = sorted(
+    f.stem for f in (BENCH / "layer_metrics").glob("peer_*.json")
+)
+
+
+@pytest.fixture(scope="module")
+def routed():
+    """Daemon 0 of two on one ring, after RPCs whose keys both own."""
+    from gubernator_tpu import native
+    from gubernator_tpu.testing.cluster import Cluster
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    c = Cluster.start(2, device=DeviceConfig(
+        num_slots=1 << 12, ways=8, batch_size=128,
+    ))
+    try:
+        yield _Seam(c, global_keys=0)
+    finally:
+        c.stop()
+
+
+@pytest.mark.parametrize("calltype", ["forward", "local"])
+def test_hop_series_counts_by_calltype(routed, calltype):
+    assert any(
+        name == HOP_SERIES and lab.get("calltype") == calltype
+        for name, lab in routed.series
+    ), f"/metrics has no {HOP_SERIES}{{calltype={calltype}}}"
+    counted = routed.daemon.metrics.getratelimit_counter.labels(calltype)
+    assert counted._value.get() > 0
+
+
+def test_the_peer_hop_metrics_were_found():
+    assert len(PEER_HOP_METRICS) == 5, PEER_HOP_METRICS
+
+
+@pytest.mark.parametrize("name", PEER_HOP_METRICS)
+def test_peer_hop_metric_reads_a_live_routed_daemon(routed, name):
+    """Every term of the metric's data file finds ONE number on a daemon
+    that has forwarded, and the numerator has grown from zero."""
+    read = json.loads(
+        (BENCH / "layer_metrics" / f"{name}.json").read_text())["read"]
+    assert read["kind"] == "ratio" and read["delta"] is True
+    total = {"num": 0.0, "den": 0.0}
+    for side in ("num", "den"):
+        for term in read[side]:
+            if isinstance(term, dict):
+                assert any(
+                    n == term["metrics"]
+                    and all(lab.get(k) == v
+                            for k, v in term["labels"].items())
+                    for n, lab in routed.series
+                ), term
+                total[side] += 1.0      # exported; its value is /metrics'
+                continue
+            nodes = _resolve(routed.vars, term[len("vars:"):])
+            assert len(nodes) == 1 and _is_number(nodes[0]), (term, nodes)
+            total[side] += nodes[0]
+    assert total["num"] > 0 and total["den"] > 0, (name, total)
+
+
+def test_the_routed_rpcs_handler_closes_with_the_hop_named(routed):
+    """peer_attributed_share.closed's terms on a live entry daemon."""
+    st = routed.vars["stages"]
+    handler = st["wire"]["handler"]["ms_total"]
+    parts = sum(st["wire"][s]["ms_total"] for s in (
+        "ingress", "wake", "egress", "peer_wait"
+    )) + st["mach"]["queue_wait"]["ms_total"] + st["mach"]["in_drain"][
+        "ms_total"]
+    assert st["wire"]["peer_wait"]["count"] > 0
+    assert 0.90 * handler <= parts <= 1.001 * handler, (parts, handler)
+    hop = st["peer"]["forward"]
+    assert hop["count"] > 0 and hop["checks"] >= hop["count"]
+    assert [hop[k] for k in ("timeouts", "reasked", "joined", "retried",
+                             "refused")] == [0] * 5
+
+
+def test_the_ready_reports_names_on_a_daemon_of_a_cluster(routed):
+    """bench/serve.py's ready report, bench/run.py `check_chips`: the
+    device block's four names, the warm-up time, and the devices'
+    `id` / `coords` that `chip_report` reads."""
+    import jax
+
+    info = routed.obj("backend").device_info()    # bench/serve.py:274
+    assert set(info) >= {"platform", "device_kind", "device_count",
+                         "table_device_ids"}      # bench/run.py:135-165
+    assert len(set(info["table_device_ids"])) == 1    # bench/run.py:149
+    assert routed.daemon._warmup_s > 0            # bench/serve.py:276
+    devs = [d for d in jax.devices() if d.id in info["table_device_ids"]]
+    assert devs and all(isinstance(d.id, int) for d in devs)  # serve.py:222
+
+
+def test_a_daemon_of_a_cluster_starts_from_the_environment(monkeypatch):
+    """bench/run.py `server_env`: a configuration's `daemon` group plus
+    the daemon's own addresses; `--control noforward` rewrites GUBER_PEERS
+    to the advertise address alone (bench/serve.py:87)."""
+    from gubernator_tpu.core.config import setup_daemon_config
+
+    peers = "127.0.0.1:21051,127.0.0.1:21052,127.0.0.1:21053,127.0.0.1:21054"
+    for k, v in {
+        "GUBER_TPU_NUM_SLOTS": "4194304", "GUBER_TPU_WAYS": "8",
+        "GUBER_TPU_BATCH_SIZE": "4096", "GUBER_PEERS": peers,
+        "GUBER_PEER_PICKER_HASH": "xx", "GUBER_BATCH_TIMEOUT": "500ms",
+        "GUBER_GRPC_ADDRESS": "127.0.0.1:21052",
+        "GUBER_HTTP_ADDRESS": "127.0.0.1:21999",
+        "GUBER_ADVERTISE_ADDRESS": "127.0.0.1:21052",
+    }.items():
+        monkeypatch.setenv(k, v)
+    conf = setup_daemon_config(None)
+    assert conf.static_peers == peers.split(",")
+    assert conf.advertise_address == "127.0.0.1:21052"
+    assert conf.local_picker_hash == "xx"
+    assert conf.behaviors.batch_timeout_s == 0.5
+    assert (conf.device.num_slots, conf.device.ways,
+            conf.device.batch_size) == (1 << 22, 8, 4096)
