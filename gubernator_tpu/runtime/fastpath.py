@@ -12,7 +12,11 @@ daemon hands the raw gRPC payload straight here:
 
     C++ parse  (native/gubtpu.cpp gub_parse_reqs2: wire -> columns + XXH64)
     numpy      (burst defaults, behavior masks, shard routing)
-    C++ pack   (gub_assign_rounds: duplicate-key round/lane assignment)
+    C++ pack   (gub_assign_rounds: duplicate-key round/lane assignment;
+                a drain takes the host cascade instead only where that
+                saves a device launch, _cascade_or_rounds: a key three
+                times in a drain of one round does, one pair among 5,000
+                checks rides the two rounds the drain has anyway)
     numpy      (scatter columns into fixed-shape DeviceBatch rounds)
     device     (backend.step_rounds: the same jitted kernels as check();
                 sketch-named lanes take one CMS step instead)
@@ -587,6 +591,9 @@ class FastPath:
         # What PR 34's two counters count may never happen in a run (no
         # merge cascades, every key is resident): they read 0, not nothing.
         self._stages.declare("mach", "lane.cascade", "wb_lanes")
+        # The drains whose duplicate groups went plain, and the device
+        # lanes their later occurrences took (_cascade_or_rounds).
+        self._stages.declare("mach", "lane.pack", "dup_plain", "dup_lanes")
         self._stages.declare("mach", "lane.unpack", "new_windows")
         # The sketch and engine lanes each coalesce cross-RPC into one
         # maximal merge at a time, on DEDICATED workers so machinery
@@ -2025,7 +2032,17 @@ class FastPath:
         duplicate groups instead take the host-cascade path (_plan_cascade):
         one read lane, an exact host-side replay of the per-occurrence
         algorithm branches, and one effective write-back lane — two rounds
-        total regardless of skew."""
+        total regardless of skew.
+
+        A drain cascades only where that saves a device launch
+        (_cascade_or_rounds): the cascade pays for its rounds with the
+        response fetch INSIDE the backend lock and the dispatch stage, so
+        a drain whose duplicates fit the rounds it takes anyway (one pair
+        among 5,000 checks at batch_size 4096: two rounds either way)
+        drops the plan and is a plain merge, each occurrence on a device
+        lane of a later round than the one before it, the fetch on the
+        fetch stage.  A store drain fetches inside the lock either way
+        and keeps its plan."""
         cfg = self.s.backend.cfg
         n_shards = cfg.num_shards
         B = cfg.batch_size
@@ -2073,19 +2090,6 @@ class FastPath:
                     km[int(np.int64(fp).view(np.uint64))] = key
             backend._maybe_prune_keymap()
         do_store = store is not None and bool(uniq)
-        if plan is None:
-            h_mach, hits_mach = h, hits
-        else:
-            h_mach = h.copy()
-            hits_mach = hits.copy()
-            h_mach[plan.occ] = 0          # divert cascade occurrences
-            h_mach[plan.firsts] = h[plan.firsts]  # keep one READ lane
-            hits_mach[plan.firsts] = 0
-            # What the replay will serve, for the lane.cascade row.
-            casc_counts = dict(
-                groups=len(plan.groups), occ=int(plan.occ.sum()),
-                peeks=int((hits[plan.occ] == 0).sum()),
-            )
 
         if n_shards > 1:
             from gubernator_tpu.parallel.mesh import shard_of_hash
@@ -2097,8 +2101,32 @@ class FastPath:
         else:
             to_host = packed_rounds_to_host
             sh_all = np.zeros(n, dtype=np.int32)
-        rnd, lane, n_rounds = native.assign_rounds(
-            h_mach, sh_all if n_shards > 1 else None, n_shards, B
+        shards = sh_all if n_shards > 1 else None
+
+        h_mach, hits_mach, assigned = h, hits, None
+        if plan is not None:
+            h_mach = _read_lanes(plan, h)
+            if not do_store:
+                cascades, assigned = _cascade_or_rounds(
+                    plan, h, h_mach, use_cached, shards, n_shards, B
+                )
+                if not cascades:
+                    # The duplicates ride the rounds the drain has anyway.
+                    pack.tally(
+                        dup_plain=1,
+                        dup_lanes=int(plan.occ.sum()) - len(plan.groups),
+                    )
+                    plan, h_mach = None, h
+        if plan is not None:
+            hits_mach = hits.copy()
+            hits_mach[plan.firsts] = 0    # the read lane spends nothing
+            # What the replay will serve, for the lane.cascade row.
+            casc_counts = dict(
+                groups=len(plan.groups), occ=int(plan.occ.sum()),
+                peeks=int((hits[plan.occ] == 0).sum()),
+            )
+        rnd, lane, n_rounds = assigned or native.assign_rounds(
+            h_mach, shards, n_shards, B
         )
 
         values = dict(
@@ -2588,6 +2616,42 @@ def _plan_cascade(h, hits, reset_remaining, is_greg, lim, dur, algo, burst,
         inv=inv,
         first_idx=first_idx,
     )
+
+
+def _read_lanes(plan, h):
+    """The drain's hashes as a cascade merge sends them to the device:
+    every occurrence of a cascade group diverted (0) but its first, the
+    group's one READ lane."""
+    h_mach = h.copy()
+    h_mach[plan.occ] = 0
+    h_mach[plan.firsts] = h[plan.firsts]
+    return h_mach
+
+
+def _cascade_or_rounds(plan, h, h_mach, use_cached, shards, n_shards, B):
+    """Whether a drain that holds eligible duplicate groups (`plan`) takes
+    the host cascade: only where that saves a device launch.
+
+    `native.assign_rounds` already places occurrence k of a key in a later
+    round than k-1, so the drain's own hashes `h` take `plain_rounds`
+    launches with no help; the cascade takes the rounds of `h_mach`
+    (_read_lanes) plus one for the write-back, which a drain whose
+    groups are all `use_cached` never sends.  The cascade also holds the
+    response fetch inside `backend._lock` and the coalescer's serial
+    dispatch stage, so a tie goes plain: one pair among 5,000 checks at
+    batch_size 4096 is two rounds either way, a pair in a drain of one
+    round a second 128-lane launch either way.  Where the hottest key
+    comes three times or more (zipfian traffic, a token held by many)
+    the cascade's two rounds win.
+
+    Returns (cascades, (rnd, lane, n_rounds)): the assignment of the
+    path chosen — of `h_mach` where it cascades, of `h` where not."""
+    plain = native.assign_rounds(h, shards, n_shards, B)
+    reads = native.assign_rounds(h_mach, shards, n_shards, B)
+    write_back = 0 if use_cached[plan.firsts].all() else 1
+    if plain[2] > reads[2] + write_back:
+        return True, reads
+    return False, plain
 
 
 def _run_cascade(plan, h, hits, lim, dur, algo, burst,

@@ -12,7 +12,10 @@ cascade merge's write-back rounds run under the clock its read rounds took,
 so a window that ends, or a leak that completes, between the two is not
 taken by the write-back (PERF.md section 7, PR 33 found both; they failed
 here until `fastpath._process_packed` took the clock once a hold of
-`backend._lock`).
+`backend._lock`).  A duplicated key that comes three times makes such a
+merge; one that comes twice rides the drain's rounds (PR 40,
+`fastpath._cascade_or_rounds`), a plain merge whose rounds all run under the
+one reading `backend._dispatch_rounds_locked` takes: both are held here.
 
 The tier-1 copy of bench/tests/test_clock_properties.py, without its
 `xfail`s, on the one-chip backend and on the mesh backend."""
@@ -144,38 +147,62 @@ def test_a_token_answer_carries_its_buckets_creation_stamp(served):
     assert stamps == [0, 0, 0, 0, 1000, 1000, 5000, 5000, 6000]
 
 
-def test_a_window_that_ends_inside_a_cascade_merge_starts_full(served):
-    cl, clk, _ = served
+def cascades_of(fp):
+    return fp._stages.debug_vars()["mach"]["cascade"]["count"]
+
+
+# The spends of one key in one RPC: three make a cascade merge (read round,
+# replay, write-back round), two a plain merge of two device rounds.
+MERGES = pytest.mark.parametrize("spends,cascade", [
+    ((1, 1, 1), 1), ((1, 1), 0),
+], ids=["cascade_merge", "pair_rides_rounds"])
+
+
+@MERGES
+def test_a_window_that_ends_inside_a_cascade_merge_starts_full(
+    served, spends, cascade,
+):
+    cl, clk, fp = served
     ref = Reference()
+    key = f"merge{len(spends)}"
     clk.t = T + 10_000
-    assert same(ask(cl, "merge", Algorithm.TOKEN_BUCKET, 1),
-                ref.ask(T + 10_000, "merge", Algorithm.TOKEN_BUCKET, 1))
-    # The merge's read round at the window's last millisecond, whatever
-    # reads the clock next (the write-back round) at its end.
+    assert same(ask(cl, key, Algorithm.TOKEN_BUCKET, 1),
+                ref.ask(T + 10_000, key, Algorithm.TOKEN_BUCKET, 1))
+    # The merge's first round at the window's last millisecond, whatever
+    # reads the clock next (the write-back round, a second round) at its
+    # end.
     clk.script = [T + 10_999, T + 11_000]
-    assert same(ask(cl, "merge", Algorithm.TOKEN_BUCKET, 1, 1),
-                ref.ask(T + 10_999, "merge", Algorithm.TOKEN_BUCKET, 1, 1))
+    before = cascades_of(fp)
+    assert same(ask(cl, key, Algorithm.TOKEN_BUCKET, *spends),
+                ref.ask(T + 10_999, key, Algorithm.TOKEN_BUCKET, *spends))
+    assert cascades_of(fp) - before == cascade
     clk.script = []
     clk.t = T + 11_001
-    # The reference: the old window took both spends and the peek opens a
+    # The reference: the old window took every spend and the peek opens a
     # new one, full.  (With a clock of its own the write-back opened it at
-    # 11,000 and spent both there, so the peek read 98.)
-    assert same(ask(cl, "merge", Algorithm.TOKEN_BUCKET, 0),
-                ref.ask(T + 11_001, "merge", Algorithm.TOKEN_BUCKET, 0))
+    # 11,000 and spent them there, so the peek read 98.)
+    assert same(ask(cl, key, Algorithm.TOKEN_BUCKET, 0),
+                ref.ask(T + 11_001, key, Algorithm.TOKEN_BUCKET, 0))
 
 
-def test_a_leak_pending_at_a_cascade_merge_is_not_taken_early(served):
-    cl, clk, _ = served
+@MERGES
+def test_a_leak_pending_at_a_cascade_merge_is_not_taken_early(
+    served, spends, cascade,
+):
+    cl, clk, fp = served
     ref = Reference()
+    key = f"pending{len(spends)}"
     clk.t = T + 20_000
-    assert same(ask(cl, "pending", Algorithm.LEAKY_BUCKET, 10),
-                ref.ask(T + 20_000, "pending", Algorithm.LEAKY_BUCKET, 10))
-    # 8 ms later a merge: no whole token has leaked at its read round; at
-    # its write-back round, 3 ms on, one has.  (With a clock of its own the
+    assert same(ask(cl, key, Algorithm.LEAKY_BUCKET, 10),
+                ref.ask(T + 20_000, key, Algorithm.LEAKY_BUCKET, 10))
+    # 8 ms later a merge: no whole token has leaked at its first round; at
+    # its next round, 3 ms on, one has.  (With a clock of its own the
     # write-back took 1.1 tokens there and stamped the bucket 20,011.)
     clk.script = [T + 20_008, T + 20_011]
-    assert same(ask(cl, "pending", Algorithm.LEAKY_BUCKET, 1, 1),
-                ref.ask(T + 20_008, "pending", Algorithm.LEAKY_BUCKET, 1, 1))
+    before = cascades_of(fp)
+    assert same(ask(cl, key, Algorithm.LEAKY_BUCKET, *spends),
+                ref.ask(T + 20_008, key, Algorithm.LEAKY_BUCKET, *spends))
+    assert cascades_of(fp) - before == cascade
     clk.script = []
     # At 20,020 the reference leaks 2.0 tokens, all since 20,000 (the
     # write-back's own clock left 0.9 of a token pending since 20,011: one
@@ -184,6 +211,6 @@ def test_a_leak_pending_at_a_cascade_merge_is_not_taken_early(served):
     for at in (20_019, 20_020, 20_021, 20_029, 20_030):
         clk.t = T + at
         differ.append(not same(
-            ask(cl, "pending", Algorithm.LEAKY_BUCKET, 0),
-            ref.ask(T + at, "pending", Algorithm.LEAKY_BUCKET, 0)))
+            ask(cl, key, Algorithm.LEAKY_BUCKET, 0),
+            ref.ask(T + at, key, Algorithm.LEAKY_BUCKET, 0)))
     assert not any(differ), differ

@@ -695,7 +695,7 @@ _PEEK_CASES = {
     "token-new-peek-first": (0, [
         _B(0, 1, 0, 2), _B(0), _B(1)]),
     "token-new-peeks-only": (0, [
-        _B(0, 0), _B(1, 1), _B(0), _B(1)]),
+        _B(0, 0, 0), _B(1, 1, 1), _B(0), _B(1)]),
     "token-new-over-asked-after-a-peek": (0, [
         _B(0, 11, 1), _B(0), _B(1)]),
     # r reaches 0 mid-group, a spend flips the stored status, the peek
@@ -738,7 +738,7 @@ _PEEK_CASES = {
     "leaky-drained-mid-group": (1, [
         _B(98), _B(1, 0, 1, 0, 1, 0), _B(0), _B(1)]),
     "leaky-peek-at-zero": (1, [
-        _B(100), _B(0, 0), _B(0, 1, 0), _B(0), _B(1)]),
+        _B(100), _B(0, 0, 0), _B(0, 1, 0), _B(0), _B(1)]),
     # Every spend over the limit, eff == 0: the touch lane refreshes the
     # sliding expiry, so past the OLD expiry the bucket still stands
     # (57 tokens: 45 + two leaks of 6) where a fresh one would hold 100.
@@ -755,6 +755,24 @@ _PEEK_CASES = {
         _B(3), _B(1, -1, 0), _B(0), _B(1)]),
     "leaky-negative-hits-take-rounds": (1, [
         _B(5), _B(0, -2, 1), _B(0), _B(1)]),
+    # A PAIR costs two launches either way (read + write-back, or a round
+    # an occurrence): the tie goes to the rounds, whose fetch is outside
+    # the lock (_cascade_or_rounds), and the device's order of the two IS
+    # the reference's.
+    "token-pair-takes-rounds": (0, [
+        _B(0, 0), _B(1, 1), _B(1, 0), _B(0, 11), _B(0), _B(1)]),
+    "token-pair-at-zero-takes-rounds": (0, [
+        _B(2, limit=2), _B(1, 1, limit=2), _B(1, 0, limit=4),
+        _B(0, limit=4), _B(1, limit=4)]),
+    "leaky-pair-takes-rounds": (1, [
+        _B(1, 400), _B(0, 0), _B(98, 1), _B(1, 0), _B(0), _B(1)]),
+    "leaky-new-over-asked-pair-takes-rounds": (1, [
+        _B(400, 1), _B(0), _B(1)]),
+    # ... unless a group beside it comes three times: the whole plan
+    # cascades, the pair with it.
+    "token-pair-beside-a-triple-cascades": (0, [
+        _B(1, ("b", 1), 0, ("b", 0), 1), _B(0), _B(("b", 0)),
+        _B(1, ("b", 1))]),
 }
 
 
@@ -797,12 +815,18 @@ def test_fastpath_peeks_in_a_duplicate_group(frozen_clock, case):
                 )
                 for key, h in items
             ]
-            for key in {key for key, _ in items}:
-                hs = [h for k, h in items if k == key]
-                if len(hs) > 1 and min(hs) >= 0:
-                    groups += 1
-                    occ += len(hs)
-                    peeks += hs.count(0)
+            by_key = {
+                key: [h for k, h in items if k == key] for key, _ in items
+            }
+            took = [hs for hs in by_key.values()
+                    if len(hs) > 1 and min(hs) >= 0]
+            # The rule (one lane a shard here): the cascade's rounds are
+            # the longest group it does NOT take, or one, and a write-back.
+            left = [len(hs) for hs in by_key.values() if hs not in took]
+            if took and max(map(len, by_key.values())) > max(left + [1]) + 1:
+                groups += len(took)
+                occ += sum(map(len, took))
+                peeks += sum(hs.count(0) for hs in took)
             payload = pb.GetRateLimitsReq(requests=reqs).SerializeToString()
             out = await fp.check_raw(payload, peer_rpc=False)
             got = pb.GetRateLimitsResp.FromString(out).responses
@@ -819,7 +843,7 @@ def test_fastpath_peeks_in_a_duplicate_group(frozen_clock, case):
         return row, groups, occ, peeks
 
     row, groups, occ, peeks = asyncio.run(scenario())
-    if "negative" in case:
+    if "take-rounds" in case or "takes-rounds" in case:
         assert row["count"] == 0 and "groups" not in row
     else:
         assert peeks > 0
@@ -878,6 +902,208 @@ def test_plan_cascade_eligibility(hits, cols, occ):
     uniform use_cached, and no occurrence with negative hits,
     RESET_REMAINING or a Gregorian duration; hits == 0 is no bar."""
     assert _plan(hits, **cols) == occ
+
+
+def _drain_cols(checks, mult, dup_at, cached, negative=False):
+    """Columns of one drain: `checks` lanes; key i + 1 comes mult[i]
+    times, together at the front or at the back, every other key once."""
+    import numpy as np
+
+    dups = [k + 1 for k, m in enumerate(mult) for _ in range(m)]
+    rest = list(range(len(mult) + 1, len(mult) + 1 + checks - len(dups)))
+    h = np.array(dups + rest if dup_at == "front" else rest + dups,
+                 dtype=np.int64)
+    hits = np.ones(checks, dtype=np.int64)
+    if negative:
+        hits[h == 1] = -1
+    return h, hits, np.full(checks, cached, dtype=bool)
+
+
+@pytest.mark.parametrize("checks,B,mult,dup_at,cached,shards,cascades", [
+    # One pair among 5,000 at 4096 lanes: two rounds anyway, three with a
+    # write-back.
+    (5000, 4096, [2], "front", False, 1, False),
+    # Both of the pair among the last 900: three rounds either way.
+    (5000, 4096, [2], "back", False, 1, False),
+    # A pair in one round: a second 128-lane launch either way -- a tie.
+    (64, 4096, [2], "front", False, 1, False),
+    (64, 64, [2, 2, 2], "back", False, 1, False),
+    # Three occurrences: three rounds against read + write-back.
+    (64, 4096, [3], "front", False, 1, True),
+    (64, 4096, [2, 3], "back", False, 1, True),
+    (5000, 4096, [3], "front", False, 1, False),   # ... unless it has them
+    (4000, 4096, [3], "front", False, 1, True),
+    # token1k: 1,000 keys six times each.
+    (6000, 4096, [6] * 1000, "front", False, 1, True),
+    # use_cached groups never write back: one launch against two.
+    (64, 4096, [2], "front", True, 1, True),
+    (5000, 4096, [2], "front", True, 1, False),
+    # An ineligible group takes its rounds, as before.
+    (64, 4096, [3], "front", False, 1, None),
+    # The mesh: 4096 lanes a shard.
+    (5000, 4096, [2], "front", False, 4, False),
+    (5000, 4096, [3], "back", False, 4, True),
+])
+def test_a_drain_cascades_only_where_that_saves_a_launch(
+    checks, B, mult, dup_at, cached, shards, cascades,
+):
+    """_cascade_or_rounds: the drain's own hashes take `plain` launches
+    (assign_rounds puts occurrence k a round after k-1); the cascade takes
+    its read rounds and a write-back.  Cascade iff strictly fewer."""
+    import numpy as np
+
+    from gubernator_tpu.runtime.fastpath import (
+        _cascade_or_rounds,
+        _plan_cascade,
+        _read_lanes,
+    )
+
+    h, hits, use_cached = _drain_cols(
+        checks, mult, dup_at, cached, negative=cascades is None)
+    z = np.zeros(checks, dtype=bool)
+    lim = np.full(checks, 10, dtype=np.int64)
+    plan = _plan_cascade(h, hits, z, z, lim, lim * 1000,
+                         np.zeros(checks, dtype=np.int32), lim, use_cached)
+    if cascades is None:
+        assert plan is None
+        return
+    assert int(plan.occ.sum()) == sum(mult)
+    h_mach = _read_lanes(plan, h)
+    sh = (h % shards).astype(np.int32) if shards > 1 else None
+    got, (rnd, lane, n_rounds) = _cascade_or_rounds(
+        plan, h, h_mach, use_cached, sh, shards, B)
+    assert got is cascades
+    # The assignment is the chosen path's: every lane of the drain where
+    # it goes plain, one read lane a group where it cascades.
+    lanes = int((rnd >= 0).sum())
+    assert lanes == (checks - sum(mult) + len(mult) if cascades else checks)
+    want = native.assign_rounds(h_mach if cascades else h, sh, shards, B)
+    assert n_rounds == want[2] and (rnd == want[0]).all()
+    assert (lane == want[1]).all()
+
+
+@pytest.fixture(scope="module")
+def lane4096():
+    """A service at the benchmark's batch_size, its compiled lane, and the
+    ledger rows of its machinery lane."""
+    from gubernator_tpu.core import clock as clock_mod
+    from gubernator_tpu.core.config import Config
+    from gubernator_tpu.runtime.fastpath import FastPath
+    from gubernator_tpu.runtime.service import Service
+
+    loop = asyncio.new_event_loop()
+    clk = clock_mod.Clock()
+    clk.freeze(1_790_000_000_000 * 1_000_000)
+    dev = DeviceConfig(num_slots=1 << 17, ways=8, batch_size=4096)
+    svc = Service(Config(device=dev), clock=clk)
+    loop.run_until_complete(svc.start())
+    fp = FastPath(svc)
+    yield loop, fp, clk
+    loop.run_until_complete(fp.close())
+    loop.run_until_complete(svc.close())
+    loop.close()
+
+
+_PAIRS = {
+    # id -> (algorithm, limit, burst, an RPC before the drain, the pair)
+    "a-spend-and-a-peek": (0, 10, 0, None, (1, 0)),
+    "a-token-bucket-at-zero": (0, 2, 0, (2,), (1, 1)),
+    "a-leaky-bucket-created-then-over-asked": (1, 10, 100, None, (1, 400)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAIRS))
+def test_one_pair_among_5000_checks_rides_the_drains_rounds(lane4096, case):
+    """Five RPCs of 1,000 checks in ONE drain, 4,999 keys, one of them
+    twice: the drain has two rounds anyway, so the pair's second
+    occurrence takes a lane of round two.  Every answer is
+    core/pymodel.py's; the merge is a plain one -- no lane.cascade, the
+    wait for the device on the fetch stage -- and lane.pack says so."""
+    from gubernator_tpu.core.pymodel import PyRateLimiter
+    from gubernator_tpu.net.grpc_api import reqs_from_pb
+    from gubernator_tpu.proto import gubernator_pb2 as pb
+
+    loop, fp, clk = lane4096
+    algo, limit, burst, before, pair = _PAIRS[case]
+    oracle = PyRateLimiter(clock=clk)
+
+    def rows():
+        return fp._stages.debug_vars()["mach"]
+
+    def req(key, hits, a=algo, lim=limit, b=burst):
+        return pb.RateLimitReq(name="pair5k", unique_key=key, hits=hits,
+                               limit=lim, duration=3_600_000, burst=b,
+                               algorithm=a)
+
+    def rpc(i):
+        reqs = [req(f"{case}.{i}.{j}", j % 3, a=j % 2, lim=10, b=0)
+                for j in range(1000)]
+        if i == 0:
+            # The pair, far apart in the drain's first RPC.
+            reqs[3], reqs[900] = req(case, pair[0]), req(case, pair[1])
+        return reqs
+
+    async def ask(reqs):
+        out = await fp.check_raw(
+            pb.GetRateLimitsReq(requests=reqs).SerializeToString(),
+            peer_rpc=False)
+        return pb.GetRateLimitsResp.FromString(out).responses
+
+    def check(got, reqs, where):
+        for j, (g, r) in enumerate(zip(got, reqs_from_pb(reqs))):
+            w = oracle.get_rate_limit(r)
+            assert (g.error, g.status, g.limit, g.remaining,
+                    g.reset_time) == (
+                "", int(w.status), w.limit, w.remaining, w.reset_time
+            ), (where, j)
+
+    seen = []
+    inner = fp._mach._process
+
+    def spy(entries):
+        res = inner(entries)
+        seen.append((len(entries), res.__name__,
+                     rows()["d2h_wait"]["count"]))
+        return res
+
+    async def scenario():
+        if before:
+            reqs = [req(case, h) for h in before]
+            check(await ask(reqs), reqs, "before")
+        r0 = rows()
+        mach = fp._mach
+        mach._process = spy
+        # Hold the dispatch slot until all five RPCs are queued: they
+        # leave it as one merge.
+        await mach._dispatch_sem.acquire()
+        try:
+            rpcs = [rpc(i) for i in range(5)]
+            tasks = [asyncio.ensure_future(ask(r)) for r in rpcs]
+            while len(mach._waits) < 5 or not mach._queue.empty():
+                await asyncio.sleep(0.001)
+        finally:
+            mach._dispatch_sem.release()
+        answers = await asyncio.gather(*tasks)
+        mach._process = inner
+        r1 = rows()
+        for i, (got, reqs) in enumerate(zip(answers, rpcs)):
+            check(got, reqs, i)
+        for reqs in ([req(case, 0)], [req(case, 1)]):
+            check(await ask(reqs), reqs, "after")
+        return r0, r1
+
+    r0, r1 = loop.run_until_complete(scenario())
+
+    def d(stage, k="count"):
+        return r1[stage].get(k, 0) - r0[stage].get(k, 0)
+
+    # One drain of five entries, handed back as the plain continuation
+    # before anything waited for the device.
+    assert seen == [(5, "fetch_plain", r0["d2h_wait"]["count"])]
+    assert d("drain") == d("pack") == d("d2h_wait") == 1
+    assert d("cascade") == 0 and d("cascade", "occ") == 0
+    assert (d("pack", "dup_plain"), d("pack", "dup_lanes")) == (1, 1)
+    assert fp.fallbacks == 0
 
 
 def test_multinode_columnar_routing():

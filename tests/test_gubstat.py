@@ -429,8 +429,10 @@ def test_debug_vars_schema_golden(stats_cluster):
     assert {"wire", "mach", "xla"} <= set(v["stages"])
     assert {"handler", "ingress", "egress", "wake", "empty",
             "occupied"} <= set(v["stages"]["wire"])
+    # (the machinery lane's pack row with its two counters, there at zero
+    # from the start: docs/tracing.md, "When a drain cascades")
     assert set(v["stages"]["mach"]["pack"]) == {
-        "count", "ms_total", "ms_max",
+        "count", "ms_total", "ms_max", "dup_plain", "dup_lanes",
     }
     # Where the daemon runs, as JAX reports it (tests are held to the
     # CPU; chip_smoke.py requires "tpu" here).

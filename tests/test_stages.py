@@ -386,9 +386,11 @@ def test_identities_and_views_on_a_daemon():
 
 
 def test_the_cascade_row_counts_its_groups_and_peeks():
-    """120 RPCs, 8 in flight, six checks each on three hot keys, every
-    other check a peek: every drain holds duplicate groups with peeks in
-    them and the host cascade replays them.  The lane.cascade row says
+    """120 RPCs, 8 in flight, nine checks each on three hot keys, every
+    other check a peek: every drain holds duplicate groups of three or
+    more with peeks in them — a round an occurrence would be three
+    launches, so the host cascade replays them (a PAIR would ride the
+    rounds: fastpath._cascade_or_rounds).  The lane.cascade row says
     so — groups replayed, their occurrences, the peeks among those —
     and the per-drain identity closes on such drains as on any other."""
     from gubernator_tpu.testing.cluster import Cluster
@@ -400,13 +402,13 @@ def test_the_cascade_row_counts_its_groups_and_peeks():
                 hits=(i + j) % 2, limit=1_000_000, duration=60_000,
                 algorithm=j % 3 % 2,
             )
-            for j in range(6)
+            for j in range(9)
         ]).SerializeToString()
 
     cluster = Cluster.start(1)
     try:
         d = cluster.daemon_at(0)
-        _drive(cluster, d, payload, 120, 6)
+        _drive(cluster, d, payload, 120, 9)
         stages = d.metrics.stages.debug_vars()
         drains = d.fastpath.debug_vars()["lanes"]["mach"]["drains"]
         assert d.fastpath.fallbacks == 0
@@ -415,12 +417,14 @@ def test_the_cascade_row_counts_its_groups_and_peeks():
 
     mach = stages["mach"]
     row = mach["cascade"]
-    # Every RPC holds each of its three keys twice, a peek and a spend:
-    # every drain is a cascade merge of three groups.
+    # Every RPC holds each of its three keys three times, peek and spend
+    # in turn (five peeks where i is even, four where odd): every drain is
+    # a cascade merge of three groups, and none went plain.
     assert row["count"] == drains == mach["drain"]["count"] > 0
     assert row["groups"] == 3 * drains
-    assert row["occ"] == 120 * 6
-    assert row["peeks"] == 120 * 3
+    assert row["occ"] == 120 * 9
+    assert row["peeks"] == 60 * 5 + 60 * 4
+    assert (mach["pack"]["dup_plain"], mach["pack"]["dup_lanes"]) == (0, 0)
     assert mach["pack"]["count"] == mach["unpack"]["count"] == drains
     drain = mach["drain"]["ms_total"]
     parts = _ms(stages, "mach", *_DRAIN_PARTS)
