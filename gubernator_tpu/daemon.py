@@ -362,6 +362,11 @@ class Daemon:
 
         self.flightrec = recorder_from_config(self.conf, self.metrics)
         self.metrics.flightrec = self.flightrec
+        if self.flightrec is not None:
+            self.flightrec.extras["stalls"] = tracing.stalls
+        # The loop's heartbeat (the stage ledger's host.loop_lag): one
+        # task a daemon, always on, flight recorder or not.
+        self._heartbeat: Optional[asyncio.Task] = None
         # gubload phase attribution (loadgen/engine.py PhaseTracker):
         # {"scenario", "phase", "seq", "since"} while a load-scenario
         # phase is driving this node, None otherwise.
@@ -467,6 +472,10 @@ class Daemon:
         )
         if self.flightrec is not None:
             self.flightrec.start()
+        if self._heartbeat is None:
+            self._heartbeat = asyncio.ensure_future(
+                self.metrics.stages.heartbeat()
+            )
         t_warm = time.monotonic()
         self.service = Service(
             cfg,
@@ -683,11 +692,23 @@ class Daemon:
             await self.service.close()
         if self.flightrec is not None:
             await self.flightrec.close()
+        if self._heartbeat is not None:
+            self._heartbeat.cancel()
+            await asyncio.gather(self._heartbeat, return_exceptions=True)
+            self._heartbeat = None
         # The served path's budget over this daemon's life, for whoever
         # reads the log after /debug/vars is gone.
         log.info(
             "stage ledger at close: %s",
             json.dumps(self.metrics.stages.debug_vars(), sort_keys=True),
+        )
+        log.info(
+            "stalls at close: %s",
+            json.dumps({
+                "stalls": tracing.stalls(),
+                "threads": tracing.thread_vars(),
+                "process": tracing.process_vars(),
+            }, sort_keys=True),
         )
 
     # -- HTTP gateway (daemon.go:231-270) --------------------------------
@@ -763,6 +784,10 @@ class Daemon:
         )
 
     async def _http_metrics(self, request: web.Request):
+        with self.metrics.stages.stage("host.scrape", "host"):
+            return self._render_metrics(request)
+
+    def _render_metrics(self, request: web.Request):
         # Refresh device gauges at scrape time.
         if self.service is not None:
             self.metrics.device_occupancy.set(
@@ -861,6 +886,10 @@ class Daemon:
     async def _http_vars(self, request: web.Request):
         """expvar-style internal counters (the Go daemon exposes
         /debug/vars via expvar; these are the TPU engine's equivalents)."""
+        with self.metrics.stages.stage("host.scrape", "host"):
+            return web.json_response(self._vars())
+
+    def _vars(self) -> dict:
         out = {
             "grpc_address": self.grpc_address,
             "http_address": self.http_address,
@@ -961,6 +990,12 @@ class Daemon:
         # ms_max of every step of the served path, by lane
         # (docs/observability.md).
         out["stages"] = self.metrics.stages.debug_vars()
+        # Why a stage took that long (docs/observability.md): the leaf
+        # instances far over their row's mean, with their times; every
+        # Python thread's CPU clock; the whole process's.
+        out["stalls"] = tracing.stalls()
+        out["threads"] = tracing.thread_vars()
+        out["process"] = tracing.process_vars()
         # Attribution plane (runtime/tracing.py): enabled, sampler,
         # honest exporter status, spans started/exported/dropped.
         out["tracing"] = tracing.debug_vars()
@@ -976,7 +1011,7 @@ class Daemon:
             }
         if self.load_status is not None:
             out["load"] = dict(self.load_status)
-        return web.json_response(out)
+        return out
 
     @staticmethod
     def _cache_item_json(item) -> Optional[dict]:

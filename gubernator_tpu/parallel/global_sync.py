@@ -40,7 +40,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -386,7 +386,6 @@ class GlobalEngine:
         self.syncs = 0
         self.sync_keys = 0
         self.dropped = 0
-        self.sync_bytes_accessed: Optional[float] = None  # set by warmup
         # Post-sync hook: called with the synced pending dict (may run on a
         # device-executor thread).  The service uses it to bridge collective
         # syncs to the RPC tier — broadcasting owner-authoritative statuses
@@ -787,27 +786,10 @@ class GlobalEngine:
                 self.cache_table, _ = self._ingest(
                     self.cache_table, batch, now
                 )
-            self.sync_bytes_accessed = self._sync_cost(sharded, now)
-
-    def _sync_cost(self, sharded: DeltaGrid, now) -> Optional[float]:
-        """`bytes accessed` of the sync program as compiled for one
-        device, from the compiler's own cost analysis: what one chunk's
-        launch moves through HBM (for a roofline share; /debug/vars
-        `global.engine`).  Lowered with the arguments the tick passes, so
-        the compile is the served program's and a compile-cache hit.
-        None where the compiler does not say."""
-        try:
-            cost = self._sync_step.lower(
-                self.b.table, self.cache_table, sharded, now
-            ).compile().cost_analysis()
-            cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-            return float(cost["bytes accessed"])
-        except (AttributeError, TypeError, KeyError, IndexError):
-            return None
 
     def debug_vars(self) -> dict:
         """The /debug/vars `global.engine` block: the tick's counts, and
-        the geometry and compiled cost of one chunk's sync program."""
+        the geometry of one chunk's sync program."""
         with self._lock:
             out = {
                 "syncs": self.syncs,
@@ -819,7 +801,6 @@ class GlobalEngine:
             "collective": self.collective,
             "shards": self.n,
             "delta_slots": self.delta_slots,
-            "bytes_accessed": self.sync_bytes_accessed,
         }
         return out
 

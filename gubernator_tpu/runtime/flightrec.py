@@ -19,15 +19,14 @@ off the hot path:
    dumps a JSON snapshot to disk.  A check-error storm (error count in
    the trailing window over `error_storm`) triggers the same dump.
 
-3. **Event-loop lag sampling** — the production port of raceguard's
-   stall detector (testing/raceguard.py times Handle._run by patching
-   asyncio internals; a daemon cannot).  Here a periodic task measures
-   how late its own wakeup fires: `lag = now - (t0 + interval)`.  Any
-   single callback that hogs the loop delays the wakeup by its runtime,
-   so the sample is a faithful lower bound on the worst stall in the
-   tick — with zero patching and one timer per daemon.  Exposed as
-   `gubernator_event_loop_lag_seconds`; samples over `stall_ms` land in
-   the ring.
+3. **Event-loop lag** — the production port of raceguard's stall
+   detector (testing/raceguard.py times Handle._run by patching asyncio
+   internals; a daemon cannot).  The recorder does not time the loop
+   itself: the daemon's heartbeat does, always on, as the stage ledger's
+   `host.loop_lag` (runtime/tracing.py `StageLedger.heartbeat`), and
+   `gubernator_event_loop_lag_seconds`, `loop_lag_ms` and the
+   `loop_stall` records (samples over `stall_ms`) are views of that row
+   (`note_loop_lag`).  A dump also carries the ledger's `stalls` ring.
 
 On breach it can also start a time-boxed `jax.profiler` trace
 (`profile_secs` > 0) so the host-side records line up with XLA traces —
@@ -192,6 +191,15 @@ class FlightRecorder:
         dump can name the slowest traces in its window."""
         self._lat.append((time.monotonic(), duration_s, trace_id))
 
+    def note_loop_lag(self, lag_s: float) -> None:
+        """One sample of the stage ledger's host.loop_lag (the daemon's
+        heartbeat; Metrics._on_loop_lag)."""
+        lag_ms = lag_s * 1e3
+        self.last_lag_ms = lag_ms
+        self.max_lag_ms = max(self.max_lag_ms, lag_ms)
+        if lag_ms > self.stall_ms:
+            self.record("loop_stall", lag_ms=round(lag_ms, 1))
+
     def note_error(self, n: int = 1) -> None:
         now = time.monotonic()
         for _ in range(min(n, 64)):  # storm detection, not exact counting
@@ -296,19 +304,8 @@ class FlightRecorder:
         self._stop_profiler()
 
     async def _run(self) -> None:
-        loop = asyncio.get_running_loop()
-        interval = self.sample_interval_s
         while True:
-            t0 = loop.time()
-            await asyncio.sleep(interval)
-            lag = max(0.0, loop.time() - t0 - interval)
-            lag_ms = lag * 1e3
-            self.last_lag_ms = lag_ms
-            self.max_lag_ms = max(self.max_lag_ms, lag_ms)
-            if self.metrics is not None:
-                self.metrics.loop_lag.set(lag)
-            if lag_ms > self.stall_ms:
-                self.record("loop_stall", lag_ms=round(lag_ms, 1))
+            await asyncio.sleep(self.sample_interval_s)
             reason = self.evaluate()
             if reason is not None:
                 try:

@@ -41,6 +41,7 @@ from gubernator_tpu.ops.state import (
     SHADOW_PLANES,
     TableStats,
 )
+from gubernator_tpu.runtime import tracing
 
 log = logging.getLogger("gubernator.gubstat")
 
@@ -389,10 +390,17 @@ class TableStatsSampler:
         grid = self._shadow_grid()
         loop = asyncio.get_running_loop()
 
-        def dispatch():
-            return backend.table_stats_dispatch(grid)
+        stages = tracing.ledger_of(self.metrics)
 
-        fetch = await loop.run_in_executor(None, dispatch)
+        def dispatch():
+            with stages.stage("host.census_dispatch", "host"):
+                return backend.table_stats_dispatch(grid)
+
+        def fetch():
+            with stages.stage("host.census_fetch", "host"):
+                return fetched()
+
+        fetched = await loop.run_in_executor(None, dispatch)
         st = await loop.run_in_executor(None, fetch)
         block = self._publish(st, grid)
         return block

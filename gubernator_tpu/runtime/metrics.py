@@ -503,8 +503,8 @@ class Metrics:
         )
         self.loop_lag = Gauge(
             "gubernator_event_loop_lag_seconds",
-            "Latest event-loop lag sample (scheduling delay of the "
-            "flight recorder's periodic tick).",
+            "Latest event-loop lag sample (how late the daemon's "
+            "heartbeat woke: the stage ledger's host.loop_lag).",
             registry=r,
         )
         self.flightrec_dump_total = Counter(
@@ -577,6 +577,10 @@ class Metrics:
         self.stages.observe(
             "backend.dispatch", self.device_step_duration.observe
         )
+        # What shares the process with the served path: lane `host`'s
+        # rows at zero from start-up.
+        self.stages.register("host", tracing.HOST_STAGES)
+        self.stages.observe("host.loop_lag", self._on_loop_lag, "host")
         self.device_occupancy = Gauge(
             "gubernator_tpu_slot_occupancy",
             "Occupied slots in the device table.",
@@ -741,6 +745,14 @@ class Metrics:
         fr = self.flightrec
         if fr is not None:
             fr.note_error(n)
+
+    def _on_loop_lag(self, lag_s: float) -> None:
+        """The ledger's host.loop_lag, as the gauge and the flight
+        recorder's lag readings."""
+        self.loop_lag.set(lag_s)
+        fr = self.flightrec
+        if fr is not None:
+            fr.note_loop_lag(lag_s)
 
     def render(self) -> bytes:
         """Text exposition for the /metrics endpoint."""

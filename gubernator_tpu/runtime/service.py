@@ -620,7 +620,8 @@ class Service:
         fallback never observes the same requests twice."""
         hk = self.hotkeys
         if hk is not None and len(key_hashes):
-            hk.observe(key_hashes, hits)
+            with self.metrics.stages.stage("host.hotkey", "host"):
+                hk.observe(key_hashes, hits)
         tier = self.tier
         if tier is not None and len(key_hashes):
             # Promote-on-access (docs/tiering.md): a served key that is

@@ -44,13 +44,26 @@ the thread that ended it), and — only when the span plane above is
 armed and the parent sampled — a `Span`.  It has no switch: the cells
 cost a clock read and three integer adds, the annotation one atomic
 check while no profiler session is active (docs/tracing.md).
+
+The same ledger says WHY a stage took that long: what shares the
+process with the served path has rows of its own on lane `host` (the
+collector, the census, the hot-key sketch, the scrapes, the loop's lag);
+`/debug/vars` `threads` and `process` say who had the CPU — every Python
+thread's own CPU clock, read at render and never on the hot path: a
+pool's CPU beside the wall of the sections it ran is how much of that
+wall it was running, the rest it waited for the GIL or a core; and an
+instance of a leaf row far over its row's mean is kept with its times
+in the `stalls` ring.
 """
 from __future__ import annotations
 
+import asyncio
 import contextlib
 import contextvars
+import gc
 import logging
 import os
+import re
 import sys
 import threading
 import time
@@ -735,7 +748,49 @@ STAGES: Dict[str, str] = {
     "global.sync_step": "inside a tick, under both locks: the enqueue of "
                         "one chunk's sync program",
     "xla.compile": "backend compiles seen by jax.monitoring (count, ms)",
+    # what shares the process with the served path (lane `host`)
+    "host.gc": "one collection of the garbage collector, gc.callbacks "
+               "start -> stop, on whichever thread collected; counter "
+               "gen2 (full collections: the long pauses)",
+    "host.census_dispatch": "the gubstat census, an executor thread: "
+                            "table_stats_dispatch entry -> return (the wait "
+                            "for backend._lock and the enqueue under it)",
+    "host.census_fetch": "the census: the executor's wait for the result "
+                         "on the host",
+    "host.hotkey": "service.note_traffic -> hotkey.observe, on the loop "
+                   "inside wire.ingress",
+    "host.scrape": "the /metrics and /debug/vars handlers, entry -> return, "
+                   "on the loop",
+    "host.loop_lag": "the daemon's heartbeat: how late a sleep of "
+                     "LOOP_LAG_INTERVAL_S wakes (feeds "
+                     "gubernator_event_loop_lag_seconds)",
+    "host.stall": "the instances of leaf rows kept in the stalls ring "
+                  "(count, and their wall)",
 }
+
+# A LEAF has both ends on one thread (or is the heartbeat's lag): only
+# its instances can be stalls.  Every other stage is a wait across an
+# await or a thread hand-off, or holds leaves across them (wire.handler,
+# lane.drain), and a stall inside it is its leaf's.  (host.hotkey runs
+# inside wire.ingress, host.gc inside whatever allocated: a stall there
+# is an entry of both.)
+LEAVES = frozenset((
+    "wire.ingress", "wire.egress",
+    "lane.pack", "lane.cascade", "lane.unpack",
+    "backend.lock_wait", "backend.dispatch", "backend.d2h_wait",
+    "peer.route", "peer.splice", "peer.assemble",
+    "global.sync_tick", "global.build_chunks", "global.wait_locks",
+    "global.sync_step",
+    "host.gc", "host.census_dispatch", "host.census_fetch", "host.hotkey",
+    "host.scrape", "host.loop_lag",
+))
+# An instance of a leaf row that took at least STALL_MIN_NS and
+# STALL_FACTOR x its row's mean so far is a stall: kept, with its times,
+# in a ring of STALL_RING.
+STALL_MIN_NS = 20_000_000
+STALL_FACTOR = 8
+STALL_RING = 256
+LOOP_LAG_INTERVAL_S = 0.25
 
 # What a daemon's raw handlers and a coalescer lane time: the rows they
 # create at start-up (StageLedger.register).
@@ -751,6 +806,12 @@ PEER_STAGES = tuple(s for s in STAGES if s.startswith("peer."))
 PEER_FORWARD_COUNTERS = (
     "checks", "timeouts", "reasked", "joined", "retried", "refused",
 )
+# Lane `host`'s rows, at zero from a daemon's start-up (Metrics);
+# host.gc and host.stall are the process's (_GC, _STALL below).
+HOST_STAGES = tuple(
+    s for s in STAGES
+    if s.startswith("host.") and s not in ("host.gc", "host.stall")
+)
 
 _TRACE_ME = None  # jax.profiler.TraceAnnotation, resolved on first use
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -762,7 +823,7 @@ class _Cell:
     stage on two pool threads)."""
 
     __slots__ = ("lane", "stage", "trace_name", "count", "ns_total",
-                 "ns_max", "observe", "counters")
+                 "ns_max", "max_at_ns", "leaf", "observe", "counters")
 
     def __init__(self, lane: str, stage: str, observe=None) -> None:
         if stage not in STAGES:
@@ -773,19 +834,40 @@ class _Cell:
         self.count = 0
         self.ns_total = 0
         self.ns_max = 0
+        self.max_at_ns = 0  # epoch: the end of the longest instance
+        self.leaf = stage in LEAVES
         self.observe = observe
         # Named whole-number counters of what the stage worked on (keys
         # a tick flushed, checks a drain packed): rendered beside count.
         self.counters: Dict[str, int] = {}
 
-    def add(self, ns: int) -> None:
-        self.count += 1
-        self.ns_total += ns
+    def add(self, ns: int) -> bool:
+        """Count one instance; True where it is a stall."""
+        n, total = self.count, self.ns_total
+        self.count = n + 1
+        self.ns_total = total + ns
         if ns > self.ns_max:
             self.ns_max = ns
+            self.max_at_ns = time.time_ns()
+        return (
+            ns >= STALL_MIN_NS and self.leaf
+            and 0 < STALL_FACTOR * total <= ns * n
+        )
+
+    def row(self) -> Dict:
+        """The row as /debug/vars renders it."""
+        out = {
+            "count": self.count,
+            "ms_total": round(self.ns_total / 1e6, 6),
+            "ms_max": round(self.ns_max / 1e6, 6),
+            "max_at_ms": self.max_at_ns // 1_000_000,
+        }
+        out.update(self.counters)
+        return out
 
 
-_RESERVED = frozenset(("count", "ms_total", "ms_max"))  # a row's own keys
+# A row's own keys
+_RESERVED = frozenset(("count", "ms_total", "ms_max", "max_at_ms"))
 _COMPILES = _Cell("xla", "xla.compile")  # process-wide, lock-free reads
 
 
@@ -794,10 +876,64 @@ def _on_jax_duration(event: str, duration_secs: float, **_kw) -> None:
         _COMPILES.add(int(duration_secs * 1e9))
 
 
+# The collector is the process's, and a collection can start on any
+# thread at any moment, also inside a ledger's `_lock`, which is not
+# re-entrant: its row is a cell of its own, written without that lock
+# (collections do not overlap), and so are the stalls — the row
+# host.stall and the ring — which a collection has to reach too.  Every
+# ledger renders them, as it does _COMPILES.
+_GC = _Cell("host", "host.gc")
+_GC.counters["gen2"] = 0
+_STALL = _Cell("host", "host.stall")
+_STALLS: deque = deque(maxlen=STALL_RING)
+_gc_open: Optional[Tuple] = None  # (TraceMe, wall ns)
+
+
+def _note_stall(cell: _Cell, ns: int) -> None:
+    """One entry of the stalls ring, taken as the instance ends."""
+    end_ms = time.time_ns() / 1e6
+    _STALL.add(ns)
+    _STALLS.append({
+        "t_start_ms": round(end_ms - ns / 1e6, 3),
+        "t_end_ms": round(end_ms, 3),
+        "lane": cell.lane,
+        "stage": cell.stage.split(".", 1)[1],
+        "ms": round(ns / 1e6, 3),
+        "thread": threading.current_thread().name,
+    })
+
+
+def stalls() -> List[Dict]:
+    """The /debug/vars `stalls` block: the process's last STALL_RING
+    instances of leaf rows that took STALL_MIN_NS and STALL_FACTOR x
+    their row's mean so far — (t_start_ms, t_end_ms) on the epoch clock,
+    lane, stage, ms and the thread — oldest first.  Overlapping them by
+    time says what a stall shared the process with."""
+    return list(_STALLS)
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    global _gc_open
+    if phase == "start":
+        tm = _TRACE_ME(_GC.trace_name)
+        tm.__enter__()
+        _gc_open = (tm, time.perf_counter_ns())
+    elif _gc_open is not None:
+        tm, t0 = _gc_open
+        _gc_open = None
+        ns = time.perf_counter_ns() - t0
+        tm.__exit__(None, None, None)
+        if info["generation"] == 2:
+            _GC.counters["gen2"] += 1
+        if _GC.add(ns):
+            _note_stall(_GC, ns)
+
+
 def _trace_me():
     """The profiler's TraceMe class, imported the first time a stage is
     timed (a process that times none — the load generator — never
-    imports JAX); the compile listener is installed with it."""
+    imports JAX); the compile listener and the collector's callback are
+    installed with it."""
     global _TRACE_ME
     if _TRACE_ME is None:
         import jax.monitoring
@@ -807,6 +943,7 @@ def _trace_me():
             _on_jax_duration
         )
         _TRACE_ME = TraceAnnotation
+        gc.callbacks.append(_on_gc)
     return _TRACE_ME
 
 
@@ -944,7 +1081,9 @@ class StageLedger:
 
     def _add(self, cell: _Cell, ns: int) -> None:
         with self._lock:
-            cell.add(ns)
+            stall = cell.add(ns)
+        if stall:
+            _note_stall(cell, ns)
         if cell.observe is not None:
             cell.observe(ns / 1e9)
 
@@ -974,6 +1113,18 @@ class StageLedger:
         if lane is None:
             lane = _scope.get()[1]
         return _Open(self, self.cell(lane, stage), parent, False, False)
+
+    async def heartbeat(self) -> None:
+        """host.loop_lag: how late a sleep of LOOP_LAG_INTERVAL_S wakes.
+        Any callback that holds the loop delays the wake-up by as long,
+        so a sample is a lower bound on the worst stall of its interval.
+        One task a daemon, always on (Daemon.start)."""
+        cell = self.cell("host", "host.loop_lag")
+        due = int(LOOP_LAG_INTERVAL_S * 1e9)
+        while True:
+            t0 = time.perf_counter_ns()
+            await asyncio.sleep(LOOP_LAG_INTERVAL_S)
+            self._add(cell, max(0, time.perf_counter_ns() - t0 - due))
 
     # -- the wire.empty / wire.occupied state clock --------------------------
     def rpc_enter(self) -> None:
@@ -1005,41 +1156,89 @@ class StageLedger:
 
     def debug_vars(self) -> Dict:
         """The /debug/vars `stages` block:
-        stages.<lane>.<stage>.{count, ms_total, ms_max} and the stage's
-        named counters.  The open empty/occupied interval is counted up
-        to now."""
+        stages.<lane>.<stage>.{count, ms_total, ms_max, max_at_ms} and
+        the stage's named counters.  The open empty/occupied interval is
+        counted up to now."""
         with self._lock:
-            rows = [
-                (c.lane, c.stage, c.count, c.ns_total, c.ns_max)
-                for c in self._cells.values()
-            ]
-            tallies = {
-                (c.lane, c.stage): dict(c.counters)
-                for c in self._cells.values() if c.counters
+            rows = {
+                (c.lane, c.stage): c.row() for c in self._cells.values()
             }
-        rows.append((
-            _COMPILES.lane, _COMPILES.stage, _COMPILES.count,
-            _COMPILES.ns_total, _COMPILES.ns_max,
-        ))
+        for c in (_COMPILES, _GC, _STALL):
+            rows[c.lane, c.stage] = c.row()
         since, rpcs = self._since, self._rpcs
         if since is not None:
             state = "wire.occupied" if rpcs else "wire.empty"
-            open_ns = max(0, time.perf_counter_ns() - since)
-            for i, (lane, stage, n, tot, mx) in enumerate(rows):
-                if lane == "wire" and stage == state:
-                    rows[i] = (lane, stage, n, tot + open_ns, max(mx, open_ns))
-                    break
-            else:
-                rows.append(("wire", state, 0, open_ns, open_ns))
+            open_ms = max(0, time.perf_counter_ns() - since) / 1e6
+            row = rows.setdefault(
+                ("wire", state), _Cell("wire", state).row()
+            )
+            row["ms_total"] = round(row["ms_total"] + open_ms, 6)
+            if open_ms > row["ms_max"]:
+                row["ms_max"] = round(open_ms, 6)
+                row["max_at_ms"] = time.time_ns() // 1_000_000
         out: Dict[str, Dict] = {}
-        for lane, stage, n, tot, mx in rows:
-            out.setdefault(lane, {})[stage.split(".", 1)[1]] = {
-                "count": n,
-                "ms_total": round(tot / 1e6, 6),
-                "ms_max": round(mx / 1e6, 6),
-                **tallies.get((lane, stage), {}),
-            }
+        for (lane, stage), row in rows.items():
+            out.setdefault(lane, {})[stage.split(".", 1)[1]] = row
         return out
+
+
+# Who had the CPU.  A thread's CPU clock is read from outside it by the
+# kernel's id for that clock — (~tid << 3) | 6, what pthread_getcpuclockid
+# returns for a live thread (tests/test_stages.py) — computed from
+# Thread.native_id: a thread that has ended makes clock_gettime fail
+# cleanly, where pthread_getcpuclockid would read its freed handle.
+_THREAD_CPU_NS: Dict[int, Tuple[str, int]] = {}  # live: tid -> name, ns
+_ENDED_CPU_NS: Dict[str, int] = {}  # name -> CPU of its ended threads
+_FAMILY = re.compile(r"[A-Za-z0-9]+(?:-[A-Za-z]+)?")
+
+
+def thread_vars() -> Dict[str, Dict[str, Dict[str, float]]]:
+    """The /debug/vars `threads` block:
+    threads.<family>.<name>.cpu_ms of every Python thread of the
+    process, read at render from the threads' own CPU clocks; nothing on
+    the hot path.  A family is the first two words of a name: the
+    coalescer's pools are `tpu-fastlane` (`tpu-fastlane_0`,
+    `tpu-fastlane-engine_0`), the executor's threads `asyncio`, the loop
+    `MainThread`.  Over a window the block's sum ÷ wall near 1.0 says
+    the GIL is full; `tpu-fastlane`'s ÷ the wall of the sections its
+    threads ran says how much of that wall they were running.  A thread
+    that has ended keeps its last reading under its name, so no sum
+    falls.  Empty off Linux."""
+    if not sys.platform.startswith("linux"):
+        return {}
+    seen = {}
+    for t in threading.enumerate():
+        tid = t.native_id
+        if tid is None:  # not started yet
+            continue
+        try:
+            seen[tid] = (t.name, time.clock_gettime_ns((~tid << 3) | 6))
+        except OSError:  # ended since enumerate()
+            continue
+    for tid, (name, was) in list(_THREAD_CPU_NS.items()):
+        now = seen.get(tid)
+        # Ended: gone, or its clock has restarted (the id serves a new
+        # thread already).
+        if now is None or now[1] < was:
+            if _THREAD_CPU_NS.pop(tid, None) is not None:
+                _ENDED_CPU_NS[name] = _ENDED_CPU_NS.get(name, 0) + was
+    _THREAD_CPU_NS.update(seen)
+    total = dict(_ENDED_CPU_NS)
+    for name, ns in list(_THREAD_CPU_NS.values()):
+        total[name] = total.get(name, 0) + ns
+    out: Dict[str, Dict[str, Dict[str, float]]] = {}
+    for name, ns in total.items():
+        m = _FAMILY.match(name)
+        family = m.group(0) if m else name
+        out.setdefault(family, {})[name] = {"cpu_ms": round(ns / 1e6, 6)}
+    return out
+
+
+def process_vars() -> Dict[str, float]:
+    """The /debug/vars `process` block: the whole process's CPU time, the
+    XLA runtime's and gRPC's own threads included; less the `threads`
+    block's sum it is what the runtimes burn beside Python."""
+    return {"cpu_ms": round(time.process_time_ns() / 1e6, 6)}
 
 
 # The ambient (ledger, lane): what a stage placed in code that serves

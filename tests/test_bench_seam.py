@@ -64,11 +64,16 @@ CODE_VARS = [
     ("backend.not_persisted", "number"),          # bench/run.py:526
     ("backend.occupancy", "number"),              # bench/run.py:527
     ("backend.checks", "number"),                 # bench/run.py:580
-    # bench/readers/global_sync_roofline_share.mesh.py:22
-    ("global.engine.sync_program.bytes_accessed", "number"),
+    # bench/readers/global_sync_roofline_share.mesh.py:26 (the compiled
+    # program's `bytes_accessed` went with PR 41: the reader counts from
+    # the work since PR 31 and reads these two alone)
     ("global.engine.sync_program.shards", "number"),
     ("global.engine.sync_program.delta_slots", "number"),
     ("stages", "block"),       # bench/readers/idle_named_share.open.py:15
+    # bench/readers/step_hbm_share.closed.py: lanes = checks - occ + groups
+    # (there once a drain has cascaded: the seam's RPCs hold keys thrice)
+    ("stages.mach.cascade.occ", "number"),
+    ("stages.mach.cascade.groups", "number"),
 ]
 
 # Jitted programs the traced metrics find by name
@@ -189,6 +194,15 @@ class _Seam:
                     ).SerializeToString())
                     resp = pb.GetRateLimitsResp.FromString(raw)
                     assert not any(r.error for r in resp.responses)
+                # One key three times, alone in its drain: three rounds
+                # plain, a read lane and a write-back cascaded — the drain
+                # cascades (fastpath._cascade_or_rounds).
+                raw = await rpc(pb.GetRateLimitsReq(requests=[
+                    pb.RateLimitReq(name="seam", unique_key="thrice",
+                                    hits=1, limit=100, duration=60_000)
+                ] * 3).SerializeToString())
+                resp = pb.GetRateLimitsResp.FromString(raw)
+                assert [r.remaining for r in resp.responses] == [99, 98, 97]
             finally:
                 await ch.close()
 
@@ -277,6 +291,15 @@ def test_layer_metrics_were_found():
     # The parse above is the test's own; if the files change form it
     # must fail here and not pass on nothing.
     assert len(LAYER_VARS) >= 29 and len(LAYER_SERIES) >= 2
+
+
+def test_the_seams_daemon_has_cascaded(one_chip):
+    """What `step_hbm_share.closed` subtracts: groups the host replayed
+    and the occurrences they held, on a daemon whose drains held a key
+    three times (left open by PR 29-31: PERF.md section 7)."""
+    row = one_chip.vars["stages"]["mach"]["cascade"]
+    assert row["count"] > 0
+    assert row["occ"] >= 3 * row["groups"] > 0
 
 
 # -- (b) private names ----------------------------------------------------
@@ -420,6 +443,51 @@ def test_peer_hop_metric_reads_a_live_routed_daemon(routed, name):
             assert len(nodes) == 1 and _is_number(nodes[0]), (term, nodes)
             total[side] += nodes[0]
     assert total["num"] > 0 and total["den"] > 0, (name, total)
+
+
+# -- (e) why a stage took that long (PR 41) -----------------------------------
+#
+# The fifteen metrics over lane `host` and the `threads` / `process`
+# blocks: every term of every data file finds numbers
+# on a live daemon, and the divisor has grown from zero.
+
+HOST_METRICS = sorted(
+    f.stem for f in (BENCH / "layer_metrics").glob("*.json")
+    if f.stem.rsplit(".", 1)[0] in (
+        "lane_oncpu_share", "host_python_cores",
+        "host_process_cores", "host_gc_share", "host_planes_share",
+        "host_census_ms", "wire_loop_lag_ms", "host_stall_share",
+    )
+)
+
+
+def test_the_host_metrics_were_found():
+    assert len(HOST_METRICS) == 15, HOST_METRICS
+
+
+@pytest.mark.parametrize("name", HOST_METRICS)
+def test_host_metric_reads_a_live_daemon(one_chip, name):
+    read = json.loads(
+        (BENCH / "layer_metrics" / f"{name}.json").read_text())["read"]
+    assert read["kind"] == "ratio" and read["delta"] is True
+    total = {}
+    for side in ("num", "den"):
+        total[side] = 0.0
+        for term in read[side]:
+            assert "vars:stages." not in term    # spelled `*`: PERF.md s.7
+            nodes = _resolve(one_chip.vars, term[len("vars:"):])
+            assert nodes and all(_is_number(n) for n in nodes), (term, nodes)
+            total[side] += sum(nodes)
+    assert total["den"] > 0 and total["num"] >= 0, (name, total)
+    base = name.rsplit(".", 1)[0]
+    if base == "lane_oncpu_share":
+        # the pools' CPU and nobody else's; within the sections' wall but
+        # for the clock's grain and what the pools do between sections
+        assert read["num"] == ["vars:threads.tpu-fastlane.*.cpu_ms"]
+        assert 0 < total["num"] <= 1.5 * total["den"] + 10, total
+    if base in ("host_python_cores", "host_process_cores",
+                "host_planes_share", "host_census_ms"):
+        assert total["num"] > 0, (name, total)
 
 
 def test_the_routed_rpcs_handler_closes_with_the_hop_named(routed):

@@ -422,18 +422,28 @@ def test_debug_vars_schema_golden(stats_cluster):
         "inflight_checks",
         "global", "multi_region_sends", "peers", "circuits", "degraded",
         "hotkeys", "leases", "reshard", "tenants", "table", "fastpath",
-        "stages", "tracing", "flightrec",
+        "stages", "stalls", "threads", "process", "tracing", "flightrec",
     }
     # The stage ledger's block (docs/observability.md): lane -> stage ->
-    # {count, ms_total, ms_max}, a lane's rows there from the start.
-    assert {"wire", "mach", "xla"} <= set(v["stages"])
+    # {count, ms_total, ms_max, max_at_ms}, a lane's rows there from the
+    # start.
+    assert {"wire", "mach", "xla", "host"} <= set(v["stages"])
     assert {"handler", "ingress", "egress", "wake", "empty",
             "occupied"} <= set(v["stages"]["wire"])
     # (the machinery lane's pack row with its two counters, there at zero
     # from the start: docs/tracing.md, "When a drain cascades")
     assert set(v["stages"]["mach"]["pack"]) == {
-        "count", "ms_total", "ms_max", "dup_plain", "dup_lanes",
+        "count", "ms_total", "ms_max", "max_at_ms", "dup_plain",
+        "dup_lanes",
     }
+    assert set(v["stages"]["mach"]["queue_wait"]) == {
+        "count", "ms_total", "ms_max", "max_at_ms",
+    }
+    # Who had the CPU, and the leaf instances far over their row's mean.
+    assert set(v["process"]) == {"cpu_ms"}
+    assert all(set(t) == {"cpu_ms"} for family in v["threads"].values()
+               for t in family.values())
+    assert isinstance(v["stalls"], list)
     # Where the daemon runs, as JAX reports it (tests are held to the
     # CPU; chip_smoke.py requires "tpu" here).
     assert set(v["device"]) == {

@@ -317,7 +317,7 @@ def test_a_peer_rpc_and_a_single_daemon_leave_the_hops_rows_at_zero(ring4):
     try:
         d = single.daemon_at(0)
         rows0 = _stages(d)
-        zero = {"count": 0, "ms_total": 0.0, "ms_max": 0.0}
+        zero = {"count": 0, "ms_total": 0.0, "ms_max": 0.0, "max_at_ms": 0}
         assert rows0["peer"] == {
             "route": zero, "splice": zero, "assemble": zero,
             "forward": dict(zero, checks=0, **dict.fromkeys(HOP_EVENTS, 0)),
@@ -605,7 +605,8 @@ def test_a_peer_that_never_said_it_applies_once_is_not_asked_again(
     grown = {k: v - e0[k] for k, v in _hop(entry).items()}
     assert grown == dict(
         dict.fromkeys(HOP_EVENTS, 0), count=1, checks=5, timeouts=1,
-        refused=1, ms_total=grown["ms_total"], ms_max=grown["ms_max"])
+        refused=1, ms_total=grown["ms_total"], ms_max=grown["ms_max"],
+        max_at_ms=grown["max_at_ms"])
 
 
 @pytest.mark.parametrize("case,want", [

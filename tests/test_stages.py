@@ -15,15 +15,24 @@ Pinned here:
     armed, `fastpath.merge` parents the stage spans;
   * the profiler: a short CPU trace holds `gub.*` events for a wait held
     across an await and for a stage on a pool thread, and the clock
-    anchor's argument can be read back.
+    anchor's argument can be read back;
+  * why a stage took that long (PR 41): the rows of lane `host` (the
+    collector, the census, the hot-key sketch, the scrapes, the loop's
+    lag), the `threads` / `process` blocks (every thread's CPU clock,
+    read from outside at render: a pool's CPU beside its sections' wall),
+    and the stalls ring with its rule.
 """
 from __future__ import annotations
 
 import asyncio
+import gc
 import glob
+import json
 import os
+import threading
 import time
 import types
+import urllib.request
 from concurrent.futures import ThreadPoolExecutor
 
 import pytest
@@ -35,8 +44,12 @@ from gubernator_tpu.runtime.metrics import Metrics
 from gubernator_tpu.testing.tracing import memory_tracing
 
 
+EPOCH_NS = 1_700_000_000_000_000_000
+
+
 class _Clock:
-    """perf_counter_ns under the test's control."""
+    """perf_counter_ns and the epoch clock under the test's control:
+    `now` moves the wall, and the epoch with it."""
 
     def __init__(self) -> None:
         self.now = 1_000
@@ -44,12 +57,15 @@ class _Clock:
     def perf_counter_ns(self) -> int:
         return self.now
 
+    def time_ns(self) -> int:
+        return EPOCH_NS + self.now
+
 
 @pytest.fixture
 def clock(monkeypatch):
     c = _Clock()
     monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
-        perf_counter_ns=c.perf_counter_ns, time_ns=time.time_ns,
+        perf_counter_ns=c.perf_counter_ns, time_ns=c.time_ns,
     ))
     return c
 
@@ -72,7 +88,10 @@ def test_count_total_max(clock):
     assert ledger.totals("mach", "lane.pack") == (3, 57, 40)
     assert ledger.totals("engine", "lane.pack") == (0, 0, 0)
     row = ledger.debug_vars()["mach"]["pack"]
-    assert row == {"count": 3, "ms_total": 57e-6, "ms_max": 40e-6}
+    # The longest instance ended at wall 1,045: `max_at_ms` is that
+    # moment on the epoch clock.
+    assert row == {"count": 3, "ms_total": 57e-6, "ms_max": 40e-6,
+                   "max_at_ms": (EPOCH_NS + 1_045) // 1_000_000}
 
 
 def test_begin_end_pair_is_idempotent_and_returns_ns(clock):
@@ -140,12 +159,14 @@ def test_empty_occupied_state_clock(clock):
     ledger.rpc_exit()                            # occupied 100..300
     clock.now = 450
     wire = ledger.debug_vars()["wire"]
+    at = (EPOCH_NS + 300) // 1_000_000
     assert wire["occupied"] == {
-        "count": 1, "ms_total": 200e-6, "ms_max": 200e-6,
+        "count": 1, "ms_total": 200e-6, "ms_max": 200e-6, "max_at_ms": at,
     }
     # The open interval is counted up to "now", and is not yet a count.
     assert wire["empty"] == {
         "count": 0, "ms_total": 150e-6, "ms_max": 150e-6,
+        "max_at_ms": (EPOCH_NS + 450) // 1_000_000,
     }
     clock.now = 500
     ledger.rpc_enter()                           # empty 300..500 closes
@@ -153,6 +174,7 @@ def test_empty_occupied_state_clock(clock):
     wire = ledger.debug_vars()["wire"]
     assert wire["empty"] == {
         "count": 1, "ms_total": 200e-6, "ms_max": 200e-6,
+        "max_at_ms": (EPOCH_NS + 500) // 1_000_000,
     }
     assert wire["occupied"]["ms_total"] == 230e-6   # 200 + the open 30
     assert wire["occupied"]["count"] == 1
@@ -442,6 +464,7 @@ def test_counters_ride_on_a_stage(clock):
             tick.tally(keys=keys, chunks=1)
     row = ledger.debug_vars()["global"]["sync_tick"]
     assert row == {"count": 2, "ms_total": 2.0, "ms_max": 1.0,
+                   "max_at_ms": (EPOCH_NS + 1_001_000) // 1_000_000,
                    "keys": 8, "chunks": 2}
     with pytest.raises(KeyError):
         tick = ledger.stage("global.sync_tick", "global")
@@ -569,10 +592,360 @@ def test_the_sync_tick_divides_and_its_counters_add_up():
     assert lane["pack"]["checks"] == rpcs * per_rpc
     assert lane["pack"]["rounds"] >= lane["drain"]["count"] > 0
     assert lane["pack"]["count"] == lane["drain"]["count"]
-    # One chunk's sync program, as /debug/vars describes it.
+    # One chunk's sync program, as /debug/vars describes it: its
+    # geometry, which is all the roofline reader takes (PR 31).
     prog = engine_vars["sync_program"]
-    assert prog["shards"] == 4 and prog["collective"] == "psum"
-    assert prog["bytes_accessed"] is None or prog["bytes_accessed"] > 0
+    assert prog == {"collective": "psum", "shards": 4, "delta_slots": 32}
+
+
+# -- why a stage took that long (PR 41) ---------------------------------------
+
+def _spin(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _cpu_ms(block: dict, name: str) -> float:
+    family = tracing._FAMILY.match(name).group(0)
+    return block.get(family, {}).get(name, {"cpu_ms": 0.0})["cpu_ms"]
+
+
+@pytest.mark.parametrize("kind", ["spin", "sleep", "ended"])
+def test_a_pools_cpu_beside_the_wall_of_its_sections(kind):
+    """No stage reads a CPU clock (a system call of microseconds on the
+    chip's host): a pool thread's own clock, read from outside at render,
+    beside the wall of the section it ran says how much of that wall it
+    was running.  One that computes reads the CPU it burnt, within its
+    wall; one that sleeps next to none; one that has ended keeps its last
+    reading under its name."""
+    ledger = tracing.StageLedger()
+    name = f"tpu-fastlane-{kind}_0"
+    done, leave = threading.Event(), threading.Event()
+
+    def work():
+        with ledger.stage("lane.pack", "mach"):
+            if kind == "sleep":
+                time.sleep(0.08)
+            else:       # 60 ms of CPU, however loaded the machine
+                until = time.thread_time() + 0.06
+                while time.thread_time() < until:
+                    pass
+        done.set()
+        leave.wait()
+
+    t = threading.Thread(target=work, name=name)
+    before = _cpu_ms(tracing.thread_vars(), name)
+    t.start()
+    done.wait()
+    block = tracing.thread_vars()
+    assert name in block["tpu-fastlane"]       # the pool's family
+    cpu = _cpu_ms(block, name) - before
+    leave.set()
+    t.join()
+    row = ledger.debug_vars()["mach"]["pack"]
+    assert set(row) == {"count", "ms_total", "ms_max", "max_at_ms"}
+    assert cpu <= 1.01 * row["ms_total"] + 10      # the clock's grain
+    if kind == "sleep":
+        assert row["ms_total"] >= 80 and cpu < 10, (cpu, row)
+    else:
+        assert row["ms_total"] >= 60 and cpu >= 55, (cpu, row)
+    if kind == "ended":
+        for _ in range(2):     # retired once, and not counted twice
+            after = tracing.thread_vars()
+            assert _cpu_ms(after, name) - before == pytest.approx(
+                cpu, abs=5)
+        assert all(n != name for n, _ in tracing._THREAD_CPU_NS.values())
+        assert tracing._ENDED_CPU_NS[name] > 0
+
+
+def test_a_threads_cpu_clock_is_read_by_its_kernel_id():
+    """A thread's CPU clock is read from outside it by the kernel's id
+    for that clock, computed from Thread.native_id: what
+    pthread_getcpuclockid returns for a live thread, and a clean error —
+    not a read of a freed handle — for one that has ended."""
+    here = (~threading.get_native_id() << 3) | 6
+    assert time.pthread_getcpuclockid(threading.get_ident()) == here
+    assert abs(time.clock_gettime_ns(here) - time.thread_time_ns()) < 5e6
+    t = threading.Thread(target=_spin, args=(0.01,))
+    t.start()
+    tid = t.native_id
+    t.join()
+    with pytest.raises(OSError):      # EINVAL, once the kernel's thread
+        for _ in range(1000):         # has gone (join() returns a little
+            time.clock_gettime_ns((~tid << 3) | 6)          # before it)
+            time.sleep(0.001)
+    mine = _cpu_ms(tracing.thread_vars(), threading.current_thread().name)
+    assert abs(mine - time.thread_time_ns() / 1e6) < 50
+
+
+@pytest.mark.parametrize("name,family", [
+    ("tpu-fastlane_0", "tpu-fastlane"),
+    ("tpu-fastlane-engine_1", "tpu-fastlane"),
+    ("tpu-fastlane-sketch_0", "tpu-fastlane"),
+    ("tpu-step_0", "tpu-step"),
+    ("asyncio_3", "asyncio"),
+    ("Thread-2 (_poll_wrapper)", "Thread"),
+    ("MainThread", "MainThread"),
+])
+def test_a_threads_family_is_the_first_two_words_of_its_name(name, family):
+    """`threads.<family>.<name>`: every lane's pool is one family, so a
+    data file sums the coalescer's threads by one path."""
+    assert tracing._FAMILY.match(name).group(0) == family
+
+
+def test_two_spinning_threads_share_one_gil():
+    """Two Python threads spin in sections at once: between them they
+    had the CPU for about the wall that passed, not twice it — the GIL,
+    whatever the machine's load — which is what `host_python_cores`
+    reads near 1.0."""
+    ledger = tracing.StageLedger()
+    start = threading.Barrier(3)
+    seen = threading.Event()
+    before = tracing.thread_vars()
+
+    def work():
+        start.wait()
+        with ledger.stage("lane.unpack", "mach"):
+            _spin(0.3)
+            while not seen.is_set():
+                pass
+
+    threads = [threading.Thread(target=work, name=f"gil-spin-{i}")
+               for i in range(2)]
+    for t in threads:
+        t.start()
+    t0 = time.perf_counter()
+    start.wait()
+    time.sleep(0.35)
+    during = tracing.thread_vars()
+    wall_ms = (time.perf_counter() - t0) * 1e3
+    seen.set()
+    for t in threads:
+        t.join()
+    row = ledger.debug_vars()["mach"]["unpack"]
+    assert row["count"] == 2 and row["ms_total"] >= 2 * 300
+    assert "gil-spin" not in before
+    spun = [during["gil-spin"][f"gil-spin-{i}"]["cpu_ms"] for i in range(2)]
+    assert all(ms > 0 for ms in spun), spun
+    assert 0.5 * wall_ms <= sum(spun) <= 1.15 * wall_ms + 20, (spun, wall_ms)
+    # Once they have ended the block keeps their readings: no sum falls.
+    after = tracing.thread_vars()
+    assert [after["gil-spin"][f"gil-spin-{i}"]["cpu_ms"]
+            for i in range(2)] == spun
+    main = threading.current_thread().name
+    assert _cpu_ms(after, main) >= _cpu_ms(before, main)
+    assert tracing.process_vars()["cpu_ms"] >= _cpu_ms(after, main)
+
+
+def test_a_collection_is_a_row_of_lane_host():
+    """gc.collect() adds one to host.gc's count and to its `gen2`; a
+    young collection to the count alone; the row is the process's, so
+    every ledger renders it."""
+    ledger = tracing.StageLedger()
+    before = ledger.debug_vars()["host"]["gc"]
+    gc.collect()
+    after = tracing.StageLedger().debug_vars()["host"]["gc"]
+    assert set(after) == {"count", "ms_total", "ms_max", "max_at_ms", "gen2"}
+    assert after["count"] == before["count"] + 1
+    assert after["gen2"] == before["gen2"] + 1
+    assert after["ms_total"] > before["ms_total"]
+    gc.collect(0)
+    young = ledger.debug_vars()["host"]["gc"]
+    assert young["count"] == after["count"] + 1
+    assert young["gen2"] == after["gen2"]
+
+
+def test_the_rows_of_lane_host_stand_at_zero_from_start_up():
+    host = Metrics().stages.debug_vars()["host"]
+    assert set(host) == {"gc", "census_dispatch", "census_fetch", "hotkey",
+                         "scrape", "loop_lag", "stall"}
+    for name, row in host.items():
+        want = {"count", "ms_total", "ms_max", "max_at_ms"}
+        if name == "gc":
+            want = want | {"gen2"}
+        assert set(row) == want, name
+        if name in ("gc", "stall"):   # the process's: other tests have run
+            continue
+        assert (row["count"], row["ms_total"], row["ms_max"]) == (0, 0, 0)
+    assert host["stall"]["count"] == tracing._STALL.count
+
+
+@pytest.fixture
+def stall_store(monkeypatch):
+    """The process's stalls row and ring, empty for one test."""
+    from collections import deque
+
+    monkeypatch.setattr(tracing, "_STALL", tracing._Cell("host", "host.stall"))
+    monkeypatch.setattr(tracing, "_STALLS", deque(maxlen=tracing.STALL_RING))
+
+
+@pytest.mark.parametrize("ms,lands", [(30, True), (19, False), (24, True)])
+def test_a_stall_has_a_time_and_a_neighbourhood(clock, stall_store, ms, lands):
+    """An instance of a leaf row of at least 20 ms and 8 x its row's mean
+    so far lands in the ring with its times, and in host.stall; a shorter
+    one, or one that is long beside no mean, does not.  The row and the
+    ring are the process's: every ledger renders them."""
+    ledger = tracing.StageLedger()
+    for _ in range(8):  # mean so far: 3 ms
+        with ledger.stage("lane.unpack", "mach"):
+            clock.now += 3_000_000
+    assert tracing.stalls() == []
+    with ledger.stage("lane.unpack", "mach"):
+        clock.now += ms * 1_000_000
+    t_end = (EPOCH_NS + clock.now) / 1e6
+    stall = tracing.StageLedger().debug_vars()["host"]["stall"]
+    if not lands:
+        assert tracing.stalls() == [] and stall["count"] == 0
+        return
+    assert tracing.stalls() == [{
+        "t_start_ms": t_end - ms, "t_end_ms": t_end, "lane": "mach",
+        "stage": "unpack", "ms": float(ms),
+        "thread": threading.current_thread().name,
+    }]
+    assert (stall["count"], stall["ms_total"]) == (1, pytest.approx(ms))
+    assert stall["max_at_ms"] == int(t_end)
+    # A wait across threads is no leaf, however long; a row's first
+    # instance has no mean to stand out from.
+    wait = ledger.begin("lane.handoff", "mach")
+    clock.now += 1
+    wait.end()
+    wait = ledger.begin("lane.handoff", "mach")
+    clock.now += 900_000_000
+    wait.end()
+    with ledger.stage("lane.cascade", "mach"):
+        clock.now += 900_000_000
+    assert len(tracing.stalls()) == 1
+    # The ring keeps the newest STALL_RING, oldest first.
+    for _ in range(tracing.STALL_RING + 5):
+        with ledger.stage("lane.pack", "mach"):
+            clock.now += 1_000
+        with ledger.stage("lane.pack", "mach"):
+            clock.now += 10_000_000_000
+            ledger.cell("mach", "lane.pack").ns_total = 1_000   # keep the mean
+    ring = tracing.stalls()
+    assert len(ring) == tracing.STALL_RING
+    assert ring == sorted(ring, key=lambda r: r["t_end_ms"])
+    assert all(r["stage"] == "pack" for r in ring)
+    assert tracing._STALL.count == tracing.STALL_RING + 6
+
+
+def test_a_long_collection_is_a_stall_too(stall_store, monkeypatch):
+    """The collector reaches the stalls without a ledger's lock (a
+    collection can start inside it): one far over its row's mean lands in
+    the ring and the row like any leaf's."""
+    monkeypatch.setattr(tracing, "_GC", tracing._Cell("host", "host.gc"))
+    tracing._GC.counters["gen2"] = 0
+    tracing._trace_me()
+    for _ in range(4):
+        gc.collect(0)
+    assert tracing.stalls() == []
+    real = time.perf_counter_ns
+    late = iter((0, 30_000_000))
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        perf_counter_ns=lambda: real() + next(late, 30_000_000),
+        time_ns=time.time_ns,
+    ))
+    gc.collect()
+    (r,) = tracing.stalls()
+    assert (r["lane"], r["stage"]) == ("host", "gc") and r["ms"] >= 30
+    assert r["thread"] == threading.current_thread().name
+    assert tracing._STALL.count == 1 and tracing._GC.counters["gen2"] == 1
+
+
+def test_the_heartbeat_times_the_loop_and_feeds_its_views():
+    """host.loop_lag: one sample a LOOP_LAG_INTERVAL_S, a callback that
+    holds the loop shows in it; gubernator_event_loop_lag_seconds and the
+    flight recorder's lag readings are views of the row — the recorder
+    times nothing itself."""
+    from gubernator_tpu.runtime.flightrec import FlightRecorder
+
+    metrics = Metrics()
+    fr = metrics.flightrec = FlightRecorder(metrics=metrics, stall_ms=40.0)
+
+    async def scenario():
+        beat = asyncio.ensure_future(metrics.stages.heartbeat())
+        await asyncio.sleep(tracing.LOOP_LAG_INTERVAL_S / 2)
+        time.sleep(tracing.LOOP_LAG_INTERVAL_S)      # holds the loop
+        await asyncio.sleep(tracing.LOOP_LAG_INTERVAL_S * 1.5)
+        beat.cancel()
+        await asyncio.gather(beat, return_exceptions=True)
+
+    asyncio.run(scenario())
+    n, ns, mx = metrics.stages.totals("host", "host.loop_lag")
+    assert n >= 2 and mx >= 0.4 * tracing.LOOP_LAG_INTERVAL_S * 1e9
+    assert fr.max_lag_ms == pytest.approx(mx / 1e6)
+    assert fr.last_lag_ms <= fr.max_lag_ms
+    assert metrics.registry.get_sample_value(
+        "gubernator_event_loop_lag_seconds"
+    ) == pytest.approx(fr.last_lag_ms / 1e3)
+    stalls = [r for r in fr.snapshot()["ring"] if r["kind"] == "loop_stall"]
+    assert [r["lag_ms"] for r in stalls] == [round(mx / 1e6, 1)]
+    assert not hasattr(fr, "_lag_task")
+
+
+def test_the_interferers_add_to_their_rows_on_a_live_daemon():
+    """The census, note_traffic and both scrape routes each add to their
+    row of lane `host` on a daemon over real gRPC and HTTP; /debug/vars
+    carries `threads`, `process` and `stalls`, and no row a CPU key."""
+    from gubernator_tpu.testing.cluster import Cluster
+
+    cluster = Cluster.start(1)
+    try:
+        d = cluster.daemon_at(0)
+
+        def get(path):
+            with urllib.request.urlopen(
+                f"http://{d.http_address}{path}", timeout=30
+            ) as r:
+                return r.read()
+
+        first = json.loads(get("/debug/vars"))
+        _drive(cluster, d, _payload, 40, 6)
+        cluster.run(d.stats_sampler.sample(), timeout=60)
+        get("/metrics")
+        time.sleep(2 * tracing.LOOP_LAG_INTERVAL_S)
+        out = json.loads(get("/debug/vars"))
+    finally:
+        cluster.stop()
+
+    host0, host = first["stages"]["host"], out["stages"]["host"]
+    assert set(host0) == set(host) >= {
+        "gc", "census_dispatch", "census_fetch", "hotkey", "scrape",
+        "loop_lag", "stall",
+    }
+    for row in ("census_dispatch", "census_fetch"):
+        assert host[row]["count"] > host0[row]["count"], row
+        assert host[row]["ms_total"] > host0[row]["ms_total"], row
+    # note_traffic: once an RPC.
+    assert host["hotkey"]["count"] - host0["hotkey"]["count"] == 40
+    # A scrape is timed as it ends: each /debug/vars was under way when
+    # it rendered itself, so the second sees the first and the /metrics.
+    assert (host0["scrape"]["count"], host["scrape"]["count"]) == (0, 2)
+    assert host["scrape"]["ms_total"] > 0
+    assert host["loop_lag"]["count"] >= 2
+    # Who had the CPU: threads.<family>.<name>.cpu_ms.
+    threads = out["threads"]
+    assert threads["tpu-fastlane"], sorted(threads)
+    assert all(name.startswith("tpu-fastlane")
+               for name in threads["tpu-fastlane"])
+    assert all(set(t) == {"cpu_ms"}
+               for family in threads.values() for t in family.values())
+    assert sum(t["cpu_ms"] for family in threads.values()
+               for t in family.values()) > 0
+    assert out["process"]["cpu_ms"] > 0
+    assert isinstance(out["stalls"], list)
+    for r in out["stalls"]:
+        assert r["ms"] >= 20 and r["t_end_ms"] >= r["t_start_ms"]
+    # No stage reads a CPU clock: a row is its four keys and its counters.
+    seen = 0
+    for lane, rows in out["stages"].items():
+        for stage, row in rows.items():
+            assert {"count", "ms_total", "ms_max", "max_at_ms"} <= set(row)
+            assert not any("cpu" in key for key in row), (lane, stage, row)
+            assert row["max_at_ms"] > 0 or row["ms_max"] == 0, (lane, stage)
+            seen += row["count"] > 0
+    assert seen >= 12
 
 
 # -- the span plane ---------------------------------------------------------
@@ -653,6 +1026,9 @@ def test_profiler_trace_holds_gub_events(tmp_path):
         with ledger.stage("global.sync_tick", "global", anchor=True):
             time.sleep(0.001)
         asyncio.run(scenario(ledger))
+        gc.collect()                 # lane `host`: on the host plane too
+        with ledger.stage("host.scrape", "host"):
+            pass
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(
@@ -670,7 +1046,7 @@ def test_profiler_trace_holds_gub_events(tmp_path):
     for name in ("gub.lane.slot_wait", "gub.lane.queue_wait",
                  "gub.lane.in_drain", "gub.wire.wake", "gub.lane.handoff",
                  "gub.lane.pack", "gub.lane.resume",
-                 "gub.global.sync_tick"):
+                 "gub.global.sync_tick", "gub.host.gc", "gub.host.scrape"):
         assert name in events, (name, sorted(events))
     assert events["gub.lane.slot_wait"].duration_ns >= 4_000_000
     assert events["gub.lane.pack"].duration_ns >= 1_500_000

@@ -304,21 +304,34 @@ def test_flightrec_cli_renders_dump(tmp_path, capsys):
 
 
 def test_flightrec_lag_sampler_runs_and_sets_gauge():
+    """The recorder's sampler evaluates the SLO and times nothing; the
+    lag gauge and the recorder's lag readings are fed by the daemon's
+    heartbeat, the stage ledger's host.loop_lag (tests/test_stages.py
+    holds the views to the row)."""
+    from gubernator_tpu.runtime import tracing
+
     m = Metrics()
-    fr = FlightRecorder(
+    fr = m.flightrec = FlightRecorder(
         metrics=m, sample_interval_s=0.02, min_samples=10_000
     )
 
     async def go():
         fr.start()
-        await asyncio.sleep(0.2)
+        await asyncio.sleep(0.1)
+        assert fr.max_lag_ms == 0.0          # no heartbeat, no reading
+        beat = asyncio.ensure_future(m.stages.heartbeat())
+        await asyncio.sleep(tracing.LOOP_LAG_INTERVAL_S * 1.6)
+        beat.cancel()
+        await asyncio.gather(beat, return_exceptions=True)
         await fr.close()
 
     asyncio.run(go())
+    n, _ns, mx = m.stages.totals("host", "host.loop_lag")
+    assert n >= 1
     assert m.registry.get_sample_value(
         "gubernator_event_loop_lag_seconds"
-    ) is not None
-    assert fr.max_lag_ms >= 0.0
+    ) == pytest.approx(fr.last_lag_ms / 1e3)
+    assert fr.max_lag_ms == pytest.approx(mx / 1e6)
 
 
 # ---------------------------------------------------------------------------
@@ -393,6 +406,12 @@ def test_slo_breach_dumps_and_counts(slo_cluster):
     assert data["rolling"]["samples"] >= 10
     kinds = {r["kind"] for r in data["ring"]}
     assert kinds & {"device_step", "fastlane_drain"}, kinds
+    # The stage ledger's stalls ring rides every dump, on the epoch clock
+    # of the ring's own `ts` (docs/flightrec.md).
+    assert isinstance(data["stalls"], list)
+    for r in data["stalls"]:
+        assert r["t_start_ms"] <= r["t_end_ms"] <= data["now"] * 1e3 + 1
+        assert r["ms"] >= 20
 
 
 def test_slo_breach_surfaces_in_healthcheck(slo_cluster):

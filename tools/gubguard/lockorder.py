@@ -180,7 +180,10 @@ RANK = {
     # tracing.stages._lock (runtime/tracing.py StageLedger rows) is a
     # leaf like the two above: a stage may end under ANY layer's lock
     # (backend.dispatch ends inside backend._lock), the critical section
-    # is three integer adds, and observers run after release.
+    # is the row's integer adds, and the stalls and observers are fed
+    # after release.  The garbage collector's row and the stalls' store
+    # are NOT under it: a collection can start inside it, so host.gc and
+    # host.stall are the process's cells, written without a lock.
     "tracing.stages._lock": 72,
     # clock._lock (core/clock.py frozen-time guard) ranks dead last:
     # now_ns() may be called under ANY other lock (timestamps are
