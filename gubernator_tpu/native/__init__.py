@@ -19,7 +19,7 @@ import os
 import re
 import subprocess
 import threading
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -131,6 +131,24 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
     ]
     lib.gub_assign_rounds.restype = ctypes.c_int64
+    # The drain's pack and unpack take addresses (pack_rounds /
+    # gather_rounds check dtype and layout themselves: thirty ndpointer
+    # conversions would cost a small drain more than the pass).
+    lib.gub_pack_rounds.argtypes = (
+        [ctypes.c_int64] + [ctypes.c_void_p] * 11
+        + [ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+           ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
+           ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+           ctypes.c_int64]
+        + [ctypes.c_void_p] * 7
+    )
+    lib.gub_pack_rounds.restype = ctypes.c_int64
+    lib.gub_gather_rounds.argtypes = [
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p,
+    ]
+    lib.gub_gather_rounds.restype = None
     lib.gub_count_reqs.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.gub_count_reqs.restype = ctypes.c_int64
     lib.gub_parse_reqs2.argtypes = [
@@ -290,6 +308,186 @@ def assign_rounds(
         out_lane,
     )
     return out_round, out_lane, int(n_rounds)
+
+
+def _column(a: Optional[np.ndarray], dtype, n: int):
+    """A column of n `dtype` words as the native pass takes it: (the
+    contiguous array, kept alive by the caller for the call; its
+    address).  None: a column of zeros, (None, None)."""
+    if a is None:
+        return None, None
+    a = np.ascontiguousarray(a, dtype=dtype)
+    if a.shape != (n,):
+        raise ValueError(f"native column: want [{n}], got {list(a.shape)}")
+    return a, a.ctypes.data
+
+
+_META_HEAD = 8  # gubtpu.cpp GUB_META_*
+
+
+class PackedDrain:
+    """What gub_pack_rounds made of one drain's columns.
+
+    `rounds[r]` is round r as the device takes it: a fresh contiguous
+    int64[12, t] (n_shards 1) or int64[12, n_shards, t], rows in
+    DeviceBatch field order, t = `tiers[r]` the smallest compiled tier
+    that holds its fullest shard, `lanes[r]` of its lanes active.  Check
+    i went to (`rnd[i]`, shard of its hash, `lane[i]`), nowhere where
+    `rnd[i]` < 0 (an errored check, or a cascade group's later
+    occurrence).  `cap_ok[i]`: i is the last occurrence of its key.
+
+    `groups` > 0: the drain holds that many duplicate groups the host
+    cascade may take, of `occ_total` occurrences, `peeks` of them with
+    hits 0; `cascades` says whether the rounds are the read lanes'
+    (each group's first occurrence alone, hits 0) or every check's.
+    `occ`, `firsts`, `order`, `bounds` are the groups, in ascending
+    order of the signed hash: bool[n] on their occurrences, each group's
+    first occurrence, and its occurrences in arrival order
+    `order[bounds[g]:bounds[g + 1]]`."""
+
+    __slots__ = (
+        "rounds", "tiers", "lanes", "rnd", "lane", "cap_ok", "valid",
+        "cascades", "groups", "occ_total", "peeks", "occ", "firsts",
+        "order", "bounds", "n_shards", "shard_shift",
+    )
+
+
+def pack_rounds(
+    hash: np.ndarray, hits: np.ndarray, limit: np.ndarray,
+    duration: np.ndarray, algo: np.ndarray, burst: np.ndarray,
+    behavior: Optional[np.ndarray], is_greg: Optional[np.ndarray],
+    greg_expire: Optional[np.ndarray], greg_duration: Optional[np.ndarray],
+    use_cached: Optional[np.ndarray], *, reset_bit: int, n_shards: int,
+    shard_shift: int, batch_size: int, tiers: Sequence[int], mode: int,
+    cap_ok: bool = False,
+) -> PackedDrain:
+    """A drain's columns to its packed rounds in ONE native call with the
+    GIL released (gub_pack_rounds; the layouts are in its comment).
+    `burst` is the wire's (0: the limit); `mode` 0 the plain assignment,
+    1 the host cascade where it saves a launch, 2 wherever a group is
+    eligible.  Native only."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native library unavailable")
+    n = len(hash)
+    i64 = np.int64
+    tiers_a = np.array(tiers, dtype=np.int32)
+    kept, addrs = zip(*(
+        _column(a, dt, n) for a, dt in (
+            (hash, i64), (hits, i64), (limit, i64), (duration, i64),
+            (algo, np.int32), (burst, i64), (behavior, i64),
+            (is_greg, np.bool_), (greg_expire, i64), (greg_duration, i64),
+            (use_cached, np.bool_),
+        )
+    ))
+    pos = np.empty((2, n), dtype=np.int32)
+    outs = [pos[0], pos[1], np.empty(n, dtype=bool) if cap_ok else None]
+    if mode:
+        # occ, firsts, order, bounds
+        outs += [
+            np.empty(n, dtype=bool), np.empty(n // 2 + 1, dtype=i64),
+            np.empty(n, dtype=i64), np.empty(n // 2 + 2, dtype=i64),
+        ]
+    else:
+        outs += [None] * 4
+    out_addrs = [None if a is None else a.ctypes.data for a in outs]
+    # Two rounds at the tier the drain's checks would fill and two of the
+    # smallest hold every drain but one that sends a key many times over
+    # with no group eligible; that one is packed again at its size.
+    fit = next((t for t in tiers if min(n, batch_size) <= t), tiers[-1])
+    words = 12 * n_shards * 2 * (fit + tiers[0])
+    max_rounds = 16
+    while True:
+        # Fresh every drain: the runtime may still read a round of the
+        # drain before.
+        arena = np.empty(words, dtype=i64)
+        meta = np.empty(_META_HEAD + 3 * max_rounds, dtype=i64)
+        again = lib.gub_pack_rounds(
+            n, *addrs, reset_bit, n_shards, shard_shift, batch_size,
+            tiers_a.ctypes.data, len(tiers_a), mode, arena.ctypes.data,
+            words, meta.ctypes.data, max_rounds, *out_addrs,
+        )
+        m = meta.tolist()
+        if not again:
+            break
+        max_rounds, words = m[0], m[1]
+    del kept
+    cap, occ, firsts, order, bounds = outs[2:]
+    out = PackedDrain()
+    n_rounds = m[0]
+    out.tiers = m[_META_HEAD:_META_HEAD + 3 * n_rounds:3]
+    out.lanes = m[_META_HEAD + 1:_META_HEAD + 3 * n_rounds:3]
+    shape = (12, n_shards) if n_shards > 1 else (12,)
+    out.rounds = [
+        arena[off:off + 12 * n_shards * t].reshape(shape + (t,))
+        for t, off in zip(
+            out.tiers, m[_META_HEAD + 2:_META_HEAD + 3 * n_rounds:3]
+        )
+    ]
+    out.rnd, out.lane = pos[0], pos[1]
+    out.cap_ok = cap
+    out.cascades = m[2] != 0
+    out.groups, out.occ_total, out.peeks, out.valid = m[3], m[4], m[5], m[7]
+    if out.groups:
+        out.occ = occ
+        out.firsts = firsts[:out.groups]
+        out.order = order[:out.occ_total]
+        out.bounds = bounds[:out.groups + 1]
+    else:
+        out.occ = out.firsts = out.order = out.bounds = None
+    out.n_shards, out.shard_shift = n_shards, shard_shift
+    return out
+
+
+class GatheredDrain:
+    """gub_gather_rounds' answer: `cols` int64[k, n], the first k response
+    rows (status, limit, remaining, reset_time, persisted, found, stored,
+    cached, stored_status) a check, zero where the check had no lane; and
+    over the lanes read: `over_limit` (status 1), `not_persisted`,
+    `cache_hits` (found), `lanes`."""
+
+    __slots__ = ("cols", "over_limit", "not_persisted", "cache_hits",
+                 "lanes")
+
+
+def gather_rounds(
+    packed: PackedDrain, hash: np.ndarray, resps: Sequence[np.ndarray],
+    n_cols: int = 9,
+) -> GatheredDrain:
+    """The fetched responses of `packed`'s rounds (int64[9, t] a round, or
+    [n_shards, 9, t]) back to the order of the drain's checks, in ONE
+    native call with the GIL released.  Native only."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native library unavailable")
+    n = len(hash)
+    S = packed.n_shards
+    if len(resps) != len(packed.tiers):
+        raise ValueError("native gather: a response a round")
+    keep = []
+    for a, t in zip(resps, packed.tiers):
+        want = (S, 9, t) if S > 1 else (9, t)
+        if a.dtype != np.int64 or a.shape != want:
+            raise TypeError(
+                f"native gather: want int64{list(want)}, got "
+                f"{a.dtype}{list(a.shape)}"
+            )
+        keep.append(np.ascontiguousarray(a))
+    ptrs = np.array([a.ctypes.data for a in keep], dtype=np.int64)
+    tiers = np.array(packed.tiers, dtype=np.int64)
+    out = GatheredDrain()
+    out.cols = np.empty((n_cols, n), dtype=np.int64)
+    sums = np.empty(4, dtype=np.int64)
+    hash, hash_addr = _column(hash, np.int64, n)
+    lib.gub_gather_rounds(
+        n, hash_addr, packed.rnd.ctypes.data,
+        packed.lane.ctypes.data, S, packed.shard_shift,
+        ptrs.ctypes.data, tiers.ctypes.data, n_cols,
+        out.cols.ctypes.data, sums.ctypes.data,
+    )
+    (out.over_limit, out.not_persisted, out.cache_hits,
+     out.lanes) = sums.tolist()
+    return out
 
 
 class ParsedReqs:

@@ -64,7 +64,11 @@ from gubernator_tpu.parallel.sharded import (
     pack_grid_batch,
     packed_grid_rounds_to_host,
 )
-from gubernator_tpu.runtime.backend import tier_of, unmarshal_responses
+from gubernator_tpu.runtime.backend import (
+    round_words,
+    tier_of,
+    unmarshal_responses,
+)
 
 
 class DeltaGrid(NamedTuple):
@@ -522,9 +526,9 @@ class GlobalEngine:
             resps = []
             with self.b._stages.stage("backend.dispatch"):
                 for db in rounds:
-                    t = tier_of(db.active, self.b._tiers)
                     batch = jax.device_put(
-                        pack_grid_batch(db)[:, :, :t], self.b._psharding
+                        round_words(db, self.b._tiers, pack_grid_batch),
+                        self.b._psharding,
                     )
                     self.cache_table, r = self._ingest(
                         self.cache_table, batch, now

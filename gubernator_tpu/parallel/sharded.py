@@ -47,6 +47,7 @@ from gubernator_tpu.runtime.backend import (
     _row_to_item,
     probe_bucket,
     resolve_tiers,
+    round_words,
     tier_of,
     unmarshal_responses,
 )
@@ -469,9 +470,9 @@ class MeshBackend(PersistenceHost):
         round_resps = []
         with self._stages.stage("backend.dispatch"):
             for db in rounds:
-                t = tier_of(db.active, self._tiers)
                 batch = jax.device_put(
-                    pack_grid_batch(db)[:, :, :t], self._psharding
+                    round_words(db, self._tiers, pack_grid_batch),
+                    self._psharding,
                 )
                 self.table, resp = self._step_packed(self.table, batch, now)
                 round_resps.append(resp)

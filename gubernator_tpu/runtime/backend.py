@@ -62,6 +62,17 @@ def pack_batch_q(db) -> np.ndarray:
     return q
 
 
+def round_words(db, tiers, pack=pack_batch_q) -> np.ndarray:
+    """One round as the step program takes it: int64[12, .., t], t the
+    smallest compiled tier that holds its lanes.  The compiled lane hands
+    rounds over in that form already (native.pack_rounds wrote them so,
+    once); a DeviceBatch (the object path's packers, a store repair) is
+    packed here and cut to its tier."""
+    if isinstance(db, np.ndarray):
+        return db
+    return pack(db)[..., : tier_of(db.active, tiers)]
+
+
 def resolve_tiers(cfg) -> tuple:
     """Sorted compiled batch tiers; batch_size is ALWAYS included so
     tier_of's fallback never truncates a full round."""
@@ -647,9 +658,8 @@ class DeviceBackend(PersistenceHost):
         round_resps = []
         with self._stages.stage("backend.dispatch"):
             for db in rounds:
-                t = tier_of(db.active, self._tiers)
                 self.table, packed_resp = self._step_packed_q(
-                    self.table, pack_batch_q(db)[:, :t], now
+                    self.table, round_words(db, self._tiers), now
                 )
                 round_resps.append(packed_resp)
         return round_resps
@@ -1206,21 +1216,30 @@ def fetch_ravel(arrs) -> List[np.ndarray]:
         return [np.asarray(a) for a in arrs]
 
 
+# apply_batch_packed_q's response rows, in order.
+RESP_FIELDS = (
+    "status", "limit", "remaining", "reset_time", "persisted", "found",
+    "stored", "cached", "stored_status",
+)
+
+
+class PackedResp(dict):
+    """_packed_resp_dict's named columns, with the fetched words they are
+    views of: the compiled lane's native gather reads `words` in one
+    pass (fastpath._resp_words) where the object path indexes columns."""
+
+    __slots__ = ("words",)
+
+
 def _packed_resp_dict(a: np.ndarray) -> Dict[str, np.ndarray]:
     """apply_batch_packed_q row order -> named host columns; `a` is
     [9, B] (single table) or [n, 9, B] (grid, leading shard dim)."""
     sl = (slice(None),) * (a.ndim - 2)
-    return {
-        "status": a[sl + (0,)],
-        "limit": a[sl + (1,)],
-        "remaining": a[sl + (2,)],
-        "reset_time": a[sl + (3,)],
-        "persisted": a[sl + (4,)],
-        "found": a[sl + (5,)],
-        "stored": a[sl + (6,)],
-        "cached": a[sl + (7,)],
-        "stored_status": a[sl + (8,)],
-    }
+    out = PackedResp(
+        (f, a[sl + (i,)]) for i, f in enumerate(RESP_FIELDS)
+    )
+    out.words = a
+    return out
 
 
 def packed_rounds_to_host(round_packed) -> List[Dict[str, np.ndarray]]:

@@ -11,17 +11,27 @@ This module is the equivalent compiled lane.  For eligible requests the
 daemon hands the raw gRPC payload straight here:
 
     C++ parse  (native/gubtpu.cpp gub_parse_reqs2: wire -> columns + XXH64)
-    numpy      (burst defaults, behavior masks, shard routing)
-    C++ pack   (gub_assign_rounds: duplicate-key round/lane assignment;
-                a drain takes the host cascade instead only where that
-                saves a device launch, _cascade_or_rounds: a key three
-                times in a drain of one round does, one pair among 5,000
-                checks rides the two rounds the drain has anyway)
-    numpy      (scatter columns into fixed-shape DeviceBatch rounds)
-    device     (backend.step_rounds: the same jitted kernels as check();
-                sketch-named lanes take one CMS step instead)
-    numpy      (gather packed responses back to request order)
+    numpy      (the coalesced RPCs' columns concatenated)
+    C++ pack   (gub_pack_rounds, ONE call a drain with the GIL released:
+                burst defaults, the RESET_REMAINING bit, shard routing,
+                the duplicate-key round/lane assignment — a drain takes
+                the host cascade instead only where that saves a device
+                launch: a key three times in a drain of one round does,
+                one pair among 5,000 checks rides the two rounds the drain
+                has anyway — and the rounds written once, in the layout
+                the step program takes, int64[12, tier] each)
+    device     (backend.step_rounds_begin: the same jitted kernels as
+                check(); sketch-named lanes take one CMS step instead)
+    C++ unpack (gub_gather_rounds, ONE call: the fetched responses back to
+                request order, with the sums the tallies take)
     C++ emit   (gub_serialize_resps2: columns -> response wire bytes)
+
+_plan_cascade, _read_lanes, _cascade_or_rounds and _build_rounds below are
+the pack as numpy made it until PR 42, put together by _reference_pack
+(_reference_unpack: the gather, the sums and the cap_ok loop): the plain
+reference that tests/test_pack_native.py holds the native pass to, bit for
+bit, and scripts/pack_bench.py times it against.  No served path calls
+them.
 
 No per-request Python objects exist anywhere on this path.  Concurrent
 RPCs coalesce into shared device steps (the LocalBatcher discipline,
@@ -1203,6 +1213,24 @@ class FastPath:
         await asyncio.gather(*tasks)
         return status, out_lim, remaining, reset, stored, stored_st, cap_ok
 
+    def _pack_rounds(self, *cols, mode: int, shard_shift: int = None,
+                     cap_ok: bool = False):
+        """native.pack_rounds at this backend's geometry: `cols` are the
+        drain's eleven columns (hash, hits, limit, duration, algo, burst,
+        behavior, is_greg, greg_expire, greg_duration, use_cached; None:
+        zeros), `shard_shift` the bits a hash's shard is read from (the
+        table's owner shard unless given)."""
+        from gubernator_tpu.parallel.mesh import _SHARD_SHIFT
+
+        backend = self.s.backend
+        return native.pack_rounds(
+            *cols, reset_bit=int(Behavior.RESET_REMAINING),
+            n_shards=backend.cfg.num_shards,
+            shard_shift=_SHARD_SHIFT if shard_shift is None else shard_shift,
+            batch_size=backend.cfg.batch_size, tiers=backend._tiers,
+            mode=mode, cap_ok=cap_ok,
+        )
+
     def _engine_process(self, entries):
         pack = tracing.stage("lane.pack")  # ended at the hand-over
         try:
@@ -1228,14 +1256,10 @@ class FastPath:
         from gubernator_tpu.parallel.sharded import (
             packed_grid_rounds_to_host,
         )
-        from gubernator_tpu.runtime.backend import (
-            Tally,
-            tally_from_rounds,
-        )
+        from gubernator_tpu.runtime.backend import Tally
 
         engine = self.s.global_engine
-        cfg = self.s.backend.cfg
-        n_shards, B = cfg.num_shards, cfg.batch_size
+        n_shards = self.s.backend.cfg.num_shards
         shift = np.uint64(_ARRIVAL_SHIFT)  # vectorized arrival_dev
 
         per = []
@@ -1250,9 +1274,7 @@ class FastPath:
             # hits above 2^53 and diverge from the pending queue).
             hits_sum = np.zeros(m, dtype=np.int64)
             np.add.at(hits_sum, inv, e.cols.hits[e.idx])
-            burst = e.cols.burst[rep]
-            burst = np.where(burst == 0, e.cols.limit[rep], burst)
-            per.append((e, uniq, inv, rep, m, hits_sum, burst))
+            per.append((e, uniq, inv, rep, m, hits_sum))
 
         def cat(parts):
             # Uncontended drains (one entry) skip the copies.
@@ -1264,34 +1286,27 @@ class FastPath:
         sh = (
             (h_all.view(np.uint64) >> shift) % np.uint64(n_shards)
         ).astype(np.int32)
-        rnd, lane, n_rounds = native.assign_rounds(h_all, sh, n_shards, B)
-        values = dict(
-            key_hash=h_all,
-            hits=cat([p[5] for p in per]),
-            limit=cat([p[0].cols.limit[p[3]] for p in per]),
-            duration=cat([p[0].cols.duration[p[3]] for p in per]),
-            algo=cat([p[0].cols.algo[p[3]] for p in per]),
-            burst=cat([p[6] for p in per]),
-            reset_remaining=cat([
-                (p[0].cols.behavior[p[3]]
-                 & int(Behavior.RESET_REMAINING)) != 0
-                for p in per
-            ]),
-            is_greg=cat([p[0].is_greg[p[3]] for p in per]),
-            greg_expire=cat([p[0].ge[p[3]] for p in per]),
-            greg_duration=cat([p[0].gd[p[3]] for p in per]),
-            use_cached=np.ones(len(h_all), dtype=bool),
-        )
-        rounds, order, bounds = _build_rounds(
-            values, rnd, lane, sh, n_rounds, n_shards, B
+        # Round/lane assignment and the rounds in the device's layout, in
+        # one native call (the arrival shard is the same arithmetic there).
+        packed = self._pack_rounds(
+            h_all,
+            cat([p[5] for p in per]),
+            cat([p[0].cols.limit[p[3]] for p in per]),
+            cat([p[0].cols.duration[p[3]] for p in per]),
+            cat([p[0].cols.algo[p[3]] for p in per]),
+            cat([p[0].cols.burst[p[3]] for p in per]),
+            cat([p[0].cols.behavior[p[3]] for p in per]),
+            cat([p[0].is_greg[p[3]] for p in per]),
+            cat([p[0].ge[p[3]] for p in per]),
+            cat([p[0].gd[p[3]] for p in per]),
+            np.ones(len(h_all), dtype=bool),
+            mode=0, shard_shift=_ARRIVAL_SHIFT,
         )
         # _decode_unique yields groups in ascending-hash order — exactly
         # each entry's uniq order — so the decoded reqs zip with the
         # computed sums and arrival shards (one source of truth).
         pend = []
-        for i, (e, _uniq, _inv, _rep, _m, hits_sum, _burst) in enumerate(
-            per
-        ):
+        for i, (e, _uniq, _inv, _rep, _m, hits_sum) in enumerate(per):
             off = int(offs[i])
             for j, (req, _group) in enumerate(
                 self._decode_unique(e.payload, e.cols, e.idx)
@@ -1301,36 +1316,28 @@ class FastPath:
                 )
         # What this drain held: checks as the RPCs sent them, and the
         # device rounds they took.
-        pack.tally(checks=sum(len(e.idx) for e in entries), rounds=n_rounds)
+        pack.tally(
+            checks=sum(len(e.idx) for e in entries),
+            rounds=len(packed.rounds),
+        )
         pack.end()
-        resps, want_sync = engine.serve_packed(rounds, pend)
+        resps, want_sync = engine.serve_packed(packed.rounds, pend)
 
         def unpack(host) -> List[Tuple[np.ndarray, ...]]:
-            mt = len(h_all)
-            st_u = np.zeros(mt, dtype=np.int64)
-            lm_u = np.zeros(mt, dtype=np.int64)
-            rem_u = np.zeros(mt, dtype=np.int64)
-            rst_u = np.zeros(mt, dtype=np.int64)
-            for r_idx in range(n_rounds):
-                sel = order[bounds[r_idx]:bounds[r_idx + 1]]
-                hr = host[r_idx]
-                at = (sh[sel], lane[sel])
-                st_u[sel] = hr["status"][at]
-                lm_u[sel] = hr["limit"][at]
-                rem_u[sel] = hr["remaining"][at]
-                rst_u[sel] = hr["reset_time"][at]
-
-            t = tally_from_rounds(rounds, host)
+            got = native.gather_rounds(
+                packed, h_all, [_resp_words(hr) for hr in host], n_cols=4
+            )
+            st_u, lm_u, rem_u, rst_u = got.cols
             self.s.backend._add_tally(Tally(
-                checks=mt,
-                over_limit=int((st_u == 1).sum()),
-                not_persisted=t.not_persisted,
-                cache_hits=t.cache_hits,
+                checks=len(h_all),
+                over_limit=got.over_limit,
+                not_persisted=got.not_persisted,
+                cache_hits=got.cache_hits,
             ))
             if want_sync:
                 engine.sync()
             outs: List[Tuple[np.ndarray, ...]] = []
-            for i, (_e, _uq, inv, _rep, _m, _hits, _bst) in enumerate(per):
+            for i, (_e, _uq, inv, _rep, _m, _hits) in enumerate(per):
                 lo, hi = int(offs[i]), int(offs[i + 1])
                 outs.append((
                     st_u[lo:hi][inv], lm_u[lo:hi][inv],
@@ -1866,8 +1873,7 @@ class FastPath:
         )
 
     def _repair_cold_store_keys(
-        self, backend, uniq, foundv, h, cols_d, sh_all, n_shards, B,
-        now_ms, out_arrays,
+        self, backend, uniq, cols, answers, now_ms,
     ):
         """Post-step Store.get for COLD keys (backend lock held, response
         already fetched): the step's `found` column replaces the pre-step
@@ -1889,13 +1895,17 @@ class FastPath:
         may each go transient — the same acceptable-loss corner every
         insert path shares (architecture.md:5-11).
 
-        Returns None when nothing needed repair, else (new capture
-        token, its prefetched int host chunks)."""
+        `cols` are the drain's eleven columns as native.pack_rounds takes
+        them (hash first), `answers` the gather's int64[9, n] block, whose
+        rows the re-run's answers overwrite in place.  Returns None when
+        nothing needed repair, else (new capture token, its prefetched
+        int host chunks)."""
         from gubernator_tpu.runtime.backend import (
             _packed_resp_dict,
             fetch_ravel,
         )
 
+        h, foundv = cols[0], answers[5]
         uq, first = np.unique(h, return_index=True)
         fidx = dict(zip(uq.tolist(), first.tolist()))
         fps = list(uniq.keys())
@@ -1909,16 +1919,10 @@ class FastPath:
             return None
         rep_fps = [fps[i] for i in seeded]
         R = np.flatnonzero(np.isin(h, np.array(rep_fps, dtype=np.int64)))
-        r_sh = sh_all[R]
-        rrnd, rlane, rn = native.assign_rounds(
-            h[R], r_sh if n_shards > 1 else None, n_shards, B
-        )
-        rvals = {"key_hash": h[R]}
-        rvals.update({k: v[R] for k, v in cols_d.items()})
-        r_rounds, r_order, r_bounds = _build_rounds(
-            rvals, rrnd, rlane, r_sh, rn, n_shards, B
-        )
-        r_resps = backend._dispatch_rounds_locked(r_rounds, now_ms)
+        # Every occurrence of the seeded keys again, a round apart.
+        r_cols = [c[R] for c in cols]
+        r_packed = self._pack_rounds(*r_cols, mode=0)
+        r_resps = backend._dispatch_rounds_locked(r_packed.rounds, now_ms)
         cap_fps = np.array(
             [fp for fp, v in uniq.items() if v[2] is not None],
             dtype=np.int64,
@@ -1927,24 +1931,14 @@ class FastPath:
         cap_ints = backend._gather_rows_int_arrays(cap_token)
         hosts = fetch_ravel(list(r_resps) + cap_ints)
         nr = len(r_resps)
-        rhost = [_packed_resp_dict(a) for a in hosts[:nr]]
-        (status, out_lim, remaining, reset, stored, cachedv,
-         stored_st) = out_arrays
-        for r_idx in range(rn):
-            sub = r_order[r_bounds[r_idx]:r_bounds[r_idx + 1]]
-            sel = R[sub]
-            hr = rhost[r_idx]
-            if n_shards > 1:
-                idx = (r_sh[sub], rlane[sub])
-            else:
-                idx = (rlane[sub],)
-            status[sel] = hr["status"][idx]
-            out_lim[sel] = hr["limit"][idx]
-            remaining[sel] = hr["remaining"][idx]
-            reset[sel] = hr["reset_time"][idx]
-            stored[sel] = hr["stored"][idx]
-            cachedv[sel] = hr["cached"][idx]
-            stored_st[sel] = hr["stored_status"][idx]
+        again = native.gather_rounds(
+            r_packed, r_cols[0],
+            [_resp_words(_packed_resp_dict(a)) for a in hosts[:nr]],
+        )
+        # The answers a request reads, and the capture's: `persisted` and
+        # `found` (rows 4, 5) stay the first run's, what the tallies count.
+        for row in (0, 1, 2, 3, 6, 7, 8):
+            answers[row][R] = again.cols[row]
         return cap_token, hosts[nr:]
 
     def _build_captured(self, uniq, cap_fps, a, rf) -> list:
@@ -2029,23 +2023,24 @@ class FastPath:
 
         Duplicate-heavy batches (Zipfian hot keys) would otherwise explode
         into one device round PER OCCURRENCE of the hottest key; eligible
-        duplicate groups instead take the host-cascade path (_plan_cascade):
-        one read lane, an exact host-side replay of the per-occurrence
-        algorithm branches, and one effective write-back lane — two rounds
-        total regardless of skew.
+        duplicate groups (_plan_cascade states the rule; the native pack
+        applies it) instead take the host-cascade path: one read lane, an
+        exact host-side replay of the per-occurrence algorithm branches,
+        and one effective write-back lane — two rounds total regardless
+        of skew.
 
         A drain cascades only where that saves a device launch
-        (_cascade_or_rounds): the cascade pays for its rounds with the
-        response fetch INSIDE the backend lock and the dispatch stage, so
+        (_cascade_or_rounds states that rule): the cascade pays for its
+        rounds with the response fetch INSIDE the backend lock and the
+        dispatch stage, so
         a drain whose duplicates fit the rounds it takes anyway (one pair
         among 5,000 checks at batch_size 4096: two rounds either way)
         drops the plan and is a plain merge, each occurrence on a device
         lane of a later round than the one before it, the fetch on the
         fetch stage.  A store drain fetches inside the lock either way
         and keeps its plan."""
-        cfg = self.s.backend.cfg
-        n_shards = cfg.num_shards
-        B = cfg.batch_size
+        backend = self.s.backend
+        n_shards = backend.cfg.num_shards
 
         if len(entries) == 1:
             c = entries[0].cols
@@ -2066,17 +2061,7 @@ class FastPath:
             ge = np.concatenate([e.greg_expire for e in entries])
             gd = np.concatenate([e.greg_duration for e in entries])
             use_cached = np.concatenate([e.use_cached for e in entries])
-        n = len(h)
 
-        burst = np.where(burst == 0, lim, burst)
-        reset_remaining = (behavior & int(Behavior.RESET_REMAINING)) != 0
-
-        plan = _plan_cascade(h, hits, reset_remaining, is_greg,
-                             lim, dur, algo, burst, use_cached)
-
-        from gubernator_tpu.runtime.backend import packed_rounds_to_host
-
-        backend = self.s.backend
         store = backend.store
         uniq = (
             self._persist_decode(entries)
@@ -2091,97 +2076,48 @@ class FastPath:
             backend._maybe_prune_keymap()
         do_store = store is not None and bool(uniq)
 
-        if n_shards > 1:
-            from gubernator_tpu.parallel.mesh import shard_of_hash
-            from gubernator_tpu.parallel.sharded import (
-                packed_grid_rounds_to_host as to_host,
+        # The whole of the pack in one native call, the GIL released: the
+        # eligible duplicate groups (_plan_cascade's rule), whether they
+        # cascade (_cascade_or_rounds' rule; a store drain's always do),
+        # the (round, lane) of every check and the rounds as the device
+        # takes them, burst defaults and the RESET_REMAINING bit applied
+        # on the way.
+        packed = self._pack_rounds(
+            h, hits, lim, dur, algo, burst, behavior, is_greg, ge, gd,
+            use_cached, mode=2 if do_store else 1, cap_ok=True,
+        )
+        rounds = packed.rounds
+        plan = None
+        if packed.cascades:
+            plan = _CascadePlan(
+                occ=packed.occ, firsts=packed.firsts,
+                order=packed.order, bounds=packed.bounds,
             )
-
-            sh_all = shard_of_hash(h, n_shards).astype(np.int32)
-        else:
-            to_host = packed_rounds_to_host
-            sh_all = np.zeros(n, dtype=np.int32)
-        shards = sh_all if n_shards > 1 else None
-
-        h_mach, hits_mach, assigned = h, hits, None
-        if plan is not None:
-            h_mach = _read_lanes(plan, h)
-            if not do_store:
-                cascades, assigned = _cascade_or_rounds(
-                    plan, h, h_mach, use_cached, shards, n_shards, B
-                )
-                if not cascades:
-                    # The duplicates ride the rounds the drain has anyway.
-                    pack.tally(
-                        dup_plain=1,
-                        dup_lanes=int(plan.occ.sum()) - len(plan.groups),
-                    )
-                    plan, h_mach = None, h
-        if plan is not None:
-            hits_mach = hits.copy()
-            hits_mach[plan.firsts] = 0    # the read lane spends nothing
-            # What the replay will serve, for the lane.cascade row.
+            # What the replay reads of a group's first occurrence.
+            burst = np.where(burst == 0, lim, burst)
+            # What it will serve, for the lane.cascade row.
             casc_counts = dict(
-                groups=len(plan.groups), occ=int(plan.occ.sum()),
-                peeks=int((hits[plan.occ] == 0).sum()),
+                groups=packed.groups, occ=packed.occ_total,
+                peeks=packed.peeks,
             )
-        rnd, lane, n_rounds = assigned or native.assign_rounds(
-            h_mach, shards, n_shards, B
-        )
-
-        values = dict(
-            key_hash=h_mach, hits=hits_mach, limit=lim, duration=dur,
-            algo=algo, burst=burst, reset_remaining=reset_remaining,
-            is_greg=is_greg, greg_expire=ge, greg_duration=gd,
-            use_cached=use_cached,
-        )
-        rounds, order, bounds = _build_rounds(
-            values, rnd, lane, sh_all, n_rounds, n_shards, B
-        )
-
-        status = np.zeros(n, dtype=np.int64)
-        out_lim = np.zeros(n, dtype=np.int64)
-        remaining = np.zeros(n, dtype=np.int64)
-        reset = np.zeros(n, dtype=np.int64)
-        stored = np.zeros(n, dtype=np.int64)
-        cachedv = np.zeros(n, dtype=np.int64)
-        stored_st = np.zeros(n, dtype=np.int64)
-        foundv = np.zeros(n, dtype=np.int64)
-        persv = np.zeros(n, dtype=np.int64)
-
-        def gather(host) -> None:
-            for r_idx in range(n_rounds):
-                sel = order[bounds[r_idx]:bounds[r_idx + 1]]
-                hr = host[r_idx]
-                if n_shards > 1:
-                    idx = (sh_all[sel], lane[sel])
-                else:
-                    idx = (lane[sel],)
-                status[sel] = hr["status"][idx]
-                out_lim[sel] = hr["limit"][idx]
-                remaining[sel] = hr["remaining"][idx]
-                reset[sel] = hr["reset_time"][idx]
-                stored[sel] = hr["stored"][idx]
-                cachedv[sel] = hr["cached"][idx]
-                stored_st[sel] = hr["stored_status"][idx]
-                foundv[sel] = hr["found"][idx]
-                persv[sel] = hr["persisted"][idx]
+        elif packed.groups:
+            # The duplicates ride the rounds the drain has anyway.
+            pack.tally(
+                dup_plain=1, dup_lanes=packed.occ_total - packed.groups
+            )
 
         t_step0 = time.monotonic()
-        host_box: List = []  # [host] once the response reaches host
+        got: List = []  # [GatheredDrain] once the response is on the host
+
+        def gather(host) -> None:
+            got.append(native.gather_rounds(
+                packed, h, [_resp_words(hr) for hr in host]
+            ))
 
         def finish(unpack) -> List[Tuple[np.ndarray, ...]]:
-            # Device read lanes the step answered with `found` = 0: a
-            # key's first arrival or, where windows elapse inside a run,
-            # a new window opened (its own row expired).  Read from the
-            # fetched response; no device output is added for it.
-            unpack.tally(
-                new_windows=int(((foundv == 0) & (h_mach != 0)).sum())
-            )
             return self._finish_process(
-                entries, host_box[0], rounds, h, h_mach, foundv, persv,
-                status, out_lim, remaining, reset, stored, stored_st,
-                t_step0,
+                entries, packed, got[0], h, unpack,
+                replayed=plan is not None or do_store, t_step0=t_step0,
             )
 
         if plan is None and not do_store:
@@ -2195,10 +2131,10 @@ class FastPath:
             )
 
             def fetch_plain() -> List[Tuple[np.ndarray, ...]]:
-                host_box.append(fetch_host())
+                host = fetch_host()
                 self.blocking_fetches["mach"] += 1
                 with tracing.stage("lane.unpack") as unpack:
-                    gather(host_box[0])
+                    gather(host)
                     return finish(unpack)
 
             return fetch_plain
@@ -2222,6 +2158,14 @@ class FastPath:
         # drain may observe the interim state.  These in-lock fetches
         # belong to the DISPATCH stage by necessity; what moves to the
         # fetch stage is the rf fetch + write-through delivery below.
+        if n_shards > 1:
+            from gubernator_tpu.parallel.sharded import (
+                packed_grid_rounds_to_host as to_host,
+            )
+        else:
+            from gubernator_tpu.runtime.backend import (
+                packed_rounds_to_host as to_host,
+            )
         cap_token = wt_seq = None
         cap_fps = int_hosts = None
         pack.end()
@@ -2238,10 +2182,12 @@ class FastPath:
             now_ms = backend.clock.millisecond_now()
             resps = backend._dispatch_rounds_locked(rounds, now_ms)
             if plan is not None:
-                host_box.append(to_host(resps))
+                host = to_host(resps)
                 cascade = tracing.stage("lane.cascade")
                 cascade.tally(**casc_counts)
-                gather(host_box[0])
+                gather(host)
+                (status, out_lim, remaining, reset, _persv, foundv,
+                 stored, cachedv, stored_st) = got[0].cols
                 wb = _run_cascade(
                     plan, h, hits, lim, dur, algo, burst,
                     status, out_lim, remaining, reset, stored, cachedv,
@@ -2250,33 +2196,17 @@ class FastPath:
                 if wb is None:
                     cascade.end()
                 else:
-                    (wb_h, wb_hits, wb_lim, wb_dur, wb_algo,
-                     wb_burst) = wb
-                    wb_sh = (
-                        shard_of_hash(wb_h, n_shards).astype(np.int32)
-                        if n_shards > 1 else None
+                    # The write-back's lanes: no flag set, and a group's
+                    # two lanes (what it spent, then its status flip) in
+                    # that order, a round apart.
+                    wb_packed = self._pack_rounds(
+                        *wb, None, None, None, None, None, mode=0
                     )
-                    wrnd, wlane, wn = native.assign_rounds(
-                        wb_h, wb_sh, n_shards, B
-                    )
-                    m = len(wb_h)
-                    wvals = dict(
-                        key_hash=wb_h, hits=wb_hits, limit=wb_lim,
-                        duration=wb_dur, algo=wb_algo, burst=wb_burst,
-                        reset_remaining=np.zeros(m, dtype=bool),
-                        is_greg=np.zeros(m, dtype=bool),
-                        greg_expire=np.zeros(m, dtype=np.int64),
-                        greg_duration=np.zeros(m, dtype=np.int64),
-                    )
-                    wb_rounds, _, _ = _build_rounds(
-                        wvals, wrnd, wlane,
-                        wb_sh if wb_sh is not None
-                        else np.zeros(m, dtype=np.int32),
-                        wn, n_shards, B,
-                    )
-                    cascade.tally(wb_lanes=m)
+                    cascade.tally(wb_lanes=len(wb[0]))
                     cascade.end()
-                    backend._dispatch_rounds_locked(wb_rounds, now_ms)
+                    backend._dispatch_rounds_locked(
+                        wb_packed.rounds, now_ms
+                    )
             if do_store:
                 from gubernator_tpu.runtime.backend import (
                     _packed_resp_dict,
@@ -2297,23 +2227,15 @@ class FastPath:
                 if plan is None:
                     hosts = fetch_ravel(list(resps) + cap_ints)
                     nr = len(resps)
-                    host_box.append(
-                        [_packed_resp_dict(hh) for hh in hosts[:nr]]
-                    )
-                    gather(host_box[0])
+                    gather([_packed_resp_dict(hh) for hh in hosts[:nr]])
                     int_hosts = hosts[nr:]
                 else:
                     int_hosts = fetch_ravel(cap_ints)
                 rep = self._repair_cold_store_keys(
-                    backend, uniq, foundv, h, dict(
-                        hits=hits, limit=lim, duration=dur, algo=algo,
-                        burst=burst, reset_remaining=reset_remaining,
-                        is_greg=is_greg, greg_expire=ge,
-                        greg_duration=gd, use_cached=use_cached,
-                    ),
-                    sh_all, n_shards, B, now_ms,
-                    (status, out_lim, remaining, reset, stored,
-                     cachedv, stored_st),
+                    backend, uniq,
+                    (h, hits, lim, dur, algo, burst, behavior, is_greg,
+                     ge, gd, use_cached),
+                    got[0].cols, now_ms,
                 )
                 if rep is not None:
                     # Rows changed under the optimistic capture —
@@ -2361,35 +2283,43 @@ class FastPath:
         return fetch_locked_merge
 
     def _finish_process(
-        self, entries, host, rounds, h, h_mach, foundv, persv,
-        status, out_lim, remaining, reset, stored, stored_st, t_step0,
+        self, entries, packed, gathered, h, unpack, replayed, t_step0,
     ) -> List[Tuple[np.ndarray, ...]]:
         """Shared tail of a machinery merge's fetch stage: tallies,
-        flight-recorder record, spill pressure, the GLOBAL capture-
-        validity mask, and the per-entry split."""
-        from gubernator_tpu.runtime.backend import (
-            Tally,
-            tally_from_rounds,
-        )
+        flight-recorder record, spill pressure and the per-entry split,
+        from what the native pack (`packed`: the assignment, the GLOBAL
+        capture-validity mask) and gather (`gathered`: the nine columns
+        a check, the sums over the device lanes) made.  `replayed`: a
+        cascade's replay or a store repair wrote answers after the
+        gather, so over-limit is counted from the status column."""
+        from gubernator_tpu.runtime.backend import Tally
 
         backend = self.s.backend
-        n = len(h)
+        (status, out_lim, remaining, reset, persv, foundv, stored,
+         _cachedv, stored_st) = gathered.cols
+        # Device read lanes the step answered with `found` = 0: a key's
+        # first arrival or, where windows elapse inside a run, a new
+        # window opened (its own row expired).  Read from the fetched
+        # response; no device output is added for it.
+        unpack.tally(new_windows=gathered.lanes - gathered.cache_hits)
         # Metric parity: checks/over-limit from the per-REQUEST outputs
         # (cascade occurrences never had their own device lane); cache
         # hit/miss + eviction tallies from the device rounds.
         valid = h != 0
-        t = tally_from_rounds(rounds, host)
-        n_over = int((status[valid] == 1).sum())
+        n_over = (
+            int((status[valid] == 1).sum()) if replayed
+            else gathered.over_limit
+        )
         backend._add_tally(Tally(
-            checks=int(valid.sum()),
+            checks=packed.valid,
             over_limit=n_over,
-            not_persisted=t.not_persisted,
-            cache_hits=t.cache_hits,
+            not_persisted=gathered.not_persisted,
+            cache_hits=gathered.cache_hits,
         ))
         fr = getattr(self.s.metrics, "flightrec", None)
         if fr is not None:
             fr.record_batch(
-                int(valid.sum()), (time.monotonic() - t_step0) * 1e3,
+                packed.valid, (time.monotonic() - t_step0) * 1e3,
                 over_limit=n_over, kind="fastlane_drain",
             )
 
@@ -2423,32 +2353,28 @@ class FastPath:
 
         sb = self.s.sketch_backend
         if sb is not None and sb.spill_enabled:
-            # h_mach, not h: cascade-diverted duplicate occurrences never
-            # got a device lane — their persv stays 0 and raw h would
-            # count them as fake transients (a healthy hot key would
-            # self-degrade under Zipfian traffic).
-            self._note_spill_pressure(entries, h_mach, foundv, persv)
-
-        # GLOBAL broadcast capture validity, judged over the WHOLE merged
-        # drain (entries are concurrent RPCs; a per-entry view would miss
-        # another RPC's later occurrence of the same key): a lane may
-        # capture only if it is its key's LAST mutating occurrence in the
-        # merge.  Judged here — not at queue time — because entries queue
-        # their updates in COMPLETION order (remote forwards differ in
-        # latency), so a stale earlier occurrence could otherwise
-        # overwrite a fresh capture; with this mask it degrades to
-        # (req, None) instead, and the flush re-reads.  h == 0 lanes
-        # (errored) mutate nothing and never capture.
-        cap_ok = np.zeros(n, dtype=bool)
-        mut_idx = np.flatnonzero(h != 0)
-        if len(mut_idx):
-            last_of: Dict[int, int] = {}
-            for j in mut_idx:
-                last_of[int(h[j])] = int(j)
-            cap_ok[list(last_of.values())] = True
+            # The hashes as the device saw them, not h: cascade-diverted
+            # duplicate occurrences never got a device lane — their persv
+            # stays 0 and raw h would count them as fake transients (a
+            # healthy hot key would self-degrade under Zipfian traffic).
+            self._note_spill_pressure(
+                entries, np.where(packed.rnd >= 0, h, 0), foundv, persv
+            )
 
         # Split back per entry (stored/stored_status/cap_ok feed the
-        # GLOBAL broadcast capture; see _queue_global_updates).
+        # GLOBAL broadcast capture; see _queue_global_updates).  cap_ok,
+        # the capture's validity, is judged over the WHOLE merged drain
+        # (entries are concurrent RPCs; a per-entry view would miss
+        # another RPC's later occurrence of the same key): a lane may
+        # capture only if it is its key's LAST mutating occurrence in the
+        # merge.  Judged per drain — not at queue time — because entries
+        # queue their updates in COMPLETION order (remote forwards differ
+        # in latency), so a stale earlier occurrence could otherwise
+        # overwrite a fresh capture; with this mask it degrades to
+        # (req, None) instead, and the flush re-reads.  h == 0 lanes
+        # (errored) mutate nothing and never capture.  The pack's one
+        # hash map knows each key's last occurrence.
+        cap_ok = packed.cap_ok
         outs: List[Tuple[np.ndarray, ...]] = []
         off = 0
         for e in entries:
@@ -2528,6 +2454,19 @@ class _EngineEntry:
         self.trace_ctx = None
 
 
+def _resp_words(hr) -> np.ndarray:
+    """One round's fetched response as the native gather reads it: the
+    int64[9, t] / [n_shards, 9, t] words `_packed_resp_dict` made its
+    columns of, or, of a plain dict of columns (a test's, a harness's
+    altered one), the columns stacked back into that layout."""
+    from gubernator_tpu.runtime.backend import RESP_FIELDS
+
+    words = getattr(hr, "words", None)
+    if words is None:
+        words = np.stack([hr[f] for f in RESP_FIELDS], axis=-2)
+    return words
+
+
 def _build_rounds(values, rnd, lane, sh_all, n_rounds, n_shards, B):
     """Scatter columnar values into fixed-shape DeviceBatch rounds.
     Returns (rounds, order, bounds) — order/bounds group request indices
@@ -2550,14 +2489,27 @@ def _build_rounds(values, rnd, lane, sh_all, n_rounds, n_shards, B):
 
 
 class _CascadePlan:
-    __slots__ = ("occ", "firsts", "groups", "inv", "first_idx")
+    """The duplicate groups of a drain that the host cascade serves.  The
+    served lane's comes from the native pack (native.PackedDrain), with
+    the groups in ascending order of the signed hash; _plan_cascade
+    builds the same from numpy, the reference the tests hold it to, and
+    keeps np.unique's `groups` / `inv` / `first_idx` beside it."""
 
-    def __init__(self, occ, firsts, groups, inv, first_idx):
+    __slots__ = ("occ", "firsts", "order", "bounds", "groups", "inv",
+                 "first_idx")
+
+    def __init__(self, occ, firsts, order, bounds, groups=None, inv=None,
+                 first_idx=None):
         self.occ = occ          # bool[n]: occurrence is in a cascade group
-        self.firsts = firsts    # int[-]: first-occurrence index per group
-        self.groups = groups    # int[-]: group ids (into inv's codomain)
+        self.firsts = firsts    # int[G]: first-occurrence index per group
+        # Group g's occurrences in arrival order:
+        # order[bounds[g]:bounds[g + 1]].  _run_cascade sends the groups'
+        # write-back lanes in this order of groups.
+        self.order = order
+        self.bounds = bounds
+        self.groups = groups    # int[G]: group ids (into inv's codomain)
         self.inv = inv          # int[n]: np.unique inverse (key group id)
-        self.first_idx = first_idx    # int[nb]: first occurrence per group
+        self.first_idx = first_idx    # int[nb]: first occurrence per key
 
 
 def _plan_cascade(h, hits, reset_remaining, is_greg, lim, dur, algo, burst,
@@ -2609,9 +2561,14 @@ def _plan_cascade(h, hits, reset_remaining, is_greg, lim, dur, algo, burst,
 
     if not casc.any():
         return None
+    occ = casc[inv]
+    at = np.flatnonzero(occ)
     return _CascadePlan(
-        occ=casc[inv],
+        occ=occ,
         firsts=first_idx[casc],
+        # Occurrence lists per group, in arrival order, via one argsort.
+        order=at[np.argsort(inv[at], kind="stable")],
+        bounds=np.concatenate([[0], np.cumsum(counts[casc])]),
         groups=np.flatnonzero(casc),
         inv=inv,
         first_idx=first_idx,
@@ -2652,6 +2609,101 @@ def _cascade_or_rounds(plan, h, h_mach, use_cached, shards, n_shards, B):
     if plain[2] > reads[2] + write_back:
         return True, reads
     return False, plain
+
+
+def _reference_pack(cols, n_shards, B, tiers, mode, shard_shift):
+    """A drain's pack as numpy made it until PR 42, whole: what
+    native.pack_rounds (gub_pack_rounds) is held to bit for bit
+    (tests/test_pack_native.py) and timed against
+    (scripts/pack_bench.py).  `cols`, `mode`, `shard_shift` as
+    native.pack_rounds takes them.  Serves nothing."""
+    from gubernator_tpu.parallel.sharded import pack_grid_batch
+    from gubernator_tpu.runtime.backend import pack_batch_q, tier_of
+
+    h, hits, lim, dur, algo, burst, behavior, is_greg, ge, gd, cached = cols
+    n = len(h)
+    burst = np.where(burst == 0, lim, burst)
+    reset_remaining = (behavior & int(Behavior.RESET_REMAINING)) != 0
+    if n_shards > 1:
+        sh_all = (
+            (h.view(np.uint64) >> np.uint64(shard_shift))
+            % np.uint64(n_shards)
+        ).astype(np.int32)
+    else:
+        sh_all = np.zeros(n, dtype=np.int32)
+    shards = sh_all if n_shards > 1 else None
+    groups = _plan_cascade(
+        h, hits, reset_remaining, is_greg, lim, dur, algo, burst, cached
+    ) if mode else None
+    plan, h_mach, hits_mach, assigned = groups, h, hits, None
+    if plan is not None:
+        h_mach = _read_lanes(plan, h)
+        cascades = True
+        if mode == 1:
+            cascades, assigned = _cascade_or_rounds(
+                plan, h, h_mach, cached, shards, n_shards, B
+            )
+        if not cascades:
+            plan, h_mach = None, h
+    if plan is not None:
+        hits_mach = hits.copy()
+        hits_mach[plan.firsts] = 0    # the read lane spends nothing
+    rnd, lane, n_rounds = assigned or native.assign_rounds(
+        h_mach, shards, n_shards, B
+    )
+    values = dict(
+        key_hash=h_mach, hits=hits_mach, limit=lim, duration=dur,
+        algo=algo, burst=burst, reset_remaining=reset_remaining,
+        is_greg=is_greg, greg_expire=ge, greg_duration=gd,
+        use_cached=cached,
+    )
+    rounds, order, bounds = _build_rounds(
+        values, rnd, lane, sh_all, n_rounds, n_shards, B
+    )
+    # backend.dispatch's copy, under backend._lock, cut to the tier.
+    pack = pack_grid_batch if n_shards > 1 else pack_batch_q
+    words = [pack(db)[..., :tier_of(db.active, tiers)] for db in rounds]
+    return dict(
+        words=words, rounds=rounds, rnd=rnd, lane=lane, order=order,
+        bounds=bounds, sh_all=sh_all, h_mach=h_mach, groups=groups,
+        cascades=plan is not None,
+    )
+
+
+def _reference_unpack(ref, h, host, n_shards):
+    """A drain's unpack as numpy and Python made it until PR 42, over
+    `_reference_pack`'s `ref` and the rounds' host response dicts: the
+    nine columns a check, the tallies' sums and `cap_ok`; what
+    native.gather_rounds (and the pack's `cap_ok`) are held to."""
+    from gubernator_tpu.runtime.backend import (
+        RESP_FIELDS,
+        tally_from_rounds,
+    )
+
+    n = len(h)
+    cols = {f: np.zeros(n, dtype=np.int64) for f in RESP_FIELDS}
+    order, bounds, lane = ref["order"], ref["bounds"], ref["lane"]
+    for r_idx, hr in enumerate(host):
+        sel = order[bounds[r_idx]:bounds[r_idx + 1]]
+        if n_shards > 1:
+            idx = (ref["sh_all"][sel], lane[sel])
+        else:
+            idx = (lane[sel],)
+        for f in RESP_FIELDS:
+            cols[f][sel] = hr[f][idx]
+    t = tally_from_rounds(ref["rounds"], host)
+    h_mach = ref["h_mach"]
+    cap_ok = np.zeros(n, dtype=bool)
+    last_of: Dict[int, int] = {}
+    for j in np.flatnonzero(h != 0):
+        last_of[int(h[j])] = int(j)
+    cap_ok[list(last_of.values())] = True
+    return cols, dict(
+        over_limit=int((cols["status"][h_mach != 0] == 1).sum()),
+        not_persisted=t.not_persisted, cache_hits=t.cache_hits,
+        lanes=t.checks,
+        new_windows=int(((cols["found"] == 0) & (h_mach != 0)).sum()),
+    ), cap_ok
 
 
 def _run_cascade(plan, h, hits, lim, dur, algo, burst,
@@ -2697,13 +2749,9 @@ def _run_cascade(plan, h, hits, lim, dur, algo, burst,
     wb_algo: List[int] = []
     wb_burst: List[int] = []
 
-    # Occurrence lists per group, in arrival order, via one argsort.
-    order = np.argsort(plan.inv, kind="stable")
-    sorted_inv = plan.inv[order]
-    for g in plan.groups:
-        lo = np.searchsorted(sorted_inv, g)
-        hi = np.searchsorted(sorted_inv, g, side="right")
-        occ = order[lo:hi]
+    bounds = plan.bounds.tolist()
+    for g in range(len(plan.firsts)):
+        occ = plan.order[bounds[g]:bounds[g + 1]]
         fi = occ[0]
         if cachedv[fi]:
             # Verbatim broadcast-row serve: share, mutate nothing.

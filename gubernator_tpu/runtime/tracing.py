@@ -700,23 +700,25 @@ STAGES: Dict[str, str] = {
                     "thread, dispatch and fetch stage each",
     "lane.resume": "last line on the pool thread -> the coalescer task "
                    "resumes on the loop, dispatch and fetch stage each",
-    "lane.pack": "process() up to the device dispatch (concatenate, "
-                 "cascade plan, assign_rounds, build rounds)",
+    "lane.pack": "process() up to the device dispatch: concatenate, then "
+                 "one native pass with the GIL released (cascade plan, "
+                 "round/lane assignment, the rounds in the step's layout)",
     "lane.cascade": "inside a cascade merge's locked window: gather, "
                     "host replay, write-back rounds; counters groups "
                     "(duplicate groups replayed), occ (their occurrences), "
                     "peeks (of those, hits == 0), wb_lanes (lanes of the "
                     "write-back rounds the merge sent)",
-    "lane.unpack": "gather + finish (tallies, capture mask, per-entry "
-                   "split) after the answer is on the host; counter "
+    "lane.unpack": "one native gather with the GIL released + finish "
+                   "(tallies, per-entry split) after the answer is on "
+                   "the host; counter "
                    "new_windows (machinery lane: device read lanes "
                    "answered with found = 0)",
     "lane.dispatch_stage": "the coalescer's side of the dispatch stage "
                            "(feeds fastpath_stage_duration)",
     "lane.fetch_stage": "the coalescer's side of the fetch stage",
     "backend.lock_wait": "blocked on backend._lock / engine._lock",
-    "backend.dispatch": "pack_batch_q / shard_args and the enqueue of "
-                        "each round's program (feeds "
+    "backend.dispatch": "the enqueue of each round's program, packed "
+                        "already (compiled lane) or packed here (feeds "
                         "gubernator_tpu_device_step_duration)",
     "backend.d2h_wait": "fetch_ravel: blocked until the answer is on "
                         "the host",

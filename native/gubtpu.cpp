@@ -13,10 +13,34 @@
 //                        duplicate detection (ops/batch.py's contract:
 //                        occurrence k of a key lands in a strictly later
 //                        round than occurrence k-1)
+//   gub_pack_rounds    — a coalescer drain's `lane.pack` in one pass: one
+//                        hash map over the drain's key hashes gives the
+//                        duplicate groups the host cascade may take, the
+//                        plain and the read-lane assignments (the rule of
+//                        gub_assign_rounds, twice over one map), which of
+//                        the two saves a device launch, and the rounds of
+//                        the one chosen, written once into the device's
+//                        own layout: int64[12, t] a round on one chip,
+//                        int64[12, n_shards, t] on a mesh, rows in
+//                        DeviceBatch field order (key_hash, hits, limit,
+//                        duration, algo, burst, reset_remaining, is_greg,
+//                        greg_expire, greg_duration, active, use_cached),
+//                        t the smallest compiled tier that holds the
+//                        round's fullest shard, unused lanes zero
+//   gub_gather_rounds  — `lane.unpack`: the fetched responses (int64[9, t]
+//                        a round, or [n_shards, 9, t]; rows status, limit,
+//                        remaining, reset_time, persisted, found, stored,
+//                        cached, stored_status) walked back through that
+//                        assignment into int64[k, n] per-check columns,
+//                        with the sums the tallies take
+//
+// ctypes releases the GIL for the length of a call: a drain's pack and
+// unpack run beside the other lanes' Python, not in turn with it.
 //
 // Build: make -C native  (g++ -O3 -shared; no external dependencies —
 // XXH64 is implemented from its public spec below).
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
 #include <vector>
@@ -154,6 +178,40 @@ struct RoundMap {
   }
 };
 
+// The lane counters of an assignment: counters[r * n_shards + s] = lanes
+// used.  The keysets per round for the "key not in round" check are
+// implied by last_round tracking: a key's next occurrence starts probing
+// at last_round+1, and WITHIN one probe sequence only capacity can force
+// extra rounds, never the same key.
+struct LaneCounters {
+  std::vector<int32_t> used;
+  int64_t n_rounds = 0;
+  int32_t n_shards, batch_size;
+  LaneCounters(int32_t n_shards, int32_t batch_size)
+      : n_shards(n_shards), batch_size(batch_size) {}
+  // Place a key's next occurrence on shard s: the first round after
+  // *last_round with a free lane there.
+  inline void place(int32_t* last_round, int32_t s, int32_t* out_round,
+                    int32_t* out_lane) {
+    int32_t r = *last_round + 1;
+    for (;;) {
+      if (r >= n_rounds) {
+        used.resize((size_t)(r + 1) * n_shards, 0);
+        n_rounds = r + 1;
+      }
+      int32_t& c = used[(size_t)r * n_shards + s];
+      if (c < batch_size) {
+        *out_round = r;
+        *out_lane = c;
+        c++;
+        *last_round = r;
+        return;
+      }
+      r++;
+    }
+  }
+};
+
 // Assign each request a (round, lane) such that:
 //  - a key hash appears at most once per round,
 //  - occurrence k of a key lands in a strictly later round than k-1,
@@ -164,12 +222,7 @@ int64_t gub_assign_rounds(const int64_t* hashes, const int32_t* shards,
                           int64_t n, int32_t n_shards, int32_t batch_size,
                           int32_t* out_round, int32_t* out_lane) {
   RoundMap seen(n);
-  // counters[r * n_shards + s] = lanes used; keysets per round for the
-  // "key not in round" check are implied by last_round tracking: a key's
-  // next occurrence starts probing at last_round+1, and WITHIN one probe
-  // sequence only capacity can force extra rounds, never the same key.
-  std::vector<int32_t> counters;
-  int64_t n_rounds = 0;
+  LaneCounters lanes(n_shards, batch_size);
   for (int64_t i = 0; i < n; i++) {
     uint64_t h = (uint64_t)hashes[i];
     if (h == 0) {
@@ -177,26 +230,300 @@ int64_t gub_assign_rounds(const int64_t* hashes, const int32_t* shards,
       out_lane[i] = -1;
       continue;
     }
-    int32_t s = shards ? shards[i] : 0;
-    int32_t* lr = seen.slot(h);
-    int32_t r = *lr + 1;
-    for (;;) {
-      if (r >= n_rounds) {
-        counters.resize((size_t)(r + 1) * n_shards, 0);
-        n_rounds = r + 1;
+    lanes.place(seen.slot(h), shards ? shards[i] : 0, &out_round[i],
+                &out_lane[i]);
+  }
+  return lanes.n_rounds;
+}
+
+// ---------------------------------------------------------------------------
+// A drain's pack and unpack (runtime/fastpath.py _process_packed,
+// _engine_process_packed)
+// ---------------------------------------------------------------------------
+
+// One distinct key of a drain, in order of first appearance.
+struct DrainKey {
+  int64_t first, last;  // first and last occurrence
+  int32_t count;
+  int32_t last_round;   // the running assignment's cursor
+  int32_t group;        // cascade group number, -1: keeps its rounds
+  bool same;            // one limit / duration / algo / burst / use_cached
+  bool bad;             // negative hits, RESET_REMAINING or Gregorian met
+};
+
+enum {
+  GUB_META_ROUNDS = 0,    // rounds of the assignment chosen
+  GUB_META_WORDS = 1,     // arena words they take
+  GUB_META_CASCADES = 2,  // 1: the read-lane assignment was chosen
+  GUB_META_GROUPS = 3,    // eligible duplicate groups
+  GUB_META_OCC = 4,       // their occurrences
+  GUB_META_PEEKS = 5,     // hits == 0 among those
+  GUB_META_LANES = 6,     // lanes assigned
+  GUB_META_VALID = 7,     // checks with hash != 0
+  GUB_META_HEAD = 8,      // then (tier, lanes, arena offset) a round
+};
+
+// mode 0: the plain assignment (gub_assign_rounds' own).
+// mode 1: the host cascade where it saves a launch: eligible groups keep
+//         one READ lane (their first occurrence, hits 0) iff the plain
+//         assignment takes more rounds than the read lanes' plus one for
+//         the write-back, which groups that are all use_cached never send.
+// mode 2: the cascade wherever a group is eligible.
+//
+// A group is eligible as fastpath._plan_cascade has it: more than one
+// occurrence, one limit / duration / algorithm / burst (burst 0 reads as
+// limit) / use_cached, no occurrence with negative hits, RESET_REMAINING
+// (behavior & reset_bit) or a Gregorian duration.  behavior, is_greg,
+// greg_expire, greg_duration, use_cached may be null: all zero.
+//
+// shard of a hash: ((uint64)h >> shard_shift) % n_shards.
+//
+// arena/meta: the caller's; nothing is written to arena unless the rounds
+// fit (arena_words, max_rounds), and the return is then 1 with
+// meta[ROUNDS] and meta[WORDS] saying what they take.  Round r is
+// arena[off : off + 12 * n_shards * t], meta[HEAD + 3r ..] = (t, lanes, off).
+//
+// Where groups are eligible (meta[GROUPS] > 0; modes 1, 2): occ[i] = 1 on
+// their occurrences, and the groups, numbered in ascending order of the
+// signed hash as np.unique numbers them (the write-back's lanes go out in
+// that order), each with its first occurrence firsts[g] and its
+// occurrences in arrival order order[bounds[g] : bounds[g + 1]].
+// cap_ok[i] = 1 where i is the last occurrence of its key (may be null).
+int64_t gub_pack_rounds(
+    int64_t n, const int64_t* hash, const int64_t* hits,
+    const int64_t* limit, const int64_t* duration, const int32_t* algo,
+    const int64_t* burst, const int64_t* behavior, const uint8_t* is_greg,
+    const int64_t* greg_expire, const int64_t* greg_duration,
+    const uint8_t* use_cached, int64_t reset_bit, int32_t n_shards,
+    int32_t shard_shift, int32_t batch_size, const int32_t* tiers,
+    int32_t n_tiers, int32_t mode, int64_t* arena, int64_t arena_words,
+    int64_t* meta, int64_t max_rounds, int32_t* out_round,
+    int32_t* out_lane, uint8_t* cap_ok, uint8_t* occ, int64_t* firsts,
+    int64_t* order, int64_t* bounds) {
+  RoundMap seen(n);  // hash -> number of the key (its last_round slot)
+  std::vector<DrainKey> keys;
+  std::vector<int32_t> key_of((size_t)n), shard((size_t)n);
+  auto burst_of = [&](int64_t i) { return burst[i] ? burst[i] : limit[i]; };
+  auto cached_of = [&](int64_t i) {
+    return use_cached ? use_cached[i] != 0 : false;
+  };
+  int64_t valid = 0;
+  for (int64_t i = 0; i < n; i++) {
+    uint64_t h = (uint64_t)hash[i];
+    if (h == 0) {
+      key_of[i] = -1;
+      continue;
+    }
+    valid++;
+    shard[i] = n_shards > 1 ? (int32_t)((h >> shard_shift) % n_shards) : 0;
+    int32_t* k = seen.slot(h);
+    if (*k < 0) {
+      *k = (int32_t)keys.size();
+      keys.push_back({i, i, 0, -1, -1, true, false});
+    }
+    key_of[i] = *k;
+    DrainKey& key = keys[*k];
+    key.count++;
+    key.last = i;
+    if (mode == 0) continue;
+    int64_t f = key.first;
+    key.same = key.same && limit[i] == limit[f] &&
+               duration[i] == duration[f] && algo[i] == algo[f] &&
+               burst_of(i) == burst_of(f) && cached_of(i) == cached_of(f);
+    key.bad = key.bad || hits[i] < 0 ||
+              (behavior && (behavior[i] & reset_bit)) ||
+              (is_greg && is_greg[i]);
+  }
+  if (cap_ok) {
+    std::memset(cap_ok, 0, (size_t)n);
+    for (const DrainKey& key : keys) cap_ok[key.last] = 1;
+  }
+
+  // The eligible groups, numbered in ascending order of the signed hash.
+  std::vector<int32_t> groups;
+  if (mode != 0) {
+    for (size_t k = 0; k < keys.size(); k++)
+      if (keys[k].count > 1 && keys[k].same && !keys[k].bad)
+        groups.push_back((int32_t)k);
+    std::sort(groups.begin(), groups.end(), [&](int32_t a, int32_t b) {
+      return hash[keys[a].first] < hash[keys[b].first];
+    });
+  }
+  int64_t occ_total = 0, peeks = 0;
+  bool write_back = false;
+  for (size_t g = 0; g < groups.size(); g++) {
+    DrainKey& key = keys[groups[g]];
+    key.group = (int32_t)g;
+    occ_total += key.count;
+    write_back = write_back || !cached_of(key.first);
+  }
+
+  // The assignment: of every check (plain), or with each group's later
+  // occurrences diverted (reads).
+  auto assign = [&](bool reads, LaneCounters& lanes, int32_t* rnd,
+                    int32_t* lane) {
+    for (DrainKey& key : keys) key.last_round = -1;
+    for (int64_t i = 0; i < n; i++) {
+      int32_t k = key_of[i];
+      if (k < 0 || (reads && keys[k].group >= 0 && keys[k].first != i)) {
+        rnd[i] = -1;
+        lane[i] = -1;
+        continue;
       }
-      int32_t& c = counters[(size_t)r * n_shards + s];
-      if (c < batch_size) {
-        out_round[i] = r;
-        out_lane[i] = c;
-        c++;
-        *lr = r;
-        break;
-      }
-      r++;
+      lanes.place(&keys[k].last_round, shard[i], &rnd[i], &lane[i]);
+    }
+  };
+  LaneCounters lanes(n_shards, batch_size);
+  bool cascades = false;
+  if (groups.empty()) {
+    assign(false, lanes, out_round, out_lane);
+  } else if (mode == 2) {
+    cascades = true;
+    assign(true, lanes, out_round, out_lane);
+  } else {
+    LaneCounters read_lanes(n_shards, batch_size);
+    std::vector<int32_t> read_rnd((size_t)n), read_lane((size_t)n);
+    assign(false, lanes, out_round, out_lane);
+    assign(true, read_lanes, read_rnd.data(), read_lane.data());
+    if (lanes.n_rounds > read_lanes.n_rounds + (write_back ? 1 : 0)) {
+      cascades = true;
+      lanes = std::move(read_lanes);
+      std::memcpy(out_round, read_rnd.data(), (size_t)n * sizeof(int32_t));
+      std::memcpy(out_lane, read_lane.data(), (size_t)n * sizeof(int32_t));
     }
   }
-  return n_rounds;
+
+  if (!groups.empty()) {
+    std::memset(occ, 0, (size_t)n);
+    bounds[0] = 0;
+    for (size_t g = 0; g < groups.size(); g++) {
+      firsts[g] = keys[groups[g]].first;
+      bounds[g + 1] = bounds[g] + keys[groups[g]].count;
+    }
+    std::vector<int64_t> at(bounds, bounds + groups.size());
+    for (int64_t i = 0; i < n; i++) {
+      int32_t g = key_of[i] < 0 ? -1 : keys[key_of[i]].group;
+      if (g < 0) continue;
+      occ[i] = 1;
+      order[at[g]++] = i;
+      if (hits[i] == 0) peeks++;
+    }
+  }
+
+  // Each round's tier and its place in the arena.
+  int64_t n_rounds = lanes.n_rounds, words = 0, n_lanes = 0;
+  meta[GUB_META_ROUNDS] = n_rounds;
+  meta[GUB_META_CASCADES] = cascades ? 1 : 0;
+  meta[GUB_META_GROUPS] = (int64_t)groups.size();
+  meta[GUB_META_OCC] = occ_total;
+  meta[GUB_META_PEEKS] = peeks;
+  meta[GUB_META_VALID] = valid;
+  std::vector<int64_t> tier((size_t)n_rounds), off((size_t)n_rounds);
+  for (int64_t r = 0; r < n_rounds; r++) {
+    int32_t fullest = 0;
+    int64_t in_round = 0;
+    for (int32_t s = 0; s < n_shards; s++) {
+      int32_t c = lanes.used[(size_t)r * n_shards + s];
+      fullest = c > fullest ? c : fullest;
+      in_round += c;
+    }
+    int64_t t = tiers[n_tiers - 1];
+    for (int32_t j = 0; j < n_tiers; j++)
+      if (fullest <= tiers[j]) {
+        t = tiers[j];
+        break;
+      }
+    tier[r] = t;
+    off[r] = words;
+    words += 12 * (int64_t)n_shards * t;
+    n_lanes += in_round;
+    if (r < max_rounds) {
+      meta[GUB_META_HEAD + 3 * r] = t;
+      meta[GUB_META_HEAD + 3 * r + 1] = in_round;
+      meta[GUB_META_HEAD + 3 * r + 2] = off[r];
+    }
+  }
+  meta[GUB_META_WORDS] = words;
+  meta[GUB_META_LANES] = n_lanes;
+  if (n_rounds > max_rounds || words > arena_words) return 1;
+
+  // The rounds, a field at a time: each field's row of a round is filled
+  // in rising lane order, a shard after another.
+  std::memset(arena, 0, (size_t)words * sizeof(int64_t));
+  std::vector<int64_t> at0((size_t)n), row((size_t)n);
+  for (int64_t i = 0; i < n; i++) {
+    int32_t r = out_round[i];
+    if (r < 0) {
+      at0[i] = -1;
+      continue;
+    }
+    at0[i] = off[r] + (int64_t)shard[i] * tier[r] + out_lane[i];
+    row[i] = (int64_t)n_shards * tier[r];
+  }
+  auto fill = [&](int field, auto value) {
+    for (int64_t i = 0; i < n; i++)
+      if (at0[i] >= 0) arena[at0[i] + field * row[i]] = (int64_t)value(i);
+  };
+  fill(0, [&](int64_t i) { return hash[i]; });
+  // A read lane spends nothing: the replay serves its group.
+  fill(1, [&](int64_t i) {
+    return cascades && keys[key_of[i]].group >= 0 ? 0 : hits[i];
+  });
+  fill(2, [&](int64_t i) { return limit[i]; });
+  fill(3, [&](int64_t i) { return duration[i]; });
+  fill(4, [&](int64_t i) { return algo[i]; });
+  fill(5, burst_of);
+  if (behavior)
+    fill(6, [&](int64_t i) { return (behavior[i] & reset_bit) != 0; });
+  if (is_greg) fill(7, [&](int64_t i) { return is_greg[i] != 0; });
+  if (greg_expire) fill(8, [&](int64_t i) { return greg_expire[i]; });
+  if (greg_duration) fill(9, [&](int64_t i) { return greg_duration[i]; });
+  fill(10, [&](int64_t) { return 1; });
+  if (use_cached) fill(11, [&](int64_t i) { return use_cached[i] != 0; });
+  return 0;
+}
+
+// The responses of a drain's rounds back in the order of its checks.
+// resp[r] is round r's fetched buffer, int64[9, tier[r]] (n_shards 1) or
+// int64[n_shards, 9, tier[r]]; check i sits at (round[i], shard of
+// hash[i], lane[i]), nowhere where round[i] < 0 (its columns read 0).
+// out is int64[n_cols, n]: the first n_cols response rows (9: all; 4:
+// status, limit, remaining, reset_time).  sums[0..3]: over the lanes
+// read, status == 1, persisted == 0, found != 0, and their number.
+void gub_gather_rounds(int64_t n, const int64_t* hash, const int32_t* round,
+                       const int32_t* lane, int32_t n_shards,
+                       int32_t shard_shift, const int64_t* const* resp,
+                       const int64_t* tier, int32_t n_cols, int64_t* out,
+                       int64_t* sums) {
+  std::vector<const int64_t*> at((size_t)n);
+  std::vector<int64_t> row((size_t)n);
+  int64_t over = 0, not_persisted = 0, found = 0, lanes = 0;
+  for (int64_t i = 0; i < n; i++) {
+    int32_t r = round[i];
+    if (r < 0) {
+      at[i] = nullptr;
+      continue;
+    }
+    int64_t t = tier[r];
+    int64_t s =
+        n_shards > 1 ? (int64_t)(((uint64_t)hash[i] >> shard_shift) % n_shards)
+                     : 0;
+    const int64_t* p = resp[r] + s * 9 * t + lane[i];
+    at[i] = p;
+    row[i] = t;
+    lanes++;
+    over += p[0] == 1;
+    not_persisted += p[4 * t] == 0;
+    found += p[5 * t] != 0;
+  }
+  for (int32_t f = 0; f < n_cols; f++) {
+    int64_t* col = out + (int64_t)f * n;
+    for (int64_t i = 0; i < n; i++) col[i] = at[i] ? at[i][f * row[i]] : 0;
+  }
+  sums[0] = over;
+  sums[1] = not_persisted;
+  sums[2] = found;
+  sums[3] = lanes;
 }
 
 // ---------------------------------------------------------------------------
