@@ -8,6 +8,12 @@ GetPeerRateLimits RPC whose responses are demultiplexed back to the waiting
 callers in order (peers.proto order-preservation contract).  NO_BATCHING
 requests bypass the queue with a direct single-item RPC.
 
+The compiled lane's forwards (`forward_raw`) pass through the same window
+under the same two settings, as bytes: what concurrent client RPCs send
+to this peer is concatenated into ONE GetPeerRateLimits under one forward
+id, and the answer is handed back by count and in order
+(docs/cluster.md "The peer batcher").
+
 Differences from the reference are deliberate asyncio re-expressions:
 goroutine+channel batcher -> asyncio task + futures; WaitGroup drain on
 shutdown -> in-flight counter + event.  The rolling per-peer error window
@@ -17,14 +23,21 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import itertools
 import logging
+import os
 import time
 from typing import Deque, List, Optional, Tuple
 
 import grpc
 import grpc.aio
 
-from gubernator_tpu.core.config import BehaviorConfig, CircuitConfig
+from gubernator_tpu import native
+from gubernator_tpu.core.config import (
+    MAX_BATCH_SIZE,
+    BehaviorConfig,
+    CircuitConfig,
+)
 from gubernator_tpu.core.types import (
     Behavior,
     LeaseGrant,
@@ -70,6 +83,32 @@ FORWARD_TRIES = 4
 class PeerNotReadyError(RuntimeError):
     """Routing-layer retry signal: peer is shutting down or unreachable
     (the reference's PeerErr/IsNotReady, peer_client.go:549-573)."""
+
+
+class PeerAnswerError(RuntimeError):
+    """A GetPeerRateLimits answer ARRIVED and cannot be handed out: not
+    parseable, or not one response a check.  The peer applied the batch,
+    so it is never sent again: every check of it reads this error."""
+
+
+class ForwardExpiredError(TimeoutError):
+    """A client RPC's deadline ended while its forward waited in the
+    batch window: the forward was taken out before the batch was sent,
+    and spent nothing."""
+
+
+class _RawForward:
+    """One client RPC's checks for this peer, from `forward_raw` to the
+    answer of the GetPeerRateLimits that carried them."""
+
+    __slots__ = ("payload", "n", "deadline", "fut", "wait")
+
+    def __init__(self, payload, n, deadline, fut, wait) -> None:
+        self.payload = payload      # spliced `requests` frames
+        self.n = n                  # checks in them
+        self.deadline = deadline    # the client's own (monotonic), or None
+        self.fut = fut              # -> (raw answer, its columns, offset)
+        self.wait = wait            # the open peer.batch_wait stage
 
 
 # Connect-phase failure markers, matched against BOTH details() and
@@ -170,6 +209,18 @@ class PeerClient:
             asyncio.Queue(maxsize=1000)
         )
         self._batcher_task: Optional[asyncio.Task] = None
+        # The raw batcher (forward_raw): the open window's forwards and
+        # their checks, the timer that closes it, the sends in flight.
+        # Event-loop thread only.
+        self._raw_batch: List[_RawForward] = []
+        self._raw_checks = 0
+        self._raw_timer: Optional[asyncio.TimerHandle] = None
+        self._raw_sends: set = set()
+        # The identity its forwards carry (docs/cluster.md): random a
+        # client, so that a restarted daemon's counter cannot meet an id
+        # the peer still keeps.
+        self._forward_instance = os.urandom(6).hex()
+        self._forward_seq = itertools.count()
         # Bound concurrent batch RPCs: the reference serializes sends
         # through one sendQueue goroutine (peer_client.go:450-509); we allow
         # a small window of overlap but never unbounded fan-out — under a
@@ -448,28 +499,193 @@ class PeerClient:
         finally:
             self._track_inflight(-1)
 
-    async def get_peer_rate_limits_raw(
-        self, payload: bytes, forward_id: Optional[str] = None,
-        deadline: Optional[float] = None,
-    ) -> bytes:
-        """One pre-encoded GetPeerRateLimitsReq as a raw-bytes RPC — the
-        compiled router's zero-copy forward.  Same shutdown/error
-        accounting as the batch path.
+    async def forward_raw(
+        self, payload: bytes, n: int, deadline: Optional[float] = None,
+        batch: bool = True,
+    ) -> Tuple[bytes, "native.ParsedResps", int]:
+        """The compiled router's zero-copy forward: `payload`, the `n`
+        checks one client RPC holds for this peer (their `requests`
+        frames as the client sent them), through the peer batcher
+        (peer_client.go:373-446).  Forwards of concurrent client RPCs
+        share one GetPeerRateLimits: the first opens a window of
+        `batch_wait`, the batch goes when the window ends or
+        `batch_limit` checks are pending, as bytes joined in arrival
+        order under ONE forward id.  Returns the batch's raw answer, its
+        parsed columns and the index of this forward's first answer in
+        them.  `batch=False` (a NO_BATCHING check) and a `batch_wait` of
+        zero send at once.
 
-        `forward_id` rides the call's metadata (FORWARD_ID_KEY).  An ask
-        that ends DEADLINE_EXCEEDED is made again under the same id while
-        the peer has said it applies an id once and `deadline`
-        (time.monotonic(): the client's own) leaves time — FORWARD_TRIES
-        asks where the client set none.  Every other failure, and the
-        last timeout, is the caller's: the router falls back to the
-        object path's ownership-retry loop per request, or answers with
-        the error."""
+        What bounds what (docs/cluster.md): the batch's id rides the
+        call's metadata (FORWARD_ID_KEY), and an ask that ends
+        DEADLINE_EXCEEDED is made again under it while the peer has said
+        it applies an id once and ANY of the batch's client RPCs has
+        time left (`_ask_raw`); each of them waits no longer than its
+        own `deadline` (time.monotonic()), and one whose deadline ended
+        in the window is taken out before the send (ForwardExpiredError:
+        nothing spent).  A failure of the batch is every member's: the
+        router falls back to the object path's ownership-retry loop per
+        request, or answers with the error, as for a forward sent
+        alone."""
         if self._shutdown:
             raise PeerNotReadyError(
                 f"peer {self.peer_info.grpc_address} is shut down"
             )
         if self.breaker is not None and not self.breaker.would_allow():
             raise self._shed("breaker_open")
+        loop = asyncio.get_running_loop()
+        fw = _RawForward(
+            payload, n, deadline, loop.create_future(),
+            self._stages.begin("peer.batch_wait", "peer"),
+        )
+        wait_s = self.behavior.batch_wait_s
+        limit = min(self.behavior.batch_limit, MAX_BATCH_SIZE)
+        self._track_inflight(+1)
+        try:
+            if not batch or wait_s <= 0:
+                fw.wait.end()
+                self._start_send([fw])
+            else:
+                if self._raw_batch and self._raw_checks + n > limit:
+                    self._flush_raw("flush_limit")
+                self._raw_batch.append(fw)
+                self._raw_checks += n
+                if self._raw_checks >= limit:
+                    self._flush_raw("flush_limit")
+                elif self._raw_timer is None:
+                    self._raw_timer = loop.call_later(
+                        wait_s, self._flush_raw, "flush_wait"
+                    )
+            return await fw.fut
+        except asyncio.CancelledError:
+            # Its client gave up.  Still in the window: taken out, so
+            # that nothing is sent (or spent) for it.  Sent already: the
+            # batch goes on for the others.
+            if fw in self._raw_batch:
+                self._raw_batch.remove(fw)
+                self._raw_checks -= n
+                fw.wait.end()
+                if not self._raw_batch and self._raw_timer is not None:
+                    self._raw_timer.cancel()
+                    self._raw_timer = None
+            raise
+        finally:
+            self._track_inflight(-1)
+
+    def _flush_raw(self, reason: str) -> None:
+        """Close the window: what waits in it goes as one
+        GetPeerRateLimits.  `reason` is the tally, flush_wait (the timer)
+        or flush_limit."""
+        batch, self._raw_batch, self._raw_checks = self._raw_batch, [], 0
+        if self._raw_timer is not None:
+            self._raw_timer.cancel()
+            self._raw_timer = None
+        now = time.monotonic()
+        live = []
+        for fw in batch:
+            fw.wait.end()
+            if fw.deadline is not None and fw.deadline <= now:
+                fw.fut.set_exception(ForwardExpiredError(
+                    "the client's deadline ended before the forward to "
+                    f"{self.peer_info.grpc_address} was sent"
+                ))
+            else:
+                live.append(fw)
+        if not live:
+            return
+        self._stages.tally(
+            "peer", "peer.forward",
+            batched=len(live) if len(live) > 1 else 0, **{reason: 1},
+        )
+        self._start_send(live)
+
+    def _start_send(self, members: List[_RawForward]) -> None:
+        # A task of its own: one member's cancellation (its client gave
+        # up) must not cancel the send the others wait for.
+        # Started at once: the send leaves in this turn of the loop.
+        task = asyncio.Task(
+            self._send_raw(members), loop=asyncio.get_running_loop(),
+            eager_start=True,
+        )
+        self._raw_sends.add(task)
+        task.add_done_callback(self._raw_sends.discard)
+
+    async def _send_raw(self, members: List[_RawForward]) -> None:
+        """One GetPeerRateLimits for `members`, and its answer handed
+        back by count and in order; an error is every member's."""
+        total = sum(fw.n for fw in members)
+        payload = members[0].payload if len(members) == 1 else b"".join(
+            fw.payload for fw in members
+        )
+        fid = "%s-%x" % (self._forward_instance, next(self._forward_seq))
+        self._stages.tally("peer", "peer.forward", checks=total)
+        start = time.monotonic()
+        if self.metrics is not None:
+            self.metrics.queue_length.labels(
+                peerAddr=self.peer_info.grpc_address
+            ).observe(total)
+
+        def fail(err: Exception) -> None:
+            for fw in members:
+                if not fw.fut.done():
+                    fw.fut.set_exception(err)
+
+        try:
+            raw = await self._ask_raw(payload, fid, members)
+            rc = native.parse_resps(raw)
+            if rc is None or rc.n != total:
+                # The peer applied the batch — never re-send.
+                raise PeerAnswerError(
+                    "peer '%s' returned %s responses for %d requests" % (
+                        self.peer_info.grpc_address,
+                        "unparseable" if rc is None else rc.n, total,
+                    )
+                )
+        except asyncio.CancelledError:  # this task's own: the loop's end
+            fail(PeerNotReadyError(
+                f"forward {fid} to {self.peer_info.grpc_address} cancelled"
+            ))
+            raise
+        except Exception as e:  # noqa: BLE001 — every waiter's answer
+            fail(e)
+            return
+        if self.metrics is not None:
+            self.metrics.batch_send_duration.labels(
+                peerAddr=self.peer_info.grpc_address
+            ).observe(time.monotonic() - start)
+        lo = 0
+        for fw in members:
+            if not fw.fut.done():
+                fw.fut.set_result((raw, rc, lo))
+            lo += fw.n
+
+    @staticmethod
+    def _live_deadline(
+        members: List[_RawForward], err: BaseException
+    ) -> Optional[float]:
+        """After a batch's ask timed out: its members whose own deadline
+        has ended read `err` now; the deadline that bounds the next ask
+        is the earliest the others set (None: none did; now: nobody is
+        left to ask for)."""
+        now = time.monotonic()
+        left: List[Optional[float]] = []
+        for fw in members:
+            if fw.fut.done():
+                continue                # answered with its error, or gone
+            if fw.deadline is not None and fw.deadline - now <= 0.005:
+                fw.fut.set_exception(err)
+            else:
+                left.append(fw.deadline)
+        if not left:
+            return now
+        return min((d for d in left if d is not None), default=None)
+
+    async def _ask_raw(
+        self, payload: bytes, forward_id: str, members: List[_RawForward],
+    ) -> bytes:
+        """The GetPeerRateLimits of one batch, asked until it is answered
+        or may not be asked again: `_reask_budget`, under the deadline
+        its `members` leave (`_live_deadline`).  Same error accounting as
+        the batch path."""
         self._track_inflight(+1)
         try:
             await self._connect()
@@ -489,9 +705,9 @@ class PeerClient:
                 hop = self._stages.begin("peer.forward", "peer")
                 try:
                     budget = await self._ensure_ready()
-                    md = tracing.grpc_metadata() or ()
-                    if forward_id is not None:
-                        md += ((FORWARD_ID_KEY, forward_id),)
+                    md = (tracing.grpc_metadata() or ()) + (
+                        (FORWARD_ID_KEY, forward_id),
+                    )
                     asks = 0
                     while True:
                         asks += 1
@@ -502,8 +718,7 @@ class PeerClient:
                                     "GetPeerRateLimits",
                                 )
                             call = self._raw_get_peer_rate_limits(
-                                payload, timeout=budget,
-                                metadata=md or None,
+                                payload, timeout=budget, metadata=md,
                             )
                             out = await call
                             break
@@ -514,7 +729,8 @@ class PeerClient:
                                 "peer", "peer.forward", timeouts=1
                             )
                             budget = self._reask_budget(
-                                forward_id, deadline, asks
+                                forward_id,
+                                self._live_deadline(members, e), asks,
                             )
                             log.warning(
                                 "forward %s to %s: ask %d ended "

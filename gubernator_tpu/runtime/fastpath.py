@@ -72,8 +72,6 @@ the semantic reference:
 from __future__ import annotations
 
 import asyncio
-import itertools
-import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -98,6 +96,7 @@ _ERR_GREG = 3  # parse err code for host-side Gregorian failures
 
 _GLOBAL = int(Behavior.GLOBAL)
 _MULTI_REGION = int(Behavior.MULTI_REGION)
+_NO_BATCHING = int(Behavior.NO_BATCHING)
 
 # The sketch tier's response annotation (object path: metadata
 # {"tier": "sketch"}, runtime/sketch_backend.py).
@@ -571,11 +570,6 @@ class FastPath:
         self._stages.declare(
             "peer", "peer.forward", *tracing.PEER_FORWARD_COUNTERS
         )
-        # The identity forwards carry (docs/cluster.md): random a lane,
-        # so that a restarted daemon's counter cannot meet an id its
-        # owner still keeps.
-        self._forward_instance = os.urandom(6).hex()
-        self._forward_seq = itertools.count()
         # Blocking device->host fetches performed ON the request path
         # (a coalescer dispatch/fetch stage), by lane.  The background
         # planes (census, tiering) must leave it unchanged.
@@ -689,10 +683,6 @@ class FastPath:
             f = native.meta_frame(b"owner", addr)
             self._owner_frames[addr] = f
         return f
-
-    def _next_forward_id(self) -> str:
-        """A forward's identity: this lane's instance and a counter."""
-        return "%s-%x" % (self._forward_instance, next(self._forward_seq))
 
     def _single_node(self) -> bool:
         """True when no request can need a peer forward: an empty picker,
@@ -1577,29 +1567,17 @@ class FastPath:
             if len(idx) - n_glob:
                 m.labels("local").inc(len(idx) - n_glob)
 
-        def assemble(peer, addr: bytes, idx: np.ndarray, raw: bytes) -> None:
-            rc = native.parse_resps(raw)
-            if rc is None or rc.n != len(idx):
-                # A response ARRIVED, so the peer applied the batch —
-                # never re-send; report the protocol error instead.
-                stages.tally("peer", "peer.forward", refused=1)
-                msg = (
-                    "peer '%s' returned %s responses for %d requests"
-                    % (
-                        peer.info().grpc_address,
-                        "unparseable" if rc is None else rc.n,
-                        len(idx),
-                    )
-                ).encode()
-                for i in idx:
-                    errs[int(i)] = msg
-                return
-            status[idx] = rc.status
-            out_lim[idx] = rc.limit
-            remaining[idx] = rc.remaining
-            reset[idx] = rc.reset_time
+        def assemble(addr: bytes, idx: np.ndarray, raw: bytes, rc,
+                     lo: int) -> None:
+            """This forward's answers: `len(idx)` of the batch's, from
+            `lo` (the batcher parsed them once for all it coalesced)."""
+            hi = lo + len(idx)
+            status[idx] = rc.status[lo:hi]
+            out_lim[idx] = rc.limit[lo:hi]
+            remaining[idx] = rc.remaining[lo:hi]
+            reset[idx] = rc.reset_time[lo:hi]
             owner_frame = self._owner_frame(addr)
-            for j, i in enumerate(idx):
+            for j, i in enumerate(idx, lo):
                 i = int(i)
                 if rc.err_len[j]:
                     o = int(rc.err_off[j])
@@ -1626,14 +1604,15 @@ class FastPath:
             self.s.metrics.getratelimit_counter.labels("forward").inc(
                 len(idx)
             )
-            stages.tally("peer", "peer.forward", checks=len(idx))
             try:
-                # peer.forward is timed where the RPC is made
-                # (net/peer_client.py), readiness gate and re-asks
-                # included.
-                raw = await peer.get_peer_rate_limits_raw(
-                    sub_pay, forward_id=self._next_forward_id(),
-                    deadline=deadline,
+                # Through the peer's batcher (net/peer_client.py): what
+                # concurrent client RPCs send this owner shares one
+                # GetPeerRateLimits.  peer.forward is timed where that
+                # RPC is made, readiness gate and re-asks included, and
+                # counts its checks there.
+                raw, rc, lo = await peer.forward_raw(
+                    sub_pay, len(idx), deadline=deadline,
+                    batch=not (cols.behavior[idx] & _NO_BATCHING).any(),
                 )
             except Exception as e:  # noqa: BLE001
                 # Retry ONLY the failures the object path retries
@@ -1654,7 +1633,9 @@ class FastPath:
                 else:
                     # (A timeout here is one nobody may ask again:
                     # the client's deadline or the tries are spent, or
-                    # the peer never said it applies an id once.)
+                    # the peer never said it applies an id once.  An
+                    # answer that ARRIVED with a wrong count is never
+                    # sent again either: the peer applied the batch.)
                     stages.tally("peer", "peer.forward", refused=1)
                     msg = (
                         "Error while fetching rate limit from peer "
@@ -1664,7 +1645,7 @@ class FastPath:
                         errs[int(i)] = msg
                 return
             with stages.stage("peer.assemble", "peer"):
-                assemble(peer, addr, idx, raw)
+                assemble(addr, idx, raw, rc, lo)
 
         async def forward_fallback(peer, idx: np.ndarray) -> None:
             """Re-route failed forwards through the object path's retry

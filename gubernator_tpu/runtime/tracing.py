@@ -725,19 +725,29 @@ STAGES: Dict[str, str] = {
     # the forward hop, on the entry daemon's loop (lane `peer`)
     "peer.route": "once a routed RPC: the ring lookup, the owner masks, "
                   "the per-owner index sets",
-    "peer.splice": "per forward: the GetPeerRateLimits payload joined from "
-                   "the request's own bytes",
-    "peer.forward": "per forward: send -> raw answer (the readiness gate, "
-                    "the RPC, the owner's whole handler, every re-ask); "
-                    "counters checks (checks sent), and by event: timeouts "
+    "peer.splice": "per client RPC and remote owner: its checks' frames "
+                   "joined from the request's own bytes",
+    "peer.batch_wait": "per client RPC and remote owner: its checks handed "
+                       "to the peer batcher -> the GetPeerRateLimits that "
+                       "carries them is sent (the GUBER_BATCH_WAIT window, "
+                       "or less where GUBER_BATCH_LIMIT sent it first)",
+    "peer.forward": "per GetPeerRateLimits, which carries what concurrent "
+                    "client RPCs send one owner: send -> raw answer (the "
+                    "readiness gate, the RPC, the owner's whole handler, "
+                    "every re-ask); counters checks (checks sent), batched "
+                    "(client RPCs' forwards that shared their "
+                    "GetPeerRateLimits with another's), flush_wait / "
+                    "flush_limit (batches the window's end / the limit "
+                    "sent), and by event: timeouts "
                     "(an ask that ended DEADLINE_EXCEEDED), reasked (the "
                     "same forward asked again under its id), joined (OWNER "
                     "side: an arrival that found its id applied or in "
                     "progress and took that answer), retried (handed to "
                     "the object path's ownership-retry loop), refused "
                     "(answered with an error, or a wrong response count)",
-    "peer.assemble": "per forward: parse_resps, and the per-check copy of "
-                     "errors and the owner's metadata frame",
+    "peer.assemble": "per client RPC and remote owner: its slice of the "
+                     "answer's columns, and the per-check copy of errors "
+                     "and the owner's metadata frame",
     # per tick / process
     "global.sync_tick": "one GLOBAL psum sync: staging, dispatch, "
                         "write-through read-back; counters keys (pending "
@@ -806,7 +816,8 @@ LANE_STAGES = tuple(
 # daemon from start-up, whether or not it ever routes.
 PEER_STAGES = tuple(s for s in STAGES if s.startswith("peer."))
 PEER_FORWARD_COUNTERS = (
-    "checks", "timeouts", "reasked", "joined", "retried", "refused",
+    "checks", "batched", "flush_wait", "flush_limit",
+    "timeouts", "reasked", "joined", "retried", "refused",
 )
 # Lane `host`'s rows, at zero from a daemon's start-up (Metrics);
 # host.gc and host.stall are the process's (_GC, _STALL below).

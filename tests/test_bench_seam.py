@@ -417,7 +417,8 @@ def test_hop_series_counts_by_calltype(routed, calltype):
 
 
 def test_the_peer_hop_metrics_were_found():
-    assert len(PEER_HOP_METRICS) == 5, PEER_HOP_METRICS
+    # PR 39's five; PR 43's peer_checks_per_forward and peer_batch_wait_ms.
+    assert len(PEER_HOP_METRICS) == 7, PEER_HOP_METRICS
 
 
 @pytest.mark.parametrize("name", PEER_HOP_METRICS)
@@ -506,6 +507,28 @@ def test_the_routed_rpcs_handler_closes_with_the_hop_named(routed):
                              "refused")] == [0] * 5
 
 
+@pytest.mark.parametrize("path", [
+    "stages.peer.batch_wait.count", "stages.peer.batch_wait.ms_total",
+    "stages.peer.forward.batched", "stages.peer.forward.flush_limit",
+    "stages.peer.forward.flush_wait",
+])
+def test_the_peer_batchers_span_and_tallies(routed, path):
+    """PR 43: `peer.batch_wait` (a client RPC's forward, enqueue -> its
+    GetPeerRateLimits is sent) and why a batch went, where
+    peer_batch_wait_ms.closed and peer_checks_per_forward.closed (and a
+    reader of the ledger) look for them."""
+    nodes = _resolve(routed.vars, path)
+    assert len(nodes) == 1 and _is_number(nodes[0]), (path, nodes)
+    hop = routed.vars["stages"]["peer"]
+    # Every GetPeerRateLimits went for one of the two reasons, and carried
+    # at least one client RPC's forward.
+    assert hop["forward"]["count"] == (
+        hop["forward"]["flush_wait"] + hop["forward"]["flush_limit"]) > 0
+    assert hop["batch_wait"]["count"] >= hop["forward"]["count"]
+    assert hop["forward"]["batched"] <= hop["batch_wait"]["count"]
+    assert hop["batch_wait"]["ms_total"] > 0
+
+
 def test_the_ready_reports_names_on_a_daemon_of_a_cluster(routed):
     """bench/serve.py's ready report, bench/run.py `check_chips`: the
     device block's four names, the warm-up time, and the devices'
@@ -521,17 +544,39 @@ def test_the_ready_reports_names_on_a_daemon_of_a_cluster(routed):
     assert devs and all(isinstance(d.id, int) for d in devs)  # serve.py:222
 
 
-def test_a_daemon_of_a_cluster_starts_from_the_environment(monkeypatch):
+def _cluster_configs():
+    """The configuration files of BENCHMARK.json that state `peers`."""
+    bm = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    files = [BENCH.parent / c["file"] for c in bm["configs"]]
+    return sorted(f.stem for f in files
+                  if json.loads(f.read_text()).get("peers", 1) > 1)
+
+
+def test_the_cluster_configurations_were_found():
+    assert _cluster_configs() == ["peers4-10m", "peers4-10m-batched"]
+
+
+@pytest.mark.parametrize("config", _cluster_configs())
+def test_a_daemon_of_a_cluster_starts_from_the_environment(
+        monkeypatch, config):
     """bench/run.py `server_env`: a configuration's `daemon` group plus
     the daemon's own addresses; `--control noforward` rewrites GUBER_PEERS
-    to the advertise address alone (bench/serve.py:87)."""
+    to the advertise address alone (bench/serve.py:87).  The group is read
+    from the configuration's file: every setting it states is one the
+    daemon reads, in the spelling it states (`peers4-10m-batched` states
+    the peer batcher's window and limit; `peers4-10m` runs the same two
+    as the program's defaults)."""
     from gubernator_tpu.core.config import setup_daemon_config
 
+    daemon = json.loads(
+        (BENCH / "configs" / f"{config}.json").read_text())["daemon"]
     peers = "127.0.0.1:21051,127.0.0.1:21052,127.0.0.1:21053,127.0.0.1:21054"
+    assert daemon["GUBER_PEERS"] == peers
+    for k in ("GUBER_BATCH_WAIT", "GUBER_BATCH_LIMIT"):
+        monkeypatch.delenv(k, raising=False)
+        assert (k in daemon) == (config == "peers4-10m-batched")
     for k, v in {
-        "GUBER_TPU_NUM_SLOTS": "4194304", "GUBER_TPU_WAYS": "8",
-        "GUBER_TPU_BATCH_SIZE": "4096", "GUBER_PEERS": peers,
-        "GUBER_PEER_PICKER_HASH": "xx", "GUBER_BATCH_TIMEOUT": "500ms",
+        **daemon,
         "GUBER_GRPC_ADDRESS": "127.0.0.1:21052",
         "GUBER_HTTP_ADDRESS": "127.0.0.1:21999",
         "GUBER_ADVERTISE_ADDRESS": "127.0.0.1:21052",
@@ -542,5 +587,7 @@ def test_a_daemon_of_a_cluster_starts_from_the_environment(monkeypatch):
     assert conf.advertise_address == "127.0.0.1:21052"
     assert conf.local_picker_hash == "xx"
     assert conf.behaviors.batch_timeout_s == 0.5
+    assert conf.behaviors.batch_wait_s == 500e-6
+    assert conf.behaviors.batch_limit == 1000
     assert (conf.device.num_slots, conf.device.ways,
             conf.device.batch_size) == (1 << 22, 8, 4096)

@@ -48,6 +48,8 @@ KEYS = 3000
 ROUNDS = 5
 # peer.forward's counters beside `checks` (runtime/tracing.py).
 HOP_EVENTS = ("timeouts", "reasked", "joined", "retried", "refused")
+# ... and the peer batcher's (net/peer_client.py `forward_raw`, PR 43).
+BATCH_EVENTS = ("batched", "flush_wait", "flush_limit")
 # The stages a client RPC's handler divides into on a daemon that does not
 # route (docs/tracing.md, identity 1); an entry daemon adds wire.peer_wait.
 RPC_PARTS = (("wire", "ingress"), ("mach", "queue_wait"),
@@ -207,14 +209,25 @@ def test_every_answer_is_the_owners_and_the_ledger_names_the_hop(ring4):
             assert (item is not None) == (owner_of[k] == i), (k, i)
 
     # The hop, counted three ways: the ring, the ledger, the series.
+    forwards_sent = handlers = 0
     for i, d in enumerate(c.daemons):
         mine = [keys for rnd in plan for e, keys in rnd if e == i]
         not_owned = sum(int((owner_of[k] != i).sum()) for k in mine)
         owned = sum(int((owner_of[k] == i).sum()) for k in mine)
         fwd = {k: _grown(after[i], before[i], "peer", "forward", k)
-               for k in ("count", "checks", *HOP_EVENTS)}
-        assert fwd == dict(dict.fromkeys(HOP_EVENTS, 0),
-                           count=3 * len(mine), checks=not_owned), i
+               for k in ("count", "checks", *BATCH_EVENTS, *HOP_EVENTS)}
+        # The two RPCs a peer enters in a round are in flight together:
+        # where their forwards to one owner met in the batch window (PR
+        # 43: 10 ms here, never the limit: 2 x ~190 checks) they shared
+        # one GetPeerRateLimits, so a pair is one forward and two
+        # `batched`.
+        sent = 3 * len(mine) - fwd["batched"] // 2
+        assert fwd["batched"] % 2 == 0 and fwd == dict(
+            dict.fromkeys(HOP_EVENTS, 0), count=sent, checks=not_owned,
+            batched=fwd["batched"], flush_wait=sent, flush_limit=0), i
+        forwards_sent += sent
+        assert _grown(after[i], before[i], "peer", "batch_wait", "count") == (
+            3 * len(mine))
         assert _calltype(d, "forward") - forward0[i] == not_owned
         assert _calltype(d, "local") - local0[i] == owned
         for stage, n in (("route", 1), ("splice", 3), ("assemble", 3)):
@@ -225,8 +238,7 @@ def test_every_answer_is_the_owners_and_the_ledger_names_the_hop(ring4):
 
         # The routed RPC's identity, over this daemon's handlers (entry
         # and owner side: an owner's handler has no peer_wait).
-        handlers = _grown(after[i], before[i], "wire", "handler", "count")
-        assert handlers == ROUNDS * 8   # its own, and one of every other
+        handlers += _grown(after[i], before[i], "wire", "handler", "count")
         handler = _grown(after[i], before[i], "wire", "handler", "ms_total")
         parts = sum(
             _grown(after[i], before[i], lane, stage, "ms_total")
@@ -244,6 +256,8 @@ def test_every_answer_is_the_owners_and_the_ledger_names_the_hop(ring4):
             for st in DRAIN_PARTS if st in after[i]["mach"])
         assert 0.75 * drain <= stages_of_it <= 1.001 * drain, (
             i, stages_of_it, drain)
+    # A handler a client RPC and one a GetPeerRateLimits, cluster-wide.
+    assert handlers == ROUNDS * 8 + forwards_sent
 
 
 def test_an_rpc_that_owns_none_of_its_checks_ends_ingress_first(ring4):
@@ -320,7 +334,9 @@ def test_a_peer_rpc_and_a_single_daemon_leave_the_hops_rows_at_zero(ring4):
         zero = {"count": 0, "ms_total": 0.0, "ms_max": 0.0, "max_at_ms": 0}
         assert rows0["peer"] == {
             "route": zero, "splice": zero, "assemble": zero,
-            "forward": dict(zero, checks=0, **dict.fromkeys(HOP_EVENTS, 0)),
+            "batch_wait": zero,
+            "forward": dict(zero, checks=0, **dict.fromkeys(
+                BATCH_EVENTS + HOP_EVENTS, 0)),
         }
         assert rows0["wire"]["peer_wait"] == zero
 
@@ -604,8 +620,8 @@ def test_a_peer_that_never_said_it_applies_once_is_not_asked_again(
     assert got[-1] == ("", 0, 10, 9)            # its own check is served
     grown = {k: v - e0[k] for k, v in _hop(entry).items()}
     assert grown == dict(
-        dict.fromkeys(HOP_EVENTS, 0), count=1, checks=5, timeouts=1,
-        refused=1, ms_total=grown["ms_total"], ms_max=grown["ms_max"],
+        dict.fromkeys(BATCH_EVENTS + HOP_EVENTS, 0), count=1, checks=5,
+        flush_wait=1, timeouts=1, refused=1, ms_total=grown["ms_total"], ms_max=grown["ms_max"],
         max_at_ms=grown["max_at_ms"])
 
 
