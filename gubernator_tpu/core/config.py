@@ -757,7 +757,10 @@ class DeviceConfig:
     # Compiled batch-shape tiers: a round whose active lanes fit a smaller
     # tier ships that shape instead of the full batch_size array, so
     # host<->device transfer (and small-batch latency) scales with traffic.
-    # None = (128, batch_size).  Each tier costs one XLA compile at warmup.
+    # None = runtime/backend.py default_tiers(batch_size): 128, 1,024
+    # where batch_size is wider, batch_size — (128, 1024, 4096) at 4096,
+    # (128, 1024) at the default 1024.  Each tier costs one XLA compile a
+    # step kind at warmup, and a trace of the step at every start.
     batch_tiers: Optional[Tuple[int, ...]] = None
     # GLOBAL replicated-serving cache table size (mesh GlobalEngine only).
     # None = num_slots, i.e. the engine DOUBLES the table HBM footprint;

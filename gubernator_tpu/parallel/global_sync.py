@@ -65,8 +65,8 @@ from gubernator_tpu.parallel.sharded import (
     packed_grid_rounds_to_host,
 )
 from gubernator_tpu.runtime.backend import (
-    round_words,
-    tier_of,
+    declare_launches,
+    launch_rounds,
     unmarshal_responses,
 )
 
@@ -349,6 +349,7 @@ class GlobalEngine:
             )
         self.b = backend
         self.n = backend.cfg.num_shards
+        declare_launches(backend._stages, backend._tiers, "engine")
         self.delta_slots = delta_slots
         self.batch_limit = batch_limit
         self.collective = collective
@@ -453,17 +454,8 @@ class GlobalEngine:
             with self.b._lock, self._lock:
                 self._seed_from_store_engine(agg_reqs, packed, now_ms)
 
-        round_resps = []
         with self._lock:
-            for db in packed.rounds:
-                t = tier_of(db.active, self.b._tiers)
-                batch = jax.device_put(
-                    pack_grid_batch(db)[:, :, :t], self.b._psharding
-                )
-                self.cache_table, resp = self._ingest(
-                    self.cache_table, batch, now
-                )
-                round_resps.append(resp)
+            round_resps = self._ingest_rounds_locked(packed.rounds, now)
             # Queue hits AFTER preparing the response (the deferred QueueHit,
             # gubernator.go:429-432).
             for j, r in enumerate(agg_reqs):
@@ -523,17 +515,7 @@ class GlobalEngine:
         lock_wait = self.b._stages.stage("backend.lock_wait")
         with self._lock:
             lock_wait.end()
-            resps = []
-            with self.b._stages.stage("backend.dispatch"):
-                for db in rounds:
-                    batch = jax.device_put(
-                        round_words(db, self.b._tiers, pack_grid_batch),
-                        self.b._psharding,
-                    )
-                    self.cache_table, r = self._ingest(
-                        self.cache_table, batch, now
-                    )
-                    resps.append(r)
+            resps = self._ingest_rounds_locked(rounds, now)
             for req, hits, src_dev in pend_items:
                 key = req.hash_key()
                 p = self.pending.get(key)
@@ -546,6 +528,23 @@ class GlobalEngine:
                     p.req = req
             want_sync = len(self.pending) >= self.batch_limit
         return resps, want_sync
+
+    def _ingest_rounds_locked(self, rounds, now) -> list:
+        """Ingest grid rounds into the replicated cache under the clock
+        `now`; caller holds `_lock`.  The object path's DeviceBatches
+        and the engine lane's packed words alike."""
+
+        def launch(words):
+            batch = jax.device_put(words, self.b._psharding)
+            self.cache_table, resp = self._ingest(
+                self.cache_table, batch, now
+            )
+            return resp
+
+        return launch_rounds(
+            self.b._stages, rounds, self.b._tiers, launch, pack_grid_batch,
+            self.n,
+        )
 
     # -- sync path -------------------------------------------------------
     def _seed_from_store_engine(self, agg_reqs, packed, now_ms: int) -> None:

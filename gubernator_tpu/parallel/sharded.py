@@ -46,9 +46,9 @@ from gubernator_tpu.runtime.backend import (
     PersistenceHost,
     _row_to_item,
     probe_bucket,
+    declare_launches,
+    launch_rounds,
     resolve_tiers,
-    round_words,
-    tier_of,
     unmarshal_responses,
 )
 
@@ -326,6 +326,7 @@ class MeshBackend(PersistenceHost):
         # Batch-shape tiers (see DeviceConfig.batch_tiers): sparse rounds
         # ship a sliced [12, n, t] block instead of the full batch shape.
         self._tiers = resolve_tiers(cfg)
+        declare_launches(self._stages, self._tiers, "mach", "direct")
         # Batch input sharding: [12, n, B] split on the shard axis (dim 1).
         self._psharding = NamedSharding(self.mesh, P(None, SHARD_AXIS))
         self._cached_store = make_sharded_row_op(
@@ -374,7 +375,6 @@ class MeshBackend(PersistenceHost):
             use_cached,
         )
         now_ms = self.clock.millisecond_now()
-        now = np.int64(now_ms)
         if self._keymap is not None:
             with self._keymap_lock:
                 for i, r in enumerate(reqs):
@@ -385,7 +385,6 @@ class MeshBackend(PersistenceHost):
 
         import time as time_mod
 
-        round_resps = []
         captured = None
         t_start = time_mod.monotonic()
         lock_wait = self._stages.stage("backend.lock_wait")
@@ -393,18 +392,10 @@ class MeshBackend(PersistenceHost):
             lock_wait.end()
             if self.store is not None:
                 self._seed_from_store(reqs, packed, now_ms)
-            with self._stages.stage("backend.dispatch"):
-                for db in packed.rounds:
-                    # ONE sharded put for the whole batch, ONE packed
-                    # readback.
-                    t = tier_of(db.active, self._tiers)
-                    batch = jax.device_put(
-                        pack_grid_batch(db)[:, :, :t], self._psharding
-                    )
-                    self.table, resp = self._step_packed(
-                        self.table, batch, now
-                    )
-                    round_resps.append(resp)
+            # ONE sharded put a round, ONE packed readback.
+            round_resps = self._dispatch_rounds_locked(
+                packed.rounds, now_ms
+            )
             if self.store is not None:
                 # Read-back inside the lock: a concurrent batch must not
                 # mutate a key between this batch's step and on_change.
@@ -467,16 +458,16 @@ class MeshBackend(PersistenceHost):
         `_lock` (see DeviceBackend._dispatch_rounds_locked: one clock a
         drain; None reads it here)."""
         now = np.int64(self.clock.millisecond_now() if now is None else now)
-        round_resps = []
-        with self._stages.stage("backend.dispatch"):
-            for db in rounds:
-                batch = jax.device_put(
-                    round_words(db, self._tiers, pack_grid_batch),
-                    self._psharding,
-                )
-                self.table, resp = self._step_packed(self.table, batch, now)
-                round_resps.append(resp)
-        return round_resps
+
+        def launch(words):
+            batch = jax.device_put(words, self._psharding)
+            self.table, resp = self._step_packed(self.table, batch, now)
+            return resp
+
+        return launch_rounds(
+            self._stages, rounds, self._tiers, launch, pack_grid_batch,
+            self.cfg.num_shards,
+        )
 
     def warmup(self) -> None:
         """Compile the sharded executables with a synthetic batch that

@@ -73,10 +73,50 @@ def round_words(db, tiers, pack=pack_batch_q) -> np.ndarray:
     return pack(db)[..., : tier_of(db.active, tiers)]
 
 
+def declare_launches(stages, tiers, *lanes: str) -> None:
+    """`backend.dispatch`'s counters at zero on the rows of `lanes`: a run
+    whose rounds never leave the 128 rung reads the other rungs 0, not
+    nothing."""
+    rungs = tuple(f"tier_{t}" for t in tiers)
+    for lane in lanes:
+        stages.declare(lane, "backend.dispatch", "launches", "lanes", *rungs)
+
+
+def launch_rounds(stages, rounds, tiers, launch, pack=pack_batch_q,
+                  shards: int = 1) -> list:
+    """Every step launch passes here: one `backend.dispatch` section that
+    cuts each round to its rung (round_words), hands the words to
+    `launch` and counts the launch by the compiled width it rode —
+    `launches`, `lanes` (x shards on the mesh) and `tier_<width>`.  Beside
+    `backend.checks`, `lanes` over the lanes carried is the width a drain
+    paid for and left empty.  Returns what `launch` returned, a round."""
+    resps = []
+    counts = {"lanes": 0}
+    with stages.stage("backend.dispatch") as dispatch:
+        for db in rounds:
+            words = round_words(db, tiers, pack)
+            t = words.shape[-1]
+            counts["lanes"] += shards * t
+            counts[f"tier_{t}"] = counts.get(f"tier_{t}", 0) + 1
+            resps.append(launch(words))
+        dispatch.tally(launches=len(resps), **counts)
+    return resps
+
+
+def default_tiers(batch_size: int) -> tuple:
+    """The ladder of compiled batch widths where `batch_tiers` is unset:
+    128, 1,024 where batch_size is wider, batch_size — (128, 1024, 4096)
+    at 4096.  A round that overflows the full width by a few hundred
+    lanes, or an owner's drain of a thousand, rides a launch of its own
+    size; under 1,024 no cell's rounds are between the rungs, and a
+    2,048-lane rung was measured and not kept (PERF.md section 5.11)."""
+    return tuple(t for t in (128, 1024) if t < batch_size) + (batch_size,)
+
+
 def resolve_tiers(cfg) -> tuple:
     """Sorted compiled batch tiers; batch_size is ALWAYS included so
     tier_of's fallback never truncates a full round."""
-    tiers = cfg.batch_tiers or (128, cfg.batch_size)
+    tiers = cfg.batch_tiers or default_tiers(cfg.batch_size)
     return tuple(sorted(
         {min(t, cfg.batch_size) for t in tiers} | {cfg.batch_size}
     ))
@@ -478,6 +518,7 @@ class DeviceBackend(PersistenceHost):
         # batch_size is always a tier so a full round can never be
         # truncated.
         self._tiers = resolve_tiers(self.cfg)
+        declare_launches(self._stages, self._tiers, "mach", "direct")
         self._load_rows = functools.partial(load_rows, ways=self.cfg.ways)
         self._probe = functools.partial(probe_batch, ways=self.cfg.ways)
         # Module-level jits (apply_batch_packed_q/load_rows/probe_batch/
@@ -547,7 +588,6 @@ class DeviceBackend(PersistenceHost):
                         k = r.hash_key()
                         self._keymap[key_hash64(k)] = k
             self._maybe_prune_keymap()
-        round_resps = []
         captured = None
         t_start = time.monotonic()
         lock_wait = self._stages.stage("backend.lock_wait")
@@ -555,13 +595,7 @@ class DeviceBackend(PersistenceHost):
             lock_wait.end()
             if self.store is not None:
                 self._seed_from_store(reqs, packed, now)
-            with self._stages.stage("backend.dispatch"):
-                for db in packed.rounds:
-                    t = tier_of(db.active, self._tiers)
-                    self.table, packed_resp = self._step_packed_q(
-                        self.table, pack_batch_q(db)[:, :t], np.int64(now)
-                    )
-                    round_resps.append(packed_resp)
+            round_resps = self._dispatch_rounds_locked(packed.rounds, now)
             if self.store is not None:
                 # Read-back inside the lock: a concurrent batch must not
                 # mutate a key between this batch's step and on_change.
@@ -655,14 +689,14 @@ class DeviceBackend(PersistenceHost):
         writes back never saw (PERF.md section 7, PR 33).  None: this
         dispatch is the hold's only one, and reads the clock itself."""
         now = np.int64(self.clock.millisecond_now() if now is None else now)
-        round_resps = []
-        with self._stages.stage("backend.dispatch"):
-            for db in rounds:
-                self.table, packed_resp = self._step_packed_q(
-                    self.table, round_words(db, self._tiers), now
-                )
-                round_resps.append(packed_resp)
-        return round_resps
+
+        def launch(words):
+            self.table, packed_resp = self._step_packed_q(
+                self.table, words, now
+            )
+            return packed_resp
+
+        return launch_rounds(self._stages, rounds, self._tiers, launch)
 
     def _probe_padded(self, hashes: np.ndarray, now: int) -> np.ndarray:
         """found-mask for a host hash vector, probing in fixed batch_size

@@ -719,7 +719,11 @@ STAGES: Dict[str, str] = {
     "backend.lock_wait": "blocked on backend._lock / engine._lock",
     "backend.dispatch": "the enqueue of each round's program, packed "
                         "already (compiled lane) or packed here (feeds "
-                        "gubernator_tpu_device_step_duration)",
+                        "gubernator_tpu_device_step_duration); counters "
+                        "launches (step programs enqueued), lanes (the "
+                        "compiled width of each, summed, x shards on the "
+                        "mesh) and tier_<width> (launches a rung of the "
+                        "compiled widths)",
     "backend.d2h_wait": "fetch_ravel: blocked until the answer is on "
                         "the host",
     # the forward hop, on the entry daemon's loop (lane `peer`)

@@ -5,7 +5,7 @@
     JAX_PLATFORMS=cpu python scripts/claim_rounds_chip.py --platform cpu \\
         --slots 65536 --reps 3                        # dry run here
 
-Four things, one JSON object on the last line of stdout (also written to
+Five things, one JSON object on the last line of stdout (also written to
 --out/summary.json):
 
   identical  seeded tables and batches (tests/test_locate_slots.py's
@@ -38,6 +38,19 @@ Four things, one JSON object on the last line of stdout (also written to
              of --reps launches the device's ms a launch, of which the
              table scatters', the sorts' and the gathers'
              (--out/write_back.json holds every op).
+
+  rungs      the served step at every rung of the compiled widths
+             (`runtime/backend.py` `default_tiers(4096)`, or --rungs) on
+             the 2^24- and the 2^22-slot table (--rung-slots), full and with
+             three lanes in ten active (an overflow's few hundred, an
+             owner's thousand: lanes fill from 0, as the packer fills
+             them): ms a launch by the host clock, and from a trace of
+             --reps launches the device's ms a launch, of which the table
+             scatters', the sorts' and the gathers', and the launch as a
+             share of the full 4096-lane one's and of the 4096-lane
+             program's carrying the same lanes (what the round paid before
+             it had a rung; --out/rungs.json holds every op).  `--only
+             rungs` runs nothing else.
 
 It fails where there is no TPU unless `--platform cpu` is given, and a
 number from such a run is a rehearsal, not a device time.
@@ -138,6 +151,16 @@ def _trace_ops(fn, reps: int, trace_dir: Path, names: dict, traced: bool):
     return launch_ms, rows
 
 
+def _device_parts(device_ms: float, rows) -> dict:
+    """A traced launch's device ms, of which the table scatters', the
+    sorts' and the gathers' (`rows`: _trace_ops's, (op, ms, op_name))."""
+    def of(suffix):
+        return sum(row[1] for row in rows if row[2].endswith(suffix))
+
+    return {"device_ms": device_ms, "scatter_ms": of("/scatter"),
+            "sort_ms": of("/sort"), "gather_ms": of("/gather")}
+
+
 def _write_back(served: dict, args, now, trace_dir: Path, traced: bool):
     """The step under each variant of the write-back (module docstring):
     (all identical, {tier: {variant: readings}}, {tier.variant: ops})."""
@@ -197,14 +220,9 @@ def _write_back(served: dict, args, now, trace_dir: Path, traced: bool):
                 launch, args.reps, trace_dir, _op_names(step.as_text()),
                 traced)
 
-            def of(suffix):
-                return sum(r[1] for r in rows if r[2].endswith(suffix))
-
             tier[name] = {"identical": identical, "ms": ms}
             if traced:
-                tier[name].update(
-                    device_ms=device_ms, scatter_ms=of("/scatter"),
-                    sort_ms=of("/sort"), gather_ms=of("/gather"))
+                tier[name].update(_device_parts(device_ms, rows))
                 ops[f"B{B}.{name}"] = rows
             del state, step
         # `served` against the plain scatter on the conflict-heavy cases
@@ -221,6 +239,60 @@ def _write_back(served: dict, args, now, trace_dir: Path, traced: bool):
             ok = ok and identical
             tier[f"served.identical.{case}"] = identical
     return ok, report, ops
+
+
+def _rungs(args, now, trace_dir: Path, traced: bool):
+    """The served step at every rung (module docstring):
+    ({S<slots>: {B<rung>.<active lanes>: readings}}, {the same: ops})."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import test_locate_slots as ref
+    from gubernator_tpu.ops import step as sp
+    from gubernator_tpu.runtime.backend import default_tiers
+
+    report, ops = {}, {}
+    for slots in args.rung_slots:
+        # The served geometry: 2 % of the lanes miss.
+        table, h, _ = ref._random_case(
+            args.seed + 7000, 4096, WAYS, slots // WAYS, *CASES["served"][1:])
+        state = {"table": table}
+        report[f"S{slots}"] = rows_of = {}
+        tiers = args.rungs or default_tiers(4096)
+        occupied = {B: (B, B * 3 // 10) for B in tiers}
+        for B in tiers:
+            q_shape = jax.ShapeDtypeStruct((12, B), jnp.int64)
+            names = _op_names(sp.apply_batch_packed_q.lower(
+                state["table"], q_shape, now, ways=WAYS).compile().as_text())
+            # The full width carries every narrower rung's rounds too:
+            # what each would have paid without its rung.
+            for lanes in (occupied[B] if B < tiers[-1] else sorted(
+                    {n for v in occupied.values() for n in v}, reverse=True)):
+                q = jnp.asarray(_batch_q(h[:B], np.arange(B) < lanes))
+
+                def launch(q=q):
+                    state["table"], resp = sp.apply_batch_packed_q(
+                        state["table"], q, now, ways=WAYS)
+                    return resp
+
+                r = {"ms": _ms_per_call(launch, args.reps)}
+                device_ms, rows = _trace_ops(
+                    launch, args.reps, trace_dir, names, traced)
+
+                if traced:
+                    r.update(_device_parts(device_ms, rows))
+                    ops[f"S{slots}.B{B}.{lanes}"] = rows
+                rows_of[f"B{B}.{lanes}"] = r
+        if traced:
+            top = f"B{tiers[-1]}."
+            full = rows_of[f"{top}{tiers[-1]}"]["device_ms"]
+            for name, r in rows_of.items():
+                r["of_the_4096_lane_launch"] = r["device_ms"] / full
+                r["of_the_same_lanes_at_4096"] = r["device_ms"] / rows_of[
+                    top + name.split(".")[1]]["device_ms"]
+        del state, table
+    return report, ops
 
 
 def _batch_q(h, active):
@@ -243,6 +315,15 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=str(REPO / "chiprun_out" / "claim_rounds"))
+    ap.add_argument("--only", default="", choices=("", "rungs"))
+    def ints(s):
+        return [int(x) for x in s.split(",") if x]
+
+    ap.add_argument("--rung-slots", default=f"{1 << 24},{1 << 22}",
+                    type=ints)
+    ap.add_argument("--rungs", default="", type=ints,
+                    help="default: default_tiers(4096); PR 44 measured "
+                         "128,1024,2048,4096")
     args = ap.parse_args()
     if args.platform == "cpu":
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -267,6 +348,12 @@ def main() -> int:
                    "count": len(jax.devices())},
         "slots": args.slots, "identical": {}, "ms": {},
     }
+
+    traced = dev.platform == "tpu"
+    summary["rungs"], rung_ops = _rungs(args, now, out_dir / "trace", traced)
+    (out_dir / "rungs.json").write_text(json.dumps(rung_ops, indent=0) + "\n")
+    if args.only == "rungs":
+        return _finish(summary, True, out_dir)
 
     ok = True
     served = {}
@@ -315,7 +402,6 @@ def main() -> int:
         steps[B] = step
 
     # What the 4096-lane step is made of, op by op.
-    traced = dev.platform == "tpu"
     table, h, _ = served[4096]
     q = jax.ShapeDtypeStruct((12, 4096), jnp.int64)
     names = _op_names(sp.apply_batch_packed_q.lower(
@@ -333,6 +419,10 @@ def main() -> int:
     (out_dir / "write_back.json").write_text(
         json.dumps(wb_ops, indent=0) + "\n")
 
+    return _finish(summary, ok, out_dir)
+
+
+def _finish(summary: dict, ok: bool, out_dir: Path) -> int:
     summary["ok"] = bool(ok)
     line = json.dumps(summary)
     (out_dir / "summary.json").write_text(line + "\n")

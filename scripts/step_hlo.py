@@ -22,8 +22,9 @@ at the deployment geometry and prints what a device trace only names:
     emitters apart (some 132 KB: it walks the updates; 16 MB: it streams
     the column).
 
-Programs: the one-chip `apply_batch_packed_q` at each step tier, and over
-the four described devices the mesh step (`make_sharded_step_packed`) and
+Programs: the one-chip `apply_batch_packed_q` at each rung of the compiled
+widths (`runtime/backend.py` `default_tiers`), and over the four described
+devices the mesh step (`make_sharded_step_packed`) at each and
 the GLOBAL sync program (`make_global_sync_step_psum`: two applies and a
 store of broadcast rows a launch).
 
@@ -160,6 +161,11 @@ def summarize(compiled, table_len: int) -> dict:
             table_ops.append(rec)
     mem = compiled.memory_analysis()
     x64 = [r for r in table_ops if r["target"] in X64_TARGETS]
+    # A column the compiler stages through fast memory around its
+    # gathers and scatter: whole (a copy-start/copy-done pair) or in
+    # slices joined again (slice-starts and one ConcatBitcast).
+    staged = [r for r in table_ops if r["opcode"] == "copy-start"
+              or r["target"] == "ConcatBitcast"]
     return {
         "table_len": table_len,
         "memory": {
@@ -175,6 +181,8 @@ def summarize(compiled, table_len: int) -> dict:
             for t in X64_TARGETS
         },
         "loops": loops,
+        "staged_columns": len(staged),
+        "table_copies": [r for r in table_ops if r["opcode"] == "copy"],
         "table_scatters": table_scatters(hlo, table_len),
     }
 
@@ -294,6 +302,9 @@ def _print(rep: dict) -> None:
         print(f"   {k:26s} {v / 1e6:10.1f} MB")
     print(f"   table-length X64 conversions: {rep['table_length_x64']} "
           f"{rep['x64_by_target']}")
+    print(f"   columns staged through fast memory: "
+          f"{rep['staged_columns']}; synchronous table-length copies: "
+          f"{len(rep['table_copies'])}")
     print(f"   table-length ops in the entry computation "
           f"({len(rep['table_length_ops'])}):")
     for r in rep["table_length_ops"]:
@@ -315,23 +326,35 @@ def _print(rep: dict) -> None:
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--slots", type=int, default=1 << 24)
-    ap.add_argument("--tiers", default="128,4096",
-                    help="comma-separated one-chip step tiers (lanes)")
-    ap.add_argument("--mesh-lanes", type=int, default=4096)
+    ap.add_argument("--tiers", default="",
+                    help="comma-separated step tiers (lanes); default: "
+                         "the ladder of --batch-size")
+    ap.add_argument("--batch-size", type=int, default=4096)
+    ap.add_argument("--mesh-lanes", default="",
+                    help="the mesh step's tiers; default: --tiers "
+                         "above 128 lanes")
     ap.add_argument("--topology", default="v5e:2x2")
     ap.add_argument("--json", default="", help="also write the reports here")
     args = ap.parse_args(argv)
 
     import gubernator_tpu.ops  # noqa: F401 — switches x64 on
 
+    from gubernator_tpu.runtime.backend import default_tiers
+
+    def lanes_of(text: str, default) -> List[int]:
+        return [int(t) for t in text.split(",") if t] or list(default)
+
     topo = describe(args.topology)
+    tiers = lanes_of(args.tiers, default_tiers(args.batch_size))
+    mesh_tiers = lanes_of(args.mesh_lanes, [t for t in tiers if t > 128])
     reports: Dict[str, dict] = {}
-    for lanes in (int(t) for t in args.tiers.split(",") if t):
-        rep = analyze_step(topo, args.slots, lanes)
-        reports[rep["program"]] = rep
-        _print(rep)
-    for rep in (analyze_mesh_step(topo, args.slots, args.mesh_lanes),
-                analyze_global_sync(topo, args.slots)):
+    programs = (
+        [(analyze_step, lanes) for lanes in tiers]
+        + [(analyze_mesh_step, lanes) for lanes in mesh_tiers]
+        + [(analyze_global_sync,)]
+    )
+    for analyze, *lanes in programs:
+        rep = analyze(topo, args.slots, *lanes)
         reports[rep["program"]] = rep
         _print(rep)
     if args.json:

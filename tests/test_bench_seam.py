@@ -462,6 +462,33 @@ HOST_METRICS = sorted(
 )
 
 
+@pytest.mark.parametrize("daemon", ["one_chip", "mesh"])
+def test_lanes_per_launch_reads_the_dispatch_rows_alone(request, daemon):
+    """backend_lanes_per_launch.closed (PR 44): its two terms, the
+    `stages` level spelled `*`, find the ledger's backend.dispatch rows
+    and nothing else of /debug/vars; every launch the seam's RPCs made
+    rode the 128 rung, x shards on the mesh, and the rungs are counted."""
+    seam = request.getfixturevalue(daemon)
+    read = json.loads((
+        BENCH / "layer_metrics" / "backend_lanes_per_launch.closed.json"
+    ).read_text())["read"]
+    assert read["kind"] == "ratio" and read["delta"] is True
+    rows = [lane["dispatch"] for lane in seam.vars["stages"].values()
+            if "dispatch" in lane]
+    total = {}
+    for side, counter in (("num", "lanes"), ("den", "launches")):
+        (term,) = read[side]
+        nodes = _resolve(seam.vars, term[len("vars:"):])
+        assert sorted(nodes) == sorted(
+            r[counter] for r in rows if counter in r), (term, nodes)
+        total[side] = sum(nodes)
+    shards = seam.daemon.service.backend.cfg.num_shards
+    assert total["den"] > 0
+    assert total["num"] == 128 * shards * total["den"], total
+    mach = seam.vars["stages"]["mach"]["dispatch"]
+    assert mach["tier_128"] == mach["launches"] > 0, mach
+
+
 def test_the_host_metrics_were_found():
     assert len(HOST_METRICS) == 15, HOST_METRICS
 

@@ -680,6 +680,52 @@ def test_one_chip_table_scatters_carry_their_tier_s_promises(
         "indices_are_sorted"]
 
 
+# The rung between (runtime/backend.py `default_tiers`): the same program
+# at another static width.  By table rows: whether the write-back sorts
+# (at most 8,192 rows a lane), and the columns the compiler stages through
+# fast memory around the gathers and the scatter.  On 2^22 rows it stages
+# no more of them than the 4096 rung does (46); on 2^24 rows four whole
+# columns for the walked scatter (the 4096 rung: 2), 0.1 ms each at the
+# memory system's pace.
+_RUNGS = {
+    (1 << 24, 1024): {"indices_are_sorted": False, "staged": 4},
+    (1 << 22, 1024): {"indices_are_sorted": True, "staged": 17},
+}
+
+
+def _assert_a_rung_of_the_same_program(rep: dict, want: dict) -> None:
+    _assert_no_boundary_conversion(rep)
+    assert rep["table_copies"] == [], rep["table_copies"]
+    assert rep["staged_columns"] <= want["staged"], rep["staged_columns"]
+    whiles = [r for r in rep["loops"] if r["opcode"] == "while"]
+    assert len(whiles) <= 2, rep["loops"]
+    for r in whiles:
+        assert "leaky_f64bits" in (r["op_name"] or ""), r
+    sorts = [r for r in rep["loops"] if r["opcode"] == "sort"]
+    assert not [r for r in sorts
+                if (r["op_name"] or "").endswith("/scatter")], sorts
+    assert len(sorts) == 2 + want["indices_are_sorted"], sorts
+    _assert_table_scatters(rep, want)
+
+
+@pytest.mark.parametrize("slots,lanes", sorted(_RUNGS))
+def test_the_rung_between_is_the_4096_lane_program_at_its_width(
+        step_hlo, topo, slots, lanes):
+    want = _RUNGS[slots, lanes]
+    assert st.sorts_write_back(slots, lanes) == want["indices_are_sorted"]
+    _assert_a_rung_of_the_same_program(
+        step_hlo.analyze_step(topo, slots, lanes), want)
+
+
+def test_a_mesh_shard_s_rung_is_its_table_s(step_hlo, topo):
+    """A shard of the 2^24-slot mesh holds 2^22 rows: its rung sorts and
+    streams as a one-chip table of that size does."""
+    want = _RUNGS[1 << 22, 1024]
+    assert st.sorts_write_back(1 << 22, 1024)
+    _assert_a_rung_of_the_same_program(
+        step_hlo.analyze_mesh_step(topo, 1 << 24, 1024), want)
+
+
 @pytest.fixture(scope="module")
 def mesh_report(step_hlo, topo):
     return step_hlo.analyze_mesh_step(topo, 1 << 24, 4096)
