@@ -71,12 +71,59 @@ clock; RPCs in flight together under one clock are matched as a multiset as
 above.  A key of a crowded bucket may still answer as a fresh bucket, and no
 more: a leaky one at its own clock, a token one by a new generation before
 the old one's end.
+
+A table that does not hold its universe (`replay_tiered`, for a
+configuration that says `"residency": "tiered"`).  docs/tiering.md serves a
+check on a key whose row is in the cold store at once, from a fresh row, and
+merges the cold row back later; `replay_sample` would call the fresh answer
+wrong.  The tiered replay holds the daemon to what that document promises and
+proves, and to no less.  Of a sampled key it keeps what the two tiers may
+hold — a table row, a cold row, each as its (remaining, status) — and every
+answer has to be what core/pymodel.py gives, after the key's earlier
+answers, from one of the states the tier's algebra allows:
+
+  continued   the table row as the earlier answers left it;
+  merged      that row once the cold row has come back: its remaining less
+              what the cold row had consumed, `max(r - consumed, 0)` of the
+              cold row's `r` and the fresh row's `consumed` — the cold row
+              is gone;
+  promoted    no table row: the cold row itself (the promote landed before
+              the check did, `note_access` runs ahead of the step);
+  fresh       a fresh bucket, the table row — if there was one — now the
+              cold row (a demotion; merged with a cold row still waiting,
+              which assumes the least budget) — one more fresh start of the
+              key;
+
+and nothing else: an answer that none of these gives, such as one that mints
+budget, is `wrong_answers`.  Beside the answers, per sampled key:
+
+  admitted_beyond_bound   hits admitted in the run, one limit window, are at
+                          most `limit x (1 + fresh starts seen)`;
+  merged_late             the widening ends: a key answered from a fresh row
+                          whose cold row is CERTAIN to wait — it was
+                          preloaded there, or the row went from a bucket
+                          that cannot evict — is answered from the merged
+                          one by its first RPC sent more than the
+                          configuration's `promote_deadline_ms` after that
+                          fresh answer was received.  A key of a bucket
+                          with more arrivals than ways may have lost its
+                          row to the step's eviction instead of a demotion:
+                          its fresh starts are allowed, and its unmerged
+                          answers past the deadline not counted, since no
+                          cold row may wait.
+
+reset_time is `replay_sample`'s: a leaky answer's inside its RPC's bounds, a
+token's its row's expiry — the preloaded one exactly, in either tier, or
+inside the bounds of the RPC that started the fresh row, which a merge keeps.
+RPCs in flight together may reach the table in any order, and a fresh start
+may fall between them: every interleaving of a small group is tried (each
+RPC's own checks in their order), the canonical order alone for a large one.
 """
 from __future__ import annotations
 
 import copy
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -91,6 +138,9 @@ FROZEN_COUNTS = ("wrong_answers", "wrong_reset_time",
 MOVING_COUNTS = FROZEN_COUNTS + (
     "clock_outside_rpc", "clock_runs_backwards", "answered_after_expiry",
     "renewed_before_expiry")
+TIERED_COUNTS = FROZEN_COUNTS + ("admitted_beyond_bound", "merged_late")
+TIERED_SEEN = ("continued_answers", "fresh_answers", "merged_answers",
+               "promoted_answers", "keys_started_cold")
 MOVING_SEEN = ("live_window_answers", "new_window_answers",
                "whole_token_leaks", "straddling_answers",
                "live_leaky_answers",
@@ -284,15 +334,24 @@ class _Reference:
         }
         self.model.cache.clear()
         if uni.resident[k]:
-            self.model.cache[hkey] = t.CacheItem(
-                key=hkey, algorithm=algorithm,
-                expire_at=self.t0_ms + dur, limit=uni.limit, duration=dur,
-                remaining=(float(uni.remaining0[k]) if leaky
-                           else int(uni.remaining0[k])),
-                created_at=self.t0_ms, status=t.Status.UNDER_LIMIT,
-                burst=uni.limit,
-            )
+            self.model.cache[hkey] = self.preloaded_item(k, hkey, leaky)
         return hkey, leaky, reqs, weak
+
+    def preloaded_item(self, k: int, hkey: str, leaky: bool):
+        """Key `k`'s row as the harness preloaded it, at the stamp
+        `start_key` set."""
+        uni, t = self.uni, self.types
+        dur = uni.duration_ms
+        return t.CacheItem(
+            key=hkey,
+            algorithm=(t.Algorithm.LEAKY_BUCKET if leaky
+                       else t.Algorithm.TOKEN_BUCKET),
+            expire_at=self.t0_ms + dur, limit=uni.limit, duration=dur,
+            remaining=(float(uni.remaining0[k]) if leaky
+                       else int(uni.remaining0[k])),
+            created_at=self.t0_ms, status=t.Status.UNDER_LIMIT,
+            burst=uni.limit,
+        )
 
 
 def _in_flight_groups(rows, a: Answers, t_send, t_done,
@@ -316,7 +375,7 @@ def _in_flight_groups(rows, a: Answers, t_send, t_done,
 
 
 def _observed(grp: List[int], a: Answers, hkey: str, model, reqs: dict,
-              v: Verdict):
+              v: Verdict, linear: bool = True):
     """A group's answers as (status, remaining, reset_time, rpc, hits) in
     the order the reference is to give them, and the group's RPCs."""
     rpcs = {int(a.rpc[r]) for r in grp}
@@ -334,7 +393,7 @@ def _observed(grp: List[int], a: Answers, hkey: str, model, reqs: dict,
             v.bad("out_of_order_duplicates", key=hkey, rpc=q,
                   got=[o[:2] for o in lst])
     if len(rpcs) > 1:
-        obs = (_canonical(obs) if len(reqs) == 1
+        obs = (_canonical(obs) if len(reqs) == 1 or not linear
                else linearize(model, reqs, hkey, obs))
     return obs, rpcs
 
@@ -527,3 +586,269 @@ def replay_moving(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
     v.notes.update(saw)
     v.notes["crowded_restarts"] = restarts
     v.notes["crowded_ambiguous"] = ambiguous
+
+
+# -- a table that does not hold its universe ---------------------------------
+
+# A group of RPCs in flight together with more answers than this is
+# replayed in its canonical order alone.
+INTERLEAVE_MOST = 8
+
+
+class _Tiers(NamedTuple):
+    """What the two tiers may hold of one key, as the replay knows it.  A
+    row is (remaining, status): under a clock that stands nothing else of
+    the reference's item moves an answer."""
+
+    hot: Optional[tuple] = None       # the table row
+    cold: Optional[tuple] = None      # the cold store's row
+    hot_born: Optional[tuple] = None  # wall bounds of the RPC that started
+    cold_born: Optional[tuple] = None   # the row; None: the preload stamp
+    certain: bool = True              # the cold row is known to wait
+    since: Optional[float] = None     # when its fresh answer was received
+    fresh: int = 0                    # fresh starts seen
+    admitted: int = 0                 # hits admitted
+
+
+def merge_rows(hot: tuple, cold: tuple, limit: int) -> tuple:
+    """docs/tiering.md's merge on (remaining, status) rows: the table row
+    keeps its status, and its remaining falls by what the cold row had
+    consumed and stops at 0 — `max(r - consumed, 0)` of the cold row's `r`
+    and the table row's `consumed`.  Never more than either."""
+    return max(hot[0] - max(limit - cold[0], 0), 0), hot[1]
+
+
+class _TieredKeys:
+    """The answers of a sampled key against the states the tier's algebra
+    allows.  What cannot be told apart is kept apart: a replay holds every
+    state the answers so far allow (a row of a crowded bucket that went may
+    have been demoted or evicted) and an answer is wrong where none gives
+    it.  core/pymodel.py answers every question, once: under a clock that
+    stands its answer follows from the row's (remaining, status), the
+    algorithm and the hits alone."""
+
+    def __init__(self, ref: _Reference, deadline_s: float, v: Verdict,
+                 saw: dict) -> None:
+        self.ref, self.deadline_s, self.v, self.saw = ref, deadline_s, v, saw
+        self.limit = ref.uni.limit
+        self.asked: Dict[tuple, tuple] = {}
+
+    def start(self, hkey: str, reqs: dict, weak: bool, leaky: bool) -> None:
+        self.hkey, self.reqs, self.weak, self.leaky = hkey, reqs, weak, leaky
+
+    def _ask(self, row: Optional[tuple], hits: int) -> tuple:
+        """(status, remaining, reset_time - the preload stamp) the reference
+        answers `hits` with from `row`, and the row it leaves."""
+        at = (self.leaky, row, hits)
+        got = self.asked.get(at)
+        if got is None:
+            ref, cache = self.ref, self.ref.model.cache
+            cache.clear()
+            if row is not None:
+                item = ref.preloaded_item(0, self.hkey, self.leaky)
+                item.remaining, item.status = row[0], ref.types.Status(row[1])
+                cache[self.hkey] = item
+            want = ref.model.get_rate_limit(self.reqs[hits])
+            left = cache[self.hkey]
+            got = self.asked[at] = (
+                (int(want.status), want.remaining,
+                 want.reset_time - ref.t0_ms),
+                (left.remaining, int(left.status)),
+            )
+            cache.clear()
+        return got
+
+    def step(self, st: _Tiers, o: tuple, bounds: tuple, t_send: float,
+             t_done: float) -> List[tuple]:
+        """Every (`st` after the answer `o`, the state that gave it, whether
+        its reset_time is that state's, whether the key's cold row had
+        waited past the deadline) that the algebra allows; none where no
+        allowed state gives `o` = (status, remaining, reset_time, rpc, hits).
+        """
+        waited = (st.hot is not None and st.cold is not None
+                  and st.since is not None
+                  and t_send > st.since + self.deadline_s)
+        tries = []
+        if st.hot is not None:
+            tries.append(("continued", st.hot))
+            if st.cold is not None:
+                tries.append(("merged",
+                              merge_rows(st.hot, st.cold, self.limit)))
+        if st.cold is not None and (st.hot is None or self.weak):
+            # The promote found no table row: it landed before the check
+            # did, or the step had evicted the row of a crowded bucket.
+            tries.append(("promoted", st.cold))
+        tries.append(("fresh", None))
+        out = []
+        for kind, row in tries:
+            want, left = self._ask(row, o[4])
+            if want[:2] != o[:2]:
+                continue
+            nxt = st._replace(hot=left, admitted=st.admitted
+                              + (o[4] if o[0] == 0 else 0))
+            if kind == "merged":
+                nxt = nxt._replace(cold=None, cold_born=None, since=None)
+            elif kind == "promoted":
+                nxt = nxt._replace(hot_born=st.cold_born, cold=None,
+                                   cold_born=None, since=None)
+            elif kind == "fresh":
+                nxt = nxt._replace(hot_born=bounds, fresh=st.fresh + 1)
+            born = bounds if self.leaky else nxt.hot_born
+            good = (o[2] == self.ref.t0_ms + want[2] if born is None
+                    else born[0] + want[2] <= o[2] <= born[1] + want[2])
+            late = waited and kind == "continued"
+            if kind != "fresh":
+                out.append((nxt, kind, good, late))
+                continue
+            waiting = st.cold is not None
+            if st.hot is None:
+                out.append((nxt._replace(since=t_done if waiting else None),
+                            kind, good, late))
+                continue
+            # A fresh start over a table row: the row went to the cold store
+            # (merged with a cold row still waiting there, which assumes the
+            # least budget) — or, in a bucket that can evict, was lost.
+            out.append((nxt._replace(
+                cold=(merge_rows(st.hot, st.cold, self.limit) if waiting
+                      else st.hot),
+                cold_born=st.hot_born, since=t_done,
+                certain=(st.certain and waiting) or not self.weak,
+            ), kind, good, late))
+            if self.weak:
+                out.append((nxt._replace(since=t_done if waiting else None),
+                            kind, good, late))
+        return out
+
+    def run(self, states: List[_Tiers], obs: List[tuple], rec,
+            judge: bool) -> Optional[List[_Tiers]]:
+        """`obs` in this order from every state of `states`; with `judge` a
+        fault is counted and the replay goes on, without it the first one
+        ends the try (None)."""
+        v = self.v
+        for o in obs:
+            q = o[3]
+            bounds = (int(rec["wall_send"][q]), int(rec["wall_recv"][q]))
+            ts, td = float(rec["t_send"][q]), float(rec["t_done"][q])
+            got = [g for st in states for g in self.step(st, o, bounds, ts, td)]
+            if not got:
+                if not judge:
+                    return None
+                st = states[0]
+                v.bad("wrong_answers", key=self.hkey, rpc=q,
+                      want=self._ask(st.hot, o[4])[0][:2], got=o[:2],
+                      leaky=self.leaky, fresh_starts=st.fresh,
+                      table_row=st.hot, cold_row=st.cold)
+                states = [st._replace(hot=self._ask(st.hot, o[4])[1])
+                          for st in states]
+                continue
+            # The least fault that explains the answer: none, then a
+            # reset_time, then a merge that a row known to wait had not had.
+            merged = [g for g in got if not (g[3] and g[0].certain)]
+            keep = [g for g in merged if g[2]] or merged or got
+            unmerged = not merged
+            if not judge and (unmerged or not keep[0][2]):
+                return None
+            if judge:
+                if not any(g[2] for g in keep):
+                    v.bad("wrong_reset_time", key=self.hkey, rpc=q,
+                          got=o[2] - self.ref.t0_ms, leaky=self.leaky,
+                          born=keep[0][0].hot_born, state=keep[0][1])
+                if unmerged:
+                    v.bad("merged_late", key=self.hkey, rpc=q,
+                          after_s=ts - keep[0][0].since, leaky=self.leaky)
+                self.saw[keep[0][1] + "_answers"] += 1
+            states = list(dict.fromkeys(g[0] for g in keep))
+        return states
+
+
+def tiered_ledger(a: Answers) -> tuple:
+    """(hits the daemon admitted, keys it touched) over every answer of a
+    run: the two sides of docs/tiering.md's bound in the form an operator
+    checks it, admitted <= limit x (keys touched + demotes)."""
+    under = (a.status == 0) & (a.err_len == 0)
+    return int(a.hits[under].sum()), len(np.unique(a.key))
+
+
+def most_checks_in_span(t_done: np.ndarray, sizes: np.ndarray,
+                        span_s: float) -> int:
+    """The most checks answered in any `span_s` seconds of a run: what the
+    served path can have inserted into the table between the end of one
+    tick of the tier's manager and the end of the next (a tick that starts
+    on its interval and takes no longer than it), since a check inserts at
+    most one row.  A tick leaves the table at or under its high-water mark,
+    so high_water x slots plus this bounds the table at any time."""
+    if not len(t_done):
+        return 0
+    by = np.argsort(t_done, kind="stable")
+    t, upto = t_done[by], np.cumsum(sizes[by])
+    first = np.searchsorted(t, t - span_s, side="left")
+    before = np.where(first > 0, upto[first - 1], 0)
+    return int((upto - before).max())
+
+
+def _interleavings(obs: List[tuple]):
+    """Every order of `obs` that keeps each RPC's own answers in theirs."""
+    queues: Dict[int, List[tuple]] = {}
+    for o in obs:
+        queues.setdefault(o[3], []).append(o)
+    qs = list(queues.values())
+
+    def rec(at: List[int], out: List[tuple]):
+        if len(out) == len(obs):
+            yield list(out)
+            return
+        for i, q in enumerate(qs):
+            if at[i] < len(q):
+                at[i] += 1
+                out.append(q[at[i] - 1])
+                yield from rec(at, out)
+                out.pop()
+                at[i] -= 1
+
+    yield from rec([0] * len(qs), [])
+
+
+def replay_tiered(a: Answers, rec, uni: Universe, t0_ms: int, seed: int,
+                  set_aside: np.ndarray, extra_crowded: np.ndarray,
+                  v: Verdict, always: Optional[np.ndarray] = None) -> None:
+    """`replay_sample` for a configuration whose table does not hold its
+    universe: every answer on the sampled keys has to be what core/pymodel.py
+    gives from one of the states docs/tiering.md's algebra allows (the
+    module docstring has them).  Counts in `v.counts` are the names of
+    TIERED_COUNTS, all held to 0; `v.notes` gains TIERED_SEEN."""
+    order, keys, cuts = _sample(a, rec, uni, seed, set_aside, always, v)
+    t_send, t_done = rec["t_send"], rec["t_done"]
+    ref = _Reference(uni, t0_ms, extra_crowded)
+    saw = dict.fromkeys(TIERED_SEEN, 0)
+    deadline_s = uni.promote_deadline_ms / 1e3
+
+    key = _TieredKeys(ref, deadline_s, v, saw)
+    for c in range(len(cuts) - 1):
+        rows = order[cuts[c]:cuts[c + 1]]
+        k = int(keys[cuts[c]])
+        hkey, leaky, reqs, weak = ref.start_key(k, a.hits[rows])
+        # A key starts with the row the harness preloaded, in the table or
+        # in the cold store.
+        row = (float(uni.remaining0[k]) if leaky else int(uni.remaining0[k]),
+               0)
+        saw["keys_started_cold"] += bool(uni.cold[k])
+        states = [_Tiers(cold=row) if uni.cold[k] else _Tiers(hot=row)]
+        key.start(hkey, reqs, weak, leaky)
+        for grp in _in_flight_groups(rows, a, t_send, t_done):
+            obs, rpcs = _observed(grp, a, hkey, ref.model, reqs, v,
+                                  linear=False)
+            if 1 < len(rpcs) and len(obs) <= INTERLEAVE_MOST:
+                for cand in _interleavings(obs):
+                    if key.run(states, cand, rec, judge=False) is not None:
+                        obs = cand
+                        break
+            states = key.run(states, obs, rec, judge=True)
+        # The bound of docs/tiering.md, by the state that saw the most
+        # fresh starts: no state the answers allow may break it.
+        st = max(states, key=lambda s: s.fresh)
+        if st.admitted > uni.limit * (1 + st.fresh):
+            v.bad("admitted_beyond_bound", key=hkey, admitted=st.admitted,
+                  fresh_starts=st.fresh)
+    for name in TIERED_COUNTS:
+        v.counts.setdefault(name, 0)
+    v.notes.update(saw)

@@ -127,6 +127,21 @@ PEERS_FORMS = ('absent or 1: one daemon on all of the configuration\'s chips',
                "lists N distinct advertise addresses 127.0.0.1:<port>, and "
                'daemon.GUBER_PEER_PICKER_HASH is "xx" (the ring hash is the '
                "table fingerprint; no configuration needs another yet)")
+RESIDENCY_FORMS = ('absent or "table": the device table holds the universe, '
+                   "min(arrivals, ways) rows a bucket, and the rest was "
+                   "evicted before the run",
+                   '"tiered": the table does not hold its universe '
+                   "(docs/tiering.md): it starts at its low-water mark and "
+                   "every other key as a row of the cold store; then "
+                   "daemon.GUBER_TIER_ENABLED is \"true\", "
+                   "GUBER_TIER_HIGH_WATER and GUBER_TIER_LOW_WATER are stated "
+                   "with 0 < low < high <= 1, GUBER_TIER_COLD_CAPACITY is at "
+                   "least universe.keys less the rows the table starts with "
+                   "(floor(low x slots)), GUBER_TIER_INTERVAL is plain seconds "
+                   "and background_timers_s.tier_tick says the same, "
+                   "universe.promote_deadline_ms is a number above 0 and, "
+                   "for now, universe.shards is 1, peers 1, "
+                   "universe.global_keys 0 or absent and universe.clock frozen")
 _LOOPBACK = re.compile(r"^127\.0\.0\.1:([1-9][0-9]{3,4})$")
 # No run may outlast this (the contract: 360 s, 1200 s where it compiles).
 LONGEST_RUN_MS = 1_200_000
@@ -235,6 +250,52 @@ def clock_moves(u: dict) -> bool:
     return u.get("clock", "frozen") == "moving"
 
 
+def tiered(u: dict) -> bool:
+    """Whether a configuration's `universe` group says that the table does
+    not hold it (bench/lib/oracle.py `replay_tiered`)."""
+    return u.get("residency", "table") == "tiered"
+
+
+def tier_marks(c: dict) -> tuple:
+    """(high, low) water marks of a tiered configuration, as shares of the
+    table's slots."""
+    d = c["daemon"]
+    return float(d["GUBER_TIER_HIGH_WATER"]), float(d["GUBER_TIER_LOW_WATER"])
+
+
+def table_rows_at_start(c: dict) -> int:
+    """Rows a tiered configuration's table starts with: its low-water mark,
+    or every key where the universe is smaller."""
+    low = tier_marks(c)[1]
+    return min(int(c["universe"]["keys"]),
+               int(low * int(c["daemon"]["GUBER_TPU_NUM_SLOTS"])))
+
+
+def check_residency(c: dict, where: str) -> None:
+    known = f"{where}: universe.residency is none of the known forms: " \
+        + "; ".join(RESIDENCY_FORMS)
+    u, d = c["universe"], c["daemon"]
+    kind = u.get("residency", "table")
+    _need(kind in ("table", "tiered"), known)
+    if kind == "table":
+        return
+    _need(d.get("GUBER_TIER_ENABLED") == "true", known)
+    try:
+        high, low = tier_marks(c)
+        cold = int(d["GUBER_TIER_COLD_CAPACITY"])
+        slots = int(d["GUBER_TPU_NUM_SLOTS"])
+        tick = float(d["GUBER_TIER_INTERVAL"])
+    except (KeyError, ValueError) as e:
+        raise SpecError(known) from e
+    _need(0 < low < high <= 1 and slots >= 1, known)
+    _need(0 < tick == c["background_timers_s"].get("tier_tick"), known)
+    _need(cold >= int(u["keys"]) - table_rows_at_start(c), known)
+    _need(_number(u.get("promote_deadline_ms"))
+          and u["promote_deadline_ms"] > 0, known)
+    _need(int(u["shards"]) == 1 and peers_of(c) == 1
+          and int(u.get("global_keys", 0)) == 0 and not clock_moves(u), known)
+
+
 def peers_of(c: dict) -> int:
     """How many daemons a configuration runs (absent: one)."""
     return c.get("peers", 1)
@@ -295,6 +356,7 @@ def check_config(c: dict, where: str) -> None:
     _need(all(k.startswith("GUBER_") for k in c["daemon"]),
           f"{where}: daemon settings are GUBER_* variables")
     check_peers(c, where)
+    check_residency(c, where)
 
 
 def check_layer_metric(m: dict, where: str) -> None:

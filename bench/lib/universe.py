@@ -19,6 +19,15 @@ hash ring, its bucket there ``h & (buckets_per_daemon - 1)``, and
 ``gbucket = owner * buckets_per_daemon + bucket``; `slots` counts every
 daemon's.  With `peers` absent every array is what it was before the form
 existed, bit for bit.
+
+A table that does not hold its universe (a configuration that says
+`"residency": "tiered"`, docs/tiering.md) starts with BOTH tiers filled,
+each key in exactly one (`tier_split`): the table with `table_rows` rows,
+its low-water mark, taken way by way — every bucket's first arrival, then
+every bucket's second, ... and of the last way that fits only the buckets
+of the lowest numbers — so that a row's slot is still ``bucket * ways +
+way``; every other key of the universe is a row of the cold store.  With
+`residency` absent nothing here is reached.
 """
 from __future__ import annotations
 
@@ -148,6 +157,10 @@ class Universe:
     moving: bool = False     # the configuration's windows elapse in a run
     ring: Optional[ring_mod.Ring] = None    # a cluster's; None: one daemon
     owner: Optional[np.ndarray] = None      # uint8[U] daemon, with a ring
+    # A tiered universe's (None: the table holds it): bool[U], the key
+    # starts as a row of the cold store; `resident | cold` is every key.
+    cold: Optional[np.ndarray] = None
+    promote_deadline_ms: float = 0.0
 
     @property
     def n_resident(self) -> int:
@@ -172,12 +185,35 @@ class Universe:
                              self.peers)
 
 
+def tier_split(way: np.ndarray, gbucket: np.ndarray, ways: int,
+               table_rows: int) -> np.ndarray:
+    """bool[U]: the keys a tiered universe's table starts with — at most
+    `table_rows` of those whose rank among their bucket's arrivals is under
+    `ways`, the lower ranks first and, of the last rank that fits, the
+    buckets of the lowest numbers (a bucket has one key of a rank, so the
+    cut is exact).  Every other key starts in the cold store."""
+    fits = way < ways
+    level = np.bincount(way[fits], minlength=ways).cumsum()
+    if level[-1] <= table_rows:
+        return fits
+    last = int(np.searchsorted(level, table_rows, side="right"))
+    room = table_rows - (int(level[last - 1]) if last else 0)
+    resident = way < last
+    if room:
+        at = np.flatnonzero(way == last)
+        cut = np.partition(gbucket[at], room - 1)[room - 1]
+        resident[at[gbucket[at] <= cut]] = True
+    return resident
+
+
 def build_universe(native, cfg: dict, seed: int, slots: int,
-                   ring=None) -> Universe:
+                   ring=None, table_rows: Optional[int] = None) -> Universe:
     """`cfg` is the configuration file's "universe" group; `slots` the
     table size in use (the CPU dry run overrides it), ONE daemon's where
-    `ring` (bench/lib/ring.py) says there are several.  Works in chunks so
-    that temporaries are reused instead of freshly mapped."""
+    `ring` (bench/lib/ring.py) says there are several; `table_rows` the
+    rows a tiered universe's table starts with (None: the table holds the
+    universe).  Works in chunks so that temporaries are reused instead of
+    freshly mapped."""
     n = int(cfg["keys"])
     ways, shards = int(cfg["ways"]), int(cfg["shards"])
     n_global = int(cfg.get("global_keys", 0))
@@ -243,6 +279,10 @@ def build_universe(native, cfg: dict, seed: int, slots: int,
     # GLOBAL keys are created by traffic (the engine syncs them into the
     # owner's bucket), so their way is reserved but left empty at preload.
     resident = (way < ways) & ~is_global
+    cold = None
+    if table_rows is not None:
+        resident = tier_split(way, gbucket, ways, table_rows)
+        cold = ~resident
     return Universe(
         ids=ids, fp=fp, algo=algo, is_global=is_global,
         remaining0=remaining0, gbucket=gbucket, way=way, resident=resident,
@@ -250,6 +290,7 @@ def build_universe(native, cfg: dict, seed: int, slots: int,
         global_limit=int(cfg.get("global_limit", 0)),
         duration_ms=int(cfg["duration_ms"]), slots=slots, ways=ways,
         shards=shards, moving=clock_moves(cfg), ring=ring, owner=owner,
+        cold=cold, promote_deadline_ms=float(cfg.get("promote_deadline_ms", 0)),
     )
 
 
@@ -273,6 +314,13 @@ def handoff(u: Universe, seed: int, n_probe: int,
     # Rows whose window is a second are expired before they are probed:
     # the launcher then asks the lookup at the stamp they were made at.
     at_stamp = {"probe_at_preload_stamp": np.ones(1, bool)} if u.moving else {}
+    if u.cold is not None:
+        # The cold store's rows, and of the sample whether IT must hold each.
+        csel = np.flatnonzero(u.cold)
+        at_stamp.update(
+            cold_fp=u.fp[csel], cold_algo=u.algo[csel],
+            cold_remaining0=u.remaining0[csel], probe_cold=u.cold[idx],
+        )
     return {
         **at_stamp,
         "slot": slot,
@@ -319,6 +367,80 @@ def table_arrays(h: Dict[str, np.ndarray], t0_ms: int) -> Dict[str, np.ndarray]:
     }
 
 
+# The cold store's columns (runtime/coldtier.py COLD_FIELDS, the MigratedRows
+# layout; copied, not imported): what `ColdTier.restore` takes.
+COLD_FIELDS = ("key_hash", "algo", "limit", "duration", "remaining",
+               "remaining_f", "t0", "status", "burst", "expire_at")
+
+
+def cold_arrays(h: Dict[str, np.ndarray], t0_ms: int) -> Dict[str, np.ndarray]:
+    """Columns of the preloaded cold store: every key the table does not
+    start with as the row `table_arrays` would have given it — created at
+    `t0_ms` with `remaining0` tokens left — in the layout a checkpoint's
+    `coldtier` entry has."""
+    _, limit, duration_ms = (int(x) for x in h["geometry"])
+    n = len(h["cold_fp"])
+    leaky = h["cold_algo"] == ALGO_LEAKY
+    rem = h["cold_remaining0"]
+
+    def full(dtype, value):
+        return np.full(n, value, dtype=dtype)
+
+    return {
+        "key_hash": h["cold_fp"].astype(np.int64),
+        "algo": h["cold_algo"].astype(np.int32),
+        "limit": full(np.int64, limit),
+        "duration": full(np.int64, duration_ms),
+        "remaining": np.where(leaky, 0, rem).astype(np.int64),
+        "remaining_f": np.where(leaky, rem, 0).astype(np.float64),
+        "t0": full(np.int64, t0_ms),
+        "status": full(np.int32, 0),                  # UNDER_LIMIT
+        "burst": full(np.int64, limit),
+        "expire_at": full(np.int64, t0_ms + duration_ms),
+    }
+
+
+def tiered_row_bounds(u: Universe, extra_fp: np.ndarray,
+                      unmerged: int) -> tuple:
+    """(least, most) rows the two tiers hold TOGETHER — the table's
+    occupancy plus the cold store's residents — once a tiered universe has
+    been served.
+
+    Most.  Every key of the universe starts as one row, in one tier, and a
+    key outside it (`extra_fp`, the wire check's) gains one.  A demotion
+    moves a row, a merge folds two into one; the one thing that makes a
+    second row of a key is a fresh start while its cold row waits for its
+    merge, and the configuration's `promote_deadline_ms` ends that: of the
+    keys answered less than the deadline before the count was taken,
+    `unmerged`, each may still have both.  So most = keys + extra +
+    `unmerged`.
+
+    Least.  A cold row goes only into the table (a promote) or with a drop
+    that is counted and held to 0; a table row goes only to the cold store
+    (a demote) or under the step's own eviction, which takes a row only of
+    a bucket whose `ways` ways are all live — and leaves that bucket
+    `ways` rows.  So the rows of a bucket's keys, both tiers together, never
+    fall under min(arrivals, ways): the same arithmetic `expected_occupancy`
+    states for a table alone, here over every key, since every key is
+    preloaded."""
+    least = _rows_a_bucket_holds(u, u.gbucket, extra_fp)
+    return least, len(u.fp) + len(np.unique(extra_fp)) + int(unmerged)
+
+
+def _rows_a_bucket_holds(u: Universe, gbucket: np.ndarray,
+                         extra_fp: np.ndarray) -> int:
+    """min(distinct arrivals, ways), summed over the buckets: the arrivals
+    at `gbucket` and the keys outside the universe with the fingerprints
+    `extra_fp`."""
+    nb = u.slots // u.ways
+    counts = np.bincount(gbucket, minlength=nb)
+    if len(extra_fp):
+        counts = counts + np.bincount(
+            u.bucket_of(np.unique(extra_fp)), minlength=nb,
+        )
+    return int(np.minimum(counts, u.ways).sum())
+
+
 def expected_occupancy(u: Universe, touched_index: np.ndarray,
                        extra_fp: np.ndarray) -> int:
     """Rows the table holds once the keys at `touched_index` (and the
@@ -332,12 +454,6 @@ def expected_occupancy(u: Universe, touched_index: np.ndarray,
     arrival takes an empty way before another key's expired row
     (ops/step.py `locate_slots`: "my own expired slot > empty > other
     expired > oldest touch"), so no row goes while its bucket has room."""
-    nb = u.slots // u.ways
     present = u.resident.copy()
     present[touched_index] = True
-    counts = np.bincount(u.gbucket[present], minlength=nb)
-    if len(extra_fp):
-        counts = counts + np.bincount(
-            u.bucket_of(np.unique(extra_fp)), minlength=nb,
-        )
-    return int(np.minimum(counts, u.ways).sum())
+    return _rows_a_bucket_holds(u, u.gbucket[present], extra_fp)

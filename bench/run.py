@@ -351,6 +351,65 @@ def window_stats(traffic: dict, rec: dict, plan, tw0: float,
     return out
 
 
+TIER_COUNTERS = ("demotes", "promotes", "cold_hits", "capacity_drops",
+                 "promote_failures", "promote_retries", "demote_passes",
+                 "ticks")
+
+
+def tiered_counts(compare, cfg: dict, uni, plan, rec: dict, answers,
+                  snap_ready, snap_end, slots: int, aux_fp, window) -> tuple:
+    """What a table that does not hold its universe is held to beside its
+    answers, from the daemon's `/debug/vars` `tier` block and the client's
+    record.  Returns (the tier's numbers for the result line, the least and
+    the most rows both tiers may hold together, the rows they hold)."""
+    t0, t1 = snap_ready.vars["tier"], snap_end.vars["tier"]
+    grown = {k: int(t1[k]) - int(t0[k]) for k in TIER_COUNTERS}
+    compare("tier_capacity_drops_grown", grown["capacity_drops"])
+    compare("tier_promote_failures_grown", grown["promote_failures"])
+    # docs/tiering.md's bound from the daemon's own ledger, the form
+    # scripts/chaos_smoke.py --scenario coldstorm checks.
+    admitted, touched = oracle.tiered_ledger(answers)
+    compare("tier_admitted_beyond_ledger", max(
+        0, admitted - uni.limit * (touched + grown["demotes"])))
+    high, _ = spec.tier_marks(cfg)
+    tick_s = float(cfg["background_timers_s"]["tier_tick"])
+    ok = rec["code"] == oracle.OK
+    sizes = np.diff(plan.offsets)[rec["plan_idx"]]
+    room = oracle.most_checks_in_span(rec["t_done"][ok], sizes[ok],
+                                      2 * tick_s)
+    table = int(snap_end.vars["backend"]["occupancy"])
+    most = int(high * slots) + room
+    log(f"tier: table {table} rows of {slots}, high-water mark "
+        f"{int(high * slots)} and {room} checks in the busiest two ticks; "
+        f"cold store {t1['cold_residents']} of {t1['cold_capacity']}; grown "
+        f"since ready {grown}; promote latency {t1['promote_latency']}")
+    compare("table_beyond_high_water", max(0, table - most))
+    # Keys answered less than the deadline before the count: their cold
+    # row may still wait for its merge beside a fresh row.
+    since = snap_end.t - uni.promote_deadline_ms / 1e3
+    young = rec["t_done"][answers.rpc] >= since
+    lo, hi = universe_mod.tiered_row_bounds(
+        uni, aux_fp, len(np.unique(answers.key[young])))
+    w0, w1 = (s.vars["tier"] for s in window)
+    per_s = {k + "_per_s": (int(w1[k]) - int(w0[k])) / (window[1].t - window[0].t)
+             for k in ("demotes", "promotes", "cold_hits", "capacity_drops")}
+    tier = {
+        **per_s, "grown_since_ready": grown,
+        "table_rows": table, "table_rows_at_window": [
+            int(s.vars["backend"]["occupancy"]) for s in window],
+        "high_water_rows": int(high * slots),
+        "cold_residents": int(t1["cold_residents"]),
+        "promote_latency_p99_s": t1["promote_latency"]["p99_s"],
+        "promote_latency_mean_s": (
+            t1["promote_latency"]["sum_s"]
+            / max(1, t1["promote_latency"]["cumulative"][-1])),
+        "promote_latency": {
+            "buckets": t1["promote_latency"]["buckets"],
+            "cumulative": t1["promote_latency"]["cumulative"]},
+    }
+    return tier, lo, hi, table + int(t1["cold_residents"])
+
+
 def reduce_trace(trace_dirs: list) -> dict:
     env = os.environ.copy()
     env["JAX_PLATFORMS"] = "cpu"
@@ -455,8 +514,10 @@ def _run(args, tmp: str) -> dict:
              "--out", rec_path],
             client_env, os.path.join(out_dir, "client.log"),
         )
+        tiered = spec.tiered(cfg["universe"])
         uni = universe_mod.build_universe(
-            native, cfg["universe"], args.seed, slots, ring
+            native, cfg["universe"], args.seed, slots, ring,
+            table_rows=spec.table_rows_at_start(cfg) if tiered else None,
         )
         for k, tag in enumerate(tags):
             np.savez(os.path.join(tmp, "handoff.npz"),
@@ -471,7 +532,9 @@ def _run(args, tmp: str) -> dict:
             f"{int(uni.is_global.sum())} GLOBAL; plan {len(plan)} RPCs, "
             f"{int(plan.offsets[-1])} checks"
             + (f"; resident a daemon {uni.resident_by_daemon().tolist()}"
-               if peers > 1 else ""))
+               if peers > 1 else "")
+            + (f"; {int(uni.cold.sum())} start in the cold store"
+               if tiered else ""))
 
         readies = [sv.next_json(READY_TIMEOUT_S) for sv in servers]
         for tag, ready in zip(tags, readies):
@@ -480,17 +543,23 @@ def _run(args, tmp: str) -> dict:
                 f"(warm-up {dev['warmup_s']}s), device {dev}, chip "
                 f"{ready.get('chip')}; preload {ready.get('preload')}; "
                 f"fetch shapes {ready['fetch_shapes']}; "
+                + (f"tier programs {ready['tier_programs']}; "
+                   if "tier_programs" in ready else "") +
                 f"lanes {lanes}; compile cache {cache_at_start} -> "
                 f"{len(cache_entries())} entries")
         check_chips(args, cell, cfg, readies)
         compare = Compare()
         # Every daemon against its own share of the placement.
+        # Of a tiered universe both tiers, each against its own share.
         compare("preload_occupancy_differs", sum(
             abs(r["preload"]["occupancy"] - int(n))
             for r, n in zip(readies, uni.resident_by_daemon())
-        ))
+        ) + (abs(readies[0]["preload"]["cold_residents"] - int(uni.cold.sum()))
+             if tiered else 0))
         compare("probe_differs_from_placement",
-                sum(r["preload"]["probe_differs"] for r in readies))
+                sum(r["preload"]["probe_differs"]
+                    + r["preload"].get("probe_cold_differs", 0)
+                    for r in readies))
 
         from lib import wirecheck
 
@@ -610,6 +679,7 @@ def _run(args, tmp: str) -> dict:
     skewed = traffic["keys"]["distribution"] != "uniform"
     replay, counted = (
         (oracle.replay_moving, oracle.MOVING_COUNTS) if uni.moving
+        else (oracle.replay_tiered, oracle.TIERED_COUNTS) if tiered
         else (oracle.replay_sample, oracle.FROZEN_COUNTS)
     )
     replay(
@@ -659,11 +729,20 @@ def _run(args, tmp: str) -> dict:
     hi_keys = np.unique(np.concatenate([answers.key, aside]))
     be0, be1 = snap_ready.vars["backend"], snap_end.vars["backend"]
     not_persisted = be1["not_persisted"] - be0["not_persisted"]
-    occ = be1["occupancy"]
-    hi = universe_mod.expected_occupancy(uni, hi_keys, aux_fp)
+    tier = None
+    if tiered:
+        # Both tiers together against the placement arithmetic, and the
+        # table against its high-water mark (bench/lib/universe.py
+        # `tiered_row_bounds`, bench/lib/oracle.py `most_checks_in_span`).
+        tier, lo, hi, occ = tiered_counts(
+            compare, cfg, uni, plan, rec, answers, snap_ready, snap_end,
+            slots, aux_fp, (snap0, snap1))
+    else:
+        occ = be1["occupancy"]
+        hi = universe_mod.expected_occupancy(uni, hi_keys, aux_fp)
+        lo = universe_mod.expected_occupancy(uni, lo_keys, aux_fp)
     # A wire-check bucket that came and went may have evicted a row.
-    lo = (universe_mod.expected_occupancy(uni, lo_keys, aux_fp)
-          - not_persisted - (len(aux_seen) - len(aux_fp)))
+    lo -= not_persisted + (len(aux_seen) - len(aux_fp))
     log(f"occupancy {occ}, expected {lo}..{hi} ({not_persisted} lanes not "
         f"persisted)")
     compare("occupancy_beyond_expected", max(0, occ - hi))
@@ -741,6 +820,10 @@ def _run(args, tmp: str) -> dict:
     if uni.moving:
         # What the moving-clock replay saw (the driver ignores it).
         result["replay"] = {k: verdict.notes[k] for k in oracle.MOVING_SEEN}
+    if tier:
+        # What the tiered replay saw and the tier's own counters (the same).
+        result["replay"] = {k: verdict.notes[k] for k in oracle.TIERED_SEEN}
+        result["tier"] = tier
     if daemons:
         # What each daemon of the cluster held and did (the same).
         result["daemons"] = daemons

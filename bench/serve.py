@@ -9,7 +9,13 @@ the chip:
             checkpoint-restore seam (`backend._install_table`, what
             runtime/checkpoint.py calls) instead of 10M checks over gRPC,
             then a seeded sample of keys probed through the program's own
-            lookup against the placement arithmetic;
+            lookup against the placement arithmetic; of a table that does
+            not hold its universe (bench/README.md) also the cold store's
+            rows, through the seam a checkpoint restores them by
+            (`ColdTier.restore`), the probe saying of each key which tier
+            the arithmetic puts it in and which the daemon has it in, and
+            the tier's three device programs compiled before the ready
+            report, which the daemon's own warm-up leaves to first use;
   warming   every response-fetch executable a drain of the cell's traffic
             can ask for (`fetch_ravel` concatenates one program per
             sequence of round shapes), so that none compiles on the
@@ -27,7 +33,10 @@ Serving itself is untouched: gRPC handlers, compiled lane, default
 that the correctness check fails when it should (never used by a benchmark
 run): `f32` is the lower-precision control, the leaky bucket's float64
 operands rounded to float32 before use; `alter` changes one answer in 97
-where the fetched response is unpacked; `noforward` gives a daemon of a
+where the fetched response is unpacked; `droppromote` is the tiered form's
+own: a promote that takes the cold row out of the store and drops it instead
+of merging it, so that a key keeps the fresh budget it was served from;
+`noforward` gives a daemon of a
 cluster a ring of itself alone, so that it serves every check where it
 arrives; `oneclock` is no control but a witness (bench/witness/oneclock.py).
 """
@@ -82,6 +91,19 @@ def apply_control(kind: str) -> None:
             return out
 
         backend._packed_resp_dict = altered
+    elif kind == "droppromote":
+        # The tiered form's control: the cold row is popped and dropped, so
+        # the merge never lands and every cold hit mints a fresh budget.
+        from gubernator_tpu.runtime import coldtier
+
+        def dropped(self, fps, t0):
+            n = len(self.cold.pop_rows(fps)["key_hash"])
+            with self._cv:
+                self._pending.difference_update(fps)
+            self.promotes += n
+            return n
+
+        coldtier.TierManager._promote = dropped
     elif kind == "noforward":
         # A cluster that serves every check where it arrives: this daemon's
         # ring holds itself alone, so nothing is forwarded to an owner.
@@ -115,6 +137,10 @@ def preload(service, path: str) -> dict:
     occupancy = backend.occupancy()
     t2 = time.monotonic()
     del arrays
+    tiers = {}
+    if "cold_fp" in h:
+        tiers = preload_cold(service, h, t0_ms)
+    t_cold = time.monotonic()
     # The mesh's probe loops over keys in Python: a smaller sample there.
     n = len(h["probe_fp"]) if backend.cfg.num_shards == 1 else 16384
     # The lookup holds `expire_at > now`: rows whose window has elapsed by
@@ -126,7 +152,19 @@ def preload(service, path: str) -> dict:
             t0_ms if at_stamp else backend.clock.millisecond_now(),
         ))
     t3 = time.monotonic()
+    if tiers:
+        # Which tier the arithmetic puts each sampled key in, which the
+        # daemon has it in: "table", "cold", "both" or "none" of either.
+        cold = service.tier.cold.member_hits(h["probe_fp"][:n])
+        names = np.array(["none", "table", "cold", "both"])
+        want = names[h["probe_found"][:n] + 2 * h["probe_cold"][:n]]
+        have = names[found + 2 * cold]
+        pairs, counts = np.unique(
+            np.char.add(np.char.add(want, " -> "), have), return_counts=True)
+        tiers["probe_tiers"] = dict(zip(pairs.tolist(), counts.tolist()))
+        tiers["probe_cold_differs"] = int((cold != h["probe_cold"][:n]).sum())
     return {
+        **tiers,
         "t0_ms": int(t0_ms),
         "occupancy": int(occupancy),
         "rows": int(len(h["fp"])),
@@ -135,8 +173,67 @@ def preload(service, path: str) -> dict:
         "waited_s": round(t0 - t_wait, 3),
         "rows_s": round(t1 - t0, 3),
         "install_s": round(t2 - t1, 3),
-        "probe_s": round(t3 - t2, 3),
+        "probe_s": round(t3 - t_cold, 3),
     }
+
+
+def preload_cold(service, h: dict, t0_ms: int) -> dict:
+    """The cold store's rows through the seam runtime/checkpoint.py restores
+    them by, stamped as the table's rows are."""
+    tier = getattr(service, "tier", None)
+    if tier is None:
+        raise SystemExit("the configuration is tiered and the daemon has no "
+                         "tier manager (GUBER_TIER_ENABLED)")
+    t0 = time.monotonic()
+    kept = tier.cold.restore(universe_mod.cold_arrays(h, t0_ms))
+    return {
+        "cold_rows": int(len(h["cold_fp"])),
+        "cold_restored": int(kept),
+        "cold_residents": int(tier.cold.residents()),
+        "cold_restore_s": round(time.monotonic() - t0, 3),
+    }
+
+
+def warm_tier_programs(service) -> dict:
+    """The tier's device programs, compiled before the ready report: the
+    daemon's warm-up leaves `migrate_inject` to the first promote and
+    `demote_extract` to the first tick over the high-water mark, each under
+    `backend._lock` with the served path waiting.  Neither call changes the
+    table: the promote injects one inactive lane, the demote looks for
+    victims at a clock no row is alive at.  Private names, guarded as the
+    fetch shapes' are."""
+    t0 = time.monotonic()
+    out = {"programs": 0, "skipped": ""}
+    tier = getattr(service, "tier", None)
+    if tier is None:
+        return out
+    try:
+        from gubernator_tpu.ops.state import demote_extract
+        from gubernator_tpu.runtime.backend import fetch_ravel
+
+        backend = service.backend
+        backend.occupancy_dispatch()()
+        idle = {f: np.zeros(1, dtype=np.int64)
+                for f in universe_mod.COLD_FIELDS}
+        backend.migrate_inject_dispatch(idle)()
+        grid = np.asarray(tier._protect_grid(), dtype=np.int64)
+        never = np.int64(np.iinfo(np.int64).max - 1)
+        with backend._lock:
+            backend.table, packed, rf = demote_extract(
+                backend.table, grid, never, ways=backend.cfg.ways,
+                batch=int(tier.cfg.demote_batch),
+            )
+        taken = int((fetch_ravel([packed])[0] != 0).sum())
+        fetch_ravel([rf])
+        if taken:
+            raise SystemExit(f"warming demote_extract took {taken} values "
+                             "out of the table")
+        out["programs"] = 3
+    except (AttributeError, TypeError, ImportError) as e:
+        out["skipped"] = f"{type(e).__name__}: {e}"
+        log.warning("tier-program warming skipped: %s", out["skipped"])
+    out["seconds"] = round(time.monotonic() - t0, 3)
+    return out
 
 
 def _lane_responses(service, lane: str, tiers):
@@ -282,6 +379,10 @@ async def run(args) -> None:
     report["fetch_shapes"] = await loop.run_in_executor(
         None, warm_fetch_shapes, daemon.service, json.loads(args.lanes)
     )
+    if getattr(daemon.service, "tier", None) is not None:
+        report["tier_programs"] = await loop.run_in_executor(
+            None, warm_tier_programs, daemon.service
+        )
     say(report)
 
     stop = asyncio.Event()

@@ -381,7 +381,9 @@ CELL = "peers4-10m.batch.closed"
 
 
 def test_the_cluster_cell_is_built_and_held_out():
-    assert CELL not in {w["name"] for w in spec.benchmark()["workloads"]}
+    # PR 37 built the cell and held it out; PR 39 entered it.  held_out.json
+    # still lists it and is not edited, BENCHMARK.json wins
+    # (test_peers_entered.py): whichever holds, the cell is built.
     held = spec.load_json(os.path.join(spec.BENCH, "held_out.json"))
     (w,) = [w for w in held["workloads"] if w["name"] == CELL]
     assert w["chips"] == 4 and w["reports_as"] == "mesh4-10m.batch.closed"

@@ -72,7 +72,11 @@ def test_the_cells_and_what_they_report():
     assert (cells[CELL2]["config"], cells[CELL2]["traffic"]) == (
         "mesh4-global8k", "global250.closed")
     assert "zipf99.batch.closed" not in {w["traffic"] for w in cells.values()}
-    assert sum(w["chips"] == 4 for w in BM["workloads"]) == 2
+    # Two four-chip cells at PR 28; held by membership and by the quota,
+    # not by count: later cells are additions.
+    four = {w["name"] for w in BM["workloads"] if w["chips"] == 4}
+    assert {CELL2, "mesh4-10m.batch.closed"} <= four
+    assert len(four) <= max(1, len(BM["workloads"]) // 2)
     assert len(BM["workloads"]) >= 5     # a later cell is an addition
 
     def names(group, cell):

@@ -60,8 +60,11 @@ def _series(name):
 def test_the_cell_is_entered_and_benchmark_json_wins():
     spec.check_benchmark(BM)
     cells = {w["name"]: w for w in BM["workloads"]}
-    assert len(cells) == 7 and list(cells)[-1] == CELL
-    assert sum(w["chips"] == 4 for w in cells.values()) == 3    # 3 of 7
+    # Held by membership, not by count or last place: the next cell that
+    # is appended must not fail this (PR 45).
+    assert CELL in cells and cells[CELL]["chips"] == 4
+    assert sum(w["chips"] == 4 for w in cells.values()) <= max(
+        1, len(cells) // 2)
     held = spec.load_json(os.path.join(spec.BENCH, "held_out.json"))
     (theirs,) = [w for w in held["workloads"] if w["name"] == CELL]
     assert cells[CELL] == {k: theirs[k] for k in (
@@ -69,7 +72,7 @@ def test_the_cell_is_entered_and_benchmark_json_wins():
     (cfg,) = [c for c in BM["configs"] if c["name"] == "peers4-10m"]
     assert cfg == [c for c in held["configs"]
                    if c["name"] == "peers4-10m"][0]
-    assert BM["configs"][-1] == cfg and cfg["reduced"] == []
+    assert cfg in BM["configs"] and cfg["reduced"] == []
     # With --held-out the cell is still BENCHMARK.json's: named once, its
     # lists its own and not the borrowed `reports_as` ones.
     both = spec.benchmark(held_out=True)
@@ -87,19 +90,27 @@ def test_the_cells_own_metric_lists():
     assert not [n for n in mine if n.endswith(".mesh")]
     assert all(n.endswith(".closed") for n in mine)
     assert set(HOP) <= set(mine)
-    # Every .closed metric the mesh's batch cell is on (fourteen), the two
-    # a one-chip daemon's drain also reads, and the hop's five.
+    # Every .closed metric the mesh's batch cell is on (fourteen at PR 39,
+    # more since: held by membership), the two a one-chip daemon's drain
+    # also reads, the hop's five — and beyond those only metrics of the
+    # hop's own layer (PR 43 brought two).
     on_mesh = {m["name"] for m in spec.metrics_of(
         BM, "per_layer", "mesh4-10m.batch.closed") if m["name"].endswith(
         ".closed")}
-    assert len(on_mesh) == 14
-    assert set(mine) == on_mesh | {
-        "lane_cascade_ms.closed", "lane_rounds_per_drain.closed"} | set(HOP)
+    assert on_mesh | {"lane_cascade_ms.closed",
+                      "lane_rounds_per_drain.closed"} | set(HOP) <= set(mine)
+    layer = {m["name"]: m["layer"] for m in BM["per_layer"]}
+    assert {layer[n] for n in set(mine) - on_mesh - {
+        "lane_cascade_ms.closed", "lane_rounds_per_drain.closed"}} == {
+        "peer hop"}
     for m in BM["per_layer"]:
         if m["name"] in HOP:
-            assert m["workloads"] == [CELL] and m["layer"] == "peer hop"
+            assert CELL in m["workloads"] and m["layer"] == "peer hop"
             assert m["moves"] == "decisions_per_s"
-    assert [m["name"] for m in BM["per_layer"]][-5:] == list(HOP)
+    # The five in the order they were entered in, wherever later entries
+    # were appended.
+    assert [m["name"] for m in BM["per_layer"]
+            if m["name"] in HOP] == list(HOP)
 
 
 # -- the five data files -------------------------------------------------------
