@@ -653,8 +653,11 @@ class TierConfig:
     When enabled, the TierManager demotes the coldest HBM rows once
     occupancy crosses `high_water` (fraction of slots), draining to
     `low_water` (hysteresis — the gap is the breathing room between
-    demote ticks); `demote_batch` bounds one demote_extract dispatch
-    (per shard on a mesh)."""
+    demote ticks).  A tick moves what the marks ask, in launches sized
+    from that need: `demote_batch` is the SMALLEST width a
+    demote_extract launch comes in (per shard on a mesh), the ladder's
+    wider rungs 16 and 256 times it while they stay within a 64th of
+    the table (runtime/coldtier.py `demote_ladder`)."""
 
     enabled: bool = False
     # Cold-tier row budget (host RAM; rows beyond it are dropped).
@@ -663,7 +666,8 @@ class TierConfig:
     high_water: float = 0.85
     # Occupancy fraction demotion drains down to.
     low_water: float = 0.70
-    # Rows per demote_extract dispatch (per shard on a mesh).
+    # The smallest demote_extract launch (per shard on a mesh); wider
+    # rungs are multiples of it, each a compiled shape.
     demote_batch: int = 256
     # Watermark evaluation cadence in seconds.
     interval_s: float = 1.0

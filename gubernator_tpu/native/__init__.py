@@ -149,6 +149,22 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p,
     ]
     lib.gub_gather_rounds.restype = None
+    lib.gub_cold_probe.argtypes = [
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_void_p,
+    ]
+    lib.gub_cold_probe.restype = None
+    lib.gub_cold_put.argtypes = [
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+    ]
+    lib.gub_cold_put.restype = ctypes.c_int64
+    lib.gub_cold_pop.argtypes = [
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_int64),
+    ]
+    lib.gub_cold_pop.restype = ctypes.c_int64
     lib.gub_count_reqs.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.gub_count_reqs.restype = ctypes.c_int64
     lib.gub_parse_reqs2.argtypes = [
@@ -488,6 +504,59 @@ def gather_rounds(
     (out.over_limit, out.not_persisted, out.cache_hits,
      out.lanes) = sums.tolist()
     return out
+
+
+def cold_probe(fps: np.ndarray, rows: np.ndarray, state: np.ndarray,
+               mask: int) -> np.ndarray:
+    """int64[n]: the slot of each fingerprint in the cold store's table
+    (`rows` int64[mask + 1, 10], `state` uint8[mask + 1]), -1 where it is
+    not resident — ONE native pass with the GIL released, as `cold_put`
+    and `cold_pop` are (runtime/coldtier.py keeps the numpy forms as
+    their reference).  Native only."""
+    lib = _load()
+    n = len(fps)
+    fps, fps_addr = _column(fps, np.int64, n)
+    slot = np.empty(n, dtype=np.int64)
+    lib.gub_cold_probe(
+        n, fps_addr, rows.ctypes.data, state.ctypes.data, int(mask),
+        slot.ctypes.data,
+    )
+    return slot
+
+
+def cold_put(new: np.ndarray, rows: np.ndarray, state: np.ndarray,
+             mask: int, room: int) -> Tuple[int, int, int, int]:
+    """Rows `new` (int64[n, 10]) into the cold store's table, one after
+    another — a resident key merges, another takes a free slot of its
+    chain while `room` lasts: (rows resident after the call that came
+    from the batch, merges, drops, tombstones reused).  Native only."""
+    lib = _load()
+    new = np.ascontiguousarray(new, dtype=np.int64)
+    counts = np.zeros(3, dtype=np.int64)
+    put = lib.gub_cold_put(
+        len(new), new.ctypes.data, rows.ctypes.data, state.ctypes.data,
+        int(mask), int(room), counts.ctypes.data,
+    )
+    merges, drops, reused = counts.tolist()
+    return int(put), merges, drops, reused
+
+
+def cold_pop(fps: np.ndarray, rows: np.ndarray, state: np.ndarray,
+             mask: int) -> Tuple[np.ndarray, np.ndarray, int]:
+    """The resident rows of `fps` out of the cold store's table: (the
+    rows int64[k, 10] in the order asked, which entries of `fps` they
+    answer, the tombstones left behind).  Native only."""
+    lib = _load()
+    n = len(fps)
+    fps, fps_addr = _column(fps, np.int64, n)
+    out = np.empty((n, rows.shape[1]), dtype=np.int64)
+    which = np.empty(n, dtype=np.int64)
+    tombs = ctypes.c_int64(0)
+    k = lib.gub_cold_pop(
+        n, fps_addr, rows.ctypes.data, state.ctypes.data, int(mask),
+        out.ctypes.data, which.ctypes.data, ctypes.byref(tombs),
+    )
+    return out[:k], which[:k], int(tombs.value)
 
 
 class ParsedReqs:

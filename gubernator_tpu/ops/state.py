@@ -465,21 +465,110 @@ DEMOTE_ROW_FIELDS = (
 )
 
 
+# What the cut-off stamp is estimated from, and the unit the selection
+# counts in.  DEMOTE_SAMPLE slots — whole buckets, every way of one
+# bucket in DEMOTE_SAMPLE // ways groups of buckets — are sorted a
+# launch, where `top_k` sorted the table (51 ms a pass at 2^24 rows on
+# the v5e, PERF.md section 5.12); a table no larger than the sample is
+# its own sample, and the cut-off is then exact.  The rows under the
+# cut-off are counted by blocks of DEMOTE_BLOCK slots, so the search
+# for the r-th of them walks NBK = S / DEMOTE_BLOCK sums and one block,
+# never the table.
+DEMOTE_SAMPLE = 1 << 16
+DEMOTE_BLOCK = 256
+
+
+def _demote_cutoff(score, take, phase, ways: int):
+    """The stamp under which about `take` eligible rows lie: the k-th
+    smallest of a sample of `score` (ineligible slots carry int64 max).
+    The sample is one bucket (all its ways) of every group of G
+    consecutive buckets, `phase` saying which; k = take / G plus three
+    standard deviations of that count, so that a launch finds its
+    `take` rows under the cut-off; G = 1 makes it the exact k-th."""
+    S = score.shape[0]
+    nb = S // ways
+    G = 1
+    while nb // G > max(DEMOTE_SAMPLE // ways, 1) and nb % (2 * G) == 0:
+        G *= 2
+    if G > 1:
+        sample = jax.lax.dynamic_index_in_dim(
+            score.reshape(nb // G, G, ways), phase % G, axis=1,
+            keepdims=False,
+        ).reshape(-1)
+        k0 = (take + G - 1) // G
+        k = k0 + 3 * jnp.floor(
+            jnp.sqrt(k0.astype(jnp.float32))
+        ).astype(jnp.int32) + 2
+    else:
+        sample, k = score, take
+    srt = jnp.sort(sample)
+    return srt[jnp.clip(k, 1, sample.shape[0]) - 1]
+
+
+def _block_of(cum, rank):
+    """(the block holding the `rank`-th (1-based) entry of a class,
+    entries of the class before that block), given `cum`, the running
+    count of the class by block: a binary search over the NBK sums."""
+    nbk = cum.shape[0]
+    b = jnp.clip(
+        jnp.searchsorted(cum, rank, side="left"), 0, nbk - 1
+    ).astype(jnp.int32)
+    return b, jnp.where(b > 0, cum[jnp.maximum(b - 1, 0)], 0)
+
+
+def _nth_in_block(blocks, b, cls, nth):
+    """Slot of the `nth` (1-based) entry of class `cls` inside block
+    `b` of `blocks` (int8[NBK, BLK] class codes): the block's prefix
+    counts by one [BLK, BLK] triangular product, the form the MXU takes
+    (a count is at most BLK, exact in float32)."""
+    blk = blocks.shape[1]
+    bits = (blocks[b] == cls[:, None]).astype(jnp.bfloat16)
+    tri = (
+        jnp.arange(blk)[:, None] <= jnp.arange(blk)[None, :]
+    ).astype(jnp.bfloat16)
+    prefix = jnp.dot(bits, tri, preferred_element_type=jnp.float32)
+    off = (prefix < nth[:, None].astype(jnp.float32)).sum(
+        axis=1, dtype=jnp.int32
+    )
+    return b * blk + jnp.minimum(off, blk - 1)
+
+
 def demote_extract_impl(
     table: SlotTable,
     protect: jax.Array,  # int64[M] shadow-plane fps; 0 = inactive
     now: jax.Array,
+    take=None,           # int32: rows wanted, <= batch (None: batch)
+    start=0,             # int32: the block the ties are taken from
     ways: int = 8,
     batch: int = 64,
 ):
-    """Pick the `batch` coldest (least-recently-touched) live
+    """Pick up to `take` of the coldest (least-recently-touched) live
     KIND_BUCKET residents not on the `protect` list, gather their rows,
     and CLEAR the matched slots (key=0, expire_at=0) in the same
     donated step.  Returns (new_table, packed int64[10, batch] in
     DEMOTE_ROW_FIELDS order, int64[batch] remaining_f bits); lanes past
-    the eligible population come back with key 0 and clear nothing."""
+    `take`, or past the eligible population, come back with key 0 and
+    clear nothing.
+
+    No sort of the table: ONE streaming pass picks the cut-off stamp
+    (`_demote_cutoff`), a second marks every eligible row as under it
+    or on it and counts both by block, and the `take` rows are found
+    by rank (`_block_of`, `_nth_in_block`): every row UNDER the cut-off
+    first — from block `start` on if there are more than `take` of
+    them, which only an estimated cut-off allows — then rows ON it,
+    from block `start` on, round the table.  Stamps tie by the million
+    (a preload, a restore, a burst inside one millisecond), and a tie
+    taken in slot order would empty the same buckets every tick: the
+    caller moves `start` (runtime/coldtier.py)."""
     S = table.key.shape[0]
+    blk = min(DEMOTE_BLOCK, S)
+    nbk = S // blk
     now = jnp.asarray(now, dtype=jnp.int64)
+    take = jnp.clip(
+        jnp.asarray(batch if take is None else take, dtype=jnp.int32),
+        0, batch,
+    )
+    start = jnp.asarray(start, dtype=jnp.int32) % nbk
     alive = table.key.occupied() & (table.expire_at[...] > now)
     eligible = alive & (table.kind == KIND_BUCKET)
     protected = (
@@ -488,14 +577,43 @@ def demote_extract_impl(
     ).any(axis=1)
     eligible = eligible & ~protected
     # Victim score: last-touch stamp, ineligible slots pushed past any
-    # real timestamp so top_k(-score) yields the `batch` coldest
-    # eligible rows (the bucket-local pseudo-LRU word, applied
+    # real timestamp (the bucket-local pseudo-LRU word, applied
     # table-wide).
     big = jnp.iinfo(jnp.int64).max
     score = jnp.where(eligible, table.touched[...], big)
-    neg, idx = jax.lax.top_k(-score, batch)
-    idx = idx.astype(jnp.int64)
-    sel = neg != -big
+    cutoff = _demote_cutoff(score, take, start, ways)
+    under = score < cutoff          # `big` for no eligible row at all
+    on = eligible & (score == cutoff)
+    blocks = (
+        under.astype(jnp.int8) + 2 * on.astype(jnp.int8)
+    ).reshape(nbk, blk)
+    cum_u = jnp.cumsum((blocks == 1).sum(axis=1, dtype=jnp.int32))
+    cum_o = jnp.cumsum((blocks == 2).sum(axis=1, dtype=jnp.int32))
+    n_u, n_o = cum_u[-1], cum_o[-1]
+    a = jnp.minimum(n_u, take)
+    b = jnp.minimum(n_o, take - a)
+    lane = jnp.arange(batch, dtype=jnp.int32)
+    is_u = lane < a
+    sel = lane < a + b
+    # Ranks count from block `start`, and wrap round the table once.
+    # (A count is at most S: said, for the range analysis.)
+    prev = jnp.maximum(start - 1, 0)
+    base = jnp.where(
+        start > 0,
+        jnp.clip(jnp.where(is_u, cum_u[prev], cum_o[prev]), 0, S), 0,
+    )
+    n = jnp.clip(jnp.where(is_u, n_u, n_o), 1, S)
+    rank = base + jnp.where(is_u, lane, lane - a) + 1
+    rank = jnp.clip(jnp.where(rank > n, rank - n, rank), 1, n)
+    # The block by the class's own sums, then ONE look into the block.
+    (b_u, before_u), (b_o, before_o) = (
+        _block_of(cum_u, rank), _block_of(cum_o, rank)
+    )
+    idx = _nth_in_block(
+        blocks, jnp.where(is_u, b_u, b_o),
+        jnp.where(is_u, 1, 2).astype(jnp.int8),
+        rank - jnp.where(is_u, before_u, before_o),
+    )
     src = jnp.where(sel, idx, 0)
 
     def g(arr):
@@ -516,11 +634,19 @@ def demote_extract_impl(
     rf = jnp.where(sel, table.remaining_f[src], f64bits.ZERO)
     # Clear exactly like migrate_extract: drop the fingerprint AND the
     # expiry so the slot reads empty to every probe and first-choice to
-    # every victim claim.
+    # every victim claim.  The targets are pairwise different (a rank a
+    # lane), sorted where `write_rows` would sort them.
+    sort = sorts_write_back(S, batch)
     tgt = jnp.where(sel, idx, S)
+    if sort:
+        tgt = jnp.sort(tgt)
     new_table = table._replace(
-        key=table.key.at[tgt].set(0, mode="drop"),
-        expire_at=table.expire_at.at[tgt].set(0, mode="drop"),
+        key=table.key.at[tgt].set(
+            0, mode="drop", indices_are_sorted=sort
+        ),
+        expire_at=table.expire_at.at[tgt].set(
+            0, mode="drop", indices_are_sorted=sort
+        ),
     )
     return new_table, packed, rf
 

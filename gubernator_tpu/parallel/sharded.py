@@ -210,21 +210,23 @@ def make_sharded_demote_extract(mesh, ways: int, batch: int):
     (victim choice is slice-local, exactly like bucket-local pseudo-LRU
     is bucket-local), gathers and clears them atomically.  The protect
     fingerprint grid is replicated (P()): a shadow key only matches on
-    its home shard, so protection is exact.  Output carries the leading
+    its home shard, so protection is exact.  `take` (rows a shard,
+    at most `batch`) and `start` (the block a shard takes tied stamps
+    from) are replicated too.  Output carries the leading
     [n] shard axis: packed int64[n, 10, batch] (DEMOTE_ROW_FIELDS
     order), remaining_f bits int64[n, batch]."""
     from gubernator_tpu.ops.state import demote_extract_impl
 
-    def _local(table: SlotTable, protect, now):
+    def _local(table: SlotTable, protect, take, start, now):
         t2, packed, rf = demote_extract_impl(
-            table, protect, now, ways=ways, batch=batch
+            table, protect, now, take, start, ways=ways, batch=batch
         )
         return t2, packed[None], rf[None]
 
     sharded = _shard_map(
         _local,
         mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(), P()),
+        in_specs=(P(SHARD_AXIS), P(), P(), P(), P()),
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
     )
     return jax.jit(sharded, donate_argnums=(0,))
@@ -469,11 +471,13 @@ class MeshBackend(PersistenceHost):
             self.cfg.num_shards,
         )
 
-    def warmup(self) -> None:
+    def warmup(self, tier=None) -> None:
         """Compile the sharded executables with a synthetic batch that
         BYPASSES the Store/keymap hooks and the tallies — a check() here
         would leak '__warmup__' keys into an attached store (the same
-        bypass DeviceBackend.warmup applies)."""
+        bypass DeviceBackend.warmup applies).  `tier` (the daemon's
+        TierConfig where the two-tier table is on) is DeviceBackend's:
+        the mesh compiles its demote program a width at first use."""
         reqs = [
             RateLimitReq(name="__warmup__", unique_key=f"w{s}", hits=0,
                          limit=1, duration=1)
@@ -891,13 +895,14 @@ class MeshBackend(PersistenceHost):
         return fetch
 
     def demote_extract_dispatch(self, protect_fps: np.ndarray,
-                                batch: int):
-        """Sharded demote: each shard picks its own `batch` coldest
-        unprotected rows (victim choice is slice-local, like the
-        bucket-local pseudo-LRU), so one dispatch yields n_shards*batch
-        candidates.  Fetch flattens the per-shard planes back to the
-        DeviceBackend contract: (int64[10, n*batch], float64[n*batch]).
-        """
+                                batch: int, take: Optional[int] = None,
+                                start: int = 0):
+        """Sharded demote: each shard picks its own share of `take`
+        (`batch` where None) among its coldest unprotected rows (victim
+        choice is slice-local, like the bucket-local pseudo-LRU), so
+        one dispatch yields up to n_shards*batch candidates.  Fetch
+        flattens the per-shard planes back to the DeviceBackend
+        contract: (int64[10, n*batch], float64[n*batch])."""
         if not hasattr(self, "_demote_cache"):
             self._demote_cache = {}
         fn = self._demote_cache.get(batch)
@@ -909,8 +914,14 @@ class MeshBackend(PersistenceHost):
 
         now = np.int64(self.clock.millisecond_now())
         fps = np.asarray(protect_fps, dtype=np.int64)
+        n = self.cfg.num_shards
+        share = np.int32(
+            batch if take is None else min(-(-take // n), batch)
+        )
         with self._lock:
-            self.table, packed, rf = fn(self.table, fps, now)
+            self.table, packed, rf = fn(
+                self.table, fps, share, np.int32(start), now
+            )
 
         def fetch():
             p = np.asarray(packed)  # [n, 10, batch]

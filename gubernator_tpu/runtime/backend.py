@@ -807,7 +807,6 @@ class DeviceBackend(PersistenceHost):
         .migrate_inject): one donated dispatch per chunk; returns
         (injected, skipped)."""
         from gubernator_tpu.ops.state import migrate_inject
-        from gubernator_tpu.ops.step import BucketRows
 
         B = self.cfg.batch_size
         now = np.int64(self.clock.millisecond_now())
@@ -815,29 +814,9 @@ class DeviceBackend(PersistenceHost):
         resident_devs = []
         actives = []
         with self._lock:
-            for lo in range(0, len(cols["key_hash"]), B):
-                hi = min(lo + B, n)
-                pad = B - (hi - lo)
-
-                def col(f, dt):
-                    return np.concatenate([
-                        np.asarray(cols[f][lo:hi], dtype=dt),
-                        np.zeros(pad, dtype=dt),
-                    ])
-
-                rows = BucketRows(
-                    key_hash=col("key_hash", np.int64),
-                    algo=col("algo", np.int32),
-                    limit=col("limit", np.int64),
-                    duration=col("duration", np.int64),
-                    remaining=col("remaining", np.int64),
-                    remaining_f=f64bits.to_bits(
-                        col("remaining_f", np.float64)
-                    ),
-                    t0=col("t0", np.int64),
-                    status=col("status", np.int32),
-                    burst=col("burst", np.int64),
-                    expire_at=col("expire_at", np.int64),
+            for lo in range(0, n, B):
+                rows = _bucket_rows(
+                    cols, np.arange(lo, min(lo + B, n)), B
                 )
                 self.table, resident = migrate_inject(
                     self.table, rows, now, ways=self.cfg.ways
@@ -853,11 +832,12 @@ class DeviceBackend(PersistenceHost):
             skipped += int((act & res).sum())
         return injected, skipped
 
-    def warmup(self) -> None:
+    def warmup(self, tier=None) -> None:
         """Compile the hot-path executables with a synthetic batch that
         bypasses the Store/Loader hooks and the keymap — no persistence
         side effects (a real check() would leak the synthetic key into an
-        attached store)."""
+        attached store).  `tier`: the daemon's TierConfig where the
+        two-tier table is enabled — its programs compile here too."""
         now = np.int64(self.clock.millisecond_now())
         packed = pack_requests(
             [RateLimitReq(name="__warmup__", unique_key="w", hits=0,
@@ -917,6 +897,37 @@ class DeviceBackend(PersistenceHost):
                 ways=self.cfg.ways,
             )
         jax.block_until_ready(resp)
+        if tier is not None:
+            self.warmup_tier(tier)
+
+    def warmup_tier(self, tier) -> None:
+        """The two-tier table's programs (runtime/coldtier.py), every
+        width each can launch at: the occupancy read, `migrate_inject`
+        on the step's ladder and `demote_extract` on the demoter's — a
+        daemon's first promote and its first tick over the mark then
+        compile nothing under `backend._lock` (48-51 s on a v5e from an
+        empty cache; PERF.md section 7, PR 45 (5)).  No call changes
+        the table: the inject carries inactive lanes, the demote takes
+        no row."""
+        from gubernator_tpu.ops.state import migrate_inject
+        from gubernator_tpu.runtime.coldtier import (
+            COLD_FIELDS, demote_ladder,
+        )
+
+        self.occupancy_dispatch()()
+        now = np.int64(self.clock.millisecond_now())
+        no_rows = np.zeros(0, dtype=np.int64)
+        idle = dict.fromkeys(COLD_FIELDS, no_rows)
+        with self._lock:
+            for t in self._tiers:
+                self.table, resident = migrate_inject(
+                    self.table, _bucket_rows(idle, no_rows, t), now,
+                    ways=self.cfg.ways,
+                )
+        jax.block_until_ready(resident)
+        grid = np.zeros(8, dtype=np.int64)
+        for b in demote_ladder(tier.demote_batch, self.cfg.num_slots):
+            self.demote_extract_dispatch(grid, b, take=0)()
 
     # -- persistence device hooks (PersistenceHost) ----------------------
     def _found_mask(self, keys, hashes, now: int) -> np.ndarray:
@@ -1104,21 +1115,26 @@ class DeviceBackend(PersistenceHost):
         return fetch
 
     def demote_extract_dispatch(self, protect_fps: np.ndarray,
-                                batch: int):
+                                batch: int, take: Optional[int] = None,
+                                start: int = 0):
         """ONE donated ops/state.demote_extract dispatch under the lock:
-        the device picks the `batch` coldest unprotected live bucket
-        rows, gathers their fields, and clears the slots atomically.
+        the device picks up to `take` (`batch` where None) of the
+        coldest unprotected live bucket rows — ties from block `start`
+        on — gathers their fields, and clears the slots atomically.
         Returns a zero-arg fetch closure yielding (packed int64
         [10, batch] in DEMOTE_ROW_FIELDS order, float64[batch]
         remaining_f) — dispatched and fetched on the tier manager's
-        executor thread."""
+        thread; the lock's hold is the ledger's `tier.lock`."""
         from gubernator_tpu.ops.state import demote_extract
 
         now = np.int64(self.clock.millisecond_now())
         fps = np.asarray(protect_fps, dtype=np.int64)
-        with self._lock:
+        take = np.int32(batch if take is None else min(take, batch))
+        start = np.int32(start)
+        with self._lock, self._stages.stage("tier.lock", "tier"):
             self.table, packed, rf = demote_extract(
-                self.table, fps, now, ways=self.cfg.ways, batch=batch
+                self.table, fps, now, take, start,
+                ways=self.cfg.ways, batch=batch,
             )
 
         def fetch():
@@ -1132,80 +1148,100 @@ class DeviceBackend(PersistenceHost):
     def migrate_inject_dispatch(self, cols: Dict[str, np.ndarray]):
         """Dispatch-only form of migrate_inject_rows for the tier
         promote path: the donated upsert-or-merge chunks go out under
-        the lock; the returned fetch closure resolves the (injected,
-        merged) counts off the runner thread.  Same kernel, same merge
-        algebra — only the host sync moves."""
+        the lock, each in a launch of the size it carries (the step's
+        ladder of widths, `self._tiers`); the returned fetch closure
+        resolves the (injected, merged) counts off the runner thread.
+        Same kernel, same merge algebra — only the host sync moves.
+        The ledger's lane `tier` gets the lock's hold (`tier.lock`) and,
+        on `tier.promote`, the launches, their lanes, the rows they
+        carry (`rows_injected`) and those that met a resident row
+        (`rows_merged`)."""
         from gubernator_tpu.ops.state import migrate_inject
-        from gubernator_tpu.ops.step import BucketRows
 
-        B = self.cfg.batch_size
         now = np.int64(self.clock.millisecond_now())
-        n = len(cols["key_hash"])
+        fps = np.asarray(cols["key_hash"], dtype=np.int64)
+        live = np.flatnonzero(fps)      # 0 = padding, as everywhere
+        fps = fps[live]
+        n = len(fps)
 
         # locate_slots resolves at most INSERT_ROUNDS (= 3) same-bucket
         # insert conflicts per dispatch; a 4th contender ends transient
         # and load_rows drops it — losing the row's consumed budget.
         # Spread same-bucket rows across successive dispatches so every
-        # lane can claim a slot.
+        # lane can claim a slot: a row's wave is its rank among the rows
+        # of its bucket, three a wave.
         nb = self.cfg.num_slots // self.cfg.ways
-        fps = np.asarray(cols["key_hash"], dtype=np.int64)
         bucket = fps.view(np.uint64) & np.uint64(nb - 1)
-        rank = np.zeros(n, dtype=np.int64)
-        seen: Dict[int, int] = {}
-        for i in range(n):
-            b = int(bucket[i])
-            rank[i] = seen.get(b, 0)
-            seen[b] = int(rank[i]) + 1
+        order = np.argsort(bucket, kind="stable")
+        sb = bucket[order]
+        first = np.flatnonzero(np.r_[True, sb[1:] != sb[:-1]])
+        rank = np.arange(n) - np.repeat(first, np.diff(np.r_[first, n]))
         wave = rank // 3
+        B = self._tiers[-1]
         chunks = []
         for w in range(int(wave.max()) + 1 if n else 0):
-            widx = np.flatnonzero(wave == w)
-            for lo in range(0, len(widx), B):
-                chunks.append(widx[lo:lo + B])
-
+            widx = live[order[wave == w]]
+            chunks += [widx[lo:lo + B] for lo in range(0, len(widx), B)]
+        # Built before the lock: the served path waits for the
+        # dispatches alone.
+        batches = [
+            _bucket_rows(
+                cols, sel, next(t for t in self._tiers if t >= len(sel))
+            )
+            for sel in chunks
+        ]
         resident_devs = []
-        actives = []
-        with self._lock:
-            for sel in chunks:
-                pad = B - len(sel)
-
-                def col(f, dt):
-                    return np.concatenate([
-                        np.asarray(cols[f], dtype=dt)[sel],
-                        np.zeros(pad, dtype=dt),
-                    ])
-
-                rows = BucketRows(
-                    key_hash=col("key_hash", np.int64),
-                    algo=col("algo", np.int32),
-                    limit=col("limit", np.int64),
-                    duration=col("duration", np.int64),
-                    remaining=col("remaining", np.int64),
-                    remaining_f=f64bits.to_bits(
-                        col("remaining_f", np.float64)
-                    ),
-                    t0=col("t0", np.int64),
-                    status=col("status", np.int32),
-                    burst=col("burst", np.int64),
-                    expire_at=col("expire_at", np.int64),
-                )
-                self.table, resident = migrate_inject(
-                    self.table, rows, now, ways=self.cfg.ways
-                )
-                resident_devs.append(resident)
-                actives.append(np.asarray(rows.key_hash) != 0)
+        if batches:
+            with self._lock, self._stages.stage("tier.lock", "tier"):
+                for rows in batches:
+                    self.table, resident = migrate_inject(
+                        self.table, rows, now, ways=self.cfg.ways
+                    )
+                    resident_devs.append(resident)
+            self._stages.tally(
+                "tier", "tier.promote", inject_launches=len(batches),
+                inject_lanes=sum(len(r.key_hash) for r in batches),
+                rows_injected=n,
+            )
 
         def fetch():
-            if not resident_devs:
-                return 0, 0
             injected = merged = 0
-            for res, act in zip(fetch_ravel(resident_devs), actives):
+            for res, rows in zip(fetch_ravel(resident_devs), batches):
+                act = rows.key_hash != 0
                 res = np.asarray(res)
                 injected += int((act & ~res).sum())
                 merged += int((act & res).sum())
+            if merged:
+                self._stages.tally("tier", "tier.promote",
+                                   rows_merged=merged)
             return injected, merged
 
         return fetch
+
+
+def _bucket_rows(cols: Dict[str, np.ndarray], sel: np.ndarray,
+                 width: int):
+    """Rows `sel` of COLD_FIELDS columns as one `width`-lane BucketRows
+    (ops/step.py), the lanes past them inactive."""
+    from gubernator_tpu.ops.step import BucketRows
+
+    def col(f, dt):
+        out = np.zeros(width, dtype=dt)
+        out[:len(sel)] = np.asarray(cols[f], dtype=dt)[sel]
+        return out
+
+    return BucketRows(
+        key_hash=col("key_hash", np.int64),
+        algo=col("algo", np.int32),
+        limit=col("limit", np.int64),
+        duration=col("duration", np.int64),
+        remaining=col("remaining", np.int64),
+        remaining_f=f64bits.to_bits(col("remaining_f", np.float64)),
+        t0=col("t0", np.int64),
+        status=col("status", np.int32),
+        burst=col("burst", np.int64),
+        expire_at=col("expire_at", np.int64),
+    )
 
 
 class Tally(NamedTuple):

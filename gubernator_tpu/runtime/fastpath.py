@@ -814,13 +814,15 @@ class FastPath:
             self.s.metrics.concurrent_checks.observe(
                 self.s._inflight_checks
             )
-        # Hot-key detection (docs/hotkeys.md): feed the tracker the
+        # Hot-key detection (docs/hotkeys.md) and promote-on-access
+        # (docs/tiering.md), each where it is armed: feed them the
         # parsed fingerprint/hits columns once, at the point of no
         # return — every fallback already happened, so the object path
         # can never observe the same batch again.  Zero fingerprints
-        # (errored lanes) are ignored by the tracker.
-        if self.s.hotkeys is not None:
-            self.s.note_traffic(cols.hash, cols.hits)
+        # (errored lanes) are ignored by both.
+        noted = None
+        if self.s.hotkeys is not None or self.s.tier is not None:
+            noted = self.s.note_traffic(cols.hash, cols.hits)
         try:
             if routed:
                 return await self._serve_routed(
@@ -830,6 +832,8 @@ class FastPath:
                 payload, cols, n, is_global, sk, peer_rpc, ingress
             )
         finally:
+            if noted is not None:
+                self.s.tier.note_done(noted)
             if not peer_rpc:
                 self.s._inflight_checks -= 1
 

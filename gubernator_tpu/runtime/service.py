@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import asyncio
 import fnmatch
+import functools
 import logging
 import random
 import time
@@ -337,7 +338,11 @@ class Service:
         # Warm the jitted device step so the first client request doesn't
         # pay XLA compilation (20-40s cold) inside an RPC deadline.
         loop = asyncio.get_running_loop()
-        await loop.run_in_executor(self._dev_executor, self.backend.warmup)
+        # The two-tier table's programs compile with the rest.
+        tier = self.cfg.tier if self.cfg.tier.enabled else None
+        await loop.run_in_executor(
+            self._dev_executor, functools.partial(self.backend.warmup, tier)
+        )
         if self.global_engine is not None:
             await loop.run_in_executor(
                 self._dev_executor, self.global_engine.warmup
@@ -613,11 +618,13 @@ class Service:
     # ------------------------------------------------------------------
     def note_traffic(
         self, key_hashes: np.ndarray, hits: np.ndarray
-    ) -> None:
+    ):
         """Feed the hot-key detector one batch of routed traffic.
         Called once per batch by whichever path actually serves it (the
         compiled lane's check_raw or the object path), so a fast-lane
-        fallback never observes the same requests twice."""
+        fallback never observes the same requests twice.  Returns what
+        the tier wants back through `TierManager.note_done` once the
+        batch has been served (None without a tier)."""
         hk = self.hotkeys
         if hk is not None and len(key_hashes):
             with self.metrics.stages.stage("host.hotkey", "host"):
@@ -627,7 +634,8 @@ class Service:
             # Promote-on-access (docs/tiering.md): a served key that is
             # cold-resident schedules a FIFO host-job inject; THIS
             # batch was already answered from whatever the device had.
-            tier.note_access(key_hashes, hits)
+            return tier.note_access(key_hashes, hits)
+        return None
 
     def _peer_by_fp(self, fp: int) -> Optional[PeerClient]:
         """Owning peer for a device fingerprint — xx rings only, where

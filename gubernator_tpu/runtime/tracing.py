@@ -764,6 +764,28 @@ STAGES: Dict[str, str] = {
     "global.sync_step": "inside a tick, under both locks: the enqueue of "
                         "one chunk's sync program",
     "xla.compile": "backend compiles seen by jax.monitoring (count, ms)",
+    # the two-tier table (lane `tier`; runtime/coldtier.py)
+    "tier.note_access": "TierManager.note_access, on the request path "
+                        "inside service.note_traffic: the sketch update "
+                        "and one probe of the batch in the cold store; "
+                        "counters keys, cold_hits",
+    "tier.promote": "one pass of the promote worker: everything queued "
+                    "popped from the cold store and merged into the "
+                    "table; counters rows_popped and, of every inject "
+                    "launch (the demoter's hotter tail too), "
+                    "inject_launches, inject_lanes, rows_injected (rows "
+                    "carried), rows_merged (met a resident row); its "
+                    "parts in microseconds pop_us, dispatch_us, fetch_us",
+    "tier.demote": "one watermark tick over the high mark: select and "
+                   "extract on the device, the fetch, the put into the "
+                   "cold store; counters demote_rows, demote_launches, "
+                   "demote_lanes, reinjected, renoted, cold_merges, "
+                   "ticks_late, select_us, put_us",
+    "tier.lock": "backend._lock held by one of the tier's dispatches "
+                 "(migrate_inject, demote_extract): what the served "
+                 "path waits",
+    "tier.restore": "ColdTier.restore: a checkpoint's (or a preload's) "
+                    "cold rows re-inserted; counter rows",
     # what shares the process with the served path (lane `host`)
     "host.gc": "one collection of the garbage collector, gc.callbacks "
                "start -> stop, on whichever thread collected; counter "
@@ -799,6 +821,7 @@ LEAVES = frozenset((
     "global.sync_step",
     "host.gc", "host.census_dispatch", "host.census_fetch", "host.hotkey",
     "host.scrape", "host.loop_lag",
+    "tier.note_access", "tier.lock",
 ))
 # An instance of a leaf row that took at least STALL_MIN_NS and
 # STALL_FACTOR x its row's mean so far is a stall: kept, with its times,

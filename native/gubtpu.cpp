@@ -585,6 +585,129 @@ static inline bool skip_field(const uint8_t*& p, const uint8_t* end,
   }
 }
 
+// The cold store (runtime/coldtier.py ColdTier): an open-addressed,
+// linear-probed table of mask + 1 slots.  A slot's row is GUB_COLD_W
+// int64 words in COLD_FIELDS order — key_hash, algo, limit, duration,
+// remaining, remaining_f (a binary64's bits), t0, status, burst,
+// expire_at — and `state` says what the slot holds: 0 empty (a chain
+// ends), 1 full, 2 tombstone (a chain passes through).  Fingerprint 0 is
+// padding, never resident.  The numpy forms in coldtier.py are the
+// reference; tests hold these passes to them slot for slot.
+static const int GUB_COLD_W = 10;
+
+// (slot of fp, or -1; the first tombstone on the way, or -1; where the
+// chain ended, or -1 where the table has no empty slot)
+static inline int64_t cold_find(int64_t fp, const int64_t* rows,
+                                const uint8_t* state, int64_t mask,
+                                int64_t* tomb, int64_t* end) {
+  int64_t pos = (int64_t)((uint64_t)fp & (uint64_t)mask);
+  *tomb = -1;
+  *end = -1;
+  for (int64_t step = 0; step <= mask; ++step) {
+    const uint8_t st = state[pos];
+    if (st == 0) {
+      *end = pos;
+      return -1;
+    }
+    if (st == 2) {
+      if (*tomb < 0) *tomb = pos;
+    } else if (rows[pos * GUB_COLD_W] == fp) {
+      return pos;
+    }
+    pos = (pos + 1) & mask;
+  }
+  return -1;
+}
+
+// slot[i]: where fps[i] is resident, -1 where it is not.
+void gub_cold_probe(int64_t n, const int64_t* fps, const int64_t* rows,
+                    const uint8_t* state, int64_t mask, int64_t* slot) {
+  int64_t tomb, end;
+  for (int64_t i = 0; i < n; ++i)
+    slot[i] = fps[i] == 0 ? -1
+                          : cold_find(fps[i], rows, state, mask, &tomb, &end);
+}
+
+// Rows in[n, W] into the store, one after another: a row whose key is
+// resident MERGES into the waiting row — the new row at the least
+// budget, max(r_new - consumed_old, 0), a token row's `remaining`, a
+// leaky row's `remaining_f`: ops/state.py migrate_inject_impl's algebra
+// — one whose key is not takes the first tombstone of its chain, or its
+// end, while `room` lasts, and is dropped and counted after.  Returns
+// the rows that are resident after the call and came from this batch;
+// counts[0..2]: merges, drops, tombstones reused.
+int64_t gub_cold_put(int64_t n, const int64_t* in, int64_t* rows,
+                     uint8_t* state, int64_t mask, int64_t room,
+                     int64_t* counts) {
+  int64_t put = 0, merges = 0, drops = 0, reused = 0, tomb, end;
+  for (int64_t i = 0; i < n; ++i) {
+    const int64_t* r = in + i * GUB_COLD_W;
+    if (r[0] == 0) continue;
+    int64_t at = cold_find(r[0], rows, state, mask, &tomb, &end);
+    if (at >= 0) {
+      int64_t* old = rows + at * GUB_COLD_W;
+      const bool leaky = old[1] == 1;
+      int64_t used_i = old[2] - old[4];
+      if (used_i < 0 || leaky) used_i = 0;
+      double old_f, new_f;
+      memcpy(&old_f, &old[5], 8);
+      memcpy(&new_f, &r[5], 8);
+      double used_f = (double)old[2] - old_f;
+      if (!(used_f > 0.0) || !leaky) used_f = 0.0;
+      int64_t rem = r[4] - used_i;
+      if (rem < 0) rem = 0;
+      new_f -= used_f;
+      if (!(new_f > 0.0)) new_f = 0.0;
+      memcpy(old, r, GUB_COLD_W * 8);
+      old[4] = rem;
+      memcpy(&old[5], &new_f, 8);
+      ++merges;
+      ++put;
+      continue;
+    }
+    at = tomb >= 0 ? tomb : end;
+    if (room <= 0 || at < 0) {
+      ++drops;
+      continue;
+    }
+    if (state[at] == 2) ++reused;
+    memcpy(rows + at * GUB_COLD_W, r, GUB_COLD_W * 8);
+    state[at] = 1;
+    --room;
+    ++put;
+  }
+  counts[0] = merges;
+  counts[1] = drops;
+  counts[2] = reused;
+  return put;
+}
+
+// The rows of the fingerprints that are resident out of the store, in
+// the order asked (one asked twice leaves once): out[k, W] the rows,
+// which[k] the entries of fps they answer.  A vacated slot is a
+// tombstone, or empty again where its successor is empty (no chain goes
+// on from there).  Returns k; *tombs the tombstones it left.
+int64_t gub_cold_pop(int64_t n, const int64_t* fps, const int64_t* rows,
+                     uint8_t* state, int64_t mask, int64_t* out,
+                     int64_t* which, int64_t* tombs) {
+  int64_t k = 0, left = 0, tomb, end;
+  for (int64_t i = 0; i < n; ++i) {
+    if (fps[i] == 0) continue;
+    const int64_t at = cold_find(fps[i], rows, state, mask, &tomb, &end);
+    if (at < 0) continue;
+    memcpy(out + k * GUB_COLD_W, rows + at * GUB_COLD_W, GUB_COLD_W * 8);
+    which[k++] = i;
+    if (state[(at + 1) & mask] == 0) {
+      state[at] = 0;
+    } else {
+      state[at] = 2;
+      ++left;
+    }
+  }
+  *tombs = left;
+  return k;
+}
+
 // Count the repeated field-1 submessages of a GetRateLimitsReq (or
 // GetPeerRateLimitsReq) payload.  Returns -1 on malformed input.
 int64_t gub_count_reqs(const uint8_t* buf, int64_t len) {

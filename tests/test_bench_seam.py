@@ -12,7 +12,9 @@ written out below, each with the line that uses it.
 
 One daemon of each kind per module: one chip for the `mach` and `wire`
 rows, a 4-shard mesh with GLOBAL traffic and one sync tick for the
-`engine` and `global` rows.
+`engine` and `global` rows, and one chip with the two-tier table on
+(docs/tiering.md), cold rows restored, promoted and demoted, for the
+`tier` rows and the tier's private names.
 """
 from __future__ import annotations
 
@@ -126,6 +128,10 @@ OBJECT_NAMES = [
 
 def _is_mesh_row(path: str) -> bool:
     return re.search(r"(^|\.)(engine|global)(\.|$)", path) is not None
+
+
+def _is_tier_row(path: str) -> bool:
+    return re.search(r"(^|\.)tier(\.|$)", path) is not None
 
 
 def _resolve(tree, path: str):
@@ -258,9 +264,86 @@ def mesh():
         c.stop()
 
 
+TIER_SLOTS = 1 << 12
+
+
+@pytest.fixture(scope="module")
+def tiered():
+    """One chip with the tier on: cold rows restored for the keys the
+    seam's RPCs send (so they promote), then a keyspace past the
+    table's high mark (so a tick demotes)."""
+    import numpy as np
+
+    from gubernator_tpu import native
+    from gubernator_tpu.core.config import DaemonConfig, TierConfig
+    from gubernator_tpu.core.hashing import key_hash64
+    from gubernator_tpu.testing.cluster import Cluster
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    c = Cluster.start(1, device=DeviceConfig(
+        num_slots=TIER_SLOTS, ways=8, batch_size=128,
+    ), conf_template=DaemonConfig(tier=TierConfig(
+        enabled=True, cold_capacity=8192, high_water=0.5, low_water=0.4,
+        demote_batch=64, interval_s=0.2,
+    )))
+    try:
+        d = c.daemon_at(0)
+        tier = d.service.tier
+        fps = np.array([
+            np.uint64(key_hash64(f"seam_k{i}_{j}")).view(np.int64)
+            for i in range(4) for j in range(5)
+        ], dtype=np.int64)
+        n = len(fps)
+        now = d.service.backend.clock.millisecond_now()
+        kept = tier.cold.restore({
+            "key_hash": fps, "algo": np.arange(n) % 2,
+            "limit": np.full(n, 100), "duration": np.full(n, 60_000),
+            "remaining": np.full(n, 50),
+            "remaining_f": np.full(n, 50.0), "t0": np.full(n, now),
+            "status": np.zeros(n), "burst": np.full(n, 100),
+            "expire_at": np.full(n, now + 60_000),
+        })
+        assert kept == n == tier.cold.residents()
+        seam = _Seam(c, global_keys=0)
+
+        async def fill():
+            import grpc.aio
+
+            ch = grpc.aio.insecure_channel(d.grpc_address)
+            rpc = ch.unary_unary(GET_RATE_LIMITS)
+            try:
+                for lo in range(0, 3000, 500):
+                    await rpc(pb.GetRateLimitsReq(requests=[
+                        pb.RateLimitReq(
+                            name="fill", unique_key=f"f{k}", hits=1,
+                            limit=100, duration=60_000)
+                        for k in range(lo, lo + 500)
+                    ]).SerializeToString())
+            finally:
+                await ch.close()
+
+        c.run(fill(), timeout=120)
+        deadline = time.monotonic() + 60
+        while True:
+            seam.vars = json.loads(seam._get("/debug/vars"))
+            # A whole tick over the mark (its row counts at its end).
+            if (seam.vars["stages"]["tier"]["demote"]["count"]
+                    and seam.vars["tier"]["promotes"] >= n):
+                break
+            assert time.monotonic() < deadline, tier.debug_vars()
+            time.sleep(0.05)
+        tier.close()            # the scrape stands still from here
+        seam.vars = json.loads(seam._get("/debug/vars"))
+        yield seam
+    finally:
+        c.stop()
+
+
 def _seam_for(request, path: str) -> _Seam:
     return request.getfixturevalue(
-        "mesh" if _is_mesh_row(path) else "one_chip"
+        "mesh" if _is_mesh_row(path)
+        else "tiered" if _is_tier_row(path) else "one_chip"
     )
 
 
@@ -341,9 +424,21 @@ def test_device_info_and_warmup(request, daemon):
 
 
 def test_step_packed_q_is_a_partial_of_a_jitted_function(one_chip):
-    # bench/serve.py:226-231 lowers `step.func` with `step.keywords`.
-    step = one_chip.obj("backend")._step_packed_q
-    assert callable(step.func.lower) and "ways" in step.keywords
+    # bench/serve.py `_lane_responses` CALLS it on an all-inactive batch
+    # of every tier (it lowered `step.func` once, and no longer does:
+    # the pin of the partial's inside went with that, ROADMAP C12): the
+    # table comes back as it was, a response comes with it.
+    import numpy as np
+
+    be = one_chip.obj("backend")
+    before = be.occupancy()
+    now = np.int64(be.clock.millisecond_now())
+    with be._lock:
+        for t in be._tiers:
+            be.table, resp = be._step_packed_q(
+                be.table, np.zeros((12, t), dtype=np.int64), now)
+            assert resp is not None
+    assert be.occupancy() == before
 
 
 @pytest.mark.parametrize(
@@ -444,6 +539,202 @@ def test_peer_hop_metric_reads_a_live_routed_daemon(routed, name):
             assert len(nodes) == 1 and _is_number(nodes[0]), (term, nodes)
             total[side] += nodes[0]
     assert total["num"] > 0 and total["den"] > 0, (name, total)
+
+
+# -- (d') the two-tier table: what bench/ reads of it ---------------------
+
+# bench/run.py:353-412 `tiered_counts`: the `tier` block's keys.
+TIER_BLOCK = [
+    "demotes", "promotes", "cold_hits", "capacity_drops",
+    "promote_failures", "promote_retries", "demote_passes", "ticks",
+    "cold_residents", "cold_capacity",
+]
+TIER_LATENCY = ["buckets", "cumulative", "sum_s", "p99_s"]
+
+
+@pytest.mark.parametrize("key", TIER_BLOCK)
+def test_tier_block_key_is_a_number(tiered, key):
+    assert _is_number(tiered.vars["tier"][key]), key
+
+
+def test_tier_block_holds_the_promote_latency_histogram(tiered):
+    lat = tiered.vars["tier"]["promote_latency"]
+    assert set(TIER_LATENCY) <= set(lat)
+    assert len(lat["cumulative"]) == len(lat["buckets"]) + 1
+    assert lat["cumulative"][-1] == tiered.vars["tier"]["promotes"] > 0
+    assert _is_number(lat["sum_s"]) and _is_number(lat["p99_s"])
+
+
+def test_the_ledger_shows_lane_tier_with_its_stages_and_counters(tiered):
+    from gubernator_tpu.runtime.coldtier import TIER_COUNTERS, TIER_STAGES
+
+    lane = tiered.vars["stages"]["tier"]
+    assert set(lane) == {s.split(".", 1)[1] for s in TIER_STAGES}
+    for stage, counters in TIER_COUNTERS.items():
+        row = lane[stage.split(".", 1)[1]]
+        assert set(counters) <= set(row), (stage, row)
+    # What the seam's daemon did, as the ledger counted it.
+    assert lane["restore"]["rows"] == 20 and lane["restore"]["count"] == 1
+    assert lane["note_access"]["cold_hits"] >= 20
+    assert lane["promote"]["rows_popped"] >= 20
+    assert lane["promote"]["rows_injected"] >= 20
+    assert 0 < lane["promote"]["inject_launches"]
+    assert lane["promote"]["inject_lanes"] \
+        >= 128 * lane["promote"]["inject_launches"]
+    assert lane["demote"]["demote_rows"] == tiered.vars["tier"]["demotes"]
+    assert lane["demote"]["demote_launches"] \
+        == tiered.vars["tier"]["demote_passes"] > 0
+    assert lane["lock"]["count"] >= lane["promote"]["count"] > 0
+
+
+TIER_NAMES = [
+    # (object spelled from service.tier, attribute, kind); bench/serve.py
+    ("cold", "restore", "callable"),              # :183 preload_cold
+    ("cold", "residents", "callable"),            # :187
+    ("cold", "member_hits", "callable"),          # :157 the probe's tiers
+    ("cold", "pop_rows", "callable"),             # :100 droppromote
+    ("", "_promote", "callable"),                 # :106
+    ("", "_pending", "attr"),                     # :102
+    ("", "_cv", "attr"),                          # :101
+    ("", "promotes", "attr"),                     # :103
+    ("", "_protect_grid", "callable"),            # :219
+    ("cfg", "demote_batch", "attr"),              # :225
+]
+
+
+@pytest.mark.parametrize("obj,name,kind", TIER_NAMES,
+                         ids=[f"{o or 'tier'}.{n}" for o, n, _ in TIER_NAMES])
+def test_tier_name_bench_serve_uses(tiered, obj, name, kind):
+    target = tiered.obj("tier")
+    if obj:
+        target = getattr(target, obj)
+    assert hasattr(target, name), f"tier.{obj}.{name} is gone"
+    if kind == "callable":
+        assert callable(getattr(target, name))
+
+
+def test_promote_is_what_the_droppromote_control_replaces(tiered):
+    """bench/serve.py:99-106 swaps `TierManager._promote(self, fps, t0)`
+    for one that pops and drops: the signature, what it may hand
+    `pop_rows`, `_pending.difference_update` under `_cv`."""
+    import inspect
+
+    import numpy as np
+
+    from gubernator_tpu.runtime.coldtier import TierManager
+
+    assert list(inspect.signature(TierManager._promote).parameters) \
+        == ["self", "fps", "t0"]
+    tier = tiered.obj("tier")
+    fps = np.array([123456789], dtype=np.int64)
+    assert len(tier.cold.pop_rows(fps)["key_hash"]) == 0
+    with tier._cv:
+        tier._pending.difference_update(fps)
+
+
+def test_the_tier_programs_warm_as_bench_serve_warms_them(tiered):
+    """bench/serve.py:197-236 `warm_tier_programs`, call for call: no
+    row leaves the table, the demote kernel takes (table, protect, now,
+    ways=, batch=) and gives back three."""
+    import inspect
+
+    import numpy as np
+
+    from gubernator_tpu.ops.state import demote_extract, demote_extract_impl
+    from gubernator_tpu.runtime.backend import fetch_ravel
+
+    params = inspect.signature(demote_extract_impl).parameters
+    assert list(params)[:3] == ["table", "protect", "now"]
+    assert {"ways", "batch"} <= set(params)
+    service = tiered.daemon.service
+    backend, tier = service.backend, service.tier
+    before = backend.occupancy_dispatch()()
+    idle = {f: np.zeros(1, dtype=np.int64) for f in (
+        "key_hash", "algo", "limit", "duration", "remaining",
+        "remaining_f", "t0", "status", "burst", "expire_at")}
+    assert backend.migrate_inject_dispatch(idle)() == (0, 0)
+    grid = np.asarray(tier._protect_grid(), dtype=np.int64)
+    never = np.int64(np.iinfo(np.int64).max - 1)
+    with backend._lock:
+        backend.table, packed, rf = demote_extract(
+            backend.table, grid, never, ways=backend.cfg.ways,
+            batch=int(tier.cfg.demote_batch),
+        )
+    assert int((fetch_ravel([packed])[0] != 0).sum()) == 0
+    assert len(fetch_ravel([rf])[0]) == tier.cfg.demote_batch
+    assert backend.occupancy_dispatch()() <= before   # a tick may run
+
+
+@pytest.mark.parametrize("name,attr", [
+    ("tier_inject_device_ms.closed", "migrate_inject"),
+    ("tier_inject_roofline_share.closed", "migrate_inject"),
+])
+def test_tier_program_name_matches_the_traced_metrics_regex(name, attr):
+    from gubernator_tpu.ops import state
+
+    read = json.loads(
+        (BENCH / "layer_metrics" / f"{name}.json").read_text())["read"]
+    fn = getattr(state, attr)
+    # XLA names a jitted program "jit_" + the function's name.
+    assert re.match(read["program_regex"], "jit_" + fn.__wrapped__.__name__)
+
+
+@pytest.mark.parametrize("name", [
+    "tier_demote_tick_ms.closed", "tier_demote_rows_per_tick.closed",
+])
+def test_a_demote_metric_reads_the_daemons_life_not_the_window(name):
+    """The table crosses its high mark once in about 3 s, and a traced
+    run's per-layer window ends 3 s early: a metric of the demoter that
+    took the window's difference would be missing from some result lines,
+    which the driver refuses.  (So is one that reads the demote program
+    from the profiler's 2 s span: there is none.)"""
+    read = json.loads(
+        (BENCH / "layer_metrics" / f"{name}.json").read_text())["read"]
+    assert read["kind"] == "ratio" and not read.get("delta"), read
+    traced = [
+        f.stem for f in (BENCH / "layer_metrics").glob("tier_*.json")
+        if "demote" in json.loads(f.read_text())["read"].get(
+            "program_regex", "")
+    ]
+    assert traced == [], traced
+
+
+TIER_METRICS = sorted(
+    f.stem for f in (BENCH / "layer_metrics").glob("tier_*.json"))
+
+
+def test_the_tier_metrics_were_found():
+    assert len(TIER_METRICS) == 10, TIER_METRICS
+
+
+@pytest.mark.parametrize("name", TIER_METRICS)
+def test_tier_metric_terms_read_a_live_tiered_daemon(tiered, name):
+    """Every `vars:` term of a tier_* data file, wherever in its `read`
+    block, is a number on a daemon whose tier has promoted and demoted;
+    a ratio's denominator is above 0 there."""
+    read = json.loads(
+        (BENCH / "layer_metrics" / f"{name}.json").read_text())["read"]
+    terms = []
+
+    def walk(node):
+        if isinstance(node, str) and node.startswith("vars:"):
+            terms.append(node)
+        elif isinstance(node, dict):
+            for v in node.values():
+                walk(v)
+        elif isinstance(node, list):
+            for v in node:
+                walk(v)
+
+    walk(read)
+    if read["kind"] == "ratio":
+        assert terms
+    for term in terms:
+        assert "vars:stages." not in term        # spelled `*`
+        nodes = _resolve(tiered.vars, term[len("vars:"):])
+        assert nodes and all(_is_number(n) for n in nodes), (term, nodes)
+    for term in read.get("den", []) + read.get("launches", []):
+        assert sum(_resolve(tiered.vars, term[len("vars:"):])) > 0, term
 
 
 # -- (e) why a stage took that long (PR 41) -----------------------------------

@@ -265,10 +265,13 @@ def test_coldtier_put_pop_membership_overwrite():
     assert hits.tolist() == [True, False, True, False]
     # fp 0 is the empty sentinel: never stored, never a member.
     assert ct.put_rows(_mkrows(np.array([0], dtype=np.int64))) == 0
-    # Overwrite wins (a re-demotion replaces the stale row).
-    ct.put_rows(_mkrows(fps[:5], remaining=3))
+    # A re-demotion MERGES into the row still waiting (the fresher
+    # row at the least budget, never an overwrite: that would mint the
+    # waiting row's consumed budget — PERF.md section 7, PR 45 (4)).
+    ct.put_rows(_mkrows(fps[:5], remaining=LIMIT - 2))
+    assert ct.cold_merges == 5
     got = ct.pop_rows(fps[:5])
-    assert (got["remaining"] == 3).all()
+    assert (got["remaining"] == 7 - 2).all()
     assert ct.residents() == 45
     # Absent fps simply don't appear.
     got = ct.pop_rows(np.array([1, 6, 7], dtype=np.int64))
@@ -726,3 +729,272 @@ def test_tier_debug_vars_and_latency_histogram(frozen_clock):
     )
     assert 0 < p99 <= 0.01
     assert clock_mod is not None  # keep the import honest
+
+
+# ---------------------------------------------------------------------
+# ISSUE 46: the rebuilt tier — merge on demote, coalesced promotes, the
+# threshold demoter, the warm-up
+# ---------------------------------------------------------------------
+
+SMALL = DeviceConfig(num_slots=65536, ways=8, batch_size=256)
+
+
+def _demote_all(be, tm, n):
+    """Every live row out of the table and into the cold store, as a
+    tick does it (extract, then `put_rows`)."""
+    packed, rf = be.demote_extract_dispatch(_no_protect(), 1024)()
+    idx = np.flatnonzero(packed[0] != 0)
+    assert len(idx) == n
+    assert tm.cold.put_rows(
+        TierManager._cols_from_packed(packed, rf, idx)) == n
+
+
+@pytest.mark.parametrize("algorithm", [
+    Algorithm.TOKEN_BUCKET, Algorithm.LEAKY_BUCKET,
+])
+def test_a_fresh_row_demoted_while_its_cold_row_waits_mints_nothing(
+    frozen_clock, algorithm
+):
+    """PERF.md section 7, PR 45 (4): a key's fresh row is demoted while
+    its cold row still waits for its merge.  The parent's `put_rows`
+    overwrote the waiting row and minted its budget; the store now
+    merges, so every answer is core/pymodel.py's from a state
+    docs/tiering.md allows — here the merged one, which is the bucket
+    continued: hits consumed in any tier stay consumed."""
+    from gubernator_tpu.core.pymodel import PyRateLimiter
+
+    n, c1, c2, c3 = 300, 3, 4, 2
+    be = DeviceBackend(SMALL, clock=frozen_clock)
+    tm = TierManager(_StubService(be), TierConfig(
+        enabled=True, cold_capacity=4096, high_water=0.6, low_water=0.4,
+        demote_batch=256, interval_s=1.0))
+    py = PyRateLimiter(clock=frozen_clock)
+
+    def reqs(hits):
+        return [_req(f"k{i}", hits=hits, algorithm=algorithm)
+                for i in range(n)]
+
+    fps = _fps_of(be, reqs(1))
+    for r in reqs(c1):
+        py.get_rate_limit(r)
+    be.check(reqs(c1))
+    _demote_all(be, tm, n)                      # cold: LIMIT - c1
+    # Touched while cold: fresh rows, the promotes queued, NOT run.
+    tm.note_access(fps, np.full(n, c2))
+    got = be.check(reqs(c2))
+    assert all(r.remaining == LIMIT - c2 for r in got)
+    for r in reqs(c2):
+        py.get_rate_limit(r)
+    # The fresh rows are demoted before their promote lands: each meets
+    # its waiting cold row, and the row kept carries the least budget.
+    _demote_all(be, tm, n)
+    assert tm.cold.cold_merges == n and tm.cold.residents() == n
+    assert tm.drain_promotes_sync() == n
+    assert tm.cold.residents() == 0 and be.occupancy() == n
+    # Now the answers are the reference's, continued: nothing minted.
+    got = be.check(reqs(c3))
+    for r, g in zip(reqs(c3), got):
+        want = py.get_rate_limit(r)
+        assert (g.status, g.remaining) == (want.status, want.remaining)
+    assert got[0].remaining == LIMIT - c1 - c2 - c3
+
+
+def test_coalesced_promotes_keep_the_cycle_bound(frozen_clock):
+    """The demote -> touch -> promote race with the promotes of many
+    RPCs coalesced into ONE pass: per key `allowed <= limit x (1 +
+    cycles)`, every queued entry's rows observed from their own t0,
+    launches of the size they carry, and more than three rows of one
+    bucket in a pass (INSERT_ROUNDS a dispatch still holds)."""
+    limit, cycles, n = 10, 3, 40
+    be = DeviceBackend(
+        DeviceConfig(num_slots=64, ways=8, batch_size=128),
+        clock=frozen_clock,
+    )                                           # 8 buckets: 5 keys each
+    tm = TierManager(_StubService(be), TierConfig(
+        enabled=True, cold_capacity=4096, high_water=0.9, low_water=0.4,
+        demote_batch=64, interval_s=1.0))
+
+    def reqs(hits):
+        return [_req(f"k{i}", hits=hits, limit=limit) for i in range(n)]
+
+    fps = _fps_of(be, reqs(1))
+    allowed = np.zeros(n, dtype=np.int64)
+
+    def ledger():       # the process's, shared by tests: read by difference
+        return dict(tm._stages.debug_vars()["tier"]["promote"])
+
+    row0 = ledger()
+
+    def serve(hits):
+        for j, r in enumerate(be.check(reqs(hits))):
+            if r.status == Status.UNDER_LIMIT:
+                allowed[j] += hits
+
+    serve(4)
+    most_waves = 0
+    for cycle in range(cycles):
+        resident = int(be.occupancy())
+        packed, rf = be.demote_extract_dispatch(_no_protect(), 64)()
+        idx = np.flatnonzero(packed[0] != 0)
+        assert len(idx) == resident
+        tm.cold.put_rows(TierManager._cols_from_packed(packed, rf, idx))
+        # Four RPCs' worth of cold hits, queued one entry each ...
+        for part in np.array_split(np.arange(n), 4):
+            tm.note_access(fps[part], np.full(len(part), 4))
+            frozen_clock.advance(1)
+        assert len(tm._q) == 4
+        serve(4)                                # ... served fresh ...
+        before = int(np.sum(tm._hist))
+        launches = ledger()["inject_launches"]
+        cold = fps[tm.cold.member_hits(fps)]
+        crowd = np.bincount(
+            (cold.view(np.uint64) & np.uint64(7)).astype(np.int64))
+        waves = -(-int(crowd.max()) // 3)
+        most_waves = max(most_waves, waves)
+        promoted = tm.drain_promotes_sync()     # ... merged in one pass
+        assert promoted == resident and not tm._q and not tm._pending
+        assert int(np.sum(tm._hist)) - before == promoted
+        # Three rows of a bucket a launch, each launch of 128 lanes.
+        assert ledger()["inject_launches"] - launches == waves
+        serve(4)
+    assert most_waves >= 2          # some bucket held more than three
+    assert (allowed <= limit * (1 + cycles)).all(), allowed
+    assert tm.promote_failures == 0 and tm.cold.capacity_drops == 0
+    row = {k: v - row0[k] for k, v in ledger().items()}
+    assert row["inject_lanes"] == 128 * row["inject_launches"]
+    assert row["rows_injected"] == row["rows_popped"] == tm.promotes
+
+
+def _random_table(rng, S, ways, now, stamps, tie=False):
+    """A table of `S` slots as host arrays: live bucket rows with random
+    last-touch stamps, and among them cached responses, expired rows
+    and empty slots — none of which may ever be demoted."""
+    from gubernator_tpu.ops.state import KIND_CACHED_RESP
+
+    key = rng.integers(1, 1 << 62, S)
+    key[rng.random(S) < 0.2] = 0                      # empty
+    kind = np.where(rng.random(S) < 0.1, KIND_CACHED_RESP, 0)
+    expire = np.where(rng.random(S) < 0.1, now - 5, now + 60_000)
+    touched = (np.full(S, stamps[0]) if tie
+               else rng.integers(stamps[0], stamps[1], S))
+    z = np.zeros(S, dtype=np.int64)
+    return {
+        "key": key.astype(np.int64), "algo": z.astype(np.int32),
+        "kind": kind.astype(np.int32), "limit": z + LIMIT,
+        "duration": z + DURATION, "remaining": z + 7,
+        "remaining_f": z.astype(np.float64), "t0": z + 5,
+        "status": z.astype(np.int32), "burst": z + LIMIT,
+        "expire_at": expire.astype(np.int64),
+        "touched": touched.astype(np.int64),
+    }
+
+
+@pytest.mark.parametrize("take,start,batch,sample,tie", [
+    (37, 0, 64, None, False),       # the exact cut-off (table = sample)
+    (64, 5, 64, None, False),       # a full launch, from another block
+    (500, 3, 512, None, False),     # more than a block's worth
+    (4000, 0, 4096, None, False),   # more than are eligible: all leave
+    (200, 9, 256, 512, False),      # a SAMPLED cut-off (1 bucket in 8)
+    (300, 7, 512, None, True),      # every stamp ties: taken from `start`
+])
+def test_the_threshold_demoter_against_a_plain_argsort(
+    monkeypatch, take, start, batch, sample, tie
+):
+    """`demote_extract` without its sort: the rows that leave are the
+    ones a plain `argsort` of `touched` over the eligible rows would
+    give, ties aside; protected fingerprints, cached responses, expired
+    and empty slots never leave; the slots are cleared in the same
+    dispatch and every field rides along."""
+    from gubernator_tpu.ops import state as st
+
+    if sample:
+        monkeypatch.setattr(st, "DEMOTE_SAMPLE", sample)
+        st.demote_extract.clear_cache() if hasattr(
+            st.demote_extract, "clear_cache") else None
+    rng = np.random.default_rng(take * 31 + start)
+    S, ways, now = 4096, 8, 1_000_000
+    host = _random_table(rng, S, ways, now, (now - 50_000, now), tie)
+    live = (host["key"] != 0) & (host["expire_at"] > now)
+    bucket_rows = np.flatnonzero(live & (host["kind"] == 0))
+    protect = np.zeros(8, dtype=np.int64)
+    protect[:5] = host["key"][rng.choice(bucket_rows, 5, replace=False)]
+    eligible = np.flatnonzero(
+        live & (host["kind"] == 0) & ~np.isin(host["key"], protect))
+    table, packed, rf = st.demote_extract(
+        st.table_from_host(host), protect, np.int64(now),
+        np.int32(take), np.int32(start), ways=ways, batch=batch,
+    )
+    packed = np.asarray(packed)
+    out = packed[0][packed[0] != 0]
+    want_n = min(take, len(eligible))
+    assert len(out) == want_n == len(set(out.tolist()))
+    # Never: protected, cached, expired, empty.
+    assert set(out.tolist()) <= set(host["key"][eligible].tolist())
+    # The coldest: no row that stays is colder than one that left.
+    stamp = dict(zip(host["key"].tolist(), host["touched"].tolist()))
+    left = np.array([stamp[k] for k in out.tolist()])
+    stay = np.array(sorted(
+        stamp[k] for k in set(host["key"][eligible].tolist())
+        - set(out.tolist())))
+    order = np.sort(host["touched"][eligible])
+    if not sample:
+        # Ties aside, the very rows an argsort names.
+        assert sorted(left.tolist()) == order[:want_n].tolist()
+        assert not len(stay) or left.max() <= stay.min()
+    else:
+        # An estimated cut-off: all under it, within its margin of the
+        # exact one (three standard deviations of the sample's count).
+        assert left.max() <= order[min(len(order) - 1, 2 * take)]
+    # Cleared in the same dispatch, and nothing else was.
+    after = st.table_to_host(table)
+    gone = np.isin(host["key"], out)
+    assert (after["key"][gone] == 0).all()
+    assert (after["expire_at"][gone] == 0).all()
+    assert (after["key"][~gone] == host["key"][~gone]).all()
+    # The fields ride along (DEMOTE_ROW_FIELDS order).
+    sel = packed[0] != 0
+    assert (packed[5][sel] == 7).all() and (packed[3][sel] == LIMIT).all()
+    if tie:
+        # A tie is taken from block `start` on, round the table.
+        blk = min(st.DEMOTE_BLOCK, S)
+        slot_of = {int(k): i for i, k in enumerate(host["key"]) if k}
+        blocks = np.array([slot_of[int(k)] // blk for k in out])
+        turned = (blocks - start) % (S // blk)
+        assert (np.diff(turned) >= 0).all() and turned[0] == 0
+
+
+def test_warmup_with_the_tier_on_leaves_nothing_to_compile(frozen_clock):
+    """A daemon that is not the benchmark's: after `warmup(tier=...)` a
+    first promote and a first tick over the mark compile nothing (48-51
+    s under `backend._lock` on a v5e before; PERF.md section 7, PR 45
+    (5))."""
+    from gubernator_tpu.runtime import tracing
+
+    tcfg = TierConfig(enabled=True, cold_capacity=4096, high_water=0.5,
+                      low_water=0.3, demote_batch=16, interval_s=1.0)
+    be = DeviceBackend(
+        DeviceConfig(num_slots=16384, ways=8, batch_size=256),
+        clock=frozen_clock,
+    )
+    tm = TierManager(_StubService(be), tcfg)
+    be.warmup(tier=tcfg)
+    # The served path's own shapes, before the count starts.
+    reqs = [_req(f"w{i}") for i in range(9000)]
+    for lo in range(0, len(reqs), 250):
+        be.check(reqs[lo:lo + 250])
+    fps = _fps_of(be, reqs)
+    assert be.occupancy() > 0.5 * 16384
+    before = tracing._COMPILES.count
+    # The first tick over the mark: launches of the rung its need asks.
+    assert tm._ladder == (16, 256)
+    demoted = tm.demote_once_sync()
+    assert demoted >= 0.2 * 16384 - 256 and tm.demote_passes > 1
+    # The first promotes: a handful (the 128 rung), then hundreds.
+    cold = fps[tm.cold.member_hits(fps)]
+    assert demoted - 1 <= len(cold) <= demoted  # but the warm-up's own row
+    tm.note_access(cold[:5], None)
+    assert tm.drain_promotes_sync() == 5
+    tm.note_access(cold[5:], None)
+    assert tm.drain_promotes_sync() == len(cold) - 5
+    assert tracing._COMPILES.count == before, (
+        "the tier compiled on its first use after warm-up")

@@ -665,7 +665,7 @@ def specs() -> List[KernelSpec]:
         ),
         _mesh_spec(
             "sharded_demote_extract", demote_factory,
-            lambda: (np.zeros(8, np.int64),),
+            lambda: (np.zeros(8, np.int64), np.int32(MESH_B), np.int32(0)),
             _TABLE_COUNTERS + ("[1]", "[2]"), {}, donated=21,
         ),
         _mesh_spec(
