@@ -32,6 +32,9 @@ _NATIVE_DIR = os.path.join(
 _SRC_PATH = os.path.join(_NATIVE_DIR, "gubtpu.cpp")
 _STAMP_RE = re.compile(rb"GUBSRCHASH:([0-9a-f]{64})")
 _lib: Optional[ctypes.CDLL] = None
+# The same library bound a second time, its calls made with the GIL HELD
+# (ctypes.PyDLL): for a pass shorter than taking the GIL back (HotkeyPass).
+_held: Optional[ctypes.PyDLL] = None
 _tried = False
 _load_error = ""
 _rebuilt = False
@@ -82,7 +85,7 @@ def _build() -> None:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried, _load_error, _rebuilt
+    global _lib, _held, _tried, _load_error, _rebuilt
     if _lib is not None or _tried:
         return _lib
     with _load_lock:
@@ -94,6 +97,7 @@ def _load() -> Optional[ctypes.CDLL]:
             if want is not None and _stamped_hash() != want:
                 _build()
                 _rebuilt = True
+            _held = _sign_hotkey(ctypes.PyDLL(_SO_PATH))
             _lib = _bind(ctypes.CDLL(_SO_PATH))
         except (RuntimeError, OSError, AttributeError) as e:
             _load_error = f"{type(e).__name__}: {e}"
@@ -165,6 +169,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.POINTER(ctypes.c_int64),
     ]
     lib.gub_cold_pop.restype = ctypes.c_int64
+    _sign_hotkey(lib)
     lib.gub_count_reqs.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.gub_count_reqs.restype = ctypes.c_int64
     lib.gub_parse_reqs2.argtypes = [
@@ -228,6 +233,16 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_int64,
     ]
     lib.gub_serialize_reqs.restype = ctypes.c_int64
+    return lib
+
+
+def _sign_hotkey(lib):
+    lib.gub_hotkey_observe.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_double, ctypes.c_int64, ctypes.c_void_p,
+    ]
+    lib.gub_hotkey_observe.restype = ctypes.c_int64
     return lib
 
 
@@ -557,6 +572,79 @@ def cold_pop(fps: np.ndarray, rows: np.ndarray, state: np.ndarray,
         out.ctypes.data, which.ctypes.data, ctypes.byref(tombs),
     )
     return out[:k], which[:k], int(tombs.value)
+
+
+# HotkeyPass keeps the GIL for a batch of at most this many fingerprints
+# and releases it for a longer one.  The pass itself is microseconds at
+# either size; what differs is who waits.  An RPC of 2 or 16 checks that
+# gives the GIL away takes it back behind whichever pool thread woke
+# meanwhile, and pays twice the pass's whole cost for it (0.030 against
+# 0.059-0.075 ms a call on the chip's host).  An RPC of 750 checks holds
+# the loop's GIL for a millisecond of Python either side of the pass, and
+# the release inside it is the lanes' pool threads' turn: held, the batch
+# cell answered 5-9 % FEWER checks than with the forty numpy calls,
+# released 2-6 % more (PERF.md section 5.14).  128 is the step's
+# smallest compiled width: a batch one small launch holds is a small one.
+HOTKEY_HOLD_GIL_UP_TO = 128
+
+
+class HotkeyPass:
+    """gub_hotkey_observe bound to one sketch (`runtime/hotkey.py`
+    HotKeyTracker.observe; `HostCMS.update` then `estimate` are its
+    reference): `table` int64[depth, width], updated in place, `mults`
+    uint64[depth] and `shift` the sketch's.  The addresses are taken once
+    — on the event loop an RPC of two checks pays for every attribute
+    read — so the sketch must keep `table` where it is (`HostCMS.clear`
+    zeroes it in place).  One caller at a time (the tracker's lock): the
+    candidates' buffer is reused.  The same symbol through two bindings,
+    chosen by the batch's length (`HOTKEY_HOLD_GIL_UP_TO`).  Native
+    only."""
+
+    __slots__ = ("_held", "_freed", "_head", "_keep", "_out", "_out_addr")
+
+    def __init__(self, table: np.ndarray, mults: np.ndarray,
+                 shift: int) -> None:
+        if _load() is None:
+            raise RuntimeError("native library unavailable")
+        depth, width = table.shape
+        if (table.dtype != np.int64 or not table.flags.c_contiguous
+                or mults.dtype != np.uint64 or mults.shape != (depth,)):
+            raise TypeError("native hotkey pass: int64[depth, width] and "
+                            "uint64[depth]")
+        self._held = _held.gub_hotkey_observe
+        self._freed = _lib.gub_hotkey_observe
+        self._keep = (table, mults)
+        self._head = (table.ctypes.data, depth, width, mults.ctypes.data,
+                      int(shift))
+        self._grow(1024)
+
+    def _grow(self, n: int) -> None:
+        self._out = np.empty(n, dtype=np.int64)
+        self._out_addr = self._out.ctypes.data
+
+    def __call__(self, hashes: np.ndarray, hits: np.ndarray, floor: float,
+                 want: bool) -> Tuple[bool, Sequence[int]]:
+        """One served batch: max(hits, 1) added at every non-zero
+        fingerprint in each row, then — where `want` — every fingerprint
+        whose estimate AFTER the whole batch's adds reaches `floor`, in
+        batch order, repeats included.  Returns (whether any fingerprint
+        was not zero, those candidates)."""
+        n = len(hashes)
+        hashes = np.ascontiguousarray(hashes, dtype=np.int64)
+        hits = np.ascontiguousarray(hits, dtype=np.int64)
+        if hits.shape != (n,) or hashes.ndim != 1:
+            raise ValueError("native hotkey pass: two columns of one length")
+        room = 0
+        if want:
+            if n > len(self._out):
+                self._grow(n)
+            room = n
+        fn = self._held if n <= HOTKEY_HOLD_GIL_UP_TO else self._freed
+        k = fn(
+            *self._head, n, hashes.ctypes.data, hits.ctypes.data, floor,
+            room, self._out_addr,
+        )
+        return k >= 0, (self._out[:k].tolist() if k > 0 else ())
 
 
 class ParsedReqs:

@@ -41,6 +41,8 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from gubernator_tpu import native
+
 # A mirror check serves `<unique_key>` + this suffix from its own local
 # slot, so mirror admission state never collides with the real key's
 # rows (the SHADOW_SUFFIX convention, runtime/service.py).
@@ -78,6 +80,14 @@ class HotKeyTracker:
         self._time = time_fn
         self._lock = threading.Lock()
         self._cms = HostCMS(depth=depth, width=width)
+        # The sketch's per-batch update as one native pass; None where
+        # the library did not load (observe then runs the numpy form).
+        self._native_pass = (
+            native.HotkeyPass(
+                self._cms.table, self._cms.mults, self._cms.shift
+            )
+            if native.available() else None
+        )
         self._win_start: Optional[float] = None
         self._window_idx = 0
         # Candidate fingerprints whose CMS estimate crossed the
@@ -118,35 +128,56 @@ class HotKeyTracker:
     # -- producers -------------------------------------------------------
     def observe(
         self, key_hashes: np.ndarray, hits: np.ndarray
-    ) -> None:
+    ) -> bool:
         """One routed batch: int64 fingerprints + per-request hits.
         Zero fingerprints (the parser's error sentinel) are ignored;
         each request weighs max(hits, 1) — a read still costs the owner
-        a served request.  Rolls the window when its boundary passed."""
+        a served request.  Rolls the window when its boundary passed.
+        The sketch's update is ONE native pass over the batch
+        (`native.HotkeyPass`); `_sketch_numpy` states the same in numpy,
+        is what the tests hold the pass to bit for bit, and serves where
+        the library did not load.  True: the native pass took the batch."""
         if not self.cfg.enabled or not len(key_hashes):
-            return
+            return False
         now = self._time()
         events = None
+        native_pass = self._native_pass
         with self._lock:
             self._roll_locked(now)
-            valid = key_hashes != 0
-            kh = key_hashes[valid] if not valid.all() else key_hashes
-            if not len(kh):
-                return
-            w = np.maximum(
-                hits[valid] if not valid.all() else hits, 1
+            cand = self._cand
+            want = len(cand) < self._cand_cap
+            observed, over = (native_pass or self._sketch_numpy)(
+                key_hashes, hits, self._floor, want
             )
-            self._cms.update(kh, w)
-            if len(self._cand) < self._cand_cap:
-                est = self._cms.estimate(kh)
-                for fp in kh[est >= self._floor]:
-                    self._cand.add(int(fp))
-                    if len(self._cand) >= self._cand_cap:
-                        break
+            if not observed:
+                return False
+            for fp in over:
+                cand.add(fp)
+                if len(cand) >= self._cand_cap:
+                    break
             events = self._pending_events
             self._pending_events = None
         if events:
             self._fire(events)
+        return native_pass is not None
+
+    def _sketch_numpy(self, key_hashes, hits, floor, want):
+        """The sketch's update for one batch, in numpy: (whether any
+        fingerprint was not zero, those whose estimate after the batch's
+        adds reaches `floor` where `want`) — `native.HotkeyPass`'s
+        reference."""
+        valid = key_hashes != 0
+        kh = key_hashes[valid] if not valid.all() else key_hashes
+        if not len(kh):
+            return False, ()
+        w = np.maximum(
+            hits[valid] if not valid.all() else hits, 1
+        )
+        self._cms.update(kh, w)
+        if not want:
+            return True, ()
+        est = self._cms.estimate(kh)
+        return True, kh[est >= floor].tolist()
 
     _pending_events = None  # (promoted, demoted) staged under the lock
 

@@ -580,6 +580,7 @@ class Metrics:
         # What shares the process with the served path: lane `host`'s
         # rows at zero from start-up.
         self.stages.register("host", tracing.HOST_STAGES)
+        self.stages.declare("host", "host.hotkey", "keys", "native")
         self.stages.observe("host.loop_lag", self._on_loop_lag, "host")
         self.device_occupancy = Gauge(
             "gubernator_tpu_slot_occupancy",

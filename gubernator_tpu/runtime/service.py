@@ -627,8 +627,11 @@ class Service:
         batch has been served (None without a tier)."""
         hk = self.hotkeys
         if hk is not None and len(key_hashes):
-            with self.metrics.stages.stage("host.hotkey", "host"):
-                hk.observe(key_hashes, hits)
+            with self.metrics.stages.stage("host.hotkey", "host") as st:
+                st.tally(
+                    keys=len(key_hashes),
+                    native=hk.observe(key_hashes, hits),
+                )
         tier = self.tier
         if tier is not None and len(key_hashes):
             # Promote-on-access (docs/tiering.md): a served key that is

@@ -69,14 +69,16 @@ class HostCMS:
             )
         self.depth = depth
         self.width = width
-        self._shift = np.uint64(64 - int(width).bit_length() + 1)
-        self._mults = [np.uint64(m) for m in self._MULTS[:depth]]
+        self.shift = 64 - int(width).bit_length() + 1
+        self.mults = np.array(self._MULTS[:depth], dtype=np.uint64)
         self.table = np.zeros((depth, width), dtype=np.int64)
 
     def _row_idx(self, u: np.ndarray, d: int) -> np.ndarray:
         # Multiply-shift: top log2(width) bits of (u * odd_const).
         with np.errstate(over="ignore"):
-            return ((u * self._mults[d]) >> self._shift).astype(np.int64)
+            return (
+                (u * self.mults[d]) >> np.uint64(self.shift)
+            ).astype(np.int64)
 
     def update(self, key_hashes: np.ndarray, weights: np.ndarray) -> None:
         """Add `weights[i]` to fingerprint `key_hashes[i]` (vectorized;

@@ -796,7 +796,9 @@ STAGES: Dict[str, str] = {
     "host.census_fetch": "the census: the executor's wait for the result "
                          "on the host",
     "host.hotkey": "service.note_traffic -> hotkey.observe, on the loop "
-                   "inside wire.ingress",
+                   "inside wire.ingress; counters keys (fingerprints "
+                   "handed to it), native (calls whose sketch update "
+                   "was the one native pass)",
     "host.scrape": "the /metrics and /debug/vars handlers, entry -> return, "
                    "on the loop",
     "host.loop_lag": "the daemon's heartbeat: how late a sleep of "

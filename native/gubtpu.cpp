@@ -33,9 +33,15 @@
 //                        cached, stored_status) walked back through that
 //                        assignment into int64[k, n] per-check columns,
 //                        with the sums the tallies take
+//   gub_hotkey_observe — the hot-key detector's per-batch sketch update
+//                        (runtime/hotkey.py): the adds, the estimates
+//                        after them and the candidates, one pass
 //
 // ctypes releases the GIL for the length of a call: a drain's pack and
-// unpack run beside the other lanes' Python, not in turn with it.
+// unpack run beside the other lanes' Python, not in turn with it.  (The
+// one exception is gub_hotkey_observe for a small batch, bound a second
+// time through ctypes.PyDLL: a pass of a microsecond on the event loop,
+// shorter than taking the GIL back.)
 //
 // Build: make -C native  (g++ -O3 -shared; no external dependencies —
 // XXH64 is implemented from its public spec below).
@@ -706,6 +712,53 @@ int64_t gub_cold_pop(int64_t n, const int64_t* fps, const int64_t* rows,
   }
   *tombs = left;
   return k;
+}
+
+// The hot-key detector's update for one served batch (runtime/hotkey.py
+// HotKeyTracker.observe, on the event loop inside wire.ingress), over the
+// count-min sketch of runtime/sketch_backend.py HostCMS, whose `update`
+// and `estimate` in numpy are the reference the tests hold this pass to
+// bit for bit.  `table` is the sketch's int64[depth, width] in place; row
+// d's index of a fingerprint is the top log2(width) bits of its uint64
+// view times mults[d] (`shift` = 64 - log2(width)).  A zero fingerprint
+// (the parser's error sentinel) is skipped; every other one adds
+// max(hits, 1) in each row, duplicates accumulating (and wrapping as
+// int64 does in numpy).  Then, where `room` > 0, each fingerprint's
+// min-over-rows estimate is taken AFTER the whole batch's adds, and those
+// whose estimate as a binary64 reaches `floor` are written to `out`, in
+// batch order, repeats included, `room` of them at most.  Returns how
+// many were written, or -1 where every fingerprint was zero.
+static inline int64_t cms_idx(uint64_t u, uint64_t mult, int32_t shift) {
+  return shift >= 64 ? 0 : (int64_t)((u * mult) >> shift);
+}
+
+int64_t gub_hotkey_observe(int64_t* table, int32_t depth, int64_t width,
+                           const uint64_t* mults, int32_t shift, int64_t n,
+                           const int64_t* hashes, const int64_t* hits,
+                           double floor, int64_t room, int64_t* out) {
+  bool any = false;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint64_t u = (uint64_t)hashes[i];
+    if (u == 0) continue;
+    any = true;
+    const uint64_t w = hits[i] > 1 ? (uint64_t)hits[i] : 1;
+    for (int32_t d = 0; d < depth; ++d) {
+      int64_t* cell = table + d * width + cms_idx(u, mults[d], shift);
+      *cell = (int64_t)((uint64_t)*cell + w);
+    }
+  }
+  int64_t k = 0;
+  for (int64_t i = 0; i < n && k < room; ++i) {
+    const uint64_t u = (uint64_t)hashes[i];
+    if (u == 0) continue;
+    int64_t est = table[cms_idx(u, mults[0], shift)];
+    for (int32_t d = 1; d < depth; ++d) {
+      const int64_t c = table[d * width + cms_idx(u, mults[d], shift)];
+      if (c < est) est = c;
+    }
+    if ((double)est >= floor) out[k++] = hashes[i];
+  }
+  return any ? k : -1;
 }
 
 // Count the repeated field-1 submessages of a GetRateLimitsReq (or

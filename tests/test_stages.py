@@ -764,6 +764,9 @@ def test_the_rows_of_lane_host_stand_at_zero_from_start_up():
         want = {"count", "ms_total", "ms_max", "max_at_ms"}
         if name == "gc":
             want = want | {"gen2"}
+        if name == "hotkey":
+            want = want | {"keys", "native"}
+            assert (row["keys"], row["native"]) == (0, 0)
         assert set(row) == want, name
         if name in ("gc", "stall"):   # the process's: other tests have run
             continue
@@ -919,6 +922,13 @@ def test_the_interferers_add_to_their_rows_on_a_live_daemon():
         assert host[row]["ms_total"] > host0[row]["ms_total"], row
     # note_traffic: once an RPC.
     assert host["hotkey"]["count"] - host0["hotkey"]["count"] == 40
+    # ... and the row says what it worked on: six fingerprints an RPC,
+    # every update the native pass where the library loaded.
+    from gubernator_tpu import native
+
+    assert {k: host["hotkey"][k] - host0["hotkey"][k]
+            for k in ("keys", "native")} == {
+        "keys": 240, "native": 40 if native.available() else 0}
     # A scrape is timed as it ends: each /debug/vars was under way when
     # it rendered itself, so the second sees the first and the /metrics.
     assert (host0["scrape"]["count"], host["scrape"]["count"]) == (0, 2)
