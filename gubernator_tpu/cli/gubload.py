@@ -3,8 +3,8 @@
 
 Runs one scenario from the library (loadgen/scenarios.py) against an
 in-process cluster (default; fault scenarios require it) or an
-external address list, prints each BENCH-compatible artifact row as a
-JSON line, and writes the full artifact for scripts/bench_gate.py.
+external address list, prints each artifact row as a JSON line, and
+writes the full artifact (loadgen/report.py).
 
 Knobs come from the gubload env surface (deploy/example.conf) with
 flags overriding; the run is deterministic from GUBER_LOAD_SEED.

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field, replace
+from fnmatch import fnmatchcase
 from typing import Dict, List, Optional, Tuple
 
 # Defaults from reference config.go:115-131, 300-301, lrucache.go:63.
@@ -215,9 +216,8 @@ class HotKeyConfig:
 
 
 def hotkey_config_from_env() -> HotKeyConfig:
-    """The hot-key plane's env parse, shared by the daemon and harnesses
-    (same contract as pipeline_depth_from_env): validation errors name
-    the env var at startup instead of crashing a constructor later."""
+    """The hot-key plane's env parse: validation errors name the env
+    var at startup instead of crashing a constructor later."""
     prios = [
         p.strip()
         for p in _env("GUBER_HOTKEY_SHED_PRIORITIES").split(",")
@@ -911,28 +911,6 @@ class DaemonConfig:
     store: Optional[object] = None
     # Approximate (count-min sketch) tier for selected limit names.
     sketch: Optional[SketchTierConfig] = None
-    # Compiled fast lane pipeline depth: how many coalesced device
-    # merges may be in flight at once.  Depth 1 means every drain takes
-    # the WHOLE queue as one maximal merge.  Raise only if profiling shows
-    # host-side gather/serialize starving the device between merges.
-    # (This default and the two below have no measurement on a directly
-    # attached chip behind them yet — PERF.md, open questions.)
-    fastpath_inflight: int = 1
-    # Sparse-overlap threshold (requests): a fast-lane drain at most this
-    # big may dispatch on one of 3 overlap slots instead of waiting out
-    # the in-flight merge's response sync (big drains exceed the limit
-    # and keep the strict depth-1 maximal-merge discipline).  0 disables.
-    fastpath_sparse: int = 64
-    # Pipelined-drain depth (docs/pipeline.md): how many coalesced
-    # merges may be OUTSTANDING (dispatched, response not yet fetched)
-    # per fast-lane lane.  The dispatch stage stays serialized — this
-    # never splits a maximal merge — but merge N+1's device dispatch
-    # overlaps merge N's device->host readback, moving steady-state
-    # throughput from B/(dispatch+fetch) toward B/max(dispatch, fetch).
-    # 1 restores the strict pre-pipeline discipline (dispatch and fetch
-    # serialized end to end); raise past 2 only if pipeline-occupancy
-    # telemetry shows the depth saturated AND bubble time is nonzero.
-    pipeline_depth: int = 2
     # Flight recorder / SLO telemetry (runtime/flightrec.py).  Off by
     # default: the ring + sampler are cheap, but dumps write to disk and
     # operators should choose the directory.
@@ -1138,25 +1116,6 @@ def gubrange_strict_from_env() -> bool:
     return _env("GUBRANGE_STRICT", "false").lower() in ("1", "true", "yes")
 
 
-def fastpath_sparse_from_env() -> int:
-    """The sparse-overlap drain knob, parsed/validated exactly as the
-    daemon does — the public entry for harnesses (bench_e2e) that build
-    DaemonConfig directly but must honor the same env override."""
-    return _require_min(
-        "GUBER_FASTPATH_SPARSE",
-        _env_int("GUBER_FASTPATH_SPARSE", 64), 0,
-    )
-
-
-def pipeline_depth_from_env() -> int:
-    """The pipelined-drain depth knob, parsed/validated exactly as the
-    daemon does (same harness contract as fastpath_sparse_from_env)."""
-    return _require_min(
-        "GUBER_PIPELINE_DEPTH",
-        _env_int("GUBER_PIPELINE_DEPTH", 2), 1,
-    )
-
-
 def mesh_ways_from_env() -> int:
     """The mesh axis size (GUBER_MESH_WAYS — the deployment-mode
     spelling for "shards mapped onto mesh axes"; GUBER_TPU_NUM_SHARDS
@@ -1174,13 +1133,25 @@ def mesh_ways_from_env() -> int:
     return v
 
 
+# Settings a daemon no longer reads (fnmatch patterns), each with why.
+# setup_daemon_config refuses to start with one of them set.
+RETIRED_ENV = (
+    ("GUBER_RING*", "the ring drain disciplines were removed"),
+    ("GUBER_FASTPATH_INFLIGHT",
+     "the drain's dispatch depth is a constant (docs/pipeline.md)"),
+    ("GUBER_FASTPATH_SPARSE",
+     "the drain's sparse-overlap limit is a constant (docs/pipeline.md)"),
+    ("GUBER_PIPELINE_DEPTH",
+     "the drain's pipeline depth is a constant (docs/pipeline.md)"),
+)
+
+
 def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
     """Build a DaemonConfig from GUBER_* env vars (config.go:253-459)."""
     if config_file:
         load_config_file(config_file)
 
-    # Settings removed with the drain disciplines they selected: refused
-    # by name, never ignored in silence.
+    # Retired settings: refused by name, never ignored in silence.
     mode = _env("GUBER_SERVE_MODE").strip().lower()
     if mode not in ("", "pipelined"):
         raise ValueError(
@@ -1188,11 +1159,11 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
             "has one drain discipline ('pipelined'); unset the variable"
         )
     for name in sorted(os.environ):
-        if name.startswith("GUBER_RING") and os.environ[name]:
-            raise ValueError(
-                f"{name} is not supported: the ring drain disciplines "
-                "were removed; unset the variable"
-            )
+        for retired, why in RETIRED_ENV:
+            if os.environ[name] and fnmatchcase(name, retired):
+                raise ValueError(
+                    f"{name} is not supported: {why}; unset the variable"
+                )
 
     behaviors = BehaviorConfig(
         batch_timeout_s=_env_float_s("GUBER_BATCH_TIMEOUT", DEFAULT_BATCH_TIMEOUT_S),
@@ -1313,12 +1284,6 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
         # Bit 1 = process/platform/GC collectors (the GUBER_METRIC_FLAGS
         # golang/process flags, daemon.go:255-266, flags.go:19-56).
         metric_flags=_env_int("GUBER_METRIC_FLAGS", 0),
-        fastpath_inflight=_require_min(
-            "GUBER_FASTPATH_INFLIGHT",
-            _env_int("GUBER_FASTPATH_INFLIGHT", 1), 1,
-        ),
-        fastpath_sparse=fastpath_sparse_from_env(),
-        pipeline_depth=pipeline_depth_from_env(),
         flightrec=_env("GUBER_FLIGHTREC") in ("1", "true"),
         flightrec_dir=_env("GUBER_FLIGHTREC_DIR", "flightrec-dumps"),
         flightrec_ring=_require_min(

@@ -486,12 +486,7 @@ class Daemon:
         await self.service.start()
         from gubernator_tpu.runtime.fastpath import FastPath
 
-        self.fastpath = FastPath(
-            self.service,
-            max_inflight=getattr(self.conf, "fastpath_inflight", 1),
-            sparse_limit=getattr(self.conf, "fastpath_sparse", 64),
-            pipeline_depth=getattr(self.conf, "pipeline_depth", 2),
-        )
+        self.fastpath = FastPath(self.service)
         # Table build + every start-up compile (or compile-cache load).
         self._warmup_s = time.monotonic() - t_warm
         if cfg.stats.enabled:
@@ -983,8 +978,8 @@ class Daemon:
         fp = self.fastpath
         if fp is not None:
             # Per-lane drain/pipeline counters (drains, overlap_drains,
-            # waited_drains, bubble_ms_total, occupancy) — the knobs an
-            # operator reads when tuning GUBER_PIPELINE_DEPTH.
+            # waited_drains, bubble_ms_total, occupancy): whether the
+            # drain's depth binds.
             out["fastpath"] = fp.debug_vars()
         # The stage ledger (runtime/tracing.py): count / ms_total /
         # ms_max of every step of the served path, by lane

@@ -13,7 +13,8 @@ Layers:
   scenarios.py  the scenario library (steady, diurnal, burststorm,
                 flashcrowd, reshard_churn, partition_leased)
   runner.py     composition: cluster, phases, hooks, verdict
-  report.py     BENCH_E2E-compatible artifact rows bench_gate gates on
+  report.py     the run's artifact: one row per phase plus the overall
+                row with the verdict (schema: report.validate_row)
 """
 from .engine import OutcomeCounts, PhaseTracker, closed_loop, open_loop
 from .report import build_artifact, validate_row

@@ -1,12 +1,10 @@
-"""BENCH_E2E-compatible artifact rows for scenario runs
-(docs/loadgen.md).
+"""gubload's artifact rows for scenario runs (docs/loadgen.md).
 
-The artifact is the same shape bench_e2e.py emits — a top-level
-platform-honest label plus one JSON line per result — so
-scripts/bench_gate.py gates scenario runs with the same machinery:
-per-scenario keys (config, scenario, phase, platform), p50 regression
-past the threshold + noise floor fails, a scenario key with no
-baseline warns instead of hard-failing on first appearance.
+The artifact is a top-level platform-honest label plus one JSON line
+per result, keyed (config, scenario, phase, platform).  Nothing gates
+one run's rows against another's: the repo's performance record is
+the benchmark's (bench/, PERF_LEDGER.jsonl); `validate_row` holds the
+schema.
 
 Every row carries the OPEN-LOOP percentiles (latency from intended
 send) and the run's intended-vs-actual send skew, so a reader can
@@ -28,7 +26,8 @@ ROW_REQUIRED = (
 
 def _platform() -> str:
     """The ACTUAL jax platform (platform honesty: a cpu artifact must
-    never gate a tpu recording as if hardware were comparable)."""
+    never be read beside a tpu recording as if hardware were
+    comparable)."""
     try:
         import jax
 

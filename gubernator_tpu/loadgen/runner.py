@@ -1,11 +1,11 @@
 """Scenario runner (docs/loadgen.md): boots (or targets) a cluster,
 precomputes every phase's arrival schedule, drives them open-loop,
 applies fault hooks at phase boundaries, and ends in the scenario's
-merged-ledger verdict plus a BENCH_E2E-compatible artifact.
+merged-ledger verdict plus the run's artifact (report.py).
 
 The runner is the composition point: schedule.py plans, engine.py
 dispatches and records, spec.py/scenarios.py decide pass/fail, and
-report.py shapes the proof into an artifact bench_gate can gate on.
+report.py shapes the proof into the artifact.
 """
 from __future__ import annotations
 

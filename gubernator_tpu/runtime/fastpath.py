@@ -120,7 +120,7 @@ class _Coalescer:
         The table-update chain already serializes correctly on the XLA
         stream, so merge N+1 may dispatch the moment merge N's dispatch
         returns.
-      fetch stage — depth-`pipeline_depth` (GUBER_PIPELINE_DEPTH).  The
+      fetch stage — depth-`pipeline_depth`.  The
         continuation syncs the response to host and unmarshals; out-of-
         order completion is safe because results flow through per-entry
         futures.  A fetch SLOT is taken before dispatching, so at most
@@ -535,14 +535,20 @@ class _Coalescer:
 class FastPath:
     """Per-service compiled lane with a coalescing columnar batcher.
 
-    `max_inflight` bounds concurrent DISPATCH stages (default 1: every
-    drain takes the WHOLE queue as one maximal merge; no measurement on
-    a directly attached chip stands behind the default yet).
-    `pipeline_depth` bounds
-    OUTSTANDING merges (dispatched, response not yet fetched): the
-    response round-trip that used to serialize behind the next dispatch
-    now overlaps it, so maximal merges pipeline without ever being
-    split (docs/pipeline.md).  Dispatch order is serialized by the
+    The three arguments' defaults are what every daemon runs (daemon.py
+    builds `FastPath(service)`; no setting carries them); other values
+    are for tests, whose reference is depth 1.  No measurement on the
+    chip stands behind any of the three yet (ROADMAP A2).
+    `max_inflight` bounds concurrent DISPATCH stages (1: every drain
+    takes the WHOLE queue as one maximal merge).  `sparse_limit` (64
+    requests): a drain at most this big may dispatch on an overlap slot
+    rather than wait out the in-flight merge's response sync; 0 is off.
+    `pipeline_depth` (2: one merge fetching while the next dispatches)
+    bounds OUTSTANDING merges (dispatched, response not yet fetched):
+    the response round-trip overlaps the next dispatch, so maximal
+    merges pipeline without ever being split (docs/pipeline.md).
+
+    Dispatch order is serialized by the
     backend lock; cascade merges hold that lock across their whole
     read -> replay -> write-back window, which serializes them against
     every other mutation path (this lane, the object path, the GLOBAL
@@ -2109,7 +2115,7 @@ class FastPath:
             # Plain merge: dispatch under the backend lock; the response
             # sync rides the coalescer's FETCH stage, so the next
             # maximal merge dispatches while this one's response syncs
-            # (depth bounded by GUBER_PIPELINE_DEPTH).
+            # (depth bounded by `pipeline_depth`).
             pack.end()
             fetch_host = backend.step_rounds_begin(
                 rounds, add_tally=False

@@ -61,9 +61,9 @@ DEFAULT_SAMPLE_INTERVAL_S = 0.25
 
 
 def _quantiles(values: List[float]) -> Tuple[float, float]:
-    """(p50, p99) by nearest-rank on a sorted copy — same convention as
-    bench_e2e._percentiles up to interpolation, cheap enough to run
-    every sampler tick on a bounded window."""
+    """(p50, p99) by nearest-rank on a sorted copy — numpy's
+    percentile up to interpolation, cheap enough to run every sampler
+    tick on a bounded window."""
     if not values:
         return 0.0, 0.0
     s = sorted(values)
@@ -177,8 +177,9 @@ class FlightRecorder:
         """One pipelined-drain bubble (runtime/fastpath.py): a ready
         merge stalled `wait_ms` waiting for a fetch slot while the
         dispatch stage sat idle.  Sustained bubbles with saturated
-        pipeline occupancy are the signal to raise
-        GUBER_PIPELINE_DEPTH."""
+        pipeline occupancy are the signal that the drain's depth
+        (FastPath's `pipeline_depth`, a constant: docs/pipeline.md) is
+        too small."""
         self.record(
             "fastlane_bubble", lane=lane, wait_ms=round(wait_ms, 3)
         )

@@ -538,7 +538,7 @@ class Metrics:
             "Wall time of one pipelined-drain stage in seconds: "
             "dispatch (pack + device dispatch, serialized) vs fetch "
             "(device->host readback + unmarshal, depth "
-            "GUBER_PIPELINE_DEPTH).",
+            "FastPath.pipeline_depth).",
             ["lane", "stage"],
             buckets=LATENCY_BUCKETS,
             registry=r,

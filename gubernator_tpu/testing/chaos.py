@@ -65,8 +65,7 @@ def zipf_keys(seed: int, s: float, n: int, universe: int):
     models production key popularity).  Deterministic from the seed —
     the same discipline as the fault plans, so a hot-key overload
     scenario reproduces from (seed, s) alone.  Used by
-    scripts/chaos_smoke.py and the bench_e2e --workload zipf:<s>
-    config."""
+    scripts/chaos_smoke.py."""
     import numpy as np
 
     rng = np.random.default_rng(seed)

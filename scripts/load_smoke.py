@@ -11,9 +11,8 @@ open-loop harness chain in one required step:
   3. phase markers landed in every daemon's flight-recorder ring
      (kind="load_phase", enter AND exit for each phase) — the
      phase-linked attribution an operator joins dumps against;
-  4. every artifact row passes the BENCH schema check and
-     scripts/bench_gate.py accepts the artifact against itself
-     (0 regressions — the self-diff proves key compatibility).
+  4. every artifact row passes gubload's schema check
+     (loadgen/report.py validate_row).
 
 On any failure each daemon's flight recorder dumps its ring to
 GUBER_FLIGHTREC_DIR (default flightrec-dumps/) so the CI artifact
@@ -119,19 +118,12 @@ def main(argv=None) -> int:
         print(f"load_smoke: phase markers present in "
               f"{len(cluster.daemons)} rings ({sorted(want_phases)})")
 
-        # 4. Artifact schema + bench_gate self-diff (exit 0, matched
-        # keys, no regressions).
+        # 4. Artifact schema.
         artifact = result["artifact"]
         for row in artifact["results"]:
             validate_row(row)
-        from scripts import bench_gate
-
-        rc = bench_gate.gate(
-            artifact, artifact, threshold=0.25, warn_only=False
-        )
-        assert rc == 0, f"bench_gate self-diff failed (exit {rc})"
         print(f"load_smoke: {len(artifact['results'])} artifact rows "
-              "valid; bench_gate accepts")
+              "valid")
     except BaseException:
         _dump_flightrec(cluster)
         raise

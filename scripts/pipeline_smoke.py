@@ -2,12 +2,13 @@
 
 Drives ~10k mixed checks (token/leaky, bursts, RESET_REMAINING, valid
 Gregorian, zero/negative hits, duplicate keys) through the compiled fast
-lane twice — once at GUBER_PIPELINE_DEPTH=1 (the strict pre-pipeline
-discipline) and once at depth 2 — under a frozen clock, with concurrent
-workers owning disjoint key spaces so every key's history is
-deterministic regardless of merge composition.  Responses and the final
-table rows must match bit-for-bit; the depth-2 run must actually have
-pipelined (>= 2 merges observed in flight) or the smoke is vacuous.
+lane twice — once at depth 1 (`FastPath(svc, pipeline_depth=1)`, the
+strict pre-pipeline discipline: the reference) and once at depth 2 —
+under a frozen clock, with concurrent workers owning disjoint key spaces
+so every key's history is deterministic regardless of merge composition.
+Responses and the final table rows must match bit-for-bit; the depth-2
+run must actually have pipelined (>= 2 merges observed in flight) or the
+smoke is vacuous.
 
 Runs in the CI matrix (JAX_PLATFORMS=cpu); exit 0 = pass.
 """

@@ -1,11 +1,17 @@
 """Core-layer tests: types, clock, Gregorian intervals, config, hashing."""
+import json
 import os
+import re
 from datetime import datetime, timezone
+from fnmatch import fnmatchcase
+from pathlib import Path
 
 import pytest
 
 from gubernator_tpu.core import clock as clock_mod
+from gubernator_tpu.core import config as config_mod
 from gubernator_tpu.core.config import (
+    RETIRED_ENV,
     BehaviorConfig,
     DeviceConfig,
     parse_duration_s,
@@ -130,24 +136,10 @@ def test_env_config(monkeypatch):
     assert cfg.peer_discovery_type == "static"
 
 
-def test_fastpath_sparse_env(monkeypatch):
-    """The public sparse-knob parser (used by bench_e2e so A/B harness
-    runs share the daemon's own parse) matches setup_daemon_config."""
-    from gubernator_tpu.core.config import fastpath_sparse_from_env
-
-    monkeypatch.delenv("GUBER_FASTPATH_SPARSE", raising=False)
-    assert fastpath_sparse_from_env() == 64
-    monkeypatch.setenv("GUBER_FASTPATH_SPARSE", "0")
-    assert fastpath_sparse_from_env() == 0
-    assert setup_daemon_config().fastpath_sparse == 0
-    monkeypatch.setenv("GUBER_FASTPATH_SPARSE", "-1")
-    with pytest.raises(ValueError):
-        fastpath_sparse_from_env()
-
-
-# The settings that selected a drain discipline are gone; a daemon must
-# refuse them by name, not ignore them.  The ring family is matched by
-# its prefix.
+# The settings that selected a drain discipline, and the three that
+# sized the one that is left (constants of FastPath since PR 50), are
+# gone; a daemon must refuse them by name, not ignore them.  The ring
+# family is matched by its prefix.
 _RING = "GUBER_RING"
 
 
@@ -159,6 +151,9 @@ _RING = "GUBER_RING"
     (_RING + "_SLOTS", "8"),
     (_RING + "_ROUNDS", "4"),
     (_RING + "_MAX_LINGER_US", "200"),
+    ("GUBER_FASTPATH_INFLIGHT", "1"),
+    ("GUBER_FASTPATH_SPARSE", "64"),
+    ("GUBER_PIPELINE_DEPTH", "2"),
 ])
 def test_removed_drain_settings_are_refused(monkeypatch, name, value):
     monkeypatch.setenv(name, value)
@@ -168,7 +163,30 @@ def test_removed_drain_settings_are_refused(monkeypatch, name, value):
 
 def test_the_one_drain_discipline_is_accepted_by_name(monkeypatch):
     monkeypatch.setenv("GUBER_SERVE_MODE", "pipelined")
-    assert setup_daemon_config().pipeline_depth == 2
+    conf = setup_daemon_config()
+    # What sizes that discipline is not the configuration's to say.
+    assert not {"fastpath_inflight", "fastpath_sparse",
+                "pipeline_depth"} & set(vars(conf))
+
+
+_BENCH_CONFIGS = sorted(
+    (Path(__file__).resolve().parents[1] / "bench" / "configs").glob("*.json")
+)
+
+
+@pytest.mark.parametrize("path", _BENCH_CONFIGS, ids=lambda p: p.stem)
+def test_a_benchmark_configuration_sets_only_what_a_daemon_reads(path):
+    """A cell cannot set what the daemon ignores (a name core/config.py
+    never reads) or refuses (a retired one): every GUBER_* name in a
+    configuration's `daemon` block is read by core/config.py."""
+    read = set(re.findall(
+        r"GUBER_[A-Z0-9_]+", Path(config_mod.__file__).read_text()
+    ))
+    settings = json.loads(path.read_text())["daemon"]
+    assert settings, path
+    for name in settings:
+        assert not any(fnmatchcase(name, r) for r, _ in RETIRED_ENV), name
+        assert name in read, f"{path.name} sets {name}, which nothing reads"
 
 
 def test_device_config_validation():

@@ -372,20 +372,31 @@ def test_fastpath_differential_duplicate_heavy(frozen_clock):
 
 
 def test_sparse_overlap_drains():
-    """GUBER_FASTPATH_SPARSE>0 (the shipped default is 64; 0 disables):
-    small drains may overlap the in-flight merge on an overlap slot.
+    """`sparse_limit` > 0 (every daemon runs 64; 0 disables): small
+    drains may overlap the in-flight merge on an overlap slot.
     Pin the concurrency path — overlap drains actually trigger under
     concurrent small batches, every response stays correct (each key's
     decrement sequence is exact), and close() during traffic neither
     hangs nor orphans waiters."""
-    # Depth 1 pins the r5-exact configuration: the sparse slot is the
-    # ONLY overlap mechanism, so any drain arriving while the single
-    # fetch slot is busy is overlap-eligible.
-    conf = DaemonConfig(fastpath_sparse=64, pipeline_depth=1)
-    c = Cluster.start(1, conf_template=conf)
+    # Depth 1 makes the sparse slot the ONLY overlap mechanism, so any
+    # drain arriving while the single fetch slot is busy is
+    # overlap-eligible.  No setting reaches the depth: the daemon's lane
+    # is swapped for one built at depth 1 through the constructor.
+    from gubernator_tpu.runtime.fastpath import FastPath
+
+    c = Cluster.start(1)
     try:
+        d = c.daemons[0]
+
+        async def at_depth_one():
+            served, d.fastpath = d.fastpath, FastPath(
+                d.service, sparse_limit=64, pipeline_depth=1
+            )
+            await served.close()
+
+        c.run(at_depth_one())
         fp = _fp(c)
-        assert fp._mach._sparse_limit == 64
+        assert fp._mach._sparse_limit == 64 and fp.pipeline_depth == 1
 
         async def hammer(rounds_done: int):
             from gubernator_tpu.client import AsyncV1Client
@@ -1969,16 +1980,34 @@ def _free_ports(n):
     SAME ports across their two sequential runs (identical advertise
     addresses => identical vnode rings), but hardcoded ports collide
     when suites run in parallel on one host (pytest-xdist/CI) — so pick
-    dynamically once per test and reuse for both runs.  All n sockets
-    stay bound until every port is collected so the picks are distinct."""
+    dynamically once per test and reuse for both runs.  A daemon binds
+    its listeners only after its warm-up, tens of seconds after the
+    pick (and the second run binds them again), so the ports are drawn
+    BELOW the kernel's ephemeral range: there no other worker's port-0
+    listener and no outgoing connection can take one meanwhile.  All n
+    sockets stay bound until every port is collected so the picks are
+    distinct."""
+    import random
     import socket
 
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            ephemeral_lo = int(f.read().split()[0])
+    except (OSError, ValueError):
+        ephemeral_lo = 32768
     socks = []
     try:
-        for _ in range(n):
+        for port in random.sample(range(10_000, ephemeral_lo), 512):
             s = socket.socket()
-            s.bind(("127.0.0.1", 0))
+            try:
+                s.bind(("127.0.0.1", port))
+            except OSError:
+                s.close()
+                continue
             socks.append(s)
+            if len(socks) == n:
+                break
+        assert len(socks) == n, "no free ports below the ephemeral range"
         return [s.getsockname()[1] for s in socks]
     finally:
         for s in socks:

@@ -181,7 +181,7 @@ def test_cms_step_consumes_donated_state():
     )
 
 
-# -- runtime recompile report (microbench --recompile-audit core) --------
+# -- runtime recompile report: live jit-cache counts of this process ------
 def test_runtime_cache_report_sees_module_kernels():
     from tools.gubtrace.recompile import runtime_cache_report
 

@@ -439,6 +439,17 @@ def test_hotkey_lifecycle_mirror_bound_and_collapse(hot_cluster):
 def _assert_owner_sheds(owner):
     cl = V1Client(owner.grpc_address)
     try:
+        # The owner judges its SLO from the requests of its 2 s window and
+        # from no fewer than `min_samples` (20) of them: one probe a tenth
+        # of a second is under that, so once the storm's samples have left
+        # the window — at once, on a slow host — the breach run would end
+        # before `shed_cooldown_s`.  Reads that keep the window fed,
+        # whatever the machine's pace.
+        for _ in range(25):
+            cl.get_rate_limits([
+                RateLimitReq(name="keep", unique_key="kp", hits=0,
+                             limit=1000, duration=DURATION),
+            ], timeout=30)
         rs = cl.get_rate_limits([
             RateLimitReq(name="bulk.jobs", unique_key="b", hits=1,
                          limit=1000, duration=DURATION),

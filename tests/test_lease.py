@@ -530,8 +530,8 @@ def test_ownership_routing_and_reconvergence(lease_cluster):
 
 def test_leased_client_zero_rpc_steady_state(lease_cluster):
     """Steady single-key load burns locally: >=10x fewer RPCs per
-    admitted check than per-call traffic (the ISSUE acceptance ratio,
-    measured end to end by bench_e2e --client-mode)."""
+    admitted check than per-call traffic (the ISSUE acceptance ratio;
+    no cell of the benchmark drives a leased client yet: ROADMAP B13)."""
     c = lease_cluster
     addr = c.daemons[0].grpc_address
     cfg = LeaseConfig(

@@ -20,8 +20,8 @@ The load-bearing claims pinned here:
   5. The gubload env surface parses with named-variable errors.
   6. End to end (tier-1): the steady scenario against a real 2-daemon
      cluster — exact ledger verdict, phase markers in the flight
-     recorder, schema-valid BENCH artifact rows that bench_gate
-     accepts, phase attribution cleaned up after the run.
+     recorder, schema-valid artifact rows, phase attribution cleaned
+     up after the run.
 """
 from __future__ import annotations
 
@@ -413,9 +413,8 @@ def test_gubtop_renders_load_line():
 def test_steady_scenario_end_to_end():
     """The tier-1 acceptance run: a short seeded steady scenario on a
     2-daemon cluster — exact ledger verdict, load_phase markers in the
-    flight recorder ring, schema-valid artifact rows that bench_gate
-    accepts against themselves, and every attribution plane cleaned up
-    after the run."""
+    flight recorder ring, schema-valid artifact rows, and every
+    attribution plane cleaned up after the run."""
     from gubernator_tpu.testing import Cluster
 
     cfg = LoadConfig(
@@ -450,8 +449,7 @@ def test_steady_scenario_end_to_end():
             assert d.load_status is None
             assert _gauge_samples(d.metrics.load_active) == []
 
-        # Artifact rows: schema-valid, per-phase + overall, and the
-        # gate accepts them (self-diff: matched keys, 0 regressions).
+        # Artifact rows: schema-valid, per-phase + overall.
         artifact = result["artifact"]
         rows = artifact["results"]
         assert {r["phase"] for r in rows} == {
@@ -459,20 +457,5 @@ def test_steady_scenario_end_to_end():
         }
         for row in rows:
             validate_row(row)
-        import importlib.util
-        import sys
-        from pathlib import Path
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_gate",
-            Path(__file__).resolve().parent.parent
-            / "scripts" / "bench_gate.py",
-        )
-        bench_gate = importlib.util.module_from_spec(spec)
-        sys.modules.setdefault("bench_gate", bench_gate)
-        spec.loader.exec_module(bench_gate)
-        assert bench_gate.gate(
-            artifact, artifact, threshold=0.25, warn_only=False
-        ) == 0
     finally:
         cluster.stop()

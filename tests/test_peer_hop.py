@@ -447,7 +447,14 @@ def _warm(c, tag: str):
     entry, owner = c.daemons
     k = next(k for k in range(100, 1000)
              if c.owner_daemon_of(f"once_{tag}{k}") is owner)
-    assert _ask(c, entry, [_once_req(tag, k, 0)]) == [("", 0, 10, 10)]
+    # A peek, so asking again spends nothing: on a loaded host the first
+    # forward over a cold channel can outlast the forward limit, and a
+    # peer that has never answered is not asked again by the program.
+    for _ in range(5):
+        got = _ask(c, entry, [_once_req(tag, k, 0)])
+        if not got[0][0]:
+            break
+    assert got == [("", 0, 10, 10)]
     peer = entry.service.get_peer(f"once_{tag}{k}")
     assert peer.info().grpc_address == owner.grpc_address
     return peer
@@ -471,8 +478,17 @@ def test_an_owners_stall_costs_latency_and_never_an_error(pair, where):
     tag = f"{where}-"
     peer = _warm(c, tag)
     assert peer._applies_once
+    # Twelve keys of each daemon, whatever ring the run's ports give:
+    # how many of the seeded checks are forwarded does not depend on it.
+    by_owner = {entry: [], owner: []}
+    for k in range(1000):
+        mine = by_owner[c.owner_daemon_of(f"once_{tag}{k}")]
+        if len(mine) < 12:
+            mine.append(k)
+    ids = by_owner[entry] + by_owner[owner]
+    assert len(ids) == 24
     rng = np.random.default_rng(39)
-    reqs = [_once_req(tag, int(rng.integers(24)),
+    reqs = [_once_req(tag, ids[int(rng.integers(24))],
                       int(rng.choice([0, 1, 1, 2, 5]))) for _ in range(160)]
     owned = [c.owner_daemon_of(f"once_{r.unique_key}") is owner
              for r in reqs]
@@ -507,12 +523,11 @@ def test_an_owners_stall_costs_latency_and_never_an_error(pair, where):
     assert got == _reference(oracle, reqs)
     assert [g[0] for g in got] == [""] * len(reqs)
     # Each hit spent exactly once: what a read finds now.
-    reads = [_once_req(tag, k, 0) for k in range(24)]
+    reads = [_once_req(tag, k, 0) for k in ids]
     assert _ask(c, entry, reads) == _reference(oracle, reads)
     e1, o1 = _hop(entry), _hop(owner)
     grown = {k: e1[k] - e0[k] for k in e0}
-    assert grown["count"] == 2 and grown["checks"] == sum(owned) + 24 - sum(
-        c.owner_daemon_of(f"once_{tag}{k}") is not owner for k in range(24))
+    assert grown["count"] == 2 and grown["checks"] == sum(owned) + 12
     assert grown["timeouts"] == grown["reasked"] >= 1, grown
     assert (grown["refused"], grown["retried"], grown["joined"]) == (0, 0, 0)
     joined = o1["joined"] - o0["joined"]

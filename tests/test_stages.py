@@ -696,9 +696,10 @@ def test_a_threads_family_is_the_first_two_words_of_its_name(name, family):
 
 def test_two_spinning_threads_share_one_gil():
     """Two Python threads spin in sections at once: between them they
-    had the CPU for about the wall that passed, not twice it — the GIL,
-    whatever the machine's load — which is what `host_python_cores`
-    reads near 1.0."""
+    had the CPU for at most about the wall that passed, not twice it —
+    the GIL, whatever the machine's load — which is what
+    `host_python_cores` reads near 1.0.  The bound is the upper one: on
+    a loaded host the two get less of a core, never more than one."""
     ledger = tracing.StageLedger()
     start = threading.Barrier(3)
     seen = threading.Event()
@@ -728,7 +729,7 @@ def test_two_spinning_threads_share_one_gil():
     assert "gil-spin" not in before
     spun = [during["gil-spin"][f"gil-spin-{i}"]["cpu_ms"] for i in range(2)]
     assert all(ms > 0 for ms in spun), spun
-    assert 0.5 * wall_ms <= sum(spun) <= 1.15 * wall_ms + 20, (spun, wall_ms)
+    assert sum(spun) <= 1.15 * wall_ms + 20, (spun, wall_ms)
     # Once they have ended the block keeps their readings: no sum falls.
     after = tracing.thread_vars()
     assert [after["gil-spin"][f"gil-spin-{i}"]["cpu_ms"]

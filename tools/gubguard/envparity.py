@@ -22,6 +22,10 @@ Rules:
   * parsed-but-undocumented (absent from deploy/example.conf) is a
     WARNING: every supported knob must be discoverable.
 
+The names in core/config.py's RETIRED_ENV (settings a daemon refuses
+to start with) are not parsed: a doc that still promises one is the
+same ERROR, and example.conf need not list them.
+
 OTEL_* is an ACKNOWLEDGED external namespace, not drift: it is the
 OpenTelemetry SDK's own env spec (runtime/tracing.py reads the subset
 it implements; an attached OTel SDK reads more).  Docs may therefore
@@ -101,11 +105,20 @@ class EnvParityChecker(Checker):
     def __init__(self) -> None:
         self.parsed: Set[str] = set()
         self.parsed_otel: Set[str] = set()
+        self.retired: Set[str] = set()
         self.saw_config = False
 
     def check_module(self, mod: ModuleInfo) -> Iterable[Finding]:
         if mod.relpath.endswith("core/config.py"):
             self.saw_config = True
+            for node in mod.tree.body:
+                if isinstance(node, ast.Assign) and any(
+                    isinstance(t, ast.Name) and t.id == "RETIRED_ENV"
+                    for t in node.targets
+                ):
+                    self.retired.update(
+                        _VAR_RE.findall(ast.unparse(node.value))
+                    )
         for node in ast.walk(mod.tree):
             if isinstance(node, ast.Constant) and isinstance(
                 node.value, str
@@ -119,6 +132,7 @@ class EnvParityChecker(Checker):
             # Partial scan (single file / subpackage): the parsed set is
             # incomplete, so a doc diff would be all false positives.
             return ()
+        self.parsed -= self.retired
         referenced: Dict[str, List[str]] = {}
         for pattern in _DOC_GLOBS:
             for p in sorted(root.glob(pattern)):

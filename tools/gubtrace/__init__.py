@@ -21,7 +21,9 @@ Run:
     python -m tools.gubtrace --update                    # re-snapshot
 
 Exit status 0 = clean (warnings allowed), 1 = errors.  The runtime
-counterpart is `gubernator-tpu-microbench --recompile-audit`.
+counterpart is the benchmark's `compiled_in_window` (bench/run.py:
+compile-cache entries written inside a cell's measured window; any
+is `correct: false`).
 """
 from __future__ import annotations
 

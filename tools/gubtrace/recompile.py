@@ -36,10 +36,11 @@ def cache_size(fn) -> Optional[int]:
 
 def runtime_cache_report() -> Dict[str, Optional[int]]:
     """Live jit-cache entry counts for every module-level jitted kernel
-    the registry watches — the runtime counterpart of the static audit
-    (`gubernator-tpu-microbench --recompile-audit` prints this after a
-    canonical workload; a count above the expected tier/shape set means
-    a recompile storm reached production)."""
+    the registry watches, for a process that has already run them (a
+    count above the expected tier/shape set means a kernel recompiled).
+    The served daemon's runtime check is the benchmark's
+    `compiled_in_window` (bench/run.py), which holds every cell to zero
+    compiles inside its measured window."""
     import importlib
     from pathlib import Path
 
